@@ -790,8 +790,8 @@ def audit_decode(rules=None, config_overrides=None, kv_cache_dtype=None,
             engine._decode, engine.decode_lowering_args(), engine)
     census = cache_dtype_census(engine.cache)
     if paged:
-        payload_shape = (engine.spec.n_pages, engine.spec.page_size,
-                         engine.spec.n_head, engine.spec.head_dim)
+        payload_shape = (engine.spec.n_pages, engine.spec.n_head,
+                         engine.spec.page_size, engine.spec.head_dim)
         page_facts = {"page_size": engine.page_size,
                       "n_pages": engine.n_pages,
                       "pages_per_row": engine.pages_per_row,
@@ -953,8 +953,8 @@ def audit_speculative(rules=None, config_overrides=None,
         full_flops = _xla_flops(engine._decode,
                                 engine.decode_lowering_args())
         if layout == "paged":
-            payload_shape = (engine.spec.n_pages, engine.spec.page_size,
-                             engine.spec.n_head, engine.spec.head_dim)
+            payload_shape = (engine.spec.n_pages, engine.spec.n_head,
+                             engine.spec.page_size, engine.spec.head_dim)
             page_facts = {"page_size": engine.page_size,
                           "n_pages": engine.n_pages,
                           "pages_per_row": engine.pages_per_row,
@@ -1096,9 +1096,8 @@ def audit_disagg(rules=None, config_overrides=None):
                   for t, e in (("prefill", pre_engine),
                                ("decode", dec_engine))}
     census = cache_dtype_census(dec_engine.cache)
-    payload_shape = (dec_engine.spec.n_pages,
-                     dec_engine.spec.page_size,
-                     dec_engine.spec.n_head, dec_engine.spec.head_dim)
+    payload_shape = (dec_engine.spec.n_pages, dec_engine.spec.n_head,
+                     dec_engine.spec.page_size, dec_engine.spec.head_dim)
     ctx = StepContext(
         hlo_text=hlo_text, flavor="disagg",
         compute_dtype="f32",

@@ -5,7 +5,7 @@ The HLO side of the audit (`analysis/hlo.py`) reads facts off the
 closed jaxpr of a train step — where control flow (``cond``/``while``/
 ``scan``), mesh-axis data dependence, and collective ordering are still
 first-class structure instead of partitioned channel ids. Three passes,
-all pure functions over a :class:`jax.core.ClosedJaxpr`:
+all pure functions over a :class:`jax.extend.core.ClosedJaxpr`:
 
 - :func:`check_divergent_collectives` — the PR 5 pipeline deadlock as a
   rule. Values derived from ``lax.axis_index`` are *device-varying*
@@ -47,7 +47,8 @@ import dataclasses
 
 import numpy as np
 
-from jax import core as jcore
+from jax.core import DropVar
+from jax.extend import core as jcore
 
 # Collective primitives by rendezvous discipline (jaxpr names).
 # ``ppermute`` lowers to ``collective-permute`` whose rendezvous is
@@ -220,7 +221,7 @@ def check_divergent_collectives(closed_jaxpr):
             return env.get(atom, frozenset())
 
         def write(var, taint):
-            if not isinstance(var, jcore.DropVar):
+            if not isinstance(var, DropVar):
                 env[var] = taint
 
         for var, t in zip(jaxpr.invars, in_taints):
@@ -441,7 +442,7 @@ def check_unordered_permutes(closed_jaxpr, max_findings=16):
                         })
                 permute_eqns.append((i, eqn.primitive.name))
             for v in eqn.outvars:
-                if not isinstance(v, jcore.DropVar):
+                if not isinstance(v, DropVar):
                     producer[v] = i
 
     walk(closed_jaxpr.jaxpr, ())
@@ -538,7 +539,7 @@ def propagate_partition_specs(closed_jaxpr, in_specs):
             return env.get(atom, UNKNOWN)
 
         def write(var, spec):
-            if not isinstance(var, jcore.DropVar):
+            if not isinstance(var, DropVar):
                 env[var] = spec
 
         for var, s in zip(jaxpr.invars, specs_in):
@@ -656,7 +657,7 @@ def propagate_partition_specs(closed_jaxpr, in_specs):
                     write(var, s)
                 continue
 
-            if p == "pjit":
+            if p == "jit":
                 for jx, binders, label in subjaxpr_bindings(eqn):
                     sub_out = walk(jx, in_s, path + (label,))
                     if len(sub_out) == len(eqn.outvars):

@@ -176,35 +176,35 @@ class KernelAnalysis:
 # Call-like primitives worth recursing through when (and only when) a
 # pallas_call hides inside; everything else executes via plain bind.
 _CALL_JAXPR_KEYS = {
-    "pjit": "jaxpr",
+    "jit": "jaxpr",
     "closed_call": "call_jaxpr",
     "core_call": "call_jaxpr",
     "custom_jvp_call": "call_jaxpr",
     "custom_vjp_call": "call_jaxpr",
     "custom_vjp_call_jaxpr": "fun_jaxpr",
-    "remat": "jaxpr",
+    "remat2": "jaxpr",
     "checkpoint": "jaxpr",
 }
 
 
 def _as_closed(obj):
-    import jax
+    from jax.extend import core as jcore
 
-    if isinstance(obj, jax.core.ClosedJaxpr):
+    if isinstance(obj, jcore.ClosedJaxpr):
         return obj
-    return jax.core.ClosedJaxpr(obj, ())
+    return jcore.ClosedJaxpr(obj, ())
 
 
 def _param_jaxprs(params):
-    import jax
+    from jax.extend import core as jcore
 
     out = []
     for v in params.values():
         vals = v if isinstance(v, (tuple, list)) else (v,)
         for item in vals:
-            if isinstance(item, jax.core.ClosedJaxpr):
+            if isinstance(item, jcore.ClosedJaxpr):
                 out.append(item.jaxpr)
-            elif isinstance(item, jax.core.Jaxpr):
+            elif isinstance(item, jcore.Jaxpr):
                 out.append(item)
     return out
 
@@ -247,12 +247,12 @@ def _interp_jaxpr(jaxpr, consts, args, hits):
     occurrence per equation — scan iterations share one). Sub-jaxprs
     are only interpreted when a pallas_call hides inside; everything
     else runs as one compiled bind."""
-    import jax
+    from jax.extend import core as jcore
 
     env = {}
 
     def read(v):
-        return v.val if isinstance(v, jax.core.Literal) else env[v]
+        return v.val if isinstance(v, jcore.Literal) else env[v]
 
     for var, val in zip(jaxpr.constvars, consts):
         env[var] = val
@@ -389,8 +389,11 @@ def extract_pallas_calls(fn, args=None):
 # ---------------------------------------------------------------------------
 
 def _block_dims(block_shape):
-    """Block shape with Pallas's squeezed-dim sentinel mapped to 1."""
-    return tuple(int(d) if isinstance(d, (int, np.integer)) else 1
+    """Block shape as plain ints: ``Blocked(n)``/``Element(n)`` dims
+    (and bare ints) give ``n``, Pallas's squeezed dim gives 1."""
+    return tuple(int(getattr(d, "block_size", d))
+                 if isinstance(getattr(d, "block_size", d),
+                               (int, np.integer)) else 1
                  for d in block_shape)
 
 
@@ -452,10 +455,10 @@ class _Block:
 
 
 def _block_of(bm):
-    sd = bm.array_shape_dtype
+    aval = bm.array_aval
     return _Block(block_shape=tuple(bm.block_shape),
-                  array_shape=tuple(int(d) for d in sd.shape),
-                  dtype=str(np.dtype(sd.dtype)),
+                  array_shape=tuple(int(d) for d in aval.shape),
+                  dtype=str(np.dtype(aval.dtype)),
                   index_map=getattr(bm, "index_map_jaxpr", None))
 
 
@@ -466,11 +469,13 @@ def _eval_index_map(index_map, grid, scalar_vals, rank):
     discharged to plain array reads fed with the captured values."""
     import jax
     import jax.numpy as jnp
+    from jax.extend import core as jcore
+    # no public spelling exists for state discharge (jax 0.9.0)
     from jax._src.state import discharge as state_discharge
 
     discharged, dconsts = state_discharge.discharge_state(
         index_map.jaxpr, index_map.consts)
-    f = jax.core.jaxpr_as_fun(jax.core.ClosedJaxpr(discharged, dconsts))
+    f = jcore.jaxpr_as_fun(jcore.ClosedJaxpr(discharged, dconsts))
     n = int(np.prod(grid))
     idx = np.unravel_index(np.arange(n), grid)   # C order = last fastest
 
@@ -515,8 +520,9 @@ def kernel_facts(eqn, invals=None, grid_point_cap=DEFAULT_GRID_POINT_CAP):
     n_scalars = int(getattr(gm, "num_index_operands", 0))
     n_in = int(gm.num_inputs)
     n_out = int(gm.num_outputs)
-    name = getattr(eqn.params.get("name_and_src_info"), "name", None) \
-        or "pallas_kernel"
+    name = eqn.params.get("name") or getattr(
+        getattr(eqn.params["jaxpr"], "debug_info", None),
+        "func_name", None) or "pallas_kernel"
     scalar_vals = None
     if invals is not None:
         scalar_vals = [np.asarray(v) for v in invals[:n_scalars]]

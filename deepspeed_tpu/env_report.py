@@ -32,20 +32,9 @@ def op_report(out=sys.stdout):
 
 
 def debug_report(out=sys.stdout):
-    import os
     import jax
     import jaxlib
     import deepspeed_tpu
-    # Some environments register extra PJRT plugins at interpreter startup
-    # in a way that ignores the JAX_PLATFORMS env var; re-assert it through
-    # the config so `ds_tpu_report` can be pointed at a platform (e.g.
-    # JAX_PLATFORMS=cpu) without initializing unreachable backends.
-    plat = os.environ.get("JAX_PLATFORMS")
-    if plat:
-        try:
-            jax.config.update("jax_platforms", plat)
-        except Exception:
-            pass
     print("-" * 64, file=out)
     print("environment", file=out)
     print("-" * 64, file=out)

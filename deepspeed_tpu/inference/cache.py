@@ -9,8 +9,11 @@ admission/eviction never changes a compiled shape, which is what keeps
 the decode loop at exactly one compile (`engine.compile_counts`).
 
 The **paged** layout (``KVCacheSpec.page_size > 0``) replaces the
-per-row ring with one pool of fixed-size pages per layer,
-``[n_pages, page_size, n_head, head_dim]``, addressed through per-row
+per-row ring with one pool of fixed-size pages per layer, head-major
+``[n_pages, n_head, page_size, head_dim]`` (so the flash kernel cuts a
+``[block_k, head_dim]`` KV block straight out of it — a position-major
+pool would need a block whose second-minor dim is 1, which the TPU
+block rule refuses), addressed through per-row
 page tables (``[B, pages_per_row]`` int32) that enter the compiled
 programs as plain data. The pool shape and the table shape are both
 static, so page allocation, freeing, prefix sharing and host-tier
@@ -117,7 +120,7 @@ def spec_for_model(cfg, max_batch, max_seq, kv_cache_dtype=None,
 
 def _layer_leaves(spec):
     if spec.paged:
-        shape = (spec.n_pages, spec.page_size, spec.n_head,
+        shape = (spec.n_pages, spec.n_head, spec.page_size,
                  spec.head_dim)
     else:
         shape = (spec.max_batch, spec.max_seq, spec.n_head,
@@ -169,16 +172,16 @@ def kv_partition_specs(spec, model_axis="model"):
     (`models/gpt2.py:gpt2_partition_specs`): each TP shard holds the
     heads it computes, so decode attention runs collective-free and the
     row-parallel ``c_proj`` psum GSPMD inserts is the only combine.
-    The ring row axis and the paged pool's page axis sit in the same
-    slot (axis 0 / axis 1 stacked), so one spec covers both layouts."""
+    The ring keeps heads on axis 2 (``[B, S, H, D]``), the head-major
+    paged pool on axis 1 (``[n_pages, H, page_size, D]``)."""
     from jax.sharding import PartitionSpec as P
     lead = (None,) if spec.stacked else ()
     # no trailing None after the sharded head axis: jit keys compiled
     # programs on the exact sharding object, and GSPMD canonicalizes
     # output specs without trailing Nones — a trailing-None input spec
     # would mismatch the pinned output and recompile on the 2nd call.
-    payload = P(*lead, None, None, model_axis)
-    scale = P(*lead, None, None, model_axis)
+    before_head = (None,) if spec.paged else (None, None)
+    payload = scale = P(*lead, *before_head, model_axis)
 
     def per_layer():
         leaves = {"k": payload, "v": payload}
@@ -278,11 +281,11 @@ def attention_mask(layer_cache, positions, page_table=None):
     callers running several layers per step (`models/gpt2.py`) can
     compute it ONCE and pass it down — rebuilt per layer it is the
     compiled decode program's only per-layer iota. With a paged cache
-    the buffer no longer carries the sequence length (``shape[-3]`` is
+    the buffer no longer carries the sequence length (``shape[-2]`` is
     ``page_size``); ``S`` is ``pages_per_row * page_size`` off the page
     table instead — the mask itself is layout-independent."""
     if page_table is not None:
-        S = page_table.shape[-1] * layer_cache["k"].shape[-3]
+        S = page_table.shape[-1] * layer_cache["k"].shape[-2]
     else:
         S = layer_cache["k"].shape[-3]
     return jnp.arange(S)[None, None, :] <= positions[:, :, None]
@@ -294,8 +297,9 @@ def attention_mask(layer_cache, positions, page_table=None):
 
 def paged_write_kv(layer_cache, k_new, v_new, positions, page_table):
     """Write one chunk's keys/values into the page pool through a
-    page table. ``layer_cache`` holds ``[n_pages, page_size, H, D]``
-    pool leaves; ``page_table`` is ``[B, pages_per_row]`` int32 of
+    page table. ``layer_cache`` holds head-major ``[n_pages, H,
+    page_size, D]`` pool leaves (scales ``[n_pages, H, page_size]``);
+    ``page_table`` is ``[B, pages_per_row]`` int32 of
     physical page ids (0 = trash for unallocated slots); positions are
     contiguous per row as in :func:`write_kv`. Two shapes exist:
 
@@ -319,32 +323,36 @@ def paged_write_kv(layer_cache, k_new, v_new, positions, page_table):
     the flash kernel's fused dequant carry over unchanged.
     """
     codec = _codec_of(layer_cache)
-    page_size = layer_cache["k"].shape[-3]
+    page_size = layer_cache["k"].shape[-2]
     B, T = positions.shape
     start = positions[:, 0]
 
+    # ``vals`` arrive position-major ([B, T, H, D] payloads, [B, T, H]
+    # scales); the pool's (page, slot) pair brackets the head axis, so
+    # the advanced indices sit either side of a full ``:`` slice (numpy
+    # then puts the indexed dims first — the layout ``vals`` has).
     if T == 1:
         pp = jnp.take_along_axis(
             page_table, (start // page_size)[:, None], axis=1)[:, 0]
         off = start % page_size
 
         def scatter(buf, vals):
-            return buf.at[pp, off].set(vals[:, 0].astype(buf.dtype))
+            return buf.at[pp, :, off].set(vals[:, 0].astype(buf.dtype))
     elif B == 1:
         pp = page_table[0, start[0] // page_size]
         off = start[0] % page_size
 
         def scatter(buf, vals):
-            idx = (pp, off) + (0,) * (buf.ndim - 2)
+            idx = (pp, 0, off) + (0,) * (buf.ndim - 3)
             return jax.lax.dynamic_update_slice(
-                buf, vals.astype(buf.dtype), idx)
+                buf, jnp.swapaxes(vals, 1, 2).astype(buf.dtype), idx)
     else:
         pages = jnp.take_along_axis(
             page_table, positions // page_size, axis=1)     # [B, T]
         offs = positions % page_size
 
         def scatter(buf, vals):
-            return buf.at[pages, offs].set(vals.astype(buf.dtype))
+            return buf.at[pages, :, offs].set(vals.astype(buf.dtype))
 
     if codec is None:
         return {"k": scatter(layer_cache["k"], k_new),
@@ -368,7 +376,8 @@ def paged_read_kv(layer_cache, page_table, dtype):
     codec = _codec_of(layer_cache)
 
     def gather(buf):
-        g = jnp.take(buf, page_table, axis=0)   # [B, n_pt, ps, ...]
+        g = jnp.take(buf, page_table, axis=0)   # [B, n_pt, H, ps, ...]
+        g = jnp.swapaxes(g, 2, 3)               # [B, n_pt, ps, H, ...]
         B, n_pt, ps = g.shape[:3]
         return g.reshape((B, n_pt * ps) + g.shape[3:])
 
@@ -400,15 +409,14 @@ def _flash_attend(q, layer_cache, positions, block_k, mesh):
         return flash_decode(q, layer_cache["k"], layer_cache["v"], pos,
                             *scales, block_k=block_k)
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     head = P(None, None, "model", None)
     in_specs = (head, head, head, P(None)) + \
         ((P(None, None, "model"),) * 2 if scales else ())
-    sharded = shard_map(
+    sharded = jax.shard_map(
         lambda q_, k_, v_, p_, *s_: flash_decode(q_, k_, v_, p_, *s_,
                                                  block_k=block_k),
-        mesh=mesh, in_specs=in_specs, out_specs=head, check_rep=False)
+        mesh=mesh, in_specs=in_specs, out_specs=head, check_vma=False)
     return sharded(q, layer_cache["k"], layer_cache["v"], pos, *scales)
 
 
@@ -416,10 +424,11 @@ def _flash_attend_paged(q, layer_cache, positions, page_table, block_k,
                         mesh):
     """Paged twin of :func:`_flash_attend`: the kernel gathers KV
     blocks straight out of the pool through the scalar-prefetched page
-    table (`ops/pallas/flash_decode.py:flash_decode_paged`) — no
-    pool-sized gather/copy ever materializes. The pool's head axis
-    shards exactly like the ring's, so the TP ``shard_map`` only swaps
-    in the replicated page-table spec."""
+    table (`ops/pallas/flash_decode.py:flash_decode_paged`) — this
+    code gathers and transposes nothing (the relayouts XLA adds around
+    the call on the chip are in `PERF.md`, PR 21). Under TP the head-major
+    pool shards on axis 1 (`kv_partition_specs`); the query and the
+    output keep the model's ``[B, 1, H, D]`` layout."""
     from deepspeed_tpu.ops.pallas import flash_decode_paged
 
     pos = positions[:, 0]
@@ -432,15 +441,15 @@ def _flash_attend_paged(q, layer_cache, positions, page_table, block_k,
                                   pos, page_table, *scales,
                                   block_k=block_k)
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     head = P(None, None, "model", None)
-    in_specs = (head, head, head, P(None), P(None, None)) + \
-        ((P(None, None, "model"),) * 2 if scales else ())
-    sharded = shard_map(
+    pool = P(None, "model", None, None)
+    in_specs = (head, pool, pool, P(None), P(None, None)) + \
+        ((P(None, "model", None),) * 2 if scales else ())
+    sharded = jax.shard_map(
         lambda q_, k_, v_, p_, t_, *s_: flash_decode_paged(
             q_, k_, v_, p_, t_, *s_, block_k=block_k),
-        mesh=mesh, in_specs=in_specs, out_specs=head, check_rep=False)
+        mesh=mesh, in_specs=in_specs, out_specs=head, check_vma=False)
     return sharded(q, layer_cache["k"], layer_cache["v"], pos,
                    page_table, *scales)
 

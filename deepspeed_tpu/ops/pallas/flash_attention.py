@@ -21,12 +21,15 @@ Implementations:
 path (or pallas in interpreter mode when explicitly requested).
 """
 
+import contextlib
+import contextvars
 import functools
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec
 
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 # Additive form of a hard key mask (added to scores, so it must stay well
@@ -723,6 +726,81 @@ def _flash_pallas_bwd(causal, sm_scale, block_q, block_k, dropout_rate,
 _flash_pallas.defvjp(_flash_pallas_fwd, _flash_pallas_bwd)
 
 
+# How q/k/v lie on the mesh of the program being traced, as whoever traces
+# it said through :func:`placed_on_mesh`: (mesh, rows axis, heads axis).
+_PLACEMENT = contextvars.ContextVar("flash_attention_placement",
+                                    default=None)
+
+
+@contextlib.contextmanager
+def placed_on_mesh(mesh, rows, heads):
+    """Trace-time scope: the Pallas attention calls traced inside run
+    once per shard of ``mesh``, batch rows split over the axis named
+    ``rows`` and heads over the axis named ``heads``.
+
+    GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot be
+    automatically partitioned"), so a program jitted over a multi-device
+    mesh has to say where the kernel's operands lie; the kernel then
+    wraps itself in ``shard_map`` over that mesh. The caller that owns
+    the mesh (the training engine, around its loss function) names the
+    axes — this module knows none."""
+    token = _PLACEMENT.set((mesh, rows, heads))
+    try:
+        yield
+    finally:
+        _PLACEMENT.reset(token)
+
+
+def _flash_pallas_on_mesh(q, k, v, key_bias, dropout_seed,
+                          dropout_head_offset, causal, sm_scale, block_q,
+                          block_k, dropout_rate, dropout_num_heads,
+                          interpret):
+    """:func:`_flash_pallas`, placed as :func:`placed_on_mesh` says.
+
+    Attention is independent per (row, head), so each shard runs the
+    plain kernel on its block; the dropout mask hashes GLOBAL (row,
+    head) coordinates (``dropout_head_offset``), so its bits match the
+    unsharded call. With no placement, or inside a ``shard_map`` that
+    already made the mesh's axes manual, this is the bare kernel call.
+    """
+    def kernel(q, k, v, key_bias, seed, offset, num_heads):
+        return _flash_pallas(q, k, v, key_bias, seed, offset, causal,
+                             sm_scale, block_q, block_k, dropout_rate,
+                             num_heads, interpret)
+
+    placement = _PLACEMENT.get()
+    if placement is None or jax.sharding.get_abstract_mesh().manual_axes:
+        return kernel(q, k, v, key_bias, dropout_seed,
+                      dropout_head_offset, dropout_num_heads)
+
+    mesh, rows, heads = placement
+    B, _, H, _ = q.shape
+    for axis, extent, what in ((rows, B, "batch rows"), (heads, H, "heads")):
+        if extent % mesh.shape[axis]:
+            raise ValueError(
+                f"flash_attention: {extent} {what} do not divide over "
+                f"the {mesh.shape[axis]} devices of mesh axis {axis!r}")
+    qkv = PartitionSpec(rows, None, heads, None)
+    # the dropout mask hashes the GLOBAL folded index b * Hg + h: a
+    # shard adds where its rows and heads start (both terms are linear)
+    Hg = H if dropout_num_heads is None else dropout_num_heads
+
+    def local(q, k, v, key_bias, seed, offset):
+        offset += jax.lax.axis_index(rows) * (q.shape[0] * Hg)
+        offset += jax.lax.axis_index(heads) * q.shape[2]
+        return kernel(q, k, v, key_bias, seed, offset, Hg)
+
+    return jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(qkv, qkv, qkv,
+                  None if key_bias is None else PartitionSpec(rows, None),
+                  None if dropout_seed is None else PartitionSpec(),
+                  PartitionSpec()),
+        out_specs=qkv, check_vma=False,
+    )(q, k, v, key_bias, dropout_seed,
+      jnp.asarray(dropout_head_offset, jnp.int32))
+
+
 def flash_attention(q, k, v, causal=True, sm_scale=None,
                     block_q=512, block_k=512, implementation="auto",
                     key_padding_mask=None, key_bias=None,
@@ -790,12 +868,21 @@ def flash_attention(q, k, v, causal=True, sm_scale=None,
         T = q.shape[1]
         bq = min(block_q, T)
         bk = min(block_k, k.shape[1])
-        # Fall back when shapes don't tile cleanly.
         if T % bq != 0 or k.shape[1] % bk != 0:
+            if on_tpu:
+                # the caller asked for the kernel (or "auto" chose it):
+                # never hand back another implementation in its name
+                raise ValueError(
+                    f"flash_attention: q length {T} / kv length "
+                    f"{k.shape[1]} do not tile by blocks ({bq}, {bk}); "
+                    f"pick block_q/block_k that divide them, or ask for "
+                    f"implementation='xla'")
+            # off-TPU "pallas" is the interpret-mode parity path of the
+            # CPU tests, where odd toy shapes use the blockwise oracle
             return _blockwise_attention(q, k, v, causal, sm_scale,
                                         key_bias=bias, **drop_kw)
-        return _flash_pallas(q, k, v, bias, dropout_seed,
-                             dropout_head_offset, causal, sm_scale,
-                             bq, bk, float(dropout_rate),
-                             dropout_num_heads, not on_tpu)
+        return _flash_pallas_on_mesh(
+            q, k, v, bias, dropout_seed, dropout_head_offset, causal,
+            sm_scale, bq, bk, float(dropout_rate), dropout_num_heads,
+            not on_tpu)
     raise ValueError(f"unknown implementation {implementation!r}")

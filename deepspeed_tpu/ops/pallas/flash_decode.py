@@ -38,9 +38,21 @@ FlashDecoding-style fix, specialized for the ring buffer:
   ``(page, intra-page block)``. The clamp runs BEFORE the lookup, so
   the map only ever dereferences table entries the row has actually
   filled — dead and unallocated pages never cost a DMA, the paged
-  generalization of the ring kernel's block skipping. KV blocks are
-  cut directly from the 4-D pool (``[1, block_k, 1, D]``), so no
-  pool-sized transpose copy materializes either.
+  generalization of the ring kernel's block skipping. The pool is
+  HEAD-MAJOR (``[n_pages, H, page_size, D]``): with (page, head) merged
+  into one leading dim it IS the ring kernel's folded layout, so a KV
+  block is cut from it as ``[1, block_k, D]`` — last two dims obey the
+  TPU (8, 128) block rule — and this file transposes nothing. (What
+  XLA does around the call is another matter: at D = 64 the TPU keeps
+  the pool positions-minor in HBM and re-lays it out for the kernel —
+  `PERF.md`, PR 21.)
+
+Both kernels compile for the chip (`tests/unit/test_tpu_compile.py`
+pins that against a described v5e). The online-softmax running max/sum
+live in ``(1, 1)`` VMEM scratch and are only ever read and written
+whole, as vectors — Mosaic refuses scalar stores to VMEM. Quantized
+scales enter lane-major (``[..., 1, block_k]`` rows), the layout the
+``[1, block_k]`` score row multiplies without a relayout.
 
 Off-TPU the kernel runs in Pallas interpret mode (CPU test meshes);
 the dense cached-attention path stays available as the parity oracle
@@ -60,6 +72,7 @@ DEFAULT_BLOCK_K = 128
 # a compiled block whose second-minor dim doesn't tile to this pads to
 # full register tiles on every touch.
 _SUBLANE_TILES = {4: 8, 2: 16, 1: 32}
+_LANES = 128
 
 
 class KernelGeometryError(ValueError):
@@ -74,7 +87,8 @@ class KernelGeometryError(ValueError):
     """
 
 
-def _validate_block_k(block_k, extent, extent_name, kv_dtype, interpret):
+def _validate_block_k(block_k, extent, extent_name, kv_dtype, interpret,
+                      quant=False):
     """Clamp and validate ``block_k`` against the KV extent it tiles.
 
     ``extent`` is ``max_seq`` for the ring layout and ``page_size``
@@ -82,7 +96,8 @@ def _validate_block_k(block_k, extent, extent_name, kv_dtype, interpret):
     sublane-tile check only gates the COMPILED path (``interpret``
     False, i.e. a real TPU lowering where Mosaic's tiling constraints
     bite on sub-tile quantized blocks); interpret-mode CPU runs accept
-    any divisor so CI toys stay small.
+    any divisor so CI toys stay small. ``quant`` adds the lane rule for
+    the scale rows of a codec cache.
     """
     block_k = int(block_k)
     if block_k < 1:
@@ -101,7 +116,24 @@ def _validate_block_k(block_k, extent, extent_name, kv_dtype, interpret):
             f"compiled kernel would pad every KV block to full "
             f"register tiles; pick a multiple of {tile} (or cover the "
             f"whole {extent_name})")
+    if not interpret and quant and block_k % _LANES and block_k != extent:
+        raise KernelGeometryError(
+            f"attention block_k {block_k} over a quantized cache must "
+            f"be a multiple of {_LANES} (or cover the whole "
+            f"{extent_name} {extent}): the scales stream lane-major, "
+            f"one [1, block_k] row per KV block")
     return block_k
+
+
+def check_decode_geometry(block_k, extent, extent_name, kv_dtype, quant):
+    """The call-time block validation, for the device this process
+    compiles for — so the serving engine refuses, typed, a geometry the
+    chip's compiler would refuse when it is BUILT, not at the first
+    decode step (and never by serving through another path). Returns
+    the clamped ``block_k``."""
+    interpret = jax.devices()[0].platform != "tpu"
+    return _validate_block_k(block_k, extent, extent_name, kv_dtype,
+                             interpret, quant)
 
 
 def _fold_heads(x):
@@ -115,12 +147,12 @@ def _flash_decode_kernel(H, D, block_k, n_kb, quant, paged=False):
 
     Scalar-prefetch arg 0 is the ``[B]`` positions vector (SMEM);
     scratch carries the online-softmax state (acc [1, D], running max
-    and sum [1, 1]) across the sequential kv-block dim. The paged
-    variant carries the page tables as a second scalar-prefetch arg —
-    consumed ONLY by the index maps (the body's math is identical; a
-    KV block is a KV block wherever it was fetched from), except that
-    paged blocks arrive in pool layout ``(1, bk, 1, D)`` instead of
-    the folded ``(1, bk, D)``.
+    and sum [1, 1]) across the sequential kv-block dim — all three are
+    read and written whole, as vectors. The paged variant carries the
+    page tables as a second scalar-prefetch arg — consumed ONLY by the
+    index maps: the body's math is identical, a KV block is a KV block
+    wherever it was fetched from, and both layouts hand it over as
+    ``(1, bk, D)`` with its scales as a ``(1, 1, bk)`` row.
     """
 
     def kernel(pos_ref, *all_refs):
@@ -151,33 +183,31 @@ def _flash_decode_kernel(H, D, block_k, n_kb, quant, paged=False):
         @pl.when(run)
         def _compute():
             qb = q_ref[0].astype(jnp.float32)              # [1, D]
-            kb = (k_ref[0, :, 0, :] if paged
-                  else k_ref[0]).astype(jnp.float32)       # [bk, D]
+            kb = k_ref[0].astype(jnp.float32)              # [bk, D]
             s = jax.lax.dot_general(
                 qb, kb, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)        # [1, bk]
             if quant:
                 # fused dequant: scale the SCORES by the key scales
                 # (dot distributes over the per-position scalar) —
-                # the kb block itself stays in storage dtype.
-                ks = ks_ref[0, :, 0] if paged else ks_ref[0][:, 0]
-                s = s * ks[None, :]
+                # the kb block itself stays in storage dtype. The scale
+                # row is [1, bk] f32, lane-major like the scores.
+                s = s * ks_ref[0]
             s = s * (D ** -0.5)
             k_pos = ki * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (1, block_k), 1)
             s = jnp.where(k_pos <= p, s, DEFAULT_MASK_VALUE)
-            m_prev = m_ref[0, 0]
-            m_new = jnp.maximum(m_prev, s.max())
+            m_prev = m_ref[:]                              # [1, 1]
+            m_new = jnp.maximum(m_prev,
+                                s.max(axis=-1, keepdims=True))
             pr = jnp.exp(s - m_new)
             corr = jnp.exp(m_prev - m_new)
-            l_ref[0, 0] = l_ref[0, 0] * corr + pr.sum()
-            m_ref[0, 0] = m_new
+            l_ref[:] = l_ref[:] * corr + pr.sum(axis=-1, keepdims=True)
+            m_ref[:] = m_new
             if quant:
                 # value scales fold into the probs the same way
-                vs = vs_ref[0, :, 0] if paged else vs_ref[0][:, 0]
-                pr = pr * vs[None, :]
-            vb = (v_ref[0, :, 0, :] if paged
-                  else v_ref[0]).astype(jnp.float32)       # [bk, D]
+                pr = pr * vs_ref[0]
+            vb = v_ref[0].astype(jnp.float32)              # [bk, D]
             acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
                 pr, vb, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
@@ -185,9 +215,15 @@ def _flash_decode_kernel(H, D, block_k, n_kb, quant, paged=False):
         @pl.when(ki == n_kb - 1)
         def _finish():
             o_ref[0] = (acc_ref[:] /
-                        jnp.maximum(l_ref[0, 0], 1e-30)).astype(o_ref.dtype)
+                        jnp.maximum(l_ref[:], 1e-30)).astype(o_ref.dtype)
 
     return kernel
+
+
+def _softmax_scratch(D):
+    return [pltpu.VMEM((1, D), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32)]
 
 
 def flash_decode(q, k, v, positions, k_scale=None, v_scale=None,
@@ -215,10 +251,11 @@ def flash_decode(q, k, v, positions, k_scale=None, v_scale=None,
             f"{q.shape} != {(B, 1, H, D)}")
     if interpret is None:
         interpret = jax.devices()[0].platform != "tpu"
-    block_k = _validate_block_k(block_k, S, "max_seq", k.dtype, interpret)
     if (k_scale is None) != (v_scale is None):
         raise ValueError("pass both k_scale and v_scale or neither")
     quant = k_scale is not None
+    block_k = _validate_block_k(block_k, S, "max_seq", k.dtype, interpret,
+                                quant)
     n_kb = S // block_k
 
     qh = q.transpose(0, 2, 1, 3).reshape(B * H, 1, D)
@@ -228,11 +265,17 @@ def flash_decode(q, k, v, positions, k_scale=None, v_scale=None,
     def q_map(bh, ki, pos_ref):
         return (bh, 0, 0)
 
-    def kv_map(bh, ki, pos_ref):
+    def _active(bh, ki, pos_ref):
         # Clamp past-occupancy block indices to the row's last active
         # block: consecutive grid steps then request the SAME block and
         # Pallas elides the DMA — the skipped blocks cost no HBM reads.
-        return (bh, jnp.minimum(ki, pos_ref[bh // H] // block_k), 0)
+        return jnp.minimum(ki, pos_ref[bh // H] // block_k)
+
+    def kv_map(bh, ki, pos_ref):
+        return (bh, _active(bh, ki, pos_ref), 0)
+
+    def sc_map(bh, ki, pos_ref):
+        return (bh, 0, _active(bh, ki, pos_ref))
 
     in_specs = [
         pl.BlockSpec((1, 1, D), q_map),
@@ -241,21 +284,17 @@ def flash_decode(q, k, v, positions, k_scale=None, v_scale=None,
     ]
     args = [qh, kh, vh]
     if quant:
-        in_specs += [pl.BlockSpec((1, block_k, 1), kv_map),
-                     pl.BlockSpec((1, block_k, 1), kv_map)]
-        args += [k_scale.transpose(0, 2, 1).reshape(B * H, S, 1),
-                 v_scale.transpose(0, 2, 1).reshape(B * H, S, 1)]
+        in_specs += [pl.BlockSpec((1, 1, block_k), sc_map),
+                     pl.BlockSpec((1, 1, block_k), sc_map)]
+        args += [k_scale.transpose(0, 2, 1).reshape(B * H, 1, S),
+                 v_scale.transpose(0, 2, 1).reshape(B * H, 1, S)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B * H, n_kb),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, D), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((1, D), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-        ],
+        scratch_shapes=_softmax_scratch(D),
     )
     out = pl.pallas_call(
         _flash_decode_kernel(H, D, block_k, n_kb, quant),
@@ -272,8 +311,8 @@ def flash_decode_paged(q, k, v, positions, page_tables, k_scale=None,
     """Split-K flash decode over a paged KV pool.
 
     ``q``: ``[B, 1, H, D]`` as in :func:`flash_decode`. ``k``/``v``:
-    the POOL buffers ``[n_pages, page_size, H, D]`` in storage dtype
-    (scales ``[n_pages, page_size, H]`` when quantized —
+    the head-major POOL buffers ``[n_pages, H, page_size, D]`` in
+    storage dtype (scales ``[n_pages, H, page_size]`` when quantized —
     `inference/cache.py` paged layout). ``page_tables``: ``[B,
     pages_per_row]`` int32 physical page ids per row (entry 0 = the
     trash page for unallocated slots). ``positions``: ``[B]`` int32
@@ -288,7 +327,7 @@ def flash_decode_paged(q, k, v, positions, page_tables, k_scale=None,
     never straddles a page boundary, which is what keeps the gather a
     single block index per grid step.
     """
-    n_pages, page_size, H, D = k.shape
+    n_pages, H, page_size, D = k.shape
     B = q.shape[0]
     if q.shape != (B, 1, H, D):
         raise ValueError(
@@ -301,11 +340,11 @@ def flash_decode_paged(q, k, v, positions, page_tables, k_scale=None,
     S = n_pt * page_size
     if interpret is None:
         interpret = jax.devices()[0].platform != "tpu"
-    block_k = _validate_block_k(block_k, page_size, "page_size",
-                                k.dtype, interpret)
     if (k_scale is None) != (v_scale is None):
         raise ValueError("pass both k_scale and v_scale or neither")
     quant = k_scale is not None
+    block_k = _validate_block_k(block_k, page_size, "page_size",
+                                k.dtype, interpret, quant)
     n_kb = S // block_k
     bpp = page_size // block_k          # kv-blocks per page
 
@@ -320,35 +359,38 @@ def flash_decode_paged(q, k, v, positions, page_tables, k_scale=None,
         kc = jnp.minimum(ki, pos_ref[bh // H] // block_k)
         return pt_ref[bh // H, kc // bpp], kc % bpp
 
+    # the head-major pool with (page, head) merged into one leading dim
+    # is the ring kernel's folded layout: the same blocks, found through
+    # the page table. K and V keep their last two extents, so the merge
+    # moves no byte; a scale pool becomes one [1, page_size] row per
+    # (page, head), which XLA re-tiles (1/16 of the int8 pool's bytes)
     def kv_map(bh, ki, pos_ref, pt_ref):
         page, intra = _physical(bh, ki, pos_ref, pt_ref)
-        return (page, intra, bh % H, 0)
+        return (page * H + bh % H, intra, 0)
 
     def sc_map(bh, ki, pos_ref, pt_ref):
         page, intra = _physical(bh, ki, pos_ref, pt_ref)
-        return (page, intra, bh % H)
+        return (page * H + bh % H, 0, intra)
 
     in_specs = [
         pl.BlockSpec((1, 1, D), q_map),
-        pl.BlockSpec((1, block_k, 1, D), kv_map),
-        pl.BlockSpec((1, block_k, 1, D), kv_map),
+        pl.BlockSpec((1, block_k, D), kv_map),
+        pl.BlockSpec((1, block_k, D), kv_map),
     ]
-    args = [qh, k, v]
+    args = [qh, k.reshape(n_pages * H, page_size, D),
+            v.reshape(n_pages * H, page_size, D)]
     if quant:
-        in_specs += [pl.BlockSpec((1, block_k, 1), sc_map),
-                     pl.BlockSpec((1, block_k, 1), sc_map)]
-        args += [k_scale, v_scale]
+        in_specs += [pl.BlockSpec((1, 1, block_k), sc_map),
+                     pl.BlockSpec((1, 1, block_k), sc_map)]
+        args += [k_scale.reshape(n_pages * H, 1, page_size),
+                 v_scale.reshape(n_pages * H, 1, page_size)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B * H, n_kb),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, D), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((1, D), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-        ],
+        scratch_shapes=_softmax_scratch(D),
     )
     out = pl.pallas_call(
         _flash_decode_kernel(H, D, block_k, n_kb, quant, paged=True),

@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from deepspeed_tpu.utils.compat import shard_map
+from jax import shard_map
 
 from deepspeed_tpu.ops.pallas.flash_attention import (
     DEFAULT_MASK_VALUE,

@@ -171,10 +171,8 @@ def _partition_constraint(tree, axis="model"):
     ``partition_activations`` capability (reference 369-397) as a GSPMD
     sharding constraint. Outside a mesh context this is a no-op."""
     from jax.sharding import PartitionSpec as P
-    from deepspeed_tpu.utils.compat import get_abstract_mesh
-    mesh = get_abstract_mesh()
-    if mesh is None or not mesh.shape or axis not in mesh.shape \
-            or mesh.shape[axis] == 1:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or axis not in mesh.shape or mesh.shape[axis] == 1:
         return tree
 
     def constrain(x):

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.parallel.collectives import (
     gather_from_chunk_servers, scatter_to_chunk_servers)
-from deepspeed_tpu.utils.compat import axis_size
+from jax.lax import axis_size
 
 __all__ = ["pack_signs", "unpack_signs", "compressed_allreduce",
            "error_feedback_sizes"]

@@ -43,7 +43,7 @@ import jax.numpy as jnp
 from deepspeed_tpu.parallel.collectives import (
     gather_from_chunk_servers, scatter_to_chunk_servers)
 from deepspeed_tpu.runtime.comm.codecs import decode_chunks, encode_chunks
-from deepspeed_tpu.utils.compat import axis_size
+from jax.lax import axis_size
 
 __all__ = [
     "quantize_chunks", "dequantize_chunks", "quantized_allreduce",

@@ -26,7 +26,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.utils.compat import axis_size
+from jax.lax import axis_size
 
 __all__ = ["CSRTensor", "csr_allreduce", "embedding_grad_csr",
            "dense_to_csr"]

@@ -19,6 +19,7 @@ global batch).
 
 import collections
 import contextlib
+import functools
 import os
 import json
 import signal
@@ -82,7 +83,7 @@ from deepspeed_tpu.telemetry import (
     set_default_session)
 from deepspeed_tpu.telemetry.timers import (
     SynchronizedWallClockTimer, ThroughputTimer)
-from deepspeed_tpu.utils.compat import shard_map
+from jax import shard_map
 from deepspeed_tpu.utils.logging import log_dist, logger
 
 MEMORY_OPT_ALLREDUCE_SIZE = 500000000
@@ -332,6 +333,28 @@ def make_grad_accumulator(loss_fn, compute_dtype, accum, constrain=None,
     return accumulate
 
 
+def place_kernels_on_mesh(loss_fn, mesh):
+    """``loss_fn``, traced with its Pallas attention placed on ``mesh``.
+
+    GSPMD cannot partition a Mosaic kernel, so every program the engine
+    jits over more than one device — the train steps, ``eval_batch``,
+    ``forward``/``backward`` — has to tell the kernel how its operands
+    lie: the engine shards batch rows over ``data`` and tensor
+    parallelism shards heads over ``model``
+    (`ops/pallas/flash_attention.py:placed_on_mesh`). The steps that run
+    the loss inside their own ``shard_map`` are untouched: there the
+    axes are already manual and the kernel runs bare."""
+    if mesh.size == 1:
+        return loss_fn
+    from deepspeed_tpu.ops.pallas.flash_attention import placed_on_mesh
+
+    @functools.wraps(loss_fn)       # keeps direct_value_and_grad[_local]
+    def placed(*args, **kwargs):
+        with placed_on_mesh(mesh, rows="data", heads="model"):
+            return loss_fn(*args, **kwargs)
+    return placed
+
+
 class DeepSpeedEngine:
     """Training engine around a pure ``loss_fn(params, batch, rng)``."""
 
@@ -364,7 +387,6 @@ class DeepSpeedEngine:
             "loss_fn= directly or a model object exposing .loss_fn")
         assert params is not None, "initial params pytree required"
         self.module = model
-        self.loss_fn = loss_fn
 
         # --- config ------------------------------------------------------
         if config is None and config_params is not None:
@@ -392,23 +414,15 @@ class DeepSpeedEngine:
             raise
         self.mesh = mesh if mesh is not None else build_mesh(
             (config.get("mesh") if isinstance(config, dict) else None))
+        self.loss_fn = place_kernels_on_mesh(loss_fn, self.mesh)
         self.dp_world_size = self.mesh.shape["data"]
         self.mp_world_size = self.mesh.shape["model"]
         self._config = DeepSpeedConfig(config, world_size=self.dp_world_size)
         if self._config.compilation_cache_dir:
-            # before ANY engine jit (opt-state init compiles below)
-            jax.config.update("jax_compilation_cache_dir",
-                              self._config.compilation_cache_dir)
-            try:
-                # jax latches "no cache" at the process's FIRST compile
-                # (param init/mesh build typically precede the engine);
-                # reset so the next compile re-reads the dir.
-                from jax._src import compilation_cache as _jax_cc
-                _jax_cc.reset_cache()
-            except Exception:  # pragma: no cover - jax internals moved
-                pass
+            # before ANY engine jit (opt-state init compiles below);
+            # JAX_COMPILATION_CACHE_DIR, where set, wins over the config
             from deepspeed_tpu.telemetry import compile_cache
-            compile_cache.install()
+            compile_cache.configure(self._config.compilation_cache_dir)
 
         # --- precision policy -------------------------------------------
         if self._config.fp16_enabled:

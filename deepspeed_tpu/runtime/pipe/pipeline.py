@@ -49,7 +49,8 @@ from deepspeed_tpu.ops.fp8 import fp8_scope
 from deepspeed_tpu.parallel.collectives import (barrier_after,
                                                 log_collective_site,
                                                 manual_axes, overlap_scope)
-from deepspeed_tpu.utils.compat import axis_size, shard_map
+from jax import shard_map
+from jax.lax import axis_size
 from deepspeed_tpu.runtime.pipe.module import LayerSpec, TiedLayerSpec
 
 

@@ -27,7 +27,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.utils.compat import shard_map
+from jax import shard_map
 
 
 def zero_partition_spec(shape, base_spec, mesh, axis="data"):

@@ -45,7 +45,7 @@ from deepspeed_tpu.parallel.collectives import (
     log_collective_site,
     ring_all_gather,
 )
-from deepspeed_tpu.utils.compat import shard_map
+from jax import shard_map
 
 # The checkpoint_name tag on every gathered leaf; the remat policy
 # excludes exactly this name from the saved residuals.

@@ -5,7 +5,6 @@ reference does via CUDA RNG state capture."""
 
 import jax
 
-from deepspeed_tpu.utils.compat import set_mesh
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -155,7 +154,7 @@ def test_partition_activations_matches_unpartitioned():
     g_ref = jax.grad(lambda p: _mlp(p, x))(params)
 
     ck.configure(partition_activations=True)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         g = jax.jit(jax.grad(lambda p: ck.checkpoint(_mlp, p, x)))(params)
     jax.tree_util.tree_map(
         lambda a, b: np.testing.assert_allclose(np.asarray(a), np.asarray(b),

@@ -23,7 +23,7 @@ from deepspeed_tpu.analysis.hlo import (
     split_computations,
     while_loops,
 )
-from deepspeed_tpu.utils.compat import shard_map
+from jax import shard_map
 
 SCAN_TRIPS = 6
 SCAN_WIDTH = 4

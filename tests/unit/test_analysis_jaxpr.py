@@ -43,7 +43,7 @@ from deepspeed_tpu.parallel.collectives import (
     record_collective_sites,
 )
 from deepspeed_tpu.runtime.pipe import pipeline as pl
-from deepspeed_tpu.utils.compat import shard_map
+from jax import shard_map
 
 
 def _mesh():

@@ -143,7 +143,7 @@ def test_dropped_donation_is_reported():
 
 def test_f32_all_reduce_in_bf16_run_is_reported():
     from jax.sharding import Mesh, PartitionSpec as P
-    from deepspeed_tpu.utils.compat import shard_map
+    from jax import shard_map
     mesh = Mesh(np.array(jax.devices()[:2]), ("d",))
     mapped = shard_map(lambda x: jax.lax.psum(x, "d"), mesh=mesh,
                        in_specs=(P("d"),), out_specs=P(None),
@@ -393,3 +393,42 @@ def test_unknown_rule_id_rejected_by_config():
                     "analysis": {"enabled": True, "rules": ["no_such"]}},
             loss_fn=lambda p, b, rng=None: jnp.sum(p["w"]),
             params=params)
+
+
+_M = 4 << 20        # fp32 master bytes of a 1,048,576-parameter model
+_REFRESH = "\n  %ag = f32[1048576]{0} all-gather(f32[262144]{0} %p)"
+
+
+@pytest.mark.parametrize("spelling,hlo,severities", [
+    # CPU: the whole fp32 gradient in one all-reduce (then a slice)
+    ("all-reduce",
+     "\n  %ar = f32[1048576]{0} all-reduce(f32[1048576]{0} %g)",
+     []),
+    # the op by its own name: each device keeps a 1/4 shard, in bf16
+    ("reduce-scatter",
+     "\n  %rs = bf16[262144]{0} reduce-scatter(bf16[1048576]{0} %g)",
+     []),
+    # v5e (PR 21's four-chip run): a ring of N-1 = 3 shard-sized bf16
+    # permutes and no op named reduce-scatter
+    ("permute-ring",
+     "".join(f"\n  %cp{i} = bf16[262144]{{0}} collective-permute("
+             f"bf16[262144]{{0}} %s{i})" for i in range(3)),
+     []),
+    ("missing", "", [SEV_WARNING]),
+    ("doubled-ring",
+     "".join(f"\n  %cp{i} = f32[262144]{{0}} collective-permute("
+             f"f32[262144]{{0}} %s{i})" for i in range(6)),
+     [SEV_ERROR]),
+])
+def test_zero2_gradient_exchange_in_every_spelling(spelling, hlo,
+                                                   severities):
+    """`zero_budget` holds a stage-2 step to ONE gradient exchange and
+    one refresh gather whatever XLA calls the exchange: the rule used
+    to add up all-reduce and reduce-scatter outputs only, so on the v5e
+    it saw 2.7 MB of a 590 MB exchange and warned that the sync was
+    missing."""
+    report = audit_hlo(hlo + _REFRESH, rules=["zero_budget"],
+                       zero_stage=2, param_bytes=_M, n_devices=4,
+                       compute_dtype="bf16")
+    assert [f.severity for f in report.findings] == severities, \
+        report.to_text()

@@ -23,7 +23,7 @@ from deepspeed_tpu.parallel.collectives import (
     _chunk_slices, manual_axes, matmul_psum_overlap, matmul_reduce_scatter,
     overlap_plan, overlap_scope, psum_combine, psum_grad, ring_psum)
 from deepspeed_tpu.parallel.mesh import build_mesh
-from deepspeed_tpu.utils.compat import shard_map
+from jax import shard_map
 
 N = 4                        # model-parallel degree for the fast tests
 B, T = 2, 3
@@ -277,7 +277,7 @@ def test_ring_psum_matches_psum(chunks, bidirectional):
 
     def make(fn):
         return _sharded(lambda xl: fn(xl), mesh,
-                        (P("model", None, None),), P(None, None, None))
+                        (P("model", None, None),), P(None, None))
 
     got = np.asarray(make(lambda xl: ring_psum(
         xl[0], "model", chunks=chunks, bidirectional=bidirectional))(x))
@@ -346,7 +346,7 @@ def test_ring_psum_wire_error_bounded(chunks, bidirectional, codec):
 
     def make(fn):
         return _sharded(lambda xl: fn(xl), mesh,
-                        (P("model", None, None),), P(None, None, None))
+                        (P("model", None, None),), P(None, None))
 
     got = np.asarray(make(lambda xl: ring_psum(
         xl[0], "model", chunks=chunks, bidirectional=bidirectional,

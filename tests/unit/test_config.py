@@ -212,22 +212,36 @@ def test_mesh_config():
     assert cfg.mesh_shape == {"data": 2, "model": 4}
 
 
-def test_compilation_cache_dir_config(tmp_path):
+@pytest.mark.parametrize("placed_from_outside", [False, True])
+def test_compilation_cache_dir_config(tmp_path, monkeypatch,
+                                      placed_from_outside):
+    """`compilation_cache_dir` turns the persistent cache on — but where
+    JAX_COMPILATION_CACHE_DIR places the cache from outside, no code
+    path sets another directory (`telemetry/compile_cache.configure`)."""
     import deepspeed_tpu
     import jax
     from tests.unit.simple_model import (base_config, simple_init_params,
                                          simple_loss_fn)
 
+    before = jax.config.jax_compilation_cache_dir
+    outside = str(tmp_path / "from_env")
+    if placed_from_outside:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     cache = str(tmp_path / "xla_cache")
     cfg = base_config(compilation_cache_dir=cache)
     params = simple_init_params(jax.random.PRNGKey(0))
     try:
         engine, _, _, _ = deepspeed_tpu.initialize(
             config=cfg, loss_fn=simple_loss_fn, params=params)
-        assert jax.config.jax_compilation_cache_dir == cache
+        if placed_from_outside:
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            assert jax.config.jax_compilation_cache_dir == cache
     finally:
         # restore the default so other tests are unaffected
-        jax.config.update("jax_compilation_cache_dir", None)
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 def test_hot_checkpoint_config():

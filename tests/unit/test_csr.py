@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from deepspeed_tpu.utils.compat import shard_map
+from jax import shard_map
 
 from deepspeed_tpu.runtime.csr_tensor import (
     CSRTensor, csr_allreduce, dense_to_csr, embedding_grad_csr)

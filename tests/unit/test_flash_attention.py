@@ -348,3 +348,43 @@ def test_dropout_head_offset_gradients_match_global_slice():
                 np.asarray(a)[:, :, lo:lo + 2],
                 np.asarray(b)[:, :, lo:lo + 2], rtol=1e-5, atol=1e-5)
             assert np.all(np.asarray(a)[:, :, :lo] == 0)
+
+
+@pytest.mark.parametrize("dropout", [0.0, 0.3])
+def test_pallas_under_mesh_shard_maps_itself(dropout):
+    """GSPMD cannot partition a Mosaic kernel, so inside `placed_on_mesh`
+    the pallas path wraps itself in shard_map over the axes the caller
+    named. Values, gradients and — through the global head offset —
+    dropout bits equal the call without a placement."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from deepspeed_tpu.ops.pallas.flash_attention import placed_on_mesh
+
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("dp", "tp"))
+    q, k, v = qkv(B=2, T=64, H=4)
+    kw = dict(causal=True, implementation="pallas", block_q=32,
+              block_k=32, dropout_rate=dropout,
+              dropout_seed=jnp.int32(5) if dropout else None)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, **kw) ** 2)
+
+    def on_mesh(q, k, v):
+        with placed_on_mesh(mesh, rows="dp", heads="tp"):
+            return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    placed = [jax.device_put(x, NamedSharding(mesh, P("dp")))
+              for x in (q, k, v)]
+    assert "shard_map" in str(jax.make_jaxpr(on_mesh)(*placed))
+    assert "shard_map" not in str(jax.make_jaxpr(loss)(q, k, v))
+    got, g_got = jax.jit(on_mesh)(*placed)
+    ref, g_ref = jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(float(got), float(ref), rtol=1e-5)
+    for a, b in zip(g_got, g_ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5)
+
+    # rows or heads that do not divide their axis are refused, never
+    # silently replicated
+    with placed_on_mesh(mesh, rows="dp", heads="tp"), \
+            pytest.raises(ValueError, match="do not divide"):
+        flash_attention(q[:, :, :3], k[:, :, :3], v[:, :, :3], **kw)

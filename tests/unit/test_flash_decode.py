@@ -103,7 +103,6 @@ def test_tp_shard_map_matches_unsharded(qkv):
     (the `cache.kv_partition_specs` layout) each kernel instance sees
     only local heads and the stitched result equals the unsharded
     call."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     q, k, v = qkv
@@ -111,13 +110,13 @@ def test_tp_shard_map_matches_unsharded(qkv):
     v_q, v_s = _quantize(v, "int8")
     mesh = Mesh(np.array(jax.devices()[:4]).reshape(4), ("model",))
     head = P(None, None, "model", None)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         lambda q_, k_, v_, p_, ks_, vs_: flash_decode(
             q_, k_, v_, p_, k_scale=ks_, v_scale=vs_, block_k=8),
         mesh=mesh,
         in_specs=(head, head, head, P(None),
                   P(None, None, "model"), P(None, None, "model")),
-        out_specs=head, check_rep=False)
+        out_specs=head, check_vma=False)
     out = sharded(q, k_q, v_q, POSITIONS, k_s, v_s)
     ref = flash_decode(q, k_q, v_q, POSITIONS, k_scale=k_s, v_scale=v_s,
                        block_k=8)

@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-from deepspeed_tpu.utils.compat import shard_map
+from jax import shard_map
 
 from deepspeed_tpu.runtime.comm.compressed import (
     compressed_allreduce, error_feedback_sizes, pack_signs, unpack_signs)

@@ -379,8 +379,9 @@ class TestPagedSpec:
                                                    spec_for_model)
         spec = spec_for_model(self._cfg(), 2, 32, "int8", page_size=8)
         cache = init_kv_cache(spec)
-        assert cache["h_0"]["k"].shape == (9, 8, 4, 8)
-        assert cache["h_0"]["k_scale"].shape == (9, 8, 4)
+        # head-major pool: [n_pages, n_head, page_size, head_dim]
+        assert cache["h_0"]["k"].shape == (9, 4, 8, 8)
+        assert cache["h_0"]["k_scale"].shape == (9, 4, 8)
 
 
 # ---------------------------------------------------------------------------

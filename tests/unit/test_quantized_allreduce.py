@@ -15,7 +15,7 @@ from deepspeed_tpu.runtime.comm.quantized import (
     quantized_allreduce, quantized_allreduce_sizes,
     quantized_allreduce_tree)
 from deepspeed_tpu.runtime.config import DeepSpeedConfig
-from deepspeed_tpu.utils.compat import shard_map
+from jax import shard_map
 
 WORLD = 8
 CHUNK = 64

@@ -1,0 +1,254 @@
+"""Every Pallas kernel on the main paths compiles for the chip.
+
+The TPU compiler is installed here without a TPU: it compiles for a
+chip that is *described* (`v5e:2x2`), which is what catches the faults
+interpret mode cannot see — block shapes that break the (8, 128) rule,
+scalar stores to VMEM, too much VMEM. Shapes are GPT-2 350M's (16 heads
+x 64) at the sizes `chip_smoke.py` runs. A compile that passes is not a
+chip run: nothing executes, so results and times are not covered here.
+
+The topology is described inside the `topo` fixture and nowhere else —
+only one process may load the TPU library, so no import, `skipif`,
+`parametrize` argument or conftest hook may touch it (xdist workers all
+import this file). All cases live in this one file for the same reason.
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+B, T, H, D = 8, 1024, 16, 64          # GPT-2 350M: 16 heads x 64
+PAGE = 128                            # chip_smoke.py's page size
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import (
+        compilation_cache as jax_cc)
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described-topology executable is written to the persistent
+    # cache but cannot be read back without a chip: keep it off
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax_cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    jax_cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """``chip(shape, dtype)`` -> an abstract array on the first chip."""
+    one = SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one)
+
+
+def compiled_text(fn, *args):
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
+def test_flash_attention_compiles(chip, grad):
+    # `flash_attention` picks interpret mode off the first device (the
+    # CPU here), so the test steers the kernel entry it wraps, with the
+    # public function's default blocks
+    from deepspeed_tpu.ops.pallas.flash_attention import _flash_pallas
+
+    def fwd(q, k, v):
+        return _flash_pallas(q, k, v, None, None, 0, True, D ** -0.5,
+                             512, 512, 0.0, None, False)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    x = chip((B, T, H, D), jnp.bfloat16)
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
+    assert "tpu_custom_call" in compiled_text(fn, x, x, x)
+
+
+# every storage dtype `inference.kv_cache_dtype` accepts: the model's
+# compute dtype (f32 for a served checkpoint, bf16 otherwise), the two
+# plain overrides, and the three codecs
+KV_DTYPES = ["float32", "bfloat16", "int8", "float8_e4m3fn", "float8_e5m2"]
+
+
+@pytest.mark.parametrize("kv_dtype", KV_DTYPES)
+@pytest.mark.parametrize("layout", ["ring", "paged"])
+def test_flash_decode_compiles(chip, layout, kv_dtype):
+    from deepspeed_tpu.ops.pallas.flash_decode import (
+        flash_decode, flash_decode_paged)
+
+    dt = jnp.dtype(kv_dtype)
+    quant = dt.itemsize == 1
+    # a served checkpoint computes in f32 (q and the cache both); the
+    # other storage dtypes sit under a bf16 model
+    q = chip((B, 1, H, D), dt if kv_dtype == "float32" else jnp.bfloat16)
+    pos = chip((B,), jnp.int32)
+    if layout == "ring":
+        kv = chip((B, T, H, D), dt)
+        scales = (chip((B, T, H), jnp.float32),) * 2 if quant else ()
+
+        def fn(q, k, v, pos, *s):
+            return flash_decode(q, k, v, pos, *s, interpret=False)
+        args = (q, kv, kv, pos) + scales
+    else:
+        n_pages = B * (T // PAGE) + 1
+        kv = chip((n_pages, H, PAGE, D), dt)
+        tables = chip((B, T // PAGE), jnp.int32)
+        scales = (chip((n_pages, H, PAGE), jnp.float32),) * 2 \
+            if quant else ()
+
+        def fn(q, k, v, pos, pt, *s):
+            return flash_decode_paged(q, k, v, pos, pt, *s,
+                                      interpret=False)
+        args = (q, kv, kv, pos, tables) + scales
+    assert "tpu_custom_call" in compiled_text(fn, *args)
+
+
+@pytest.mark.parametrize("shape", [(50257, 1024), (1024, 4096), (1024,)],
+                         ids=str)
+def test_pallas_adam_leaf_compiles(chip, shape):
+    from deepspeed_tpu.ops.pallas.fused_adam import _leaf_update
+
+    leaf = chip(shape, jnp.float32)
+    scalars = chip((8,), jnp.float32)
+    text = compiled_text(
+        lambda p, g, m, v, s: _leaf_update(p, g, m, v, s,
+                                           interpret=False),
+        leaf, leaf, leaf, leaf, scalars)
+    assert "tpu_custom_call" in text
+
+
+def test_block_sparse_attention_compiles(chip):
+    from deepspeed_tpu.ops.sparse_attention.block_sparse_attention import (
+        block_sparse_attention)
+
+    block = 64
+    nb = T // block
+    # causal band: each query block sees itself and the three before it
+    band = np.tril(np.ones((nb, nb), np.int64)) - \
+        np.tril(np.ones((nb, nb), np.int64), -4)
+    layout = np.broadcast_to(band, (H, nb, nb))
+
+    def loss(q, k, v):
+        return block_sparse_attention(
+            q, k, v, layout, block, causal=True,
+            implementation="pallas",
+            interpret=False).astype(jnp.float32).sum()
+
+    x = chip((B, T, H, D), jnp.bfloat16)
+    text = compiled_text(jax.grad(loss, argnums=(0, 1, 2)), x, x, x)
+    assert "tpu_custom_call" in text
+
+
+class _AnswersTpu:
+    """Stands in for `jax` inside one kernel module: the kernels ask
+    `jax.devices()` whether to interpret, and here that is the CPU."""
+
+    def __getattr__(self, name):
+        return getattr(jax, name)
+
+    @staticmethod
+    def devices(*_):
+        return [type("Dev", (), {"platform": "tpu"})]
+
+
+def _compiled_not_interpreted(monkeypatch, module_name):
+    import importlib
+    monkeypatch.setattr(importlib.import_module(module_name), "jax",
+                        _AnswersTpu())
+
+
+@pytest.mark.parametrize("program", ["eval_batch", "train_step"])
+def test_engine_loss_over_data_mesh_compiles(topo, monkeypatch, program):
+    """GSPMD cannot partition a Mosaic kernel, so every program the
+    engine jits over a `data=4` mesh must carry the flash kernels inside
+    a `shard_map`: `DeepSpeedEngine.__init__` wraps its loss function
+    once (`place_kernels_on_mesh`) and `eval_batch`, `forward`/`backward`
+    and the train steps all trace that. An engine cannot be built on
+    described devices (it places real arrays), so this compiles what
+    those programs trace — the wrapped loss of a 1-layer GPT-2 at 350M
+    width, batch rows over `data`, forward-only as `eval_batch` jits it
+    and differentiated as the train step does. Interpret mode inlines
+    the kernel, so no CPU test can see this fault."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from deepspeed_tpu.models.gpt2 import (
+        GPT2Config, GPT2LMHead, make_gpt2_loss_fn)
+    from deepspeed_tpu.parallel.mesh import build_mesh
+    from deepspeed_tpu.runtime.engine import place_kernels_on_mesh
+
+    _compiled_not_interpreted(monkeypatch,
+                              "deepspeed_tpu.ops.pallas.flash_attention")
+    mesh = build_mesh({"data": 4}, devices=topo.devices)
+    model = GPT2LMHead(GPT2Config(
+        vocab_size=50257, n_positions=T, n_embd=H * D, n_layer=1,
+        n_head=H, use_flash_attention=True))
+    loss_fn = place_kernels_on_mesh(make_gpt2_loss_fn(model), mesh)
+
+    shapes = jax.eval_shape(
+        lambda: model.init({"params": jax.random.PRNGKey(0)},
+                           jnp.zeros((2, T), jnp.int32))["params"])
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=NamedSharding(mesh, P())), shapes)
+    batch = {"input_ids": jax.ShapeDtypeStruct(
+        (B, T), jnp.int32, sharding=NamedSharding(mesh, P("data")))}
+
+    def eval_step(params, batch):       # `DeepSpeedEngine.eval_batch`
+        cast = jax.tree_util.tree_map(
+            lambda x: x.astype(jnp.bfloat16), params)
+        return loss_fn(cast, batch, None)
+
+    fn = eval_step if program == "eval_batch" else jax.grad(eval_step)
+    assert "tpu_custom_call" in compiled_text(fn, params, batch)
+
+
+@pytest.mark.parametrize("layout", ["ring", "paged"])
+def test_tp_sharded_flash_decode_compiles(topo, monkeypatch, layout):
+    """The serving engine's TP path: `inference/cache.py` runs the decode
+    kernel under `shard_map` over the `model` axis (4 chips, 4 heads
+    each)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from deepspeed_tpu.inference import cache
+
+    _compiled_not_interpreted(monkeypatch,
+                              "deepspeed_tpu.ops.pallas.flash_decode")
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("model",))
+
+    def on(shape, dtype, *spec):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, P(*spec)))
+
+    q = on((B, 1, H, D), jnp.bfloat16, None, None, "model")
+    positions = on((B, 1), jnp.int32)
+    if layout == "ring":
+        kv = on((B, T, H, D), jnp.bfloat16, None, None, "model")
+
+        def fn(q, k, v, positions):
+            return cache._flash_attend(q, {"k": k, "v": v}, positions,
+                                       128, mesh)
+        args = (q, kv, kv, positions)
+    else:
+        kv = on((B * (T // PAGE) + 1, H, PAGE, D), jnp.bfloat16,
+                None, "model")
+        tables = on((B, T // PAGE), jnp.int32)
+
+        def fn(q, k, v, positions, tables):
+            return cache._flash_attend_paged(
+                q, {"k": k, "v": v}, positions, tables, 128, mesh)
+        args = (q, kv, kv, positions, tables)
+    assert "tpu_custom_call" in compiled_text(fn, *args)
