@@ -9,8 +9,10 @@ of
 
 - recent telemetry **events** (it plugs into the session's exporter
   fan-out, so it sees exactly what the JSONL log sees),
-- **phase-span transitions** (enter/exit of every ``session.span``
-  scope, fed by `telemetry/spans.py`), and
+- **phase-span transitions** (enter/exit of every span scope: read at
+  dump time from the process-wide span ring and the live span stacks
+  of `telemetry/spans.py`; the recorder keeps no phase log of its
+  own), and
 - the compiled step's **collective confessions**
   (`parallel/collectives.py:SiteRecord` — which sites emitted which
   rings, captured at trace time),
@@ -43,7 +45,7 @@ import time
 import traceback
 import collections
 
-from deepspeed_tpu.telemetry.spans import live_phase_paths
+from deepspeed_tpu.telemetry import spans
 from deepspeed_tpu.utils.logging import logger
 
 FLIGHT_SCHEMA = "ds-tpu-flight/1"
@@ -71,15 +73,17 @@ class FlightRecorder:
 
     Implements the exporter protocol (``export``/``close``) so a
     :class:`~deepspeed_tpu.telemetry.events.EventLog` fans events into
-    the ring exactly like any other exporter; phase transitions and
-    collective confessions arrive through the session hooks.
+    the ring exactly like any other exporter; collective confessions
+    arrive through the session hook; phase transitions are the span
+    ring's (closed since this recorder was made, at most ``history``).
     """
 
     def __init__(self, dump_dir, history=512, meta=None):
         self.dump_dir = str(dump_dir)
         self.meta = dict(meta or {})
-        self._events = collections.deque(maxlen=int(history))
-        self._phases = collections.deque(maxlen=int(history))
+        self.history = int(history)
+        self._events = collections.deque(maxlen=self.history)
+        self._since = spans.clock()
         self._collectives = []
         self._lock = threading.Lock()
         self._dumps = 0
@@ -94,12 +98,28 @@ class FlightRecorder:
 
     # -- session hooks -------------------------------------------------
     def record_phase(self, kind, path, duration_s=None):
-        """One span transition: ``kind`` is ``"enter"`` or ``"exit"``."""
-        rec = {"t": time.time(), "kind": kind, "path": path}
-        if duration_s is not None:
-            rec["duration_s"] = round(duration_s, 6)
-        with self._lock:
-            self._phases.append(rec)
+        """A span transition reported by hand (a ``Span`` needs no such
+        call: it lands in the ring by itself). Only an ``"exit"`` with
+        its duration is a closed span; an ``"enter"`` alone is not
+        kept."""
+        if kind == "exit" and duration_s is not None:
+            t1 = spans.clock()
+            spans.record(path, t1 - duration_s, t1)
+
+    def _phase_log(self):
+        """Enter/exit records, oldest first, from the ring's closed
+        spans and the live stacks' open ones, on Unix-epoch seconds."""
+        off = spans.epoch_offset()
+        log = []
+        for path, t0, t1, _ in spans.recent(self._since)[-self.history:]:
+            log.append({"t": t0 + off, "kind": "enter", "path": path})
+            log.append({"t": t1 + off, "kind": "exit", "path": path,
+                        "duration_s": round(t1 - t0, 6)})
+        for stack in spans.live_spans().values():
+            log.extend({"t": t0 + off, "kind": "enter", "path": path}
+                       for path, t0 in stack if t0 is not None)
+        log.sort(key=lambda rec: rec["t"])
+        return log[-self.history:]
 
     def record_collectives(self, records):
         """Stamp the step's trace-time :class:`SiteRecord` confessions
@@ -121,11 +141,11 @@ class FlightRecorder:
         """The dump payload as a dict (no I/O)."""
         with self._lock:
             events = list(self._events)
-            phases = list(self._phases)
             collectives = list(self._collectives)
+        phases = self._phase_log()
         names = {t.ident: t.name for t in threading.enumerate()}
         in_flight = {names.get(ident, f"thread-{ident}"): path
-                     for ident, path in live_phase_paths().items()}
+                     for ident, path in spans.live_phase_paths().items()}
         snap = {
             "schema": FLIGHT_SCHEMA,
             "reason": reason,

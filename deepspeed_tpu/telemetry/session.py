@@ -11,8 +11,8 @@ step's event, so nested/repeated spans within a step sum correctly.
 
 The session is also the forensics hub: when the config enables them it
 owns a :class:`~deepspeed_tpu.telemetry.flight.FlightRecorder` (which
-rides the exporter fan-out and additionally receives every span
-transition) and a
+rides the exporter fan-out and reads span transitions from the span
+ring when it dumps) and a
 :class:`~deepspeed_tpu.telemetry.watchdog.HangWatchdog` (which every
 span entry/exit feeds as a heartbeat).
 """
@@ -95,15 +95,13 @@ class TelemetrySession:
                    history=tcfg.history, flight=flight, watchdog=watchdog)
 
     # -- spans ---------------------------------------------------------
-    def span(self, name):
-        return Span(name, self)
+    def span(self, name, attrs=None):
+        return Span(name, self, attrs)
 
     def _enter_phase(self, name, path):
         wd = self.watchdog
         if wd is not None:
             wd.beat(path)
-        if self.flight is not None:
-            self.flight.record_phase("enter", path)
 
     def _record_phase(self, name, path, duration_s):
         self._phases[name] = self._phases.get(name, 0.0) + duration_s
@@ -113,8 +111,6 @@ class TelemetrySession:
         wd = self.watchdog
         if wd is not None:
             wd.beat(path)
-        if self.flight is not None:
-            self.flight.record_phase("exit", path, duration_s)
 
     def drain_phases(self):
         """Per-phase seconds accumulated since the last drain (one step's
