@@ -43,7 +43,6 @@ import collections
 import json
 import os
 import threading
-import time
 
 import numpy as np
 
@@ -55,6 +54,7 @@ from deepspeed_tpu.inference.paging import (
 from deepspeed_tpu.inference.scheduler import ContinuousBatchingScheduler
 from deepspeed_tpu.runtime.resilience import fault_injection
 from deepspeed_tpu.runtime.resilience.checkpoint import _leaf_checksums
+from deepspeed_tpu.telemetry.spans import clock
 
 META_FIELDS = ("rid", "prompt_len", "first_token", "next_pos",
                "page_size", "pages_per_row", "n_pages", "parked")
@@ -288,7 +288,7 @@ class PrefillWorker:
             raise ValueError(
                 f"request {request.rid}: max_new_tokens must be >= 1")
         if request.submit_t is None:
-            request.submit_t = time.monotonic()
+            request.submit_t = clock()
         self.queue.append(request)
 
     def drain_outputs(self):
@@ -330,14 +330,14 @@ class PrefillWorker:
             # a typed completion beats an admission spin.
             self._complete(req, [], "incomplete", row=None)
             return bool(self.queue)
-        t0 = time.perf_counter()
+        t0 = clock()
         last_logits = self.engine.prefill(
             0, req.prompt,
             page_table=row.table(self.paging.pages_per_row),
             start=row.start)
         self.paging.after_prefill(row, req.prompt)
         first = self.engine.sample_first(last_logits)
-        wall = time.perf_counter() - t0
+        wall = clock() - t0
         self.steps += 1
         self.prefills += 1
         self._emit(req, row, wall)

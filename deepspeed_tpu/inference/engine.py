@@ -53,6 +53,7 @@ from deepspeed_tpu.inference.cache import (
     spec_for_model,
     update_rows,
 )
+from deepspeed_tpu.telemetry.spans import Span, enclosing_attr
 
 DEFAULT_MAX_BATCH = 8
 DEFAULT_SEQ_BUCKETS = (128, 512)
@@ -81,7 +82,11 @@ class InferenceEngine:
     (`runtime/config.py:InferenceConfig`) or a plain dict with the same
     keys; ``session`` an optional
     :class:`~deepspeed_tpu.telemetry.session.TelemetrySession` the
-    scheduler emits ``decode_step`` events through.
+    scheduler emits ``decode_step`` events through. :meth:`prefill` and
+    :meth:`decode` open their own spans (``prefill``; ``decode`` with
+    ``upload``, ``dispatch``, ``wait_tokens``, ``logits_d2h``), which
+    land in the process-wide span ring with or without a session and
+    nest under the scheduler's ``serve/step``.
     """
 
     def __init__(self, model, params, config=None, mesh=None,
@@ -390,22 +395,29 @@ class InferenceEngine:
                 f"(chunk={chunk})")
         from deepspeed_tpu.runtime.resilience import fault_injection
         last = None
-        for ci in range(start // chunk, padded // chunk):
-            # disagg soak seam: an armed prefill_chunk kill dies HERE,
-            # mid-prompt, with pages allocated and partially written.
-            fault_injection.maybe_kill("prefill_chunk", ci)
-            tc = jnp.asarray(toks[:, ci * chunk:(ci + 1) * chunk])
-            pc = jnp.arange(ci * chunk, (ci + 1) * chunk,
-                            dtype=jnp.int32)[None, :]
-            if paged:
-                logits, self.cache = self._prefill(
-                    self.params, self.cache, tc, pc, pt)
-            else:
-                logits, self.cache = self._prefill(
-                    self.params, self.cache, tc, pc,
-                    jnp.asarray(slot, jnp.int32))
-            if ci == last_chunk:
-                last = np.asarray(logits[0, (n - 1) % chunk])
+        # one span for the whole prompt; ``rid`` is the enclosing
+        # (scheduler's ``admit``) span's, so the call takes no new
+        # argument
+        attrs = {"rid": enclosing_attr("rid"),
+                 "chunks": (padded - start) // chunk}
+        with Span("prefill", self.session, attrs):
+            for ci in range(start // chunk, padded // chunk):
+                # disagg soak seam: an armed prefill_chunk kill dies
+                # HERE, mid-prompt, with pages allocated and partially
+                # written.
+                fault_injection.maybe_kill("prefill_chunk", ci)
+                tc = jnp.asarray(toks[:, ci * chunk:(ci + 1) * chunk])
+                pc = jnp.arange(ci * chunk, (ci + 1) * chunk,
+                                dtype=jnp.int32)[None, :]
+                if paged:
+                    logits, self.cache = self._prefill(
+                        self.params, self.cache, tc, pc, pt)
+                else:
+                    logits, self.cache = self._prefill(
+                        self.params, self.cache, tc, pc,
+                        jnp.asarray(slot, jnp.int32))
+                if ci == last_chunk:
+                    last = np.asarray(logits[0, (n - 1) % chunk])
         return last
 
     def decode(self, tokens, positions, page_tables=None):
@@ -422,18 +434,29 @@ class InferenceEngine:
             raise RuntimeError(
                 "prefill-tier engine: the decode program is pinned off "
                 "— decode belongs to the decode tier")
-        t = jnp.asarray(np.asarray(tokens, np.int32))
-        p = jnp.asarray(np.asarray(positions, np.int32))
-        if self.kv_layout == "paged":
-            if page_tables is None:
-                raise ValueError("paged decode requires page_tables")
-            pt = jnp.asarray(np.asarray(page_tables, np.int32))
-            nxt, logits, self._sample_key, self.cache = self._decode(
-                self.params, self.cache, t, p, pt, self._sample_key)
-        else:
-            nxt, logits, self._sample_key, self.cache = self._decode(
-                self.params, self.cache, t, p, self._sample_key)
-        return np.asarray(nxt), np.asarray(logits)
+        paged = self.kv_layout == "paged"
+        if paged and page_tables is None:
+            raise ValueError("paged decode requires page_tables")
+        session = self.session
+        # four spans, so that a gap on the device can be laid to the
+        # part of the call the host was in: the uploads, the dispatch,
+        # the wait for the tokens (the device's own time), the logits'
+        # copy to the host
+        with Span("decode", session):
+            with Span("upload", session):
+                args = [jnp.asarray(np.asarray(tokens, np.int32)),
+                        jnp.asarray(np.asarray(positions, np.int32))]
+                if paged:
+                    args.append(
+                        jnp.asarray(np.asarray(page_tables, np.int32)))
+            with Span("dispatch", session):
+                nxt, logits, self._sample_key, self.cache = self._decode(
+                    self.params, self.cache, *args, self._sample_key)
+            with Span("wait_tokens", session):
+                nxt = np.asarray(nxt)
+            with Span("logits_d2h", session):
+                logits = np.asarray(logits)
+        return nxt, logits
 
     # -- host-RAM page tier (paged layout only) -----------------------------
 

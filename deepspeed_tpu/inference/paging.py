@@ -320,6 +320,11 @@ class PagedCacheManager:
         self.host_store = HostPageStore()
         self.sessions: Dict[str, _ParkedSession] = {}
         self._clock = 0
+        # pages in live rows' tables (a page two rows share counts
+        # twice, as in the tables the decode step is handed): kept here,
+        # where rows take and return pages, so the scheduler's
+        # ``serve/step`` span reads it without walking its slots
+        self.pages_live = 0
         self.sessions_admitted = 0
         self.sessions_parked = 0
         self.sessions_resumed = 0
@@ -356,6 +361,7 @@ class PagedCacheManager:
             "n_pages": self.engine.n_pages,
             "pages_free": self.allocator.free_pages,
             "pages_resident": self.allocator.resident_pages,
+            "pages_live": self.pages_live,
             "page_bytes": self.page_bytes(),
             "prefix_hits": self.prefix_hits,
             "prefix_misses": self.prefix_misses,
@@ -508,6 +514,7 @@ class PagedCacheManager:
         pages.extend(fresh)
 
         self.sessions_admitted += 1
+        self.pages_live += len(pages)
         if resumed:
             self.sessions_resumed += 1
         padded_chunks = -(-n // chunk)
@@ -516,6 +523,13 @@ class PagedCacheManager:
             resumed=resumed,
             prefill_chunks=padded_chunks - start // chunk,
             prefill_chunks_skipped=start // chunk)
+
+    def adopt(self, row):
+        """Count a row whose pages were taken from the allocator
+        directly (the disaggregated decode tier installs a handoff's
+        pages itself) among the live rows; :meth:`release` uncounts
+        it."""
+        self.pages_live += len(row.pages)
 
     def after_prefill(self, row, prompt):
         """Intern the freshly prefilled prompt's full pages so later
@@ -536,6 +550,7 @@ class PagedCacheManager:
         if page is None:
             return False
         row.pages.append(page)
+        self.pages_live += 1
         return True
 
     def ensure_span(self, row, start, end):
@@ -561,6 +576,7 @@ class PagedCacheManager:
         a follow-up request on the session resumes without re-prefill;
         otherwise every reference drops back to the allocator."""
         self._clock += 1
+        self.pages_live -= len(row.pages)
         if session_id and kv_tokens:
             covered = min(len(kv_tokens),
                           len(row.pages) * self.page_size)

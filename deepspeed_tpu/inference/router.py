@@ -38,6 +38,8 @@ import dataclasses
 import time
 from typing import Dict, List, Optional
 
+from deepspeed_tpu.telemetry.spans import clock
+
 
 class RequestAbortedError(RuntimeError):
     """A request exhausted its redispatch budget: every attempt landed
@@ -120,7 +122,7 @@ class FleetRouter:
         self.timeouts = 0
         self._deferring = False
         self._recovering = {}   # index -> (t_detect, {rids not yet out})
-        self._submit_t = {}     # rid -> wall-clock submit (latency)
+        self._submit_t = {}     # rid -> submit on spans.clock (latency)
 
     # -- telemetry -----------------------------------------------------
 
@@ -141,7 +143,7 @@ class FleetRouter:
         """Admit one request, or shed it at the global pending bound."""
         if request.rid in self._submit_t:
             raise ValueError(f"duplicate rid {request.rid!r}")
-        self._submit_t[request.rid] = time.monotonic()
+        self._submit_t[request.rid] = clock()
         if request.submit_t is None:
             request.submit_t = self._submit_t[request.rid]
         if self.max_pending is not None and \
@@ -164,7 +166,7 @@ class FleetRouter:
         if request.rid in self.completed_rids:
             return
         self.completed_rids.add(request.rid)
-        now = time.monotonic()
+        now = clock()
         comp = {
             "rid": request.rid, "prompt_len": len(request.prompt),
             "tokens": list(tokens), "finish_reason": finish_reason,
@@ -342,10 +344,10 @@ class FleetRouter:
         timeouts, or fleet-level truncation)."""
         for r in requests:
             self.submit(r)
-        t0 = time.monotonic()
+        t0 = clock()
         while self.queue or any(self.assigned[r.index]
                                 for r in self._healthy()):
-            now = time.monotonic()
+            now = clock()
             self._collect()
             self._check_health(now)
             self._expire(now)
@@ -366,7 +368,7 @@ class FleetRouter:
                                redispatched=req.redispatched,
                                last_replica=None)
                 break
-            if time.monotonic() - t0 > timeout_s:
+            if clock() - t0 > timeout_s:
                 for rep in self._healthy():
                     for rid, req in list(
                             self.assigned[rep.index].items()):
@@ -494,8 +496,8 @@ class DisaggRouter(FleetRouter):
         self.resumed_from_park = 0
         self._metas = {}            # rid -> handoff meta dict (decode leg)
         self._extras = {}           # rid -> prefill-side completion fields
-        self._prefilled_t = {}      # rid -> monotonic handoff time
-        self._dispatch_t = {}       # rid -> monotonic prefill dispatch
+        self._prefilled_t = {}      # rid -> handoff time on spans.clock
+        self._dispatch_t = {}       # rid -> prefill dispatch, same clock
         self.ttft = {}              # rid -> seconds to first token
 
     # -- tier plumbing -------------------------------------------------
@@ -531,7 +533,7 @@ class DisaggRouter(FleetRouter):
     # -- collection ----------------------------------------------------
 
     def _collect(self):
-        now = time.monotonic()
+        now = clock()
         for rep in self.replicas:
             if rep.index in self.dead:
                 continue
@@ -733,10 +735,10 @@ class DisaggRouter(FleetRouter):
     def run(self, requests=(), timeout_s=120.0):
         for r in requests:
             self.submit(r)
-        t0 = time.monotonic()
+        t0 = clock()
         while self.queue or self.decode_queue or any(
                 self.assigned[r.index] for r in self._healthy()):
-            now = time.monotonic()
+            now = clock()
             self._collect()
             self._check_health(now)
             self._expire(now)
@@ -752,7 +754,7 @@ class DisaggRouter(FleetRouter):
                 self._abort_queue(self.queue, "fleet_dead")
                 self._abort_queue(self.decode_queue, "fleet_dead")
                 break
-            if time.monotonic() - t0 > timeout_s:
+            if clock() - t0 > timeout_s:
                 for rep in self._healthy():
                     for rid, req in list(
                             self.assigned[rep.index].items()):

@@ -17,8 +17,27 @@ without buying per-bucket XLA programs.
 
 Every decode step emits one ``decode_step`` telemetry event (tokens
 produced, live batch, occupancy, queue depth, host wall) through the
-session, feeding ``ds_tpu_metrics summary``'s serve mode and the
-registry's ``decode_*`` metric families.
+session, feeding ``ds_tpu_metrics summary``'s serve mode, and every
+completion one ``request_done`` event (queue wait, time to first token,
+latency).
+
+**Stamps and spans** (always on; `telemetry/spans.py` has the clock and
+the ring). Every request is stamped on ``telemetry.spans.clock``:
+``arrival_t`` (the caller's, else ``submit_t``), ``submit_t``,
+``admit_t`` (taken off the queue), ``first_token_t`` (``sample_first``
+has returned: the token exists on the host), ``first_return_t`` (the
+``step()`` that made it returns: the first moment a caller of
+``step()`` can read it), ``token_t`` (one per generated token) and
+``finish_t``; they ride on :class:`Completion` and, at finish, on one
+``serve/request`` record in the span ring. Every ``step()`` is one
+``serve/step`` span whose attrs carry that step's counters, read at the
+step's end (``live_rows``, ``batch``, ``max_batch``, ``queue_depth``,
+``tokens``; paged also ``pages_live``, ``pages_resident``,
+``pages_total``), with children ``expire``, ``admit`` (one per request
+taken up, attrs ``rid``; under it ``pages``, the engine's ``prefill``
+and ``sample``), ``grow``, ``inputs``, the engine's ``decode`` (under
+it ``upload``, ``dispatch``, ``wait_tokens``, ``logits_d2h``; a
+speculative engine has ``draft`` and ``verify`` instead) and ``book``.
 
 With a paged engine (``inference.kv_layout = "paged"``) the scheduler
 delegates page mapping to `inference/paging.py:PagedCacheManager`:
@@ -32,20 +51,24 @@ of it stays host-side: the compiled decode step just receives the
 
 import collections
 import dataclasses
-import time
 from typing import List, Optional
 
 import numpy as np
 
 from deepspeed_tpu.runtime.resilience import fault_injection
+from deepspeed_tpu.telemetry.spans import Span, clock, record
 
 
 @dataclasses.dataclass
 class Request:
-    """One generation request. ``arrival_step``>0 makes the stream
-    open-loop: the scheduler won't admit the request before its decode
-    step count reaches it (deterministic synthetic load for benches and
-    tests). ``session_id`` (paged engines) parks the request's KV pages
+    """One generation request. ``arrival_step``>0 holds the request
+    back until the scheduler's decode step count reaches it: a *test*
+    clock (deterministic synthetic streams for tests and smokes), not a
+    load model, since a slower server is then offered less load.
+    ``arrival_t`` is when the request reached the system on
+    ``telemetry.spans.clock``, where the caller knows it (a front end's
+    receive time, a load generator's due time); ``submit()`` defaults
+    it to ``submit_t``. ``session_id`` (paged engines) parks the request's KV pages
     at completion so a follow-up request on the same session resumes
     without re-prefilling its history.
 
@@ -54,8 +77,8 @@ class Request:
     bounds its wait for a cache row — either expiry finishes it with the
     typed ``timeout`` reason instead of letting it stall the stream.
     ``redispatched``/``restarts`` are stamped by the fleet router when a
-    replica death forces a re-prefill elsewhere; ``submit_t`` is the
-    monotonic clock at FIRST submit and survives redispatch, so the
+    replica death forces a re-prefill elsewhere; ``submit_t`` is
+    ``telemetry.spans.clock`` at FIRST submit and survives redispatch, so the
     deadline spans retries (exactly-once completion semantics over
     at-least-once execution)."""
     rid: str
@@ -69,6 +92,7 @@ class Request:
     redispatched: int = 0       # replica-death redispatches (router)
     restarts: int = 0           # total re-executions (router)
     submit_t: Optional[float] = None
+    arrival_t: Optional[float] = None
 
 
 @dataclasses.dataclass
@@ -87,6 +111,14 @@ class Completion:
     prefill_chunks_skipped: int = 0
     redispatched: int = 0       # times redispatched across replicas
     restarts: int = 0           # times its execution restarted
+    # stamps on telemetry.spans.clock (None: it never got that far)
+    arrival_t: Optional[float] = None
+    submit_t: Optional[float] = None
+    admit_t: Optional[float] = None         # taken off the queue
+    first_token_t: Optional[float] = None   # exists on the host
+    first_return_t: Optional[float] = None  # its step() returned
+    token_t: List[float] = dataclasses.field(default_factory=list)
+    finish_t: Optional[float] = None
 
 
 @dataclasses.dataclass
@@ -98,6 +130,14 @@ class _Slot:
     generated: List[int]
     admitted_step: int
     paging: object = None       # RowPaging when the engine is paged
+    admit_t: Optional[float] = None
+    first_return_t: Optional[float] = None
+    # one stamp per generated token; the first is the first token's
+    token_t: List[float] = dataclasses.field(default_factory=list)
+
+
+def _minus(a, b):
+    return None if a is None or b is None else a - b
 
 
 class ContinuousBatchingScheduler:
@@ -108,6 +148,10 @@ class ContinuousBatchingScheduler:
         self.slots = [None] * engine.max_batch
         self.step_count = 0
         self.completions = []
+        # completions made inside the step that admitted them: their
+        # first token is not out before that step returns
+        self._unreturned = []
+        self._step_attrs = None
         self.paging = None
         if getattr(engine, "kv_layout", "ring") == "paged":
             from deepspeed_tpu.inference.paging import PagedCacheManager
@@ -127,7 +171,9 @@ class ContinuousBatchingScheduler:
             raise ValueError(
                 f"request {request.rid}: max_new_tokens must be >= 1")
         if request.submit_t is None:    # survives redispatch resubmits
-            request.submit_t = time.monotonic()
+            request.submit_t = clock()
+        if request.arrival_t is None:
+            request.arrival_t = request.submit_t
         self.queue.append(request)
 
     def admit_prefilled(self, request, row, first_token):
@@ -142,13 +188,21 @@ class ContinuousBatchingScheduler:
         for i in range(len(self.slots)):
             if self.slots[i] is not None:
                 continue
+            # the token was made on another tier: it is here, and
+            # readable by the caller, as of now
+            now = clock()
             if request.submit_t is None:
-                request.submit_t = time.monotonic()
+                request.submit_t = now
+            if request.arrival_t is None:
+                request.arrival_t = request.submit_t
+            if row is not None and self.paging is not None:
+                self.paging.adopt(row)
             self.slots[i] = _Slot(
                 request=request, bucket=self._bucket_for(request),
                 next_pos=len(request.prompt), pending=first_token,
                 generated=[first_token],
-                admitted_step=self.step_count, paging=row)
+                admitted_step=self.step_count, paging=row,
+                admit_t=now, first_return_t=now, token_t=[now])
             # eos / single-token budgets can finish right here, exactly
             # where the colocated loop's post-admission check fires.
             self._check_finished(i)
@@ -169,7 +223,11 @@ class ContinuousBatchingScheduler:
             tokens=list(s.generated), finish_reason=reason, bucket=s.bucket,
             slot=i, steps=self.step_count - s.admitted_step,
             redispatched=s.request.redispatched,
-            restarts=s.request.restarts)
+            restarts=s.request.restarts,
+            arrival_t=s.request.arrival_t, submit_t=s.request.submit_t,
+            admit_t=s.admit_t, first_token_t=s.token_t[0],
+            first_return_t=s.first_return_t, token_t=s.token_t,
+            finish_t=clock())
         if s.paging is not None:
             comp.prefix_hit = s.paging.prefix_hit
             comp.resumed = s.paging.resumed
@@ -183,15 +241,58 @@ class ContinuousBatchingScheduler:
                                 session_id=s.request.session_id)
         self.completions.append(comp)
         self.slots[i] = None            # row back on the ring
+        if comp.first_return_t is None:
+            self._unreturned.append(comp)   # step() stamps and records
+        else:
+            self._record_request(comp)
 
     def _finish_unstarted(self, request, reason):
         """Record a completion for a request that never held a row
         (queued timeout / max_steps exhaustion)."""
-        self.completions.append(Completion(
+        comp = Completion(
             rid=request.rid, prompt_len=len(request.prompt), tokens=[],
             finish_reason=reason, bucket=self._bucket_for(request),
             slot=-1, steps=0, redispatched=request.redispatched,
-            restarts=request.restarts))
+            restarts=request.restarts, arrival_t=request.arrival_t,
+            submit_t=request.submit_t, finish_t=clock())
+        self.completions.append(comp)
+        self._record_request(comp)
+
+    def _record_request(self, comp):
+        """One ``serve/request`` record in the span ring (``token_t`` by
+        reference) and, with a session, one ``request_done`` event."""
+        record("serve/request", comp.arrival_t, comp.finish_t, {
+            "rid": comp.rid, "prompt_len": comp.prompt_len,
+            "finish_reason": comp.finish_reason,
+            "arrival_t": comp.arrival_t, "submit_t": comp.submit_t,
+            "admit_t": comp.admit_t, "first_token_t": comp.first_token_t,
+            "first_return_t": comp.first_return_t,
+            "token_t": comp.token_t, "finish_t": comp.finish_t})
+        if self.session is not None:
+            self.session.emit(
+                "request_done", rid=comp.rid,
+                finish_reason=comp.finish_reason,
+                prompt_len=comp.prompt_len, tokens=len(comp.tokens),
+                queue_wait_s=_minus(comp.admit_t, comp.submit_t),
+                ttft_s=_minus(comp.first_return_t, comp.arrival_t),
+                hold_s=_minus(comp.first_return_t, comp.first_token_t),
+                latency_s=_minus(comp.finish_t, comp.arrival_t),
+                token_gaps_s=[round(b - a, 6) for a, b in
+                              zip(comp.token_t[1:], comp.token_t[2:])])
+
+    def _stamp_returned(self):
+        """The last thing ``step()`` does: first tokens made in this
+        step can be read from now on."""
+        now = clock()
+        for s in self.slots:
+            if s is not None and s.first_return_t is None:
+                s.first_return_t = now
+        for comp in self._unreturned:
+            # finished inside the step that admitted it: the caller
+            # sees token and completion together, now
+            comp.first_return_t = comp.finish_t = now
+            self._record_request(comp)
+        self._unreturned.clear()
 
     def _check_finished(self, i):
         s = self.slots[i]
@@ -209,7 +310,7 @@ class ContinuousBatchingScheduler:
         timeout (or total deadline) drop WITHOUT ever taking a row, and
         live rows past their deadline finish with whatever they
         generated so far."""
-        now = time.monotonic()
+        now = clock()
 
         def _queued_expired(r):
             waited = now - r.submit_t if r.submit_t is not None else 0.0
@@ -245,31 +346,48 @@ class ContinuousBatchingScheduler:
                     self.queue[0].arrival_step > self.step_count:
                 break
             req = self.queue[0]
-            row = None
-            if self.paging is not None:
-                row = self.paging.admit(req.prompt,
-                                        session_id=req.session_id)
-                if row is None:
+            attrs = {"rid": req.rid}
+            with Span("admit", self.session, attrs):
+                if not self._admit_one(i, req):
                     # pool can't back the prompt right now even after
                     # the eviction ladder — leave the request queued
                     # and let running rows finish and free pages.
+                    attrs["admitted"] = False
                     break
-                self.queue.popleft()
-                last_logits = self.engine.prefill(
-                    i, req.prompt,
-                    page_table=row.table(self.paging.pages_per_row),
-                    start=row.start)
-                self.paging.after_prefill(row, req.prompt)
-            else:
-                self.queue.popleft()
-                last_logits = self.engine.prefill(i, req.prompt)
+
+    def _admit_one(self, i, req):
+        """Take ``req`` off the queue into row ``i``: pages, prefill,
+        first token. False (and the request stays queued) when the pool
+        cannot back its prompt."""
+        session = self.session
+        row = None
+        if self.paging is not None:
+            with Span("pages", session):
+                row = self.paging.admit(req.prompt,
+                                        session_id=req.session_id)
+            if row is None:
+                return False
+        self.queue.popleft()
+        admit_t = clock()
+        if row is not None:
+            last_logits = self.engine.prefill(
+                i, req.prompt,
+                page_table=row.table(self.paging.pages_per_row),
+                start=row.start)
+            self.paging.after_prefill(row, req.prompt)
+        else:
+            last_logits = self.engine.prefill(i, req.prompt)
+        with Span("sample", session):
             first = self.engine.sample_first(last_logits)
-            self.slots[i] = _Slot(
-                request=req, bucket=self._bucket_for(req),
-                next_pos=len(req.prompt), pending=first,
-                generated=[first], admitted_step=self.step_count,
-                paging=row)
-            self._check_finished(i)
+        self.slots[i] = _Slot(
+            request=req, bucket=self._bucket_for(req),
+            next_pos=len(req.prompt), pending=first,
+            generated=[first], admitted_step=self.step_count,
+            paging=row, admit_t=admit_t, token_t=[clock()])
+        if self._step_attrs is not None:
+            self._step_attrs["tokens"] += 1
+        self._check_finished(i)
+        return True
 
     # -- the decode loop ----------------------------------------------------
 
@@ -279,24 +397,31 @@ class ContinuousBatchingScheduler:
         be) work left. With a speculative engine the "step" is a whole
         draft/verify round and rows advance by a VARIABLE number of
         tokens (their accepted length) — see :meth:`_spec_step`."""
-        self._expire()
-        self._admit()
-        if getattr(self.engine, "speculative", None) is not None:
-            return self._spec_step(self.engine.speculative)
-        if self.paging is not None:
-            # grow each live row's page mapping to cover this step's
-            # write BEFORE building the tables; a row the pool can't
-            # grow even after the eviction ladder is length-finished
-            # (same truncation contract as a bucket edge).
-            for i, s in enumerate(self.slots):
-                if s is not None and \
-                        not self.paging.ensure_position(s.paging,
-                                                        s.next_pos):
-                    self._finish(i, "length")
-        active = [i for i, s in enumerate(self.slots) if s is not None]
-        if not active:
-            self.step_count += 1        # idle tick (open-loop gap)
-            return bool(self.queue)
+        attrs = self._step_attrs = {
+            "step": self.step_count, "max_batch": self.engine.max_batch,
+            "batch": 0, "tokens": 0}
+        try:
+            with Span("serve/step", self.session, attrs):
+                try:
+                    return self._step()
+                finally:
+                    # the step's counters, read where they are true:
+                    # at its end, as a caller polling after it would
+                    attrs["live_rows"] = sum(
+                        s is not None for s in self.slots)
+                    attrs["queue_depth"] = len(self.queue)
+                    if self.paging is not None:
+                        alloc = self.paging.allocator
+                        attrs["pages_live"] = self.paging.pages_live
+                        attrs["pages_resident"] = alloc.resident_pages
+                        attrs["pages_total"] = alloc.n_pages - 1
+        finally:
+            self._step_attrs = None
+            self._stamp_returned()
+
+    def _inputs(self, active):
+        """The decode step's numpy inputs: pending token and position
+        of every live row, and with a pool the page tables."""
         mb = self.engine.max_batch
         tokens = np.zeros(mb, np.int32)
         positions = np.zeros(mb, np.int32)
@@ -310,26 +435,56 @@ class ContinuousBatchingScheduler:
             for i in active:
                 page_tables[i] = self.slots[i].paging.table(
                     self.paging.pages_per_row)
+        return tokens, positions, page_tables
+
+    def _step(self):
+        session = self.session
+        with Span("expire", session):
+            self._expire()
+        self._admit()
+        if getattr(self.engine, "speculative", None) is not None:
+            return self._spec_step(self.engine.speculative)
+        if self.paging is not None:
+            # grow each live row's page mapping to cover this step's
+            # write BEFORE building the tables; a row the pool can't
+            # grow even after the eviction ladder is length-finished
+            # (same truncation contract as a bucket edge).
+            with Span("grow", session):
+                for i, s in enumerate(self.slots):
+                    if s is not None and \
+                            not self.paging.ensure_position(s.paging,
+                                                            s.next_pos):
+                        self._finish(i, "length")
+        active = [i for i, s in enumerate(self.slots) if s is not None]
+        if not active:
+            self.step_count += 1        # idle tick (open-loop gap)
+            return bool(self.queue)
+        with Span("inputs", session):
+            tokens, positions, page_tables = self._inputs(active)
         # fault-injection seams: a hard kill (SIGKILL — the process just
         # dies with admitted sessions' KV un-drained) and the soft
         # decode exception, both no-ops unless a harness armed them.
         fault_injection.maybe_kill("decode_step", self.step_count)
         fault_injection.maybe_fail_decode(self.step_count)
-        t0 = time.perf_counter()
+        t_in = clock()
         if page_tables is None:
             next_tokens, _ = self.engine.decode(tokens, positions)
         else:
             next_tokens, _ = self.engine.decode(tokens, positions,
                                                 page_tables=page_tables)
-        wall = time.perf_counter() - t0
+        t_tokens = clock()      # the host has this step's tokens
         self.step_count += 1
-        for i in active:
-            s = self.slots[i]
-            s.next_pos += 1
-            s.pending = int(next_tokens[i])
-            s.generated.append(s.pending)
-            self._check_finished(i)
-        self._emit(len(active), wall)
+        with Span("book", session):
+            for i in active:
+                s = self.slots[i]
+                s.next_pos += 1
+                s.pending = int(next_tokens[i])
+                s.generated.append(s.pending)
+                s.token_t.append(t_tokens)
+                self._check_finished(i)
+            self._step_attrs["batch"] = len(active)
+            self._step_attrs["tokens"] += len(active)
+            self._emit(len(active), t_tokens - t_in)
         return bool(self.queue) or any(s is not None for s in self.slots)
 
     def _spec_step(self, spec):
@@ -354,27 +509,19 @@ class ContinuousBatchingScheduler:
                     s.next_pos + k + 1 > self.engine.max_seq:
                 self._finish(i, "length")
         if self.paging is not None:
-            for i, s in enumerate(self.slots):
-                if s is not None and not self.paging.ensure_span(
-                        s.paging, s.next_pos, s.next_pos + j):
-                    self._finish(i, "length")
+            with Span("grow", self.session):
+                for i, s in enumerate(self.slots):
+                    if s is not None and not self.paging.ensure_span(
+                            s.paging, s.next_pos, s.next_pos + j):
+                        self._finish(i, "length")
         active = [i for i, s in enumerate(self.slots) if s is not None]
         if not active:
             self.step_count += 1        # idle tick (open-loop gap)
             return bool(self.queue)
+        session = self.session
         mb = self.engine.max_batch
-        tokens = np.zeros(mb, np.int32)
-        positions = np.zeros(mb, np.int32)
-        for i in active:
-            tokens[i] = self.slots[i].pending
-            positions[i] = self.slots[i].next_pos
-        page_tables = None
-        if self.paging is not None:
-            page_tables = np.zeros((mb, self.paging.pages_per_row),
-                                   np.int32)
-            for i in active:
-                page_tables[i] = self.slots[i].paging.table(
-                    self.paging.pages_per_row)
+        with Span("inputs", session):
+            tokens, positions, page_tables = self._inputs(active)
         fault_injection.maybe_kill("decode_step", self.step_count)
         fault_injection.maybe_fail_decode(self.step_count)
         # draft: j chained truncated-forward calls of ONE compiled
@@ -384,51 +531,57 @@ class ContinuousBatchingScheduler:
         chunk[:, 0] = tokens
         q_dists = None
         cur, cur_pos = tokens, positions.copy()
-        t0 = time.perf_counter()
-        for t in range(j):
-            cur, q = spec.draft(cur, cur_pos, page_tables=page_tables)
-            chunk[:, t + 1] = cur
-            if q is not None:
-                if q_dists is None:
-                    q_dists = np.zeros((mb, k, q.shape[-1]), np.float32)
-                q_dists[:, t] = q
-            cur_pos = cur_pos + 1
-        draft_wall = time.perf_counter() - t0
+        with Span("draft", session) as draft_span:
+            for t in range(j):
+                cur, q = spec.draft(cur, cur_pos, page_tables=page_tables)
+                chunk[:, t + 1] = cur
+                if q is not None:
+                    if q_dists is None:
+                        q_dists = np.zeros((mb, k, q.shape[-1]),
+                                           np.float32)
+                    q_dists[:, t] = q
+                cur_pos = cur_pos + 1
         # verify: one full-depth teacher-forced call over [B, k+1]
         pos_chunk = positions[:, None] + \
             np.arange(k + 1, dtype=np.int32)[None, :]
         draft_len = np.zeros(mb, np.int32)
         draft_len[active] = j
-        t1 = time.perf_counter()
-        acc, out = spec.verify(chunk, pos_chunk, draft_len,
-                               q_dists=q_dists,
-                               page_tables=page_tables)
-        verify_wall = time.perf_counter() - t1
+        with Span("verify", session) as verify_span:
+            acc, out = spec.verify(chunk, pos_chunk, draft_len,
+                                   q_dists=q_dists,
+                                   page_tables=page_tables)
+        t_tokens = clock()      # the host has this round's tokens
         self.step_count += 1
         # consume: walk each row's accepted block token by token so
         # eos / token budget / bucket edges bind MID-CHUNK exactly
         # where the non-speculative loop would have stopped
         emitted = accepted = 0
-        for i in active:
-            s = self.slots[i]
-            accepted += int(acc[i])
-            for t in range(int(acc[i]) + 1):
-                s.next_pos += 1
-                s.pending = int(out[i, t])
-                s.generated.append(s.pending)
-                emitted += 1
-                self._check_finished(i)
-                if self.slots[i] is None:
-                    break
-        spec.observe(len(active), len(active) * j, accepted, emitted)
-        self._emit(len(active), draft_wall + verify_wall,
-                   tokens=emitted,
-                   spec_stats={"accepted_tokens": emitted,
-                               "accepted_drafts": accepted,
-                               "draft_tokens": len(active) * j,
-                               "draft_len": j,
-                               "draft_wall_s": draft_wall,
-                               "verify_wall_s": verify_wall})
+        with Span("book", session):
+            for i in active:
+                s = self.slots[i]
+                accepted += int(acc[i])
+                for t in range(int(acc[i]) + 1):
+                    s.next_pos += 1
+                    s.pending = int(out[i, t])
+                    s.generated.append(s.pending)
+                    s.token_t.append(t_tokens)
+                    emitted += 1
+                    self._check_finished(i)
+                    if self.slots[i] is None:
+                        break
+            spec.observe(len(active), len(active) * j, accepted, emitted)
+            self._step_attrs["batch"] = len(active)
+            self._step_attrs["tokens"] += emitted
+            self._emit(len(active),
+                       draft_span.duration_s + verify_span.duration_s,
+                       tokens=emitted,
+                       spec_stats={"accepted_tokens": emitted,
+                                   "accepted_drafts": accepted,
+                                   "draft_tokens": len(active) * j,
+                                   "draft_len": j,
+                                   "draft_wall_s": draft_span.duration_s,
+                                   "verify_wall_s":
+                                       verify_span.duration_s})
         return bool(self.queue) or any(s is not None for s in self.slots)
 
     def run(self, requests=None, max_steps=100000):
@@ -473,58 +626,22 @@ class ContinuousBatchingScheduler:
             return
         occ = batch / float(self.engine.max_batch)
         tokens = batch if tokens is None else tokens
-        extra = {}
-        if spec_stats is not None:
-            extra.update(spec_stats)
+        extra = dict(spec_stats or {})
         if self.paging is not None:
             pg = self.paging
-            extra = {"pages_free": pg.allocator.free_pages,
-                     "pages_resident": pg.allocator.resident_pages,
-                     "prefix_hits": pg.prefix_hits,
-                     "prefix_misses": pg.prefix_misses,
-                     "sessions_admitted": pg.sessions_admitted,
-                     "sessions_parked_host": len(pg.host_store),
-                     "cache_bytes": pg.page_bytes() * pg.engine.n_pages}
+            extra.update(
+                pages_free=pg.allocator.free_pages,
+                pages_resident=pg.allocator.resident_pages,
+                pages_live=pg.pages_live,
+                prefix_hits=pg.prefix_hits,
+                prefix_misses=pg.prefix_misses,
+                sessions_admitted=pg.sessions_admitted,
+                sessions_parked_host=len(pg.host_store),
+                cache_bytes=pg.page_bytes() * pg.engine.n_pages)
         self.session.emit(
             "decode_step", step=self.step_count, tokens=tokens,
             batch=batch, occupancy=occ, queue_depth=len(self.queue),
             wall_s=wall_s, **extra)
-        reg = self.session.registry
-        reg.histogram("decode_step_seconds",
-                      help="host wall per compiled decode step").observe(
-                          wall_s)
-        reg.counter("decode_tokens_total",
-                    help="tokens generated by decode steps").inc(tokens)
-        if spec_stats is not None:
-            reg.histogram(
-                "accepted_tokens",
-                help="tokens emitted per row per speculative round "
-                     "(accepted drafts + correction)").observe(
-                         spec_stats["accepted_tokens"] / float(batch))
-            drafted = spec_stats["draft_tokens"]
-            reg.gauge(
-                "draft_efficiency",
-                help="fraction of drafted tokens verify accepted").set(
-                    spec_stats["accepted_drafts"] / float(drafted)
-                    if drafted else 0.0)
-        reg.gauge("decode_batch_occupancy",
-                  help="live rows / max_batch").set(occ)
-        reg.gauge("decode_queue_depth",
-                  help="requests waiting for a cache row").set(
-                      len(self.queue))
-        if self.paging is not None:
-            pg = self.paging
-            reg.gauge("kv_pages_free",
-                      help="unallocated pool pages").set(
-                          pg.allocator.free_pages)
-            reg.gauge("kv_pages_resident",
-                      help="allocated pool pages (live + parked + "
-                           "interned)").set(pg.allocator.resident_pages)
-            hits = reg.counter("prefix_hits",
-                               help="admissions that mapped shared "
-                                    "radix pages")
-            hits.inc(pg.prefix_hits - hits.value)
-            misses = reg.counter("prefix_misses",
-                                 help="admissions with no interned "
-                                      "prefix")
-            misses.inc(pg.prefix_misses - misses.value)
+        self.session.registry.counter(
+            "decode_tokens_total",
+            help="tokens generated by decode steps").inc(tokens)

@@ -153,6 +153,15 @@ def live_phase_paths():
     return {ident: spans[-1][0] for ident, spans in live_spans().items()}
 
 
+def enclosing_attr(key, default=None):
+    """``attrs[key]`` of the innermost open span of this thread that
+    has it: how a callee's span takes over its caller's ``rid``."""
+    for span in reversed(_stack()):
+        if span.attrs is not None and key in span.attrs:
+            return span.attrs[key]
+    return default
+
+
 class Span:
     """One timed, annotated scope; ``TelemetrySession.span`` makes one
     that also feeds the session."""
