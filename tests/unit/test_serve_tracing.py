@@ -373,7 +373,9 @@ def test_flight_recorder_reads_the_ring(tmp_path):
     assert abs(snap["phase_log"][-1]["t"] - time.time()) < 5.0
     assert snap["phase_log"][1]["duration_s"] >= 0
     log = rec.snapshot("after")["phase_log"]
-    assert [p["kind"] for p in log] == ["enter", "exit"] * 3
+    assert [(p["kind"], p["path"]) for p in log][2:] == [
+        ("enter", "dispatch"), ("enter", "dispatch/compile"),
+        ("exit", "dispatch/compile"), ("exit", "dispatch")]
     for _ in range(20):
         with Span("many"):
             pass
