@@ -67,6 +67,9 @@ from jax.experimental.pallas import tpu as pltpu
 from deepspeed_tpu.ops.pallas.flash_attention import DEFAULT_MASK_VALUE
 
 DEFAULT_BLOCK_K = 128
+# the kernels' names in the HLO and in a device trace (see
+# flash_attention.py)
+DECODE_NAME, DECODE_PAGED_NAME = "ds_flash_decode", "ds_flash_decode_paged"
 
 # TPU native sublane tile per element width (lane dim is always 128):
 # a compiled block whose second-minor dim doesn't tile to this pads to
@@ -296,12 +299,15 @@ def flash_decode(q, k, v, positions, k_scale=None, v_scale=None,
         out_specs=pl.BlockSpec((1, 1, D), q_map),
         scratch_shapes=_softmax_scratch(D),
     )
-    out = pl.pallas_call(
+    call = pl.pallas_call(
         _flash_decode_kernel(H, D, block_k, n_kb, quant),
+        name=DECODE_NAME,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * H, 1, D), q.dtype),
         interpret=interpret,
-    )(jnp.asarray(positions, jnp.int32), *args)
+    )
+    with jax.named_scope(DECODE_NAME):
+        out = call(jnp.asarray(positions, jnp.int32), *args)
     return out.reshape(B, H, 1, D).transpose(0, 2, 1, 3)
 
 
@@ -392,11 +398,14 @@ def flash_decode_paged(q, k, v, positions, page_tables, k_scale=None,
         out_specs=pl.BlockSpec((1, 1, D), q_map),
         scratch_shapes=_softmax_scratch(D),
     )
-    out = pl.pallas_call(
+    call = pl.pallas_call(
         _flash_decode_kernel(H, D, block_k, n_kb, quant, paged=True),
+        name=DECODE_PAGED_NAME,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * H, 1, D), q.dtype),
         interpret=interpret,
-    )(jnp.asarray(positions, jnp.int32),
-      jnp.asarray(page_tables, jnp.int32), *args)
+    )
+    with jax.named_scope(DECODE_PAGED_NAME):
+        out = call(jnp.asarray(positions, jnp.int32),
+                   jnp.asarray(page_tables, jnp.int32), *args)
     return out.reshape(B, H, 1, D).transpose(0, 2, 1, 3)
