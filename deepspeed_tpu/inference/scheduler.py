@@ -95,6 +95,10 @@ class Request:
     arrival_t: Optional[float] = None
 
 
+def _minus(a, b):
+    return None if a is None or b is None else a - b
+
+
 @dataclasses.dataclass
 class Completion:
     rid: str
@@ -120,6 +124,26 @@ class Completion:
     token_t: List[float] = dataclasses.field(default_factory=list)
     finish_t: Optional[float] = None
 
+    # the stamps as durations, seconds (None where a stamp is missing)
+    @property
+    def queue_wait_s(self):
+        return _minus(self.admit_t, self.submit_t)
+
+    @property
+    def ttft_s(self):
+        """Arrival to the first moment a caller of ``step()`` can read
+        the first token."""
+        return _minus(self.first_return_t, self.arrival_t)
+
+    @property
+    def hold_s(self):
+        """How long the finished first token waited inside ``step()``."""
+        return _minus(self.first_return_t, self.first_token_t)
+
+    @property
+    def latency_s(self):
+        return _minus(self.finish_t, self.arrival_t)
+
 
 @dataclasses.dataclass
 class _Slot:
@@ -135,9 +159,6 @@ class _Slot:
     # one stamp per generated token; the first is the first token's
     token_t: List[float] = dataclasses.field(default_factory=list)
 
-
-def _minus(a, b):
-    return None if a is None or b is None else a - b
 
 
 class ContinuousBatchingScheduler:
@@ -273,10 +294,8 @@ class ContinuousBatchingScheduler:
                 "request_done", rid=comp.rid,
                 finish_reason=comp.finish_reason,
                 prompt_len=comp.prompt_len, tokens=len(comp.tokens),
-                queue_wait_s=_minus(comp.admit_t, comp.submit_t),
-                ttft_s=_minus(comp.first_return_t, comp.arrival_t),
-                hold_s=_minus(comp.first_return_t, comp.first_token_t),
-                latency_s=_minus(comp.finish_t, comp.arrival_t),
+                queue_wait_s=comp.queue_wait_s, ttft_s=comp.ttft_s,
+                hold_s=comp.hold_s, latency_s=comp.latency_s,
                 token_gaps_s=[round(b - a, 6) for a, b in
                               zip(comp.token_t[1:], comp.token_t[2:])])
 

@@ -983,7 +983,10 @@ def main(argv=None):
              "bucket": c.bucket, "slot": c.slot, "steps": c.steps,
              "prefix_hit": c.prefix_hit, "resumed": c.resumed,
              "prefill_chunks": c.prefill_chunks,
-             "prefill_chunks_skipped": c.prefill_chunks_skipped}
+             "prefill_chunks_skipped": c.prefill_chunks_skipped,
+             # from the scheduler's own stamps: arrival -> the step
+             # that made the first token returns; arrival -> finish
+             "ttft_s": c.ttft_s, "latency_s": c.latency_s}
             for c in completions],
         "decode_steps": sched.step_count,
         "compile_counts": counts,

@@ -596,6 +596,7 @@ class DeepSpeedEngine:
                 dp_world_size=self.dp_world_size,
                 mp_world_size=self.mp_world_size,
                 n_devices=len(jax.devices()),
+                device_kind=jax.devices()[0].device_kind,
                 fp16=self.fp16_enabled(),
                 bf16=self.bfloat16_enabled(),
                 flops_per_token=tl.flops_per_token or None,

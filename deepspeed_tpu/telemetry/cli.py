@@ -7,14 +7,18 @@ Five subcommands over the schema-versioned event log a run writes when
 - ``ds_tpu_metrics summary LOG`` — step count, wall time, step-time
   stats (mean/p50/p95), per-phase breakdown with shares, tokens/sec,
   and an MFU estimate (the ANALYSIS_MFU.md accounting: achieved TFLOPS
-  = tokens/sec x flops/token; MFU = achieved / peak, default peak 197
-  TFLOPS — one v5e chip's bf16 ceiling), plus recompile / health-guard /
-  checkpoint event counts.
+  = tokens/sec x flops/token; MFU = achieved / peak, the peak being
+  ``--peak-tflops`` or the published bf16 peak of the ``device_kind``
+  the log's ``run_start`` event names; for a kind the table does not
+  know, achieved TFLOPS without an MFU), plus recompile / health-guard
+  / checkpoint event counts.
   Serving logs (``decode_step`` events from the continuous-batching
   scheduler, `inference/scheduler.py`) get a serve-mode summary
   instead: tokens/sec, per-token latency p50/p95/p99 (each token's
   latency is its decode step's host wall), mean batch occupancy, and
-  queue depth. Fleet logs (router events from `inference/router.py`)
+  queue depth; where the log holds the scheduler's ``request_done``
+  events also time to first token, the scheduler's own queue wait and
+  the gaps between a request's tokens, per request. Fleet logs (router events from `inference/router.py`)
   add a fleet block: requests/completions by reason, replica deaths by
   cause, redispatches, aborts, shed/defer backpressure, and
   per-request latency percentiles.
@@ -50,8 +54,10 @@ import sys
 
 from deepspeed_tpu.telemetry.events import SCHEMA_VERSION
 
-# One v5e chip's bf16 peak (ANALYSIS_MFU.md) — override per target chip.
-DEFAULT_PEAK_TFLOPS = 197.0
+# Published bf16 peak per chip by the ``device_kind`` a log's
+# ``run_start`` names (as benchmarks/suite/peaks.json has it). A kind
+# that is not here gets no MFU unless ``--peak-tflops`` says.
+PEAK_TFLOPS_BY_KIND = {"TPU v5 lite": 197.0, "TPU v5e": 197.0}
 
 
 def read_events(path):
@@ -78,6 +84,14 @@ def _percentile(sorted_vals, q):
     idx = min(len(sorted_vals) - 1,
               max(0, int(round(q * (len(sorted_vals) - 1)))))
     return sorted_vals[idx]
+
+
+def _device_kind(events):
+    for kind in ("run_start", "compile"):
+        for evt in events:
+            if evt.get("event") == kind and evt.get("device_kind"):
+                return str(evt["device_kind"])
+    return None
 
 
 def _resolve_flops_per_token(events, flops_per_token=None):
@@ -291,7 +305,7 @@ def _summarize_disagg(events):
     }
 
 
-def summarize(events, flops_per_token=None, peak_tflops=DEFAULT_PEAK_TFLOPS):
+def summarize(events, flops_per_token=None, peak_tflops=None):
     """Aggregate a run's events into the summary dict. None when the
     log holds neither step events nor resilience events (a supervisor's
     log is all restarts and recoveries — still worth a summary)."""
@@ -300,7 +314,9 @@ def summarize(events, flops_per_token=None, peak_tflops=DEFAULT_PEAK_TFLOPS):
     fleet = _summarize_fleet(events)
     disagg = _summarize_disagg(events)
     if not steps and (decode or fleet or disagg):
-        serve = _summarize_serve(decode, fleet=fleet)
+        serve = _summarize_serve(
+            decode, fleet=fleet,
+            done=[e for e in events if e.get("event") == "request_done"])
         if serve is not None:
             serve["kernels"] = _kernel_summary(events)
             serve["disagg"] = disagg
@@ -349,10 +365,13 @@ def summarize(events, flops_per_token=None, peak_tflops=DEFAULT_PEAK_TFLOPS):
     mfu = None
     if tokens_per_s and fpt:
         achieved_tflops = tokens_per_s * fpt / 1e12
+        kind = _device_kind(events)
+        peak = peak_tflops or PEAK_TFLOPS_BY_KIND.get(kind)
         mfu = {"flops_per_token": fpt,
-               "peak_tflops": float(peak_tflops),
+               "device_kind": kind,
+               "peak_tflops": float(peak) if peak else None,
                "achieved_tflops": achieved_tflops,
-               "mfu": achieved_tflops / float(peak_tflops)}
+               "mfu": achieved_tflops / float(peak) if peak else None}
     losses = [float(e["loss"]) for e in steps
               if e.get("loss") is not None]
     return {
@@ -403,7 +422,37 @@ def summarize(events, flops_per_token=None, peak_tflops=DEFAULT_PEAK_TFLOPS):
     }
 
 
-def _summarize_serve(decode, fleet=None):
+def _summarize_requests(done):
+    """Per-request times from the scheduler's ``request_done`` events:
+    time to first token as the caller of ``step()`` sees it, the
+    scheduler's own queue wait, how long a finished first token waited
+    for its step to return, and the gaps between a request's tokens
+    from the second on. None without such events."""
+    def stats(key):
+        vals = sorted(float(e[key]) for e in done
+                      if e.get(key) is not None)
+        return {"n": len(vals), "p50": _percentile(vals, 0.50),
+                "p95": _percentile(vals, 0.95),
+                "p99": _percentile(vals, 0.99)}
+    if not done:
+        return None
+    gaps = sorted(float(g) for e in done
+                  for g in (e.get("token_gaps_s") or ()))
+    reasons = {}
+    for e in done:
+        r = e.get("finish_reason", "?")
+        reasons[r] = reasons.get(r, 0) + 1
+    return {"count": len(done), "by_reason": reasons,
+            "ttft_s": stats("ttft_s"),
+            "queue_wait_s": stats("queue_wait_s"),
+            "hold_s": stats("hold_s"), "latency_s": stats("latency_s"),
+            "token_gap_s": {"n": len(gaps),
+                            "p50": _percentile(gaps, 0.50),
+                            "p95": _percentile(gaps, 0.95),
+                            "p99": _percentile(gaps, 0.99)}}
+
+
+def _summarize_serve(decode, fleet=None, done=()):
     """Serve-mode summary over ``decode_step`` events. Per-token latency
     samples: every token a decode step produced experienced that step's
     host wall, so the sample list is each step's wall repeated
@@ -516,6 +565,7 @@ def _summarize_serve(decode, fleet=None):
         },
         "paging": paging,
         "speculative": speculative,
+        "requests": _summarize_requests(done),
         "fleet": fleet,
         "mfu": None,
     }
@@ -541,6 +591,18 @@ def print_serve_summary(s, out=None):
         print(f"  per-token latency p50 {_fmt_s(lat['p50'])} "
               f"p95 {_fmt_s(lat['p95'])} p99 {_fmt_s(lat['p99'])}",
               file=out)
+    rq = s.get("requests")
+    if rq:
+        t, g = rq["ttft_s"], rq["token_gap_s"]
+        print(f"  requests {rq['count']}: time to first token p50 "
+              f"{_fmt_s(t['p50'])} p95 {_fmt_s(t['p95'])} p99 "
+              f"{_fmt_s(t['p99'])} (queue wait p95 "
+              f"{_fmt_s(rq['queue_wait_s']['p95'])}, first token held "
+              f"p50 {_fmt_s(rq['hold_s']['p50'])})", file=out)
+        if g["n"]:
+            print(f"  gap between tokens p50 {_fmt_s(g['p50'])} p95 "
+                  f"{_fmt_s(g['p95'])} p99 {_fmt_s(g['p99'])} "
+                  f"({g['n']} gaps, per request)", file=out)
     occ = s["batch_occupancy"]
     if occ["mean"] is not None:
         print(f"  batch occupancy mean {occ['mean'] * 100:.1f}% "
@@ -679,11 +741,18 @@ def print_summary(s, out=None):
                   f"{ps['share'] * 100:5.1f}%", file=out)
     if s["tokens_per_s"]:
         print(f"  throughput {s['tokens_per_s']:,.0f} tokens/s", file=out)
-    if s["mfu"]:
+    if s["mfu"] and s["mfu"]["mfu"] is not None:
         m = s["mfu"]
         print(f"  MFU {m['mfu'] * 100:.1f}% "
               f"({m['achieved_tflops']:.1f} / {m['peak_tflops']:.0f} "
               f"TFLOPS at {m['flops_per_token']:,.0f} flops/token)",
+              file=out)
+    elif s["mfu"]:
+        m = s["mfu"]
+        print(f"  achieved {m['achieved_tflops']:.1f} TFLOPS at "
+              f"{m['flops_per_token']:,.0f} flops/token; no MFU: the "
+              f"log's device kind ({m['device_kind'] or 'not stamped'}) "
+              f"has no peak in this tool's table, pass --peak-tflops",
               file=out)
     if s.get("collective_wire"):
         w = s["collective_wire"]
@@ -1080,10 +1149,10 @@ def main(argv=None):
     p_sum.add_argument("--flops-per-token", type=float, default=None,
                        help="model flops per token for the MFU estimate "
                             "(default: the log's compile/run_start stamp)")
-    p_sum.add_argument("--peak-tflops", type=float,
-                       default=DEFAULT_PEAK_TFLOPS,
-                       help="per-chip peak TFLOPS for MFU (default "
-                            f"{DEFAULT_PEAK_TFLOPS:.0f}, v5e bf16)")
+    p_sum.add_argument("--peak-tflops", type=float, default=None,
+                       help="per-chip peak TFLOPS for MFU (default: the "
+                            "published bf16 peak of the log's "
+                            "device_kind; none for an unknown kind)")
 
     p_tail = sub.add_parser("tail", help="print the last N events")
     p_tail.add_argument("log")
@@ -1098,8 +1167,7 @@ def main(argv=None):
     p_diff.add_argument("log_b", help="candidate run log")
     p_diff.add_argument("--json", action="store_true", dest="as_json")
     p_diff.add_argument("--flops-per-token", type=float, default=None)
-    p_diff.add_argument("--peak-tflops", type=float,
-                        default=DEFAULT_PEAK_TFLOPS)
+    p_diff.add_argument("--peak-tflops", type=float, default=None)
     p_diff.add_argument("--fail-over", type=float, default=None,
                         metavar="PCT",
                         help="exit 1 when mean step time regressed by "

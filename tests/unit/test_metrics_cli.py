@@ -33,12 +33,13 @@ def run_cli(*args, check=True):
 
 
 def write_log(path, step_wall=0.1, steps=4, loss=2.0,
-              flops_per_token=1000.0, tokens=512):
+              flops_per_token=1000.0, tokens=512,
+              device_kind="TPU v5 lite"):
     """A synthetic but schema-true run log, built through the real
     session/exporter stack so the CLI reads exactly what a run writes."""
     session = TelemetrySession(exporters=[JsonlExporter(str(path))])
     session.emit("run_start", flavor="dense", zero_stage=0, n_devices=8,
-                 flops_per_token=flops_per_token)
+                 flops_per_token=flops_per_token, device_kind=device_kind)
     session.emit("compile", step=0, flavor="dense", param_bytes=10 ** 6,
                  static_peak_bytes=2 * 10 ** 6,
                  flops_per_token=flops_per_token, batch_tokens=tokens)
@@ -70,6 +71,29 @@ def test_summary_text(tmp_path):
     assert "1 recompile(s)" in out
     assert "warn=1" in out   # health-guard trips grouped by action
     assert "1 checkpoint save(s)" in out
+
+
+@pytest.mark.parametrize("kind", ["cpu", None])
+def test_summary_unknown_device_kind_gets_no_mfu(tmp_path, kind):
+    """No peak is assumed for a device the table does not know:
+    achieved TFLOPS without an MFU, and the reason."""
+    log = write_log(tmp_path / "run.jsonl", device_kind=kind)
+    s = json.loads(run_cli("summary", str(log), "--json").stdout)
+    assert s["mfu"]["mfu"] is None and s["mfu"]["peak_tflops"] is None
+    assert s["mfu"]["device_kind"] == kind
+    assert s["mfu"]["achieved_tflops"] == pytest.approx(
+        512 / 0.1 * 1000.0 / 1e12)
+    out = run_cli("summary", str(log)).stdout
+    assert "no MFU" in out and "--peak-tflops" in out
+    assert (kind or "not stamped") in out
+    # the flag still decides
+    s = json.loads(run_cli("summary", str(log), "--json",
+                           "--peak-tflops", "50").stdout)
+    assert s["mfu"]["mfu"] == pytest.approx(
+        s["mfu"]["achieved_tflops"] / 50.0)
+    # and a log that differs only in that diffs clean
+    assert run_cli("diff", str(log), str(log),
+                   "--fail-over", "5").returncode == 0
 
 
 def test_summary_json_keys_and_mfu_math(tmp_path):
@@ -295,6 +319,47 @@ def test_serve_summary_json_math(tmp_path):
     slower = write_serve_log(tmp_path / "b.jsonl", wall_s=0.03)
     proc = run_cli("diff", str(log), str(slower), check=False)
     assert "step_s.mean" in proc.stdout
+
+
+def test_serve_summary_reads_request_done_events(tmp_path):
+    """With the scheduler's per-completion events in the log, time to
+    first token and the gaps between tokens are per request, not the
+    step wall repeated once per token."""
+    path = tmp_path / "serve.jsonl"
+    session = TelemetrySession(exporters=[JsonlExporter(str(path))])
+    for i in range(4):
+        session.emit("decode_step", step=i + 1, tokens=2, batch=2,
+                     occupancy=1.0, queue_depth=0, wall_s=0.02)
+    for i in range(10):
+        session.emit("request_done", rid=f"r{i}",
+                     finish_reason="max_new_tokens", prompt_len=8,
+                     tokens=4, queue_wait_s=0.001 * i,
+                     ttft_s=0.1 + 0.01 * i, hold_s=0.02,
+                     latency_s=0.5, token_gaps_s=[0.02, 0.03])
+    session.emit("request_done", rid="late", finish_reason="timeout",
+                 prompt_len=8, tokens=0, queue_wait_s=None, ttft_s=None,
+                 hold_s=None, latency_s=0.2, token_gaps_s=[])
+    session.close()
+    s = json.loads(run_cli("summary", str(path), "--json").stdout)
+    rq = s["requests"]
+    assert rq["count"] == 11
+    assert rq["by_reason"] == {"max_new_tokens": 10, "timeout": 1}
+    assert rq["ttft_s"]["n"] == 10
+    assert rq["ttft_s"]["p50"] == pytest.approx(0.15, abs=0.011)
+    assert rq["ttft_s"]["p99"] == pytest.approx(0.19)
+    assert rq["hold_s"]["p50"] == pytest.approx(0.02)
+    assert rq["latency_s"]["n"] == 11
+    assert rq["token_gap_s"]["n"] == 20
+    assert rq["token_gap_s"]["p99"] == pytest.approx(0.03)
+    out = run_cli("summary", str(path)).stdout
+    assert "time to first token p50" in out
+    assert "gap between tokens p50" in out and "20 gaps" in out
+    # a log without them has no such block
+    plain = write_serve_log(tmp_path / "plain.jsonl")
+    assert json.loads(run_cli("summary", str(plain),
+                              "--json").stdout)["requests"] is None
+    assert "time to first token" not in run_cli(
+        "summary", str(plain)).stdout
 
 
 # ---------------------------------------------------------------------------

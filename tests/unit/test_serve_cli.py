@@ -55,6 +55,32 @@ def test_synthetic_stream_end_to_end(tmp_path, capsys):
     assert s["mfu"] is None                   # serve summaries skip MFU
 
 
+def test_result_and_log_carry_the_schedulers_request_times(tmp_path,
+                                                           capsys):
+    """An operator's use of the stamps: the result JSON has time to
+    first token and latency per completion, and the log one
+    ``request_done`` event each, which the summary reads."""
+    log = tmp_path / "serve.jsonl"
+    rc = main(["--synthetic", "4", "--max-new", "4", "--jsonl", str(log),
+               "--json"])
+    assert rc == 0
+    result = json.loads(capsys.readouterr().out)
+    for c in result["completions"]:
+        assert 0 < c["ttft_s"] <= c["latency_s"]
+    events = read_events(str(log))
+    done = {e["rid"]: e for e in events
+            if e.get("event") == "request_done"}
+    assert set(done) == {c["rid"] for c in result["completions"]}
+    for c in result["completions"]:
+        e = done[c["rid"]]
+        assert e["ttft_s"] == pytest.approx(c["ttft_s"])
+        assert e["latency_s"] == pytest.approx(c["latency_s"])
+        assert e["tokens"] == len(c["tokens"])
+    rq = summarize(events)["requests"]
+    assert rq["count"] == 4 and rq["ttft_s"]["n"] == 4
+    assert rq["ttft_s"]["p50"] >= rq["hold_s"]["p50"] > 0
+
+
 def test_requests_file_with_config(tmp_path, capsys):
     cfg = tmp_path / "ds_config.json"
     cfg.write_text(json.dumps({
