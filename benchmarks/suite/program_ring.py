@@ -150,11 +150,17 @@ def trace_offset(v, trace):
     return fits[0] if len(fits) == 1 else None
 
 
+def nested(records):
+    """``(t0, t1, path)`` of the records, a span before those it holds:
+    the order ``innermost`` needs."""
+    return sorted(((r[1], r[2], r[0]) for r in records),
+                  key=lambda s: (s[0], -s[1]))
+
+
 def innermost(spans, t):
-    """Path of the innermost of ``spans`` (sorted ``(t0, t1, path)`` of
-    one thread, so they nest) that is open at ``t``; ``no_span`` if
-    none is."""
-    i = bisect.bisect_right(spans, (t, float("inf"), "")) - 1
+    """Path of the innermost of ``spans`` (``nested``, of one thread, so
+    they nest) that is open at ``t``; ``no_span`` if none is."""
+    i = bisect.bisect_right(spans, t, key=lambda s: s[0]) - 1
     while i >= 0:
         t0, t1, path = spans[i]
         if t < t1:

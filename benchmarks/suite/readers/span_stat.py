@@ -5,7 +5,11 @@ window.
 Without ``attr``: of each span's duration, less the time of the spans
 named in ``minus`` (full paths) that lie inside it; the profiled
 segment's spans are left out, as the harness leaves out its own (a
-traced host is slower). With ``attr = [a, b]``: of ``attrs[a] /
+traced host is slower). ``over`` = ``run`` also takes the spans of the
+ramp before the window: the harness keeps its own span series
+(``decode``, ``prefill``) from the start of the ramp to the profiler's
+start, so the twin of such a series covers the same calls and the two
+can be held against each other. With ``attr = [a, b]``: of ``attrs[a] /
 attrs[b]``, a ratio of two counters the span carries, over the whole
 window (tracing does not move a count). ``stat`` is ``median``,
 ``mean`` or ``p<q>``; ``scale`` 1000 turns seconds into ms, 100 a share
@@ -17,10 +21,15 @@ import bisect
 from benchmarks.suite import program_ring
 
 
-def read(ctx, result, path, stat, minus=(), attr=None, scale=1.0):
+def read(ctx, result, path, stat, minus=(), attr=None, scale=1.0,
+         over="window"):
     v = program_ring.view(ctx, result)
     if v is None:
         return None
+    if over == "run":
+        v.w0 -= ctx.workload["traffic"]["ramp_s"]
+    elif over != "window":
+        raise ValueError(f"unknown over {over!r}")
     if attr is not None:
         top, bottom = attr
         values = [r[3][top] / r[3][bottom]

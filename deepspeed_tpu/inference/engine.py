@@ -375,6 +375,18 @@ class InferenceEngine:
         if not 0 < n <= self.max_seq:
             raise ValueError(
                 f"prompt length {n} outside (0, max_seq={self.max_seq}]")
+        if self.kv_layout == "paged" and page_table is None:
+            raise ValueError("paged prefill requires a page_table")
+        # one span for the whole prompt, its uploads included; ``rid``
+        # is the enclosing (scheduler's ``admit``) span's, so the call
+        # takes no new argument
+        attrs = {"rid": enclosing_attr("rid")}
+        with Span("prefill", self.session, attrs):
+            return self._prefill_chunks(slot, prompt, page_table, start,
+                                        attrs)
+
+    def _prefill_chunks(self, slot, prompt, page_table, start, attrs):
+        n = len(prompt)
         chunk = self.prefill_chunk
         padded = -(-n // chunk) * chunk
         toks = np.zeros((1, padded), np.int32)
@@ -382,8 +394,6 @@ class InferenceEngine:
         last_chunk = (n - 1) // chunk
         paged = self.kv_layout == "paged"
         if paged:
-            if page_table is None:
-                raise ValueError("paged prefill requires a page_table")
             pt = jnp.asarray(
                 np.asarray(page_table, np.int32).reshape(1, -1))
         # the last chunk always runs (it produces the logits the first
@@ -393,31 +403,25 @@ class InferenceEngine:
             raise ValueError(
                 f"prefill start {start} must be chunk-aligned "
                 f"(chunk={chunk})")
+        attrs["chunks"] = (padded - start) // chunk
         from deepspeed_tpu.runtime.resilience import fault_injection
         last = None
-        # one span for the whole prompt; ``rid`` is the enclosing
-        # (scheduler's ``admit``) span's, so the call takes no new
-        # argument
-        attrs = {"rid": enclosing_attr("rid"),
-                 "chunks": (padded - start) // chunk}
-        with Span("prefill", self.session, attrs):
-            for ci in range(start // chunk, padded // chunk):
-                # disagg soak seam: an armed prefill_chunk kill dies
-                # HERE, mid-prompt, with pages allocated and partially
-                # written.
-                fault_injection.maybe_kill("prefill_chunk", ci)
-                tc = jnp.asarray(toks[:, ci * chunk:(ci + 1) * chunk])
-                pc = jnp.arange(ci * chunk, (ci + 1) * chunk,
-                                dtype=jnp.int32)[None, :]
-                if paged:
-                    logits, self.cache = self._prefill(
-                        self.params, self.cache, tc, pc, pt)
-                else:
-                    logits, self.cache = self._prefill(
-                        self.params, self.cache, tc, pc,
-                        jnp.asarray(slot, jnp.int32))
-                if ci == last_chunk:
-                    last = np.asarray(logits[0, (n - 1) % chunk])
+        for ci in range(start // chunk, padded // chunk):
+            # disagg soak seam: an armed prefill_chunk kill dies HERE,
+            # mid-prompt, with pages allocated and partially written.
+            fault_injection.maybe_kill("prefill_chunk", ci)
+            tc = jnp.asarray(toks[:, ci * chunk:(ci + 1) * chunk])
+            pc = jnp.arange(ci * chunk, (ci + 1) * chunk,
+                            dtype=jnp.int32)[None, :]
+            if paged:
+                logits, self.cache = self._prefill(
+                    self.params, self.cache, tc, pc, pt)
+            else:
+                logits, self.cache = self._prefill(
+                    self.params, self.cache, tc, pc,
+                    jnp.asarray(slot, jnp.int32))
+            if ci == last_chunk:
+                last = np.asarray(logits[0, (n - 1) % chunk])
         return last
 
     def decode(self, tokens, positions, page_tables=None):

@@ -150,6 +150,15 @@ def test_span_stat_durations_minus_children_and_ratios():
     assert read(path=S, stat="mean", attr=["pages_live", "pages_total"],
                 scale=100) == pytest.approx(100 * 200 / 6 / 100)
     assert read(path=S, stat="mean", attr=["nope", "max_batch"]) is None
+    # the harness's own series start with the ramp: so can a twin
+    put_step(w0 - 0.2, decode_s=0.002)      # in the 0.5 s ramp
+    put_step(w0 - 0.7, decode_s=0.001)      # before it
+    assert read(path=S + "/decode", stat="p0", scale=1000) == \
+        pytest.approx(10.0)
+    assert read(path=S + "/decode", stat="p0", scale=1000,
+                over="run") == pytest.approx(2.0)
+    with pytest.raises(ValueError, match="unknown over"):
+        read(path=S, stat="median", over="ever")
     assert read(path="serve/none", stat="median") is None
     with pytest.raises(ValueError, match="unknown stat"):
         read(path=S, stat="mode")
@@ -232,9 +241,8 @@ def traced_run(shift=None, drop_last=False):
     """Four steps, 5 ms apart, in a profiled segment of 1 s; the trace's
     clock is a constant away from the ring's. The device works all
     through each decode except for 1.5 ms under ``logits_d2h``, then
-    0.4 ms at the end of the step (under ``serve/step`` itself), then
-    5.95 ms that begin after the step and end 1 ms into the next
-    decode: their middle lies between the steps, under no span.
+    0.4 ms round the end of the step, then 5.95 ms that begin after the
+    step and end 1 ms into the next decode.
     ``shift`` moves one program decode span against the others."""
     ctx, res, w0 = fake_run(seconds=10.0, profile_s=1.0, trace=True)
     off = -(w0 + 9.0) + 0.25    # the profile started 0.25 s into it
@@ -272,14 +280,24 @@ def test_idle_is_laid_to_the_program_span_open_at_each_gap():
     acc, decodes = idle_under_span.split(v, res.trace, fit_off)
     assert decodes == 4
     per_step = {p: 1e3 * t / 4 for p, t in acc.items()}
+    # each gap is cut at the spans' edges: the 5.95 ms between two
+    # steps' ops are 0.1 ms of the old step's end, 4.85 ms of no span,
+    # then the new step's 0.1 ms, its upload, dispatch and the first
+    # 0.5 ms of its wait for the tokens
     assert per_step == pytest.approx({
-        S + "/decode/logits_d2h": 1.5, S: 0.4, "no_span": 3 * 5.95 / 4},
-        abs=1e-3)
+        S + "/decode/logits_d2h": 1.5, S: (4 * 0.3 + 3 * 0.1) / 4,
+        "no_span": (4 * 0.1 + 3 * 4.85) / 4,
+        S + "/decode/upload": 3 * 0.2 / 4,
+        S + "/decode/dispatch": 3 * 0.3 / 4,
+        S + "/decode/wait_tokens": 3 * 0.5 / 4}, abs=1e-3)
     read = lambda m, **kw: idle_under_span.read(ctx, res, match=m, **kw)
     assert read("^serve/step/decode/logits_d2h$") == pytest.approx(
         1.5, abs=1e-3)
-    assert read(SCHED) == pytest.approx(0.4 + 3 * 5.95 / 4, abs=1e-3)
-    assert read("^serve/step/decode/upload$") == 0.0
+    assert read(SCHED) == pytest.approx(
+        per_step[S] + per_step["no_span"], abs=1e-3)
+    assert read("^serve/step/decode/upload$") == pytest.approx(
+        0.15, abs=1e-3)
+    assert read("^serve/step/admit") == 0.0
     assert read(".", per="window") == pytest.approx(
         4 * 1.9 + 3 * 5.95, abs=1e-3)
     # all of the first chip's idle time is laid somewhere

@@ -51,13 +51,21 @@ def stream(n=6, seed=1, vocab=256):
             for i in range(n)]
 
 
+def ring_since(since):
+    """The ring's records between ``since`` and now: other tests of
+    the process may have put hand-made records at later clock
+    readings."""
+    now = clock()
+    return [r for r in spans.recent(since) if r[2] <= now]
+
+
 def serve(engine, requests):
     """Run the stream; returns (completions, the ring's records of
     this run, the scheduler)."""
     since = clock()
     sched = ContinuousBatchingScheduler(engine)
     comps = sched.run(requests)
-    return comps, spans.recent(since), sched
+    return comps, ring_since(since), sched
 
 
 @pytest.fixture(scope="module", params=["ring", "paged"])
@@ -127,7 +135,7 @@ def test_request_finished_in_its_first_step_is_stamped_at_return():
     (comp,) = sched.completions
     assert comp.first_token_t < comp.first_return_t == comp.finish_t
     assert comp.finish_t <= after
-    (rec,) = [r for r in spans.recent(since) if r[0] == "serve/request"]
+    (rec,) = [r for r in ring_since(since) if r[0] == "serve/request"]
     assert rec[2] == comp.finish_t
     assert rec[3]["first_return_t"] == comp.first_return_t
 
@@ -142,7 +150,7 @@ def test_queue_timeout_is_recorded_without_the_later_stamps():
     assert late.finish_reason == "timeout" and late.token_t == []
     assert late.admit_t is None and late.first_return_t is None
     assert late.submit_t <= late.finish_t
-    recs = {r[3]["rid"]: r for r in spans.recent(since)
+    recs = {r[3]["rid"]: r for r in ring_since(since)
             if r[0] == "serve/request"}
     assert recs["late"][3]["finish_reason"] == "timeout"
 
@@ -316,7 +324,7 @@ def test_span_without_a_session_lands_in_the_ring():
             assert spans.live_phase_paths()[
                 threading.get_ident()] == "outer/inner"
         attrs["late"] = 1       # filled in before the scope closes
-    recs = spans.recent(since)
+    recs = ring_since(since)
     assert [r[0] for r in recs] == ["outer/inner", "outer"]
     (ipath, i0, i1, iattrs), (opath, o0, o1, oattrs) = recs
     assert o0 <= i0 <= i1 <= o1 and iattrs is None
@@ -331,7 +339,7 @@ def test_span_records_when_its_body_raises():
     with pytest.raises(KeyError):
         with Span("boom"):
             raise KeyError("x")
-    assert [r[0] for r in spans.recent(since)] == ["boom"]
+    assert [r[0] for r in ring_since(since)] == ["boom"]
     assert "boom" not in spans.live_phase_paths().values()
 
 
