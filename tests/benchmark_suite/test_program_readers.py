@@ -341,6 +341,44 @@ def test_clocks_that_cannot_be_shown_to_agree_give_nothing(kind):
 
 
 # ---------------------------------------------------------------------------
+# the three flash kernels by name
+# ---------------------------------------------------------------------------
+
+def test_flash_kernels_are_found_by_name_and_sum_to_the_lump():
+    from benchmarks.suite.readers import kernel_time, op_time
+    ctx, res, _ = fake_run()
+    res.facts["profiled_steps"] = 2
+    call = " custom-call:tpu_custom_call"
+    named = xplane.Trace(spans=[], devices={0: [
+        ("ds_flash_fwd.3" + call, 0.000, 0.010),
+        ("fusion.7 fusion", 0.010, 0.020),
+        ("ds_flash_dkv.1.remat" + call, 0.020, 0.050),
+        ("ds_flash_dq.2" + call, 0.050, 0.070),
+        ("ds_flash_fwd.4" + call, 0.070, 0.080),
+        ("fused_adam.9" + call, 0.080, 0.090)]})
+    unnamed = xplane.Trace(spans=[], devices={0: [
+        ("attn.135" + call, 0.0, 0.010), ("fusion.7 fusion", 0.010, 0.020)]})
+    got = {}
+    for short in ("fwd", "dq", "dkv"):
+        spec = test_manifest.load(tiny.SUITE, "metrics",
+                                  f"flash_{short}_ms.train.json")
+        assert spec["reader"] == "kernel_time"
+        res.trace = named
+        got[short] = kernel_time.read(ctx, res, **spec["args"])
+        res.trace = unnamed             # a program that names nothing
+        assert kernel_time.read(ctx, res, **spec["args"]) is None
+        res.trace = None
+        assert kernel_time.read(ctx, res, **spec["args"]) is None
+    assert got == pytest.approx({"fwd": 10.0, "dq": 10.0, "dkv": 15.0})
+    # the lump finds any Pallas kernel, another one in the step too
+    lump = test_manifest.load(tiny.SUITE, "metrics",
+                              "flash_attn_ms.train.json")
+    res.trace = named
+    assert op_time.read(ctx, res, **lump["args"]) == pytest.approx(
+        sum(got.values()) + 5.0)
+
+
+# ---------------------------------------------------------------------------
 # the toy rehearsal: the program's numbers against the driver's
 # ---------------------------------------------------------------------------
 
