@@ -17,6 +17,7 @@ into %. ``None`` where the ring holds nothing of the window or no such
 span."""
 
 import bisect
+import dataclasses
 
 from benchmarks.suite import program_ring
 
@@ -27,7 +28,8 @@ def read(ctx, result, path, stat, minus=(), attr=None, scale=1.0,
     if v is None:
         return None
     if over == "run":
-        v.w0 -= ctx.workload["traffic"]["ramp_s"]
+        v = dataclasses.replace(
+            v, w0=v.w0 - ctx.workload["traffic"]["ramp_s"])
     elif over != "window":
         raise ValueError(f"unknown over {over!r}")
     if attr is not None:

@@ -68,7 +68,8 @@ class Request:
     ``arrival_t`` is when the request reached the system on
     ``telemetry.spans.clock``, where the caller knows it (a front end's
     receive time, a load generator's due time); ``submit()`` defaults
-    it to ``submit_t``. ``session_id`` (paged engines) parks the request's KV pages
+    it to ``submit_t``. ``session_id`` (paged engines) parks the
+    request's KV pages
     at completion so a follow-up request on the same session resumes
     without re-prefilling its history.
 
@@ -78,7 +79,8 @@ class Request:
     typed ``timeout`` reason instead of letting it stall the stream.
     ``redispatched``/``restarts`` are stamped by the fleet router when a
     replica death forces a re-prefill elsewhere; ``submit_t`` is
-    ``telemetry.spans.clock`` at FIRST submit and survives redispatch, so the
+    ``telemetry.spans.clock`` at FIRST submit and survives redispatch,
+    so the
     deadline spans retries (exactly-once completion semantics over
     at-least-once execution)."""
     rid: str
@@ -93,6 +95,12 @@ class Request:
     restarts: int = 0           # total re-executions (router)
     submit_t: Optional[float] = None
     arrival_t: Optional[float] = None
+
+
+# a request's stamps, as ``Completion`` and its ``serve/request`` record
+# carry them
+STAMPS = ("arrival_t", "submit_t", "admit_t", "first_token_t",
+          "first_return_t", "token_t", "finish_t")
 
 
 def _minus(a, b):
@@ -172,7 +180,7 @@ class ContinuousBatchingScheduler:
         # completions made inside the step that admitted them: their
         # first token is not out before that step returns
         self._unreturned = []
-        self._step_attrs = None
+        self._step_attrs = {}           # the running step's counters
         self.paging = None
         if getattr(engine, "kv_layout", "ring") == "paged":
             from deepspeed_tpu.inference.paging import PagedCacheManager
@@ -285,10 +293,7 @@ class ContinuousBatchingScheduler:
         record("serve/request", comp.arrival_t, comp.finish_t, {
             "rid": comp.rid, "prompt_len": comp.prompt_len,
             "finish_reason": comp.finish_reason,
-            "arrival_t": comp.arrival_t, "submit_t": comp.submit_t,
-            "admit_t": comp.admit_t, "first_token_t": comp.first_token_t,
-            "first_return_t": comp.first_return_t,
-            "token_t": comp.token_t, "finish_t": comp.finish_t})
+            **{k: getattr(comp, k) for k in STAMPS}})
         if self.session is not None:
             self.session.emit(
                 "request_done", rid=comp.rid,
@@ -403,8 +408,7 @@ class ContinuousBatchingScheduler:
             next_pos=len(req.prompt), pending=first,
             generated=[first], admitted_step=self.step_count,
             paging=row, admit_t=admit_t, token_t=[clock()])
-        if self._step_attrs is not None:
-            self._step_attrs["tokens"] += 1
+        self._step_attrs["tokens"] += 1
         self._check_finished(i)
         return True
 
@@ -435,7 +439,6 @@ class ContinuousBatchingScheduler:
                         attrs["pages_resident"] = alloc.resident_pages
                         attrs["pages_total"] = alloc.n_pages - 1
         finally:
-            self._step_attrs = None
             self._stamp_returned()
 
     def _inputs(self, active):

@@ -9,10 +9,9 @@ layers, bench.py, and the ``ds_tpu_metrics`` CLI share:
 - :class:`TelemetrySession` / :func:`get_default_session` — registry +
   event log + span API bundled per run (`session.py`).
 - :func:`null_span` — the telemetry-off no-op fast path (`spans.py`).
-- :class:`Span`, :data:`clock`, :func:`recent`, :func:`record`,
-  :func:`epoch_offset` — the span, the one clock of spans and request
-  stamps, and the process-wide ring every closed span lands in
-  (`spans.py`).
+- :class:`Span` — the span; `spans.py` also has the one clock of spans
+  and request stamps (``spans.clock``) and the process-wide ring every
+  closed span lands in (``spans.recent`` / ``spans.record``).
 - :data:`SCHEMA_VERSION` — the event-log version tag, also embedded in
   ``ds_tpu_audit --json`` so audits and telemetry join (`events.py`).
 - The synchronized timers and the trace-window profiler that moved here
@@ -38,8 +37,7 @@ from deepspeed_tpu.telemetry.registry import (  # noqa: F401
     Counter, Gauge, Histogram, MetricsRegistry)
 from deepspeed_tpu.telemetry.session import (  # noqa: F401
     TelemetrySession, get_default_session, set_default_session)
-from deepspeed_tpu.telemetry.spans import (  # noqa: F401
-    Span, clock, epoch_offset, null_span, recent, record)
+from deepspeed_tpu.telemetry.spans import Span, null_span  # noqa: F401
 from deepspeed_tpu.telemetry.timers import (  # noqa: F401
     SynchronizedWallClockTimer, ThroughputTimer)
 
@@ -61,14 +59,10 @@ __all__ = [
     "TelemetrySession",
     "ThroughputTimer",
     "TraceProfiler",
-    "clock",
     "device_report",
-    "epoch_offset",
     "get_default_session",
     "install_crash_hooks",
     "null_span",
-    "recent",
-    "record",
     "set_default_session",
     "uninstall_crash_hooks",
 ]
