@@ -175,23 +175,30 @@ def test_scan_pld_and_dropout_still_run():
 def test_scan_cuts_compile_wall_and_hlo_size():
     """Measured on CPU at 12 layers: ~0.15x wall, ~0.34x HLO chars.
     Pinned loosely (0.6 / 0.7) to absorb machine noise while still
-    failing if the scan ever silently unrolls."""
+    failing if the scan ever silently unrolls. A wall is the least of up
+    to three compiles: beside five other test workers one compile can
+    take several times its own time, and only a second look tells that
+    from an unrolled scan."""
     batch = _batch()
     walls, chars = {}, {}
-    for name, scan in (("unrolled", False), ("scan", True)):
-        cfg = _cfg(scan)
-        model = GPT2LMHead(cfg)
-        params = init_gpt2_params(model, jax.random.PRNGKey(0))
-        loss_fn = make_gpt2_loss_fn(model)
+    for _ in range(3):
+        for name, scan in (("unrolled", False), ("scan", True)):
+            cfg = _cfg(scan)
+            model = GPT2LMHead(cfg)
+            params = init_gpt2_params(model, jax.random.PRNGKey(0))
+            loss_fn = make_gpt2_loss_fn(model)
 
-        def step(p):
-            return jax.value_and_grad(
-                lambda q: loss_fn(q, batch, jax.random.PRNGKey(1)))(p)
+            def step(p):
+                return jax.value_and_grad(
+                    lambda q: loss_fn(q, batch, jax.random.PRNGKey(1)))(p)
 
-        t0 = time.perf_counter()
-        compiled = jax.jit(step).lower(params).compile()
-        walls[name] = time.perf_counter() - t0
-        chars[name] = len(compiled.as_text())
+            t0 = time.perf_counter()
+            compiled = jax.jit(step).lower(params).compile()
+            wall = time.perf_counter() - t0
+            walls[name] = min(wall, walls.get(name, wall))
+            chars[name] = len(compiled.as_text())
+        if walls["scan"] / walls["unrolled"] < 0.6:
+            break
     assert walls["scan"] / walls["unrolled"] < 0.6, walls
     assert chars["scan"] / chars["unrolled"] < 0.7, chars
 
