@@ -44,7 +44,6 @@ import gc
 import io
 import json
 import os
-import re
 import sys
 import tempfile
 import time
@@ -308,21 +307,12 @@ def write_requests(path, sz, seed):
             }) + "\n")
 
 
-def cache_shaped_copies(hlo_text, spec):
-    """`copy` ops in the program whose result has the shape of one
-    layer's K or V buffer: each is a relayout or a duplicate of the
-    whole cache buffer that the decode step pays for."""
-    shape = ((spec.n_pages, spec.n_head, spec.page_size, spec.head_dim)
-             if spec.paged else
-             (spec.max_batch, spec.max_seq, spec.n_head, spec.head_dim))
-    dims = ",".join(str(d) for d in shape)
-    return len(re.findall(r"= \w+\[%s\]\S* copy\(" % dims, hlo_text))
-
-
 def serve_once(sz, seed, ckpt_dir, req_path, impl, layout, on_tpu):
     """One `ds_tpu_serve --checkpoint` run, in this process."""
     import jax
+    from deepspeed_tpu.analysis.hlo import payload_shaped_copies
     from deepspeed_tpu.inference import serve
+    from deepspeed_tpu.inference.cache import payload_shape
 
     argv = ["--checkpoint", ckpt_dir, "--n-head", str(sz["n_head"]),
             "--max-batch", str(sz["batch"]),
@@ -359,9 +349,15 @@ def serve_once(sz, seed, ckpt_dir, req_path, impl, layout, on_tpu):
           f"{label}: engine ran {eng.attention_impl}/{eng.kv_layout}")
     decode_hlo = eng.decode_hlo()
     kernels = decode_hlo.count("tpu_custom_call")
+    # each one a relayout or a duplicate of a whole K or V buffer
+    copies = len(payload_shaped_copies(decode_hlo,
+                                       payload_shape(eng.spec)))
     if on_tpu:
         check((kernels > 0) == (eng.attention_impl == "flash"),
               f"{label}: decode HLO holds {kernels} tpu_custom_call")
+        if (impl, layout) == ("flash", "paged"):
+            check(copies == 0, f"{label}: the decode step copies the "
+                               f"whole pool {copies} times")
     served = {
         "params": sorted({str(l.dtype) for l in
                           jax.tree_util.tree_leaves(eng.params)}),
@@ -376,8 +372,7 @@ def serve_once(sz, seed, ckpt_dir, req_path, impl, layout, on_tpu):
         decode_steps=result["decode_steps"],
         compile_counts=result["compile_counts"],
         decode_hlo_tpu_custom_calls=kernels,
-        decode_hlo_cache_shaped_copies=cache_shaped_copies(decode_hlo,
-                                                           eng.spec),
+        decode_hlo_cache_shaped_copies=copies,
         served_dtype=served,
         checkpoint=result["checkpoint"], wall_seconds_incl_compile=wall)
     return result, tap, facts

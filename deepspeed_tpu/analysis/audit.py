@@ -714,7 +714,8 @@ def audit_decode(rules=None, config_overrides=None, kv_cache_dtype=None,
     dead cache blocks into elided fetches.
     """
     import jax.numpy as jnp
-    from deepspeed_tpu.inference.cache import cache_dtype_census
+    from deepspeed_tpu.inference.cache import (
+        cache_dtype_census, payload_shape as kv_payload_shape)
     from deepspeed_tpu.inference.engine import InferenceEngine
     from deepspeed_tpu.inference.scheduler import (
         ContinuousBatchingScheduler, Request)
@@ -789,17 +790,13 @@ def audit_decode(rules=None, config_overrides=None, kv_cache_dtype=None,
         kernel_ana, kernel_expected = _kernel_analysis_for(
             engine._decode, engine.decode_lowering_args(), engine)
     census = cache_dtype_census(engine.cache)
+    payload_shape = kv_payload_shape(engine.spec)
+    page_facts = None
     if paged:
-        payload_shape = (engine.spec.n_pages, engine.spec.n_head,
-                         engine.spec.page_size, engine.spec.head_dim)
         page_facts = {"page_size": engine.page_size,
                       "n_pages": engine.n_pages,
                       "pages_per_row": engine.pages_per_row,
                       "max_seq": engine.max_seq}
-    else:
-        payload_shape = (engine.spec.max_batch, engine.spec.max_seq,
-                         engine.spec.n_head, engine.spec.head_dim)
-        page_facts = None
     ctx = StepContext(
         hlo_text=hlo_text, flavor="decode",
         compute_dtype="f32" if cfg.dtype == jnp.float32 else "bf16",
@@ -878,7 +875,8 @@ def audit_speculative(rules=None, config_overrides=None,
     speculation must survive serve churn on each.
     """
     import jax.numpy as jnp
-    from deepspeed_tpu.inference.cache import cache_dtype_census
+    from deepspeed_tpu.inference.cache import (
+        cache_dtype_census, payload_shape as kv_payload_shape)
     from deepspeed_tpu.inference.engine import InferenceEngine
     from deepspeed_tpu.inference.scheduler import (
         ContinuousBatchingScheduler, Request)
@@ -952,17 +950,13 @@ def audit_speculative(rules=None, config_overrides=None,
         draft_flops = _xla_flops(spec._draft, draft_args)
         full_flops = _xla_flops(engine._decode,
                                 engine.decode_lowering_args())
+        payload_shape = kv_payload_shape(engine.spec)
+        page_facts = None
         if layout == "paged":
-            payload_shape = (engine.spec.n_pages, engine.spec.n_head,
-                             engine.spec.page_size, engine.spec.head_dim)
             page_facts = {"page_size": engine.page_size,
                           "n_pages": engine.n_pages,
                           "pages_per_row": engine.pages_per_row,
                           "max_seq": engine.max_seq}
-        else:
-            payload_shape = (engine.spec.max_batch, engine.spec.max_seq,
-                             engine.spec.n_head, engine.spec.head_dim)
-            page_facts = None
         ctx = StepContext(
             hlo_text=draft_hlo, flavor="speculative",
             compute_dtype="f32",
@@ -1051,7 +1045,8 @@ def audit_disagg(rules=None, config_overrides=None):
       the paged pool, cache-dtype census, pool-geometry consistency.
     """
     import jax.numpy as jnp
-    from deepspeed_tpu.inference.cache import cache_dtype_census
+    from deepspeed_tpu.inference.cache import (
+        cache_dtype_census, payload_shape as kv_payload_shape)
     from deepspeed_tpu.inference.disagg import DisaggCoordinator
     from deepspeed_tpu.inference.engine import InferenceEngine
     from deepspeed_tpu.inference.scheduler import Request
@@ -1096,8 +1091,7 @@ def audit_disagg(rules=None, config_overrides=None):
                   for t, e in (("prefill", pre_engine),
                                ("decode", dec_engine))}
     census = cache_dtype_census(dec_engine.cache)
-    payload_shape = (dec_engine.spec.n_pages, dec_engine.spec.n_head,
-                     dec_engine.spec.page_size, dec_engine.spec.head_dim)
+    payload_shape = kv_payload_shape(dec_engine.spec)
     ctx = StepContext(
         hlo_text=hlo_text, flavor="disagg",
         compute_dtype="f32",

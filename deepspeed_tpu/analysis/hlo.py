@@ -750,6 +750,16 @@ def payload_shaped_values(hlo_text, dtype, payload_dims):
     return n
 
 
+def payload_shaped_copies(hlo_text, payload_dims):
+    """``copy`` ops whose result has exactly the shape of one layer's K
+    or V cache buffer: each is a relayout or a duplicate of the whole
+    buffer, paid on every call of the program whatever share of the
+    cache is live. A decode program that updates its donated cache in
+    place, in the layout its attention kernel reads, has none."""
+    dims = ",".join(str(int(d)) for d in payload_dims)
+    return re.findall(r"= \w+\[%s\]\S* copy\([^\n]*" % dims, hlo_text)
+
+
 # Custom-call targets that round-trip through the Python host (jax
 # pure_callback / io_callback / debug.callback lower to these).
 _HOST_CALLBACK_TARGETS = (

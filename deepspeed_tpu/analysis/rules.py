@@ -937,7 +937,12 @@ def rule_flash_decode(ctx):
       the kernel exists to delete is still being paid;
     - with a quantized cache, NO f32 value may be cache-payload-shaped
       (`payload_shaped_values`): such a value is the dense path's
-      dequantized HBM copy — flash dequantizes in-register per block.
+      dequantized HBM copy — flash dequantizes in-register per block;
+    - over the paged pool, NO ``copy`` may have a pool leaf's shape
+      (`payload_shaped_copies`): the pool is donated and written a page
+      slab at a time, in the layout the kernel reads, so such a copy is
+      XLA re-laying out the whole reserved pool on every step, live or
+      not (measured at three quarters of a step: `PERF.md`, PR 25).
     """
     if ctx.decode_attention_impl != "flash":
         return []
@@ -951,8 +956,21 @@ def rule_flash_decode(ctx):
             {"platform": ctx.decode_platform}))
     payload = ctx.decode_cache_payload_shape
     if payload:
-        from deepspeed_tpu.analysis.hlo import (payload_shaped_dots,
+        from deepspeed_tpu.analysis.hlo import (payload_shaped_copies,
+                                                payload_shaped_dots,
                                                 payload_shaped_values)
+        copies = payload_shaped_copies(ctx.hlo_text, payload) \
+            if ctx.decode_kv_layout == "paged" else []
+        if copies:
+            findings.append(Finding(
+                "flash_decode", SEV_ERROR,
+                f"the paged decode program copies the whole pool: "
+                f"{len(copies)} copy op(s) of a pool leaf's shape "
+                f"{tuple(payload)} — a write or a kernel operand that "
+                f"XLA cannot take in the pool's own layout",
+                {"payload_shape": tuple(payload),
+                 "pool_shaped_copies": len(copies),
+                 "copies": copies[:8]}))
         dots = payload_shaped_dots(ctx.hlo_text, payload)
         if dots:
             findings.append(Finding(

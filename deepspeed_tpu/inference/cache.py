@@ -9,11 +9,16 @@ admission/eviction never changes a compiled shape, which is what keeps
 the decode loop at exactly one compile (`engine.compile_counts`).
 
 The **paged** layout (``KVCacheSpec.page_size > 0``) replaces the
-per-row ring with one pool of fixed-size pages per layer, head-major
-``[n_pages, n_head, page_size, head_dim]`` (so the flash kernel cuts a
-``[block_k, head_dim]`` KV block straight out of it — a position-major
-pool would need a block whose second-minor dim is 1, which the TPU
-block rule refuses), addressed through per-row
+per-row ring with one pool of fixed-size pages per layer,
+``[n_pages, n_head, head_dim, page_size]``: a page's positions lie on
+the TPU's 128 lanes and ``head_dim`` on the sublanes, so the array's
+default tiled layout is the one the flash kernel's ``[head_dim,
+block_k]`` blocks are cut from, with no lane padding at ``head_dim``
+64 — XLA re-lays nothing out around the kernel. The price is that one
+position is one lane of every tile of its page, so a write reads and
+writes back the page's whole ``[n_head, head_dim, page_size]`` slab
+(:func:`paged_write_kv`), indexing dynamically on the page axis alone.
+The pool is addressed through per-row
 page tables (``[B, pages_per_row]`` int32) that enter the compiled
 programs as plain data. The pool shape and the table shape are both
 static, so page allocation, freeing, prefix sharing and host-tier
@@ -118,17 +123,20 @@ def spec_for_model(cfg, max_batch, max_seq, kv_cache_dtype=None,
         n_pages=n_pages if page_size else 0)
 
 
-def _layer_leaves(spec):
+def payload_shape(spec):
+    """The shape of one layer's K (or V) buffer."""
     if spec.paged:
-        shape = (spec.n_pages, spec.n_head, spec.page_size,
-                 spec.head_dim)
-    else:
-        shape = (spec.max_batch, spec.max_seq, spec.n_head,
-                 spec.head_dim)
+        return (spec.n_pages, spec.n_head, spec.head_dim, spec.page_size)
+    return (spec.max_batch, spec.max_seq, spec.n_head, spec.head_dim)
+
+
+def _layer_leaves(spec):
+    shape = payload_shape(spec)
     leaves = {"k": jnp.zeros(shape, spec.dtype),
               "v": jnp.zeros(shape, spec.dtype)}
     if spec.codec is not None:
-        sshape = shape[:-1]
+        # one scale per (position, head): the payload less head_dim
+        sshape = shape[:2] + shape[3:] if spec.paged else shape[:-1]
         leaves["k_scale"] = jnp.zeros(sshape, jnp.float32)
         leaves["v_scale"] = jnp.zeros(sshape, jnp.float32)
     return leaves
@@ -172,8 +180,8 @@ def kv_partition_specs(spec, model_axis="model"):
     (`models/gpt2.py:gpt2_partition_specs`): each TP shard holds the
     heads it computes, so decode attention runs collective-free and the
     row-parallel ``c_proj`` psum GSPMD inserts is the only combine.
-    The ring keeps heads on axis 2 (``[B, S, H, D]``), the head-major
-    paged pool on axis 1 (``[n_pages, H, page_size, D]``)."""
+    The ring keeps heads on axis 2 (``[B, S, H, D]``), the paged pool
+    on axis 1 (``[n_pages, H, D, page_size]``)."""
     from jax.sharding import PartitionSpec as P
     lead = (None,) if spec.stacked else ()
     # no trailing None after the sharded head axis: jit keys compiled
@@ -281,11 +289,11 @@ def attention_mask(layer_cache, positions, page_table=None):
     callers running several layers per step (`models/gpt2.py`) can
     compute it ONCE and pass it down — rebuilt per layer it is the
     compiled decode program's only per-layer iota. With a paged cache
-    the buffer no longer carries the sequence length (``shape[-2]`` is
+    the buffer no longer carries the sequence length (``shape[-1]`` is
     ``page_size``); ``S`` is ``pages_per_row * page_size`` off the page
     table instead — the mask itself is layout-independent."""
     if page_table is not None:
-        S = page_table.shape[-1] * layer_cache["k"].shape[-2]
+        S = page_table.shape[-1] * layer_cache["k"].shape[-1]
     else:
         S = layer_cache["k"].shape[-3]
     return jnp.arange(S)[None, None, :] <= positions[:, :, None]
@@ -295,27 +303,64 @@ def attention_mask(layer_cache, positions, page_table=None):
 # paged pool ops
 # ---------------------------------------------------------------------------
 
+def _write_tokens(layer_cache, new, pages, offs):
+    """Token ``i``'s ``new[leaf][i]`` (``[H, D]`` payload or ``[H]``
+    scale) becomes position ``offs[i]`` of page ``pages[i]`` of each
+    leaf of ``layer_cache`` (``[n_pages, H, (D,) page_size]``), one
+    token after the other, all leaves in one loop.
+
+    A position is one lane of every tile of its page, so each token
+    reads its page's slab, replaces the one lane, and writes the slab
+    back: the pool is indexed dynamically on the page axis only, which
+    XLA updates in place, in the pool's own tiled layout. (A scatter on
+    the position axis makes XLA copy the whole pool out of that layout
+    and back; `tests/unit/test_tpu_compile.py` holds the count at 0.)
+    """
+    hit = jnp.arange(layer_cache["k"].shape[-1]) == offs[:, None]
+    new = {name: vals.astype(layer_cache[name].dtype)[..., None]
+           for name, vals in new.items()}
+
+    def one(i, leaves):
+        def write(buf, vals):
+            slab = jax.lax.dynamic_index_in_dim(buf, pages[i], 0)
+            slab = jnp.where(hit[i], vals[i], slab)
+            return jax.lax.dynamic_update_index_in_dim(buf, slab,
+                                                       pages[i], 0)
+        return {name: write(buf, new[name])
+                for name, buf in leaves.items()}
+
+    return jax.lax.fori_loop(0, offs.shape[0], one, layer_cache)
+
+
+def _write_chunk(buf, vals, page, off):
+    """One row's chunk ``vals`` (``[T, H, (D)]``, contiguous positions
+    from ``off``, all inside ``page``) into ``buf``: the chunk goes into
+    the page's slab, the slab back into the pool on the page axis."""
+    slab = jax.lax.dynamic_index_in_dim(buf, page, 0)
+    chunk = jnp.moveaxis(vals, 0, -1).astype(buf.dtype)[None]
+    slab = jax.lax.dynamic_update_slice_in_dim(slab, chunk, off, -1)
+    return jax.lax.dynamic_update_index_in_dim(buf, slab, page, 0)
+
+
 def paged_write_kv(layer_cache, k_new, v_new, positions, page_table):
     """Write one chunk's keys/values into the page pool through a
-    page table. ``layer_cache`` holds head-major ``[n_pages, H,
-    page_size, D]`` pool leaves (scales ``[n_pages, H, page_size]``);
+    page table. ``layer_cache`` holds ``[n_pages, H, D, page_size]``
+    pool leaves (scales ``[n_pages, H, page_size]``);
     ``page_table`` is ``[B, pages_per_row]`` int32 of
     physical page ids (0 = trash for unallocated slots); positions are
     contiguous per row as in :func:`write_kv`. Two shapes exist:
 
-    - decode (``T == 1``): a scatter of one ``[H, D]`` vector per row
-      at ``(table[b, p // page_size], p % page_size)``. Inactive rows
-      sit at position 0 with table entry 0 and collide harmlessly on
-      the trash page.
-    - prefill (``B == 1``): one ``dynamic_update_slice`` of the whole
-      chunk into a single page — the engine pins ``page_size %
+    - prefill (``B == 1``): the whole chunk into a single page
+      (:func:`_write_chunk`) — the engine pins ``page_size %
       prefill_chunk == 0`` so a chunk never straddles pages.
-    - speculative verify (``B > 1, T > 1``): a general advanced-index
-      scatter — each (row, step) token resolves its own (page, slot)
-      through the table, so a chunk MAY straddle a page boundary.
-      Positions past a row's allocated pages hit table entry 0 and
-      land on the trash page (rejected-tail rollback: those writes are
-      garbage by construction and never become visible).
+    - decode (``T == 1``) and speculative verify (``B > 1, T > 1``):
+      token by token (:func:`_write_tokens`) — each (row, step) token
+      resolves its own (page, slot) through the table, so a verify
+      chunk MAY straddle a page boundary. Inactive decode rows sit at
+      position 0 with table entry 0 and collide harmlessly on the
+      trash page; positions past a row's allocated pages hit table
+      entry 0 and land there too (rejected-tail rollback: those writes
+      are garbage by construction and never become visible).
 
     Quantization on the way in mirrors :func:`write_kv`: the pool's
     per-(page, slot, head) scales are exactly the ring's per-(row,
@@ -323,48 +368,26 @@ def paged_write_kv(layer_cache, k_new, v_new, positions, page_table):
     the flash kernel's fused dequant carry over unchanged.
     """
     codec = _codec_of(layer_cache)
-    page_size = layer_cache["k"].shape[-2]
+    page_size = layer_cache["k"].shape[-1]
     B, T = positions.shape
-    start = positions[:, 0]
+    pages = jnp.take_along_axis(page_table, positions // page_size,
+                                axis=1)                     # [B, T]
+    offs = positions % page_size
 
-    # ``vals`` arrive position-major ([B, T, H, D] payloads, [B, T, H]
-    # scales); the pool's (page, slot) pair brackets the head axis, so
-    # the advanced indices sit either side of a full ``:`` slice (numpy
-    # then puts the indexed dims first — the layout ``vals`` has).
-    if T == 1:
-        pp = jnp.take_along_axis(
-            page_table, (start // page_size)[:, None], axis=1)[:, 0]
-        off = start % page_size
-
-        def scatter(buf, vals):
-            return buf.at[pp, :, off].set(vals[:, 0].astype(buf.dtype))
-    elif B == 1:
-        pp = page_table[0, start[0] // page_size]
-        off = start[0] % page_size
-
-        def scatter(buf, vals):
-            idx = (pp, 0, off) + (0,) * (buf.ndim - 3)
-            return jax.lax.dynamic_update_slice(
-                buf, jnp.swapaxes(vals, 1, 2).astype(buf.dtype), idx)
-    else:
-        pages = jnp.take_along_axis(
-            page_table, positions // page_size, axis=1)     # [B, T]
-        offs = positions % page_size
-
-        def scatter(buf, vals):
-            return buf.at[pages, :, offs].set(vals.astype(buf.dtype))
-
-    if codec is None:
-        return {"k": scatter(layer_cache["k"], k_new),
-                "v": scatter(layer_cache["v"], v_new)}
-    k_q, k_s = _quantize(k_new, codec)
-    v_q, v_s = _quantize(v_new, codec)
-    return {
-        "k": scatter(layer_cache["k"], k_q),
-        "v": scatter(layer_cache["v"], v_q),
-        "k_scale": scatter(layer_cache["k_scale"], k_s),
-        "v_scale": scatter(layer_cache["v_scale"], v_s),
-    }
+    # position-major: [B, T, H, D] payloads, [B, T, H] scales
+    new = {"k": k_new, "v": v_new}
+    if codec is not None:
+        new["k"], new["k_scale"] = _quantize(k_new, codec)
+        new["v"], new["v_scale"] = _quantize(v_new, codec)
+    if B == 1:
+        return {name: _write_chunk(layer_cache[name], vals[0],
+                                   pages[0, 0], offs[0, 0])
+                for name, vals in new.items()}
+    return _write_tokens(
+        layer_cache,
+        {name: vals.reshape((B * T,) + vals.shape[2:])
+         for name, vals in new.items()},
+        pages.reshape(B * T), offs.reshape(B * T))
 
 
 def paged_read_kv(layer_cache, page_table, dtype):
@@ -376,8 +399,8 @@ def paged_read_kv(layer_cache, page_table, dtype):
     codec = _codec_of(layer_cache)
 
     def gather(buf):
-        g = jnp.take(buf, page_table, axis=0)   # [B, n_pt, H, ps, ...]
-        g = jnp.swapaxes(g, 2, 3)               # [B, n_pt, ps, H, ...]
+        g = jnp.take(buf, page_table, axis=0)   # [B, n_pt, H, (D,) ps]
+        g = jnp.moveaxis(g, -1, 2)              # [B, n_pt, ps, H, (D)]
         B, n_pt, ps = g.shape[:3]
         return g.reshape((B, n_pt * ps) + g.shape[3:])
 
@@ -425,10 +448,10 @@ def _flash_attend_paged(q, layer_cache, positions, page_table, block_k,
     """Paged twin of :func:`_flash_attend`: the kernel gathers KV
     blocks straight out of the pool through the scalar-prefetched page
     table (`ops/pallas/flash_decode.py:flash_decode_paged`) — this
-    code gathers and transposes nothing (the relayouts XLA adds around
-    the call on the chip are in `PERF.md`, PR 21). Under TP the head-major
-    pool shards on axis 1 (`kv_partition_specs`); the query and the
-    output keep the model's ``[B, 1, H, D]`` layout."""
+    code gathers and transposes nothing, and neither does XLA around
+    the call: the pool's tiled layout is the kernel's. Under TP the
+    pool shards on its head axis 1 (`kv_partition_specs`); the query
+    and the output keep the model's ``[B, 1, H, D]`` layout."""
     from deepspeed_tpu.ops.pallas import flash_decode_paged
 
     pos = positions[:, 0]

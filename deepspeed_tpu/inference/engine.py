@@ -216,11 +216,12 @@ class InferenceEngine:
             # for this device is a typed error here, at build time
             from deepspeed_tpu.ops.pallas.flash_decode import (
                 check_decode_geometry)
-            extent, name = (self.page_size, "page_size") \
-                if self.kv_layout == "paged" else (self.max_seq, "max_seq")
+            paged = self.kv_layout == "paged"
+            extent, name = (self.page_size, "page_size") if paged \
+                else (self.max_seq, "max_seq")
             self.attention_block_k = check_decode_geometry(
                 self.attention_block_k, extent, name, self.spec.dtype,
-                self.spec.codec is not None)
+                paged or self.spec.codec is not None)
         self.mesh = mesh
         self.session = session
         self._sample_key = jax.random.PRNGKey(self.sampling_seed)
@@ -466,7 +467,7 @@ class InferenceEngine:
 
     def gather_pages(self, page_ids):
         """Snapshot the given physical pages to host RAM: a per-layer
-        ``{"k": [n, H, page_size, D], ...}`` numpy pytree, copied with
+        ``{"k": [n, H, D, page_size], ...}`` numpy pytree, copied with
         the hot-checkpoint snapshot-isolation discipline
         (`runtime/resilience/hotckpt.py:_snapshot_to_host` — the
         compiled steps donate the pool, so host views must never alias

@@ -39,13 +39,15 @@ FlashDecoding-style fix, specialized for the ring buffer:
   the map only ever dereferences table entries the row has actually
   filled — dead and unallocated pages never cost a DMA, the paged
   generalization of the ring kernel's block skipping. The pool is
-  HEAD-MAJOR (``[n_pages, H, page_size, D]``): with (page, head) merged
-  into one leading dim it IS the ring kernel's folded layout, so a KV
-  block is cut from it as ``[1, block_k, D]`` — last two dims obey the
-  TPU (8, 128) block rule — and this file transposes nothing. (What
-  XLA does around the call is another matter: at D = 64 the TPU keeps
-  the pool positions-minor in HBM and re-lays it out for the kernel —
-  `PERF.md`, PR 21.)
+  ``[n_pages, H, D, page_size]``, positions on the lanes: with (page,
+  head) merged into one leading dim a KV block is cut from it as
+  ``[1, D, block_k]``, the transpose of the ring kernel's ``[1,
+  block_k, D]``, and the one kernel body contracts whichever it was
+  handed. At D = 64 that order, unlike the ring's, fills whole (8, 128)
+  tiles, so the pool's default layout in HBM is the kernel's and XLA
+  copies nothing around the call (`tests/unit/test_tpu_compile.py`
+  holds it at 0 pool-shaped copies; 3 per leaf before, `PERF.md`,
+  PR 25).
 
 Both kernels compile for the chip (`tests/unit/test_tpu_compile.py`
 pins that against a described v5e). The online-softmax running max/sum
@@ -91,7 +93,7 @@ class KernelGeometryError(ValueError):
 
 
 def _validate_block_k(block_k, extent, extent_name, kv_dtype, interpret,
-                      quant=False):
+                      lanes=False):
     """Clamp and validate ``block_k`` against the KV extent it tiles.
 
     ``extent`` is ``max_seq`` for the ring layout and ``page_size``
@@ -99,8 +101,9 @@ def _validate_block_k(block_k, extent, extent_name, kv_dtype, interpret,
     sublane-tile check only gates the COMPILED path (``interpret``
     False, i.e. a real TPU lowering where Mosaic's tiling constraints
     bite on sub-tile quantized blocks); interpret-mode CPU runs accept
-    any divisor so CI toys stay small. ``quant`` adds the lane rule for
-    the scale rows of a codec cache.
+    any divisor so CI toys stay small. ``lanes`` adds the lane rule:
+    some block has ``block_k`` as its minor dim — the scale rows of a
+    codec cache, and every block of the paged pool.
     """
     block_k = int(block_k)
     if block_k < 1:
@@ -119,16 +122,16 @@ def _validate_block_k(block_k, extent, extent_name, kv_dtype, interpret,
             f"compiled kernel would pad every KV block to full "
             f"register tiles; pick a multiple of {tile} (or cover the "
             f"whole {extent_name})")
-    if not interpret and quant and block_k % _LANES and block_k != extent:
+    if not interpret and lanes and block_k % _LANES and block_k != extent:
         raise KernelGeometryError(
-            f"attention block_k {block_k} over a quantized cache must "
-            f"be a multiple of {_LANES} (or cover the whole "
-            f"{extent_name} {extent}): the scales stream lane-major, "
-            f"one [1, block_k] row per KV block")
+            f"attention block_k {block_k} over a quantized cache or a "
+            f"paged pool must be a multiple of {_LANES} (or cover the "
+            f"whole {extent_name} {extent}): scales and pool pages "
+            f"stream lane-major, block_k positions to a row")
     return block_k
 
 
-def check_decode_geometry(block_k, extent, extent_name, kv_dtype, quant):
+def check_decode_geometry(block_k, extent, extent_name, kv_dtype, lanes):
     """The call-time block validation, for the device this process
     compiles for — so the serving engine refuses, typed, a geometry the
     chip's compiler would refuse when it is BUILT, not at the first
@@ -136,7 +139,7 @@ def check_decode_geometry(block_k, extent, extent_name, kv_dtype, quant):
     the clamped ``block_k``."""
     interpret = jax.devices()[0].platform != "tpu"
     return _validate_block_k(block_k, extent, extent_name, kv_dtype,
-                             interpret, quant)
+                             interpret, lanes)
 
 
 def _fold_heads(x):
@@ -153,10 +156,13 @@ def _flash_decode_kernel(H, D, block_k, n_kb, quant, paged=False):
     and sum [1, 1]) across the sequential kv-block dim — all three are
     read and written whole, as vectors. The paged variant carries the
     page tables as a second scalar-prefetch arg — consumed ONLY by the
-    index maps: the body's math is identical, a KV block is a KV block
-    wherever it was fetched from, and both layouts hand it over as
-    ``(1, bk, D)`` with its scales as a ``(1, 1, bk)`` row.
+    index maps: a KV block is a KV block wherever it was fetched
+    from. The two layouts differ in the block's memory order, ``(1, bk,
+    D)`` from the ring and ``(1, D, bk)`` from the paged pool, so the
+    two dots contract the block's D (keys) or position (values) axis
+    where this layout has it; scales are a ``(1, 1, bk)`` row in both.
     """
+    d_axis, pos_axis = (0, 1) if paged else (1, 0)
 
     def kernel(pos_ref, *all_refs):
         refs = list(all_refs)
@@ -186,9 +192,9 @@ def _flash_decode_kernel(H, D, block_k, n_kb, quant, paged=False):
         @pl.when(run)
         def _compute():
             qb = q_ref[0].astype(jnp.float32)              # [1, D]
-            kb = k_ref[0].astype(jnp.float32)              # [bk, D]
+            kb = k_ref[0].astype(jnp.float32)      # [bk, D] | [D, bk]
             s = jax.lax.dot_general(
-                qb, kb, (((1,), (1,)), ((), ())),
+                qb, kb, (((1,), (d_axis,)), ((), ())),
                 preferred_element_type=jnp.float32)        # [1, bk]
             if quant:
                 # fused dequant: scale the SCORES by the key scales
@@ -210,9 +216,9 @@ def _flash_decode_kernel(H, D, block_k, n_kb, quant, paged=False):
             if quant:
                 # value scales fold into the probs the same way
                 pr = pr * vs_ref[0]
-            vb = v_ref[0].astype(jnp.float32)              # [bk, D]
+            vb = v_ref[0].astype(jnp.float32)      # [bk, D] | [D, bk]
             acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-                pr, vb, (((1,), (0,)), ((), ())),
+                pr, vb, (((1,), (pos_axis,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
         @pl.when(ki == n_kb - 1)
@@ -317,7 +323,7 @@ def flash_decode_paged(q, k, v, positions, page_tables, k_scale=None,
     """Split-K flash decode over a paged KV pool.
 
     ``q``: ``[B, 1, H, D]`` as in :func:`flash_decode`. ``k``/``v``:
-    the head-major POOL buffers ``[n_pages, H, page_size, D]`` in
+    the POOL buffers ``[n_pages, H, D, page_size]`` in
     storage dtype (scales ``[n_pages, H, page_size]`` when quantized —
     `inference/cache.py` paged layout). ``page_tables``: ``[B,
     pages_per_row]`` int32 physical page ids per row (entry 0 = the
@@ -333,7 +339,7 @@ def flash_decode_paged(q, k, v, positions, page_tables, k_scale=None,
     never straddles a page boundary, which is what keeps the gather a
     single block index per grid step.
     """
-    n_pages, H, page_size, D = k.shape
+    n_pages, H, D, page_size = k.shape
     B = q.shape[0]
     if q.shape != (B, 1, H, D):
         raise ValueError(
@@ -349,8 +355,9 @@ def flash_decode_paged(q, k, v, positions, page_tables, k_scale=None,
     if (k_scale is None) != (v_scale is None):
         raise ValueError("pass both k_scale and v_scale or neither")
     quant = k_scale is not None
+    # positions are the lane axis of every block, scales or not
     block_k = _validate_block_k(block_k, page_size, "page_size",
-                                k.dtype, interpret, quant)
+                                k.dtype, interpret, lanes=True)
     n_kb = S // block_k
     bpp = page_size // block_k          # kv-blocks per page
 
@@ -365,29 +372,26 @@ def flash_decode_paged(q, k, v, positions, page_tables, k_scale=None,
         kc = jnp.minimum(ki, pos_ref[bh // H] // block_k)
         return pt_ref[bh // H, kc // bpp], kc % bpp
 
-    # the head-major pool with (page, head) merged into one leading dim
-    # is the ring kernel's folded layout: the same blocks, found through
-    # the page table. K and V keep their last two extents, so the merge
+    # (page, head) merge into one leading dim, as the ring kernel folds
+    # (row, head). K and V keep their last two extents, so the merge
     # moves no byte; a scale pool becomes one [1, page_size] row per
-    # (page, head), which XLA re-tiles (1/16 of the int8 pool's bytes)
+    # (page, head), which XLA re-tiles (1/16 of the int8 pool's bytes).
+    # Payload and scale blocks share one map: both are (page * H + head,
+    # 0, block within the page)
     def kv_map(bh, ki, pos_ref, pt_ref):
-        page, intra = _physical(bh, ki, pos_ref, pt_ref)
-        return (page * H + bh % H, intra, 0)
-
-    def sc_map(bh, ki, pos_ref, pt_ref):
         page, intra = _physical(bh, ki, pos_ref, pt_ref)
         return (page * H + bh % H, 0, intra)
 
     in_specs = [
         pl.BlockSpec((1, 1, D), q_map),
-        pl.BlockSpec((1, block_k, D), kv_map),
-        pl.BlockSpec((1, block_k, D), kv_map),
+        pl.BlockSpec((1, D, block_k), kv_map),
+        pl.BlockSpec((1, D, block_k), kv_map),
     ]
-    args = [qh, k.reshape(n_pages * H, page_size, D),
-            v.reshape(n_pages * H, page_size, D)]
+    args = [qh, k.reshape(n_pages * H, D, page_size),
+            v.reshape(n_pages * H, D, page_size)]
     if quant:
-        in_specs += [pl.BlockSpec((1, 1, block_k), sc_map),
-                     pl.BlockSpec((1, 1, block_k), sc_map)]
+        in_specs += [pl.BlockSpec((1, 1, block_k), kv_map),
+                     pl.BlockSpec((1, 1, block_k), kv_map)]
         args += [k_scale.reshape(n_pages * H, 1, page_size),
                  v_scale.reshape(n_pages * H, 1, page_size)]
 

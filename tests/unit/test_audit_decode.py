@@ -139,6 +139,27 @@ class TestRuleFlashDecode:
                           decode_cache_payload_shape=_PAYLOAD)
         assert rule_flash_decode(ctx) == []
 
+    @pytest.mark.parametrize("layout,hlo,n", [
+        # XLA's relayout of the pool, layout and tiling after the shape
+        ("paged", "%copy.7 = f32[2,32,4,8]{3,1,2,0:T(8,128)} copy(%p)\n"
+                  "%copy.8 = f32[2,32,4,8]{2,3,1,0:T(8,128)} copy(%copy.7)",
+         2),
+        # other shapes, other ops of that shape, and the ring are not it
+        ("paged", "%copy.1 = f32[2,32,4]{2,1,0} copy(%s)\n"
+                  "%copy.2 = f32[8,32,8]{2,1,0} copy(%m)\n"
+                  "%fusion.3 = f32[2,32,4,8]{3,2,1,0} fusion(%p)", 0),
+        ("ring", "%copy.7 = f32[2,32,4,8]{3,1,2,0} copy(%p)", 0),
+    ], ids=["relayout", "not-the-pool", "ring"])
+    def test_pool_shaped_copy_is_error_when_paged(self, layout, hlo, n):
+        ctx = StepContext(hlo_text=hlo, decode_attention_impl="flash",
+                          decode_kv_layout=layout,
+                          decode_cache_payload_shape=_PAYLOAD)
+        fs = rule_flash_decode(ctx)
+        assert len(fs) == (1 if n else 0)
+        if n:
+            assert fs[0].severity == "error"
+            assert fs[0].details["pool_shaped_copies"] == n
+
     def test_missing_custom_call_only_errors_on_tpu(self):
         ctx_cpu = StepContext(hlo_text=_BLOCK_DOT,
                               decode_attention_impl="flash",

@@ -280,9 +280,10 @@ def test_kernel_traffic_flips_block_k_ranking(paged_report):
     # pricing flips the ranking to block_k=4.
     bk4 = audit_decode(kernels=True, kv_layout="paged",
                        config_overrides={"attention_block_k": 4})
-    # the head-major pool cuts [block_k, D] KV blocks, so a 4-row f32
-    # block is honestly sub-tile (8 sublanes) and the lint says so; the
-    # interpret-mode toy still runs it, which is all the pricing needs
+    # the pool cuts [D, block_k] KV blocks, positions on the lanes, so
+    # a 4-position block of an 8-position page is honestly sub-tile and
+    # the lint says so; the interpret-mode toy still runs it, which is
+    # all the pricing needs
     assert {f.rule for f in bk4.findings} <= {"kernel_tiling"}
     assert all(f.severity == "warning" for f in bk4.findings)
     f4, f8 = _cost_facts(bk4), _cost_facts(paged_report)
@@ -332,22 +333,25 @@ def test_flash_decode_geometry_errors():
         flash_decode(q, k, v, pos, block_k=12)
 
     # paged: block_k must divide page_size, validated before lowering
-    n_pages, page_size, ppr = 5, 8, 2
-    pool_k = jnp.zeros((n_pages, H, page_size, D), jnp.float32)
-    pool_v = jnp.zeros((n_pages, H, page_size, D), jnp.float32)
+    n_pages, page_size, ppr = 5, 16, 2
+    pool_k = jnp.zeros((n_pages, H, D, page_size), jnp.float32)
+    pool_v = jnp.zeros((n_pages, H, D, page_size), jnp.float32)
     tables = jnp.zeros((B, ppr), jnp.int32)
     with pytest.raises(KernelGeometryError, match="multiple"):
         flash_decode_paged(q, pool_k, pool_v, pos, tables, block_k=3)
 
     # compiled-only rules (interpret=False is what a TPU build checks;
     # the engine runs the same check when it is built): sub-tile blocks,
-    # and quantized scale rows that are not whole 128-lane rows
+    # and blocks with positions on the lanes (quantized scale rows, every
+    # block of the paged pool) that are not whole 128-lane rows
     from deepspeed_tpu.ops.pallas.flash_decode import _validate_block_k
     assert _validate_block_k(4, 16, "max_seq", jnp.float32, True) == 4
     with pytest.raises(KernelGeometryError, match="sublane tile"):
         _validate_block_k(4, 16, "max_seq", jnp.float32, False)
     with pytest.raises(KernelGeometryError, match="multiple of 128"):
         _validate_block_k(64, 256, "page_size", jnp.int8, False, True)
+    with pytest.raises(KernelGeometryError, match="multiple of 128"):
+        _validate_block_k(64, 256, "page_size", jnp.float32, False, True)
     assert _validate_block_k(64, 64, "page_size", jnp.int8, False,
                              True) == 64
     assert _validate_block_k(128, 1024, "max_seq", jnp.int8, False,

@@ -14,8 +14,10 @@ dry pool makes ``admit`` return None without leaking references.
 The jax end pins the paged pool's static geometry
 (`cache.spec_for_model`: trash-page minimum, divisibility, ring-
 capacity default) and the `rule_decode` paged contract (host-transfer
-ops and degenerate page geometry are errors). Numerics ride
-`test_paged_parity.py`.
+ops and degenerate page geometry are errors), and holds the pool's one
+memory order (`[n_pages, H, D, page_size]`, written a page slab at a
+time) against the ring cache, layer by layer. Whole-model numerics
+ride `test_paged_parity.py`.
 """
 
 import numpy as np
@@ -377,11 +379,127 @@ class TestPagedSpec:
     def test_pool_shape_and_quantized_scales(self):
         from deepspeed_tpu.inference.cache import (init_kv_cache,
                                                    spec_for_model)
-        spec = spec_for_model(self._cfg(), 2, 32, "int8", page_size=8)
+        spec = spec_for_model(self._cfg(), 2, 32, "int8", page_size=16)
         cache = init_kv_cache(spec)
-        # head-major pool: [n_pages, n_head, page_size, head_dim]
-        assert cache["h_0"]["k"].shape == (9, 4, 8, 8)
-        assert cache["h_0"]["k_scale"].shape == (9, 4, 8)
+        # positions minor-most: [n_pages, n_head, head_dim, page_size]
+        assert cache["h_0"]["k"].shape == (5, 4, 8, 16)
+        assert cache["h_0"]["k_scale"].shape == (5, 4, 16)
+
+
+# ---------------------------------------------------------------------------
+# the pool against the ring cache, one layer
+# ---------------------------------------------------------------------------
+
+PAGE, SEQ, HEADS, DIM = 128, 256, 2, 8
+STORAGE = {"float32": (np.float32, None), "bfloat16": ("bfloat16", None),
+           "int8": (np.int8, "int8")}
+
+
+def _ring_and_pool(storage, tables, rng):
+    """A ring layer filled with random keys and values at every
+    position, and the pool that holds the same bytes under ``tables``
+    (laid out here with numpy, not by the code under test). Pages that
+    no table names, the trash page among them, hold garbage."""
+    import jax.numpy as jnp
+    from deepspeed_tpu.inference import cache
+
+    dtype, codec = STORAGE[storage]
+    B = tables.shape[0]
+    shape = (B, SEQ, HEADS, DIM)
+    ring = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    if codec:
+        ring["k_scale"] = ring["v_scale"] = jnp.zeros(shape[:-1],
+                                                      jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal(shape), jnp.float32)
+            for _ in range(2))
+    ring = cache.write_kv(ring, k, v,
+                          jnp.broadcast_to(jnp.arange(SEQ), (B, SEQ)))
+    n_pages = int(tables.max()) + 2
+    pool = {}
+    for name, leaf in ring.items():
+        leaf = np.asarray(leaf)
+        buf = rng.standard_normal(
+            (n_pages,) + leaf.shape[2:] + (PAGE,)).astype(leaf.dtype)
+        for b, j in np.ndindex(*tables.shape):
+            if tables[b, j]:
+                buf[tables[b, j]] = np.moveaxis(
+                    leaf[b, j * PAGE:(j + 1) * PAGE], 0, -1)
+        pool[name] = jnp.asarray(buf)
+    return ring, pool
+
+
+def _assert_same_bytes(ring, pool, tables, rows):
+    """Each named row's pages hold exactly the ring row's bytes."""
+    for name, leaf in ring.items():
+        leaf, buf = np.asarray(leaf), np.asarray(pool[name])
+        for b in rows:
+            for j, page in enumerate(tables[b]):
+                np.testing.assert_array_equal(
+                    np.moveaxis(buf[page], -1, 0),
+                    leaf[b, j * PAGE:(j + 1) * PAGE], err_msg=name)
+
+
+@pytest.mark.parametrize("storage", list(STORAGE))
+class TestPoolAgainstRing:
+    def _attend(self, layer, x, positions, impl, tables=None):
+        import jax.numpy as jnp
+        from deepspeed_tpu.inference import cache
+        q, k, v = x
+        return cache.cached_attention(
+            q, k, v, layer, jnp.asarray(positions), jnp.float32,
+            impl=impl, block_k=PAGE,
+            page_table=None if tables is None else jnp.asarray(tables))
+
+    def _new(self, rng, rows, tokens):
+        import jax.numpy as jnp
+        return [jnp.asarray(rng.standard_normal((rows, tokens, HEADS, DIM)),
+                            jnp.float32) for _ in range(3)]
+
+    @pytest.mark.parametrize("impl", ["dense", "flash"])
+    def test_decode_writes_land_where_the_ring_has_them(self, storage,
+                                                        impl):
+        # in-page offsets 0, 1 and 127, the first slot past a page
+        # boundary, the last slot of a row; rows 5 and 6 are inactive:
+        # position 0 through a table of zeros, both on the trash page
+        rng = np.random.default_rng(0)
+        positions = np.array([0, 1, 127, 128, 255, 0, 0])[:, None]
+        tables = np.array([[1, 2], [3, 4], [5, 6], [7, 8], [9, 10],
+                           [0, 0], [0, 0]], np.int32)
+        ring, pool = _ring_and_pool(storage, tables, rng)
+        x = self._new(rng, 7, 1)
+        want, ring = self._attend(ring, x, positions, "dense")
+        got, pool = self._attend(pool, x, positions, impl, tables)
+        np.testing.assert_allclose(got[:5], want[:5], atol=2e-6, rtol=1e-5)
+        _assert_same_bytes(ring, pool, tables, range(5))
+
+    def test_speculative_chunk_straddles_a_page(self, storage):
+        # row 0 writes 126..129 over the boundary, row 2's chunk runs
+        # off its one allocated page onto the trash page
+        rng = np.random.default_rng(1)
+        positions = np.array([126, 0, 125])[:, None] + np.arange(4)
+        tables = np.array([[1, 2], [3, 4], [5, 0]], np.int32)
+        ring, pool = _ring_and_pool(storage, tables, rng)
+        x = self._new(rng, 3, 4)
+        want, ring = self._attend(ring, x, positions, "dense")
+        got, pool = self._attend(pool, x, positions, "dense", tables)
+        np.testing.assert_allclose(got[:2], want[:2], atol=2e-6, rtol=1e-5)
+        # row 2 sees its own page only up to 127; 128 is on the trash
+        np.testing.assert_allclose(got[2, :3], want[2, :3], atol=2e-6,
+                                   rtol=1e-5)
+        _assert_same_bytes(ring, pool, tables, range(2))
+        _assert_same_bytes(ring, pool, tables[:, :1], [2])
+
+    @pytest.mark.parametrize("start", [0, 64, 192])
+    def test_prefill_chunk_into_one_page(self, storage, start):
+        rng = np.random.default_rng(2)
+        positions = start + np.arange(64)[None]
+        tables = np.array([[2, 1]], np.int32)
+        ring, pool = _ring_and_pool(storage, tables, rng)
+        x = self._new(rng, 1, 64)
+        want, ring = self._attend(ring, x, positions, "dense")
+        got, pool = self._attend(pool, x, positions, "dense", tables)
+        np.testing.assert_allclose(got, want, atol=2e-6, rtol=1e-5)
+        _assert_same_bytes(ring, pool, tables, [0])
 
 
 # ---------------------------------------------------------------------------

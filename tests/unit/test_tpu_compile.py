@@ -24,6 +24,8 @@ from jax.sharding import SingleDeviceSharding
 
 B, T, H, D = 8, 1024, 16, 64          # GPT-2 350M: 16 heads x 64
 PAGE = 128                            # chip_smoke.py's page size
+# the serve cell's pool (`benchmarks/suite`): 48 rows of 8 pages + trash
+ROWS, N_PAGES = 48, 385
 
 
 @pytest.fixture(scope="module")
@@ -105,7 +107,7 @@ def test_flash_decode_compiles(chip, layout, kv_dtype):
         args = (q, kv, kv, pos) + scales
     else:
         n_pages = B * (T // PAGE) + 1
-        kv = chip((n_pages, H, PAGE, D), dt)
+        kv = chip((n_pages, H, D, PAGE), dt)
         tables = chip((B, T // PAGE), jnp.int32)
         scales = (chip((n_pages, H, PAGE), jnp.float32),) * 2 \
             if quant else ()
@@ -115,6 +117,50 @@ def test_flash_decode_compiles(chip, layout, kv_dtype):
                                       interpret=False)
         args = (q, kv, kv, pos, tables) + scales
     assert "tpu_custom_call" in compiled_text(fn, *args)
+
+
+# (rows, tokens a row): the decode step, one prefill chunk, a
+# speculative verify round
+PAGED_PROGRAMS = {"decode": (ROWS, 1), "prefill": (1, 64),
+                  "verify": (ROWS, 4)}
+
+
+@pytest.mark.parametrize("kv_dtype", KV_DTYPES)
+@pytest.mark.parametrize("program", list(PAGED_PROGRAMS))
+def test_paged_layer_copies_no_pool(chip, monkeypatch, program, kv_dtype):
+    """One layer of the serving programs over the paged pool, as
+    `inference/cache.py:cached_attention` runs it with the pool donated:
+    the write, then the flash kernel (decode) or the gathered view and
+    the dense attention (prefill, verify). The pool stays where it is:
+    no `copy` in the program has the shape of a pool leaf. Positions
+    minor-most in memory and every write indexed on the page axis alone
+    are what hold that."""
+    from deepspeed_tpu.analysis.hlo import payload_shaped_copies
+    from deepspeed_tpu.inference import cache
+
+    _compiled_not_interpreted(monkeypatch,
+                              "deepspeed_tpu.ops.pallas.flash_decode")
+    dt = jnp.dtype(kv_dtype)
+    compute = jnp.float32 if kv_dtype == "float32" else jnp.bfloat16
+    rows, tokens = PAGED_PROGRAMS[program]
+    pool = (N_PAGES, H, D, PAGE)
+    layer = {"k": chip(pool, dt), "v": chip(pool, dt)}
+    if dt.itemsize == 1:
+        layer["k_scale"] = layer["v_scale"] = chip((N_PAGES, H, PAGE),
+                                                   jnp.float32)
+    x = chip((rows, tokens, H, D), compute)
+
+    def fn(q, k, v, layer, positions, tables):
+        return cache.cached_attention(q, k, v, layer, positions, compute,
+                                      impl="flash", page_table=tables)
+
+    text = jax.jit(fn, donate_argnums=3).lower(
+        x, x, x, layer, chip((rows, tokens), jnp.int32),
+        chip((rows, T // PAGE), jnp.int32)).compile().as_text()
+    assert ("tpu_custom_call" in text) == (program == "decode")
+    assert payload_shaped_copies(text, pool) == []
+    # the kernel's view of the pool, (page, head) merged
+    assert payload_shaped_copies(text, (N_PAGES * H, D, PAGE)) == []
 
 
 @pytest.mark.parametrize("shape", [(50257, 1024), (1024, 4096), (1024,)],
@@ -243,12 +289,21 @@ def test_tp_sharded_flash_decode_compiles(topo, monkeypatch, layout):
                                        128, mesh)
         args = (q, kv, kv, positions)
     else:
-        kv = on((B * (T // PAGE) + 1, H, PAGE, D), jnp.bfloat16,
-                None, "model")
+        # the whole layer: GSPMD partitions the write over the pool's
+        # head axis, the kernel runs under `shard_map`
+        n_pages = B * (T // PAGE) + 1
+        kv = on((n_pages, H, D, PAGE), jnp.bfloat16, None, "model")
         tables = on((B, T // PAGE), jnp.int32)
 
         def fn(q, k, v, positions, tables):
-            return cache._flash_attend_paged(
-                q, {"k": k, "v": v}, positions, tables, 128, mesh)
+            return cache.cached_attention(
+                q, q, q, {"k": k, "v": v}, positions, jnp.bfloat16,
+                impl="flash", block_k=128, mesh=mesh, page_table=tables)
         args = (q, kv, kv, positions, tables)
-    assert "tpu_custom_call" in compiled_text(fn, *args)
+    text = jax.jit(fn, donate_argnums=(1, 2)).lower(
+        *args).compile().as_text()
+    assert "tpu_custom_call" in text
+    if layout == "paged":
+        from deepspeed_tpu.analysis.hlo import payload_shaped_copies
+        assert payload_shaped_copies(text, (n_pages, H // 4, D, PAGE)) == []
+        assert "all-gather" not in text and "all-to-all" not in text
