@@ -518,6 +518,15 @@ def chunked_cross_entropy_sum_and_count(x, wte, labels, chunk,
                                            ignore_index)
 
 
+def next_token_labels(input_ids, ignore_index=-100):
+    """``input_ids`` [B, T] shifted left by one; the last position has
+    nothing to predict and is ignored."""
+    return jnp.concatenate(
+        [input_ids[:, 1:],
+         jnp.full((input_ids.shape[0], 1), ignore_index, input_ids.dtype)],
+        axis=1)
+
+
 def make_gpt2_loss_fn(model: GPT2LMHead):
     """loss_fn(params, batch, rng) for the engine.
 
@@ -529,10 +538,7 @@ def make_gpt2_loss_fn(model: GPT2LMHead):
         input_ids = batch["input_ids"]
         labels = batch.get("labels")
         if labels is None:
-            labels = jnp.concatenate(
-                [input_ids[:, 1:],
-                 jnp.full((input_ids.shape[0], 1), -100, input_ids.dtype)],
-                axis=1)
+            labels = next_token_labels(input_ids)
         rngs = {}
         if rng is not None:
             d_rng, p_rng = jax.random.split(rng)

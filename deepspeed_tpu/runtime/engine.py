@@ -157,8 +157,12 @@ def loss_scale_epilogue(dstate, overflow, fp16, dynamic, scale_args):
         consecutive_skipped=(dstate.consecutive_skipped + 1) * overflow_i32)
 
 
+LOSS_SCALARS = "loss_scalars"     # key of a step's metrics, see below
+
+
 def step_metrics(loss_sum, accum, grad_norm, applied_norm, lr, scale,
-                 overflow, loss_reduce=None, dstate=None, nonfinite=None):
+                 overflow, loss_reduce=None, dstate=None, nonfinite=None,
+                 loss_scalars=None):
     loss = loss_sum / accum
     if loss_reduce is not None:
         loss = loss_reduce(loss)
@@ -177,12 +181,33 @@ def step_metrics(loss_sum, accum, grad_norm, applied_norm, lr, scale,
         out["consecutive_skipped_steps"] = dstate.consecutive_skipped
     if nonfinite is not None:
         out["grad_nonfinite"] = nonfinite
+    if loss_scalars:
+        # what the loss function handed out beside its loss (the mean
+        # over microbatches, as the loss is): on the device until read
+        out[LOSS_SCALARS] = loss_scalars if accum == 1 else \
+            jax.tree_util.tree_map(lambda x: x / accum, loss_scalars)
     return out
+
+
+def loss_and_scalars(out):
+    """What a loss function returned, as ``(loss, scalars)``: it may
+    return its loss alone, or the loss and a dict of scalars (a sparse
+    model's load counters, the terms of its loss) that the train step
+    hands out with the step's metrics."""
+    return out if isinstance(out, tuple) else (out, {})
+
+
+def loss_alone(loss_fn):
+    """``loss_fn`` without the scalars it may hand out."""
+    @functools.wraps(loss_fn)       # keeps direct_value_and_grad[_local]
+    def alone(*args, **kwargs):
+        return loss_and_scalars(loss_fn(*args, **kwargs))[0]
+    return alone
 
 
 def make_grad_accumulator(loss_fn, compute_dtype, accum, constrain=None,
                           cast_params=None, remat_policy=None,
-                          fp8_plan=None):
+                          fp8_plan=None, with_scalars=False):
     """Build ``accumulate(params, batch, rng, scale) -> (loss_sum, grads)``:
     scaled-loss value-and-grad over one microbatch, or a ``lax.scan`` over
     ``accum`` microbatches (batch leading dim = accum). Shared by the dense
@@ -217,7 +242,11 @@ def make_grad_accumulator(loss_fn, compute_dtype, accum, constrain=None,
     update trick in `ops/fp8.py`). Across an accumulation scan the
     per-micro updates combine elementwise via ``jnp.maximum``: every
     micro sees the same input histories, so the max over their slot-0
-    amaxes is the step's amax and the older slots agree."""
+    amaxes is the step's amax and the older slots agree.
+
+    ``with_scalars``: ``loss_fn`` may return ``(loss, scalars)``
+    (:func:`loss_and_scalars`), and ``accumulate`` returns the scalars,
+    summed over the microbatches, as one more last element."""
 
     user_caster = cast_params
     if cast_params is None:
@@ -254,20 +283,23 @@ def make_grad_accumulator(loss_fn, compute_dtype, accum, constrain=None,
 
     def micro_grads(params, micro_batch, rng, scale, loss_kwargs,
                     fp8_state=None):
+        """``(loss, grads[, fp8 state], scalars)`` of one microbatch."""
         if direct is not None:
-            return direct(params, micro_batch, rng, scale, **loss_kwargs)
+            return (*direct(params, micro_batch, rng, scale,
+                            **loss_kwargs), {})
 
         arg = params if fp8_state is None else (params, fp8_state)
 
         def scaled_loss(p):
-            loss = forward(p, micro_batch, rng, loss_kwargs)
-            return loss * scale, loss
-        (_, loss), grads = jax.value_and_grad(
+            loss, scalars = loss_and_scalars(
+                forward(p, micro_batch, rng, loss_kwargs))
+            return loss * scale, (loss, scalars)
+        (_, (loss, scalars)), grads = jax.value_and_grad(
             scaled_loss, has_aux=True)(arg)
         if fp8_state is None:
-            return loss, grads
+            return loss, grads, scalars
         grads, f8_out = grads
-        return loss, grads, f8_out
+        return loss, grads, f8_out, scalars
 
     # The explicit ZeRO-3 caster exposes its SiteRecord registration as
     # a hook to be fired out here, outside the remat/shard_map trace
@@ -276,6 +308,11 @@ def make_grad_accumulator(loss_fn, compute_dtype, accum, constrain=None,
 
     def accumulate(params, batch, rng, scale, loss_kwargs=None,
                    fp8_state=None):
+        out = accumulate_all(params, batch, rng, scale, loss_kwargs,
+                             fp8_state)
+        return out if with_scalars else out[:-1]
+
+    def accumulate_all(params, batch, rng, scale, loss_kwargs, fp8_state):
         if declare_sites is not None and direct is None:
             declare_sites()
         assert (fp8_state is not None) == (
@@ -293,6 +330,9 @@ def make_grad_accumulator(loss_fn, compute_dtype, accum, constrain=None,
         if constrain is not None:
             zeros = constrain(zeros)
 
+        def summed(per_micro):
+            return jax.tree_util.tree_map(lambda x: x.sum(0), per_micro)
+
         if fp8_state is not None:
             # Histories are non-negative amaxes and every micro sees the
             # same input state, so elementwise max over the per-micro
@@ -302,33 +342,34 @@ def make_grad_accumulator(loss_fn, compute_dtype, accum, constrain=None,
             def body_fp8(carry, micro):
                 g_acc, f8_acc, loss_acc, key = carry
                 key, sub = jax.random.split(key)
-                loss, g, f8_new = micro_grads(params, micro, sub, scale,
-                                              loss_kwargs, fp8_state)
+                loss, g, f8_new, scalars = micro_grads(
+                    params, micro, sub, scale, loss_kwargs, fp8_state)
                 g_acc = jax.tree_util.tree_map(jnp.add, g_acc, g)
                 if constrain is not None:
                     g_acc = constrain(g_acc)
                 f8_acc = jax.tree_util.tree_map(jnp.maximum, f8_acc,
                                                 f8_new)
-                return (g_acc, f8_acc, loss_acc + loss, key), None
+                return (g_acc, f8_acc, loss_acc + loss, key), scalars
 
-            (grads, f8_out, loss_sum, _), _ = jax.lax.scan(
+            (grads, f8_out, loss_sum, _), scalars = jax.lax.scan(
                 body_fp8,
                 (zeros, f8_zeros, jnp.asarray(0.0, jnp.float32), rng),
                 batch)
-            return loss_sum, grads, f8_out
+            return loss_sum, grads, f8_out, summed(scalars)
 
         def body(carry, micro):
             g_acc, loss_acc, key = carry
             key, sub = jax.random.split(key)
-            loss, g = micro_grads(params, micro, sub, scale, loss_kwargs)
+            loss, g, scalars = micro_grads(params, micro, sub, scale,
+                                           loss_kwargs)
             g_acc = jax.tree_util.tree_map(jnp.add, g_acc, g)
             if constrain is not None:
                 g_acc = constrain(g_acc)
-            return (g_acc, loss_acc + loss, key), None
+            return (g_acc, loss_acc + loss, key), scalars
 
-        (grads, loss_sum, _), _ = jax.lax.scan(
+        (grads, loss_sum, _), scalars = jax.lax.scan(
             body, (zeros, jnp.asarray(0.0, jnp.float32), rng), batch)
-        return loss_sum, grads
+        return loss_sum, grads, summed(scalars)
 
     return accumulate
 
@@ -414,7 +455,10 @@ class DeepSpeedEngine:
             raise
         self.mesh = mesh if mesh is not None else build_mesh(
             (config.get("mesh") if isinstance(config, dict) else None))
-        self.loss_fn = place_kernels_on_mesh(loss_fn, self.mesh)
+        # `_make_train_step` hands out the scalars a loss function may
+        # return beside its loss; every other program takes the loss alone
+        self._loss_with_scalars = place_kernels_on_mesh(loss_fn, self.mesh)
+        self.loss_fn = loss_alone(self._loss_with_scalars)
         self.dp_world_size = self.mesh.shape["data"]
         self.mp_world_size = self.mesh.shape["model"]
         self._config = DeepSpeedConfig(config, world_size=self.dp_world_size)
@@ -755,6 +799,15 @@ class DeepSpeedEngine:
     def skipped_steps(self):
         return int(self.device_state.skipped_steps)
 
+    @property
+    def step_metrics(self):
+        """The last ``train_batch``'s metrics as the compiled step
+        returned them (``loss``, ``grad_norm``, ``lr``, ...; under
+        ``"loss_scalars"`` what the loss function handed out beside its
+        loss). They stay on the device: reading one waits for the step,
+        and a step nobody reads costs no transfer."""
+        return self._last_metrics
+
     # ------------------------------------------------------------------
     # setup helpers
     # ------------------------------------------------------------------
@@ -1054,7 +1107,7 @@ class DeepSpeedEngine:
         lr_fn = self._lr_fn
         mom_fn = self._mom_fn
         opt_update = self._opt_update
-        loss_fn = self.loss_fn
+        loss_fn = self._loss_with_scalars
         grad_shardings = self._shardings["grad"] if \
             self.zero_optimization_stage() >= 2 else None
         param_shardings = self._shardings["param"]
@@ -1105,7 +1158,8 @@ class DeepSpeedEngine:
                                            constrain=grad_constrain,
                                            cast_params=caster,
                                            remat_policy=remat_policy,
-                                           fp8_plan=fp8_plan)
+                                           fp8_plan=fp8_plan,
+                                           with_scalars=True)
         pld_fn = self._pld_theta_fn()
         detect, nan_skip, fault_on = self._nan_guard_flags()
         self._fault_arg = fault_on
@@ -1117,11 +1171,11 @@ class DeepSpeedEngine:
             loss_kw = {"pld_theta": pld_fn(dstate.global_step)} \
                 if pld_fn is not None else None
             if fp8_state is None:
-                loss_sum, grads = accumulate(params, batch, rng, scale,
-                                             loss_kw)
+                loss_sum, grads, loss_scalars = accumulate(
+                    params, batch, rng, scale, loss_kw)
                 f8_new = None
             else:
-                loss_sum, grads, f8_new = accumulate(
+                loss_sum, grads, f8_new, loss_scalars = accumulate(
                     params, batch, rng, scale, loss_kw, fp8_state)
             if fault_on:
                 grads = jax.tree_util.tree_map(lambda g: g * grad_fault,
@@ -1157,7 +1211,8 @@ class DeepSpeedEngine:
                                              scale_args)
             metrics = step_metrics(loss_sum, accum, grad_norm, applied_norm,
                                    lr, scale, overflow, dstate=dstate_out,
-                                   nonfinite=nonfinite)
+                                   nonfinite=nonfinite,
+                                   loss_scalars=loss_scalars)
             if fp8_state is not None:
                 # Overflowed steps keep the OLD amax histories: an
                 # inf/nan cotangent amax would otherwise poison the
@@ -2459,6 +2514,8 @@ class DeepSpeedEngine:
                     out[key] = cast(metrics[key])
                 except Exception:
                     pass
+        for key, value in metrics.get(LOSS_SCALARS, {}).items():
+            out[key] = float(value)
         return out
 
     def _stamp_compile_facts(self, placed, step_rng, lr_in,

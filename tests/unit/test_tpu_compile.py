@@ -61,23 +61,65 @@ def compiled_text(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+# the OLMoE cell (`benchmarks/suite`): 2 rows x 4096 tokens, 16 heads x 128
+OLMOE_SHAPE = (2, 4096, 16, 128)
+
+
+@pytest.mark.parametrize("shape", [(B, T, H, D), OLMOE_SHAPE],
+                         ids=["gpt2-350m", "olmoe-t4096-d128"])
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
-def test_flash_attention_compiles(chip, grad):
+def test_flash_attention_compiles(chip, grad, shape):
     # `flash_attention` picks interpret mode off the first device (the
     # CPU here), so the test steers the kernel entry it wraps, with the
     # public function's default blocks
     from deepspeed_tpu.ops.pallas.flash_attention import _flash_pallas
 
     def fwd(q, k, v):
-        return _flash_pallas(q, k, v, None, None, 0, True, D ** -0.5,
-                             512, 512, 0.0, None, False)
+        return _flash_pallas(q, k, v, None, None, 0, True,
+                             shape[-1] ** -0.5, 512, 512, 0.0, None, False)
 
     def loss(q, k, v):
         return fwd(q, k, v).astype(jnp.float32).sum()
 
-    x = chip((B, T, H, D), jnp.bfloat16)
+    x = chip(shape, jnp.bfloat16)
     fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
-    assert "tpu_custom_call" in compiled_text(fn, x, x, x)
+    text = compiled_text(fn, x, x, x)
+    assert "tpu_custom_call" in text
+    for name in ("ds_flash_fwd",) + (("ds_flash_dq", "ds_flash_dkv")
+                                     if grad else ()):
+        assert name in text
+
+
+@pytest.mark.parametrize("bank", [(64, 2048, 1024), (64, 1024, 2048)],
+                         ids=["gate-up", "down"])
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
+def test_grouped_matmul_compiles(chip, grad, bank):
+    """The experts' three Pallas programs at the OLMoE cell's shapes:
+    65,536 token-expert pairs over 64 experts in tiles of 128 rows."""
+    from deepspeed_tpu.moe.dropless import tile_rows
+    from deepspeed_tpu.ops.pallas.grouped_matmul import grouped_matmul
+
+    pairs, experts = 8192 * 8, bank[0]
+    tile_m = tile_rows(pairs, experts)
+    assert tile_m == 128
+    m_tiles = pairs // tile_m + experts
+
+    def fwd(rows, w, tile_group, n_used):
+        return grouped_matmul(rows, w, tile_group, n_used, tile_m,
+                              interpret=False)
+
+    def loss(rows, w, tile_group, n_used):
+        return fwd(rows, w, tile_group, n_used).astype(jnp.float32).sum()
+
+    args = (chip((m_tiles * tile_m, bank[1]), jnp.bfloat16),
+            chip(bank, jnp.bfloat16), chip((m_tiles,), jnp.int32),
+            chip((1,), jnp.int32))
+    fn = jax.grad(loss, argnums=(0, 1)) if grad else fwd
+    text = compiled_text(fn, *args)
+    names = ("ds_grouped_matmul",) + (
+        ("ds_grouped_matmul_t", "ds_grouped_matmul_dw") if grad else ())
+    for name in names:
+        assert f"%{name}" in text or f" {name}" in text, name
 
 
 # every storage dtype `inference.kv_cache_dtype` accepts: the model's
