@@ -61,14 +61,19 @@ def compare(got, want, tol):
     """The checks behind ``correct``. ``got``: the program's ``loss``
     (the engine's ``eval_batch``), ``ce``, ``lb``, ``z``,
     ``logit_max_diff`` (over the tokens whose experts are the
-    reference's, ``tokens_with_same_experts`` of all) and
+    reference's, ``tokens_with_same_experts`` of all),
     ``choice_differs`` (share of token-expert pairs whose expert the
-    reference did not choose); ``want``: the
-    reference's ``loss``, ``ce``, ``lb``, ``z`` and ``logit_scale``
-    (its largest |logit|); ``tol``: the workload file's
+    reference did not choose) and, from the feed-forward part held to
+    the reference on its own input, ``router_prob_diff``,
+    ``own_choice_differs``, ``experts_out_diff`` and
+    ``tokens_with_same_experts_own`` (`program_and_reference`);
+    ``want``: the reference's ``loss``, ``ce``, ``lb``, ``z`` and
+    ``logit_scale`` (its largest |logit|); ``tol``: the workload file's
     ``correctness`` block. Each term within its ``*_rtol`` of the
     reference's, the logits within ``logit_rtol`` of the scale, the
-    share under ``choice_differs_max``."""
+    share under ``choice_differs_max``, the router's probabilities
+    within ``router_prob_rtol`` of the largest and the experts' output
+    within ``experts_out_rtol`` of its largest."""
     out = {}
     for key in ("loss", "ce", "lb", "z"):
         allowed = tol[key + "_rtol"] * abs(want[key])
@@ -85,15 +90,64 @@ def compare(got, want, tol):
         "share_differs": got["choice_differs"],
         "bound": tol["choice_differs_max"],
         "ok": bool(got["choice_differs"] <= tol["choice_differs_max"])}
+    out["router"] = {
+        "max_prob_diff_over_max_prob": got["router_prob_diff"],
+        "own_choice_differs": got["own_choice_differs"],
+        "tolerance": tol["router_prob_rtol"],
+        "ok": bool(got["router_prob_diff"] <= tol["router_prob_rtol"])}
+    out["experts"] = {
+        "max_abs_diff_over_max_abs": got["experts_out_diff"],
+        "on_share_of_tokens": got["tokens_with_same_experts_own"],
+        "tolerance": tol["experts_out_rtol"],
+        "ok": bool(got["experts_out_diff"] <= tol["experts_out_rtol"])}
     out["ok"] = all(v["ok"] for v in out.values())
     return out
+
+
+def own_input_checks(seen, stats, params, cast, top_k):
+    """Every layer's feed-forward part against the reference's **on the
+    program's own router input** (``seen``: the intermediates flax
+    captured: each layer's ``post_attn_norm`` output and ``experts``
+    output), so that nothing upstream is in the difference and each is
+    read at the layer's own scale: the chosen probabilities against a
+    float32 router's (over the largest probability), and the experts'
+    output before the residual on the tokens whose experts agree (over
+    its largest entry). The reference takes the float32 ``params``,
+    but the router's weights as the program has them (``cast``, the
+    step's copy in the compute dtype): what the router check holds to
+    float32 is the product and the softmax. The worst layer of each."""
+    import jax.numpy as jnp
+
+    layers = []
+    for i in range(stats["chosen"].shape[0]):
+        layer = seen[f"layers_{i}"]
+        n = layer["post_attn_norm"]["__call__"][0]
+        y = layer["experts"]["__call__"][0][0]
+        n, y = (a.reshape(-1, a.shape[-1]) for a in (n, y))
+        ref_y, ref_p, ref_mask = olmoe_ref.experts(n, dict(
+            params[f"layers_{i}"]["experts"],
+            router=cast[f"layers_{i}"]["experts"]["router"]), top_k)
+        chosen = stats["chosen"][i]
+        p_diff = jnp.abs(stats["weights"][i] - jnp.take_along_axis(
+            ref_p, chosen, axis=-1)).max() / ref_p.max()
+        hit = jnp.take_along_axis(ref_mask, chosen, axis=-1) > 0
+        same = hit.all(-1)
+        y_diff = jnp.where(same[:, None], jnp.abs(
+            y.astype(jnp.float32) - ref_y), 0.0).max() / jnp.abs(ref_y).max()
+        layers.append((p_diff, 1.0 - hit.mean(), y_diff, same.mean()))
+    p_diff, differs, y_diff, same = (jnp.stack(a) for a in zip(*layers))
+    return {"router_prob_diff": p_diff.max(),
+            "own_choice_differs": differs.max(),
+            "experts_out_diff": y_diff.max(),
+            "tokens_with_same_experts_own": same.min()}
 
 
 def program_and_reference(model, params, ids, config):
     """One ``[1, T]`` sequence through both: the program's loss
     function and model on ``params`` in the compute dtype, and the plain
-    float32 reference on the same ``params``. Returns ``(got, want)``
-    as ``compare`` takes them, all floats."""
+    float32 reference on the same ``params``, whole and (each layer's
+    feed-forward part) on the program's own input. Returns ``(got,
+    want)`` as ``compare`` takes them, all floats."""
     import jax
     import jax.numpy as jnp
     from deepspeed_tpu.models.olmoe import make_olmoe_loss_fn
@@ -106,7 +160,9 @@ def program_and_reference(model, params, ids, config):
     def both(params, x):
         cast = jax.tree_util.tree_map(lambda p: p.astype(dtype), params)
         loss, scalars = loss_fn(cast, {"input_ids": x})
-        logits, stats = model.apply({"params": cast}, x)
+        (logits, stats), state = model.apply(
+            {"params": cast}, x, capture_intermediates=lambda mdl, _:
+            mdl.name in ("post_attn_norm", "experts"))
         ref = olmoe_ref.loss_terms(
             params, x, config, assumed["router_aux_loss_coef"],
             assumed["router_z_loss_coef"])
@@ -123,6 +179,9 @@ def program_and_reference(model, params, ids, config):
                "logit_max_diff": jnp.where(same, diff, 0.0).max(),
                "choice_differs": 1.0 - hit.mean(),
                "tokens_with_same_experts": same.mean()}
+        got.update(own_input_checks(
+            state["intermediates"], stats, params, cast,
+            config["num_experts_per_tok"]))
         want = {k: ref[k] for k in ("loss", "ce", "lb", "z")}
         want["logit_scale"] = jnp.abs(ref_logits).max()
         return got, want
@@ -134,8 +193,8 @@ def check_against_reference(ctx, engine, model, batch):
     """Before the first update, on one sequence of the first batch. The
     total is the engine's ``eval_batch`` (which wants the cell's global
     batch, so the sequence is repeated to fill it: every row is the
-    same, so every mean is the one sequence's); the three terms, the
-    logits and the experts chosen are ``program_and_reference``'s."""
+    same, so every mean is the one sequence's); everything else is
+    ``program_and_reference``'s."""
     ids = batch["input_ids"]
     got, want = program_and_reference(model, engine.params, ids[:1],
                                       ctx.config)

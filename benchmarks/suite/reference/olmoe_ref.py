@@ -135,6 +135,17 @@ def _experts(n, p, top_k):
     return y, logits, mask
 
 
+def experts(n, p, top_k):
+    """One layer's feed-forward part on router input ``n`` ``[N, C]``
+    (any dtype, read in float32) with that layer's ``experts``
+    parameters ``p``: (``y`` ``[N, C]`` before the residual, router
+    probabilities ``[N, E]``, top-k mask ``[N, E]``), float32. For
+    holding the program's layer to its own input."""
+    with jax.default_matmul_precision("highest"):
+        y, logits, mask = _experts(_f32(n), p, top_k)
+        return y, jax.nn.softmax(logits, axis=-1), mask
+
+
 def forward(params, input_ids, cfg):
     """``cfg``: a configuration file's dict. Returns (logits ``[B, T,
     vocab]``, router logits ``[layers, B*T, experts]``, the top-k mask

@@ -244,7 +244,10 @@ def init_olmoe_params(model: OlmoeLM, rng, seq_len=8):
 
 
 def olmoe_partition_specs(params):
-    """Data parallelism only: every parameter replicated. (Expert
-    parallelism shards the three banks' leading axis over ``expert``;
-    nothing runs it yet.)"""
+    """Data parallelism only: every parameter replicated, each chip
+    routing its own batch rows (`moe/dropless.py` wraps itself in a
+    ``shard_map`` over ``data``; compiled for four chips in
+    `tests/unit/test_tpu_compile.py`).
+    (Expert parallelism shards the three banks' leading axis over
+    ``expert``; nothing runs it yet.)"""
     return jax.tree_util.tree_map(lambda _: P(), params)

@@ -24,7 +24,6 @@ from deepspeed_tpu.ops.pallas.flash_decode import (
     flash_decode_paged,
 )
 from deepspeed_tpu.ops.pallas.fused_adam import pallas_adam_update
-from deepspeed_tpu.ops.pallas.grouped_matmul import grouped_matmul
 
 __all__ = [
     "DEFAULT_BLOCK_K",
@@ -34,6 +33,5 @@ __all__ = [
     "flash_attention",
     "flash_decode",
     "flash_decode_paged",
-    "grouped_matmul",
     "pallas_adam_update",
 ]

@@ -764,6 +764,15 @@ def placed_on_mesh(mesh, rows, heads):
         _PLACEMENT.reset(token)
 
 
+def placement():
+    """``(mesh, rows axis, heads axis)`` as `placed_on_mesh` said, for a
+    kernel's caller to wrap it by; ``None`` with no placement, or
+    inside a ``shard_map`` that already made the mesh's axes manual."""
+    if jax.sharding.get_abstract_mesh().manual_axes:
+        return None
+    return _PLACEMENT.get()
+
+
 def _flash_pallas_on_mesh(q, k, v, key_bias, dropout_seed,
                           dropout_head_offset, causal, sm_scale, block_q,
                           block_k, dropout_rate, dropout_num_heads,
@@ -781,12 +790,12 @@ def _flash_pallas_on_mesh(q, k, v, key_bias, dropout_seed,
                              sm_scale, block_q, block_k, dropout_rate,
                              num_heads, interpret)
 
-    placement = _PLACEMENT.get()
-    if placement is None or jax.sharding.get_abstract_mesh().manual_axes:
+    placed = placement()
+    if placed is None:
         return kernel(q, k, v, key_bias, dropout_seed,
                       dropout_head_offset, dropout_num_heads)
 
-    mesh, rows, heads = placement
+    mesh, rows, heads = placed
     B, _, H, _ = q.shape
     for axis, extent, what in ((rows, B, "batch rows"), (heads, H, "heads")):
         if extent % mesh.shape[axis]:

@@ -207,8 +207,9 @@ def loss_alone(loss_fn):
 
 def make_grad_accumulator(loss_fn, compute_dtype, accum, constrain=None,
                           cast_params=None, remat_policy=None,
-                          fp8_plan=None, with_scalars=False):
-    """Build ``accumulate(params, batch, rng, scale) -> (loss_sum, grads)``:
+                          fp8_plan=None):
+    """Build ``accumulate(params, batch, rng, scale) -> (loss_sum, grads,
+    scalars)`` (``(loss_sum, grads, fp8 state, scalars)`` under fp8):
     scaled-loss value-and-grad over one microbatch, or a ``lax.scan`` over
     ``accum`` microbatches (batch leading dim = accum). Shared by the dense
     and the 1-bit (shard_map) train steps.
@@ -244,9 +245,9 @@ def make_grad_accumulator(loss_fn, compute_dtype, accum, constrain=None,
     micro sees the same input histories, so the max over their slot-0
     amaxes is the step's amax and the older slots agree.
 
-    ``with_scalars``: ``loss_fn`` may return ``(loss, scalars)``
-    (:func:`loss_and_scalars`), and ``accumulate`` returns the scalars,
-    summed over the microbatches, as one more last element."""
+    ``loss_fn`` may return ``(loss, scalars)`` (:func:`loss_and_scalars`):
+    ``scalars`` are summed over the microbatches, and ``{}`` from a loss
+    function that hands none out."""
 
     user_caster = cast_params
     if cast_params is None:
@@ -308,11 +309,6 @@ def make_grad_accumulator(loss_fn, compute_dtype, accum, constrain=None,
 
     def accumulate(params, batch, rng, scale, loss_kwargs=None,
                    fp8_state=None):
-        out = accumulate_all(params, batch, rng, scale, loss_kwargs,
-                             fp8_state)
-        return out if with_scalars else out[:-1]
-
-    def accumulate_all(params, batch, rng, scale, loss_kwargs, fp8_state):
         if declare_sites is not None and direct is None:
             declare_sites()
         assert (fp8_state is not None) == (
@@ -1158,8 +1154,7 @@ class DeepSpeedEngine:
                                            constrain=grad_constrain,
                                            cast_params=caster,
                                            remat_policy=remat_policy,
-                                           fp8_plan=fp8_plan,
-                                           with_scalars=True)
+                                           fp8_plan=fp8_plan)
         pld_fn = self._pld_theta_fn()
         detect, nan_skip, fault_on = self._nan_guard_flags()
         self._fault_arg = fault_on
@@ -1480,7 +1475,8 @@ class DeepSpeedEngine:
             rng = jax.random.fold_in(rng, jax.lax.axis_index("data"))
             loss_kw = {"pld_theta": pld_fn(dstate.global_step)} \
                 if pld_fn is not None else None
-            loss_sum, grads = accumulate(params, batch, rng, scale, loss_kw)
+            loss_sum, grads, _ = accumulate(params, batch, rng, scale,
+                                            loss_kw)
 
             # Unscale BEFORE the exchange (the GSPMD path unscales after
             # its allreduce): absmax quantization scales must be computed
@@ -1649,7 +1645,8 @@ class DeepSpeedEngine:
                 else jnp.asarray(static_scale, jnp.float32)
             loss_kw = {"pld_theta": pld_fn(dstate.global_step)} \
                 if pld_fn is not None else None
-            loss_sum, grads = accumulate(params, batch, rng, scale, loss_kw)
+            loss_sum, grads, _ = accumulate(params, batch, rng, scale,
+                                            loss_kw)
             if fault_on:
                 grads = jax.tree_util.tree_map(lambda g: g * grad_fault,
                                                grads)
@@ -2016,7 +2013,8 @@ class DeepSpeedEngine:
             rng = jax.random.fold_in(rng, jax.lax.axis_index("data"))
             loss_kw = {"pld_theta": pld_fn(dstate.global_step)} \
                 if pld_fn is not None else None
-            loss_sum, grads = accumulate(params, batch, rng, scale, loss_kw)
+            loss_sum, grads, _ = accumulate(params, batch, rng, scale,
+                                            loss_kw)
 
             # Static token budget: rows touched locally per boundary is
             # bounded by the number of id elements in the local batch.
@@ -2154,7 +2152,8 @@ class DeepSpeedEngine:
             rng = jax.random.fold_in(rng, jax.lax.axis_index("data"))
             loss_kw = {"pld_theta": pld_fn(dstate.global_step)} \
                 if pld_fn is not None else None
-            loss_sum, grads = accumulate(params, batch, rng, scale, loss_kw)
+            loss_sum, grads, _ = accumulate(params, batch, rng, scale,
+                                            loss_kw)
 
             # Cross-shard overflow vote (reference stage2.py:1527-1551);
             # norms are pmean'd local-shard diagnostics (a true global norm
@@ -2514,8 +2513,11 @@ class DeepSpeedEngine:
                     out[key] = cast(metrics[key])
                 except Exception:
                     pass
-        for key, value in metrics.get(LOSS_SCALARS, {}).items():
-            out[key] = float(value)
+        try:    # one transfer for all of them
+            out.update({k: float(v) for k, v in jax.device_get(
+                metrics.get(LOSS_SCALARS, {})).items()})
+        except Exception:
+            pass
         return out
 
     def _stamp_compile_facts(self, placed, step_rng, lr_in,

@@ -3,6 +3,7 @@ functions against XLA's own count, and its readers on a hand-made
 trace. No number from here is a device metric."""
 
 import copy
+import re
 import sys
 import time
 
@@ -69,7 +70,8 @@ def test_train_olmoe_driver(trace):
     assert checks["train_step_jit_entries"] == [1, 1]
     assert checks["dropped_tokens"] == 0.0
     assert set(checks["reference"]) == {"loss", "ce", "lb", "z", "logits",
-                                        "expert_choice", "ok"}
+                                        "expert_choice", "router",
+                                        "experts", "ok"}
     assert res.end_to_end["train_tokens_per_s_per_chip"] > 0
     assert mfu.read(ctx, res) > 0
     assert res.facts["flops_per_token"] == \
@@ -156,7 +158,7 @@ def test_flops_olmoe_at_the_published_widths():
 HLO = """
   %fusion.7 = bf16[8,4]{1,0} fusion(%p0), kind=kLoop, calls=%fc.1, metadata={op_name="jit(step)/jvp(OlmoeLM)/layers_0/experts/ds_moe_dispatch/gather" stack_frame_id=4}
   ROOT %sort.1 = s32[64]{0} sort(%p1), dimensions={0}, metadata={op_name="jit(step)/jvp(OlmoeLM)/layers_0/experts/ds_moe_route/jit(argsort)/sort"}
-  %ds_grouped_matmul.3 = bf16[8,4]{1,0} custom-call(%a, %b), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/jvp(OlmoeLM)/layers_0/experts/ds_moe_experts/ds_grouped_matmul/pallas_call"}
+  %tgmm.3 = bf16[8,4]{1,0} custom-call(%a, %b), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/transpose(jvp(OlmoeLM))/layers_0/experts/ds_moe_experts/jit(tgmm)/pallas_call"}
   %fusion.9 = f32[8]{0} fusion(%p0), kind=kLoop, calls=%fc.2, metadata={op_name="jit(step)/jvp(OlmoeLM)/final_norm/mul"}
 """
 
@@ -165,8 +167,7 @@ def hand_made(profiled_steps=2):
     ms = 1e-3
     events = [("fusion.7 fusion", 0.0, 2 * ms),
               ("sort.1 sort", 2 * ms, 3 * ms),
-              ("ds_grouped_matmul.3 custom-call:tpu_custom_call", 3 * ms,
-               7 * ms),
+              ("tgmm.3 custom-call:tpu_custom_call", 3 * ms, 7 * ms),
               ("fusion.9 fusion", 7 * ms, 17 * ms)]
     res = harness.Result(
         correct=True, attempted=0, failed=0, setup_s=1.0, end_to_end={},
@@ -178,7 +179,7 @@ def hand_made(profiled_steps=2):
 
 def test_scopes_of_reads_instruction_and_scope():
     scopes = scope_time.scopes_of(HLO, "ds_moe_")
-    assert set(scopes) == {"fusion.7", "sort.1", "ds_grouped_matmul.3"}
+    assert set(scopes) == {"fusion.7", "sort.1", "tgmm.3"}
     assert "ds_moe_route" in scopes["sort.1"]
 
 
@@ -212,3 +213,43 @@ def test_new_readers_on_a_hand_made_trace():
     res.trace = None
     assert roofline_in.read(ctx, res, **spec["args"]) is None
     assert fact.read(ctx, res, key="moe_load_max_over_mean") is None
+
+
+def test_stall_watch_logs_a_stall_with_stacks_and_its_end(tmp_path):
+    """`tools/stall_watch.py`: steps, then none for longer than
+    `stall_s`, then steps again: the log holds the stall with every
+    thread's stack and its end, and the once-a-second sample."""
+    from benchmarks.suite.tools import stall_watch
+
+    class Stepper:
+        def step(self):
+            return "stepped"
+
+    path = tmp_path / "stall.log"
+    with open(path, "w", buffering=1) as out:
+        dog = stall_watch.Watchdog(out, stall_s=0.2, after_steps=3,
+                                   poll_s=0.02)
+        stall_watch.beat_on_return(Stepper, "step", dog)
+        stepper = Stepper()
+        dog.start()
+        try:
+            for _ in range(5):
+                assert stepper.step() == "stepped"
+            time.sleep(0.5)                 # the stall
+            deadline = time.perf_counter() + 5
+            while "stall over" not in path.read_text() and \
+                    time.perf_counter() < deadline:
+                stepper.step()
+                time.sleep(0.02)
+        finally:
+            dog.stop()
+    assert not dog._thread.is_alive()
+    assert dog.steps >= 6
+    log = path.read_text()
+    assert "STALL: no step returned for 0." in log
+    assert "test_stall_watch_logs_a_stall" in log     # the stacks
+    assert log.index("stall over") > log.index("STALL")
+    assert re.search(r"steps \d+ cpu \d", log)
+    cpu = stall_watch.thread_cpu()
+    assert all(seconds >= 0 for _, seconds in cpu.values())
+    assert "meminfo" in stall_watch.machine()

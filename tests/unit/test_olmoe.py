@@ -154,11 +154,9 @@ def test_dropless_gradients_match_plain_autodiff():
     got = jax.grad(loss, argnums=(0, 1, 2, 3, 4))(*args)
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(dropless, "_gather_tokens",
-                   lambda x, row_pair, row_valid, pair_row, top_k:
-                   jnp.where(row_valid[:, None], x[row_pair // top_k], 0))
+                   lambda x, order, inverse, top_k: x[order // top_k])
         mp.setattr(dropless, "_gather_pairs",
-                   lambda rows, row_pair, row_valid, pair_row:
-                   rows[pair_row])
+                   lambda rows, order, inverse: rows[inverse])
         want = jax.grad(loss, argnums=(0, 1, 2, 3, 4))(*args)
     for g, w in zip(got, want):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
@@ -219,78 +217,91 @@ def test_loss_scalars_reach_the_step_event_and_average_over_microbatches():
     engine.telemetry.close()
 
 
-def test_group_layout_pads_every_group_to_whole_tiles():
-    pair_expert = jnp.asarray([2, 0, 2, 2, 3, 0, 2, 2, 2], jnp.int32)
-    lay = jax.tree_util.tree_map(np.asarray, dropless.group_layout(
-        pair_expert, n_experts=4, tile_m=4))
-    # sizes 2, 0, 6, 1 -> 1, 1 (an empty group keeps a tile), 2, 1 tiles
-    np.testing.assert_array_equal(lay["group_sizes"], [2, 0, 6, 1])
-    assert int(lay["n_used"][0]) == 5
-    assert lay["tile_group"].shape == (-(-9 // 4) + 4,)       # static
-    np.testing.assert_array_equal(lay["tile_group"], [0, 1, 2, 2, 3, 3, 3])
-    np.testing.assert_array_equal(
-        lay["row_valid"].reshape(-1, 4).sum(1), [2, 0, 4, 2, 1, 0, 0])
-    # every pair lies in a valid row that holds it, in arrival order
-    # within its expert
-    np.testing.assert_array_equal(lay["row_pair"][lay["pair_row"]],
-                                  np.arange(9))
-    assert lay["row_valid"][lay["pair_row"]].all()
-    np.testing.assert_array_equal(lay["pair_row"],
-                                  [8, 0, 9, 10, 16, 1, 11, 12, 13])
+def test_dropless_under_a_data_mesh_routes_each_chips_tokens_alone():
+    """Traced under `placed_on_mesh` (as the engine traces its loss
+    over more than one device) the routing runs inside a `shard_map`
+    over the rows axis: each chip sorts and multiplies its own tokens,
+    the counters and the losses' sums are added up over the chips, and
+    values and every gradient are the single-device ones."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from deepspeed_tpu.ops.pallas.flash_attention import placed_on_mesh
+    from deepspeed_tpu.parallel.mesh import build_mesh
+
+    n, m, i, e, k = 64, 16, 8, 4, 2
+    ks = jax.random.split(jax.random.PRNGKey(5), 5)
+    args = [jax.random.normal(ks[0], (n, m)),
+            jax.random.normal(ks[1], (m, e)),
+            0.3 * jax.random.normal(ks[2], (e, m, i)),
+            0.3 * jax.random.normal(ks[3], (e, m, i)),
+            0.3 * jax.random.normal(ks[4], (e, i, m))]
+
+    def loss(*a):
+        y, stats = dropless.dropless_moe(*a, k)
+        counts = stats["tokens_per_expert"].astype(jnp.float32)
+        return ((y ** 2).sum() + (counts * stats["prob_sum"]).sum() +
+                0.1 * stats["z_sum"]), stats
+
+    mesh = build_mesh({"data": 4}, devices=jax.devices()[:4])
+
+    def placed(*a):
+        with placed_on_mesh(mesh, rows="data", heads="model"):
+            return loss(*a)
+
+    grad = lambda f: jax.jit(jax.value_and_grad(
+        f, argnums=(0, 1, 2, 3, 4), has_aux=True))
+    (want, want_stats), want_grads = grad(loss)(*args)
+    on_mesh = [jax.device_put(a, NamedSharding(mesh, spec))
+               for a, spec in zip(args, [P("data")] + [P()] * 4)]
+    (got, stats), grads = grad(placed)(*on_mesh)
+    assert "shard_map" in str(jax.make_jaxpr(placed)(*args))
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-6)
+    for key in ("chosen", "tokens_per_expert", "dropped"):
+        np.testing.assert_array_equal(np.asarray(stats[key]),
+                                      np.asarray(want_stats[key]))
+    assert int(stats["tokens_per_expert"].sum()) == n * k
+    for g, w in zip(grads, want_grads):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-5, atol=1e-5)
+    with pytest.raises(ValueError, match="do not divide"):
+        placed(args[0][:-1], *args[1:])
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bfloat16"])
-def test_grouped_matmul_kernels_against_a_loop_over_groups(dtype):
-    """The three Pallas programs (interpret mode here) on a layout with
-    an empty group, a group of several tiles and unused tiles at the
-    end, against plain per-group products. Rows past the used tiles are
-    filled with NaN: nothing of them may reach a result that is read."""
-    from deepspeed_tpu.ops.pallas.grouped_matmul import grouped_matmul
-    sizes, tile_m, k, n = [20, 0, 37, 3], 16, 32, 48
-    pair_expert = jnp.asarray(np.repeat(np.arange(4), sizes), jnp.int32)
-    lay = dropless.group_layout(pair_expert, 4, tile_m)
-    valid = np.asarray(lay["row_valid"])
-    used = int(lay["n_used"][0]) * tile_m
+def test_grouped_matmul_against_a_loop_over_groups(dtype):
+    """`grouped_matmul` (`megablox.gmm`, interpret mode here) over
+    groups that include an empty one, one of several row tiles and
+    ones that end inside a tile, against plain per-group products:
+    the result and both gradients."""
+    sizes, k, n = [20, 0, 37, 7], 32, 48
+    group_sizes = jnp.asarray(sizes, jnp.int32)
+    row_group = np.repeat(np.arange(4), sizes)
     ks = jax.random.split(jax.random.PRNGKey(11), 3)
-    rows = jax.random.normal(ks[0], (valid.size, k), jnp.float32)
-    rows = jnp.where(valid[:, None], rows, 0.0)
-    rows = rows.at[used:].set(jnp.nan).astype(dtype)
-    bank = jax.random.normal(ks[1], (4, k, n), jnp.float32).astype(dtype)
-    cot = jax.random.normal(ks[2], (valid.size, n), jnp.float32)
-    cot = jnp.where(valid[:, None], cot, 0.0).astype(dtype)
-    row_group = np.repeat(np.asarray(lay["tile_group"]), tile_m)
+    rows = jax.random.normal(ks[0], (sum(sizes), k)).astype(dtype)
+    bank = jax.random.normal(ks[1], (4, k, n)).astype(dtype)
+    cot = jax.random.normal(ks[2], (sum(sizes), n))
 
     def kernel_loss(rows, bank):
-        out = grouped_matmul(rows, bank, lay["tile_group"], lay["n_used"],
-                             tile_m)
-        return (jnp.where(valid[:, None], out, 0).astype(jnp.float32) *
-                cot.astype(jnp.float32)).sum(), out
+        out = dropless.grouped_matmul(rows, bank, group_sizes)
+        return (out.astype(jnp.float32) * cot).sum(), out
 
     def loop_loss(rows, bank):
         out = jnp.einsum("rk,rkn->rn", rows.astype(jnp.float32),
                          bank.astype(jnp.float32)[row_group])
-        return (jnp.where(valid[:, None], out, 0) *
-                cot.astype(jnp.float32)).sum(), out
+        return (out * cot).sum(), out
 
     (_, out), (d_rows, d_bank) = jax.value_and_grad(
         kernel_loss, argnums=(0, 1), has_aux=True)(rows, bank)
-    clean = jnp.where(jnp.arange(valid.size)[:, None] < used, rows, 0)
     (_, want), (w_rows, w_bank) = jax.value_and_grad(
-        loop_loss, argnums=(0, 1), has_aux=True)(clean, bank)
+        loop_loss, argnums=(0, 1), has_aux=True)(rows, bank)
+    assert out.dtype == d_rows.dtype == d_bank.dtype == dtype
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
-    for got, ref, rows_only in ((out, want, True), (d_rows, w_rows, True),
-                                (d_bank, w_bank, False)):
+    for got, ref in ((out, want), (d_rows, w_rows), (d_bank, w_bank)):
         got, ref = (np.asarray(a, np.float32) for a in (got, ref))
-        if rows_only:       # only the laid-out rows are ever read
-            got, ref = got[valid], ref[valid]
         assert np.isfinite(got).all()
         assert np.abs(got - ref).max() <= tol * np.abs(ref).max()
     # the empty group's block of the bank's gradient is written: zeros
     assert not np.asarray(d_bank, np.float32)[1].any()
-    with pytest.raises(ValueError, match="tile"):
-        grouped_matmul(rows[:-3], bank, lay["tile_group"], lay["n_used"],
-                       tile_m)
 
 
 # --- the benchmark's check (`benchmarks/suite/drivers/train_olmoe.py`)
@@ -316,17 +327,25 @@ def _fault_renormalised_top_k(mp):
 def _fault_dropped_tokens(mp):
     real = dropless.grouped_matmul
 
-    def with_capacity(rows, bank, tile_group, n_used, tile_m, **kw):
+    def with_capacity(rows, bank, group_sizes):
         # GShard's capacity of 1.25 x the mean load: an expert's rows
         # past it come back as zeros
-        n_experts = bank.shape[0]
-        pairs = rows.shape[0] - n_experts * tile_m
-        first = jnp.searchsorted(tile_group, tile_group, side="left")
-        rank = jnp.arange(rows.shape[0]) - jnp.repeat(first, tile_m) * tile_m
-        keep = rank < int(1.25 * pairs / n_experts)
-        return jnp.where(keep[:, None], real(rows, bank, tile_group,
-                                             n_used, tile_m, **kw), 0)
+        start = jnp.cumsum(group_sizes) - group_sizes
+        group = jnp.searchsorted(jnp.cumsum(group_sizes),
+                                 jnp.arange(rows.shape[0]), side="right")
+        keep = jnp.arange(rows.shape[0]) - start[group] < \
+            int(1.25 * rows.shape[0] / bank.shape[0])
+        return jnp.where(keep[:, None], real(rows, bank, group_sizes), 0)
     mp.setattr(dropless, "grouped_matmul", with_capacity)
+
+
+def _fault_8bit_experts(mp):
+    real = dropless.grouped_matmul
+    # both operands of the experts' products rounded to 3 bits of
+    # mantissa (an fp8 product without its scaling)
+    mp.setattr(dropless, "grouped_matmul", lambda rows, bank, sizes: real(
+        jax.lax.reduce_precision(rows, 8, 3),
+        jax.lax.reduce_precision(bank, 8, 3), sizes))
 
 
 def _fault_no_qk_norm(mp):
@@ -339,20 +358,24 @@ def _fault_no_qk_norm(mp):
 # pair elsewhere (512 tokens x 2: one flipped pair is 1e-3)
 FLOAT32_TOLERANCES = {"loss_rtol": 2e-6, "ce_rtol": 2e-6, "lb_rtol": 2e-6,
                       "z_rtol": 2e-6, "logit_rtol": 2e-5,
-                      "choice_differs_max": 5e-4}
+                      "choice_differs_max": 5e-4,
+                      "router_prob_rtol": 2e-5, "experts_out_rtol": 2e-5}
 
 
 @pytest.mark.parametrize("fault,dtype,caught_by", [
     (_fault_renormalised_top_k, jnp.bfloat16, "logits"),
     (_fault_dropped_tokens, jnp.bfloat16, "logits"),
     (_fault_no_qk_norm, jnp.bfloat16, "logits"),
-    # in bf16 the activations that reach a float32 router are rounded
-    # as coarsely as a bf16 router rounds its result, and the check
-    # cannot tell them apart (on the chip: 0.63-0.65 % of pairs moved
-    # against 0.58-0.60 %); in float32 compute it stands out
+    # against the whole reference a bf16 router hides in bf16 compute:
+    # the activations that reach a float32 router are rounded as
+    # coarsely as a bf16 router rounds its result (on the chip 0.63-0.65
+    # % of pairs moved against 0.58-0.60 %). Held to a float32 router
+    # on its own input it stands out by two orders of magnitude
+    (_fault_bf16_router, jnp.bfloat16, "router"),
     (_fault_bf16_router, jnp.float32, "expert_choice"),
+    (_fault_8bit_experts, jnp.bfloat16, "experts"),
 ], ids=["renormalised-top-k", "dropped-tokens", "no-qk-norm",
-        "bf16-router"])
+        "bf16-router", "bf16-router-float32-compute", "8-bit-experts"])
 def test_check_against_reference_fails_a_faulty_program(fault, dtype,
                                                         caught_by):
     """The cell's own check, with the committed tolerances for bf16

@@ -93,33 +93,31 @@ def test_flash_attention_compiles(chip, grad, shape):
 @pytest.mark.parametrize("bank", [(64, 2048, 1024), (64, 1024, 2048)],
                          ids=["gate-up", "down"])
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
-def test_grouped_matmul_compiles(chip, grad, bank):
-    """The experts' three Pallas programs at the OLMoE cell's shapes:
-    65,536 token-expert pairs over 64 experts in tiles of 128 rows."""
-    from deepspeed_tpu.moe.dropless import tile_rows
-    from deepspeed_tpu.ops.pallas.grouped_matmul import grouped_matmul
+def test_grouped_matmul_compiles(chip, monkeypatch, grad, bank):
+    """The experts' grouped matmul (`megablox.gmm` in the tiles
+    `moe/dropless.py` picks) at the OLMoE cell's shapes: 65,536
+    token-expert pairs over 64 experts; differentiated, the rows'
+    gradient is the same kernel and the bank's is `tgmm`."""
+    from deepspeed_tpu.moe.dropless import grouped_matmul
 
-    pairs, experts = 8192 * 8, bank[0]
-    tile_m = tile_rows(pairs, experts)
-    assert tile_m == 128
-    m_tiles = pairs // tile_m + experts
+    _compiled_not_interpreted(monkeypatch, "deepspeed_tpu.moe.dropless")
 
-    def fwd(rows, w, tile_group, n_used):
-        return grouped_matmul(rows, w, tile_group, n_used, tile_m,
-                              interpret=False)
+    def loss(rows, w, group_sizes):
+        return grouped_matmul(rows, w, group_sizes).astype(
+            jnp.float32).sum()
 
-    def loss(rows, w, tile_group, n_used):
-        return fwd(rows, w, tile_group, n_used).astype(jnp.float32).sum()
-
-    args = (chip((m_tiles * tile_m, bank[1]), jnp.bfloat16),
-            chip(bank, jnp.bfloat16), chip((m_tiles,), jnp.int32),
-            chip((1,), jnp.int32))
-    fn = jax.grad(loss, argnums=(0, 1)) if grad else fwd
+    args = (chip((8192 * 8, bank[1]), jnp.bfloat16),
+            chip(bank, jnp.bfloat16), chip((bank[0],), jnp.int32))
+    fn = jax.grad(loss, argnums=(0, 1)) if grad else grouped_matmul
     text = compiled_text(fn, *args)
-    names = ("ds_grouped_matmul",) + (
-        ("ds_grouped_matmul_t", "ds_grouped_matmul_dw") if grad else ())
-    for name in names:
-        assert f"%{name}" in text or f" {name}" in text, name
+    # an instruction is named after the innermost jit around its kernel;
+    # a sum's gradient needs no forward product
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == (2 if grad else 1)
+    assert sum("tgmm" in line.split("=")[0] for line in calls) == \
+        (1 if grad else 0)
+    assert all("gmm" in line.split("=")[0] for line in calls)
 
 
 # every storage dtype `inference.kv_cache_dtype` accepts: the model's
@@ -302,6 +300,51 @@ def test_engine_loss_over_data_mesh_compiles(topo, monkeypatch, program):
 
     fn = eval_step if program == "eval_batch" else jax.grad(eval_step)
     assert "tpu_custom_call" in compiled_text(fn, params, batch)
+
+
+@pytest.mark.parametrize("program", ["eval_batch", "train_step"])
+def test_olmoe_loss_over_data_mesh_compiles(topo, monkeypatch, program):
+    """The same for OLMoE: besides the flash kernels its step holds the
+    experts' grouped matmuls (Mosaic kernels too) and a sort that must
+    stay each chip's own, so `moe/dropless.py` runs routing, dispatch,
+    experts and combine inside one `shard_map` over `data`. One layer
+    at the published widths, 2 rows of 4096 tokens a chip as in the
+    benchmark's cell."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from deepspeed_tpu.models.olmoe import (
+        OlmoeLM, make_olmoe_loss_fn, olmoe_1b_7b)
+    from deepspeed_tpu.parallel.mesh import build_mesh
+    from deepspeed_tpu.runtime.engine import place_kernels_on_mesh
+
+    _compiled_not_interpreted(monkeypatch,
+                              "deepspeed_tpu.ops.pallas.flash_attention")
+    _compiled_not_interpreted(monkeypatch, "deepspeed_tpu.moe.dropless")
+    mesh = build_mesh({"data": 4}, devices=topo.devices)
+    model = OlmoeLM(olmoe_1b_7b(n_layer=1, use_flash_attention=True))
+    loss_fn = place_kernels_on_mesh(make_olmoe_loss_fn(model), mesh)
+
+    shapes = jax.eval_shape(
+        lambda: model.init({"params": jax.random.PRNGKey(0)},
+                           jnp.zeros((1, 8), jnp.int32))["params"])
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=NamedSharding(mesh, P())), shapes)
+    batch = {"input_ids": jax.ShapeDtypeStruct(
+        (8, 4096), jnp.int32, sharding=NamedSharding(mesh, P("data")))}
+
+    def eval_step(params, batch):
+        cast = jax.tree_util.tree_map(
+            lambda x: x.astype(jnp.bfloat16), params)
+        return loss_fn(cast, batch, None)[0]
+
+    fn = eval_step if program == "eval_batch" else jax.grad(eval_step)
+    text = compiled_text(fn, params, batch)
+    names = ("ds_flash_fwd", "gmm") + (
+        ("ds_flash_dq", "ds_flash_dkv", "tgmm")
+        if program == "train_step" else ())
+    for name in names:
+        assert f"%{name}" in text or f" {name}" in text, name
 
 
 @pytest.mark.parametrize("layout", ["ring", "paged"])
