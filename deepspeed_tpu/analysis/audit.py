@@ -661,7 +661,8 @@ def _kernel_analysis_for(fn, args, engine):
     — the contract `rules.rule_kernel_dma` enforces.
     """
     from deepspeed_tpu.analysis.kernels import (
-        analyze_kernels, ring_dead_block_fraction)
+        analyze_kernels, paged_dead_block_fraction,
+        ring_dead_block_fraction)
 
     args = list(args)
     B = engine.spec.max_batch
@@ -675,9 +676,14 @@ def _kernel_analysis_for(fn, args, engine):
               % (engine.n_pages - 1)) + 1     # live, distinct, non-trash
         args[4] = jnp.asarray(pt.astype(np.int32))
     ana = analyze_kernels(fn, tuple(args))
-    expected = ring_dead_block_fraction(
-        pos, max_seq, engine.attention_block_k) if ana.kernels else None
-    return ana, expected
+    if not ana.kernels:
+        return ana, None
+    if engine.kv_layout == "paged":
+        # the paged kernel does not launch what the ring kernel elides
+        return ana, paged_dead_block_fraction(
+            pos, pt, engine.page_size, engine.attention_block_k)
+    return ana, ring_dead_block_fraction(pos, max_seq,
+                                        engine.attention_block_k)
 
 
 def audit_decode(rules=None, config_overrides=None, kv_cache_dtype=None,

@@ -38,6 +38,17 @@ properties the Mosaic compiler will not check for us:
    look up the page) actually dedupes dead blocks — and prices the
    kernel's real HBM traffic for the cost model.
 
+   A kernel may instead leave an operand where it is
+   (``memory_space=pl.ANY``) and fetch from it by manual DMA inside
+   the step — the paged decode kernel walks each row's live blocks of
+   the pool that way. Such an operand has no pipelined block and no
+   index map: it costs no VMEM beyond the scratch slots the kernel
+   declares, and its traffic is what the kernel's *declared walk*
+   (:data:`MANUAL_WALKS`, by kernel name) says for the captured
+   scalar-prefetch values: blocks fetched against the blocks a dense
+   grid over the same rectangle would visit. "Elided" then reads "not
+   launched".
+
 4. **Grid-write races** — an output block revisited at NON-consecutive
    grid steps is undefined behavior in Pallas's grid semantics (the
    block is flushed when the grid moves away and re-fetched stale).
@@ -91,6 +102,7 @@ class OperandFacts:
     dma_fetches: int             # after consecutive-step elision
     elided_fraction: float       # 1 - dma_fetches / total_fetches
     index_map_evaluated: bool
+    manual_dma: bool = False     # left in HBM, fetched by the kernel
 
     def to_dict(self):
         return dataclasses.asdict(self)
@@ -404,22 +416,24 @@ def _block_bytes(block_shape, dtype):
     return n * np.dtype(dtype).itemsize
 
 
+def _scratch_avals(eqn):
+    """The kernel's declared scratch refs, in order: the kernel jaxpr's
+    trailing invars."""
+    n = int(getattr(eqn.params["grid_mapping"], "num_scratch_operands", 0))
+    invars = eqn.params["jaxpr"].invars
+    return [v.aval for v in invars[len(invars) - n:]] if n else []
+
+
 def _scratch_bytes(eqn):
-    """Declared scratch bytes: the kernel jaxpr's trailing refs."""
-    gm = eqn.params["grid_mapping"]
-    n = int(getattr(gm, "num_scratch_operands", 0))
-    if not n:
-        return 0
-    body = eqn.params["jaxpr"]
+    """Declared scratch bytes (a semaphore holds none)."""
     total = 0
-    for var in body.invars[len(body.invars) - n:]:
-        aval = var.aval
-        shape = getattr(aval, "shape", ())
-        dtype = getattr(aval, "dtype", np.float32)
-        size = 1
-        for d in shape:
-            size *= int(d)
-        total += size * np.dtype(dtype).itemsize
+    for aval in _scratch_avals(eqn):
+        try:
+            item = np.dtype(getattr(aval, "dtype", np.float32)).itemsize
+        except TypeError:           # a semaphore's dtype is no data type
+            continue
+        total += int(np.prod(getattr(aval, "shape", ()), dtype=np.int64)) \
+            * item
     return total
 
 
@@ -452,14 +466,44 @@ class _Block:
     array_shape: tuple
     dtype: str
     index_map: object            # ClosedJaxpr | None
+    in_hbm: bool = False         # ``memory_space=pl.ANY``: not pipelined
 
 
 def _block_of(bm):
+    from jax.experimental import pallas as pl
     aval = bm.array_aval
+    space = getattr(getattr(bm, "transformed_block_aval", None),
+                    "memory_space", None)
     return _Block(block_shape=tuple(bm.block_shape),
                   array_shape=tuple(int(d) for d in aval.shape),
                   dtype=str(np.dtype(aval.dtype)),
-                  index_map=getattr(bm, "index_map_jaxpr", None))
+                  index_map=getattr(bm, "index_map_jaxpr", None),
+                  in_hbm=space == pl.ANY)
+
+
+def _paged_decode_walk(scalar_vals, blocks, scratch):
+    """`ops/pallas/flash_decode.py:flash_decode_paged`'s declared walk:
+    ``{operand index: (block shape, dense fetches, fetched)}`` for the
+    pool operands it leaves in HBM. Slot ``j`` of the scratch is the
+    ``(2, *block)`` double buffer of the ``j``-th such operand; a dense
+    grid would visit every row's ``pages_per_row * page / block_k``
+    blocks, the kernel fetches the live rows' live ones
+    (:func:`~deepspeed_tpu.ops.pallas.flash_decode.paged_grid_blocks`,
+    which is its loop bound)."""
+    from deepspeed_tpu.ops.pallas.flash_decode import paged_grid_blocks
+    positions, tables = scalar_vals[:2]
+    pools = [i for i, b in enumerate(blocks) if b.in_hbm]
+    block_k = scratch[0][-1]
+    page = blocks[pools[0]].array_shape[-1]
+    dense = tables.shape[0] * tables.shape[1] * (page // block_k)
+    _, launched = paged_grid_blocks(positions, tables, block_k)
+    return {i: (scratch[j][1:], dense, launched)
+            for j, i in enumerate(pools)}
+
+
+# kernels that fetch by manual DMA, by pallas_call name: what they
+# fetch for given scalar-prefetch values
+MANUAL_WALKS = {"ds_flash_decode_paged": _paged_decode_walk}
 
 
 def _eval_index_map(index_map, grid, scalar_vals, rank):
@@ -538,11 +582,44 @@ def kernel_facts(eqn, invals=None, grid_point_cap=DEFAULT_GRID_POINT_CAP):
 
     operands, tiling, races = [], [], []
     block_bytes_total = dense_total = dma_total = 0
-    mappings = list(gm.block_mappings)
-    for i, bm in enumerate(mappings):
+    blocks = [_block_of(bm) for bm in gm.block_mappings]
+    walk = {}
+    if any(b.in_hbm for b in blocks):
+        declared = MANUAL_WALKS.get(name)
+        if declared is None:
+            notes.append(f"operands left in HBM but no declared walk "
+                         f"for kernel {name!r}: their traffic is not "
+                         f"priced")
+        elif scalar_vals is None:
+            notes.append("manual-DMA walk reads scalar-prefetch operands "
+                         "but no concrete values were captured")
+        else:
+            walk = declared(scalar_vals, blocks, [
+                tuple(int(d) for d in getattr(a, "shape", ()))
+                for a in _scratch_avals(eqn)])
+    for i, block in enumerate(blocks):
         kind = "input" if i < n_in else "output"
         opname = f"in{i}" if i < n_in else f"out{i - n_in}"
-        block = _block_of(bm)
+        if block.in_hbm:
+            # nothing of it is pipelined into VMEM (the kernel's slots
+            # are scratch); its traffic is the declared walk's
+            shape, dense, fetched = walk.get(
+                i, (block.array_shape[1:], 0, 0))
+            bbytes = _block_bytes(shape, block.dtype)
+            cut = _Block(tuple(shape), block.array_shape[1:], block.dtype,
+                         None)
+            tiling.extend(_tiling_lint(opname, cut, cut))
+            dense_total += dense * bbytes
+            dma_total += fetched * bbytes
+            operands.append(OperandFacts(
+                name=opname, kind=kind, block_shape=tuple(shape),
+                array_shape=block.array_shape, dtype=block.dtype,
+                block_bytes=bbytes, total_fetches=dense,
+                distinct_blocks=fetched, dma_fetches=fetched,
+                elided_fraction=round(1.0 - fetched / dense, 6)
+                if dense else 0.0,
+                index_map_evaluated=i in walk, manual_dma=True))
+            continue
         bbytes = _block_bytes(block.block_shape, block.dtype)
         block_bytes_total += bbytes
         tiling.extend(_tiling_lint(opname, block, block))
@@ -640,3 +717,19 @@ def ring_dead_block_fraction(positions, max_seq, block_k):
         return 0.0
     live = sum(min(p // int(block_k) + 1, n_kb) for p in rows)
     return 1.0 - live / (len(rows) * n_kb)
+
+
+def paged_dead_block_fraction(positions, page_tables, page_size, block_k):
+    """The paged counterpart: the fraction of the ``rows x
+    pages_per_row x page_size / block_k`` rectangle (in blocks of all
+    heads) that holds nothing — blocks past a live row's position and
+    every block of a row without a request (first table entry the trash
+    page). The paged kernel launches none of them."""
+    from deepspeed_tpu.ops.pallas.flash_decode import paged_grid_blocks
+    tables = np.asarray(page_tables)
+    dense = tables.shape[0] * tables.shape[1] * (
+        int(page_size) // int(block_k))
+    if not dense:
+        return 0.0
+    live, _ = paged_grid_blocks(positions, tables, block_k)
+    return 1.0 - live / dense

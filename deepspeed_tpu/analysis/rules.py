@@ -940,9 +940,16 @@ def rule_flash_decode(ctx):
       dequantized HBM copy — flash dequantizes in-register per block;
     - over the paged pool, NO ``copy`` may have a pool leaf's shape
       (`payload_shaped_copies`): the pool is donated and written a page
-      slab at a time, in the layout the kernel reads, so such a copy is
-      XLA re-laying out the whole reserved pool on every step, live or
-      not (measured at three quarters of a step: `PERF.md`, PR 25).
+      slab at a time, in the layout the kernel reads — the kernel takes
+      the 4-D pool as it is, in ``ANY`` memory, and cuts its ``(H, D,
+      block_k)`` blocks from it itself — so such a copy is XLA re-laying
+      out the whole reserved pool on every step, live or not (measured
+      at three quarters of a step: `PERF.md`, PR 25). Judged where the
+      kernel is a kernel: off-TPU interpret mode inlines it as plain
+      HLO and carries the ``ANY`` operand through its emulated grid
+      loop by copy, which says nothing about the chip
+      (`tests/unit/test_tpu_compile.py` holds the compiled program at 0
+      on a described v5e).
     """
     if ctx.decode_attention_impl != "flash":
         return []
@@ -960,7 +967,8 @@ def rule_flash_decode(ctx):
                                                 payload_shaped_dots,
                                                 payload_shaped_values)
         copies = payload_shaped_copies(ctx.hlo_text, payload) \
-            if ctx.decode_kv_layout == "paged" else []
+            if ctx.decode_kv_layout == "paged" \
+            and ctx.decode_platform in (None, "tpu") else []
         if copies:
             findings.append(Finding(
                 "flash_decode", SEV_ERROR,
@@ -1207,10 +1215,12 @@ def rule_kernel_dma(ctx):
     (``kernel_expected_elision``, the dead-block fraction implied by
     the analysis scenario's positions), the byte-weighted INPUT elided
     fraction proved by the index-map sweep must reach it — this is the
-    static proof that the flash-decode clamp trick
-    (`ops/pallas/flash_decode.py` ``kv_map``/``_physical``) actually
-    turns dead cache blocks into elided DMAs, instead of asserting it
-    in prose.
+    static proof that the ring kernel's clamp trick
+    (`ops/pallas/flash_decode.py` ``kv_map``) actually turns dead cache
+    blocks into elided DMAs, instead of asserting it in prose. The
+    paged kernel launches no dead block at all: its pool operands stay
+    in HBM and their fetches are its declared walk
+    (`kernels.MANUAL_WALKS`), held to the same contract.
     """
     ana = ctx.kernel_analysis
     if ana is None:
@@ -1230,9 +1240,12 @@ def rule_kernel_dma(ctx):
         in_dma = in_dense = 0
         unevaluated = []
         for k in ana.kernels:
-            for op in k.operands:
-                if op.kind != "input":
-                    continue
+            inputs = [op for op in k.operands if op.kind == "input"]
+            # a kernel that walks the cache by manual DMA is judged on
+            # what it walks: its pipelined inputs are one row block a
+            # grid step, with nothing to elide
+            walked = [op for op in inputs if op.manual_dma]
+            for op in walked or inputs:
                 in_dma += op.dma_fetches * op.block_bytes
                 in_dense += op.total_fetches * op.block_bytes
                 if not op.index_map_evaluated:
@@ -1253,7 +1266,8 @@ def rule_kernel_dma(ctx):
                 f"index maps elide only {proved:.1%} of input block "
                 f"DMAs; the scenario's occupancy requires "
                 f"{expected:.1%} — dead cache blocks are being "
-                f"fetched (unclamped index map?)",
+                f"fetched (unclamped index map, or a walk past the "
+                f"rows' positions?)",
                 {"proved_elision": round(proved, 6),
                  "expected_elision": round(expected, 6),
                  "input_dma_bytes": in_dma,

@@ -12,9 +12,10 @@ The **paged** layout (``KVCacheSpec.page_size > 0``) replaces the
 per-row ring with one pool of fixed-size pages per layer,
 ``[n_pages, n_head, head_dim, page_size]``: a page's positions lie on
 the TPU's 128 lanes and ``head_dim`` on the sublanes, so the array's
-default tiled layout is the one the flash kernel's ``[head_dim,
-block_k]`` blocks are cut from, with no lane padding at ``head_dim``
-64 — XLA re-lays nothing out around the kernel. The price is that one
+default tiled layout is the one the flash kernel's ``[n_head,
+head_dim, block_k]`` blocks (all heads of a row to a fetch) are cut
+from, with no lane padding at ``head_dim`` 64 — XLA re-lays nothing
+out around the kernel. The price is that one
 position is one lane of every tile of its page, so a write reads and
 writes back the page's whole ``[n_head, head_dim, page_size]`` slab
 (:func:`paged_write_kv`), indexing dynamically on the page axis alone.
@@ -445,13 +446,18 @@ def _flash_attend(q, layer_cache, positions, block_k, mesh):
 
 def _flash_attend_paged(q, layer_cache, positions, page_table, block_k,
                         mesh):
-    """Paged twin of :func:`_flash_attend`: the kernel gathers KV
-    blocks straight out of the pool through the scalar-prefetched page
-    table (`ops/pallas/flash_decode.py:flash_decode_paged`) — this
-    code gathers and transposes nothing, and neither does XLA around
-    the call: the pool's tiled layout is the kernel's. Under TP the
-    pool shards on its head axis 1 (`kv_partition_specs`); the query
-    and the output keep the model's ``[B, 1, H, D]`` layout."""
+    """Paged twin of :func:`_flash_attend`: the kernel fetches each
+    row's live KV blocks straight out of the pool through the scalar-
+    prefetched page table
+    (`ops/pallas/flash_decode.py:flash_decode_paged`) — this code
+    gathers and transposes nothing, and neither does XLA around the
+    call: the pool's tiled layout is the kernel's. A row whose table
+    starts with the trash page holds no request: the kernel runs
+    nothing for it and its output is zeros (the scheduler ignores such
+    rows). Under TP the pool shards on its head axis 1
+    (`kv_partition_specs`) and each shard's kernel sees its local
+    heads; the query and the output keep the model's ``[B, 1, H, D]``
+    layout."""
     from deepspeed_tpu.ops.pallas import flash_decode_paged
 
     pos = positions[:, 0]

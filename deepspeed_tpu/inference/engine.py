@@ -211,17 +211,26 @@ class InferenceEngine:
                                    n_pages=self.n_pages)
         self.n_pages = self.spec.n_pages
         self.pages_per_row = self.spec.pages_per_row
+        # the paged flash kernel's visit set, for decode()'s counters
+        self._paged_grid_blocks = None
         if self.attention_impl == "flash":
             # a block/storage geometry the flash kernel cannot compile
             # for this device is a typed error here, at build time
             from deepspeed_tpu.ops.pallas.flash_decode import (
-                check_decode_geometry)
+                check_decode_geometry, paged_grid_blocks)
             paged = self.kv_layout == "paged"
+            if paged:
+                self._paged_grid_blocks = paged_grid_blocks
             extent, name = (self.page_size, "page_size") if paged \
                 else (self.max_seq, "max_seq")
+            quant = self.spec.codec is not None
+            # the paged kernel holds all the heads a device has
+            tp = dict(mesh.shape).get("model", 1) if mesh is not None else 1
             self.attention_block_k = check_decode_geometry(
                 self.attention_block_k, extent, name, self.spec.dtype,
-                paged or self.spec.codec is not None)
+                paged or quant,
+                paged_heads=(self.spec.n_head // tp, self.spec.head_dim,
+                             quant) if paged else None)
         self.mesh = mesh
         self.session = session
         self._sample_key = jax.random.PRNGKey(self.sampling_seed)
@@ -443,11 +452,19 @@ class InferenceEngine:
         if paged and page_tables is None:
             raise ValueError("paged decode requires page_tables")
         session = self.session
+        attrs = None
+        if self._paged_grid_blocks is not None:
+            # how far the kernel's grid follows the cache: KV blocks (of
+            # all heads) the live rows hold against those it visits
+            live, launched = self._paged_grid_blocks(
+                positions, page_tables, self.attention_block_k)
+            attrs = {"kv_blocks_live": live,
+                     "kv_blocks_launched": launched}
         # four spans, so that a gap on the device can be laid to the
         # part of the call the host was in: the uploads, the dispatch,
         # the wait for the tokens (the device's own time), the logits'
         # copy to the host
-        with Span("decode", session):
+        with Span("decode", session, attrs):
             with Span("upload", session):
                 args = [jnp.asarray(np.asarray(tokens, np.int32)),
                         jnp.asarray(np.asarray(positions, np.int32))]

@@ -160,6 +160,21 @@ class TestRuleFlashDecode:
             assert fs[0].severity == "error"
             assert fs[0].details["pool_shaped_copies"] == n
 
+    @pytest.mark.parametrize("platform,n", [("tpu", 1), (None, 1),
+                                            ("cpu", 0)])
+    def test_pool_shaped_copy_is_judged_where_the_kernel_is_one(
+            self, platform, n):
+        # off-TPU the paged kernel is interpret mode's plain HLO, which
+        # carries the pool (an `ANY`-memory operand) through its
+        # emulated grid loop by copy: that says nothing about the chip
+        hlo = ("%copy.7 = f32[2,32,4,8]{3,2,1,0} copy(%get-tuple-element.9)\n"
+               "%k = f32[2,4,8]{2,1,0} custom-call(%p, %copy.7)")
+        ctx = StepContext(hlo_text=hlo, decode_attention_impl="flash",
+                          decode_kv_layout="paged",
+                          decode_platform=platform,
+                          decode_cache_payload_shape=_PAYLOAD)
+        assert len(rule_flash_decode(ctx)) == n
+
     def test_missing_custom_call_only_errors_on_tpu(self):
         ctx_cpu = StepContext(hlo_text=_BLOCK_DOT,
                               decode_attention_impl="flash",

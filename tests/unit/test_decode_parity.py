@@ -23,6 +23,14 @@ Two rows run concurrently at different lengths/offsets, so the test
 also pins row isolation and positions crossing prefill-chunk and
 bucket boundaries.
 
+The paged flash rows (`test_paged_flash_parity_with_a_dead_row`) run
+the same comparison through the pool and the paged kernel, whose grid
+is one step a row: three cache rows of which the middle one never
+holds a request (position 0, an all-trash table), the last row ending
+on the last position of its last page, over every pool dtype; and they
+read the two counters `engine.decode` puts on its span
+(``kv_blocks_live``, ``kv_blocks_launched``).
+
 Sampling sanity (`inference/sampling.py`): the in-program sampler's
 degenerate corners collapse to greedy bit-exactly (temperature 0 by
 the static-path contract, top_k=1 because the filter leaves one
@@ -115,6 +123,67 @@ def test_teacher_forced_parity(name, impl, scan, kvdt, atol):
                 err_msg=f"{name}: decode row {r} pos {pos[r]}")
             pos[r] += 1
 
+    assert eng.compile_counts() == {"prefill": 1, "decode": 1}
+
+
+PAGED_CASES = [
+    ("f32", None, 2e-6, ()),
+    ("int8", "int8", 0.2, ()),
+    ("bf16", "bf16", 0.05, ()),
+    ("f8e4m3fn", "f8e4m3fn", 0.2, ()),
+    ("f8e5m2", "f8e5m2", 0.4, ()),
+]
+
+
+@pytest.mark.parametrize(
+    "kvdt,atol", [pytest.param(*c[1:3], marks=c[3], id=c[0])
+                  for c in PAGED_CASES])
+def test_paged_flash_parity_with_a_dead_row(kvdt, atol):
+    from deepspeed_tpu.telemetry import spans
+
+    model, params, eng = _build(False, kvdt, "flash", kv_layout="paged",
+                                max_batch=3, page_size=8)
+    ppr = eng.pages_per_row                 # 4 pages of 8 positions
+    tables = np.zeros((3, ppr), np.int32)   # row 1: no request
+    tables[0] = 1 + np.arange(ppr)
+    tables[2] = 1 + ppr + np.arange(ppr)
+    rng = np.random.default_rng(0)
+    # row 0 ends mid-page; row 2 on the last position of its last page
+    seqs = {0: rng.integers(0, 64, 21).tolist(),
+            2: rng.integers(0, 64, 32).tolist()}
+    refs = {r: np.asarray(model.apply(
+        {"params": params}, jnp.asarray([seq], jnp.int32),
+        deterministic=True)[0], np.float32) for r, seq in seqs.items()}
+    pos = {0: 10, 2: 14}
+    for r, seq in seqs.items():
+        last = eng.prefill(r, seq[:pos[r]], page_table=tables[r])
+        np.testing.assert_allclose(last, refs[r][pos[r] - 1], atol=atol)
+
+    while any(pos[r] < len(seqs[r]) for r in seqs):
+        tokens = np.zeros(3, np.int32)
+        positions = np.zeros(3, np.int32)
+        step_tables = np.zeros_like(tables)
+        live = [r for r in seqs if pos[r] < len(seqs[r])]
+        for r in live:
+            tokens[r] = seqs[r][pos[r]]
+            positions[r] = pos[r]
+            step_tables[r] = tables[r]
+        since = spans.clock()
+        _, logits = eng.decode(tokens, positions, page_tables=step_tables)
+        for r in live:
+            np.testing.assert_allclose(
+                logits[r], refs[r][pos[r]], atol=atol,
+                err_msg=f"decode row {r} pos {pos[r]}")
+        # the counters of this step: blocks of 8 positions the live
+        # rows hold, and the kernel's grid visits those and no other
+        attrs, = [rec[3] for rec in spans.recent(since)
+                  if rec[0] == "decode"]
+        want = sum(pos[r] // 8 + 1 for r in live)
+        assert attrs == {"kv_blocks_live": want,
+                         "kv_blocks_launched": want}
+        for r in live:
+            pos[r] += 1
+    assert pos[2] == 32 == eng.max_seq      # the last slot was attended
     assert eng.compile_counts() == {"prefill": 1, "decode": 1}
 
 

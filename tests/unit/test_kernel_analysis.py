@@ -32,6 +32,7 @@ from deepspeed_tpu.analysis.audit import (
 from deepspeed_tpu.analysis.cost import estimate_step_cost
 from deepspeed_tpu.analysis.kernels import (
     analyze_kernels,
+    paged_dead_block_fraction,
     ring_dead_block_fraction,
 )
 from deepspeed_tpu.analysis.rules import (
@@ -231,9 +232,23 @@ def test_stock_decode_zero_findings(layout, ring_report, paged_report):
         assert kd["vmem_bytes"] <= ks["vmem_budget_bytes"]
         assert kd["races"] == []
         assert kd["tiling"] == []
-        # the proven per-kernel elision beats the contract (q/out
-        # operands elide MORE than the KV floor)
-        assert kd["elided_dma_fraction"] >= TOY_EXPECTED_ELISION
+        pools = [op for op in kd["operands"].values()
+                 if op["manual_dma"]]
+        if layout == "ring":
+            # the proven per-kernel elision beats the contract (q/out
+            # operands elide MORE than the KV floor)
+            assert not pools
+            assert kd["elided_dma_fraction"] >= TOY_EXPECTED_ELISION
+        else:
+            # the paged kernel leaves the pool in HBM and walks it: K
+            # and V launch exactly the live blocks (q and out are one
+            # row block a grid step, with nothing to elide), and only
+            # the two double buffers are VMEM
+            assert len(pools) == 2 and kd["grid"] == [2]
+            assert all(op["elided_fraction"] == pytest.approx(
+                TOY_EXPECTED_ELISION) for op in pools)
+            assert kd["scratch_bytes"] == sum(
+                2 * op["block_bytes"] for op in pools)
 
 
 @pytest.mark.slow
@@ -252,7 +267,8 @@ def test_stock_flash_train_zero_findings():
     report = audit_flash_train()
     assert report.findings == []
     ks = report.stats["kernels"]
-    assert set(ks["kernels"]) == {"kernel", "dq_kernel", "dkv_kernel"}
+    assert set(ks["kernels"]) == {"ds_flash_fwd", "ds_flash_dq",
+                                  "ds_flash_dkv"}
     for kd in ks["kernels"].values():
         # the backward accumulators revisit output blocks ONLY at
         # consecutive grid steps (carried-accumulator idiom) — no race
@@ -272,13 +288,18 @@ def _cost_facts(report):
 
 
 @pytest.mark.slow
-def test_kernel_traffic_flips_block_k_ranking(paged_report):
+@pytest.mark.parametrize("layout", ["ring", "paged"])
+def test_kernel_traffic_flips_block_k_ranking(layout, ring_report,
+                                              paged_report):
     # Pinned scenario (ISSUE 19): at the toy occupancy, block_k=4
-    # fetches FEWER live bytes (finer blocks track the ragged fill)
-    # but MORE dense bytes (more grid steps re-touch q/out). Dense
-    # pricing therefore prefers block_k=8; the elision-aware DMA
-    # pricing flips the ranking to block_k=4.
-    bk4 = audit_decode(kernels=True, kv_layout="paged",
+    # fetches FEWER live bytes (finer blocks track the ragged fill).
+    # The ring kernel launches a grid step a block, so it also pays
+    # MORE dense bytes (more steps re-touch q/out): dense pricing
+    # prefers block_k=8 and the elision-aware DMA pricing flips the
+    # ranking to block_k=4. The paged kernel (PR 27) touches q and out
+    # once a row whatever block_k: the dense rectangle is the same
+    # bytes at both, and only the DMA pricing tells them apart.
+    bk4 = audit_decode(kernels=True, kv_layout=layout,
                        config_overrides={"attention_block_k": 4})
     # the pool cuts [D, block_k] KV blocks, positions on the lanes, so
     # a 4-position block of an 8-position page is honestly sub-tile and
@@ -286,18 +307,88 @@ def test_kernel_traffic_flips_block_k_ranking(paged_report):
     # all the pricing needs
     assert {f.rule for f in bk4.findings} <= {"kernel_tiling"}
     assert all(f.severity == "warning" for f in bk4.findings)
-    f4, f8 = _cost_facts(bk4), _cost_facts(paged_report)
+    f4 = _cost_facts(bk4)
+    f8 = _cost_facts(ring_report if layout == "ring" else paged_report)
 
     def step_s(facts, traffic):
         return estimate_step_cost("", n_devices=2, kernel_facts=facts,
                                   kernel_traffic=traffic).step_seconds
 
     assert step_s(f4, "dma") < step_s(f8, "dma")
-    assert step_s(f8, "dense") < step_s(f4, "dense")
+    if layout == "ring":
+        assert step_s(f8, "dense") < step_s(f4, "dense")
+    else:
+        assert step_s(f8, "dense") == step_s(f4, "dense")
 
     with pytest.raises(ValueError, match="kernel_traffic"):
         estimate_step_cost("", n_devices=2, kernel_facts=f4,
                            kernel_traffic="bogus")
+
+
+# ---------------------------------------------------------------------------
+# the paged decode kernel: pool operands left in HBM, priced by its walk
+# ---------------------------------------------------------------------------
+
+# (positions, first table entries): three live rows and a dead one
+WALK_POSITIONS = np.array([5, 0, 31, 16], np.int32)
+WALK_TABLES = np.array([[1, 0, 0, 0], [0, 0, 0, 0], [2, 3, 4, 5],
+                        [6, 7, 8, 0]], np.int32)
+
+
+@pytest.mark.parametrize("block_k,live", [(8, 1 + 4 + 3), (4, 2 + 8 + 5)])
+def test_paged_dead_block_fraction(block_k, live):
+    """The paged counterpart of `ring_dead_block_fraction`: blocks past
+    a live row's position AND every block of a row without a request,
+    out of the rows x pages x page / block_k rectangle."""
+    dense = 4 * 4 * (8 // block_k)
+    assert paged_dead_block_fraction(
+        WALK_POSITIONS, WALK_TABLES, 8, block_k) == \
+        pytest.approx(1.0 - live / dense)
+    # all rows live: the ring's fraction for the same positions
+    tables = np.arange(1, 17, dtype=np.int32).reshape(4, 4)
+    assert paged_dead_block_fraction(
+        WALK_POSITIONS, tables, 8, block_k) == pytest.approx(
+            ring_dead_block_fraction(WALK_POSITIONS, 32, block_k))
+
+
+def test_paged_kernel_is_priced_by_its_walk():
+    """`flash_decode_paged` leaves the pool in `ANY` memory and fetches
+    by manual DMA: no block of it is pipelined (the two slots are
+    scratch), and its traffic is the declared walk's — the live rows'
+    live blocks against the dense rectangle."""
+    from deepspeed_tpu.ops.pallas import flash_decode_paged
+
+    H, D, page = 2, 8, 8
+    rng = np.random.default_rng(0)
+    pool = jnp.asarray(rng.normal(size=(9, H, D, page)), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(4, 1, H, D)), jnp.float32)
+    ana = analyze_kernels(
+        lambda *a: flash_decode_paged(*a, block_k=8),
+        (q, pool, pool, jnp.asarray(WALK_POSITIONS),
+         jnp.asarray(WALK_TABLES)))
+    k, = ana.kernels
+    assert k.name == "ds_flash_decode_paged" and k.grid == (4,)
+    ops = {op.name: op for op in k.operands}
+    block = H * D * 8 * 4
+    for name in ("in1", "in2"):                     # K and V
+        op = ops[name]
+        assert op.manual_dma and op.index_map_evaluated
+        assert op.block_shape == (H, D, 8) and op.block_bytes == block
+        assert (op.total_fetches, op.dma_fetches) == (16, 8)
+    assert not ops["in0"].manual_dma and not ops["out0"].manual_dma
+    # VMEM: q and out double-buffered, plus the four scratch slots
+    row = H * D * 4
+    assert k.scratch_bytes == 4 * block
+    assert k.vmem_bytes == 2 * 2 * row + 4 * block
+    assert k.dma_bytes == 2 * 8 * block + 2 * 4 * row
+    # the contract the audit declares for this scenario holds, and a
+    # kernel that walked every block would not meet it
+    expected = paged_dead_block_fraction(WALK_POSITIONS, WALK_TABLES, 8, 8)
+    assert _kernel_rule_findings(ana, expected) == []
+    for name in ("in1", "in2"):
+        ops[name].dma_fetches = 16
+    dense, = _kernel_rule_findings(ana, expected)
+    assert dense.rule == "kernel_dma" and dense.severity == SEV_WARNING
 
 
 def test_serving_search_space_has_block_dimension():

@@ -198,9 +198,65 @@ def test_paged_layer_copies_no_pool(chip, monkeypatch, program, kv_dtype):
         x, x, x, layer, chip((rows, tokens), jnp.int32),
         chip((rows, T // PAGE), jnp.int32)).compile().as_text()
     assert ("tpu_custom_call" in text) == (program == "decode")
+    # the kernel takes the 4-D pool as it is (`ANY` memory)
     assert payload_shaped_copies(text, pool) == []
-    # the kernel's view of the pool, (page, head) merged
-    assert payload_shaped_copies(text, (N_PAGES * H, D, PAGE)) == []
+
+
+def kernel_grids(lowered_text):
+    """The grid of every Mosaic kernel in a lowered (StableHLO) text, in
+    order: each `tpu_custom_call` carries its serialized Mosaic module,
+    whose entry function has the grid as `iteration_bounds`."""
+    import base64
+    import json
+    import re
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    grids = []
+    ctx = jax_mlir.make_ir_context()
+    tpu.register_dialect(ctx)
+    ctx.allow_unregistered_dialects = True      # `stable_mosaic.*`
+    with ctx:
+        for config in re.findall(
+                r'@tpu_custom_call\(.*?backend_config = "(\{.*?\})"',
+                lowered_text, re.S):
+            body = json.loads(config.replace("\\22", '"'))[
+                "custom_call_config"]["body"]
+            module = ir.Module.parse(base64.b64decode(body))
+            for op in module.body.operations:
+                if "iteration_bounds" in op.attributes:
+                    grids.append(tuple(
+                        ir.DenseI64ArrayAttr(
+                            op.attributes["iteration_bounds"])))
+    return grids
+
+
+@pytest.mark.parametrize("layout", ["ring", "paged"])
+def test_flash_decode_grid(chip, layout):
+    """Steps a layer at the serve cell's shape, read from the lowered
+    call itself. The paged kernel launches one step a row, 48; until PR
+    27 it launched rows x heads x blocks = 6,144 whatever the rows held
+    (`PERF.md` section 6), and a change that brings that back fails
+    here. The ring kernel is no cell's and keeps its 768 x 8."""
+    from deepspeed_tpu.ops.pallas.flash_decode import (
+        flash_decode, flash_decode_paged)
+
+    q = chip((ROWS, 1, H, D), jnp.float32)
+    pos = chip((ROWS,), jnp.int32)
+    if layout == "ring":
+        kv = chip((ROWS, T, H, D), jnp.float32)
+        lowered = jax.jit(lambda q, k, v, pos: flash_decode(
+            q, k, v, pos, interpret=False)).lower(q, kv, kv, pos)
+        want = (ROWS * H, T // PAGE)
+    else:
+        kv = chip((N_PAGES, H, D, PAGE), jnp.float32)
+        lowered = jax.jit(lambda q, k, v, pos, pt: flash_decode_paged(
+            q, k, v, pos, pt, interpret=False)).lower(
+                q, kv, kv, pos, chip((ROWS, T // PAGE), jnp.int32))
+        want = (ROWS,)
+    assert kernel_grids(lowered.as_text()) == [want]
 
 
 @pytest.mark.parametrize("shape", [(50257, 1024), (1024, 4096), (1024,)],
