@@ -525,8 +525,7 @@ from deepspeed_tpu.models.gpt2 import GPT2LMHead, gpt2_tiny
 from deepspeed_tpu.parallel.mesh import build_mesh
 
 
-def facts(kv_cache_dtype, mesh=None, attention_impl="dense",
-          kv_layout="ring"):
+def facts(kv_cache_dtype, mesh=None, attention_impl="dense"):
     cfg = gpt2_tiny(n_embd=32, dtype=jnp.float32)
     model = GPT2LMHead(cfg)
     params = model.init(jax.random.PRNGKey(0),
@@ -534,8 +533,7 @@ def facts(kv_cache_dtype, mesh=None, attention_impl="dense",
     eng = InferenceEngine(model, params, config={
         "max_batch": 2, "seq_buckets": (16, 32), "prefill_chunk": 4,
         "kv_cache_dtype": kv_cache_dtype,
-        "attention_impl": attention_impl, "attention_block_k": 8,
-        "kv_layout": kv_layout},
+        "attention_impl": attention_impl, "attention_block_k": 8},
         mesh=mesh)
     rng = np.random.default_rng(0)
     reqs = [Request(f"r{i}",
@@ -587,12 +585,11 @@ def flash_ab(max_seq):
 
 def paged_ab():
     # paged-vs-ring serving A/B over the SAME shared-prefix stream:
-    # a ring session always owns a full max_seq row, a paged session
-    # only the pages its tokens occupy — report cache bytes/session,
-    # sessions admittable at fixed HBM, and the prefill chunks the
-    # radix prefix cache let admissions skip. Greedy outputs must
-    # match bit-for-bit (keyed by rid; paged may reorder under pool
-    # pressure).
+    # a session reserved at full length ("ring": the per-row layout
+    # that went with PR 28) owns a whole max_seq row of pages, a paged
+    # session only the pages its tokens occupy — report cache
+    # bytes/session, sessions admittable at fixed HBM, and the prefill
+    # chunks the radix prefix cache let admissions skip.
     cfg = gpt2_tiny(n_embd=32, dtype=jnp.float32)
     model = GPT2LMHead(cfg)
     params = model.init(jax.random.PRNGKey(0),
@@ -608,14 +605,8 @@ def paged_ab():
                         max_new_tokens=4)
                 for i in range(6)]
 
-    def build(layout):
-        return InferenceEngine(model, params, config={
-            "max_batch": 2, "seq_buckets": (16, 32),
-            "prefill_chunk": 4, "kv_layout": layout})
-
-    ring = build("ring")
-    ring_comps = ContinuousBatchingScheduler(ring).run(stream())
-    paged = build("paged")
+    paged = InferenceEngine(model, params, config={
+        "max_batch": 2, "seq_buckets": (16, 32), "prefill_chunk": 4})
     sched = ContinuousBatchingScheduler(paged)
     comps = sched.run(stream())
     pg = sched.paging.facts()
@@ -623,11 +614,10 @@ def paged_ab():
     kv_lens = [c.prompt_len + len(c.tokens) - 1 for c in comps]
     pages = [-(-n // ps) for n in kv_lens]
     paged_bps = pb * sum(pages) / len(pages)
-    ring_bps = ring.cache_facts()["bytes"] / ring.max_batch
+    ring_bps = pb * paged.pages_per_row
     pool = paged.cache_facts()["bytes"]
     run = sum(c.prefill_chunks for c in comps)
     skipped = sum(c.prefill_chunks_skipped for c in comps)
-    ring_by_rid = {c.rid: c.tokens for c in ring_comps}
     return {
         "page_size": ps, "n_pages": pg["n_pages"],
         "ring_cache_bytes_per_session": ring_bps,
@@ -642,9 +632,7 @@ def paged_ab():
         "prefill_chunks_run": run,
         "prefill_chunks_skipped": skipped,
         "prefill_skip_fraction": skipped / max(run + skipped, 1),
-        "compile_counts": paged.compile_counts(),
-        "greedy_outputs_match":
-            all(ring_by_rid[c.rid] == c.tokens for c in comps)}
+        "compile_counts": paged.compile_counts()}
 
 
 def speculative_ab():
@@ -730,7 +718,7 @@ def disagg_ab():
 
     def build(tier=None):
         c = {"max_batch": 2, "seq_buckets": (16, 32),
-             "prefill_chunk": 4, "kv_layout": "paged"}
+             "prefill_chunk": 4}
         if tier is not None:
             c["tier"] = tier
         return InferenceEngine(model, params, config=c)
@@ -765,8 +753,7 @@ quant = facts("int8")
 tp = facts(None, mesh=build_mesh({"model": 4},
                                  devices=jax.devices()[:4]))
 flash_int8 = facts("int8", attention_impl="flash")
-paged_flash_int8 = facts("int8", attention_impl="flash",
-                         kv_layout="paged")
+paged_flash_int8 = flash_int8
 out = {"n_devices": len(jax.devices()),
        "platform": jax.devices()[0].platform,
        "plain": plain, "int8": quant, "tp4": tp,
@@ -888,7 +875,7 @@ def run_once_disagg(jax, max_batch, n_requests):
     hb(f"disagg A/B init (125M paged, max_batch={max_batch})")
     params = init_gpt2_params(model, jax.random.PRNGKey(0))
     base = {"max_batch": max_batch, "seq_buckets": (128, 512),
-            "prefill_chunk": 64, "kv_layout": "paged"}
+            "prefill_chunk": 64}
 
     def mix():
         # decode-heavy foreground plus long-prompt arrivals landing

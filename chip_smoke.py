@@ -21,10 +21,11 @@ One chip (what the driver runs):
    restores bit-exact and the next step's loss repeats.
 2. serving — the checkpoint step 1 wrote, through `ds_tpu_serve
    --checkpoint <dir> --n-head 16` (`inference/serve.py:main`): max_batch
-   8, max_seq 1024, seeded requests, three times: the defaults
-   (dense/ring), flash/ring, flash/paged. Checks: every request
+   8, max_seq 1024, seeded requests, twice: the defaults (dense
+   attention, pages of two prefill chunks) and flash attention over
+   pages of `page_size`. Checks: every request
    completes, each run compiles exactly 2 programs, the flash decode
-   HLO holds the compiled kernel, and the three runs agree — greedy
+   HLO holds the compiled kernel, and the two runs agree — greedy
    tokens equal, or (where float near-ties split them) logits within a
    stated tolerance of the dense run's; the line says which.
 
@@ -307,7 +308,7 @@ def write_requests(path, sz, seed):
             }) + "\n")
 
 
-def serve_once(sz, seed, ckpt_dir, req_path, impl, layout, on_tpu):
+def serve_once(sz, seed, ckpt_dir, req_path, impl, on_tpu):
     """One `ds_tpu_serve --checkpoint` run, in this process."""
     import jax
     from deepspeed_tpu.analysis.hlo import payload_shaped_copies
@@ -320,10 +321,8 @@ def serve_once(sz, seed, ckpt_dir, req_path, impl, layout, on_tpu):
             "--prefill-chunk", str(sz["prefill_chunk"]),
             "--requests", req_path, "--seed", str(seed),
             "--expect-compiles", "2", "--json"]
-    if impl is not None:        # None = the defaults (dense, ring)
-        argv += ["--attention", impl, "--kv-layout", layout]
-    if layout == "paged":
-        argv += ["--page-size", str(sz["page_size"])]
+    if impl is not None:        # None = the defaults
+        argv += ["--attention", impl, "--page-size", str(sz["page_size"])]
     tap, out = EngineTap(), io.StringIO()
     t0 = time.perf_counter()
     with tap.installed(), contextlib.redirect_stdout(out):
@@ -331,7 +330,7 @@ def serve_once(sz, seed, ckpt_dir, req_path, impl, layout, on_tpu):
     wall = round(time.perf_counter() - t0, 3)
     text = out.getvalue()
     result = json.loads(text[text.index("{"):])
-    label = f"{impl or 'default(dense)'}/{layout or 'default(ring)'}"
+    label = impl or "default(dense)"
     check(rc == 0 and result["ok"],
           f"ds_tpu_serve {label} failed (rc {rc}): compile_counts "
           f"{result.get('compile_counts')}, "
@@ -345,8 +344,10 @@ def serve_once(sz, seed, ckpt_dir, req_path, impl, layout, on_tpu):
               f"after {len(c['tokens'])} tokens")
     eng = tap.engine
     check(eng.attention_impl == (impl or "dense") and
-          eng.kv_layout == (layout or "ring"),
-          f"{label}: engine ran {eng.attention_impl}/{eng.kv_layout}")
+          eng.page_size == (sz["page_size"] if impl
+                            else 2 * sz["prefill_chunk"]),
+          f"{label}: engine ran {eng.attention_impl} over pages of "
+          f"{eng.page_size}")
     decode_hlo = eng.decode_hlo()
     kernels = decode_hlo.count("tpu_custom_call")
     # each one a relayout or a duplicate of a whole K or V buffer
@@ -355,7 +356,7 @@ def serve_once(sz, seed, ckpt_dir, req_path, impl, layout, on_tpu):
     if on_tpu:
         check((kernels > 0) == (eng.attention_impl == "flash"),
               f"{label}: decode HLO holds {kernels} tpu_custom_call")
-        if (impl, layout) == ("flash", "paged"):
+        if impl == "flash":
             check(copies == 0, f"{label}: the decode step copies the "
                                f"whole pool {copies} times")
     served = {
@@ -449,10 +450,9 @@ def serve_phase(sz, seed, ckpt_dir, workdir, on_tpu):
     req_path = os.path.join(workdir, "requests.jsonl")
     write_requests(req_path, sz, seed)
     dense = None            # the first run: the parity oracle
-    for impl, layout in ((None, None), ("flash", "ring"),
-                         ("flash", "paged")):
+    for impl in (None, "flash"):
         result, tap, facts = serve_once(sz, seed, ckpt_dir, req_path,
-                                        impl, layout, on_tpu)
+                                        impl, on_tpu)
         if dense is None:
             dense = (result, tap)
         else:

@@ -62,10 +62,10 @@ STEP_FLAVORS = ("dense", "zero1", "zero2", "zero3", "offload", "quantized",
 # `decode` runs the serving engine (`inference/`) through a scripted
 # continuous-batching stream across two seq buckets and audits the
 # compiled decode program: zero in-loop recompiles, cache-dtype
-# hygiene, and donation of the ring-buffer KV cache.
+# hygiene, and donation of the KV page pool.
 # `speculative` drives the self-speculative serving engine
-# (`inference/speculative.py`) through the same churn streams on BOTH
-# kv layouts and audits the pinned three-program contract (prefill /
+# (`inference/speculative.py`) through the same churn stream
+# and audits the pinned three-program contract (prefill /
 # draft / verify, plain decode at zero entries), the draft-truncation
 # flop ratio, accept-loop invariants, and host-transfer hygiene of the
 # draft and verify programs.
@@ -150,23 +150,16 @@ class AuditReport:
             lines.append(f"  recompiles: cache size "
                          f"{self.stats['compile_cache_size']} after "
                          f"{self.stats.get('steps_run', 0)} step(s)")
-        kernel_blocks = []
-        if self.stats.get("kernels"):
-            kernel_blocks.append((None, self.stats["kernels"]))
-        for layout, lstats in (self.stats.get("layouts") or {}).items():
-            if lstats.get("kernels"):
-                kernel_blocks.append((layout, lstats["kernels"]))
-        for layout, ks in kernel_blocks:
-            tag = f" [{layout}]" if layout else ""
-            for kname, kd in (ks.get("kernels") or {}).items():
-                lines.append(
-                    f"  kernel{tag} {kname}: grid {tuple(kd['grid'])}, "
-                    f"VMEM {kd['vmem_bytes'] / 1024:.1f}KB / "
-                    f"{ks.get('vmem_budget_bytes', 0) / (1 << 20):.0f}MB, "
-                    f"elided DMA {kd['elided_dma_fraction']:.1%}")
-            if ks.get("expected_elision") is not None:
-                lines.append(f"  elision contract{tag}: >= "
-                             f"{ks['expected_elision']:.1%} proven")
+        ks = self.stats.get("kernels") or {}
+        for kname, kd in (ks.get("kernels") or {}).items():
+            lines.append(
+                f"  kernel {kname}: grid {tuple(kd['grid'])}, "
+                f"VMEM {kd['vmem_bytes'] / 1024:.1f}KB / "
+                f"{ks.get('vmem_budget_bytes', 0) / (1 << 20):.0f}MB, "
+                f"elided DMA {kd['elided_dma_fraction']:.1%}")
+        if ks.get("expected_elision") is not None:
+            lines.append(f"  elision contract: >= "
+                         f"{ks['expected_elision']:.1%} proven")
         for f in self.findings:
             lines.append(f"  - [{f.severity}] {f.rule}: {f.message}")
         return "\n".join(lines)
@@ -649,20 +642,18 @@ KERNEL_RULES = ("kernel_vmem", "kernel_tiling", "kernel_dma")
 def _kernel_analysis_for(fn, args, engine):
     """Kernel analysis of a serving program at representative occupancy.
 
-    ``decode_lowering_args()`` carries all-zero positions (and, paged,
-    all-trash page tables) — correct avals for lowering, but degenerate
-    for a DMA-elision proof (everything clamps to block 0). Replace
-    them with a half-full scenario: row ``b`` at position
-    ``(b+1) * max_seq / (2 * max_batch)`` and, for the paged layout,
-    distinct live page-table entries (no cross-row physical sharing, so
-    elision is attributable to the clamp alone). Returns
-    ``(KernelAnalysis, expected_elision)`` where the expectation is the
-    scenario's dead-block fraction (`kernels.ring_dead_block_fraction`)
-    — the contract `rules.rule_kernel_dma` enforces.
+    ``decode_lowering_args()`` carries all-zero positions and all-trash
+    page tables — correct avals for lowering, but degenerate for a
+    DMA-elision proof (no row is live). Replace them with a half-full
+    scenario: row ``b`` at position ``(b+1) * max_seq / (2 *
+    max_batch)`` and distinct live page-table entries (no cross-row
+    physical sharing). Returns ``(KernelAnalysis, expected_elision)``
+    where the expectation is the scenario's dead-block fraction
+    (`kernels.paged_dead_block_fraction`) — the contract
+    `rules.rule_kernel_dma` enforces.
     """
     from deepspeed_tpu.analysis.kernels import (
-        analyze_kernels, paged_dead_block_fraction,
-        ring_dead_block_fraction)
+        analyze_kernels, paged_dead_block_fraction)
 
     args = list(args)
     B = engine.spec.max_batch
@@ -670,25 +661,55 @@ def _kernel_analysis_for(fn, args, engine):
     pos = np.array([(b + 1) * max_seq // (2 * B) for b in range(B)],
                    np.int32)
     args[3] = jnp.asarray(pos)                 # positions operand
-    if engine.kv_layout == "paged":
-        ppr = engine.pages_per_row
-        pt = (np.arange(B * ppr).reshape(B, ppr)
-              % (engine.n_pages - 1)) + 1     # live, distinct, non-trash
-        args[4] = jnp.asarray(pt.astype(np.int32))
+    ppr = engine.pages_per_row
+    pt = (np.arange(B * ppr).reshape(B, ppr)
+          % (engine.n_pages - 1)) + 1         # live, distinct, non-trash
+    args[4] = jnp.asarray(pt.astype(np.int32))
     ana = analyze_kernels(fn, tuple(args))
     if not ana.kernels:
         return ana, None
-    if engine.kv_layout == "paged":
-        # the paged kernel does not launch what the ring kernel elides
-        return ana, paged_dead_block_fraction(
-            pos, pt, engine.page_size, engine.attention_block_k)
-    return ana, ring_dead_block_fraction(pos, max_seq,
-                                        engine.attention_block_k)
+    return ana, paged_dead_block_fraction(
+        pos, pt, engine.page_size, engine.attention_block_k)
+
+
+def _serve_churn_stream(sched, vocab_size):
+    """The serving audits' scripted stream, run through ``sched``;
+    returns every completion. Allocator churn end to end: r0/r1 share a
+    >page_size prefix (r1 is a radix hit on r0's interned pages), r2
+    parks its pages under a session id, r3's 30-token prompt squeezes
+    the pool (pressure ladder: radix eviction, then host evacuation of
+    r2's parked pages) and length-evicts, r4 re-hits the shared prefix
+    open-loop; then r5 extends s0's history (prompt + every token that
+    fed a decode step) so admission pages the parked KV back in and
+    restarts prefill mid-prompt."""
+    from deepspeed_tpu.inference.scheduler import Request
+
+    rng = np.random.default_rng(0)
+
+    def toks(n):
+        return rng.integers(0, vocab_size, n).tolist()
+
+    base = toks(12)
+    stream = [
+        Request("r0", base + toks(3), max_new_tokens=4),
+        Request("r1", base + toks(5), max_new_tokens=5),
+        Request("r2", toks(6), max_new_tokens=4, session_id="s0"),
+        Request("r3", toks(30), max_new_tokens=10),
+        Request("r4", base + toks(2), max_new_tokens=3, arrival_step=3)]
+    s0 = {c.rid: c for c in sched.run(stream)}["r2"]
+    follow = stream[2].prompt + s0.tokens + toks(2)
+    return sched.run([Request("r5", follow, max_new_tokens=3,
+                              session_id="s0")])
+
+
+def _page_facts(engine):
+    return {"page_size": engine.page_size, "n_pages": engine.n_pages,
+            "pages_per_row": engine.pages_per_row,
+            "max_seq": engine.max_seq}
 
 
 def audit_decode(rules=None, config_overrides=None, kv_cache_dtype=None,
-                 attention_impl="flash", kv_layout="ring",
-                 kernels=False):
+                 attention_impl="flash", kernels=False):
     """Audit the serving engine's compiled decode program.
 
     Builds a tiny :class:`~deepspeed_tpu.inference.engine.
@@ -698,14 +719,14 @@ def audit_decode(rules=None, config_overrides=None, kv_cache_dtype=None,
     lowers the decode program through its live avals (a jit-cache hit)
     and runs the rule catalog over it — the `decode` rule pins zero
     in-loop recompiles and cache-dtype hygiene, the generic donation
-    rule pins that the ring-buffer KV cache actually aliases in place,
+    rule pins that the KV pool actually aliases in place,
     and the `flash_decode` rule pins that the stock flash attention
     path (``attention_impl="flash"``, the default) actually deleted the
     dense full-cache contraction from the lowered program.
 
-    With ``kv_layout="paged"`` the scripted stream additionally churns
-    the page allocator end to end: shared-prefix admissions (radix
-    hits), a pool-pressure request that rides the eviction ladder, a
+    The scripted stream churns the page allocator end to end:
+    shared-prefix admissions (radix hits), a pool-pressure request that
+    rides the eviction ladder and length-evicts, a
     parked session that the pressure evacuates to host RAM, and a
     follow-up that pages it back in and resumes mid-prompt — then the
     `decode` rule pins that the post-churn program still lowered zero
@@ -724,7 +745,7 @@ def audit_decode(rules=None, config_overrides=None, kv_cache_dtype=None,
         cache_dtype_census, payload_shape as kv_payload_shape)
     from deepspeed_tpu.inference.engine import InferenceEngine
     from deepspeed_tpu.inference.scheduler import (
-        ContinuousBatchingScheduler, Request)
+        ContinuousBatchingScheduler)
     from deepspeed_tpu.models.gpt2 import GPT2LMHead, gpt2_tiny
 
     t0 = time.perf_counter()
@@ -734,61 +755,11 @@ def audit_decode(rules=None, config_overrides=None, kv_cache_dtype=None,
     params = model.init(jax.random.PRNGKey(0), toks)["params"]
     inf_cfg = {"max_batch": 2, "seq_buckets": (16, 32),
                "prefill_chunk": 4, "kv_cache_dtype": kv_cache_dtype,
-               "attention_impl": attention_impl, "attention_block_k": 8,
-               "kv_layout": kv_layout}
+               "attention_impl": attention_impl, "attention_block_k": 8}
     inf_cfg.update(config_overrides or {})
     engine = InferenceEngine(model, params, config=inf_cfg)
     sched = ContinuousBatchingScheduler(engine)
-    rng = np.random.default_rng(0)
-    paged = engine.kv_layout == "paged"
-    if paged:
-        # Allocator-churn stream: r0/r1 share a >page_size prefix (r1
-        # is a radix hit on r0's interned pages), r2 parks its pages
-        # under a session id, r3's 30-token prompt squeezes the pool
-        # (pressure ladder: radix eviction, then host evacuation of
-        # r2's parked pages), r4 re-hits the shared prefix open-loop.
-        base = rng.integers(0, cfg.vocab_size, 12).tolist()
-        stream = [
-            Request("r0", base + rng.integers(
-                0, cfg.vocab_size, 3).tolist(), max_new_tokens=4),
-            Request("r1", base + rng.integers(
-                0, cfg.vocab_size, 5).tolist(), max_new_tokens=5),
-            Request("r2", rng.integers(0, cfg.vocab_size, 6).tolist(),
-                    max_new_tokens=4, session_id="s0"),
-            Request("r3", rng.integers(0, cfg.vocab_size, 30).tolist(),
-                    max_new_tokens=10),
-            Request("r4", base + rng.integers(
-                0, cfg.vocab_size, 2).tolist(), max_new_tokens=3,
-                    arrival_step=3)]
-        completions = sched.run(stream)
-        # Session resume: extend s0's history (prompt + every token
-        # that fed a decode step) so admission pages the parked KV
-        # back in and restarts prefill mid-prompt.
-        s0 = {c.rid: c for c in completions}["r2"]
-        follow = stream[2].prompt + s0.tokens + rng.integers(
-            0, cfg.vocab_size, 2).tolist()
-        completions = sched.run([Request("r5", follow, max_new_tokens=3,
-                                         session_id="s0")])
-    else:
-        # 5 requests over 2 rows: slot recycling, both buckets, a
-        # clamped over-budget request that length-evicts, and an
-        # open-loop arrival.
-        stream = [Request("r0",
-                          rng.integers(0, cfg.vocab_size, 3).tolist(),
-                          max_new_tokens=4),
-                  Request("r1",
-                          rng.integers(0, cfg.vocab_size, 20).tolist(),
-                          max_new_tokens=6),
-                  Request("r2",
-                          rng.integers(0, cfg.vocab_size, 2).tolist(),
-                          max_new_tokens=3, arrival_step=3),
-                  Request("r3",
-                          rng.integers(0, cfg.vocab_size, 30).tolist(),
-                          max_new_tokens=10),
-                  Request("r4",
-                          rng.integers(0, cfg.vocab_size, 6).tolist(),
-                          max_new_tokens=5)]
-        completions = sched.run(stream)
+    completions = _serve_churn_stream(sched, cfg.vocab_size)
     hlo_text, expected, pinfo = _lower_step(engine._decode,
                                             engine.decode_lowering_args())
     kernel_ana = kernel_expected = None
@@ -797,12 +768,6 @@ def audit_decode(rules=None, config_overrides=None, kv_cache_dtype=None,
             engine._decode, engine.decode_lowering_args(), engine)
     census = cache_dtype_census(engine.cache)
     payload_shape = kv_payload_shape(engine.spec)
-    page_facts = None
-    if paged:
-        page_facts = {"page_size": engine.page_size,
-                      "n_pages": engine.n_pages,
-                      "pages_per_row": engine.pages_per_row,
-                      "max_seq": engine.max_seq}
     ctx = StepContext(
         hlo_text=hlo_text, flavor="decode",
         compute_dtype="f32" if cfg.dtype == jnp.float32 else "bf16",
@@ -815,8 +780,7 @@ def audit_decode(rules=None, config_overrides=None, kv_cache_dtype=None,
         decode_attention_impl=engine.attention_impl,
         decode_cache_payload_shape=payload_shape,
         decode_platform=jax.devices()[0].platform,
-        decode_kv_layout=engine.kv_layout,
-        decode_page_facts=page_facts,
+        decode_page_facts=_page_facts(engine),
         kernel_analysis=kernel_ana,
         kernel_expected_elision=kernel_expected,
         skip_rules={"recompile"})
@@ -832,8 +796,7 @@ def audit_decode(rules=None, config_overrides=None, kv_cache_dtype=None,
     report.stats["cache"] = engine.cache_facts()
     report.stats["attention"] = {"impl": engine.attention_impl,
                                  "block_k": engine.attention_block_k}
-    if paged:
-        report.stats["paging"] = sched.paging.facts()
+    report.stats["paging"] = sched.paging.facts()
     if kernel_ana is not None:
         report.stats["kernels"] = kernel_ana.to_dict()
         report.stats["kernels"]["expected_elision"] = kernel_expected
@@ -855,14 +818,12 @@ def _xla_flops(fn, args):
 
 def audit_speculative(rules=None, config_overrides=None,
                       kv_cache_dtype=None, attention_impl="flash",
-                      kv_layout=None, k=3, draft_layers=1, n_layer=4,
-                      kernels=False):
+                      k=3, draft_layers=1, n_layer=4, kernels=False):
     """Audit the self-speculative serving engine end to end.
 
-    Runs :func:`audit_decode`'s scripted churn streams (slot recycling
-    and bucket crossing on the ring layout; radix hits, pool pressure,
-    host park + mid-prompt resume on the paged layout) with speculation
-    enabled, then audits:
+    Runs :func:`audit_decode`'s scripted churn stream (radix hits, pool
+    pressure, host park + mid-prompt resume) with speculation enabled,
+    then audits:
 
     - the pinned THREE-program contract — prefill, draft, verify each
       exactly one jit-cache entry and the plain decode program at ZERO
@@ -873,156 +834,96 @@ def audit_speculative(rules=None, config_overrides=None,
     - accept-loop invariants (``mean_accepted >= 1.0`` by construction,
       ``draft_efficiency`` within [0, 1]);
     - draft/verify program hygiene — donation of the cache operand,
-      zero host transfers on the paged layout, and the flash payload
-      pins on the T=1 draft step.
-
-    ``kv_layout=None`` (the default, and what the CLI flavor runs)
-    sweeps BOTH layouts and merges the findings into one report —
-    speculation must survive serve churn on each.
+      zero host transfers, and the flash payload pins on the T=1 draft
+      step.
     """
     import jax.numpy as jnp
     from deepspeed_tpu.inference.cache import (
         cache_dtype_census, payload_shape as kv_payload_shape)
     from deepspeed_tpu.inference.engine import InferenceEngine
     from deepspeed_tpu.inference.scheduler import (
-        ContinuousBatchingScheduler, Request)
+        ContinuousBatchingScheduler)
     from deepspeed_tpu.models.gpt2 import GPT2LMHead, gpt2_tiny
 
     t0 = time.perf_counter()
-    layouts = (kv_layout,) if kv_layout else ("ring", "paged")
-    findings, stats = [], {"layouts": {}}
-    hlo_text = ""
-    for layout in layouts:
-        cfg = gpt2_tiny(n_embd=32, n_layer=n_layer, dtype=jnp.float32)
-        model = GPT2LMHead(cfg)
-        toks = jnp.zeros((1, 8), jnp.int32)
-        params = model.init(jax.random.PRNGKey(0), toks)["params"]
-        inf_cfg = {"max_batch": 2, "seq_buckets": (16, 32),
-                   "prefill_chunk": 4, "kv_cache_dtype": kv_cache_dtype,
-                   "attention_impl": attention_impl,
-                   "attention_block_k": 8, "kv_layout": layout,
-                   "speculative": {"enabled": True, "k": k,
-                                   "draft_layers": draft_layers}}
-        inf_cfg.update(config_overrides or {})
-        engine = InferenceEngine(model, params, config=inf_cfg)
-        spec = engine.speculative
-        sched = ContinuousBatchingScheduler(engine)
-        rng = np.random.default_rng(0)
-        if layout == "paged":
-            base = rng.integers(0, cfg.vocab_size, 12).tolist()
-            stream = [
-                Request("r0", base + rng.integers(
-                    0, cfg.vocab_size, 3).tolist(), max_new_tokens=4),
-                Request("r1", base + rng.integers(
-                    0, cfg.vocab_size, 5).tolist(), max_new_tokens=5),
-                Request("r2", rng.integers(
-                    0, cfg.vocab_size, 6).tolist(),
-                    max_new_tokens=4, session_id="s0"),
-                Request("r3", rng.integers(
-                    0, cfg.vocab_size, 30).tolist(), max_new_tokens=10),
-                Request("r4", base + rng.integers(
-                    0, cfg.vocab_size, 2).tolist(), max_new_tokens=3,
-                    arrival_step=3)]
-            completions = sched.run(stream)
-            s0 = {c.rid: c for c in completions}["r2"]
-            follow = stream[2].prompt + s0.tokens + rng.integers(
-                0, cfg.vocab_size, 2).tolist()
-            completions = sched.run(
-                [Request("r5", follow, max_new_tokens=3,
-                         session_id="s0")])
-        else:
-            stream = [
-                Request("r0", rng.integers(
-                    0, cfg.vocab_size, 3).tolist(), max_new_tokens=4),
-                Request("r1", rng.integers(
-                    0, cfg.vocab_size, 20).tolist(), max_new_tokens=6),
-                Request("r2", rng.integers(
-                    0, cfg.vocab_size, 2).tolist(),
-                    max_new_tokens=3, arrival_step=3),
-                Request("r3", rng.integers(
-                    0, cfg.vocab_size, 30).tolist(), max_new_tokens=10),
-                Request("r4", rng.integers(
-                    0, cfg.vocab_size, 6).tolist(), max_new_tokens=5)]
-            completions = sched.run(stream)
-        compile_counts = engine.compile_counts()
-        draft_args = spec.draft_lowering_args()
-        draft_hlo, expected, pinfo = _lower_step(spec._draft, draft_args)
-        kernel_ana = kernel_expected = None
-        if kernels:
-            kernel_ana, kernel_expected = _kernel_analysis_for(
-                spec._draft, draft_args, engine)
-        verify_hlo, v_expected, v_pinfo = _lower_step(
-            spec._verify, spec.verify_lowering_args())
-        draft_flops = _xla_flops(spec._draft, draft_args)
-        full_flops = _xla_flops(engine._decode,
-                                engine.decode_lowering_args())
-        payload_shape = kv_payload_shape(engine.spec)
-        page_facts = None
-        if layout == "paged":
-            page_facts = {"page_size": engine.page_size,
-                          "n_pages": engine.n_pages,
-                          "pages_per_row": engine.pages_per_row,
-                          "max_seq": engine.max_seq}
-        ctx = StepContext(
-            hlo_text=draft_hlo, flavor="speculative",
-            compute_dtype="f32",
-            expected_donated_params=expected, donated_param_info=pinfo,
-            declared_donate_argnums=getattr(
-                spec._draft, "_ds_donate_argnums", None),
-            decode_compile_counts=compile_counts,
-            decode_kv_cache_dtype=engine.kv_cache_dtype,
-            decode_cache_census=cache_dtype_census(engine.cache),
-            decode_attention_impl=engine.attention_impl,
-            decode_cache_payload_shape=payload_shape,
-            decode_platform=jax.devices()[0].platform,
-            decode_kv_layout=engine.kv_layout,
-            decode_page_facts=page_facts,
-            spec_facts=spec.facts(),
-            spec_compile_counts=compile_counts,
-            spec_draft_hlo=draft_hlo, spec_verify_hlo=verify_hlo,
-            spec_draft_flops=draft_flops, spec_full_flops=full_flops,
-            kernel_analysis=kernel_ana,
-            kernel_expected_elision=kernel_expected,
-            skip_rules={"recompile"})
-        layout_findings = run_rules(ctx, rules)
-        # verify program: full-depth dense by design (the flash kernel
-        # is a T=1 specialization), so only the donation pin applies
-        v_ctx = StepContext(
-            hlo_text=verify_hlo, flavor="speculative",
-            compute_dtype="f32",
-            expected_donated_params=v_expected,
-            donated_param_info=v_pinfo,
-            declared_donate_argnums=getattr(
-                spec._verify, "_ds_donate_argnums", None),
-            skip_rules={"recompile"})
-        layout_findings.extend(run_rules(v_ctx, {"donation"}))
-        layout_findings.extend(engine.recompile_findings())
-        for f in layout_findings:
-            f.details.setdefault("kv_layout", layout)
-        findings.extend(layout_findings)
-        ratio = draft_flops / full_flops if full_flops else None
-        stats["layouts"][layout] = {
-            "compile_counts": compile_counts,
-            "completions": len(completions),
-            "finish_reasons": sorted(
-                c.finish_reason for c in completions),
-            "speculative": spec.facts(),
-            "draft_flops": draft_flops, "full_flops": full_flops,
-            "draft_flops_ratio": ratio,
-            "cache": engine.cache_facts(),
-        }
-        if layout == "paged":
-            stats["layouts"][layout]["paging"] = sched.paging.facts()
-        if kernel_ana is not None:
-            stats["layouts"][layout]["kernels"] = kernel_ana.to_dict()
-            stats["layouts"][layout]["kernels"]["expected_elision"] = \
-                kernel_expected
-        hlo_text = draft_hlo
+    cfg = gpt2_tiny(n_embd=32, n_layer=n_layer, dtype=jnp.float32)
+    model = GPT2LMHead(cfg)
+    toks = jnp.zeros((1, 8), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), toks)["params"]
+    inf_cfg = {"max_batch": 2, "seq_buckets": (16, 32),
+               "prefill_chunk": 4, "kv_cache_dtype": kv_cache_dtype,
+               "attention_impl": attention_impl,
+               "attention_block_k": 8,
+               "speculative": {"enabled": True, "k": k,
+                               "draft_layers": draft_layers}}
+    inf_cfg.update(config_overrides or {})
+    engine = InferenceEngine(model, params, config=inf_cfg)
+    spec = engine.speculative
+    sched = ContinuousBatchingScheduler(engine)
+    completions = _serve_churn_stream(sched, cfg.vocab_size)
+    compile_counts = engine.compile_counts()
+    draft_args = spec.draft_lowering_args()
+    draft_hlo, expected, pinfo = _lower_step(spec._draft, draft_args)
+    kernel_ana = kernel_expected = None
+    if kernels:
+        kernel_ana, kernel_expected = _kernel_analysis_for(
+            spec._draft, draft_args, engine)
+    verify_hlo, v_expected, v_pinfo = _lower_step(
+        spec._verify, spec.verify_lowering_args())
+    draft_flops = _xla_flops(spec._draft, draft_args)
+    full_flops = _xla_flops(engine._decode,
+                            engine.decode_lowering_args())
+    ctx = StepContext(
+        hlo_text=draft_hlo, flavor="speculative",
+        compute_dtype="f32",
+        expected_donated_params=expected, donated_param_info=pinfo,
+        declared_donate_argnums=getattr(
+            spec._draft, "_ds_donate_argnums", None),
+        decode_compile_counts=compile_counts,
+        decode_kv_cache_dtype=engine.kv_cache_dtype,
+        decode_cache_census=cache_dtype_census(engine.cache),
+        decode_attention_impl=engine.attention_impl,
+        decode_cache_payload_shape=kv_payload_shape(engine.spec),
+        decode_platform=jax.devices()[0].platform,
+        decode_page_facts=_page_facts(engine),
+        spec_facts=spec.facts(),
+        spec_compile_counts=compile_counts,
+        spec_draft_hlo=draft_hlo, spec_verify_hlo=verify_hlo,
+        spec_draft_flops=draft_flops, spec_full_flops=full_flops,
+        kernel_analysis=kernel_ana,
+        kernel_expected_elision=kernel_expected,
+        skip_rules={"recompile"})
+    findings = run_rules(ctx, rules)
+    # verify program: full-depth dense by design (the flash kernel
+    # is a T=1 specialization), so only the donation pin applies
+    v_ctx = StepContext(
+        hlo_text=verify_hlo, flavor="speculative",
+        compute_dtype="f32",
+        expected_donated_params=v_expected,
+        donated_param_info=v_pinfo,
+        declared_donate_argnums=getattr(
+            spec._verify, "_ds_donate_argnums", None),
+        skip_rules={"recompile"})
+    findings.extend(run_rules(v_ctx, {"donation"}))
+    findings.extend(engine.recompile_findings())
     report = AuditReport(flavor="speculative", findings=findings)
-    report.stats = _hlo_stats(hlo_text, StepContext(
-        hlo_text=hlo_text, flavor="speculative"))
-    report.stats.update(stats)
-    report.hlo_text = hlo_text
+    report.stats = _hlo_stats(draft_hlo, StepContext(
+        hlo_text=draft_hlo, flavor="speculative"))
+    report.stats.update({
+        "compile_counts": compile_counts,
+        "completions": len(completions),
+        "finish_reasons": sorted(c.finish_reason for c in completions),
+        "speculative": spec.facts(),
+        "draft_flops": draft_flops, "full_flops": full_flops,
+        "draft_flops_ratio":
+            draft_flops / full_flops if full_flops else None,
+        "cache": engine.cache_facts(),
+        "paging": sched.paging.facts(),
+    })
+    if kernel_ana is not None:
+        report.stats["kernels"] = kernel_ana.to_dict()
+        report.stats["kernels"]["expected_elision"] = kernel_expected
+    report.hlo_text = draft_hlo
     report.stats["audit_wall_s"] = round(time.perf_counter() - t0, 3)
     return report
 
@@ -1064,7 +965,7 @@ def audit_disagg(rules=None, config_overrides=None):
     toks = jnp.zeros((1, 8), jnp.int32)
     params = model.init(jax.random.PRNGKey(0), toks)["params"]
     base = {"seq_buckets": (16, 32), "prefill_chunk": 4,
-            "attention_block_k": 8, "kv_layout": "paged"}
+            "attention_block_k": 8}
     base.update(config_overrides or {})
     pre_engine = InferenceEngine(model, params, config=dict(
         base, max_batch=2, tier="prefill"))
@@ -1110,7 +1011,6 @@ def audit_disagg(rules=None, config_overrides=None):
         decode_attention_impl=dec_engine.attention_impl,
         decode_cache_payload_shape=payload_shape,
         decode_platform=jax.devices()[0].platform,
-        decode_kv_layout="paged",
         decode_page_facts=page_facts["decode"],
         disagg_tier_counts=tier_counts,
         disagg_page_facts=page_facts,
@@ -1181,17 +1081,13 @@ def audit_kernel_flavors(rules=None):
     path under the sub-``pallas_call`` analyzer.
 
     Covers the train flash-attention kernels (fwd/dQ/dKV), the decode
-    flavor on BOTH kv layouts (ring clamp and paged clamp+gather index
-    maps, each with its DMA-elision proof), and the speculative flavor
-    (draft program, both layouts). Returns ``{name: AuditReport}``;
+    flavor (the paged kernel's row walk, with its DMA-elision proof),
+    and the speculative flavor (draft program). Returns ``{name: AuditReport}``;
     stock kernels must come back zero-findings everywhere.
     """
     reports = {
         "flash_train": audit_flash_train(rules=rules),
-        "decode_ring": audit_decode(rules=rules, kv_layout="ring",
-                                    kernels=True),
-        "decode_paged": audit_decode(rules=rules, kv_layout="paged",
-                                     kernels=True),
+        "decode_paged": audit_decode(rules=rules, kernels=True),
         "speculative": audit_speculative(rules=rules, kernels=True),
     }
     for name, rep in reports.items():

@@ -79,7 +79,7 @@ def main(argv=None):
                              "the train-step flavors: VMEM budgets, "
                              "tile-alignment lint, DMA-elision proofs "
                              "and grid-write races over flash_train, "
-                             "decode_ring, decode_paged, speculative; "
+                             "decode_paged, speculative; "
                              "--flavors selects a subset of those")
     parser.add_argument("--rules", default=None,
                         help="comma-separated rule ids to run "
@@ -152,10 +152,8 @@ def main(argv=None):
     if args.kernels:
         kernel_sweep = {
             "flash_train": lambda: audit_flash_train(rules=rules),
-            "decode_ring": lambda: audit_decode(
-                rules=rules, kv_layout="ring", kernels=True),
             "decode_paged": lambda: audit_decode(
-                rules=rules, kv_layout="paged", kernels=True),
+                rules=rules, kernels=True),
             "speculative": lambda: audit_speculative(
                 rules=rules, kernels=True),
         }

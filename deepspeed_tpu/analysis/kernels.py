@@ -33,10 +33,9 @@ properties the Mosaic compiler will not check for us:
    captures as live values by interpreting the traced jaxpr). Pallas
    skips the copy when consecutive grid steps map to the same block,
    so counting distinct-vs-total physical blocks per operand *proves*
-   the flash-decode clamp trick (`flash_decode.py` ``kv_map`` /
-   ``_physical``: clamp the logical block to the row's occupancy, then
-   look up the page) actually dedupes dead blocks — and prices the
-   kernel's real HBM traffic for the cost model.
+   that an index map clamped to the row's occupancy actually dedupes
+   dead blocks — and prices the kernel's real HBM traffic for the cost
+   model.
 
    A kernel may instead leave an operand where it is
    (``memory_space=pl.ANY``) and fetch from it by manual DMA inside
@@ -706,22 +705,9 @@ def analyze_kernels(fn, args=None, *, platform="tpu_v5e",
 # elision expectations (the audit's decode proof)
 # ---------------------------------------------------------------------------
 
-def ring_dead_block_fraction(positions, max_seq, block_k):
-    """The fraction of KV-block grid steps past the rows' occupancy —
-    what the flash-decode clamp must elide. Heads multiply live and
-    total blocks alike, so the per-row fraction is the per-(row, head)
-    fraction."""
-    n_kb = max(1, int(max_seq) // int(block_k))
-    rows = [int(p) for p in np.asarray(positions).reshape(-1)]
-    if not rows:
-        return 0.0
-    live = sum(min(p // int(block_k) + 1, n_kb) for p in rows)
-    return 1.0 - live / (len(rows) * n_kb)
-
-
 def paged_dead_block_fraction(positions, page_tables, page_size, block_k):
-    """The paged counterpart: the fraction of the ``rows x
-    pages_per_row x page_size / block_k`` rectangle (in blocks of all
+    """What the decode kernel must not fetch: the fraction of the
+    ``rows x pages_per_row x page_size / block_k`` rectangle (in blocks of all
     heads) that holds nothing — blocks past a live row's position and
     every block of a row without a request (first table entry the trash
     page). The paged kernel launches none of them."""

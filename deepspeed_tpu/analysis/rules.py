@@ -128,25 +128,23 @@ class StepContext:
     # Flash-decode attention (`ops/pallas/flash_decode.py`):
     # decode_attention_impl names the engine's configured decode
     # attention ("dense" | "flash"; None = not a serving audit),
-    # decode_cache_payload_shape is one layer's k/v buffer shape
-    # (max_batch, max_seq, n_head, head_dim), and decode_platform is
+    # decode_cache_payload_shape is one layer's k/v pool shape
+    # (n_pages, n_head, head_dim, page_size), and decode_platform is
     # the backend the audited program lowered for — the Pallas
     # custom-call pin only applies to real TPU lowerings (interpret
     # mode inlines the kernel as plain HLO).
     decode_attention_impl: str = None
     decode_cache_payload_shape: tuple = None
     decode_platform: str = None
-    # Paged KV cache (`inference/paging.py`): decode_kv_layout names the
-    # engine's cache layout ("ring" | "paged"; None = not a serving
-    # audit). For a paged engine the page tables are fixed-shape int32
-    # DATA inputs — allocator churn, prefix sharing and host-tier
-    # parking are host-side bookkeeping that must never lower a host
-    # transfer into the steady-state decode program (parking runs
-    # OUTSIDE the compiled step, through `engine.gather_pages`).
-    # decode_page_facts is the engine's `cache_facts()` geometry
-    # (page_size / n_pages / pages_per_row / max_seq) for the
-    # internal-consistency pins.
-    decode_kv_layout: str = None
+    # Paged KV cache (`inference/paging.py`): the page tables are
+    # fixed-shape int32 DATA inputs — allocator churn, prefix sharing
+    # and host-tier parking are host-side bookkeeping that must never
+    # lower a host transfer into the steady-state decode program
+    # (parking runs OUTSIDE the compiled step, through
+    # `engine.gather_pages`). decode_page_facts is the engine's
+    # `cache_facts()` geometry (page_size / n_pages / pages_per_row /
+    # max_seq) for the internal-consistency pins; None = not a serving
+    # audit.
     decode_page_facts: dict = None
     # Speculative decoding (`inference/speculative.py`): spec_facts is
     # the decoder's `facts()` (k / draft_layers / n_layer and the
@@ -184,7 +182,7 @@ class StepContext:
     # the audit's *proof obligation* for the DMA-elision trick: the
     # dead-block fraction the clamped index maps MUST elide, computed
     # from the analysis scenario's positions
-    # (`kernels.ring_dead_block_fraction`). None = no elision contract
+    # (`kernels.paged_dead_block_fraction`). None = no elision contract
     # (train kernels have no occupancy clamp to prove).
     kernel_analysis: object = None
     kernel_expected_elision: float = None
@@ -791,7 +789,7 @@ def rule_decode(ctx):
     dtype — a mixed or full-precision census means some layer's cache
     silently skipped quantization and the promised HBM saving is gone.
 
-    Paged layout (``decode_kv_layout == "paged"``): the page tables are
+    The pool (``decode_page_facts``): the page tables are
     fixed-shape device data — steady-state decode must lower ZERO host
     transfer ops (a page gather routed through infeed/outfeed or a host
     callback stalls every step; host-tier parking runs outside the
@@ -811,11 +809,11 @@ def rule_decode(ctx):
     """
     if ctx.decode_compile_counts is None and \
             ctx.decode_cache_census is None and \
-            ctx.decode_kv_layout is None and \
+            ctx.decode_page_facts is None and \
             ctx.disagg_tier_counts is None:
         return []
     findings = []
-    if ctx.decode_kv_layout == "paged":
+    if ctx.decode_page_facts is not None:
         hits = host_transfer_ops(ctx.hlo_text) if ctx.hlo_text else []
         if hits:
             kinds = sorted({h["kind"] for h in hits})
@@ -827,7 +825,7 @@ def rule_decode(ctx):
                 f"decode stalls every step",
                 {"count": len(hits), "kinds": kinds,
                  "ops": [h["line"][:200] for h in hits[:8]]}))
-        pf = ctx.decode_page_facts or {}
+        pf = ctx.decode_page_facts
         ps = pf.get("page_size", 0)
         n_pg = pf.get("n_pages", 0)
         ppr = pf.get("pages_per_row", 0)
@@ -932,7 +930,7 @@ def rule_flash_decode(ctx):
       so that pin is platform-gated);
     - NO dot may touch a full cache-payload-shaped array
       (`analysis/hlo.py:payload_shaped_dots`): one surviving
-      ``[max_batch, max_seq, n_head, head_dim]`` contraction means the
+      contraction of a whole pool leaf means the
       dense softmax is still running and the O(max_seq) HBM traffic
       the kernel exists to delete is still being paid;
     - with a quantized cache, NO f32 value may be cache-payload-shaped
@@ -967,8 +965,7 @@ def rule_flash_decode(ctx):
                                                 payload_shaped_dots,
                                                 payload_shaped_values)
         copies = payload_shaped_copies(ctx.hlo_text, payload) \
-            if ctx.decode_kv_layout == "paged" \
-            and ctx.decode_platform in (None, "tpu") else []
+            if ctx.decode_platform in (None, "tpu") else []
         if copies:
             findings.append(Finding(
                 "flash_decode", SEV_ERROR,
@@ -1102,21 +1099,20 @@ def rule_speculative(ctx):
             f"draft count exceeds drafted count; the accept gather is "
             f"reading past the draft window",
             {"facts": dict(facts)}))
-    if ctx.decode_kv_layout == "paged":
-        for name, hlo in (("draft", ctx.spec_draft_hlo),
-                          ("verify", ctx.spec_verify_hlo)):
-            hits = host_transfer_ops(hlo) if hlo else []
-            if hits:
-                kinds = sorted({h["kind"] for h in hits})
-                findings.append(Finding(
-                    "speculative", SEV_ERROR,
-                    f"paged speculative {name} program lowers "
-                    f"{len(hits)} host transfer op(s) "
-                    f"({', '.join(kinds)}) — page-table gathers must "
-                    f"stay on device in every steady-state program",
-                    {"program": name, "count": len(hits),
-                     "kinds": kinds,
-                     "ops": [h["line"][:200] for h in hits[:8]]}))
+    for name, hlo in (("draft", ctx.spec_draft_hlo),
+                      ("verify", ctx.spec_verify_hlo)):
+        hits = host_transfer_ops(hlo) if hlo else []
+        if hits:
+            kinds = sorted({h["kind"] for h in hits})
+            findings.append(Finding(
+                "speculative", SEV_ERROR,
+                f"paged speculative {name} program lowers "
+                f"{len(hits)} host transfer op(s) "
+                f"({', '.join(kinds)}) — page-table gathers must "
+                f"stay on device in every steady-state program",
+                {"program": name, "count": len(hits),
+                 "kinds": kinds,
+                 "ops": [h["line"][:200] for h in hits[:8]]}))
     if ctx.decode_attention_impl == "flash" and ctx.spec_draft_hlo:
         if ctx.decode_platform == "tpu" and \
                 "custom-call" not in ctx.spec_draft_hlo:
@@ -1214,11 +1210,10 @@ def rule_kernel_dma(ctx):
     When the audit declares an elision contract
     (``kernel_expected_elision``, the dead-block fraction implied by
     the analysis scenario's positions), the byte-weighted INPUT elided
-    fraction proved by the index-map sweep must reach it — this is the
-    static proof that the ring kernel's clamp trick
-    (`ops/pallas/flash_decode.py` ``kv_map``) actually turns dead cache
-    blocks into elided DMAs, instead of asserting it in prose. The
-    paged kernel launches no dead block at all: its pool operands stay
+    fraction proved by the index-map sweep must reach it — the static
+    proof that dead cache blocks cost no DMA, instead of asserting it
+    in prose. A kernel with clamped index maps elides them; the paged
+    decode kernel launches no dead block at all: its pool operands stay
     in HBM and their fetches are its declared walk
     (`kernels.MANUAL_WALKS`), held to the same contract.
     """

@@ -348,12 +348,11 @@ def evaluate_serving_candidate(config, model_overrides=None, *,
                                build=None, platform="tpu_v5e",
                                peak_budget_bytes=None, rules=None,
                                label="candidate", dimension="base"):
-    """Compile one paged-serving candidate through ``audit_decode``'s
+    """Compile one serving candidate through ``audit_decode``'s
     allocator-churn stream and score its decode program.
 
-    The candidate's ``inference`` block configures the engine
-    (``kv_layout`` forced to "paged" — this mode tunes the paged
-    knobs); the full rule catalog runs over the post-churn decode HLO,
+    The candidate's ``inference`` block configures the engine; the
+    full rule catalog runs over the post-churn decode HLO,
     so a page_size that breaks the 2-compile contract or lowers a host
     transfer comes back as a typed rejection, never a score. The audit
     runs with ``kernels=True``, so the score includes the decode
@@ -368,13 +367,12 @@ def evaluate_serving_candidate(config, model_overrides=None, *,
     from deepspeed_tpu.analysis.rules import SEV_ERROR
 
     inf = dict(config.get("inference") or {})
-    inf.pop("kv_layout", None)
     res = CandidateResult(label=label, dimension=dimension,
                           config=config, model={})
     t0 = time.perf_counter()
     try:
         report = audit_decode(config_overrides=inf, rules=rules,
-                              kv_layout="paged", kernels=True)
+                              kernels=True)
     except Exception as exc:
         res.reject_reason = REJECT_BUILD_ERROR
         res.reject_detail = f"{type(exc).__name__}: {exc}"

@@ -9,9 +9,9 @@ mechanism — see :func:`engine.InferenceEngine.compile_counts`).
 
 Pieces:
 
-- :mod:`.cache` — bucketed ring-buffer KV cache (rows recycled across
-  requests), optionally stored int8/fp8 through the shared codec
-  registry (`runtime/comm/codecs.py`).
+- :mod:`.cache` — paged KV cache (one page pool a layer, addressed
+  through per-row page tables), optionally stored int8/fp8 through the
+  shared codec registry (`runtime/comm/codecs.py`).
 - :mod:`.engine` — the two compiled programs over the GPT-2 family
   (unrolled and ``scan_layers``), TP-shardable via the model's
   Megatron PartitionSpecs.

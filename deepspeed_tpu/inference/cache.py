@@ -1,46 +1,39 @@
-"""Bucketed ring-buffer KV cache, and its paged generalization.
+"""Paged KV cache: one pool of fixed-size pages per layer.
 
-One cache = one statically-shaped buffer per layer, ``[max_batch,
-max_seq, n_head, head_dim]`` for keys and values (``scan_layers``
-models stack a leading layer axis so the whole cache rides the same
-``lax.scan`` as the params). Rows are the ring: a finished request's
-row is handed to the next admitted request and simply overwritten —
-admission/eviction never changes a compiled shape, which is what keeps
-the decode loop at exactly one compile (`engine.compile_counts`).
-
-The **paged** layout (``KVCacheSpec.page_size > 0``) replaces the
-per-row ring with one pool of fixed-size pages per layer,
-``[n_pages, n_head, head_dim, page_size]``: a page's positions lie on
-the TPU's 128 lanes and ``head_dim`` on the sublanes, so the array's
-default tiled layout is the one the flash kernel's ``[n_head,
-head_dim, block_k]`` blocks (all heads of a row to a fetch) are cut
-from, with no lane padding at ``head_dim`` 64 — XLA re-lays nothing
-out around the kernel. The price is that one
-position is one lane of every tile of its page, so a write reads and
-writes back the page's whole ``[n_head, head_dim, page_size]`` slab
-(:func:`paged_write_kv`), indexing dynamically on the page axis alone.
-The pool is addressed through per-row
-page tables (``[B, pages_per_row]`` int32) that enter the compiled
-programs as plain data. The pool shape and the table shape are both
-static, so page allocation, freeing, prefix sharing and host-tier
-park/resume are pure host-side metadata churn — the same 2-compile
-contract as the ring, with capacity decoupled from ``max_batch *
-max_seq``. Physical page 0 is the TRASH page: the allocator never
-hands it out, unallocated table entries point at it, and inactive
-decode rows write their garbage token there, so every gather/scatter
-stays in-bounds without per-row branches.
+One cache = one statically-shaped pool per layer, ``[n_pages, n_head,
+head_dim, page_size]`` for keys and values (``scan_layers`` models
+stack a leading layer axis so the whole cache rides the same
+``lax.scan`` as the params): a page's positions lie on the TPU's 128
+lanes and ``head_dim`` on the sublanes, so the array's default tiled
+layout is the one the flash kernel's ``[n_head, head_dim, block_k]``
+blocks (all heads of a row to a fetch) are cut from, with no lane
+padding at ``head_dim`` 64 — XLA re-lays nothing out around the
+kernel. The price is that one position is one lane of every tile of
+its page, so a write reads and writes back the page's whole ``[n_head,
+head_dim, page_size]`` slab (:func:`paged_write_kv`), indexing
+dynamically on the page axis alone. The pool is addressed through
+per-row page tables (``[B, pages_per_row]`` int32) that enter the
+compiled programs as plain data. The pool shape and the table shape
+are both static, so admission, eviction, page allocation, freeing,
+prefix sharing and host-tier park/resume are pure host-side metadata
+churn and never change a compiled shape, which is what keeps the
+decode loop at exactly one compile (`engine.compile_counts`).
+Physical page 0 is the TRASH page: the allocator never hands it out,
+unallocated table entries point at it, and inactive decode rows write
+their garbage token there, so every gather/scatter stays in-bounds
+without per-row branches.
 
 Causality comes from explicit positions, not shapes: every write lands
 at the token's absolute position and every read masks cache index
 ``s`` unless ``s <= query position``. A slot past a row's live prefix
-is either stale (from the row's previous tenant) or garbage from a
+is either stale (from the page's previous tenant) or garbage from a
 padded prefill chunk — both masked, and both overwritten before the
 mask ever exposes them (the decode step writes position ``p`` before
 attending to it).
 
 Optional int8/fp8 storage reuses the wire-codec recipe from
 ``runtime/comm/codecs.py`` (absmax scale into the codec's ``qmax``,
-zero guard, round+clip for int) at per-(row, position, head) scale
+zero guard, round+clip for int) at per-(page, position, head) scale
 granularity — one f32 scale per head vector, the KV analog of the
 per-chunk wire scales.
 """
@@ -65,17 +58,13 @@ class KVCacheSpec:
     dtype: Any = jnp.bfloat16       # storage dtype (codec dtype when quantized)
     codec: Optional[str] = None     # None | "int8" | "f8e4m3fn" | "f8e5m2"
     stacked: bool = False           # scan_layers layout (leading layer axis)
-    page_size: int = 0              # 0 = ring layout; >0 = paged pool
+    page_size: int = 0              # positions a page; divides max_seq
     n_pages: int = 0                # pool pages incl. the trash page
-
-    @property
-    def paged(self):
-        return self.page_size > 0
 
     @property
     def pages_per_row(self):
         """Page-table width: pages covering one row's max_seq span."""
-        return self.max_seq // self.page_size if self.paged else 0
+        return self.max_seq // self.page_size
 
 
 def spec_for_model(cfg, max_batch, max_seq, kv_cache_dtype=None,
@@ -83,8 +72,9 @@ def spec_for_model(cfg, max_batch, max_seq, kv_cache_dtype=None,
     """Resolve a :class:`KVCacheSpec` from a ``GPT2Config`` and the
     ``inference.kv_cache_dtype`` knob (None = model compute dtype,
     "bf16"/"f32" = plain storage, a codec name = quantized storage).
-    ``page_size > 0`` selects the paged pool layout; ``n_pages=0`` then
-    defaults to ring-capacity parity plus the trash page."""
+    ``page_size`` must divide ``max_seq``; ``n_pages=0`` defaults to
+    every row filling its ``max_seq`` span at once, plus the trash
+    page."""
     codec = None
     if kv_cache_dtype is None:
         dtype = cfg.dtype
@@ -104,31 +94,29 @@ def spec_for_model(cfg, max_batch, max_seq, kv_cache_dtype=None,
             f"max seq bucket {max_seq} exceeds the model's n_positions "
             f"{cfg.n_positions}")
     page_size, n_pages = int(page_size), int(n_pages)
-    if page_size:
-        if max_seq % page_size:
-            raise ValueError(
-                f"page_size {page_size} must divide max_seq {max_seq}")
-        if not n_pages:
-            # ring-capacity parity: every row can still fill its full
-            # max_seq span concurrently, plus the reserved trash page.
-            n_pages = int(max_batch) * (int(max_seq) // page_size) + 1
-        if n_pages < 2:
-            raise ValueError(
-                f"n_pages must be >= 2 (page 0 is the trash page), "
-                f"got {n_pages}")
+    if page_size < 1 or max_seq % page_size:
+        raise ValueError(
+            f"page_size {page_size} must be a positive divisor of "
+            f"max_seq {max_seq}")
+    if not n_pages:
+        # every row can fill its full max_seq span concurrently, plus
+        # the reserved trash page.
+        n_pages = int(max_batch) * (int(max_seq) // page_size) + 1
+    if n_pages < 2:
+        raise ValueError(
+            f"n_pages must be >= 2 (page 0 is the trash page), "
+            f"got {n_pages}")
     return KVCacheSpec(
         n_layer=cfg.n_layer, max_batch=int(max_batch),
         max_seq=int(max_seq), n_head=cfg.n_head,
         head_dim=cfg.n_embd // cfg.n_head, dtype=dtype, codec=codec,
         stacked=bool(cfg.scan_layers), page_size=page_size,
-        n_pages=n_pages if page_size else 0)
+        n_pages=n_pages)
 
 
 def payload_shape(spec):
-    """The shape of one layer's K (or V) buffer."""
-    if spec.paged:
-        return (spec.n_pages, spec.n_head, spec.head_dim, spec.page_size)
-    return (spec.max_batch, spec.max_seq, spec.n_head, spec.head_dim)
+    """The shape of one layer's K (or V) pool."""
+    return (spec.n_pages, spec.n_head, spec.head_dim, spec.page_size)
 
 
 def _layer_leaves(spec):
@@ -137,7 +125,7 @@ def _layer_leaves(spec):
               "v": jnp.zeros(shape, spec.dtype)}
     if spec.codec is not None:
         # one scale per (position, head): the payload less head_dim
-        sshape = shape[:2] + shape[3:] if spec.paged else shape[:-1]
+        sshape = shape[:2] + shape[3:]
         leaves["k_scale"] = jnp.zeros(sshape, jnp.float32)
         leaves["v_scale"] = jnp.zeros(sshape, jnp.float32)
     return leaves
@@ -181,16 +169,14 @@ def kv_partition_specs(spec, model_axis="model"):
     (`models/gpt2.py:gpt2_partition_specs`): each TP shard holds the
     heads it computes, so decode attention runs collective-free and the
     row-parallel ``c_proj`` psum GSPMD inserts is the only combine.
-    The ring keeps heads on axis 2 (``[B, S, H, D]``), the paged pool
-    on axis 1 (``[n_pages, H, D, page_size]``)."""
+    The pool keeps heads on axis 1 (``[n_pages, H, D, page_size]``)."""
     from jax.sharding import PartitionSpec as P
     lead = (None,) if spec.stacked else ()
     # no trailing None after the sharded head axis: jit keys compiled
     # programs on the exact sharding object, and GSPMD canonicalizes
     # output specs without trailing Nones — a trailing-None input spec
     # would mismatch the pinned output and recompile on the 2nd call.
-    before_head = (None,) if spec.paged else (None, None)
-    payload = scale = P(*lead, *before_head, model_axis)
+    payload = scale = P(*lead, None, model_axis)
 
     def per_layer():
         leaves = {"k": payload, "v": payload}
@@ -243,60 +229,15 @@ def _dequantize(q, scale, dtype):
     return (q.astype(jnp.float32) * scale[..., None]).astype(dtype)
 
 
-def _row_write(buf, new, start):
-    """Write ``new`` [B, T, ...] into ``buf`` [B, S, ...] at per-row
-    offsets ``start`` [B] (positions are contiguous per row, so one
-    dynamic_update_slice per row covers the whole chunk)."""
-    def one(row_buf, row_new, p):
-        idx = (p,) + (0,) * (row_buf.ndim - 1)
-        return jax.lax.dynamic_update_slice(row_buf, row_new, idx)
-    return jax.vmap(one)(buf, new, start)
-
-
-def write_kv(layer_cache, k_new, v_new, positions):
-    """Write one chunk's keys/values (``[B, T, H, D]``, compute dtype)
-    into a layer's cache at ``positions`` [B, T]; quantizes on the way
-    in when the cache stores a codec dtype."""
-    codec = _codec_of(layer_cache)
-    start = positions[:, 0]
-    if codec is None:
-        dt = layer_cache["k"].dtype
-        return {"k": _row_write(layer_cache["k"], k_new.astype(dt), start),
-                "v": _row_write(layer_cache["v"], v_new.astype(dt), start)}
-    k_q, k_s = _quantize(k_new, codec)
-    v_q, v_s = _quantize(v_new, codec)
-    return {
-        "k": _row_write(layer_cache["k"], k_q, start),
-        "v": _row_write(layer_cache["v"], v_q, start),
-        "k_scale": _row_write(layer_cache["k_scale"], k_s, start),
-        "v_scale": _row_write(layer_cache["v_scale"], v_s, start),
-    }
-
-
-def read_kv(layer_cache, dtype):
-    """The full ``[B, S, H, D]`` key/value buffers in compute ``dtype``
-    (dequantized when stored quantized)."""
-    codec = _codec_of(layer_cache)
-    if codec is None:
-        return (layer_cache["k"].astype(dtype),
-                layer_cache["v"].astype(dtype))
-    return (_dequantize(layer_cache["k"], layer_cache["k_scale"], dtype),
-            _dequantize(layer_cache["v"], layer_cache["v_scale"], dtype))
-
-
-def attention_mask(layer_cache, positions, page_table=None):
+def attention_mask(layer_cache, positions, page_table):
     """The dense path's ``[B, T, S]`` position mask (cache index ``s``
     visible to the query at position ``p`` iff ``s <= p``). Exposed so
     callers running several layers per step (`models/gpt2.py`) can
     compute it ONCE and pass it down — rebuilt per layer it is the
-    compiled decode program's only per-layer iota. With a paged cache
-    the buffer no longer carries the sequence length (``shape[-1]`` is
-    ``page_size``); ``S`` is ``pages_per_row * page_size`` off the page
-    table instead — the mask itself is layout-independent."""
-    if page_table is not None:
-        S = page_table.shape[-1] * layer_cache["k"].shape[-1]
-    else:
-        S = layer_cache["k"].shape[-3]
+    compiled decode program's only per-layer iota. The pool does not
+    carry the sequence length (``shape[-1]`` is ``page_size``); ``S``
+    is ``pages_per_row * page_size`` off the page table."""
+    S = page_table.shape[-1] * layer_cache["k"].shape[-1]
     return jnp.arange(S)[None, None, :] <= positions[:, :, None]
 
 
@@ -349,7 +290,7 @@ def paged_write_kv(layer_cache, k_new, v_new, positions, page_table):
     pool leaves (scales ``[n_pages, H, page_size]``);
     ``page_table`` is ``[B, pages_per_row]`` int32 of
     physical page ids (0 = trash for unallocated slots); positions are
-    contiguous per row as in :func:`write_kv`. Two shapes exist:
+    contiguous per row. Two shapes exist:
 
     - prefill (``B == 1``): the whole chunk into a single page
       (:func:`_write_chunk`) — the engine pins ``page_size %
@@ -363,10 +304,9 @@ def paged_write_kv(layer_cache, k_new, v_new, positions, page_table):
       entry 0 and land there too (rejected-tail rollback: those writes
       are garbage by construction and never become visible).
 
-    Quantization on the way in mirrors :func:`write_kv`: the pool's
-    per-(page, slot, head) scales are exactly the ring's per-(row,
-    position, head) scales under the page mapping, which is what lets
-    the flash kernel's fused dequant carry over unchanged.
+    A pool that stores a codec dtype quantizes on the way in, one
+    scale per (page, slot, head); the flash kernel's fused dequant
+    reads them beside the payload.
     """
     codec = _codec_of(layer_cache)
     page_size = layer_cache["k"].shape[-1]
@@ -394,9 +334,9 @@ def paged_write_kv(layer_cache, k_new, v_new, positions, page_table):
 def paged_read_kv(layer_cache, page_table, dtype):
     """Gather each row's pages into contiguous ``[B, S, H, D]``
     key/value buffers in compute ``dtype`` (S = pages_per_row *
-    page_size) — the dense oracle's view of the paged pool. Trash /
+    page_size) — the dense oracle's view of the pool. Trash /
     unallocated entries gather page 0's garbage, which the position
-    mask hides exactly like ring remnants."""
+    mask hides like any stale slot."""
     codec = _codec_of(layer_cache)
 
     def gather(buf):
@@ -414,41 +354,13 @@ def paged_read_kv(layer_cache, page_table, dtype):
                         gather(layer_cache["v_scale"]), dtype))
 
 
-def _flash_attend(q, layer_cache, positions, block_k, mesh):
-    """Flash split-K attention straight over the STORAGE buffers —
-    quantized caches stream int8/f8 payloads + f32 scales into the
-    kernel (`ops/pallas/flash_decode.py`) and never materialize a
-    dequantized ``[B, S, H, D]`` copy. With a TP ``mesh`` the call runs
-    under ``shard_map`` over the head axis, matching
-    :func:`kv_partition_specs` — each shard's kernel sees only its
-    local heads, collective-free."""
-    from deepspeed_tpu.ops.pallas import flash_decode
-
-    pos = positions[:, 0]
-    scales = ()
-    if "k_scale" in layer_cache:
-        scales = (layer_cache["k_scale"], layer_cache["v_scale"])
-
-    if mesh is None:
-        return flash_decode(q, layer_cache["k"], layer_cache["v"], pos,
-                            *scales, block_k=block_k)
-
-    from jax.sharding import PartitionSpec as P
-    head = P(None, None, "model", None)
-    in_specs = (head, head, head, P(None)) + \
-        ((P(None, None, "model"),) * 2 if scales else ())
-    sharded = jax.shard_map(
-        lambda q_, k_, v_, p_, *s_: flash_decode(q_, k_, v_, p_, *s_,
-                                                 block_k=block_k),
-        mesh=mesh, in_specs=in_specs, out_specs=head, check_vma=False)
-    return sharded(q, layer_cache["k"], layer_cache["v"], pos, *scales)
-
-
 def _flash_attend_paged(q, layer_cache, positions, page_table, block_k,
                         mesh):
-    """Paged twin of :func:`_flash_attend`: the kernel fetches each
-    row's live KV blocks straight out of the pool through the scalar-
-    prefetched page table
+    """Flash split-K attention straight over the STORAGE pool:
+    quantized pools stream int8/f8 payloads + f32 scales into the
+    kernel and never materialize a dequantized copy. The kernel
+    fetches each row's live KV blocks out of the pool through the
+    scalar-prefetched page table
     (`ops/pallas/flash_decode.py:flash_decode_paged`) — this code
     gathers and transposes nothing, and neither does XLA around the
     call: the pool's tiled layout is the kernel's. A row whose table
@@ -484,86 +396,51 @@ def _flash_attend_paged(q, layer_cache, positions, page_table, block_k,
 
 
 def cached_attention(q, k_new, v_new, layer_cache, positions,
-                     compute_dtype, impl="dense", block_k=128,
-                     mesh=None, mask=None, page_table=None):
+                     compute_dtype, page_table, impl="dense",
+                     block_k=128, mesh=None, mask=None):
     """Write this chunk's k/v, then attend over the whole cache row.
 
     ``q``/``k_new``/``v_new``: ``[B, T, H, D]`` (T = 1 for a decode
     step, ``prefill_chunk`` for a prefill chunk); ``positions``:
-    ``[B, T]`` absolute token positions, contiguous per row. Returns
-    ``(y [B, T, H, D], updated layer_cache)``.
+    ``[B, T]`` absolute token positions, contiguous per row;
+    ``page_table``: ``[B, pages_per_row]`` int32, the rows' physical
+    pages. Returns ``(y [B, T, H, D], updated layer_cache)``. Writes
+    route through :func:`paged_write_kv`.
 
     ``impl="flash"`` routes decode steps (T == 1) through the Pallas
     split-K kernel (`ops/pallas/flash_decode.py`): online-softmax over
-    ``block_k``-sized cache blocks with past-occupancy blocks skipped,
+    ``block_k``-sized cache blocks, only the blocks a row has filled,
     and quantized storage dequantized IN-kernel (scales as a side
     input — no fp32 cache copy). Prefill chunks (T > 1) always use the
-    dense path, which stays the parity oracle. ``mesh``: a TP mesh
-    whose ``model`` axis shards the cache's head dim — the flash call
-    then runs under ``shard_map`` per local head shard. ``mask``: a
-    precomputed :func:`attention_mask` (dense path only) so multi-layer
-    callers hoist it out of the per-layer body.
+    dense path over :func:`paged_read_kv`'s gathered view, which stays
+    the parity oracle. ``mesh``: a TP mesh whose ``model`` axis shards
+    the pool's head dim — the flash call then runs under ``shard_map``
+    per local head shard. ``mask``: a precomputed
+    :func:`attention_mask` (dense path only) so multi-layer callers
+    hoist it out of the per-layer body.
 
     The mask admits cache index ``s`` for the query at position ``p``
     iff ``s <= p`` — the cached generalization of the training path's
     ``tril(T, T)``: within a prefill chunk it reproduces the triangle,
     across chunks it exposes exactly the already-written prefix, and
-    for padded chunk tails / recycled-row remnants it hides everything
-    until a real token overwrites the slot.
-
-    ``page_table`` (``[B, pages_per_row]`` int32) switches the layout:
-    writes route through :func:`paged_write_kv`, the dense path attends
-    over :func:`paged_read_kv`'s gathered view, and flash decode steps
-    run the page-gather kernel. The attention math itself is layout-
-    blind — pages only change where bytes live, never what the mask
-    admits — which is what makes the ring the paged path's oracle.
+    for padded chunk tails / recycled-page remnants it hides everything
+    until a real token overwrites the slot. Pages only change where
+    bytes live, never what the mask admits.
     """
-    if page_table is None:
-        layer_cache = write_kv(layer_cache, k_new, v_new, positions)
-        if impl == "flash" and q.shape[1] == 1:
-            y = _flash_attend(q, layer_cache, positions, block_k, mesh)
-            return y.astype(compute_dtype), layer_cache
-        k_full, v_full = read_kv(layer_cache, compute_dtype)
-    else:
-        layer_cache = paged_write_kv(layer_cache, k_new, v_new,
-                                     positions, page_table)
-        if impl == "flash" and q.shape[1] == 1:
-            y = _flash_attend_paged(q, layer_cache, positions,
-                                    page_table, block_k, mesh)
-            return y.astype(compute_dtype), layer_cache
-        if mask is None:
-            mask = attention_mask(layer_cache, positions, page_table)
-        k_full, v_full = paged_read_kv(layer_cache, page_table,
-                                       compute_dtype)
+    layer_cache = paged_write_kv(layer_cache, k_new, v_new, positions,
+                                 page_table)
+    if impl == "flash" and q.shape[1] == 1:
+        y = _flash_attend_paged(q, layer_cache, positions, page_table,
+                                block_k, mesh)
+        return y.astype(compute_dtype), layer_cache
+    if mask is None:
+        mask = attention_mask(layer_cache, positions, page_table)
+    k_full, v_full = paged_read_kv(layer_cache, page_table, compute_dtype)
     D = q.shape[-1]
     scale = 1.0 / jnp.sqrt(jnp.asarray(D, compute_dtype))
     att = jnp.einsum("bthd,bshd->bhts", q, k_full) * scale
-    if mask is None:
-        mask = attention_mask(layer_cache, positions)
     att = jnp.where(mask[:, None], att, jnp.finfo(att.dtype).min)
     att = jax.nn.softmax(att.astype(jnp.float32),
                          axis=-1).astype(compute_dtype)
     y = jnp.einsum("bhts,bshd->bthd", att, v_full)
     return y, layer_cache
-
-
-def slice_rows(cache, slot, stacked, rows=1):
-    """The ``rows``-row sub-cache starting at row ``slot`` (a traced
-    scalar is fine — this is how the prefill jit addresses its target
-    row without baking the slot into the compiled program)."""
-    axis = 1 if stacked else 0
-    return jax.tree_util.tree_map(
-        lambda a: jax.lax.dynamic_slice_in_dim(a, slot, rows, axis=axis),
-        cache)
-
-
-def update_rows(cache, rows_tree, slot, stacked):
-    """Inverse of :func:`slice_rows`: write an updated row block back."""
-    axis = 1 if stacked else 0
-
-    def upd(a, r):
-        idx = [0] * a.ndim
-        idx[axis] = slot
-        return jax.lax.dynamic_update_slice(a, r, tuple(idx))
-
-    return jax.tree_util.tree_map(upd, cache, rows_tree)

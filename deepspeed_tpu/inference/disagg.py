@@ -251,10 +251,6 @@ class PrefillWorker:
     tier = "prefill"
 
     def __init__(self, engine, store, session=None):
-        if getattr(engine, "kv_layout", "ring") != "paged":
-            raise ValueError(
-                "disaggregated tiers require kv_layout='paged' — the "
-                "KV handoff is a page copy")
         if getattr(engine, "tier", None) not in (None, "prefill"):
             raise ValueError(
                 f"PrefillWorker needs a prefill-tier engine, got "
@@ -413,10 +409,6 @@ class DecodeWorker:
     tier = "decode"
 
     def __init__(self, engine, store, session=None):
-        if getattr(engine, "kv_layout", "ring") != "paged":
-            raise ValueError(
-                "disaggregated tiers require kv_layout='paged' — the "
-                "KV handoff is a page copy")
         if getattr(engine, "tier", None) not in (None, "decode"):
             raise ValueError(
                 f"DecodeWorker needs a decode-tier engine, got "

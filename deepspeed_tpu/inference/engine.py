@@ -6,15 +6,15 @@ which swaps the decode program for a draft/verify pair under the same
 never-recompile discipline):
 
 - **prefill** — one chunk of one prompt: ``[1, prefill_chunk]`` tokens
-  at explicit positions, written into cache row ``slot`` (a traced
-  scalar, so any row reuses the same program). Long prompts are a host
-  loop over same-shaped chunks — prompt length never reaches a jit
-  boundary, so it can't recompile the loop and a long prompt never
-  forces a fresh XLA program while decodes wait.
+  at explicit positions, written into the pages the prompt's page
+  table names (plain data, so any request reuses the same program).
+  Long prompts are a host loop over same-shaped chunks — prompt length
+  never reaches a jit boundary, so it can't recompile the loop and a
+  long prompt never forces a fresh XLA program while decodes wait.
 - **decode** — one token for every row at once: ``[max_batch]`` tokens
   at per-row positions over the full cache. Inactive rows compute
   garbage at position 0 and the scheduler ignores them; their writes
-  land on free rows that prefill overwrites at admission.
+  land on the trash page.
 
 Everything shape-varying (number of live requests, prompt lengths, per
 -request sequence budgets a.k.a. ``seq_buckets``) is host-side
@@ -23,12 +23,14 @@ recompile contract: :meth:`compile_counts` must read ``{"prefill": 1,
 "decode": 1}`` from warmup to drain, and :meth:`recompile_findings`
 turns any growth into the PR 4 detector's error finding.
 
-``kv_layout="paged"`` swaps the ring rows for a page pool
-(`inference/cache.py` paged layout): both programs take fixed-shape
-int32 page tables as plain data, so page allocation, prefix sharing
-and host-tier park/resume (`inference/paging.py`) are admission-time
-metadata under the SAME 2-compile contract — the pool shape and table
-shape never change, only their contents.
+The KV cache is one page pool a layer (`inference/cache.py`): both
+programs take fixed-shape int32 page tables as plain data, so page
+allocation, prefix sharing and host-tier park/resume
+(`inference/paging.py`) are admission-time metadata under the SAME
+2-compile contract — the pool shape and table shape never change, only
+their contents. (``kv_layout`` chose between this pool and a per-row
+ring buffer until PR 28; the key is still read, and anything but
+``"paged"`` is refused.)
 
 With a mesh whose ``model`` axis is >1 the engine places params with
 the model's Megatron PartitionSpecs (`models/gpt2.py:
@@ -49,9 +51,7 @@ from deepspeed_tpu.inference.cache import (
     init_kv_cache,
     kv_cache_nbytes,
     kv_partition_specs,
-    slice_rows,
     spec_for_model,
-    update_rows,
 )
 from deepspeed_tpu.telemetry.spans import Span, enclosing_attr
 
@@ -110,7 +110,12 @@ class InferenceEngine:
         self.top_k = int(_cfg_get(config, "top_k", 0))
         self.top_p = float(_cfg_get(config, "top_p", 1.0))
         self.sampling_seed = int(_cfg_get(config, "sampling_seed", 0))
-        self.kv_layout = str(_cfg_get(config, "kv_layout", "ring"))
+        kv_layout = _cfg_get(config, "kv_layout", "paged")
+        if kv_layout != "paged":
+            raise ValueError(
+                f"inference.kv_layout {kv_layout!r}: the paged pool is "
+                f"the only KV layout since PR 28 (the ring layout and "
+                f"the switch are gone); drop the key or set 'paged'")
         self.page_size = int(_cfg_get(config, "page_size", 0))
         self.n_pages = int(_cfg_get(config, "n_pages", 0))
         self.prefix_cache = bool(_cfg_get(config, "prefix_cache", True))
@@ -128,19 +133,10 @@ class InferenceEngine:
             raise ValueError(
                 f"inference tier must be 'prefill' or 'decode', got "
                 f"{self.tier!r}")
-        if self.tier is not None and \
-                str(_cfg_get(config, "kv_layout", "ring")) != "paged":
-            raise ValueError(
-                "tiered (disaggregated) engines require kv_layout="
-                "'paged' — the KV handoff is a page copy")
         if self.attention_impl not in ("dense", "flash"):
             raise ValueError(
                 f"inference.attention.impl must be 'dense' or 'flash', "
                 f"got {self.attention_impl!r}")
-        if self.kv_layout not in ("ring", "paged"):
-            raise ValueError(
-                f"inference.kv_layout must be 'ring' or 'paged', got "
-                f"{self.kv_layout!r}")
         if not 0.0 <= self.host_park_threshold < 1.0:
             raise ValueError(
                 f"host_park_threshold must be in [0, 1), got "
@@ -178,59 +174,48 @@ class InferenceEngine:
             raise ValueError(
                 f"attention block_k {self.attention_block_k} must be a "
                 f"positive divisor of max_seq {self.max_seq}")
-        if self.kv_layout == "paged":
-            if not self.page_size:
-                # auto: two prefill chunks per page — fine-grained
-                # enough for the bytes/session win, coarse enough that
-                # page tables stay short.
-                self.page_size = min(2 * self.prefill_chunk,
-                                     self.max_seq)
-            if self.page_size % self.prefill_chunk:
-                # a prefill chunk must land inside ONE page (the paged
-                # prefill write is a single dynamic_update_slice).
-                raise ValueError(
-                    f"page_size {self.page_size} must be a multiple of "
-                    f"prefill_chunk {self.prefill_chunk}")
-            if self.max_seq % self.page_size:
-                raise ValueError(
-                    f"page_size {self.page_size} must divide max_seq "
-                    f"{self.max_seq}")
-            # a flash KV block must not straddle a page boundary
-            self.attention_block_k = min(self.attention_block_k,
-                                         self.page_size)
-            if self.page_size % self.attention_block_k:
-                raise ValueError(
-                    f"attention block_k {self.attention_block_k} must "
-                    f"divide page_size {self.page_size}")
-        else:
-            self.page_size = 0
-            self.n_pages = 0
+        if not self.page_size:
+            # auto: two prefill chunks per page — fine-grained enough
+            # for the bytes/session win, coarse enough that page
+            # tables stay short.
+            self.page_size = min(2 * self.prefill_chunk, self.max_seq)
+        if self.page_size % self.prefill_chunk:
+            # a prefill chunk must land inside ONE page (the prefill
+            # write is a single dynamic_update_slice).
+            raise ValueError(
+                f"page_size {self.page_size} must be a multiple of "
+                f"prefill_chunk {self.prefill_chunk}")
+        if self.max_seq % self.page_size:
+            raise ValueError(
+                f"page_size {self.page_size} must divide max_seq "
+                f"{self.max_seq}")
+        # a flash KV block must not straddle a page boundary
+        self.attention_block_k = min(self.attention_block_k,
+                                     self.page_size)
+        if self.page_size % self.attention_block_k:
+            raise ValueError(
+                f"attention block_k {self.attention_block_k} must "
+                f"divide page_size {self.page_size}")
         self.spec = spec_for_model(cfg, self.max_batch, self.max_seq,
                                    self.kv_cache_dtype,
                                    page_size=self.page_size,
                                    n_pages=self.n_pages)
         self.n_pages = self.spec.n_pages
         self.pages_per_row = self.spec.pages_per_row
-        # the paged flash kernel's visit set, for decode()'s counters
+        # the flash kernel's visit set, for decode()'s counters
         self._paged_grid_blocks = None
         if self.attention_impl == "flash":
             # a block/storage geometry the flash kernel cannot compile
             # for this device is a typed error here, at build time
             from deepspeed_tpu.ops.pallas.flash_decode import (
                 check_decode_geometry, paged_grid_blocks)
-            paged = self.kv_layout == "paged"
-            if paged:
-                self._paged_grid_blocks = paged_grid_blocks
-            extent, name = (self.page_size, "page_size") if paged \
-                else (self.max_seq, "max_seq")
-            quant = self.spec.codec is not None
-            # the paged kernel holds all the heads a device has
+            self._paged_grid_blocks = paged_grid_blocks
+            # the kernel holds all the heads a device has
             tp = dict(mesh.shape).get("model", 1) if mesh is not None else 1
             self.attention_block_k = check_decode_geometry(
-                self.attention_block_k, extent, name, self.spec.dtype,
-                paged or quant,
-                paged_heads=(self.spec.n_head // tp, self.spec.head_dim,
-                             quant) if paged else None)
+                self.attention_block_k, self.page_size, self.spec.dtype,
+                self.spec.n_head // tp, self.spec.head_dim,
+                self.spec.codec is not None)
         self.mesh = mesh
         self.session = session
         self._sample_key = jax.random.PRNGKey(self.sampling_seed)
@@ -261,21 +246,12 @@ class InferenceEngine:
         self.params = params
         self.cache = cache
 
-        # cache (arg 1) is donated in both programs: the ring buffer /
-        # page pool updates in place instead of doubling HBM every
-        # step. Layout picks which trace to compile — page tables are
-        # plain int32 DATA inputs with a fixed shape, so allocator
-        # churn never reaches a jit boundary.
-        if self.kv_layout == "paged":
-            self._prefill = donated_jit(self._prefill_fn_paged,
-                                        donate_argnums=(1,))
-            self._decode = donated_jit(self._decode_fn_paged,
-                                       donate_argnums=(1,))
-        else:
-            self._prefill = donated_jit(self._prefill_fn,
-                                        donate_argnums=(1,))
-            self._decode = donated_jit(self._decode_fn,
-                                       donate_argnums=(1,))
+        # cache (arg 1) is donated in both programs: the page pool
+        # updates in place instead of doubling HBM every step. Page
+        # tables are plain int32 DATA inputs with a fixed shape, so
+        # allocator churn never reaches a jit boundary.
+        self._prefill = donated_jit(self._prefill_fn, donate_argnums=(1,))
+        self._decode = donated_jit(self._decode_fn, donate_argnums=(1,))
 
         # speculative decoding (inference.speculative block): a draft
         # + verify program pair hung off the engine, or None when the
@@ -304,48 +280,23 @@ class InferenceEngine:
             jax.lax.with_sharding_constraint, cache,
             self._cache_shardings)
 
-    def _prefill_fn(self, params, cache, tokens, positions, slot):
-        row = slice_rows(cache, slot, self.spec.stacked)
-        logits, new_row = self.model.apply(
+    def _prefill_fn(self, params, cache, tokens, positions, page_table):
+        # prefill addresses the POOL through the chunk's page table;
+        # the whole cache flows through so donation updates it in place.
+        logits, cache = self.model.apply(
             {"params": params}, tokens, deterministic=True,
-            positions=positions, kv_cache=row)
-        cache = update_rows(cache, new_row, slot, self.spec.stacked)
+            positions=positions, kv_cache=cache,
+            kv_page_table=page_table)
         # fp32 on the way out: host-side sampling/parity reads full
         # precision regardless of compute dtype (a no-op for f32 models,
         # so fp32 parity with the full forward stays bit-exact).
         return logits.astype(jnp.float32), self._pin_cache(cache)
 
-    def _decode_fn(self, params, cache, tokens, positions, key):
+    def _decode_fn(self, params, cache, tokens, positions, page_tables,
+                   key):
         # attention impl / block size / sampling knobs are static (read
         # off self at trace time): they select the traced graph, never
         # ride as runtime values — changing them means a new engine.
-        mesh = self.mesh if self._cache_shardings is not None else None
-        logits, cache = self.model.apply(
-            {"params": params}, tokens[:, None], deterministic=True,
-            positions=positions[:, None], kv_cache=cache,
-            attn_impl=self.attention_impl,
-            attn_block_k=self.attention_block_k, attn_mesh=mesh)
-        logits = logits[:, 0]
-        from deepspeed_tpu.inference.sampling import sample_logits
-        next_tokens, key = sample_logits(
-            logits, key, temperature=self.temperature,
-            top_k=self.top_k, top_p=self.top_p)
-        return next_tokens, logits.astype(jnp.float32), key, \
-            self._pin_cache(cache)
-
-    def _prefill_fn_paged(self, params, cache, tokens, positions,
-                          page_table):
-        # the paged prefill addresses the POOL through the chunk's
-        # page table — no row slice/unslice; the whole cache flows
-        # through so donation still updates it in place.
-        logits, cache = self.model.apply(
-            {"params": params}, tokens, deterministic=True,
-            positions=positions, kv_cache=cache,
-            kv_page_table=page_table)
-        return logits.astype(jnp.float32), self._pin_cache(cache)
-
-    def _decode_fn_paged(self, params, cache, tokens, positions,
-                         page_tables, key):
         mesh = self.mesh if self._cache_shardings is not None else None
         logits, cache = self.model.apply(
             {"params": params}, tokens[:, None], deterministic=True,
@@ -363,18 +314,18 @@ class InferenceEngine:
 
     # -- host API -----------------------------------------------------------
 
-    def prefill(self, slot, prompt, page_table=None, start=0):
-        """Chunked prefill of ``prompt`` (token ids) into cache row
+    def prefill(self, slot, prompt, page_table, start=0):
+        """Chunked prefill of ``prompt`` (token ids) for batch row
         ``slot``; returns the fp-logits at the last prompt token
         (``[vocab]``, numpy) — what greedy sampling of the first
         generated token reads.
 
-        Paged layout: ``page_table`` (``[pages_per_row]`` ints, pages
-        covering the prompt allocated by the scheduler) addresses the
-        pool instead of ``slot``, and ``start`` (chunk-aligned) resumes
-        mid-prompt — a prefix-cache hit skips the chunks the shared
-        pages already hold; a parked-session resume restarts at the
-        session's frontier. The skipped span's KV is bit-identical by
+        ``page_table`` (``[pages_per_row]`` ints, pages covering the
+        prompt allocated by the scheduler) addresses the pool (``slot``
+        names the row for callers' bookkeeping only), and ``start``
+        (chunk-aligned) resumes mid-prompt — a prefix-cache hit skips
+        the chunks the shared pages already hold; a parked-session
+        resume restarts at the session's frontier. The skipped span's KV is bit-identical by
         construction: prefill is deterministic, so re-running it would
         write the same bytes the shared pages already carry."""
         if self.tier == "decode":
@@ -385,8 +336,6 @@ class InferenceEngine:
         if not 0 < n <= self.max_seq:
             raise ValueError(
                 f"prompt length {n} outside (0, max_seq={self.max_seq}]")
-        if self.kv_layout == "paged" and page_table is None:
-            raise ValueError("paged prefill requires a page_table")
         # one span for the whole prompt, its uploads included; ``rid``
         # is the enclosing (scheduler's ``admit``) span's, so the call
         # takes no new argument
@@ -402,13 +351,10 @@ class InferenceEngine:
         toks = np.zeros((1, padded), np.int32)
         toks[0, :n] = np.asarray(prompt, np.int32)
         last_chunk = (n - 1) // chunk
-        paged = self.kv_layout == "paged"
-        if paged:
-            pt = jnp.asarray(
-                np.asarray(page_table, np.int32).reshape(1, -1))
+        pt = jnp.asarray(np.asarray(page_table, np.int32).reshape(1, -1))
         # the last chunk always runs (it produces the logits the first
         # sampled token reads), so a resume start clamps to it.
-        start = min(int(start), last_chunk * chunk) if paged else 0
+        start = min(int(start), last_chunk * chunk)
         if start % chunk:
             raise ValueError(
                 f"prefill start {start} must be chunk-aligned "
@@ -423,34 +369,26 @@ class InferenceEngine:
             tc = jnp.asarray(toks[:, ci * chunk:(ci + 1) * chunk])
             pc = jnp.arange(ci * chunk, (ci + 1) * chunk,
                             dtype=jnp.int32)[None, :]
-            if paged:
-                logits, self.cache = self._prefill(
-                    self.params, self.cache, tc, pc, pt)
-            else:
-                logits, self.cache = self._prefill(
-                    self.params, self.cache, tc, pc,
-                    jnp.asarray(slot, jnp.int32))
+            logits, self.cache = self._prefill(
+                self.params, self.cache, tc, pc, pt)
             if ci == last_chunk:
                 last = np.asarray(logits[0, (n - 1) % chunk])
         return last
 
-    def decode(self, tokens, positions, page_tables=None):
+    def decode(self, tokens, positions, page_tables):
         """One decode step for every cache row at once. ``tokens`` /
         ``positions``: ``[max_batch]`` int arrays (inactive rows padded
         with zeros — their outputs are meaningless and ignored).
         Returns ``(next_tokens [max_batch], logits [max_batch, vocab])``
         as numpy; sampling (greedy argmax, or temperature/top-k/top-p
         with the threaded PRNG key) happens in-program so it costs no
-        extra device round trip. Paged layout additionally takes the
-        ``[max_batch, pages_per_row]`` page tables (inactive rows all
-        zeros — their garbage token lands on the trash page)."""
+        extra device round trip. ``page_tables``: ``[max_batch,
+        pages_per_row]`` (inactive rows all zeros — their garbage token
+        lands on the trash page)."""
         if self.tier == "prefill":
             raise RuntimeError(
                 "prefill-tier engine: the decode program is pinned off "
                 "— decode belongs to the decode tier")
-        paged = self.kv_layout == "paged"
-        if paged and page_tables is None:
-            raise ValueError("paged decode requires page_tables")
         session = self.session
         attrs = None
         if self._paged_grid_blocks is not None:
@@ -467,10 +405,8 @@ class InferenceEngine:
         with Span("decode", session, attrs):
             with Span("upload", session):
                 args = [jnp.asarray(np.asarray(tokens, np.int32)),
-                        jnp.asarray(np.asarray(positions, np.int32))]
-                if paged:
-                    args.append(
-                        jnp.asarray(np.asarray(page_tables, np.int32)))
+                        jnp.asarray(np.asarray(positions, np.int32)),
+                        jnp.asarray(np.asarray(page_tables, np.int32))]
             with Span("dispatch", session):
                 nxt, logits, self._sample_key, self.cache = self._decode(
                     self.params, self.cache, *args, self._sample_key)
@@ -480,7 +416,7 @@ class InferenceEngine:
                 logits = np.asarray(logits)
         return nxt, logits
 
-    # -- host-RAM page tier (paged layout only) -----------------------------
+    # -- host-RAM page tier -------------------------------------------------
 
     def gather_pages(self, page_ids):
         """Snapshot the given physical pages to host RAM: a per-layer
@@ -596,16 +532,10 @@ class InferenceEngine:
     def decode_lowering_args(self):
         """The exact avals :meth:`decode` calls with — lowering through
         these is a jit-cache hit, never a fresh compile."""
-        if self.kv_layout == "paged":
-            return (self.params, self.cache,
-                    jnp.zeros((self.max_batch,), jnp.int32),
-                    jnp.zeros((self.max_batch,), jnp.int32),
-                    jnp.zeros((self.max_batch, self.pages_per_row),
-                              jnp.int32),
-                    self._sample_key)
         return (self.params, self.cache,
                 jnp.zeros((self.max_batch,), jnp.int32),
                 jnp.zeros((self.max_batch,), jnp.int32),
+                jnp.zeros((self.max_batch, self.pages_per_row), jnp.int32),
                 self._sample_key)
 
     def decode_hlo(self):
@@ -618,16 +548,14 @@ class InferenceEngine:
         facts = {"bytes": kv_cache_nbytes(self.cache),
                  "dtype_census": cache_dtype_census(self.cache),
                  "kv_cache_dtype": self.kv_cache_dtype,
-                 "kv_layout": self.kv_layout,
                  "max_batch": self.max_batch,
                  "max_seq": self.max_seq,
                  "seq_buckets": list(self.seq_buckets),
                  "prefill_chunk": self.prefill_chunk,
-                 "stacked": self.spec.stacked}
-        if self.kv_layout == "paged":
-            facts.update(page_size=self.page_size,
-                         n_pages=self.n_pages,
-                         pages_per_row=self.pages_per_row)
+                 "stacked": self.spec.stacked,
+                 "page_size": self.page_size,
+                 "n_pages": self.n_pages,
+                 "pages_per_row": self.pages_per_row}
         if self.tier is not None:
             facts["tier"] = self.tier
         if self.speculative is not None:

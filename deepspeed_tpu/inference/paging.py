@@ -308,8 +308,6 @@ class PagedCacheManager:
     ``gather_pages``/``scatter_pages`` and static facts."""
 
     def __init__(self, engine, session=None):
-        if engine.kv_layout != "paged":
-            raise ValueError("PagedCacheManager requires a paged engine")
         self.engine = engine
         self.session = session
         self.page_size = engine.page_size

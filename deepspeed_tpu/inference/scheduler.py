@@ -2,7 +2,7 @@
 
 The compiled decode step always runs the full ``[max_batch]`` row
 block; this scheduler is everything around it — an open-loop request
-queue, slot assignment (the ring: a finished request's row goes
+queue, slot assignment (a finished request's row goes
 straight to the next arrival), per-request sequence budgets from
 ``seq_buckets``, and the pad arrays that keep inactive rows
 shape-stable. None of it touches a jit boundary, so admission, buckets
@@ -32,15 +32,14 @@ has returned: the token exists on the host), ``first_return_t`` (the
 ``serve/request`` record in the span ring. Every ``step()`` is one
 ``serve/step`` span whose attrs carry that step's counters, read at the
 step's end (``live_rows``, ``batch``, ``max_batch``, ``queue_depth``,
-``tokens``; paged also ``pages_live``, ``pages_resident``,
-``pages_total``), with children ``expire``, ``admit`` (one per request
-taken up, attrs ``rid``; under it ``pages``, the engine's ``prefill``
-and ``sample``), ``grow``, ``inputs``, the engine's ``decode`` (under
+``tokens``, ``pages_live``, ``pages_resident``, ``pages_total``), with
+children ``expire``, ``admit`` (one per request taken up, attrs
+``rid``; under it ``pages``, the engine's ``prefill`` and ``sample``), ``grow``, ``inputs``, the engine's ``decode`` (under
 it ``upload``, ``dispatch``, ``wait_tokens``, ``logits_d2h``; a
 speculative engine has ``draft`` and ``verify`` instead) and ``book``.
 
-With a paged engine (``inference.kv_layout = "paged"``) the scheduler
-delegates page mapping to `inference/paging.py:PagedCacheManager`:
+The scheduler delegates page mapping to
+`inference/paging.py:PagedCacheManager`:
 admission walks the radix prefix cache (shared pages mapped, prefill
 resumed mid-prompt), each decode step grows rows' mappings page by
 page, and a finished request carrying a ``session_id`` parks its pages
@@ -161,7 +160,7 @@ class _Slot:
     pending: int                # last sampled token (next decode input)
     generated: List[int]
     admitted_step: int
-    paging: object = None       # RowPaging when the engine is paged
+    paging: object = None       # the row's RowPaging
     admit_t: Optional[float] = None
     first_return_t: Optional[float] = None
     # one stamp per generated token; the first is the first token's
@@ -181,10 +180,8 @@ class ContinuousBatchingScheduler:
         # first token is not out before that step returns
         self._unreturned = []
         self._step_attrs = {}           # the running step's counters
-        self.paging = None
-        if getattr(engine, "kv_layout", "ring") == "paged":
-            from deepspeed_tpu.inference.paging import PagedCacheManager
-            self.paging = PagedCacheManager(engine, session=self.session)
+        from deepspeed_tpu.inference.paging import PagedCacheManager
+        self.paging = PagedCacheManager(engine, session=self.session)
 
     # -- request lifecycle --------------------------------------------------
 
@@ -224,8 +221,7 @@ class ContinuousBatchingScheduler:
                 request.submit_t = now
             if request.arrival_t is None:
                 request.arrival_t = request.submit_t
-            if row is not None and self.paging is not None:
-                self.paging.adopt(row)
+            self.paging.adopt(row)
             self.slots[i] = _Slot(
                 request=request, bucket=self._bucket_for(request),
                 next_pos=len(request.prompt), pending=first_token,
@@ -257,19 +253,18 @@ class ContinuousBatchingScheduler:
             admit_t=s.admit_t, first_token_t=s.token_t[0],
             first_return_t=s.first_return_t, token_t=s.token_t,
             finish_t=clock())
-        if s.paging is not None:
-            comp.prefix_hit = s.paging.prefix_hit
-            comp.resumed = s.paging.resumed
-            comp.prefill_chunks = s.paging.prefill_chunks
-            comp.prefill_chunks_skipped = s.paging.prefill_chunks_skipped
-            # KV on the pages covers the prompt plus every generated
-            # token that fed a later decode step (the LAST sampled
-            # token was never written — nothing attended past it).
-            kv_tokens = list(s.request.prompt) + s.generated[:-1]
-            self.paging.release(s.paging, kv_tokens=kv_tokens,
-                                session_id=s.request.session_id)
+        comp.prefix_hit = s.paging.prefix_hit
+        comp.resumed = s.paging.resumed
+        comp.prefill_chunks = s.paging.prefill_chunks
+        comp.prefill_chunks_skipped = s.paging.prefill_chunks_skipped
+        # KV on the pages covers the prompt plus every generated
+        # token that fed a later decode step (the LAST sampled
+        # token was never written — nothing attended past it).
+        kv_tokens = list(s.request.prompt) + s.generated[:-1]
+        self.paging.release(s.paging, kv_tokens=kv_tokens,
+                            session_id=s.request.session_id)
         self.completions.append(comp)
-        self.slots[i] = None            # row back on the ring
+        self.slots[i] = None            # row free again
         if comp.first_return_t is None:
             self._unreturned.append(comp)   # step() stamps and records
         else:
@@ -384,23 +379,16 @@ class ContinuousBatchingScheduler:
         first token. False (and the request stays queued) when the pool
         cannot back its prompt."""
         session = self.session
-        row = None
-        if self.paging is not None:
-            with Span("pages", session):
-                row = self.paging.admit(req.prompt,
-                                        session_id=req.session_id)
-            if row is None:
-                return False
+        with Span("pages", session):
+            row = self.paging.admit(req.prompt, session_id=req.session_id)
+        if row is None:
+            return False
         self.queue.popleft()
         admit_t = clock()
-        if row is not None:
-            last_logits = self.engine.prefill(
-                i, req.prompt,
-                page_table=row.table(self.paging.pages_per_row),
-                start=row.start)
-            self.paging.after_prefill(row, req.prompt)
-        else:
-            last_logits = self.engine.prefill(i, req.prompt)
+        last_logits = self.engine.prefill(
+            i, req.prompt, page_table=row.table(self.paging.pages_per_row),
+            start=row.start)
+        self.paging.after_prefill(row, req.prompt)
         with Span("sample", session):
             first = self.engine.sample_first(last_logits)
         self.slots[i] = _Slot(
@@ -433,30 +421,26 @@ class ContinuousBatchingScheduler:
                     attrs["live_rows"] = sum(
                         s is not None for s in self.slots)
                     attrs["queue_depth"] = len(self.queue)
-                    if self.paging is not None:
-                        alloc = self.paging.allocator
-                        attrs["pages_live"] = self.paging.pages_live
-                        attrs["pages_resident"] = alloc.resident_pages
-                        attrs["pages_total"] = alloc.n_pages - 1
+                    alloc = self.paging.allocator
+                    attrs["pages_live"] = self.paging.pages_live
+                    attrs["pages_resident"] = alloc.resident_pages
+                    attrs["pages_total"] = alloc.n_pages - 1
         finally:
             self._stamp_returned()
 
     def _inputs(self, active):
-        """The decode step's numpy inputs: pending token and position
-        of every live row, and with a pool the page tables."""
+        """The decode step's numpy inputs: pending token, position and
+        page table of every live row."""
         mb = self.engine.max_batch
         tokens = np.zeros(mb, np.int32)
         positions = np.zeros(mb, np.int32)
         for i in active:
             tokens[i] = self.slots[i].pending
             positions[i] = self.slots[i].next_pos
-        page_tables = None
-        if self.paging is not None:
-            page_tables = np.zeros((mb, self.paging.pages_per_row),
-                                   np.int32)
-            for i in active:
-                page_tables[i] = self.slots[i].paging.table(
-                    self.paging.pages_per_row)
+        page_tables = np.zeros((mb, self.paging.pages_per_row), np.int32)
+        for i in active:
+            page_tables[i] = self.slots[i].paging.table(
+                self.paging.pages_per_row)
         return tokens, positions, page_tables
 
     def _step(self):
@@ -466,17 +450,16 @@ class ContinuousBatchingScheduler:
         self._admit()
         if getattr(self.engine, "speculative", None) is not None:
             return self._spec_step(self.engine.speculative)
-        if self.paging is not None:
-            # grow each live row's page mapping to cover this step's
-            # write BEFORE building the tables; a row the pool can't
-            # grow even after the eviction ladder is length-finished
-            # (same truncation contract as a bucket edge).
-            with Span("grow", session):
-                for i, s in enumerate(self.slots):
-                    if s is not None and \
-                            not self.paging.ensure_position(s.paging,
-                                                            s.next_pos):
-                        self._finish(i, "length")
+        # grow each live row's page mapping to cover this step's write
+        # BEFORE building the tables; a row the pool can't grow even
+        # after the eviction ladder is length-finished (same truncation
+        # contract as a bucket edge).
+        with Span("grow", session):
+            for i, s in enumerate(self.slots):
+                if s is not None and \
+                        not self.paging.ensure_position(s.paging,
+                                                        s.next_pos):
+                    self._finish(i, "length")
         active = [i for i, s in enumerate(self.slots) if s is not None]
         if not active:
             self.step_count += 1        # idle tick (open-loop gap)
@@ -489,11 +472,8 @@ class ContinuousBatchingScheduler:
         fault_injection.maybe_kill("decode_step", self.step_count)
         fault_injection.maybe_fail_decode(self.step_count)
         t_in = clock()
-        if page_tables is None:
-            next_tokens, _ = self.engine.decode(tokens, positions)
-        else:
-            next_tokens, _ = self.engine.decode(tokens, positions,
-                                                page_tables=page_tables)
+        next_tokens, _ = self.engine.decode(tokens, positions,
+                                            page_tables=page_tables)
         t_tokens = clock()      # the host has this step's tokens
         self.step_count += 1
         with Span("book", session):
@@ -516,26 +496,24 @@ class ContinuousBatchingScheduler:
 
         Row discipline: a row must have ``k + 1`` slots of physical
         headroom before the round (the verify chunk writes positions
-        ``next_pos..next_pos+k``; past ``max_seq`` the ring write's
-        dynamic_update_slice would CLAMP the start and shift the whole
-        chunk onto valid history, and a paged table lookup would clamp
-        to the last page) — rows inside that margin length-finish now,
-        the same truncation contract as a bucket edge, at most k tokens
-        early. Paged rows also grow their mapping to cover every
-        potentially-ACCEPTED write (``next_pos + j``); pad writes past
-        the mapping land on the trash page by the PR 16 discipline."""
+        ``next_pos..next_pos+k``; past ``max_seq`` the page-table
+        lookup would clamp to the row's last page and overwrite valid
+        history) — rows inside that margin length-finish now, the same
+        truncation contract as a bucket edge, at most k tokens early.
+        Rows also grow their mapping to cover every potentially-
+        ACCEPTED write (``next_pos + j``); pad writes past the mapping
+        land on the trash page by the PR 16 discipline."""
         k = spec.k
         j = spec.draft_len()
         for i, s in enumerate(self.slots):
             if s is not None and \
                     s.next_pos + k + 1 > self.engine.max_seq:
                 self._finish(i, "length")
-        if self.paging is not None:
-            with Span("grow", self.session):
-                for i, s in enumerate(self.slots):
-                    if s is not None and not self.paging.ensure_span(
-                            s.paging, s.next_pos, s.next_pos + j):
-                        self._finish(i, "length")
+        with Span("grow", self.session):
+            for i, s in enumerate(self.slots):
+                if s is not None and not self.paging.ensure_span(
+                        s.paging, s.next_pos, s.next_pos + j):
+                    self._finish(i, "length")
         active = [i for i, s in enumerate(self.slots) if s is not None]
         if not active:
             self.step_count += 1        # idle tick (open-loop gap)
@@ -648,18 +626,17 @@ class ContinuousBatchingScheduler:
             return
         occ = batch / float(self.engine.max_batch)
         tokens = batch if tokens is None else tokens
-        extra = dict(spec_stats or {})
-        if self.paging is not None:
-            pg = self.paging
-            extra.update(
-                pages_free=pg.allocator.free_pages,
-                pages_resident=pg.allocator.resident_pages,
-                pages_live=pg.pages_live,
-                prefix_hits=pg.prefix_hits,
-                prefix_misses=pg.prefix_misses,
-                sessions_admitted=pg.sessions_admitted,
-                sessions_parked_host=len(pg.host_store),
-                cache_bytes=pg.page_bytes() * pg.engine.n_pages)
+        pg = self.paging
+        extra = dict(
+            spec_stats or {},
+            pages_free=pg.allocator.free_pages,
+            pages_resident=pg.allocator.resident_pages,
+            pages_live=pg.pages_live,
+            prefix_hits=pg.prefix_hits,
+            prefix_misses=pg.prefix_misses,
+            sessions_admitted=pg.sessions_admitted,
+            sessions_parked_host=len(pg.host_store),
+            cache_bytes=pg.page_bytes() * pg.engine.n_pages)
         self.session.emit(
             "decode_step", step=self.step_count, tokens=tokens,
             batch=batch, occupancy=occ, queue_depth=len(self.queue),

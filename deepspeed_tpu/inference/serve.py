@@ -5,12 +5,12 @@
     ds_tpu_serve --config ds_config.json      # inference block from config
     ds_tpu_serve --scan-layers --kv-cache-dtype int8
     ds_tpu_serve --expect-compiles 2 --json
-    ds_tpu_serve --synthetic 8 --kv-layout paged --shared-prefix 12 \
+    ds_tpu_serve --synthetic 8 --shared-prefix 12 \
                  --expect-prefix-hits 1   # radix prefix-cache smoke
     ds_tpu_serve --synthetic 8 --replicas 2 \
                  --kill-replica 0 --kill-at-step 3 \
                  --expect-redispatch 1    # fleet resilience smoke
-    ds_tpu_serve --synthetic 8 --kv-layout paged --disaggregate \
+    ds_tpu_serve --synthetic 8 --disaggregate \
                  --prefill-workers 1 --decode-workers 1 \
                  --expect-compiles 2      # tiered prefill/decode smoke
     ds_tpu_serve --synthetic 8 --speculative --spec-k 4 \
@@ -602,26 +602,22 @@ def main(argv=None):
     parser.add_argument("--block-k", type=int, default=None,
                         help="flash-decode KV block size (must divide "
                              "max(seq_buckets))")
-    parser.add_argument("--kv-layout", default=None,
-                        choices=("ring", "paged"),
-                        help="KV cache layout: per-row ring buffers or "
-                             "the paged pool with radix prefix sharing")
     parser.add_argument("--page-size", type=int, default=None,
-                        help="paged layout: tokens per KV page (0 = "
+                        help="tokens per KV page (0 = "
                              "auto; must be a multiple of "
                              "prefill_chunk and divide max seq bucket)")
     parser.add_argument("--n-pages", type=int, default=None,
-                        help="paged layout: physical pool pages "
+                        help="physical pool pages "
                              "(0 = auto; page 0 is the trash page)")
     parser.add_argument("--prefix-cache", dest="prefix_cache",
                         action="store_true", default=None,
-                        help="paged layout: intern finished prompts in "
+                        help="intern finished prompts in "
                              "the radix prefix cache (default on)")
     parser.add_argument("--no-prefix-cache", dest="prefix_cache",
                         action="store_false",
-                        help="paged layout: disable prefix sharing")
+                        help="disable prefix sharing")
     parser.add_argument("--park-threshold", type=float, default=None,
-                        help="paged layout: evacuate parked sessions "
+                        help="evacuate parked sessions "
                              "to host RAM when the free-page fraction "
                              "drops below this (0 disables)")
     parser.add_argument("--shared-prefix", type=int, default=0,
@@ -629,7 +625,7 @@ def main(argv=None):
                              "the same N tokens (a shared system "
                              "prompt) to exercise the prefix cache")
     parser.add_argument("--expect-prefix-hits", type=int, default=None,
-                        help="exit 1 unless the paged prefix cache "
+                        help="exit 1 unless the prefix cache "
                              "recorded at least this many hits")
     parser.add_argument("--temperature", type=float, default=None,
                         help="sampling temperature (0 = greedy argmax, "
@@ -852,7 +848,6 @@ def main(argv=None):
                    "top_k": inf.top_k,
                    "top_p": inf.top_p,
                    "sampling_seed": inf.sampling_seed,
-                   "kv_layout": inf.kv_layout,
                    "page_size": inf.page_size,
                    "n_pages": inf.n_pages,
                    "prefix_cache": inf.prefix_cache,
@@ -887,8 +882,6 @@ def main(argv=None):
         inf_cfg["top_k"] = args.top_k
     if args.top_p is not None:
         inf_cfg["top_p"] = args.top_p
-    if args.kv_layout is not None:
-        inf_cfg["kv_layout"] = args.kv_layout
     if args.page_size is not None:
         inf_cfg["page_size"] = args.page_size
     if args.n_pages is not None:
@@ -902,9 +895,6 @@ def main(argv=None):
             "enabled": True, "k": args.spec_k,
             "draft_layers": args.draft_layers,
             "min_accept_to_grow": args.min_accept_to_grow}
-    if args.expect_prefix_hits is not None and \
-            inf_cfg.get("kv_layout", "ring") != "paged":
-        parser.error("--expect-prefix-hits requires --kv-layout paged")
     # --seed doubles as the sampling seed: one knob pins params, the
     # synthetic stream, AND the in-program sampler, so a serve is
     # reproducible end to end (a non-default --seed beats the config).
@@ -927,10 +917,6 @@ def main(argv=None):
     args.disaggregate = args.disaggregate or bool(
         inf_cfg.get("disaggregated"))
     if args.disaggregate:
-        if inf_cfg.get("kv_layout", "ring") != "paged":
-            parser.error("--disaggregate requires --kv-layout paged "
-                         "(the prefill->decode handoff is a KV page "
-                         "copy)")
         if inf_cfg.get("speculative"):
             parser.error("config enables speculative decoding but the "
                          "serve is disaggregated; the tiers pin one "
@@ -997,8 +983,7 @@ def main(argv=None):
                      "top_k": engine.top_k, "top_p": engine.top_p,
                      "seed": engine.sampling_seed},
     }
-    if sched.paging is not None:
-        result["paging"] = sched.paging.facts()
+    result["paging"] = sched.paging.facts()
     if engine.speculative is not None:
         result["speculative"] = engine.speculative.facts()
     if ckpt_info is not None:
@@ -1053,12 +1038,11 @@ def main(argv=None):
                   f"mean accepted {sp['mean_accepted']:.3f} "
                   f"tokens/round over {sp['row_rounds']} row-round(s), "
                   f"draft efficiency {sp['draft_efficiency']:.3f}")
-        if sched.paging is not None:
-            pg = result["paging"]
-            print(f"paged KV: {pg['pages_resident']}/{pg['n_pages']} "
-                  f"pages resident, prefix hits {pg['prefix_hits']}/"
-                  f"misses {pg['prefix_misses']}, host-parked "
-                  f"{pg['sessions_parked_host']} session(s)")
+        pg = result["paging"]
+        print(f"paged KV: {pg['pages_resident']}/{pg['n_pages']} "
+              f"pages resident, prefix hits {pg['prefix_hits']}/"
+              f"misses {pg['prefix_misses']}, host-parked "
+              f"{pg['sessions_parked_host']} session(s)")
         if not ok:
             if len(completions) != len(requests):
                 why = "unfinished requests"

@@ -31,15 +31,13 @@ A speculative round at row position ``p`` with pending token ``t0``:
    ``m`` accepted drafts emit ``m+1`` tokens (``d1..dm`` plus the
    correction/bonus) — every round makes progress.
 
-**Rollback never reaches a jit boundary.** Rejected-tail KV (ring
-slots / paged page slots past ``p+m``) is simply left stale: the next
-round REWRITES every slot it will read before reading it (draft and
-verify both write their chunk's KV ahead of attention, and the hoisted
-position mask hides everything past the query position), and a paged
-row's writes past its allocated pages land on the trash page (page 0).
-Host-side rollback is a position-pointer decrement (ring) or an
-occupancy decrement with pages left allocated (paged) — pure
-bookkeeping.
+**Rollback never reaches a jit boundary.** Rejected-tail KV (page
+slots past ``p+m``) is simply left stale: the next round REWRITES
+every slot it will read before reading it (draft and verify both write
+their chunk's KV ahead of attention, and the hoisted position mask
+hides everything past the query position), and a row's writes past its
+allocated pages land on the trash page (page 0). Host-side rollback is
+an occupancy decrement with pages left allocated — pure bookkeeping.
 
 The compile contract grows from a pinned 2 to a pinned **3** programs
 — prefill, draft-step, verify-accept — held warmup-to-drain; the plain
@@ -187,21 +185,13 @@ class SpeculativeDecoder:
         self.accepted_total = 0         # accepted DRAFT tokens
         self.emitted_total = 0          # tokens emitted (drafts + corrections)
         self.drafted_total = 0          # draft tokens proposed
-        if engine.kv_layout == "paged":
-            self._draft = donated_jit(self._draft_fn_paged,
-                                      donate_argnums=(1,))
-            self._verify = donated_jit(self._verify_fn_paged,
-                                       donate_argnums=(1,))
-        else:
-            self._draft = donated_jit(self._draft_fn,
-                                      donate_argnums=(1,))
-            self._verify = donated_jit(self._verify_fn,
-                                       donate_argnums=(1,))
+        self._draft = donated_jit(self._draft_fn, donate_argnums=(1,))
+        self._verify = donated_jit(self._verify_fn, donate_argnums=(1,))
 
     # -- compiled programs --------------------------------------------------
 
     def _truncated_apply(self, params, cache, tokens, positions,
-                         page_table=None):
+                         page_table):
         """The early-exit forward: first ``draft_layers`` blocks + ln_f
         + tied head. Under ``scan_layers`` the stacked params and cache
         leaves are sliced to ``[:d]`` (nn.scan splits params along axis
@@ -230,12 +220,12 @@ class SpeculativeDecoder:
             cache = {**cache, **new_kv}
         return logits, cache
 
-    def _draft_step(self, params, cache, tokens, positions, key,
-                    page_table=None):
+    def _draft_fn(self, params, cache, tokens, positions, page_tables,
+                  key):
         eng = self.engine
         logits, cache = self._truncated_apply(
             params, cache, tokens[:, None], positions[:, None],
-            page_table=page_table)
+            page_tables)
         logits = logits[:, 0]
         from deepspeed_tpu.inference.sampling import (
             filtered_logits,
@@ -253,16 +243,8 @@ class SpeculativeDecoder:
                             eng.top_p), axis=-1)
         return nxt, q, key, eng._pin_cache(cache)
 
-    def _draft_fn(self, params, cache, tokens, positions, key):
-        return self._draft_step(params, cache, tokens, positions, key)
-
-    def _draft_fn_paged(self, params, cache, tokens, positions,
-                        page_tables, key):
-        return self._draft_step(params, cache, tokens, positions, key,
-                                page_table=page_tables)
-
-    def _verify_step(self, params, cache, tokens, positions, draft_len,
-                     q_dists, key, page_tables=None):
+    def _verify_fn(self, params, cache, tokens, positions, page_tables,
+                   draft_len, q_dists, key):
         eng = self.engine
         mesh = eng.mesh if eng._cache_shardings is not None else None
         # always dense: the flash-decode kernel is single-query; the
@@ -284,17 +266,6 @@ class SpeculativeDecoder:
             acc, out, key = rejection_accept(probs, tokens, draft_len,
                                              q_dists, key)
         return acc, out, key, eng._pin_cache(cache)
-
-    def _verify_fn(self, params, cache, tokens, positions, draft_len,
-                   q_dists, key):
-        return self._verify_step(params, cache, tokens, positions,
-                                 draft_len, q_dists, key)
-
-    def _verify_fn_paged(self, params, cache, tokens, positions,
-                         page_tables, draft_len, q_dists, key):
-        return self._verify_step(params, cache, tokens, positions,
-                                 draft_len, q_dists, key,
-                                 page_tables=page_tables)
 
     # -- host API -----------------------------------------------------------
 
@@ -324,7 +295,7 @@ class SpeculativeDecoder:
             else:
                 self._j = max(1, self._j - 1)
 
-    def draft(self, tokens, positions, page_tables=None):
+    def draft(self, tokens, positions, page_tables):
         """One compiled draft step: ``[max_batch]`` tokens/positions in,
         ``(next_tokens, q_dist_or_None)`` out (numpy). ``q`` is the
         filtered draft distribution each token was sampled from
@@ -332,10 +303,9 @@ class SpeculativeDecoder:
         eng = self.engine
         t = jnp.asarray(np.asarray(tokens, np.int32))
         p = jnp.asarray(np.asarray(positions, np.int32))
-        args = [eng.params, eng.cache, t, p]
-        if eng.kv_layout == "paged":
-            args.append(jnp.asarray(np.asarray(page_tables, np.int32)))
-        args.append(eng._sample_key)
+        args = [eng.params, eng.cache, t, p,
+                jnp.asarray(np.asarray(page_tables, np.int32)),
+                eng._sample_key]
         if eng.temperature == 0.0:
             nxt, eng._sample_key, eng.cache = self._draft(*args)
             return np.asarray(nxt), None
@@ -349,8 +319,8 @@ class SpeculativeDecoder:
             return jnp.zeros((1,), jnp.float32)
         return jnp.asarray(np.asarray(q_dists, np.float32))
 
-    def verify(self, tokens, positions, draft_len, q_dists=None,
-               page_tables=None):
+    def verify(self, tokens, positions, draft_len, page_tables,
+               q_dists=None):
         """The one full-depth verify-accept call. ``tokens`` ``[B,
         k+1]`` = ``[pending, d1..dj, pad]``; ``positions`` ``[B, k+1]``
         their absolute slots; ``draft_len`` ``[B]`` real drafts per row
@@ -361,11 +331,10 @@ class SpeculativeDecoder:
         t = jnp.asarray(np.asarray(tokens, np.int32))
         p = jnp.asarray(np.asarray(positions, np.int32))
         dl = jnp.asarray(np.asarray(draft_len, np.int32))
-        args = [eng.params, eng.cache, t, p]
-        if eng.kv_layout == "paged":
-            args.append(jnp.asarray(np.asarray(page_tables, np.int32)))
-        args += [dl, self._q_arg(q_dists), eng._sample_key]
-        acc, out, eng._sample_key, eng.cache = self._verify(*args)
+        acc, out, eng._sample_key, eng.cache = self._verify(
+            eng.params, eng.cache, t, p,
+            jnp.asarray(np.asarray(page_tables, np.int32)), dl,
+            self._q_arg(q_dists), eng._sample_key)
         return np.asarray(acc), np.asarray(out)
 
     # -- audit surface ------------------------------------------------------
@@ -374,29 +343,23 @@ class SpeculativeDecoder:
         """The exact avals :meth:`draft` calls with — lowering through
         these is a jit-cache hit, never a fresh compile."""
         eng = self.engine
-        args = [eng.params, eng.cache,
+        return (eng.params, eng.cache,
                 jnp.zeros((eng.max_batch,), jnp.int32),
-                jnp.zeros((eng.max_batch,), jnp.int32)]
-        if eng.kv_layout == "paged":
-            args.append(jnp.zeros((eng.max_batch, eng.pages_per_row),
-                                  jnp.int32))
-        args.append(eng._sample_key)
-        return tuple(args)
+                jnp.zeros((eng.max_batch,), jnp.int32),
+                jnp.zeros((eng.max_batch, eng.pages_per_row), jnp.int32),
+                eng._sample_key)
 
     def verify_lowering_args(self):
         eng = self.engine
-        args = [eng.params, eng.cache,
-                jnp.zeros((eng.max_batch, self.k + 1), jnp.int32),
-                jnp.zeros((eng.max_batch, self.k + 1), jnp.int32)]
-        if eng.kv_layout == "paged":
-            args.append(jnp.zeros((eng.max_batch, eng.pages_per_row),
-                                  jnp.int32))
         q = jnp.zeros((1,), jnp.float32) if eng.temperature == 0.0 \
             else jnp.zeros((eng.max_batch, self.k,
                             eng.model.config.vocab_size), jnp.float32)
-        args += [jnp.zeros((eng.max_batch,), jnp.int32), q,
-                 eng._sample_key]
-        return tuple(args)
+        return (eng.params, eng.cache,
+                jnp.zeros((eng.max_batch, self.k + 1), jnp.int32),
+                jnp.zeros((eng.max_batch, self.k + 1), jnp.int32),
+                jnp.zeros((eng.max_batch, eng.pages_per_row), jnp.int32),
+                jnp.zeros((eng.max_batch,), jnp.int32), q,
+                eng._sample_key)
 
     def draft_hlo(self):
         return self._draft.lower(

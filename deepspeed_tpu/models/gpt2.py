@@ -111,13 +111,13 @@ def gpt2_tiny(**kw):
 class CausalSelfAttention(nn.Module):
     """Causal attention; also the incremental-decode write/attend site.
 
-    ``kv_cache`` (a layer's ``{"k", "v"(, scales)}`` buffers from
-    `inference/cache.py`) switches to the cached path: this call's k/v
-    are written at explicit ``positions`` and attention runs over the
-    whole cache row under a position mask — the call then returns
-    ``(y, updated_cache)``. With ``kv_cache=None`` the training path is
-    untouched (same modules, same trace), so train and serve share
-    every parameter."""
+    ``kv_cache`` (a layer's ``{"k", "v"(, scales)}`` pool leaves from
+    `inference/cache.py`, addressed through ``kv_page_table``) switches
+    to the cached path: this call's k/v are written at explicit
+    ``positions`` and attention runs over the whole cache row under a
+    position mask — the call then returns ``(y, updated_cache)``. With
+    ``kv_cache=None`` the training path is untouched (same modules, same
+    trace), so train and serve share every parameter."""
     config: GPT2Config
 
     @nn.compact
@@ -289,6 +289,9 @@ class GPT2LMHead(nn.Module):
         if truncate_layers is not None and kv_cache is None:
             raise ValueError("truncate_layers is a decode-path knob "
                              "(requires kv_cache)")
+        if kv_cache is not None and kv_page_table is None:
+            raise ValueError("kv_cache is a page pool: it is addressed "
+                             "through kv_page_table")
         wte = self.param("wte", nn.initializers.normal(0.02),
                          (cfg.vocab_size, cfg.n_embd), cfg.param_dtype)
         wpe = self.param("wpe", nn.initializers.normal(0.01),
@@ -324,13 +327,11 @@ class GPT2LMHead(nn.Module):
             # once here and broadcast to every layer, instead of each
             # layer rebuilding the same [B, T, max_seq] iota-compare
             # inside the compiled decode program (the flash path masks
-            # in-kernel from the positions scalar and needs none; the
-            # paged pool takes S off the page table — the pool buffer
-            # no longer carries the sequence length).
+            # in-kernel from the positions scalar and needs none; S
+            # comes off the page table, not off the pool's shape).
             from deepspeed_tpu.inference.cache import attention_mask
             layer0 = kv_cache["h" if cfg.scan_layers else "h_0"]
-            attn_mask = attention_mask(layer0, positions,
-                                       page_table=kv_page_table)
+            attn_mask = attention_mask(layer0, positions, kv_page_table)
         if cfg.scan_layers and kv_cache is not None:
             # decode over the scanned stack: the per-layer cache slices
             # ride the same lax.scan as the stacked params (in_axes=0

@@ -1,7 +1,7 @@
 """Public surface of the Pallas TPU kernels.
 
 Call sites import the kernel entry points from here
-(``from deepspeed_tpu.ops.pallas import flash_decode``) instead of
+(``from deepspeed_tpu.ops.pallas import flash_decode_paged``) instead of
 deep-importing the defining modules — the module layout below this
 package is an implementation detail (the flash-attention forward and
 both backward kernels live in one file today; the static analyzer
@@ -20,7 +20,6 @@ from deepspeed_tpu.ops.pallas.flash_attention import (
 from deepspeed_tpu.ops.pallas.flash_decode import (
     DEFAULT_BLOCK_K,
     KernelGeometryError,
-    flash_decode,
     flash_decode_paged,
 )
 from deepspeed_tpu.ops.pallas.fused_adam import pallas_adam_update
@@ -31,7 +30,6 @@ __all__ = [
     "KernelGeometryError",
     "dense_attention",
     "flash_attention",
-    "flash_decode",
     "flash_decode_paged",
     "pallas_adam_update",
 ]

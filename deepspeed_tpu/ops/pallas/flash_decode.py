@@ -1,16 +1,12 @@
-"""Flash-decode: split-K attention over the serving KV cache.
+"""Flash-decode: split-K attention over the serving KV pool.
 
 The decode step of the serving engine (`inference/engine.py`) attends
 one query token per row over that row's cached keys and values. The
-dense path dequantizes the whole cache to compute dtype and runs a
-``[1, max_seq]`` softmax per head: O(max_seq) HBM traffic per token no
-matter how short the active requests are. Two kernels replace it, one
-per cache layout. They share the mathematics (below) and nothing of
-their grids, because the two layouts put the heads in different
-places: the ring's ``[B, S, H, D]`` has them between positions and
-``D``, the pool's ``[n_pages, H, D, page]`` outside both.
-
-What both do:
+dense path dequantizes the row's gathered pages to compute dtype and
+runs a ``[1, max_seq]`` softmax per head: O(max_seq) HBM traffic per
+token no matter how short the active requests are. One kernel
+(:func:`flash_decode_paged`) replaces it, cut to the pool's layout
+``[n_pages, H, D, page]`` (`inference/cache.py`):
 
 - **split-K online softmax**: a row's cache streams through VMEM in
   ``block_k``-sized KV blocks; running max, sum and output merge across
@@ -19,7 +15,7 @@ What both do:
   float32 whatever the storage dtype.
 - **the mask contract**: cache index ``s`` is admitted for row ``b``
   iff ``s <= positions[b]``, the dense oracle's rule. Stale tenants of
-  a recycled row or page past that position are invisible.
+  a recycled page past that position are invisible.
 - **fused KV dequantization**: int8/f8e4m3fn/f8e5m2 blocks
   (`inference/cache.py` codec storage) enter the kernel in their
   storage dtype with their per-position scales as lane-major rows
@@ -27,25 +23,12 @@ What both do:
   by the value scales, in registers. The quantized cache never
   materializes an fp32 copy in HBM.
 - **local heads only**: under tensor parallelism the caller wraps the
-  kernel in ``shard_map`` with the cache's head axis sharded
+  kernel in ``shard_map`` with the pool's head axis sharded
   (`cache.kv_partition_specs`); ``H`` below is whatever the kernel is
   handed, and no arithmetic sees the global head count.
 
-**The ring kernel** (:func:`flash_decode`), grid ``(B * H, S /
-block_k)``: heads fold into the grid's leading dim (``[B, S, H, D] →
-[B*H, S, D]``, the `flash_attention.py` layout), one ``(1, block_k,
-D)`` block a step by ``BlockSpec``. Each row's occupancy is its
-``positions[b]`` scalar, prefetched into SMEM; blocks past it are
-predicated off with ``pl.when`` AND their index map clamps to the last
-active block, so Pallas, which skips the DMA when consecutive grid
-steps ask for the same block, reads only the occupied cache. The grid
-step itself is still launched. The online-softmax state lives in ``(1,
-D)`` / ``(1, 1)`` VMEM scratch, read and written whole (Mosaic refuses
-scalar stores to VMEM).
-
-**The paged kernel** (:func:`flash_decode_paged`), grid ``(B,)``: one
-grid step per row walks that row's live span and nothing else. The
-pool stays where it is (``ANY`` memory: no ``BlockSpec``, no pipelined
+**The grid is ``(B,)``**: one grid step per row walks that row's live
+span and nothing else. The pool stays where it is (``ANY`` memory: no ``BlockSpec``, no pipelined
 copy); inside the step a loop over the row's ``positions[b] // block_k
 + 1`` live blocks fetches each ``(H, D, block_k)`` block — all heads
 of the row at once, cut straight from the 4-D pool, contiguous in HBM
@@ -61,11 +44,11 @@ cell's shape before PR 27, `PERF.md` section 6) — and
 :func:`paged_grid_blocks` is that visit set as arithmetic, for the
 engine's counters and the static analyzer. The scores are one dot
 batched over the heads, each a ``[1, D] x [D, block_k]`` product at
-the MXU's default precision, as before.
+the MXU's default precision.
 
-Both kernels compile for the chip (`tests/unit/test_tpu_compile.py`
-pins that against a described v5e, the paged one's grid included).
-Off-TPU they run in Pallas interpret mode (CPU test meshes); the dense
+The kernel compiles for the chip (`tests/unit/test_tpu_compile.py`
+pins that against a described v5e, its grid included). Off-TPU it
+runs in Pallas interpret mode (CPU test meshes); the dense
 cached-attention path stays available as the parity oracle behind
 ``inference.attention.impl``.
 """
@@ -79,14 +62,10 @@ from jax.experimental.pallas import tpu as pltpu
 from deepspeed_tpu.ops.pallas.flash_attention import DEFAULT_MASK_VALUE
 
 DEFAULT_BLOCK_K = 128
-# the kernels' names in the HLO and in a device trace (see
+# the kernel's name in the HLO and in a device trace (see
 # flash_attention.py)
-DECODE_NAME, DECODE_PAGED_NAME = "ds_flash_decode", "ds_flash_decode_paged"
+DECODE_PAGED_NAME = "ds_flash_decode_paged"
 
-# TPU native sublane tile per element width (lane dim is always 128):
-# a compiled block whose second-minor dim doesn't tile to this pads to
-# full register tiles on every touch.
-_SUBLANE_TILES = {4: 8, 2: 16, 1: 32}
 _LANES = 128
 
 
@@ -102,226 +81,46 @@ class KernelGeometryError(ValueError):
     """
 
 
-def _validate_block_k(block_k, extent, extent_name, kv_dtype, interpret,
-                      lanes=False):
-    """Clamp and validate ``block_k`` against the KV extent it tiles.
-
-    ``extent`` is ``max_seq`` for the ring layout and ``page_size``
-    for the paged one (a KV block never straddles a page). The
-    sublane-tile check only gates the COMPILED path (``interpret``
-    False, i.e. a real TPU lowering where Mosaic's tiling constraints
-    bite on sub-tile quantized blocks); interpret-mode CPU runs accept
-    any divisor so CI toys stay small. ``lanes`` adds the lane rule:
-    some block has ``block_k`` as its minor dim — the scale rows of a
-    codec cache, and every block of the paged pool.
+def _validate_block_k(block_k, page_size, interpret):
+    """Clamp and validate ``block_k`` against the page it tiles (a KV
+    block never straddles a page). The lane rule only gates the
+    COMPILED path (``interpret`` False, i.e. a real TPU lowering where
+    Mosaic's tiling constraints bite): positions are the minor dim of
+    every pool block and scale row, so a block is whole lanes or the
+    whole page; interpret-mode CPU runs accept any divisor so CI toys
+    stay small.
     """
     block_k = int(block_k)
     if block_k < 1:
         raise KernelGeometryError(
             f"attention block_k must be >= 1, got {block_k}")
-    block_k = min(block_k, int(extent))
-    if extent % block_k:
+    block_k = min(block_k, int(page_size))
+    if page_size % block_k:
         raise KernelGeometryError(
-            f"{extent_name} {extent} must be a multiple of attention "
+            f"page_size {page_size} must be a multiple of attention "
             f"block_k {block_k}")
-    tile = _SUBLANE_TILES.get(jnp.dtype(kv_dtype).itemsize, 8)
-    if not interpret and block_k % tile and block_k != extent:
+    if not interpret and block_k % _LANES and block_k != page_size:
         raise KernelGeometryError(
-            f"attention block_k {block_k} is not a multiple of the "
-            f"{jnp.dtype(kv_dtype).name} sublane tile {tile} — the "
-            f"compiled kernel would pad every KV block to full "
-            f"register tiles; pick a multiple of {tile} (or cover the "
-            f"whole {extent_name})")
-    if not interpret and lanes and block_k % _LANES and block_k != extent:
-        raise KernelGeometryError(
-            f"attention block_k {block_k} over a quantized cache or a "
-            f"paged pool must be a multiple of {_LANES} (or cover the "
-            f"whole {extent_name} {extent}): scales and pool pages "
-            f"stream lane-major, block_k positions to a row")
+            f"attention block_k {block_k} over a paged pool must be a "
+            f"multiple of {_LANES} (or cover the whole page_size "
+            f"{page_size}): scales and pool pages stream lane-major, "
+            f"block_k positions to a row")
     return block_k
 
 
-def check_decode_geometry(block_k, extent, extent_name, kv_dtype, lanes,
-                          paged_heads=None):
+def check_decode_geometry(block_k, page_size, kv_dtype, heads, head_dim,
+                          quant):
     """The call-time block validation, for the device this process
     compiles for — so the serving engine refuses, typed, a geometry the
     chip's compiler would refuse when it is BUILT, not at the first
     decode step (and never by serving through another path). Returns
-    the clamped ``block_k``. ``paged_heads = (heads, head_dim, quant)``
-    of the paged pool one device holds also checks that the paged
-    kernel's all-head blocks fit VMEM."""
+    the clamped ``block_k``. ``heads`` x ``head_dim`` is what one
+    device holds of the pool: the kernel's all-head blocks must fit
+    VMEM."""
     interpret = jax.devices()[0].platform != "tpu"
-    block_k = _validate_block_k(block_k, extent, extent_name, kv_dtype,
-                                interpret, lanes)
-    if paged_heads is not None:
-        heads, head_dim, quant = paged_heads
-        _check_paged_vmem(heads, head_dim, block_k, kv_dtype, quant)
+    block_k = _validate_block_k(block_k, page_size, interpret)
+    _check_paged_vmem(heads, head_dim, block_k, kv_dtype, quant)
     return block_k
-
-
-def _fold_heads(x):
-    """[B, S, H, D] → [B*H, S, D] (heads into the grid's leading dim)."""
-    B, S, H, D = x.shape
-    return x.transpose(0, 2, 1, 3).reshape(B * H, S, D)
-
-
-def _flash_decode_kernel(H, D, block_k, n_kb, quant):
-    """The ring kernel's body: one (row*head, kv-block) grid step.
-
-    Scalar-prefetch arg 0 is the ``[B]`` positions vector (SMEM);
-    scratch carries the online-softmax state (acc [1, D], running max
-    and sum [1, 1]) across the sequential kv-block dim — all three are
-    read and written whole, as vectors. A KV block is ``(1, block_k,
-    D)``, scales a ``(1, 1, block_k)`` row.
-    """
-
-    def kernel(pos_ref, q_ref, k_ref, v_ref, *refs):
-        refs = list(refs)
-        ks_ref = refs.pop(0) if quant else None
-        vs_ref = refs.pop(0) if quant else None
-        o_ref, acc_ref, m_ref, l_ref = refs
-        bh = pl.program_id(0)
-        ki = pl.program_id(1)
-        p = pos_ref[bh // H]
-
-        @pl.when(ki == 0)
-        def _init():
-            acc_ref[:] = jnp.zeros_like(acc_ref)
-            m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
-            l_ref[:] = jnp.zeros_like(l_ref)
-
-        # Block-level active-length predicate: a block whose first
-        # position is past the row's occupancy contributes nothing —
-        # skip the whole grid step (its DMA was already elided by the
-        # clamped index map).
-        run = (ki * block_k) <= p
-
-        @pl.when(run)
-        def _compute():
-            qb = q_ref[0].astype(jnp.float32)              # [1, D]
-            kb = k_ref[0].astype(jnp.float32)              # [bk, D]
-            s = jax.lax.dot_general(
-                qb, kb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)        # [1, bk]
-            if quant:
-                # fused dequant: scale the SCORES by the key scales
-                # (dot distributes over the per-position scalar) —
-                # the kb block itself stays in storage dtype. The scale
-                # row is [1, bk] f32, lane-major like the scores.
-                s = s * ks_ref[0]
-            s = s * (D ** -0.5)
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (1, block_k), 1)
-            s = jnp.where(k_pos <= p, s, DEFAULT_MASK_VALUE)
-            m_prev = m_ref[:]                              # [1, 1]
-            m_new = jnp.maximum(m_prev,
-                                s.max(axis=-1, keepdims=True))
-            pr = jnp.exp(s - m_new)
-            corr = jnp.exp(m_prev - m_new)
-            l_ref[:] = l_ref[:] * corr + pr.sum(axis=-1, keepdims=True)
-            m_ref[:] = m_new
-            if quant:
-                # value scales fold into the probs the same way
-                pr = pr * vs_ref[0]
-            vb = v_ref[0].astype(jnp.float32)              # [bk, D]
-            acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-                pr, vb, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-
-        @pl.when(ki == n_kb - 1)
-        def _finish():
-            o_ref[0] = (acc_ref[:] /
-                        jnp.maximum(l_ref[:], 1e-30)).astype(o_ref.dtype)
-
-    return kernel
-
-
-def _softmax_scratch(D):
-    return [pltpu.VMEM((1, D), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32)]
-
-
-def flash_decode(q, k, v, positions, k_scale=None, v_scale=None,
-                 block_k=DEFAULT_BLOCK_K, interpret=None):
-    """Split-K flash decode over one layer's cache buffers.
-
-    ``q``: ``[B, 1, H, D]`` compute-dtype query (the decode step's
-    single token per row). ``k``/``v``: ``[B, S, H, D]`` cache buffers
-    in STORAGE dtype — compute dtype, or a codec dtype
-    (int8/f8e4m3fn/f8e5m2) with ``k_scale``/``v_scale`` ``[B, S, H]``
-    f32 absmax scales (`inference/cache.py` layout). ``positions``:
-    ``[B]`` int32, each row's current write position (the mask admits
-    cache index ``s`` iff ``s <= positions[b]`` — identical to the
-    dense oracle's). Returns ``[B, 1, H, D]`` in ``q.dtype``.
-
-    ``interpret=None`` auto-selects: compiled kernel on TPU, Pallas
-    interpret mode elsewhere. Under tensor parallelism call through
-    ``shard_map`` with the head axis sharded (`cache.kv_partition_
-    specs`); the kernel only ever sees local heads.
-    """
-    B, S, H, D = k.shape
-    if q.shape != (B, 1, H, D):
-        raise ValueError(
-            f"flash_decode takes one query token per row: q shape "
-            f"{q.shape} != {(B, 1, H, D)}")
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
-    if (k_scale is None) != (v_scale is None):
-        raise ValueError("pass both k_scale and v_scale or neither")
-    quant = k_scale is not None
-    block_k = _validate_block_k(block_k, S, "max_seq", k.dtype, interpret,
-                                quant)
-    n_kb = S // block_k
-
-    qh = q.transpose(0, 2, 1, 3).reshape(B * H, 1, D)
-    kh = _fold_heads(k)
-    vh = _fold_heads(v)
-
-    def q_map(bh, ki, pos_ref):
-        return (bh, 0, 0)
-
-    def _active(bh, ki, pos_ref):
-        # Clamp past-occupancy block indices to the row's last active
-        # block: consecutive grid steps then request the SAME block and
-        # Pallas elides the DMA — the skipped blocks cost no HBM reads.
-        return jnp.minimum(ki, pos_ref[bh // H] // block_k)
-
-    def kv_map(bh, ki, pos_ref):
-        return (bh, _active(bh, ki, pos_ref), 0)
-
-    def sc_map(bh, ki, pos_ref):
-        return (bh, 0, _active(bh, ki, pos_ref))
-
-    in_specs = [
-        pl.BlockSpec((1, 1, D), q_map),
-        pl.BlockSpec((1, block_k, D), kv_map),
-        pl.BlockSpec((1, block_k, D), kv_map),
-    ]
-    args = [qh, kh, vh]
-    if quant:
-        in_specs += [pl.BlockSpec((1, 1, block_k), sc_map),
-                     pl.BlockSpec((1, 1, block_k), sc_map)]
-        args += [k_scale.transpose(0, 2, 1).reshape(B * H, 1, S),
-                 v_scale.transpose(0, 2, 1).reshape(B * H, 1, S)]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B * H, n_kb),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, D), q_map),
-        scratch_shapes=_softmax_scratch(D),
-    )
-    call = pl.pallas_call(
-        _flash_decode_kernel(H, D, block_k, n_kb, quant),
-        name=DECODE_NAME,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B * H, 1, D), q.dtype),
-        interpret=interpret,
-    )
-    with jax.named_scope(DECODE_NAME):
-        out = call(jnp.asarray(positions, jnp.int32), *args)
-    return out.reshape(B, H, 1, D).transpose(0, 2, 1, 3)
 
 
 # the trash page: never handed out (`inference/paging.py`), so a row
@@ -446,7 +245,8 @@ def _paged_decode_kernel(H, D, block_k, bpp, quant):
                     preferred_element_type=jnp.float32
                 ).reshape(H, block_k)
                 if quant:
-                    # fused dequant, as in the ring kernel: the scale
+                    # fused dequant: scale the SCORES by the key scales
+                    # (dot distributes over the per-position scalar);
                     # rows are [H, bk] f32, lane-major like the scores
                     s = s * ksbuf[slot]
                 s = s * (D ** -0.5)
@@ -481,13 +281,17 @@ def flash_decode_paged(q, k, v, positions, page_tables, k_scale=None,
                        interpret=None):
     """Split-K flash decode over a paged KV pool.
 
-    ``q``: ``[B, 1, H, D]`` as in :func:`flash_decode`. ``k``/``v``:
-    the POOL buffers ``[n_pages, H, D, page_size]`` in
-    storage dtype (scales ``[n_pages, H, page_size]`` when quantized —
+    ``q``: ``[B, 1, H, D]`` compute-dtype query (the decode step's
+    single token per row). ``k``/``v``: the POOL buffers
+    ``[n_pages, H, D, page_size]`` in storage dtype (scales ``[n_pages, H, page_size]`` when quantized —
     `inference/cache.py` paged layout). ``page_tables``: ``[B,
     pages_per_row]`` int32 physical page ids per row (entry 0 = the
-    trash page for unallocated slots). ``positions``: ``[B]`` int32
-    write positions, same mask contract as the ring kernel.
+    trash page for unallocated slots). ``positions``: ``[B]`` int32,
+    each row's current write position (the mask admits cache index
+    ``s`` iff ``s <= positions[b]`` — identical to the dense oracle's).
+    Returns ``[B, 1, H, D]`` in ``q.dtype``. ``interpret=None``
+    auto-selects: compiled kernel on TPU, Pallas interpret mode
+    elsewhere.
 
     One grid step a row; the row's ``positions[b] // block_k + 1`` live
     blocks are fetched from the pool by manual DMA inside it, all heads
@@ -513,9 +317,7 @@ def flash_decode_paged(q, k, v, positions, page_tables, k_scale=None,
     if (k_scale is None) != (v_scale is None):
         raise ValueError("pass both k_scale and v_scale or neither")
     quant = k_scale is not None
-    # positions are the lane axis of every block, scales or not
-    block_k = _validate_block_k(block_k, page_size, "page_size",
-                                k.dtype, interpret, lanes=True)
+    block_k = _validate_block_k(block_k, page_size, interpret)
     _check_paged_vmem(H, D, block_k, k.dtype, quant)
 
     def row(b, pos_ref, pt_ref):

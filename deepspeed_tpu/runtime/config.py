@@ -638,9 +638,9 @@ class InferenceConfig:
     """Typed view of the ``inference`` block: the jitted autoregressive
     serving engine (`deepspeed_tpu/inference/`; docs/inference.md).
 
-    ``max_batch`` sizes the KV cache's row ring (= the compiled decode
-    batch); ``seq_buckets`` are host-side per-request length budgets
-    (the cache buffer is sized to their max — buckets are NOT compiled
+    ``max_batch`` is the compiled decode batch; ``seq_buckets`` are
+    host-side per-request length budgets (a row's page table spans
+    their max — buckets are NOT compiled
     shapes, so any bucket mix costs exactly one prefill + one decode
     compile); ``prefill_chunk`` fixes the chunked-prefill shape;
     ``kv_cache_dtype`` selects plain (``bf16``/``f32``) or codec
@@ -692,8 +692,8 @@ class InferenceConfig:
                                       INFERENCE_TOP_P_DEFAULT)
         self.sampling_seed = get_scalar_param(
             sub, INFERENCE_SAMPLING_SEED, INFERENCE_SAMPLING_SEED_DEFAULT)
-        self.kv_layout = get_scalar_param(
-            sub, INFERENCE_KV_LAYOUT, INFERENCE_KV_LAYOUT_DEFAULT)
+        # no longer a choice (PR 28): read only to be refused
+        self._kv_layout = sub.get(INFERENCE_KV_LAYOUT)
         self.page_size = get_scalar_param(
             sub, INFERENCE_PAGE_SIZE, INFERENCE_PAGE_SIZE_DEFAULT)
         self.n_pages = get_scalar_param(
@@ -775,7 +775,6 @@ class InferenceConfig:
                 f"temperature={self.temperature}, top_k={self.top_k}, "
                 f"top_p={self.top_p}, "
                 f"sampling_seed={self.sampling_seed}, "
-                f"kv_layout={self.kv_layout!r}, "
                 f"page_size={self.page_size}, n_pages={self.n_pages}, "
                 f"prefix_cache={self.prefix_cache}, "
                 f"host_park_threshold={self.host_park_threshold}, "
@@ -1148,16 +1147,18 @@ class DeepSpeedConfig:
         if isinstance(seed, bool) or not isinstance(seed, int):
             raise ValueError(
                 f"inference: sampling_seed must be an int, got {seed!r}")
-        if inf.kv_layout not in ("ring", "paged"):
+        if inf._kv_layout not in (None, "paged"):
             raise ValueError(
-                f"inference: kv_layout must be 'ring' or 'paged', "
-                f"got {inf.kv_layout!r}")
+                f"inference: kv_layout {inf._kv_layout!r}: the paged "
+                f"pool is the only KV layout since PR 28 (the ring "
+                f"layout and the switch are gone); drop the key or "
+                f"set 'paged'")
         ps = inf.page_size
         if isinstance(ps, bool) or not isinstance(ps, int) or ps < 0:
             raise ValueError(
                 f"inference: page_size must be an int >= 0 (0 = auto), "
                 f"got {ps!r}")
-        if inf.kv_layout == "paged" and ps:
+        if ps:
             if ps % pc:
                 raise ValueError(
                     f"inference: page_size must be a multiple of "
@@ -1227,11 +1228,6 @@ class DeepSpeedConfig:
                     f"inference: {name} must be an int >= 0 "
                     f"(0 = max_batch), got {val!r}")
         if inf.disaggregated:
-            if inf.kv_layout != "paged":
-                raise ValueError(
-                    "inference: disaggregated serving requires "
-                    "kv_layout='paged' — the prefill->decode handoff "
-                    "is a KV page copy")
             if inf.replicas > 1:
                 raise ValueError(
                     "inference: disaggregated and replicas > 1 are "

@@ -497,35 +497,33 @@ INFERENCE_TOP_P_DEFAULT = 1.0
 INFERENCE_SAMPLING_SEED = "sampling_seed"
 INFERENCE_SAMPLING_SEED_DEFAULT = 0
 
-# KV cache layout: "ring" = one [max_batch, max_seq] row per request;
-# "paged" = one [n_pages, page_size] pool per layer addressed through
-# per-row page tables (host-side allocator + radix prefix cache +
-# host-RAM tier for parked sessions, inference/paging.py). Both keep
-# the 2-compile contract; paged decouples capacity from max_batch *
-# max_seq.
+# The KV cache is one [n_pages, page_size] pool per layer addressed
+# through per-row page tables (host-side allocator + radix prefix
+# cache + host-RAM tier for parked sessions, inference/paging.py).
+# The key chose between it and a ring layout until PR 28; it is still
+# accepted, "paged" only.
 INFERENCE_KV_LAYOUT = "kv_layout"
-INFERENCE_KV_LAYOUT_DEFAULT = "ring"
 
-# Tokens per page (paged layout). 0 = auto (two prefill chunks).
+# Tokens per page. 0 = auto (two prefill chunks).
 # Must be a multiple of prefill_chunk and divide max(seq_buckets);
 # flash block_k clamps to it.
 INFERENCE_PAGE_SIZE = "page_size"
 INFERENCE_PAGE_SIZE_DEFAULT = 0
 
-# Physical pages in the pool (paged layout). 0 = auto: ring-capacity
-# parity (max_batch * max_seq / page_size) + the reserved trash page.
+# Physical pages in the pool. 0 = auto: every row at full length
+# (max_batch * max_seq / page_size) + the reserved trash page.
 # Smaller pools trade admission headroom for HBM — the bench A/B and
 # the tuner explore this.
 INFERENCE_N_PAGES = "n_pages"
 INFERENCE_N_PAGES_DEFAULT = 0
 
-# Radix-tree prefix cache (paged layout): admissions whose prompt
+# Radix-tree prefix cache: admissions whose prompt
 # prefix matches interned pages map them copy-on-write and skip the
 # shared span's prefill chunks.
 INFERENCE_PREFIX_CACHE = "prefix_cache"
 INFERENCE_PREFIX_CACHE_DEFAULT = True
 
-# Host-RAM tier pressure threshold (paged layout): while free pages /
+# Host-RAM tier pressure threshold: while free pages /
 # n_pages sits below this fraction, parked sessions' pages are
 # evacuated to host RAM (LRU first). 0.0 disables proactive
 # evacuation (pressure-driven eviction still runs on exhaustion).
@@ -563,7 +561,7 @@ INFERENCE_QUEUE_TIMEOUT_S_DEFAULT = 0.0
 # between them through an explicit KV-page handoff. Each tier pins
 # exactly one compiled program; tiers scale independently
 # (prefill_workers x decode_workers, each with its own max_batch —
-# 0 falls back to the shared max_batch). Requires kv_layout="paged".
+# 0 falls back to the shared max_batch).
 INFERENCE_DISAGGREGATED = "disaggregated"
 INFERENCE_DISAGGREGATED_DEFAULT = False
 INFERENCE_PREFILL_WORKERS = "prefill_workers"

@@ -40,7 +40,7 @@ DECODE_PIN = {"prefill": 0, "decode": 1}
 # byte-identical engines for the token-identity check to mean anything.
 # seq_buckets as a list: the spec travels through JSON.
 INF_CFG = {"max_batch": 2, "seq_buckets": [16, 32], "prefill_chunk": 4,
-           "kv_layout": "paged", "temperature": 0.0}
+           "temperature": 0.0}
 
 
 def _requests(n=4, max_new=8):

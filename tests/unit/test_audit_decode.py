@@ -139,20 +139,17 @@ class TestRuleFlashDecode:
                           decode_cache_payload_shape=_PAYLOAD)
         assert rule_flash_decode(ctx) == []
 
-    @pytest.mark.parametrize("layout,hlo,n", [
+    @pytest.mark.parametrize("hlo,n", [
         # XLA's relayout of the pool, layout and tiling after the shape
-        ("paged", "%copy.7 = f32[2,32,4,8]{3,1,2,0:T(8,128)} copy(%p)\n"
-                  "%copy.8 = f32[2,32,4,8]{2,3,1,0:T(8,128)} copy(%copy.7)",
-         2),
-        # other shapes, other ops of that shape, and the ring are not it
-        ("paged", "%copy.1 = f32[2,32,4]{2,1,0} copy(%s)\n"
-                  "%copy.2 = f32[8,32,8]{2,1,0} copy(%m)\n"
-                  "%fusion.3 = f32[2,32,4,8]{3,2,1,0} fusion(%p)", 0),
-        ("ring", "%copy.7 = f32[2,32,4,8]{3,1,2,0} copy(%p)", 0),
-    ], ids=["relayout", "not-the-pool", "ring"])
-    def test_pool_shaped_copy_is_error_when_paged(self, layout, hlo, n):
+        ("%copy.7 = f32[2,32,4,8]{3,1,2,0:T(8,128)} copy(%p)\n"
+         "%copy.8 = f32[2,32,4,8]{2,3,1,0:T(8,128)} copy(%copy.7)", 2),
+        # other shapes and other ops of that shape are not it
+        ("%copy.1 = f32[2,32,4]{2,1,0} copy(%s)\n"
+         "%copy.2 = f32[8,32,8]{2,1,0} copy(%m)\n"
+         "%fusion.3 = f32[2,32,4,8]{3,2,1,0} fusion(%p)", 0),
+    ], ids=["relayout", "not-the-pool"])
+    def test_pool_shaped_copy_is_error(self, hlo, n):
         ctx = StepContext(hlo_text=hlo, decode_attention_impl="flash",
-                          decode_kv_layout=layout,
                           decode_cache_payload_shape=_PAYLOAD)
         fs = rule_flash_decode(ctx)
         assert len(fs) == (1 if n else 0)
@@ -170,7 +167,6 @@ class TestRuleFlashDecode:
         hlo = ("%copy.7 = f32[2,32,4,8]{3,2,1,0} copy(%get-tuple-element.9)\n"
                "%k = f32[2,4,8]{2,1,0} custom-call(%p, %copy.7)")
         ctx = StepContext(hlo_text=hlo, decode_attention_impl="flash",
-                          decode_kv_layout="paged",
                           decode_platform=platform,
                           decode_cache_payload_shape=_PAYLOAD)
         assert len(rule_flash_decode(ctx)) == n
@@ -196,7 +192,8 @@ class TestAuditDecodeEndToEnd:
         assert report.findings == []
         assert report.stats["compile_counts"] == \
             {"prefill": 1, "decode": 1}
-        assert report.stats["completions"] == 5
+        # five requests and the parked session's follow-up
+        assert report.stats["completions"] == 6
         assert set(report.stats["finish_reasons"]) >= \
             {"max_new_tokens", "length"}
         # the stock decode flavor serves flash attention
@@ -206,6 +203,7 @@ class TestAuditDecodeEndToEnd:
         report = audit_decode(kv_cache_dtype="int8")
         assert report.findings == []
         assert report.stats["cache"]["dtype_census"] == {"int8": 4}
+        assert report.stats["paging"]["prefix_hits"] >= 1
 
     @pytest.mark.slow
     def test_dense_fallback_still_audits_clean(self):
@@ -233,7 +231,7 @@ class TestAuditDecodeEndToEnd:
 
 
 class TestAuditDecodePaged:
-    """The paged-layout acceptance pin: audit_decode's paged stream
+    """The pool's acceptance pin: audit_decode's stream
     exercises the whole admission ladder (radix hits, a parked session
     evacuated to host RAM, a resume that pages it back in) and the
     full rule catalog must still come back empty on the post-churn
@@ -241,11 +239,11 @@ class TestAuditDecodePaged:
     compiled programs never change."""
 
     def test_zero_findings_paged_with_churn(self):
-        report = audit_decode(kv_layout="paged")
+        report = audit_decode()
         assert report.findings == []
         assert report.stats["compile_counts"] == \
             {"prefill": 1, "decode": 1}
-        assert report.stats["cache"]["kv_layout"] == "paged"
+        assert report.stats["cache"]["page_size"] == 8
         pg = report.stats["paging"]
         assert pg["prefix_hits"] >= 1            # shared-prefix stream
         assert pg["sessions_resumed"] >= 1       # parked -> followed up
@@ -253,10 +251,3 @@ class TestAuditDecodePaged:
         assert pg["pages_paged_in"] >= 1
         assert pg["pages_free"] + pg["pages_resident"] == \
             pg["n_pages"] - 1                    # trash page accounting
-
-    @pytest.mark.slow
-    def test_zero_findings_paged_quantized(self):
-        report = audit_decode(kv_cache_dtype="int8", kv_layout="paged")
-        assert report.findings == []
-        assert report.stats["cache"]["dtype_census"] == {"int8": 4}
-        assert report.stats["paging"]["prefix_hits"] >= 1

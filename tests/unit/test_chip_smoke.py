@@ -55,7 +55,9 @@ def test_chip_smoke_never_passes_without_the_chip(case, tmp_path):
         # not a pass
         assert r.returncode == 3, r.stderr[-2000:]
         phases = [l.get("smoke") for l in lines]
-        assert phases.count("train") == 1 and phases.count("serve") == 3
+        # serving twice: the defaults (dense), then flash, both over
+        # the paged pool (the only KV layout since PR 28)
+        assert phases.count("train") == 1 and phases.count("serve") == 2
         assert phases[-2] == "done"
         assert lines[-1]["ok"] is False and lines[-1]["rehearsal"]
         serves = [l for l in lines if l.get("smoke") == "serve"]

@@ -329,6 +329,33 @@ def test_inference_config_validation():
     bad({"max_batc": 4}, "unknown key")
 
 
+@pytest.mark.parametrize("value", ["ring", "", None, 0, "PAGED"])
+def test_inference_kv_layout_is_refused_unless_paged(value):
+    """The key chose a layout until PR 28; it is still read, so that a
+    config that asks for the layout that went fails, typed, and does not
+    silently serve through the other. `None` is JSON's null: absent."""
+    block = {"max_batch": 4, "kv_layout": value}
+    if value is None:
+        assert make_config({"train_batch_size": 16,
+                            "inference": block}).inference.max_batch == 4
+        return
+    with pytest.raises(ValueError, match="only KV layout since PR 28"):
+        make_config({"train_batch_size": 16, "inference": block})
+
+
+def test_inference_kv_layout_paged_is_accepted_and_changes_nothing():
+    plain = make_config({"train_batch_size": 16,
+                         "inference": {"page_size": 64}}).inference
+    given = make_config({"train_batch_size": 16, "inference": {
+        "page_size": 64, "kv_layout": "paged"}}).inference
+    assert repr(given) == repr(plain) and "kv_layout" not in repr(given)
+    assert not hasattr(given, "kv_layout")
+    # the page keys are checked whatever the key says
+    with pytest.raises(ValueError, match="page_size"):
+        make_config({"train_batch_size": 16,
+                     "inference": {"page_size": 48}})
+
+
 def test_inference_fleet_config_defaults_and_block():
     cfg = make_config({"train_batch_size": 16})
     inf = cfg.inference

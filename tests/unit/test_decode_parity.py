@@ -8,28 +8,20 @@ steps, and compare the logits position by position. Teacher forcing
 well-defined for quantized caches, where storage error can flip an
 argmax without any logit being wrong by more than the codec's bound.
 
-Matrix: {dense, flash} x {unrolled, scan_layers} x {fp32 cache,
-int8/f8 quantized}. The flash rows run the Pallas split-K kernel
-(`ops/pallas/flash_decode.py`, interpret mode on CPU) against the same
-full-forward reference as dense — the kernel's online softmax and
-in-kernel dequant must land inside the SAME tolerances as the dense
-oracle. fp32 rows pin to 2e-6 — the residue is XLA reduction-order
-noise from attending over the padded [max_seq] buffer instead of the
-exact [T] context (the einsum re-associates the same nonzero terms; a
-same-shape call is ulp-close). Quantized rows pin to 0.2 (measured:
-int8 ~2e-3, f8e4m3fn ~1e-2 on this model — an order of margin).
-
-Two rows run concurrently at different lengths/offsets, so the test
-also pins row isolation and positions crossing prefill-chunk and
-bucket boundaries.
-
-The paged flash rows (`test_paged_flash_parity_with_a_dead_row`) run
-the same comparison through the pool and the paged kernel, whose grid
+The matrix {dense, flash} x {unrolled, scan_layers} x {fp32 cache,
+int8/f8 quantized} over two live rows is
+`tests/unit/test_paged_parity.py::test_paged_teacher_forced_parity`.
+Here the flash rows (`test_paged_flash_parity_with_a_dead_row`) run
+the comparison through the pool and the kernel (`ops/pallas/
+flash_decode.py`, interpret mode on CPU), whose grid
 is one step a row: three cache rows of which the middle one never
 holds a request (position 0, an all-trash table), the last row ending
 on the last position of its last page, over every pool dtype; and they
 read the two counters `engine.decode` puts on its span
-(``kv_blocks_live``, ``kv_blocks_launched``).
+(``kv_blocks_live``, ``kv_blocks_launched``). fp32 rows pin to 2e-6 —
+the residue is XLA reduction-order noise from attending over the padded
+[max_seq] buffer instead of the exact [T] context (the einsum
+re-associates the same nonzero terms; a same-shape call is ulp-close).
 
 Sampling sanity (`inference/sampling.py`): the in-program sampler's
 degenerate corners collapse to greedy bit-exactly (temperature 0 by
@@ -46,25 +38,7 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.inference.engine import InferenceEngine
 from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHead
-
-# the fast lane keeps the dense oracle rows plus one flash row; the
-# rest of the flash matrix is slow-marked (interpret-mode Pallas under
-# jit is compile-heavy on CPU) and rides the full unit lane + the CI
-# serve-smoke job, which run without the marker filter.
-_slow = pytest.mark.slow
-CASES = [
-    ("dense-unrolled-f32", "dense", False, None, 2e-6, ()),
-    ("dense-scan-f32", "dense", True, None, 2e-6, ()),
-    ("dense-unrolled-int8", "dense", False, "int8", 0.2, ()),
-    ("dense-scan-f8e4m3fn", "dense", True, "f8e4m3fn", 0.2, ()),
-    ("flash-unrolled-f32", "flash", False, None, 2e-6, ()),
-    ("flash-scan-f32", "flash", True, None, 2e-6, (_slow,)),
-    ("flash-unrolled-int8", "flash", False, "int8", 0.2, (_slow,)),
-    ("flash-scan-int8", "flash", True, "int8", 0.2, (_slow,)),
-    ("flash-unrolled-f8e4m3fn", "flash", False, "f8e4m3fn", 0.2, (_slow,)),
-    ("flash-scan-f8e4m3fn", "flash", True, "f8e4m3fn", 0.2, (_slow,)),
-]
-
+from tests.unit.test_inference_engine import identity_tables
 
 def _build(scan_layers, kv_cache_dtype, impl="dense", **knobs):
     cfg = GPT2Config(vocab_size=64, n_positions=64, n_embd=32,
@@ -78,52 +52,6 @@ def _build(scan_layers, kv_cache_dtype, impl="dense", **knobs):
         "kv_cache_dtype": kv_cache_dtype, "attention_impl": impl,
         "attention_block_k": 8, **knobs})
     return model, params, eng
-
-
-@pytest.mark.parametrize(
-    "name,impl,scan,kvdt,atol",
-    [pytest.param(*c[:5], marks=c[5], id=c[0]) for c in CASES])
-def test_teacher_forced_parity(name, impl, scan, kvdt, atol):
-    model, params, eng = _build(scan, kvdt, impl)
-    rng = np.random.default_rng(0)
-    # row 0 stays inside bucket 16; row 1 crosses into bucket 32
-    seqs = [rng.integers(0, 64, 16).tolist(),
-            rng.integers(0, 64, 24).tolist()]
-    prompt_lens = [10, 14]   # 10 is mid-chunk (chunk=4): padded prefill
-
-    refs = []
-    for seq in seqs:
-        full = model.apply({"params": params},
-                           jnp.asarray([seq], jnp.int32),
-                           deterministic=True)
-        refs.append(np.asarray(full[0], np.float32))
-
-    # prefill both rows, pin the last-prompt-token logits
-    for slot, (seq, n) in enumerate(zip(seqs, prompt_lens)):
-        last = eng.prefill(slot, seq[:n])
-        np.testing.assert_allclose(last, refs[slot][n - 1], atol=atol,
-                                   err_msg=f"{name}: prefill slot {slot}")
-
-    # teacher-forced decode: both rows advance together at different
-    # positions until each row's sequence is exhausted
-    pos = list(prompt_lens)
-    while any(p < len(s) for p, s in zip(pos, seqs)):
-        tokens = np.zeros(2, np.int32)
-        positions = np.zeros(2, np.int32)
-        live = []
-        for r in range(2):
-            if pos[r] < len(seqs[r]):
-                tokens[r] = seqs[r][pos[r]]
-                positions[r] = pos[r]
-                live.append(r)
-        _, logits = eng.decode(tokens, positions)
-        for r in live:
-            np.testing.assert_allclose(
-                logits[r], refs[r][pos[r]], atol=atol,
-                err_msg=f"{name}: decode row {r} pos {pos[r]}")
-            pos[r] += 1
-
-    assert eng.compile_counts() == {"prefill": 1, "decode": 1}
 
 
 PAGED_CASES = [
@@ -141,8 +69,8 @@ PAGED_CASES = [
 def test_paged_flash_parity_with_a_dead_row(kvdt, atol):
     from deepspeed_tpu.telemetry import spans
 
-    model, params, eng = _build(False, kvdt, "flash", kv_layout="paged",
-                                max_batch=3, page_size=8)
+    model, params, eng = _build(False, kvdt, "flash", max_batch=3,
+                                page_size=8)
     ppr = eng.pages_per_row                 # 4 pages of 8 positions
     tables = np.zeros((3, ppr), np.int32)   # row 1: no request
     tables[0] = 1 + np.arange(ppr)
@@ -206,7 +134,9 @@ def test_single_chunk_prefill_is_ulp_close():
     ref = np.asarray(model.apply(
         {"params": params}, jnp.asarray([seq], jnp.int32),
         deterministic=True)[0], np.float32)
-    last = eng.prefill(0, seq)          # one chunk == whole buffer
+    # one chunk == one page == the whole row
+    assert eng.page_size == 16 == eng.max_seq
+    last = eng.prefill(0, seq, identity_tables(eng)[0])
     np.testing.assert_allclose(last, ref[-1], atol=5e-7)
 
 
@@ -216,7 +146,9 @@ def test_single_chunk_prefill_is_ulp_close():
 
 def _generate(eng, prompt, steps):
     """Free-running generation on row 0; returns the token stream."""
-    last = eng.prefill(0, prompt)
+    tables = identity_tables(eng)
+    tables[1] = 0                       # row 1 holds no request
+    last = eng.prefill(0, prompt, tables[0])
     toks = [eng.sample_first(last)]
     pos = len(prompt)
     for _ in range(steps):
@@ -224,7 +156,7 @@ def _generate(eng, prompt, steps):
         p = np.zeros(2, np.int32)
         t[0] = toks[-1]
         p[0] = pos
-        nxt, _ = eng.decode(t, p)
+        nxt, _ = eng.decode(t, p, tables)
         toks.append(int(nxt[0]))
         pos += 1
     return toks
@@ -268,7 +200,9 @@ def test_hot_sampling_draws_within_topk_support():
 
     # reproducibility: same seed, same stream
     def run(eng):
-        last = eng.prefill(0, prompt)
+        tables = identity_tables(eng)
+        tables[1] = 0
+        last = eng.prefill(0, prompt, tables[0])
         toks = [eng.sample_first(last)]
         pos = len(prompt)
         draws = []
@@ -277,7 +211,7 @@ def test_hot_sampling_draws_within_topk_support():
             p = np.zeros(2, np.int32)
             t[0] = toks[-1]
             p[0] = pos
-            nxt, logits = eng.decode(t, p)
+            nxt, logits = eng.decode(t, p, tables)
             draws.append((int(nxt[0]), np.asarray(logits[0])))
             toks.append(int(nxt[0]))
             pos += 1

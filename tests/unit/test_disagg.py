@@ -73,7 +73,7 @@ def _build(kvdt=None, impl="dense", **knobs):
     eng = InferenceEngine(model, params, config={
         "max_batch": 2, "seq_buckets": (16, 32), "prefill_chunk": 4,
         "kv_cache_dtype": kvdt, "attention_impl": impl,
-        "attention_block_k": 8, "kv_layout": "paged", **knobs})
+        "attention_block_k": 8, **knobs})
     return eng
 
 
@@ -220,8 +220,8 @@ def test_tier_engine_pins_other_program_off():
     assert dec.compile_counts() == {"prefill": 0, "decode": 0}
 
 
-def test_tier_requires_paged_layout():
-    with pytest.raises(ValueError, match="paged"):
+def test_a_tier_is_refused_a_ring_layout_like_any_engine():
+    with pytest.raises(ValueError, match="only KV layout since PR 28"):
         _build(tier="prefill", kv_layout="ring")
 
 
@@ -550,9 +550,9 @@ def test_disagg_config_block_and_validation():
             DeepSpeedConfig({"train_batch_size": 16,
                              "inference": block}, world_size=1)
 
-    bad({"disaggregated": True}, "paged")
-    bad({"kv_layout": "paged", "disaggregated": True, "replicas": 2},
-        "replicas")
+    bad({"kv_layout": "ring", "disaggregated": True},
+        "only KV layout since PR 28")
+    bad({"disaggregated": True, "replicas": 2}, "replicas")
     bad({"kv_layout": "paged", "disaggregated": True,
          "speculative": {"enabled": True}}, "speculative")
     bad({"disaggregated": 1}, "bool")

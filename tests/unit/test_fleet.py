@@ -62,9 +62,9 @@ class _SlowEngine(StubEngine):
         super().__init__(**kw)
         self.decode_sleep_s = decode_sleep_s
 
-    def decode(self, tokens, positions):
+    def decode(self, tokens, positions, page_tables):
         time.sleep(self.decode_sleep_s)
-        return super().decode(tokens, positions)
+        return super().decode(tokens, positions, page_tables)
 
 
 class TestSchedulerRobustness:
@@ -336,7 +336,7 @@ class TestThreadReplica:
         def exploding():
             eng = StubEngine()
 
-            def boom(tokens, positions):
+            def boom(tokens, positions, page_tables):
                 raise RuntimeError("injected decode fault")
             eng.decode = boom
             return eng
@@ -371,9 +371,9 @@ class TestThreadReplica:
             eng = StubEngine()
             real = eng.decode
 
-            def stuck(tokens, positions):
+            def stuck(tokens, positions, page_tables):
                 gate.wait(timeout=30.0)
-                return real(tokens, positions)
+                return real(tokens, positions, page_tables)
             eng.decode = stuck
             return eng
 

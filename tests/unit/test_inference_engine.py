@@ -27,20 +27,41 @@ from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHead
 from deepspeed_tpu.telemetry.session import TelemetrySession
 
 
+def identity_tables(eng):
+    """``[max_batch, pages_per_row]`` page tables handing row ``r`` the
+    pages ``1 + r * pages_per_row ...`` in order (page 0 is the trash
+    page): what a test that calls ``prefill`` / ``decode`` itself, with
+    no scheduler and no allocator, passes."""
+    ppr = eng.pages_per_row
+    return 1 + np.arange(eng.max_batch * ppr, dtype=np.int32).reshape(
+        eng.max_batch, ppr)
+
+
 class StubEngine:
     """Scheduler-facing engine surface without jax: prefill returns
     logits argmaxing to token 7; decode echoes position+1 as the next
-    token so generations are deterministic and inspectable."""
+    token so generations are deterministic and inspectable. The pool
+    facts are a default engine's (pages of two prefill chunks, every
+    row at full length plus the trash page); the pool itself is one
+    float a page."""
 
     def __init__(self, max_batch=2, seq_buckets=(16, 32), session=None):
         self.max_batch = max_batch
         self.seq_buckets = tuple(sorted(seq_buckets))
         self.max_seq = max(self.seq_buckets)
         self.session = session
+        self.prefill_chunk = 4
+        self.page_size = 8
+        self.pages_per_row = self.max_seq // self.page_size
+        self.n_pages = max_batch * self.pages_per_row + 1
+        self.prefix_cache = True
+        self.host_park_threshold = 0.0
+        self.cache = {"k": np.zeros((self.n_pages, 1), np.float32)}
         self.prefills = []
         self.decodes = 0
 
-    def prefill(self, slot, prompt):
+    def prefill(self, slot, prompt, page_table, start=0):
+        assert len(page_table) == self.pages_per_row
         self.prefills.append((slot, tuple(prompt)))
         logits = np.zeros(64, np.float32)
         logits[7] = 1.0
@@ -49,7 +70,8 @@ class StubEngine:
     def sample_first(self, last_logits):
         return int(np.argmax(last_logits))
 
-    def decode(self, tokens, positions):
+    def decode(self, tokens, positions, page_tables):
+        assert page_tables.shape == (self.max_batch, self.pages_per_row)
         self.decodes += 1
         nxt = (np.asarray(positions) + 1).astype(np.int32)
         return nxt, np.zeros((self.max_batch, 64), np.float32)
@@ -160,12 +182,38 @@ class TestEngineValidation:
         with pytest.raises(ValueError, match="seq_buckets"):
             _tiny_engine(seq_buckets=())
 
+    @pytest.mark.parametrize("layout", ["ring", "", "Paged"])
+    def test_kv_layout_other_than_paged_is_refused(self, layout):
+        with pytest.raises(ValueError, match="only KV layout since PR 28"):
+            _tiny_engine(kv_layout=layout)
+
+    def test_kv_layout_paged_is_accepted_and_changes_nothing(self):
+        assert _tiny_engine(kv_layout="paged").cache_facts() == \
+            _tiny_engine().cache_facts()
+
+    @pytest.mark.parametrize("chunk,buckets", [(4, (16, 32)), (16, (32,)),
+                                               (32, (32,))])
+    def test_default_pool_holds_the_bytes_a_row_buffer_held(self, chunk,
+                                                            buckets):
+        """No page keys: `page_size` is two prefill chunks (at most
+        `max_seq`) and `n_pages` every row at full length plus the trash
+        page, so the pool is `max_batch x max_seq` positions of K and V
+        a layer (the ring layout's `[max_batch, max_seq, H, D]`) and one
+        page more."""
+        eng = _tiny_engine(prefill_chunk=chunk, seq_buckets=buckets)
+        assert eng.page_size == min(2 * chunk, 32)
+        assert eng.n_pages == 2 * (32 // eng.page_size) + 1
+        position = 2 * 4 * 8 * 4         # K and V, 4 heads x 8, float32
+        assert eng.cache_facts()["bytes"] == \
+            2 * position * (2 * 32 + eng.page_size)
+
     def test_prompt_length_bounds(self):
         eng = _tiny_engine()
+        table = identity_tables(eng)[0]
         with pytest.raises(ValueError, match="prompt length"):
-            eng.prefill(0, [])
+            eng.prefill(0, [], table)
         with pytest.raises(ValueError, match="prompt length"):
-            eng.prefill(0, [1] * 33)
+            eng.prefill(0, [1] * 33, table)
 
 
 class TestRecompileContract:
@@ -216,3 +264,8 @@ class TestRecompileContract:
         assert facts["dtype_census"] == {"int8": 4}
         assert facts["seq_buckets"] == [16, 32]
         assert facts["max_seq"] == 32 and not facts["stacked"]
+        # no page keys given: pages of two prefill chunks, every row at
+        # full length and the trash page
+        assert (facts["page_size"], facts["pages_per_row"],
+                facts["n_pages"]) == (8, 4, 2 * 4 + 1)
+        assert "kv_layout" not in facts

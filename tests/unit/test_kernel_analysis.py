@@ -8,10 +8,10 @@ Two halves:
   surfacing as EXACTLY its expected finding (over-VMEM block, tile
   misalignment, unclamped index map failing the elision contract,
   grid-write race);
-- stock kernels — the real decode (ring + paged) and train
-  flash-attention programs come back zero-findings, and the proven
-  KV elided-DMA fraction equals the scenario's dead-block occupancy
-  (the static proof of the flash-decode clamp trick).
+- stock kernels — the real decode and train flash-attention programs
+  come back zero-findings, and the proven KV elided-DMA fraction
+  equals the scenario's dead-block occupancy (the static proof that
+  the decode kernel fetches no dead block).
 
 Everything runs interpret-mode on CPU; the analyzer never executes a
 kernel on hardware.
@@ -33,7 +33,6 @@ from deepspeed_tpu.analysis.cost import estimate_step_cost
 from deepspeed_tpu.analysis.kernels import (
     analyze_kernels,
     paged_dead_block_fraction,
-    ring_dead_block_fraction,
 )
 from deepspeed_tpu.analysis.rules import (
     SEV_ERROR,
@@ -44,9 +43,10 @@ from deepspeed_tpu.analysis.rules import (
 
 KERNEL_RULES = {"kernel_vmem", "kernel_tiling", "kernel_dma"}
 
-# The audit toys' kernel-analysis scenario: positions [8, 16] over
-# max_seq 32 at block_k 8 (see audit._kernel_analysis_for).
-TOY_EXPECTED_ELISION = ring_dead_block_fraction([8, 16], 32, 8)
+# The audit toys' kernel-analysis scenario: two live rows at positions
+# [8, 16] over max_seq 32 at block_k 8 (see audit._kernel_analysis_for):
+# the rows hold 8 // 8 + 1 = 2 and 16 // 8 + 1 = 3 of their 4 blocks.
+TOY_EXPECTED_ELISION = 1.0 - (2 + 3) / (2 * 4)
 
 
 def _copy_kernel(x_ref, o_ref):
@@ -199,13 +199,8 @@ def test_seeded_unclamped_elision_shortfall():
 # ---------------------------------------------------------------------------
 
 @pytest.fixture(scope="module")
-def ring_report():
-    return audit_decode(kernels=True, kv_layout="ring")
-
-
-@pytest.fixture(scope="module")
 def paged_report():
-    return audit_decode(kernels=True, kv_layout="paged")
+    return audit_decode(kernels=True)
 
 
 def _kv_elided_fractions(report):
@@ -221,9 +216,8 @@ def _kv_elided_fractions(report):
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("layout", ["ring", "paged"])
-def test_stock_decode_zero_findings(layout, ring_report, paged_report):
-    report = ring_report if layout == "ring" else paged_report
+def test_stock_decode_zero_findings(paged_report):
+    report = paged_report
     assert report.findings == []
     ks = report.stats["kernels"]
     assert ks["kernels"], "decode program lost its Pallas kernels"
@@ -234,32 +228,24 @@ def test_stock_decode_zero_findings(layout, ring_report, paged_report):
         assert kd["tiling"] == []
         pools = [op for op in kd["operands"].values()
                  if op["manual_dma"]]
-        if layout == "ring":
-            # the proven per-kernel elision beats the contract (q/out
-            # operands elide MORE than the KV floor)
-            assert not pools
-            assert kd["elided_dma_fraction"] >= TOY_EXPECTED_ELISION
-        else:
-            # the paged kernel leaves the pool in HBM and walks it: K
-            # and V launch exactly the live blocks (q and out are one
-            # row block a grid step, with nothing to elide), and only
-            # the two double buffers are VMEM
-            assert len(pools) == 2 and kd["grid"] == [2]
-            assert all(op["elided_fraction"] == pytest.approx(
-                TOY_EXPECTED_ELISION) for op in pools)
-            assert kd["scratch_bytes"] == sum(
-                2 * op["block_bytes"] for op in pools)
+        # the kernel leaves the pool in HBM and walks it: K and V
+        # launch exactly the live blocks (q and out are one row block a
+        # grid step, with nothing to elide), and only the two double
+        # buffers are VMEM
+        assert len(pools) == 2 and kd["grid"] == [2]
+        assert all(op["elided_fraction"] == pytest.approx(
+            TOY_EXPECTED_ELISION) for op in pools)
+        assert kd["scratch_bytes"] == sum(
+            2 * op["block_bytes"] for op in pools)
 
 
 @pytest.mark.slow
-def test_clamp_trick_pins_dead_block_fraction(ring_report, paged_report):
+def test_walk_pins_dead_block_fraction(paged_report):
     # The KV operands' proven elided fraction equals the scenario's
-    # dead-block occupancy on BOTH layouts — the ring clamp and the
-    # paged clamp+gather dedupe exactly the dead cache blocks, no more
-    # and no fewer.
+    # dead-block occupancy — the walk fetches exactly the live cache
+    # blocks, no more and no fewer.
     assert TOY_EXPECTED_ELISION == pytest.approx(0.375)
-    assert len(_kv_elided_fractions(ring_report)) >= 2   # k and v
-    assert len(_kv_elided_fractions(paged_report)) >= 2
+    assert len(_kv_elided_fractions(paged_report)) >= 2  # k and v
 
 
 @pytest.mark.slow
@@ -288,18 +274,13 @@ def _cost_facts(report):
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("layout", ["ring", "paged"])
-def test_kernel_traffic_flips_block_k_ranking(layout, ring_report,
-                                              paged_report):
+def test_kernel_traffic_tells_block_k_apart(paged_report):
     # Pinned scenario (ISSUE 19): at the toy occupancy, block_k=4
     # fetches FEWER live bytes (finer blocks track the ragged fill).
-    # The ring kernel launches a grid step a block, so it also pays
-    # MORE dense bytes (more steps re-touch q/out): dense pricing
-    # prefers block_k=8 and the elision-aware DMA pricing flips the
-    # ranking to block_k=4. The paged kernel (PR 27) touches q and out
-    # once a row whatever block_k: the dense rectangle is the same
-    # bytes at both, and only the DMA pricing tells them apart.
-    bk4 = audit_decode(kernels=True, kv_layout=layout,
+    # The kernel (PR 27) touches q and out once a row whatever block_k:
+    # the dense rectangle is the same bytes at both, and only the
+    # elision-aware DMA pricing tells them apart.
+    bk4 = audit_decode(kernels=True,
                        config_overrides={"attention_block_k": 4})
     # the pool cuts [D, block_k] KV blocks, positions on the lanes, so
     # a 4-position block of an 8-position page is honestly sub-tile and
@@ -308,17 +289,14 @@ def test_kernel_traffic_flips_block_k_ranking(layout, ring_report,
     assert {f.rule for f in bk4.findings} <= {"kernel_tiling"}
     assert all(f.severity == "warning" for f in bk4.findings)
     f4 = _cost_facts(bk4)
-    f8 = _cost_facts(ring_report if layout == "ring" else paged_report)
+    f8 = _cost_facts(paged_report)
 
     def step_s(facts, traffic):
         return estimate_step_cost("", n_devices=2, kernel_facts=facts,
                                   kernel_traffic=traffic).step_seconds
 
     assert step_s(f4, "dma") < step_s(f8, "dma")
-    if layout == "ring":
-        assert step_s(f8, "dense") < step_s(f4, "dense")
-    else:
-        assert step_s(f8, "dense") == step_s(f4, "dense")
+    assert step_s(f8, "dense") == step_s(f4, "dense")
 
     with pytest.raises(ValueError, match="kernel_traffic"):
         estimate_step_cost("", n_devices=2, kernel_facts=f4,
@@ -337,18 +315,18 @@ WALK_TABLES = np.array([[1, 0, 0, 0], [0, 0, 0, 0], [2, 3, 4, 5],
 
 @pytest.mark.parametrize("block_k,live", [(8, 1 + 4 + 3), (4, 2 + 8 + 5)])
 def test_paged_dead_block_fraction(block_k, live):
-    """The paged counterpart of `ring_dead_block_fraction`: blocks past
-    a live row's position AND every block of a row without a request,
-    out of the rows x pages x page / block_k rectangle."""
+    """Blocks past a live row's position AND every block of a row
+    without a request, out of the rows x pages x page / block_k
+    rectangle."""
     dense = 4 * 4 * (8 // block_k)
     assert paged_dead_block_fraction(
         WALK_POSITIONS, WALK_TABLES, 8, block_k) == \
         pytest.approx(1.0 - live / dense)
-    # all rows live: the ring's fraction for the same positions
+    # all rows live: the dead row's position 0 holds one block more
     tables = np.arange(1, 17, dtype=np.int32).reshape(4, 4)
     assert paged_dead_block_fraction(
         WALK_POSITIONS, tables, 8, block_k) == pytest.approx(
-            ring_dead_block_fraction(WALK_POSITIONS, 32, block_k))
+            1.0 - (live + 1) / dense)
 
 
 def test_paged_kernel_is_priced_by_its_walk():
@@ -406,52 +384,44 @@ def test_serving_search_space_has_block_dimension():
 def test_flash_decode_geometry_errors():
     from deepspeed_tpu.ops.pallas import (
         KernelGeometryError,
-        flash_decode,
         flash_decode_paged,
     )
     rng = np.random.default_rng(0)
-    B, S, H, D = 1, 16, 2, 8
+    B, H, D = 1, 2, 8
     q = jnp.asarray(rng.normal(size=(B, 1, H, D)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(B, S, H, D)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(B, S, H, D)), jnp.float32)
     pos = jnp.zeros((B,), jnp.int32)
-
-    assert issubclass(KernelGeometryError, ValueError)
-    # block_k < 1 is a typed geometry error, not a ZeroDivisionError
-    with pytest.raises(KernelGeometryError, match=">= 1"):
-        flash_decode(q, k, v, pos, block_k=0)
-    with pytest.raises(KernelGeometryError, match="multiple"):
-        flash_decode(q, k, v, pos, block_k=12)
-
-    # paged: block_k must divide page_size, validated before lowering
     n_pages, page_size, ppr = 5, 16, 2
     pool_k = jnp.zeros((n_pages, H, D, page_size), jnp.float32)
     pool_v = jnp.zeros((n_pages, H, D, page_size), jnp.float32)
     tables = jnp.zeros((B, ppr), jnp.int32)
+
+    assert issubclass(KernelGeometryError, ValueError)
+    # block_k < 1 is a typed geometry error, not a ZeroDivisionError
+    with pytest.raises(KernelGeometryError, match=">= 1"):
+        flash_decode_paged(q, pool_k, pool_v, pos, tables, block_k=0)
+    # block_k must divide page_size, validated before lowering
     with pytest.raises(KernelGeometryError, match="multiple"):
         flash_decode_paged(q, pool_k, pool_v, pos, tables, block_k=3)
 
-    # compiled-only rules (interpret=False is what a TPU build checks;
-    # the engine runs the same check when it is built): sub-tile blocks,
-    # and blocks with positions on the lanes (quantized scale rows, every
-    # block of the paged pool) that are not whole 128-lane rows
+    # the compiled-only rule (interpret=False is what a TPU build
+    # checks; the engine runs the same check when it is built): every
+    # block has positions on the lanes, so it is whole 128-lane rows or
+    # the whole page
     from deepspeed_tpu.ops.pallas.flash_decode import _validate_block_k
-    assert _validate_block_k(4, 16, "max_seq", jnp.float32, True) == 4
-    with pytest.raises(KernelGeometryError, match="sublane tile"):
-        _validate_block_k(4, 16, "max_seq", jnp.float32, False)
+    assert _validate_block_k(4, 16, True) == 4
     with pytest.raises(KernelGeometryError, match="multiple of 128"):
-        _validate_block_k(64, 256, "page_size", jnp.int8, False, True)
+        _validate_block_k(4, 16, False)
     with pytest.raises(KernelGeometryError, match="multiple of 128"):
-        _validate_block_k(64, 256, "page_size", jnp.float32, False, True)
-    assert _validate_block_k(64, 64, "page_size", jnp.int8, False,
-                             True) == 64
-    assert _validate_block_k(128, 1024, "max_seq", jnp.int8, False,
-                             True) == 128
+        _validate_block_k(64, 256, False)
+    assert _validate_block_k(64, 64, False) == 64
+    assert _validate_block_k(128, 1024, False) == 128
+    assert _validate_block_k(256, 128, False) == 128     # clamps
 
 
 def test_pallas_package_exports():
     import deepspeed_tpu.ops.pallas as ops
-    for name in ("flash_attention", "flash_decode", "flash_decode_paged",
+    assert "flash_decode" not in ops.__all__      # the ring kernel, PR 28
+    for name in ("flash_attention", "flash_decode_paged",
                  "dense_attention", "pallas_adam_update",
                  "KernelGeometryError", "DEFAULT_BLOCK_K",
                  "DEFAULT_MASK_VALUE"):
@@ -464,12 +434,12 @@ def test_pallas_package_exports():
 # ---------------------------------------------------------------------------
 
 @pytest.mark.slow
-def test_metrics_summary_kernel_block(ring_report):
+def test_metrics_summary_kernel_block(paged_report):
     from deepspeed_tpu.telemetry.cli import print_serve_summary, summarize
 
     events = [
         {"event": "compile", "step": 0,
-         "kernels": ring_report.stats["kernels"]},
+         "kernels": paged_report.stats["kernels"]},
         {"event": "decode_step", "step": 1, "wall_s": 0.01,
          "new_tokens": 2},
         {"event": "decode_step", "step": 2, "wall_s": 0.01,
@@ -479,10 +449,10 @@ def test_metrics_summary_kernel_block(ring_report):
     kn = s["kernels"]
     assert kn["vmem_high_water_bytes"] == max(
         kd["vmem_bytes"]
-        for kd in ring_report.stats["kernels"]["kernels"].values())
+        for kd in paged_report.stats["kernels"]["kernels"].values())
     assert kn["elided_dma_fraction"] == pytest.approx(
-        1.0 - ring_report.stats["kernels"]["dma_bytes"]
-        / ring_report.stats["kernels"]["dense_bytes"])
+        1.0 - paged_report.stats["kernels"]["dma_bytes"]
+        / paged_report.stats["kernels"]["dense_bytes"])
     assert kn["expected_elision"] == pytest.approx(TOY_EXPECTED_ELISION)
 
     out = io.StringIO()

@@ -1,5 +1,5 @@
 """Paged KV cache host machinery (`deepspeed_tpu/inference/paging.py`
-+ the paged branches of `inference/cache.py` and `analysis/rules.py`).
++ the pool ops of `inference/cache.py` and `analysis/rules.py`).
 
 Everything here is admission-time metadata, so most of the file is
 pure-python over a duck-typed engine stub: the allocator's free-list /
@@ -12,11 +12,12 @@ evacuate to host RAM under pressure and page back in on resume, and a
 dry pool makes ``admit`` return None without leaking references.
 
 The jax end pins the paged pool's static geometry
-(`cache.spec_for_model`: trash-page minimum, divisibility, ring-
-capacity default) and the `rule_decode` paged contract (host-transfer
-ops and degenerate page geometry are errors), and holds the pool's one
-memory order (`[n_pages, H, D, page_size]`, written a page slab at a
-time) against the ring cache, layer by layer. Whole-model numerics
+(`cache.spec_for_model`: trash-page minimum, divisibility, the
+every-row-full default) and the `rule_decode` paged contract
+(host-transfer ops and degenerate page geometry are errors), and holds
+the pool's one memory order (`[n_pages, H, D, page_size]`, written a
+page slab at a time) against plain contiguous `[B, S, H, D]` arrays
+written and attended over in the test, layer by layer. Whole-model numerics
 ride `test_paged_parity.py`.
 """
 
@@ -194,8 +195,6 @@ class _PoolEngine:
     moves pages through gather/scatter, and checks the park threshold —
     none of which needs a compiled program."""
 
-    kv_layout = "paged"
-
     def __init__(self, n_pages=6, page_size=4, pages_per_row=4,
                  prefill_chunk=4, prefix_cache=True,
                  host_park_threshold=0.0):
@@ -359,16 +358,15 @@ class TestPagedSpec:
         return GPT2Config(vocab_size=64, n_positions=64, n_embd=32,
                           n_layer=2, n_head=4, dtype=jnp.float32)
 
-    def test_ring_capacity_default(self):
+    def test_every_row_full_default(self):
         from deepspeed_tpu.inference.cache import spec_for_model
         spec = spec_for_model(self._cfg(), 2, 32, page_size=8)
-        assert spec.paged
         assert spec.pages_per_row == 4
         assert spec.n_pages == 2 * 4 + 1       # + the trash page
 
     def test_page_size_must_divide_max_seq(self):
         from deepspeed_tpu.inference.cache import spec_for_model
-        with pytest.raises(ValueError, match="must divide max_seq"):
+        with pytest.raises(ValueError, match="divisor of max_seq"):
             spec_for_model(self._cfg(), 2, 32, page_size=12)
 
     def test_n_pages_floor_guards_trash_page(self):
@@ -387,7 +385,7 @@ class TestPagedSpec:
 
 
 # ---------------------------------------------------------------------------
-# the pool against the ring cache, one layer
+# the pool against contiguous arrays, one layer
 # ---------------------------------------------------------------------------
 
 PAGE, SEQ, HEADS, DIM = 128, 256, 2, 8
@@ -395,28 +393,57 @@ STORAGE = {"float32": (np.float32, None), "bfloat16": ("bfloat16", None),
            "int8": (np.int8, "int8")}
 
 
-def _ring_and_pool(storage, tables, rng):
-    """A ring layer filled with random keys and values at every
+def _plain_write(plain, k, v, positions, codec):
+    """The reference's write: ``k`` / ``v`` ``[B, T, H, D]`` go into
+    contiguous ``[B, S, H, D]`` arrays (scales ``[B, S, H]``) at
+    ``positions`` ``[B, T]``, quantized as the pool quantizes."""
+    import jax.numpy as jnp
+    from deepspeed_tpu.inference.cache import _quantize
+    new = {"k": k, "v": v}
+    if codec:
+        new["k"], new["k_scale"] = _quantize(k, codec)
+        new["v"], new["v_scale"] = _quantize(v, codec)
+    rows = np.arange(positions.shape[0])[:, None]
+    return {name: leaf.at[rows, jnp.asarray(positions)].set(
+        new[name].astype(leaf.dtype)) for name, leaf in plain.items()}
+
+
+def _plain_attend(plain, x, positions, codec):
+    """Write, then each query over its row up to its own position."""
+    import jax
+    import jax.numpy as jnp
+    q, k, v = x
+    plain = _plain_write(plain, k, v, positions, codec)
+    full = {n: plain[n].astype(jnp.float32) for n in ("k", "v")}
+    if codec:
+        full = {n: full[n] * plain[n + "_scale"][..., None] for n in full}
+    att = jnp.einsum("bthd,bshd->bhts", q, full["k"]) / np.sqrt(DIM)
+    seen = np.arange(SEQ)[None, None] <= np.asarray(positions)[:, :, None]
+    att = jax.nn.softmax(jnp.where(seen[:, None], att, -jnp.inf), axis=-1)
+    return jnp.einsum("bhts,bshd->bthd", att, full["v"]), plain
+
+
+def _plain_and_pool(storage, tables, rng):
+    """Contiguous arrays filled with random keys and values at every
     position, and the pool that holds the same bytes under ``tables``
     (laid out here with numpy, not by the code under test). Pages that
     no table names, the trash page among them, hold garbage."""
     import jax.numpy as jnp
-    from deepspeed_tpu.inference import cache
 
     dtype, codec = STORAGE[storage]
     B = tables.shape[0]
     shape = (B, SEQ, HEADS, DIM)
-    ring = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    plain = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
     if codec:
-        ring["k_scale"] = ring["v_scale"] = jnp.zeros(shape[:-1],
-                                                      jnp.float32)
+        plain["k_scale"] = plain["v_scale"] = jnp.zeros(shape[:-1],
+                                                        jnp.float32)
     k, v = (jnp.asarray(rng.standard_normal(shape), jnp.float32)
             for _ in range(2))
-    ring = cache.write_kv(ring, k, v,
-                          jnp.broadcast_to(jnp.arange(SEQ), (B, SEQ)))
+    plain = _plain_write(plain, k, v,
+                         np.broadcast_to(np.arange(SEQ), (B, SEQ)), codec)
     n_pages = int(tables.max()) + 2
     pool = {}
-    for name, leaf in ring.items():
+    for name, leaf in plain.items():
         leaf = np.asarray(leaf)
         buf = rng.standard_normal(
             (n_pages,) + leaf.shape[2:] + (PAGE,)).astype(leaf.dtype)
@@ -425,12 +452,12 @@ def _ring_and_pool(storage, tables, rng):
                 buf[tables[b, j]] = np.moveaxis(
                     leaf[b, j * PAGE:(j + 1) * PAGE], 0, -1)
         pool[name] = jnp.asarray(buf)
-    return ring, pool
+    return plain, pool
 
 
-def _assert_same_bytes(ring, pool, tables, rows):
-    """Each named row's pages hold exactly the ring row's bytes."""
-    for name, leaf in ring.items():
+def _assert_same_bytes(plain, pool, tables, rows):
+    """Each named row's pages hold exactly the contiguous row's bytes."""
+    for name, leaf in plain.items():
         leaf, buf = np.asarray(leaf), np.asarray(pool[name])
         for b in rows:
             for j, page in enumerate(tables[b]):
@@ -440,15 +467,14 @@ def _assert_same_bytes(ring, pool, tables, rows):
 
 
 @pytest.mark.parametrize("storage", list(STORAGE))
-class TestPoolAgainstRing:
-    def _attend(self, layer, x, positions, impl, tables=None):
+class TestPoolAgainstContiguous:
+    def _attend(self, pool, x, positions, impl, tables):
         import jax.numpy as jnp
         from deepspeed_tpu.inference import cache
         q, k, v = x
         return cache.cached_attention(
-            q, k, v, layer, jnp.asarray(positions), jnp.float32,
-            impl=impl, block_k=PAGE,
-            page_table=None if tables is None else jnp.asarray(tables))
+            q, k, v, pool, jnp.asarray(positions), jnp.float32,
+            jnp.asarray(tables), impl=impl, block_k=PAGE)
 
     def _new(self, rng, rows, tokens):
         import jax.numpy as jnp
@@ -456,8 +482,7 @@ class TestPoolAgainstRing:
                             jnp.float32) for _ in range(3)]
 
     @pytest.mark.parametrize("impl", ["dense", "flash"])
-    def test_decode_writes_land_where_the_ring_has_them(self, storage,
-                                                        impl):
+    def test_decode_writes_land_at_their_positions(self, storage, impl):
         # in-page offsets 0, 1 and 127, the first slot past a page
         # boundary, the last slot of a row; rows 5 and 6 are inactive:
         # position 0 through a table of zeros, both on the trash page
@@ -465,12 +490,13 @@ class TestPoolAgainstRing:
         positions = np.array([0, 1, 127, 128, 255, 0, 0])[:, None]
         tables = np.array([[1, 2], [3, 4], [5, 6], [7, 8], [9, 10],
                            [0, 0], [0, 0]], np.int32)
-        ring, pool = _ring_and_pool(storage, tables, rng)
+        plain, pool = _plain_and_pool(storage, tables, rng)
         x = self._new(rng, 7, 1)
-        want, ring = self._attend(ring, x, positions, "dense")
+        want, plain = _plain_attend(plain, x, positions,
+                                    STORAGE[storage][1])
         got, pool = self._attend(pool, x, positions, impl, tables)
         np.testing.assert_allclose(got[:5], want[:5], atol=2e-6, rtol=1e-5)
-        _assert_same_bytes(ring, pool, tables, range(5))
+        _assert_same_bytes(plain, pool, tables, range(5))
 
     def test_speculative_chunk_straddles_a_page(self, storage):
         # row 0 writes 126..129 over the boundary, row 2's chunk runs
@@ -478,28 +504,30 @@ class TestPoolAgainstRing:
         rng = np.random.default_rng(1)
         positions = np.array([126, 0, 125])[:, None] + np.arange(4)
         tables = np.array([[1, 2], [3, 4], [5, 0]], np.int32)
-        ring, pool = _ring_and_pool(storage, tables, rng)
+        plain, pool = _plain_and_pool(storage, tables, rng)
         x = self._new(rng, 3, 4)
-        want, ring = self._attend(ring, x, positions, "dense")
+        want, plain = _plain_attend(plain, x, positions,
+                                    STORAGE[storage][1])
         got, pool = self._attend(pool, x, positions, "dense", tables)
         np.testing.assert_allclose(got[:2], want[:2], atol=2e-6, rtol=1e-5)
         # row 2 sees its own page only up to 127; 128 is on the trash
         np.testing.assert_allclose(got[2, :3], want[2, :3], atol=2e-6,
                                    rtol=1e-5)
-        _assert_same_bytes(ring, pool, tables, range(2))
-        _assert_same_bytes(ring, pool, tables[:, :1], [2])
+        _assert_same_bytes(plain, pool, tables, range(2))
+        _assert_same_bytes(plain, pool, tables[:, :1], [2])
 
     @pytest.mark.parametrize("start", [0, 64, 192])
     def test_prefill_chunk_into_one_page(self, storage, start):
         rng = np.random.default_rng(2)
         positions = start + np.arange(64)[None]
         tables = np.array([[2, 1]], np.int32)
-        ring, pool = _ring_and_pool(storage, tables, rng)
+        plain, pool = _plain_and_pool(storage, tables, rng)
         x = self._new(rng, 1, 64)
-        want, ring = self._attend(ring, x, positions, "dense")
+        want, plain = _plain_attend(plain, x, positions,
+                                    STORAGE[storage][1])
         got, pool = self._attend(pool, x, positions, "dense", tables)
         np.testing.assert_allclose(got, want, atol=2e-6, rtol=1e-5)
-        _assert_same_bytes(ring, pool, tables, [0])
+        _assert_same_bytes(plain, pool, tables, [0])
 
 
 # ---------------------------------------------------------------------------
@@ -513,7 +541,7 @@ _PAGE_FACTS = {"page_size": 8, "n_pages": 9, "pages_per_row": 4,
 class TestRuleDecodePaged:
     def test_clean_paged_context_passes(self):
         from deepspeed_tpu.analysis.rules import StepContext, rule_decode
-        ctx = StepContext(hlo_text="", decode_kv_layout="paged",
+        ctx = StepContext(hlo_text="",
                           decode_page_facts=dict(_PAGE_FACTS))
         assert rule_decode(ctx) == []
 
@@ -522,7 +550,7 @@ class TestRuleDecodePaged:
                                                   rule_decode)
         hlo = ("%of = token[] outfeed(f32[2,8]{1,0} %pages, "
                "token[] %tok)")
-        ctx = StepContext(hlo_text=hlo, decode_kv_layout="paged",
+        ctx = StepContext(hlo_text=hlo,
                           decode_page_facts=dict(_PAGE_FACTS))
         findings = rule_decode(ctx)
         assert [f.severity for f in findings] == [SEV_ERROR]
@@ -532,7 +560,7 @@ class TestRuleDecodePaged:
         from deepspeed_tpu.analysis.rules import (SEV_ERROR, StepContext,
                                                   rule_decode)
         ctx = StepContext(
-            hlo_text="", decode_kv_layout="paged",
+            hlo_text="",
             decode_page_facts={"page_size": 0, "n_pages": 1,
                                "pages_per_row": 0, "max_seq": 32})
         findings = rule_decode(ctx)
@@ -543,16 +571,16 @@ class TestRuleDecodePaged:
         from deepspeed_tpu.analysis.rules import (SEV_ERROR, StepContext,
                                                   rule_decode)
         bad = dict(_PAGE_FACTS, pages_per_row=3)   # 3*8 != 32
-        ctx = StepContext(hlo_text="", decode_kv_layout="paged",
-                          decode_page_facts=bad)
+        ctx = StepContext(hlo_text="", decode_page_facts=bad)
         findings = rule_decode(ctx)
         assert [f.severity for f in findings] == [SEV_ERROR]
         assert "trash page" in findings[0].message
 
-    def test_ring_layout_ignores_page_facts(self):
+    def test_a_step_that_serves_nothing_is_not_judged(self):
+        """No serving fact at all (a train step's context): the rule
+        says nothing, whatever the program holds."""
         from deepspeed_tpu.analysis.rules import StepContext, rule_decode
-        ctx = StepContext(hlo_text="%of = token[] outfeed(f32[2] %x)",
-                          decode_kv_layout="ring")
+        ctx = StepContext(hlo_text="%of = token[] outfeed(f32[2] %x)")
         assert rule_decode(ctx) == []
 
 

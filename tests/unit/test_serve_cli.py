@@ -183,14 +183,27 @@ def test_greedy_serve_is_sampling_invariant(tmp_path, capsys):
 
 
 # ---------------------------------------------------------------------------
-# paged KV layout (--kv-layout paged)
+# the pool: prefix sharing, sessions, and the switch that went
 # ---------------------------------------------------------------------------
 
 class TestPagedUsageErrors:
-    def test_expect_prefix_hits_requires_paged(self):
+    @pytest.mark.parametrize("value", ["ring", "paged"])
+    def test_kv_layout_flag_is_gone(self, value, capsys):
+        """`--kv-layout` chose a layout until PR 28: now argparse does
+        not know it, whatever it is set to."""
         with pytest.raises(SystemExit) as e:
-            main(["--synthetic", "2", "--expect-prefix-hits", "1"])
+            main(["--synthetic", "2", "--kv-layout", value])
         assert e.value.code == 2
+        assert "--kv-layout" in capsys.readouterr().err
+
+    def test_config_kv_layout_ring_is_refused(self, tmp_path):
+        cfg = tmp_path / "ds_config.json"
+        cfg.write_text(json.dumps({
+            "train_batch_size": 1,
+            "train_micro_batch_size_per_gpu": 1,
+            "inference": {"kv_layout": "ring"}}))
+        with pytest.raises(ValueError, match="only KV layout since PR 28"):
+            main(["--config", str(cfg), "--synthetic", "2"])
 
 
 def test_paged_prefix_sharing_end_to_end(tmp_path, capsys):
@@ -200,7 +213,7 @@ def test_paged_prefix_sharing_end_to_end(tmp_path, capsys):
     log = tmp_path / "paged.jsonl"
     rc = main(["--synthetic", "6", "--max-new", "4",
                "--arrival-every", "1",
-               "--kv-layout", "paged", "--shared-prefix", "12",
+               "--shared-prefix", "12",
                "--expect-compiles", "2", "--expect-prefix-hits", "1",
                "--jsonl", str(log), "--json"])
     assert rc == 0
@@ -227,7 +240,7 @@ def test_paged_prefix_sharing_end_to_end(tmp_path, capsys):
 def test_expect_prefix_hits_violation_exits_nonzero(capsys):
     # no shared prefix -> no hits -> the gate must trip
     rc = main(["--synthetic", "2", "--max-new", "2",
-               "--kv-layout", "paged", "--no-prefix-cache",
+               "--no-prefix-cache",
                "--expect-prefix-hits", "1"])
     assert rc == 1
     captured = capsys.readouterr()
@@ -236,8 +249,9 @@ def test_expect_prefix_hits_violation_exits_nonzero(capsys):
 
 
 def test_paged_config_file_with_sessions(tmp_path, capsys):
-    """kv_layout + page knobs flow through --config, and session_id
-    rides the request JSONL into parked sessions."""
+    """The page knobs flow through --config (`kv_layout: "paged"` is
+    still accepted there, and changes nothing), and session_id rides
+    the request JSONL into parked sessions."""
     cfg = tmp_path / "ds_config.json"
     cfg.write_text(json.dumps({
         "train_batch_size": 1,

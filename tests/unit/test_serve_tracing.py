@@ -68,9 +68,10 @@ def serve(engine, requests):
     return comps, ring_since(since), sched
 
 
-@pytest.fixture(scope="module", params=["ring", "paged"])
+@pytest.fixture(scope="module", params=["dense", "flash"])
 def served(request):
-    return serve(build_engine(kv_layout=request.param), stream())
+    return serve(build_engine(attention_impl=request.param,
+                              attention_block_k=8), stream())
 
 
 # ---------------------------------------------------------------------------
@@ -108,9 +109,9 @@ def test_first_token_is_held_until_its_step_returns():
     token exists before the decode and can be read only after it."""
 
     class Slow(StubEngine):
-        def decode(self, tokens, positions):
+        def decode(self, tokens, positions, page_tables):
             time.sleep(0.02)
-            return super().decode(tokens, positions)
+            return super().decode(tokens, positions, page_tables)
 
     sched = ContinuousBatchingScheduler(Slow(max_batch=2))
     sched.submit(Request("a", [1, 2], max_new_tokens=4))
@@ -189,8 +190,7 @@ def test_every_step_holds_its_children(served):
                 assert inner <= k[2] - k[1]
     want = {"serve/step/" + n
             for n in ("expire", "admit", "inputs", "decode", "book")}
-    assert want <= seen
-    assert ("serve/step/grow" in seen) == (sched.paging is not None)
+    assert want <= seen and "serve/step/grow" in seen
     # no span of the serving path lies outside a step
     for path, t0, t1, _ in records:
         if path != "serve/request":
@@ -207,11 +207,14 @@ def test_step_attrs_are_the_steps_counters(served):
         assert a["max_batch"] == 2
         assert 0 <= a["live_rows"] <= a["batch"] <= 2 or a["batch"] == 0
         assert a["queue_depth"] >= 0
-        if sched.paging is not None:
-            assert 0 <= a["pages_live"] <= a["pages_resident"] \
-                <= a["pages_total"] == sched.engine.n_pages - 1
-        else:
-            assert "pages_live" not in a
+        assert 0 <= a["pages_live"] <= a["pages_resident"] \
+            <= a["pages_total"] == sched.engine.n_pages - 1
+    # the flash kernel's counters ride the decode span, and only its
+    decodes = [r[3] for r in records if r[0] == "serve/step/decode"]
+    flash = sched.engine.attention_impl == "flash"
+    assert decodes and all(
+        (set(a or ()) == {"kv_blocks_live", "kv_blocks_launched"})
+        == flash for a in decodes)
     assert steps[-1]["live_rows"] == 0 == steps[-1]["queue_depth"]
 
 
@@ -241,7 +244,7 @@ def test_rid_joins_admit_prefill_and_request(served):
 
 
 def test_pages_live_is_the_sum_over_live_rows_tables():
-    eng = build_engine(kv_layout="paged", page_size=8)
+    eng = build_engine(page_size=8)
     sched = ContinuousBatchingScheduler(eng)
     for r in stream(n=8, seed=3):
         sched.submit(r)

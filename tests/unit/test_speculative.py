@@ -5,8 +5,7 @@ The PR's acceptance criteria, as pins:
 - **Greedy parity**: a speculative serve's outputs are BIT-IDENTICAL
   to the non-speculative engine's over the same stream — drafting and
   verify-accept are an execution strategy, not a model change. Runs
-  across {unrolled, scan} x {ring, paged} x {dense, flash+int8} and
-  under 4-way TP.
+  across {unrolled, scan} x {dense, flash+int8} and under 4-way TP.
 - **Three pinned programs**: prefill + draft + verify each compile
   exactly once through bucket churn, and the plain decode program is
   never entered (0 jit-cache entries). Degenerate configs (k == 0,
@@ -17,10 +16,11 @@ The PR's acceptance criteria, as pins:
   sampling with residual corrections for temperature > 0 — the
   empirical accept rate matches sum min(p, q)).
 - The scheduler **length-finishes** any row whose verify window would
-  cross max_seq (the ring chunk write would clamp-shift onto valid
-  history otherwise), the adaptive window controller moves draft_len
-  as traced data only, and the `speculative` audit flavor comes back
-  with zero findings after churning both KV layouts.
+  cross max_seq (the page-table lookup would clamp onto the row's
+  last page and overwrite valid history otherwise), the adaptive
+  window controller moves draft_len as traced data only, and the
+  `speculative` audit flavor comes back with zero findings after its
+  churn stream.
 """
 
 import numpy as np
@@ -177,18 +177,13 @@ class TestRejectionAccept:
 
 
 class TestGreedyParity:
-    def test_ring_unrolled(self):
+    def test_unrolled(self):
         assert_parity(build_engine(), build_engine(speculative=None))
 
     @pytest.mark.slow
-    def test_ring_scan_layers(self):
+    def test_scan_layers(self):
         assert_parity(build_engine(scan_layers=True),
                       build_engine(speculative=None, scan_layers=True))
-
-    @pytest.mark.slow
-    def test_paged(self):
-        assert_parity(build_engine(kv_layout="paged"),
-                      build_engine(speculative=None, kv_layout="paged"))
 
     @pytest.mark.slow
     def test_flash_int8_draft_vs_dense_oracle(self):
@@ -310,9 +305,9 @@ class TestAdaptiveController:
 class TestSchedulerWindowGuard:
     def test_length_finish_before_max_seq_overrun(self):
         """A row whose verify window would cross max_seq is finished
-        with the length reason BEFORE the round — the ring chunk
-        write's clamped dynamic_update_slice would otherwise shift
-        onto valid history."""
+        with the length reason BEFORE the round — the chunk's writes
+        past max_seq would otherwise clamp onto the row's last page,
+        over valid history."""
         eng = build_engine(seq_buckets=(16,))
         comps = ContinuousBatchingScheduler(eng).run(
             [Request("r0", list(range(8)), max_new_tokens=12)])
@@ -404,7 +399,6 @@ class TestRuleSpeculative:
         ctx = StepContext(
             hlo_text="", spec_facts=self._facts(),
             spec_compile_counts=self._counts(),
-            decode_kv_layout="paged",
             spec_draft_hlo='  infeed = (s32[2]) infeed(token[] %t)\n',
             spec_verify_hlo="")
         (f,) = rule_speculative(ctx)
@@ -414,21 +408,19 @@ class TestRuleSpeculative:
 
 class TestAuditSpeculative:
     @pytest.mark.slow
-    def test_zero_findings_both_layouts(self):
+    def test_zero_findings(self):
         """The acceptance criterion: the speculative flavor churns the
-        ring AND paged serve streams (paged includes park + resume)
-        and the whole catalog comes back empty; the measured draft
-        flop ratio shows real truncation."""
+        serve stream (park + resume included) and the whole catalog
+        comes back empty; the measured draft flop ratio shows real
+        truncation."""
         report = audit_speculative()
         assert report.findings == []
-        for layout in ("ring", "paged"):
-            st = report.stats["layouts"][layout]
-            assert st["compile_counts"] == \
-                {"prefill": 1, "decode": 0, "draft": 1, "verify": 1}
-            assert st["speculative"]["mean_accepted"] >= 1.0
-            ratio = st["draft_flops_ratio"]
-            dl = st["speculative"]["draft_layers"]
-            nl = st["speculative"]["n_layer"]
-            assert dl / nl <= ratio < (dl / nl + 1.0) / 2.0
-        assert report.stats["layouts"]["paged"]["paging"][
-            "sessions_resumed"] >= 1
+        st = report.stats
+        assert st["compile_counts"] == \
+            {"prefill": 1, "decode": 0, "draft": 1, "verify": 1}
+        assert st["speculative"]["mean_accepted"] >= 1.0
+        ratio = st["draft_flops_ratio"]
+        dl = st["speculative"]["draft_layers"]
+        nl = st["speculative"]["n_layer"]
+        assert dl / nl <= ratio < (dl / nl + 1.0) / 2.0
+        assert st["paging"]["sessions_resumed"] >= 1

@@ -126,37 +126,35 @@ def test_grouped_matmul_compiles(chip, monkeypatch, grad, bank):
 KV_DTYPES = ["float32", "bfloat16", "int8", "float8_e4m3fn", "float8_e5m2"]
 
 
-@pytest.mark.parametrize("kv_dtype", KV_DTYPES)
-@pytest.mark.parametrize("layout", ["ring", "paged"])
-def test_flash_decode_compiles(chip, layout, kv_dtype):
-    from deepspeed_tpu.ops.pallas.flash_decode import (
-        flash_decode, flash_decode_paged)
+# (heads a device holds, head size): GPT-2 medium (the serve cell), GPT-2
+# XL's 25 heads, one shard of medium under TP = 4, OLMoE's head size
+DECODE_GEOMETRIES = {"medium": (16, 64), "xl": (25, 64), "tp4": (4, 64),
+                     "d128": (16, 128)}
 
+
+@pytest.mark.parametrize("kv_dtype", KV_DTYPES)
+@pytest.mark.parametrize("geometry", list(DECODE_GEOMETRIES))
+def test_flash_decode_compiles(chip, geometry, kv_dtype):
+    from deepspeed_tpu.ops.pallas.flash_decode import flash_decode_paged
+
+    heads, head_dim = DECODE_GEOMETRIES[geometry]
     dt = jnp.dtype(kv_dtype)
     quant = dt.itemsize == 1
     # a served checkpoint computes in f32 (q and the cache both); the
     # other storage dtypes sit under a bf16 model
-    q = chip((B, 1, H, D), dt if kv_dtype == "float32" else jnp.bfloat16)
+    q = chip((B, 1, heads, head_dim),
+             dt if kv_dtype == "float32" else jnp.bfloat16)
     pos = chip((B,), jnp.int32)
-    if layout == "ring":
-        kv = chip((B, T, H, D), dt)
-        scales = (chip((B, T, H), jnp.float32),) * 2 if quant else ()
+    n_pages = B * (T // PAGE) + 1
+    kv = chip((n_pages, heads, head_dim, PAGE), dt)
+    tables = chip((B, T // PAGE), jnp.int32)
+    scales = (chip((n_pages, heads, PAGE), jnp.float32),) * 2 \
+        if quant else ()
 
-        def fn(q, k, v, pos, *s):
-            return flash_decode(q, k, v, pos, *s, interpret=False)
-        args = (q, kv, kv, pos) + scales
-    else:
-        n_pages = B * (T // PAGE) + 1
-        kv = chip((n_pages, H, D, PAGE), dt)
-        tables = chip((B, T // PAGE), jnp.int32)
-        scales = (chip((n_pages, H, PAGE), jnp.float32),) * 2 \
-            if quant else ()
-
-        def fn(q, k, v, pos, pt, *s):
-            return flash_decode_paged(q, k, v, pos, pt, *s,
-                                      interpret=False)
-        args = (q, kv, kv, pos, tables) + scales
-    assert "tpu_custom_call" in compiled_text(fn, *args)
+    def fn(q, k, v, pos, pt, *s):
+        return flash_decode_paged(q, k, v, pos, pt, *s, interpret=False)
+    assert "tpu_custom_call" in compiled_text(fn, q, kv, kv, pos, tables,
+                                              *scales)
 
 
 # (rows, tokens a row): the decode step, one prefill chunk, a
@@ -233,30 +231,21 @@ def kernel_grids(lowered_text):
     return grids
 
 
-@pytest.mark.parametrize("layout", ["ring", "paged"])
-def test_flash_decode_grid(chip, layout):
+def test_flash_decode_grid(chip):
     """Steps a layer at the serve cell's shape, read from the lowered
-    call itself. The paged kernel launches one step a row, 48; until PR
-    27 it launched rows x heads x blocks = 6,144 whatever the rows held
+    call itself. The kernel launches one step a row, 48; until PR 27 it
+    launched rows x heads x blocks = 6,144 whatever the rows held
     (`PERF.md` section 6), and a change that brings that back fails
-    here. The ring kernel is no cell's and keeps its 768 x 8."""
-    from deepspeed_tpu.ops.pallas.flash_decode import (
-        flash_decode, flash_decode_paged)
+    here."""
+    from deepspeed_tpu.ops.pallas.flash_decode import flash_decode_paged
 
     q = chip((ROWS, 1, H, D), jnp.float32)
     pos = chip((ROWS,), jnp.int32)
-    if layout == "ring":
-        kv = chip((ROWS, T, H, D), jnp.float32)
-        lowered = jax.jit(lambda q, k, v, pos: flash_decode(
-            q, k, v, pos, interpret=False)).lower(q, kv, kv, pos)
-        want = (ROWS * H, T // PAGE)
-    else:
-        kv = chip((N_PAGES, H, D, PAGE), jnp.float32)
-        lowered = jax.jit(lambda q, k, v, pos, pt: flash_decode_paged(
-            q, k, v, pos, pt, interpret=False)).lower(
-                q, kv, kv, pos, chip((ROWS, T // PAGE), jnp.int32))
-        want = (ROWS,)
-    assert kernel_grids(lowered.as_text()) == [want]
+    kv = chip((N_PAGES, H, D, PAGE), jnp.float32)
+    lowered = jax.jit(lambda q, k, v, pos, pt: flash_decode_paged(
+        q, k, v, pos, pt, interpret=False)).lower(
+            q, kv, kv, pos, chip((ROWS, T // PAGE), jnp.int32))
+    assert kernel_grids(lowered.as_text()) == [(ROWS,)]
 
 
 @pytest.mark.parametrize("shape", [(50257, 1024), (1024, 4096), (1024,)],
@@ -403,13 +392,13 @@ def test_olmoe_loss_over_data_mesh_compiles(topo, monkeypatch, program):
         assert f"%{name}" in text or f" {name}" in text, name
 
 
-@pytest.mark.parametrize("layout", ["ring", "paged"])
-def test_tp_sharded_flash_decode_compiles(topo, monkeypatch, layout):
+def test_tp_sharded_flash_decode_compiles(topo, monkeypatch):
     """The serving engine's TP path: `inference/cache.py` runs the decode
     kernel under `shard_map` over the `model` axis (4 chips, 4 heads
     each)."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+    from deepspeed_tpu.analysis.hlo import payload_shaped_copies
     from deepspeed_tpu.inference import cache
 
     _compiled_not_interpreted(monkeypatch,
@@ -422,29 +411,18 @@ def test_tp_sharded_flash_decode_compiles(topo, monkeypatch, layout):
 
     q = on((B, 1, H, D), jnp.bfloat16, None, None, "model")
     positions = on((B, 1), jnp.int32)
-    if layout == "ring":
-        kv = on((B, T, H, D), jnp.bfloat16, None, None, "model")
+    # the whole layer: GSPMD partitions the write over the pool's
+    # head axis, the kernel runs under `shard_map`
+    n_pages = B * (T // PAGE) + 1
+    kv = on((n_pages, H, D, PAGE), jnp.bfloat16, None, "model")
+    tables = on((B, T // PAGE), jnp.int32)
 
-        def fn(q, k, v, positions):
-            return cache._flash_attend(q, {"k": k, "v": v}, positions,
-                                       128, mesh)
-        args = (q, kv, kv, positions)
-    else:
-        # the whole layer: GSPMD partitions the write over the pool's
-        # head axis, the kernel runs under `shard_map`
-        n_pages = B * (T // PAGE) + 1
-        kv = on((n_pages, H, D, PAGE), jnp.bfloat16, None, "model")
-        tables = on((B, T // PAGE), jnp.int32)
-
-        def fn(q, k, v, positions, tables):
-            return cache.cached_attention(
-                q, q, q, {"k": k, "v": v}, positions, jnp.bfloat16,
-                impl="flash", block_k=128, mesh=mesh, page_table=tables)
-        args = (q, kv, kv, positions, tables)
+    def fn(q, k, v, positions, tables):
+        return cache.cached_attention(
+            q, q, q, {"k": k, "v": v}, positions, jnp.bfloat16,
+            impl="flash", block_k=128, mesh=mesh, page_table=tables)
     text = jax.jit(fn, donate_argnums=(1, 2)).lower(
-        *args).compile().as_text()
+        q, kv, kv, positions, tables).compile().as_text()
     assert "tpu_custom_call" in text
-    if layout == "paged":
-        from deepspeed_tpu.analysis.hlo import payload_shaped_copies
-        assert payload_shaped_copies(text, (n_pages, H // 4, D, PAGE)) == []
-        assert "all-gather" not in text and "all-to-all" not in text
+    assert payload_shaped_copies(text, (n_pages, H // 4, D, PAGE)) == []
+    assert "all-gather" not in text and "all-to-all" not in text
