@@ -1032,8 +1032,8 @@ def audit_disagg(rules=None, config_overrides=None):
     return report
 
 
-def audit_flash_train(rules=None, batch=1, seq=128, n_head=2,
-                      head_dim=128, block_q=64, block_k=64):
+def audit_flash_train(rules=None, batch=1, seq=256, n_head=2,
+                      head_dim=128, block_q=128, block_k=128):
     """Audit the training flash-attention kernels (forward + both
     backward passes) with the sub-``pallas_call`` analyzer.
 
@@ -1047,7 +1047,9 @@ def audit_flash_train(rules=None, batch=1, seq=128, n_head=2,
     constant in the innermost grid dim (the carried-accumulator idiom,
     not a grid-write race).
     """
-    from deepspeed_tpu.analysis.kernels import analyze_kernels
+    from deepspeed_tpu.analysis.kernels import (
+        analyze_kernels, causal_dead_tile_fraction,
+        causal_rectangle_dead_fraction)
     from deepspeed_tpu.ops.pallas import flash_attention
 
     t0 = time.perf_counter()
@@ -1062,13 +1064,22 @@ def audit_flash_train(rules=None, batch=1, seq=128, n_head=2,
 
     fn = jax.value_and_grad(loss, argnums=(0, 1, 2))
     ana = analyze_kernels(fn, (q, k, v))
+    # the elision contract of a causal call: the tiles the mask kills
+    # whole are no part of the walk
+    expected = causal_rectangle_dead_fraction(
+        seq // block_q, seq // block_k, block_q, block_k)
     ctx = StepContext(hlo_text="", flavor="flash_train",
                       kernel_analysis=ana,
+                      kernel_expected_elision=expected,
                       skip_rules={"recompile"})
     findings = run_rules(ctx, set(rules) if rules is not None
                          else set(KERNEL_RULES))
     report = AuditReport(flavor="flash_train", findings=findings)
     report.stats = {"kernels": ana.to_dict(),
+                    "expected_elision": expected,
+                    "dead_tile_fraction": {
+                        k.name: causal_dead_tile_fraction(k)
+                        for k in ana.kernels},
                     "geometry": {"batch": batch, "seq": seq,
                                  "n_head": n_head, "head_dim": head_dim,
                                  "block_q": block_q, "block_k": block_k},

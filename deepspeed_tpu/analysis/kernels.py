@@ -121,6 +121,13 @@ class KernelFacts:
     races: list                  # [{operand, block, steps}]
     tiling: list                 # [{operand, axis, block_dim, ...}]
     notes: list
+    # a flash-attention kernel's walk over its (q tile, kv tile)
+    # rectangle (:data:`TILE_WALKS`): the tile of every grid step
+    # ``[n_points, 2]``, the (block_q, block_k) a tile spans, and the
+    # rectangle's tiles over all rows and lane groups
+    tile_steps: Optional[np.ndarray] = None
+    tile_blocks: tuple = ()
+    tile_rectangle: int = 0
 
     @property
     def elided_fraction(self):
@@ -504,6 +511,29 @@ def _paged_decode_walk(scalar_vals, blocks, scratch):
 # fetch for given scalar-prefetch values
 MANUAL_WALKS = {"ds_flash_decode_paged": _paged_decode_walk}
 
+# kernels whose grid walks a (q tile, kv tile) rectangle, by pallas_call
+# name (`ops/pallas/flash_attention.py`): the operands (q, k), both
+# `[rows, seq, lanes]` blocks, whose index maps say which tile a grid
+# step takes
+TILE_WALKS = {"ds_flash_fwd": (0, 1), "ds_flash_dq": (0, 1),
+              "ds_flash_dkv": (0, 1)}
+
+
+def _tile_walk_facts(name, blocks, visited):
+    """``(tile_steps, tile_blocks, tile_rectangle)`` of a kernel in
+    :data:`TILE_WALKS`, from its q and k operands' evaluated index
+    maps; Nones where either was not evaluated."""
+    q, k = TILE_WALKS[name]
+    if q not in visited or k not in visited:
+        return None, (), 0
+    steps = np.stack([visited[q][:, -2], visited[k][:, -2]], axis=1)
+    spans = tuple(_block_dims(blocks[i].block_shape)[-2] for i in (q, k))
+    n_q, n_k = (blocks[i].array_shape[-2] // span
+                for i, span in zip((q, k), spans))
+    lanes = {(int(a), int(b)) for a, b in zip(visited[q][:, 0],
+                                              visited[q][:, -1])}
+    return steps, spans, len(lanes) * n_q * n_k
+
 
 def _eval_index_map(index_map, grid, scalar_vals, rank):
     """Block index tuples over the full grid, in Pallas's iteration
@@ -583,6 +613,7 @@ def kernel_facts(eqn, invals=None, grid_point_cap=DEFAULT_GRID_POINT_CAP):
     block_bytes_total = dense_total = dma_total = 0
     blocks = [_block_of(bm) for bm in gm.block_mappings]
     walk = {}
+    visited = {}                 # operand index -> block of every step
     if any(b.in_hbm for b in blocks):
         declared = MANUAL_WALKS.get(name)
         if declared is None:
@@ -626,13 +657,13 @@ def kernel_facts(eqn, invals=None, grid_point_cap=DEFAULT_GRID_POINT_CAP):
         evaluated = False
         if sweep and block.index_map is not None and scalar_vals is not None:
             try:
-                blocks = _eval_index_map(
+                visited[i] = _eval_index_map(
                     block.index_map, grid, scalar_vals,
                     len(block.block_shape))
-                distinct, dma = _fetch_stats(blocks)
+                distinct, dma = _fetch_stats(visited[i])
                 evaluated = True
                 if kind == "output":
-                    for rec in _race_scan(blocks):
+                    for rec in _race_scan(visited[i]):
                         rec["operand"] = opname
                         races.append(rec)
             except Exception as exc:
@@ -655,12 +686,15 @@ def kernel_facts(eqn, invals=None, grid_point_cap=DEFAULT_GRID_POINT_CAP):
             index_map_evaluated=evaluated))
 
     scratch = _scratch_bytes(eqn)
+    steps, spans, rectangle = (_tile_walk_facts(name, blocks, visited)
+                               if name in TILE_WALKS else (None, (), 0))
     return KernelFacts(
         name=name, grid=grid, operands=operands, scratch_bytes=scratch,
         block_bytes_per_step=block_bytes_total,
         vmem_bytes=DOUBLE_BUFFER * block_bytes_total + scratch,
         dense_bytes=dense_total, dma_bytes=dma_total,
-        races=races, tiling=tiling, notes=notes)
+        races=races, tiling=tiling, notes=notes,
+        tile_steps=steps, tile_blocks=spans, tile_rectangle=rectangle)
 
 
 def _tiling_lint_block(bdims, adims, dtype):
@@ -719,3 +753,26 @@ def paged_dead_block_fraction(positions, page_tables, page_size, block_k):
         return 0.0
     live, _ = paged_grid_blocks(positions, tables, block_k)
     return 1.0 - live / dense
+
+
+def causal_dead_tile_fraction(kernel):
+    """Of the (q tile, kv tile) pairs a causal flash-attention kernel's
+    grid visits, the fraction the mask kills whole (every key after
+    every query), read from the grid and the index maps
+    (:class:`KernelFacts` ``tile_steps``). A grid over the whole
+    rectangle fetches K and V for each and skips only the arithmetic:
+    0.25 at 2 x 2 tiles, 0.4375 at 8 x 8. A kernel that walks the live
+    tiles from a table visits none. ``None`` for a kernel that walks no
+    tiles or whose maps were not evaluated."""
+    if kernel.tile_steps is None or not len(kernel.tile_steps):
+        return None
+    qi, ki = kernel.tile_steps[:, 0], kernel.tile_steps[:, 1]
+    block_q, block_k = kernel.tile_blocks
+    return float(np.mean(ki * block_k > qi * block_q + block_q - 1))
+
+
+def causal_rectangle_dead_fraction(n_q, n_k, block_q, block_k):
+    """What a causal kernel must not visit: the wholly masked tiles'
+    share of the ``n_q x n_k`` rectangle."""
+    qi, ki = np.meshgrid(np.arange(n_q), np.arange(n_k), indexing="ij")
+    return float(np.mean(ki * block_k > qi * block_q + block_q - 1))

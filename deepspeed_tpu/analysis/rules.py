@@ -1236,6 +1236,14 @@ def rule_kernel_dma(ctx):
         unevaluated = []
         for k in ana.kernels:
             inputs = [op for op in k.operands if op.kind == "input"]
+            if k.tile_steps is not None:
+                # a kernel that walks (q tile, kv tile) pairs is judged
+                # on the tiles it visits against the rectangle's: what
+                # the causal mask kills should not be stepped over, let
+                # alone fetched
+                in_dma += len(k.tile_steps) * k.block_bytes_per_step
+                in_dense += k.tile_rectangle * k.block_bytes_per_step
+                continue
             # a kernel that walks the cache by manual DMA is judged on
             # what it walks: its pipelined inputs are one row block a
             # grid step, with nothing to elide

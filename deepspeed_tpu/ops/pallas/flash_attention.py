@@ -24,6 +24,7 @@ path (or pallas in interpreter mode when explicitly requested).
 import contextlib
 import contextvars
 import functools
+import typing
 
 import numpy as np
 
@@ -259,9 +260,14 @@ def _blockwise_attention(q, k, v, causal, sm_scale, block_k=256,
 # Pallas TPU kernels (forward + FlashAttention-2-style backward)
 # ---------------------------------------------------------------------------
 
+LANES = 128
+
+
 def _to_bh(x):
-    """[B, T, H, D] → [B*H, T, D]: heads fold into the grid's leading dim so
-    block shapes end in (seq_tile, D) — the TPU-tileable layout."""
+    """[B, T, H, D] → [B*H, T, D]: heads fold into the leading dim. The
+    flash kernels need it only for a head size that neither divides the
+    128 lanes nor is a multiple of them (:func:`_lane_groups`); the
+    block-sparse kernels still take this form."""
     B, T, H, D = x.shape
     return x.transpose(0, 2, 1, 3).reshape(B * H, T, D)
 
@@ -271,169 +277,426 @@ def _from_bh(x, B, H):
     return x.reshape(B, H, T, D).transpose(0, 2, 1, 3)
 
 
-# Per-row scalars (lse, delta) live in HBM as [B*H, T, 1] — compact, not
-# lane-broadcast. A (1, block_q, 1) block DMAs block_q contiguous words and
-# lands in VMEM as a [block_q, 1] sublane vector, which broadcasts over the
-# [block_q, block_k] score tile for free (the same m[:, None] pattern the
-# forward's scratch uses). The official jax flash kernel instead broadcasts
-# these across all 128 lanes in HBM ([.., T, 128] fp32) — 128x the bytes,
-# re-streamed on every q-step of the dK/dV grid; at long sequence lengths
-# that stream dwarfs the q/k/v traffic itself.
+def _lane_groups(H, D):
+    """How the heads of a ``[B, T, H*D]`` activation lie on the lanes:
+    ``(width, heads, groups)``: a block is ``width`` lanes wide and
+    holds ``heads`` heads, and ``groups`` of them cover the row.
+
+    - ``D`` a multiple of 128 (OLMoE's 128): one head a block.
+    - ``D`` divides 128 (GPT-2's 64): ``128 // D`` heads a block, cut
+      apart by lanes inside the kernel; an odd head count (GPT-2 XL's
+      25) leaves the last block part full, and the kernel skips the
+      heads that are not there. A row narrower than 128 lanes (a
+      tensor-parallel head shard) is one block of all its heads.
+    - anything else: ``None``; that shape runs folded to
+      ``[B*H, T, D]``, one head a row."""
+    if D % LANES == 0:
+        return D, 1, H
+    if LANES % D == 0:
+        if H * D <= LANES:
+            return H * D, H, 1
+        heads = LANES // D
+        return LANES, heads, -(-H // heads)
+    return None
+
+
+def _rows(x, fold):
+    """``[B, T, H, D]`` as the kernels take it: ``[B, T, H*D]`` as it
+    lies, or folded to ``[B*H, T, D]`` (:func:`_lane_groups`)."""
+    if fold:
+        return _to_bh(x)
+    B, T, H, D = x.shape
+    return x.reshape(B, T, H * D)
+
+
+def _seed_and_offset(dropout_seed, dropout_head_offset):
+    off = 0 if dropout_head_offset is None else dropout_head_offset
+    return jnp.stack([jnp.asarray(dropout_seed, jnp.int32).reshape(()),
+                      jnp.asarray(off, jnp.int32).reshape(())])
+
+
+class _Layout(typing.NamedTuple):
+    """How one attention call is cut up: the lane grouping of its heads
+    (:func:`_lane_groups`) and its blocks."""
+    fold: bool          # heads folded into the rows: [B*H, T, D]
+    width: int          # lanes of a block
+    heads: int          # heads in a block
+    groups: int         # blocks across a row's lanes
+    per_row: int        # rows a batch row makes (H folded, else 1)
+    row_heads: int      # heads a row holds (1 folded, else H)
+    block_q: int
+    block_k: int
+
+    @classmethod
+    def of(cls, q_shape, S, block_q, block_k):
+        _, T, H, D = q_shape
+        block_q, block_k = min(block_q, T), min(block_k, S)
+        assert T % block_q == 0 and S % block_k == 0, (
+            f"seq lens ({T},{S}) must divide blocks ({block_q},{block_k})")
+        groups = _lane_groups(H, D)
+        fold = groups is None
+        width, heads, n = (D, 1, 1) if fold else groups
+        return cls(fold, width, heads, n, H if fold else 1,
+                   1 if fold else H, block_q, block_k)
+
+
+# a tile of 1024 x 1024 scores in float32 is 4 MiB, and a kernel holds a
+# few such values at once (scores, probabilities, the mask's positions,
+# dropout's hash): more than Mosaic's default 16 MiB of scoped VMEM, well
+# inside the 128 MiB a v5e core has
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+
+# what a tile is to the causal mask (`_tile_walk`'s fifth table)
+UNDER, CROSSING, ON_DIAGONAL = 0, 1, 2
+
+
+def _tile_walk(n_q, n_k, block_q, block_k, causal, kv_major):
+    """The tiles a kernel visits, in order, as five int32 tables it
+    reads by grid step (scalar prefetch): q tile, kv tile, whether the
+    step is the first / the last of its run (a q tile's kv tiles for the
+    forward and dQ; a kv tile's q tiles, ``kv_major``, for dK/dV), and
+    what the tile is to the causal mask: ``UNDER`` the diagonal (or not
+    causal: nothing to mask), ``ON_DIAGONAL`` (square, its corner on the
+    diagonal, in a walk short enough for it to matter: what lies over
+    the diagonal is known dead, :func:`_pieces`), or ``CROSSING`` it in
+    some other way (all of it computed, and masked). Causal: only tiles
+    that hold a live score, so a dead tile is neither fetched nor
+    stepped over. Built from the shapes with array operations: the
+    kernel body is the same whatever the count."""
+    qi, ki = np.meshgrid(np.arange(n_q), np.arange(n_k), indexing="ij")
+    live = np.ones((n_q, n_k), bool)
+    kind = np.full((n_q, n_k), UNDER)
+    if causal:
+        live = ki * block_k <= qi * block_q + block_q - 1
+        kind[ki * block_k + block_k - 1 > qi * block_q] = CROSSING
+        # strips pay where the diagonal's tiles are at least half of the
+        # walk (T up to three tiles a side); a longer walk is mostly
+        # tiles under the diagonal, and takes the few on it whole rather
+        # than trace the strips' code in every process
+        if block_q == block_k and 2 * min(n_q, n_k) >= live.sum():
+            kind[qi == ki] = ON_DIAGONAL
+        # a kv tile past the last query (S > T) still has its zero
+        # gradient to write: the mask empties it
+        live[-1, :] |= kv_major
+    if kv_major:
+        ki, qi, live, kind = ki.T, qi.T, live.T, kind.T
+    major = (ki if kv_major else qi)[live]
+    change = np.flatnonzero(np.diff(major)) + 1
+    first = np.zeros(major.size, bool)
+    first[np.r_[0, change]] = True
+    last = np.zeros(major.size, bool)
+    last[np.r_[change - 1, major.size - 1]] = True
+    return [np.asarray(t, np.int32)
+            for t in (qi[live], ki[live], first, last, kind[live])]
+
+
+# strips a tile on the diagonal is cut in, by kernel (measured on the
+# chip, `PERF.md` section 6, PR 30: each further strip leaves more dead
+# scores out and costs a fixed piece of work; the forward, which turns
+# its row statistics round for every piece, gains nothing past two)
+_STRIPS = {"fwd": 2, "dq": 4, "dkv": 8}
+
+
+def _strips(kernel, block):
+    """Strips for ``kernel`` in a square tile of ``block``: its count,
+    halved until a strip is whole (16, 128)-tiles of bf16 rows."""
+    n = _STRIPS[kernel]
+    while n > 1 and block % (16 * n):
+        n //= 2
+    return n
+
+
+def _pieces(kind, lay, kernel):
+    """The rectangles ``(rows, columns, masked)`` of a tile that a
+    kernel computes, as static slices of its blocks. A tile under the
+    diagonal is one unmasked piece, one crossing it one masked piece.
+    A tile on the diagonal is a staircase of strips, each up to the
+    diagonal's end in it and no further: strips of rows for the forward
+    and dQ (each row's statistics and gradient see all their columns in
+    one piece), strips of columns for dK/dV."""
+    rows, cols = slice(0, lay.block_q), slice(0, lay.block_k)
+    if kind != ON_DIAGONAL:
+        return [(rows, cols, kind == CROSSING)]
+    n = _strips(kernel, lay.block_q)
+    step = lay.block_q // n
+    if kernel == "dkv":
+        return [(slice(i * step, lay.block_q),
+                 slice(i * step, (i + 1) * step), True) for i in range(n)]
+    return [(slice(i * step, (i + 1) * step),
+             slice(0, (i + 1) * step), True) for i in range(n)]
+
+
+def _for_each_piece(pl, kind, tables, lay, kernel, tile):
+    """Run ``tile(rows, cols, masked)`` over the pieces of this step's
+    tile, whichever kind it is: one copy of the code for each kind the
+    walk holds (three at most, of `_STRIPS` pieces at most: counts the
+    blocks fix, not the sequence length)."""
+    present = sorted(set(tables[4].tolist()))
+    for each in present:
+        def run(each=each):
+            for piece in _pieces(each, lay, kernel):
+                tile(*piece)
+        if len(present) == 1:
+            run()
+        else:
+            pl.when(kind == each)(run)
+
+
+def _positions(qi, ki, rows, cols, lay, keys_first=False):
+    """Absolute (query, key) positions of a piece's scores, laid out
+    ``[queries, keys]``, or ``[keys, queries]`` with ``keys_first``."""
+    shape = (rows.stop - rows.start, cols.stop - cols.start)
+    q_axis, k_axis = (1, 0) if keys_first else (0, 1)
+    if keys_first:
+        shape = shape[::-1]
+    return (qi * lay.block_q + rows.start
+            + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis),
+            ki * lay.block_k + cols.start
+            + jax.lax.broadcasted_iota(jnp.int32, shape, k_axis))
+
+
+def _grid_step(pl, qi_tab, ki_tab, first_tab, last_tab, kind_tab):
+    """This grid step, from the walk's tables: ``(row, lane group, q
+    tile, kv tile, kind of tile, first of its run, last of its run)``.
+    Read at the kernel's top: ``pl.program_id`` inside a ``pl.when`` body
+    breaks the interpret-mode lowering."""
+    t = pl.program_id(2)
+    return (pl.program_id(0), pl.program_id(1), qi_tab[t], ki_tab[t],
+            kind_tab[t], first_tab[t] == 1, last_tab[t] == 1)
+
+
+def _dropout_piece(seed_ref, row, head, lay, num_heads, positions, rate):
+    """A piece's dropout multiplier at the GLOBAL head coordinate
+    b * Hg + offset + h (``seed_ref``: seed, offset): with the defaults
+    exactly the folded b * H + h, and for a tensor-parallel head shard
+    the replicated run's; the same in all three kernels, so the
+    backward regenerates the forward's mask."""
+    g_head = ((row // lay.per_row) * num_heads + seed_ref[1]
+              + row % lay.per_row + head)
+    return dropout_multiplier(seed_ref[0], g_head, *positions, rate)
+
+
+def _each_head(pl, head, group, lay):
+    """Run ``head(j)`` for the heads of lane group ``group``. Where the
+    heads do not fill the last group (25 heads, two a group), the ones
+    past the row's end are skipped: their lanes lie outside the array,
+    hold whatever the copy left there, and are never written back."""
+    for j in range(lay.heads):
+        if lay.row_heads % lay.heads:
+            pl.when(group * lay.heads + j < lay.row_heads)(
+                functools.partial(head, j))
+        else:
+            head(j)
+
+
+def _walk_specs(pl, lay, masked):
+    """Block specs on the tile walk's tables (:func:`_tile_walk`), which
+    every index map is handed after the grid indices (row, lane group,
+    step): a q-side and a kv-side ``[rows, seq, lanes]`` block, a q-side
+    and a kv-side per-head scalar block (``q_row``: the q-side one of the
+    ``[rows, heads, 1, T]`` view, a row vector), and the key bias's."""
+    def q_map(r, g, t, qi_tab, ki_tab, *_):
+        return (r, qi_tab[t], g)
+
+    def k_map(r, g, t, qi_tab, ki_tab, *_):
+        return (r, ki_tab[t], g)
+
+    def q_scalar_map(r, g, t, qi_tab, ki_tab, *_):
+        return (r, g, qi_tab[t], 0)
+
+    def k_scalar_map(r, g, t, qi_tab, ki_tab, *_):
+        return (r, g, ki_tab[t], 0)
+
+    def bias_map(r, g, t, qi_tab, ki_tab, *_):
+        return (r // lay.per_row, ki_tab[t], 0)
+
+    def q_row_map(r, g, t, qi_tab, ki_tab, *_):
+        return (r, g, 0, qi_tab[t])
+
+    return dict(
+        q_row=pl.BlockSpec((1, lay.heads, 1, lay.block_q), q_row_map),
+        q=pl.BlockSpec((1, lay.block_q, lay.width), q_map),
+        k=pl.BlockSpec((1, lay.block_k, lay.width), k_map),
+        q_scalar=pl.BlockSpec((1, lay.heads, lay.block_q, 1), q_scalar_map),
+        k_scalar=pl.BlockSpec((1, lay.heads, lay.block_k, 1), k_scalar_map),
+        bias=pl.BlockSpec((1, lay.block_k, 1), bias_map) if masked else None)
+
+
+
+
+# Per-row scalars (lse, delta) live in HBM as [rows, heads, T, 1] —
+# compact, not lane-broadcast. A (1, heads, block_q, 1) block DMAs block_q
+# contiguous words a head and lands in VMEM as [block_q, 1] sublane
+# vectors, which broadcast over the [block_q, block_k] score tile for free
+# (the same m[:, None] pattern the forward's scratch uses). The official
+# jax flash kernel instead broadcasts these across all 128 lanes in HBM
+# ([.., T, 128] fp32) — 128x the bytes, re-streamed on every q-step of the
+# dK/dV grid; at long sequence lengths that stream dwarfs the q/k/v
+# traffic itself.
+# jitted, so that a model's layers share one trace and one lowering of a
+# kernel: lowering a `pallas_call` to Mosaic text is done anew in every
+# process, before any compile cache can help, once for each equation that
+# is not the same jitted call (24 layers x three kernels otherwise)
+_KERNEL_STATICS = ("causal", "sm_scale", "block_q", "block_k", "interpret",
+                   "dropout_rate", "dropout_num_heads")
+
+
+@functools.partial(jax.jit, static_argnames=_KERNEL_STATICS)
 def _pallas_fwd(q, k, v, causal, sm_scale, block_q, block_k,
                 interpret=False, key_bias=None,
                 dropout_rate=0.0, dropout_seed=None,
                 dropout_head_offset=None, dropout_num_heads=None):
-    """Returns (out [B,T,H,D], lse [B*H,T,1]) — lse is the softmax row
-    logsumexp residual consumed by the backward kernels.
+    """Returns (out [B,T,H,D], lse [rows,heads,T,1]) — lse is the softmax
+    row logsumexp residual consumed by the backward kernels.
+
+    q, k, v are read as ``[B, T, H*D]``, as the projections wrote them:
+    a grid step takes one 128-lane group of heads (:func:`_lane_groups`)
+    of one q tile and one kv tile, and walks the live tiles only
+    (:func:`_tile_walk`).
     ``key_bias`` [B, S] additive fp32 rides as a [B, S, 1] array indexed
-    per batch (bh // H). ``dropout_rate`` (static) / ``dropout_seed``
+    per batch row. ``dropout_rate`` (static) / ``dropout_seed``
     (int32 scalar, SMEM): in-kernel attention-prob dropout — applied to
     the accumulated probs while ``l`` keeps summing the undropped probs
     (softmax normalizes before dropout zeroes).
     ``dropout_head_offset`` (traced int32, rides in SMEM beside the
     seed) / ``dropout_num_heads`` (static): mask coordinates use the
-    GLOBAL head index off + bh%H (+ b*Hg) so a tensor-parallel head
+    GLOBAL head index off + h (+ b*Hg) so a tensor-parallel head
     shard reproduces the replicated run's mask bitwise; the defaults
-    reduce to the plain folded bh."""
+    reduce to the plain folded b*H + h."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, T, H, D = q.shape
     S = k.shape[1]
     Hg = H if dropout_num_heads is None else int(dropout_num_heads)
-    block_q = min(block_q, T)
-    block_k = min(block_k, S)
-    assert T % block_q == 0 and S % block_k == 0, (
-        f"seq lens ({T},{S}) must divide blocks ({block_q},{block_k})")
-    n_q = T // block_q
-    n_k = S // block_k
     masked = key_bias is not None
     dropping = dropout_rate > 0.0
+    lay = _Layout.of(q.shape, S, block_q, block_k)
+    block_q, block_k = lay.block_q, lay.block_k
+    q, k, v = (_rows(x, lay.fold) for x in (q, k, v))
+    R = q.shape[0]
+    tables = _tile_walk(T // block_q, S // block_k, block_q, block_k,
+                        causal, kv_major=False)
 
-    q, k, v = _to_bh(q), _to_bh(k), _to_bh(v)
-    kpm = None
-    if masked:
-        kpm = key_bias.astype(jnp.float32)[..., None]        # [B, S, 1]
-
-    def kernel(q_ref, k_ref, v_ref, *refs):
-        refs = list(refs)
+    def kernel(*refs):
+        r, group, qi, ki, kind, first, last = _grid_step(pl, *refs[:5])
+        q_ref, k_ref, v_ref, *refs = refs[5:]
         kpm_ref = refs.pop(0) if masked else None
         seed_ref = refs.pop(0) if dropping else None
         o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
-        bh = pl.program_id(0)
-        qi = pl.program_id(1)
-        ki = pl.program_id(2)
 
-        @pl.when(ki == 0)
-        def _init():
-            acc_ref[:] = jnp.zeros_like(acc_ref)
-            m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
-            l_ref[:] = jnp.zeros_like(l_ref)
+        def head(j):
+            lanes = slice(j * D, (j + 1) * D)
 
-        run = True
-        if causal:
-            # Skip fully-masked tiles above the diagonal.
-            run = (ki * block_k) <= (qi * block_q + block_q - 1)
+            @pl.when(first)
+            def _init():
+                acc_ref[j] = jnp.zeros((block_q, D), jnp.float32)
+                m_ref[j] = jnp.full((block_q, 1), -jnp.inf, jnp.float32)
+                l_ref[j] = jnp.zeros((block_q, 1), jnp.float32)
 
-        @pl.when(run if causal else True)
-        def _compute():
-            qb = q_ref[0].astype(jnp.float32) * sm_scale   # [bq, D]
-            kb = k_ref[0].astype(jnp.float32)              # [bk, D]
-            s = jax.lax.dot_general(
-                qb, kb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)        # [bq, bk]
-            if causal or dropping:
-                q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 0)
-                k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 1)
-            if causal:
-                s = jnp.where(k_pos <= q_pos, s, DEFAULT_MASK_VALUE)
-            if masked:
-                # [bk, 1] sublane vector → additive row bias over lanes
-                s = s + kpm_ref[0][:, 0][None, :]
-            m_prev = m_ref[:, 0]
-            m_new = jnp.maximum(m_prev, s.max(axis=-1))
-            p = jnp.exp(s - m_new[:, None])
-            corr = jnp.exp(m_prev - m_new)
-            l_ref[:, 0] = l_ref[:, 0] * corr + p.sum(axis=-1)
-            m_ref[:, 0] = m_new
-            pd = p
-            if dropping:
-                # Global head coordinate: bh%H local head + SMEM offset
-                # (+ batch stride Hg). Defaults make this exactly bh.
-                g_head = bh + (bh // H) * (Hg - H) + seed_ref[1]
-                pd = p * dropout_multiplier(
-                    seed_ref[0], g_head, q_pos, k_pos, dropout_rate)
-            vb = v_ref[0].astype(jnp.float32)              # [bk, D]
-            acc_ref[:] = acc_ref[:] * corr[:, None] + jax.lax.dot_general(
-                pd, vb, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            def tile(rows, cols, under_mask):
+                qb = q_ref[0, rows, lanes].astype(jnp.float32) * sm_scale
+                kb = k_ref[0, cols, lanes].astype(jnp.float32)  # [bk, D]
+                s = jax.lax.dot_general(
+                    qb, kb, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)        # [bq, bk]
+                if under_mask or dropping:
+                    q_pos, k_pos = _positions(qi, ki, rows, cols, lay)
+                if under_mask:
+                    s = jnp.where(k_pos <= q_pos, s, DEFAULT_MASK_VALUE)
+                if masked:
+                    # [bk, 1] sublane vector → additive row bias over
+                    # lanes
+                    s = s + kpm_ref[0, cols, :][:, 0][None, :]
+                m_prev = m_ref[j, rows, 0]
+                m_new = jnp.maximum(m_prev, s.max(axis=-1))
+                p = jnp.exp(s - m_new[:, None])
+                corr = jnp.exp(m_prev - m_new)
+                l_ref[j, rows, 0] = l_ref[j, rows, 0] * corr + p.sum(axis=-1)
+                m_ref[j, rows, 0] = m_new
+                pd = p
+                if dropping:
+                    pd = p * _dropout_piece(
+                        seed_ref, r, group * lay.heads + j, lay, Hg,
+                        (q_pos, k_pos), dropout_rate)
+                vb = v_ref[0, cols, lanes].astype(jnp.float32)  # [bk, D]
+                acc_ref[j, rows, :] = (
+                    acc_ref[j, rows, :] * corr[:, None]
+                    + jax.lax.dot_general(
+                        pd, vb, (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32))
 
-        @pl.when(ki == n_k - 1)
-        def _finish():
-            # fully-masked rows: l == 0 → guard the divide (outputs for
-            # padded q positions are meaningless and masked downstream)
-            l_safe = jnp.maximum(l_ref[:, 0], 1e-30)
-            o_ref[0] = (acc_ref[:] / l_safe[:, None]).astype(o_ref.dtype)
-            lse_ref[0] = (m_ref[:, 0] + jnp.log(l_safe))[:, None]
+            _for_each_piece(pl, kind, tables, lay, "fwd", tile)
 
-    grid = (B * H, n_q, n_k)
-    in_specs = [
-        pl.BlockSpec((1, block_q, D), lambda bh, qi, ki: (bh, qi, 0)),
-        pl.BlockSpec((1, block_k, D), lambda bh, qi, ki: (bh, ki, 0)),
-        pl.BlockSpec((1, block_k, D), lambda bh, qi, ki: (bh, ki, 0)),
-    ]
+            @pl.when(last)
+            def _finish():
+                # fully-masked rows: l == 0 → guard the divide (outputs
+                # for padded q positions are meaningless and masked
+                # downstream)
+                l_safe = jnp.maximum(l_ref[j, :, 0], 1e-30)
+                o_ref[0, :, lanes] = (
+                    acc_ref[j] / l_safe[:, None]).astype(o_ref.dtype)
+                lse_ref[0, j] = (m_ref[j, :, 0] + jnp.log(l_safe))[:, None]
+
+        _each_head(pl, head, group, lay)
+
+    specs = _walk_specs(pl, lay, masked)
+    in_specs = [specs["q"], specs["k"], specs["k"]]
     args = [q, k, v]
     if masked:
-        in_specs.append(pl.BlockSpec(
-            (1, block_k, 1), lambda bh, qi, ki: (bh // H, ki, 0)))
-        args.append(kpm)
+        in_specs.append(specs["bias"])
+        args.append(key_bias.astype(jnp.float32)[..., None])   # [B, S, 1]
     if dropping:
         in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-        off = 0 if dropout_head_offset is None else dropout_head_offset
-        args.append(jnp.stack(
-            [jnp.asarray(dropout_seed, jnp.int32).reshape(()),
-             jnp.asarray(off, jnp.int32).reshape(())]))
+        args.append(_seed_and_offset(dropout_seed, dropout_head_offset))
     fwd = pl.pallas_call(
         kernel,
         name=FWD_NAME,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bh, qi, ki: (bh, qi, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(tables),
+            grid=(R, lay.groups, tables[0].size),
+            in_specs=in_specs,
+            out_specs=[specs["q"], specs["q_scalar"]],
+            scratch_shapes=[
+                pltpu.VMEM((lay.heads, block_q, D), jnp.float32),
+                pltpu.VMEM((lay.heads, block_q, 1), jnp.float32),
+                pltpu.VMEM((lay.heads, block_q, 1), jnp.float32),
+            ]),
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
-            jax.ShapeDtypeStruct((B * H, T, 1), jnp.float32),
+            jax.ShapeDtypeStruct((R, lay.row_heads, T, 1), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-        ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )
     with jax.named_scope(FWD_NAME):
-        out, lse = fwd(*args)
-    return _from_bh(out, B, H), lse
+        out, lse = fwd(*tables, *args)
+    out = _from_bh(out, B, H) if lay.fold else out.reshape(B, T, H, D)
+    return out, lse
 
 
+@functools.partial(jax.jit, static_argnames=_KERNEL_STATICS)
 def _pallas_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
                 interpret=False, key_bias=None,
                 dropout_rate=0.0, dropout_seed=None,
                 dropout_head_offset=None, dropout_num_heads=None):
-    """FlashAttention-2 backward. Two kernels:
+    """FlashAttention-2 backward. Two kernels, on the forward's layout
+    (``[B, T, H*D]`` as it lies, a lane group of heads a grid step, live
+    tiles only):
 
-    - dQ: grid (BH, n_q, n_k), accumulates dq over KV tiles in VMEM.
-    - dK/dV: grid (BH, n_k, n_q), accumulates dk, dv over Q tiles in VMEM.
+    - dQ: grid (rows, groups, tiles), q-major: accumulates dq over a q
+      tile's KV tiles in VMEM.
+    - dK/dV: the same tiles kv-major: accumulates dk, dv over a KV tile's
+      Q tiles in VMEM.
       When a key bias is present it also emits per-head dbias partials
       (column-sums of the pre-scale ds), reduced over heads in XLA — the
       true gradient of the additive bias.
 
-    delta = rowsum(dO ⊙ O) is precomputed in XLA (it is a cheap fused
-    elementwise+reduce); with dropout, rowsum(dP ⊙ P) still equals
+    delta = rowsum(dO ⊙ O) is computed by the dQ kernel at a q tile's
+    first step and handed on to dK/dV (on ``[B, T, H*D]`` XLA would
+    re-lay both factors out to reduce over a head's lanes); with
+    dropout, rowsum(dP ⊙ P) still equals
     rowsum(dO ⊙ O) because the mask multiplier appears in both factors'
     chain. Dropout masks are regenerated in-kernel from the same
     counter-based hash as the forward — nothing [T, S]-shaped is stored.
@@ -444,248 +707,233 @@ def _pallas_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
 
     B, T, H, D = q.shape
     S = k.shape[1]
-    block_q = min(block_q, T)
-    block_k = min(block_k, S)
-    n_q = T // block_q
-    n_k = S // block_k
-
     in_dtype = q.dtype
-    H = q.shape[2]
     Hg = H if dropout_num_heads is None else int(dropout_num_heads)
     masked = key_bias is not None
     dropping = dropout_rate > 0.0
-    kpm = key_bias.astype(jnp.float32)[..., None] if masked else None
-    seed_arr = None
+    lay = _Layout.of(q.shape, S, block_q, block_k)
+    block_q, block_k = lay.block_q, lay.block_k
+    n_q, n_k = T // block_q, S // block_k
+
+    qh, kh, vh, gh, oh = (_rows(x, lay.fold) for x in (q, k, v, g, out))
+    R = qh.shape[0]
+    specs = _walk_specs(pl, lay, masked)
+    # what both kernels take after their own six operands
+    extra_specs, extra_args = [], []
+    if masked:
+        extra_specs.append(specs["bias"])
+        extra_args.append(key_bias.astype(jnp.float32)[..., None])
     if dropping:
-        off = 0 if dropout_head_offset is None else dropout_head_offset
-        seed_arr = jnp.stack(
-            [jnp.asarray(dropout_seed, jnp.int32).reshape(()),
-             jnp.asarray(off, jnp.int32).reshape(())])
-    qh, kh, vh = _to_bh(q), _to_bh(k), _to_bh(v)
-    oh, gh = _to_bh(out), _to_bh(g)
-    delta = jnp.sum(gh.astype(jnp.float32) * oh.astype(jnp.float32),
-                    axis=-1, keepdims=True)                # [BH, T, 1]
+        extra_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+        extra_args.append(_seed_and_offset(dropout_seed,
+                                           dropout_head_offset))
 
-    def positions(qi, ki):
-        q_pos = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        k_pos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        return q_pos, k_pos
-
-    def scores(q_ref, k_ref, qi, ki, kpm_ref=None):
-        qb = q_ref[0].astype(jnp.float32)                  # [bq, D]
-        kb = k_ref[0].astype(jnp.float32)                  # [bk, D]
-        s = jax.lax.dot_general(
-            qb, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale  # [bq, bk]
-        if causal:
-            q_pos, k_pos = positions(qi, ki)
-            s = jnp.where(k_pos <= q_pos, s, DEFAULT_MASK_VALUE)
-        if kpm_ref is not None:
-            s = s + kpm_ref[0][:, 0][None, :]              # additive bias
-        return s
-
-    def drop_tile(seed_ref, bh, qi, ki):
-        # NB: bh is bound at kernel top — pl.program_id inside a pl.when
-        # body breaks the interpret-mode lowering. Head coordinate is
-        # globalized (TP head shard: off + bh%H, batch stride Hg) —
-        # identical to the forward's, so the regenerated mask matches.
-        q_pos, k_pos = positions(qi, ki)
-        g_head = bh + (bh // H) * (Hg - H) + seed_ref[1]
-        return dropout_multiplier(seed_ref[0], g_head, q_pos, k_pos,
-                                  dropout_rate)
-
-    def dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
-                  *refs):
+    def unpack(refs, n_out):
         refs = list(refs)
+        ins, refs = refs[:6], refs[6:]
         kpm_ref = refs.pop(0) if masked else None
         seed_ref = refs.pop(0) if dropping else None
-        dq_ref, dq_acc = refs
-        bh = pl.program_id(0)
-        qi = pl.program_id(1)
-        ki = pl.program_id(2)
+        return (*ins, kpm_ref, seed_ref), refs[:n_out], refs[n_out:]
 
-        @pl.when(ki == 0)
-        def _init():
-            dq_acc[:] = jnp.zeros_like(dq_acc)
+    def dq_kernel(*refs):
+        r, group, qi, ki, kind, first, last = _grid_step(pl, *refs[:5])
+        ins, (dq_ref, delta_ref), (dq_acc,) = unpack(refs[5:], 2)
+        q_ref, k_ref, v_ref, g_ref, lse_ref, o_ref, kpm_ref, seed_ref = ins
 
-        run = True
-        if causal:
-            run = (ki * block_k) <= (qi * block_q + block_q - 1)
+        def head(j):
+            lanes = slice(j * D, (j + 1) * D)
 
-        @pl.when(run if causal else True)
-        def _compute():
-            s = scores(q_ref, k_ref, qi, ki, kpm_ref)
-            lse = lse_ref[0][:, :1]                        # [bq, 1]
-            p = jnp.exp(s - lse)                           # [bq, bk]
-            gb = g_ref[0].astype(jnp.float32)              # [bq, D]
-            vb = v_ref[0].astype(jnp.float32)              # [bk, D]
-            dp = jax.lax.dot_general(
-                gb, vb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)        # [bq, bk]
-            if dropping:
-                dp = dp * drop_tile(seed_ref, bh, qi, ki)
-            ds = p * (dp - delta_ref[0][:, :1]) * sm_scale
-            kb = k_ref[0].astype(jnp.float32)
-            dq_acc[:] += jax.lax.dot_general(
-                ds, kb, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)        # [bq, D]
+            @pl.when(first)
+            def _init():
+                dq_acc[j] = jnp.zeros((block_q, D), jnp.float32)
+                # delta = rowsum(dO ⊙ O), once a q tile; the block stays
+                # where it is until the tile's last step, for this
+                # kernel to read and the dK/dV kernel after it
+                delta_ref[0, j] = jnp.sum(
+                    g_ref[0, :, lanes].astype(jnp.float32)
+                    * o_ref[0, :, lanes].astype(jnp.float32),
+                    axis=-1, keepdims=True)
 
-        @pl.when(ki == n_k - 1)
-        def _finish():
-            dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+            def tile(rows, cols, under_mask):
+                qb = q_ref[0, rows, lanes].astype(jnp.float32)  # [bq, D]
+                kb = k_ref[0, cols, lanes].astype(jnp.float32)  # [bk, D]
+                s = jax.lax.dot_general(
+                    qb, kb, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * sm_scale
+                if under_mask or dropping:
+                    q_pos, k_pos = _positions(qi, ki, rows, cols, lay)
+                if under_mask:
+                    s = jnp.where(k_pos <= q_pos, s, DEFAULT_MASK_VALUE)
+                if masked:
+                    s = s + kpm_ref[0, cols, :][:, 0][None, :]  # the bias
+                p = jnp.exp(s - lse_ref[0, j, rows, :])        # [bq, bk]
+                gb = g_ref[0, rows, lanes].astype(jnp.float32)  # [bq, D]
+                vb = v_ref[0, cols, lanes].astype(jnp.float32)  # [bk, D]
+                dp = jax.lax.dot_general(
+                    gb, vb, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)        # [bq, bk]
+                if dropping:
+                    dp = dp * _dropout_piece(
+                        seed_ref, r, group * lay.heads + j, lay, Hg,
+                        (q_pos, k_pos), dropout_rate)
+                ds = p * (dp - delta_ref[0, j, rows, :]) * sm_scale
+                dq_acc[j, rows, :] += jax.lax.dot_general(
+                    ds, kb, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)        # [bq, D]
 
-    dq_in_specs = [
-        pl.BlockSpec((1, block_q, D), lambda bh, qi, ki: (bh, qi, 0)),
-        pl.BlockSpec((1, block_k, D), lambda bh, qi, ki: (bh, ki, 0)),
-        pl.BlockSpec((1, block_k, D), lambda bh, qi, ki: (bh, ki, 0)),
-        pl.BlockSpec((1, block_q, D), lambda bh, qi, ki: (bh, qi, 0)),
-        pl.BlockSpec((1, block_q, 1), lambda bh, qi, ki: (bh, qi, 0)),
-        pl.BlockSpec((1, block_q, 1), lambda bh, qi, ki: (bh, qi, 0)),
-    ]
-    dq_args = [qh, kh, vh, gh, lse, delta]
-    if masked:
-        dq_in_specs.append(pl.BlockSpec(
-            (1, block_k, 1), lambda bh, qi, ki: (bh // H, ki, 0)))
-        dq_args.append(kpm)
-    if dropping:
-        dq_in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-        dq_args.append(seed_arr)
+            _for_each_piece(pl, kind, q_tables, lay, "dq", tile)
+
+            @pl.when(last)
+            def _finish():
+                dq_ref[0, :, lanes] = dq_acc[j].astype(dq_ref.dtype)
+
+        _each_head(pl, head, group, lay)
+
+    q_tables = _tile_walk(n_q, n_k, block_q, block_k, causal,
+                          kv_major=False)
     dq_call = pl.pallas_call(
         dq_kernel,
         name=DQ_NAME,
-        grid=(B * H, n_q, n_k),
-        in_specs=dq_in_specs,
-        out_specs=pl.BlockSpec((1, block_q, D),
-                               lambda bh, qi, ki: (bh, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct(qh.shape, in_dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(q_tables),
+            grid=(R, lay.groups, q_tables[0].size),
+            in_specs=[specs["q"], specs["k"], specs["k"], specs["q"],
+                      specs["q_scalar"], specs["q"]] + extra_specs,
+            out_specs=[specs["q"], specs["q_scalar"]],
+            scratch_shapes=[
+                pltpu.VMEM((lay.heads, block_q, D), jnp.float32)]),
+        out_shape=[
+            jax.ShapeDtypeStruct(qh.shape, in_dtype),
+            jax.ShapeDtypeStruct(lse.shape, jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )
     with jax.named_scope(DQ_NAME):
-        dq = dq_call(*dq_args)
+        dq, delta = dq_call(*q_tables, qh, kh, vh, gh, lse, oh, *extra_args)
 
-    def dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
-                   *refs):
-        refs = list(refs)
-        kpm_ref = refs.pop(0) if masked else None
-        seed_ref = refs.pop(0) if dropping else None
-        if masked:
-            dk_ref, dv_ref, dbias_ref, dk_acc, dv_acc, dbias_acc = refs
-        else:
-            dk_ref, dv_ref, dk_acc, dv_acc = refs
-            dbias_ref = dbias_acc = None
-        bh = pl.program_id(0)
-        ki = pl.program_id(1)
-        qi = pl.program_id(2)
+    def dkv_kernel(*refs):
+        r, group, qi, ki, kind, first, last = _grid_step(pl, *refs[:5])
+        ins, outs, accs = unpack(refs[5:], 3 if masked else 2)
+        q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, kpm_ref, seed_ref = \
+            ins
+        dk_acc, dv_acc = accs[:2]
 
-        @pl.when(qi == 0)
-        def _init():
-            dk_acc[:] = jnp.zeros_like(dk_acc)
-            dv_acc[:] = jnp.zeros_like(dv_acc)
-            if masked:
-                dbias_acc[:] = jnp.zeros_like(dbias_acc)
+        def head(j):
+            lanes = slice(j * D, (j + 1) * D)
 
-        run = True
-        if causal:
-            # Q tiles strictly above the diagonal see nothing of this KV tile.
-            run = (ki * block_k) <= (qi * block_q + block_q - 1)
+            @pl.when(first)
+            def _init():
+                for acc in accs:
+                    acc[j] = jnp.zeros(acc.shape[1:], jnp.float32)
 
-        @pl.when(run if causal else True)
-        def _compute():
-            s = scores(q_ref, k_ref, qi, ki, kpm_ref)
-            p = jnp.exp(s - lse_ref[0][:, :1])             # [bq, bk]
-            gb = g_ref[0].astype(jnp.float32)              # [bq, D]
-            if dropping:
-                mult = drop_tile(seed_ref, bh, qi, ki)
-                pd = p * mult
-            else:
-                pd = p
-            dv_acc[:] += jax.lax.dot_general(
-                pd, gb, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)        # [bk, D]
-            vb = v_ref[0].astype(jnp.float32)
-            dp = jax.lax.dot_general(
-                gb, vb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)        # [bq, bk]
-            if dropping:
-                dp = dp * mult
-            ds0 = p * (dp - delta_ref[0][:, :1])           # pre-scale ds
-            ds = ds0 * sm_scale
-            qb = q_ref[0].astype(jnp.float32)
-            dk_acc[:] += jax.lax.dot_general(
-                ds, qb, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)        # [bk, D]
-            if masked:
-                # d(bias_j) = Σ_t ds0[t, j] (bias is added after sm_scale)
-                dbias_acc[:, 0] += ds0.sum(axis=0)
+            def tile(rows, cols, under_mask):
+                # the tile keys-first, [bk, bq]: both gradients are then
+                # plain products of it, with nothing score-sized to turn
+                # round; lse and delta come as row vectors for it
+                qb = q_ref[0, rows, lanes].astype(jnp.float32)  # [bq, D]
+                kb = k_ref[0, cols, lanes].astype(jnp.float32)  # [bk, D]
+                s = jax.lax.dot_general(
+                    kb, qb, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * sm_scale
+                if under_mask or dropping:
+                    q_pos, k_pos = _positions(qi, ki, rows, cols, lay,
+                                              keys_first=True)
+                if under_mask:
+                    s = jnp.where(k_pos <= q_pos, s, DEFAULT_MASK_VALUE)
+                if masked:
+                    s = s + kpm_ref[0, cols, :]            # additive bias
+                p = jnp.exp(s - lse_ref[0, j, :, rows])        # [bk, bq]
+                gb = g_ref[0, rows, lanes].astype(jnp.float32)  # [bq, D]
+                if dropping:
+                    mult = _dropout_piece(
+                        seed_ref, r, group * lay.heads + j, lay, Hg,
+                        (q_pos, k_pos), dropout_rate)
+                    pd = p * mult
+                else:
+                    pd = p
+                dv_acc[j, cols, :] += jax.lax.dot_general(
+                    pd, gb, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)        # [bk, D]
+                vb = v_ref[0, cols, lanes].astype(jnp.float32)
+                dp = jax.lax.dot_general(
+                    vb, gb, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)        # [bk, bq]
+                if dropping:
+                    dp = dp * mult
+                ds0 = p * (dp - delta_ref[0, j, :, rows])      # pre-scale
+                ds = ds0 * sm_scale
+                dk_acc[j, cols, :] += jax.lax.dot_general(
+                    ds, qb, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)        # [bk, D]
+                if masked:
+                    # d(bias_j) = Σ_t ds0[j, t] (bias is added after
+                    # sm_scale)
+                    accs[2][j, cols, :] += ds0.sum(axis=1, keepdims=True)
 
-        @pl.when(qi == n_q - 1)
-        def _finish():
-            dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
-            dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
-            if masked:
-                dbias_ref[0] = dbias_acc[:]
+            _for_each_piece(pl, kind, k_tables, lay, "dkv", tile)
 
-    dkv_in_specs = [
-        pl.BlockSpec((1, block_q, D), lambda bh, ki, qi: (bh, qi, 0)),
-        pl.BlockSpec((1, block_k, D), lambda bh, ki, qi: (bh, ki, 0)),
-        pl.BlockSpec((1, block_k, D), lambda bh, ki, qi: (bh, ki, 0)),
-        pl.BlockSpec((1, block_q, D), lambda bh, ki, qi: (bh, qi, 0)),
-        pl.BlockSpec((1, block_q, 1), lambda bh, ki, qi: (bh, qi, 0)),
-        pl.BlockSpec((1, block_q, 1), lambda bh, ki, qi: (bh, qi, 0)),
-    ]
-    dkv_args = [qh, kh, vh, gh, lse, delta]
-    if masked:
-        dkv_in_specs.append(pl.BlockSpec(
-            (1, block_k, 1), lambda bh, ki, qi: (bh // H, ki, 0)))
-        dkv_args.append(kpm)
-    if dropping:
-        dkv_in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-        dkv_args.append(seed_arr)
-    dkv_out_specs = [
-        pl.BlockSpec((1, block_k, D), lambda bh, ki, qi: (bh, ki, 0)),
-        pl.BlockSpec((1, block_k, D), lambda bh, ki, qi: (bh, ki, 0)),
-    ]
+            @pl.when(last)
+            def _finish():
+                outs[0][0, :, lanes] = dk_acc[j].astype(in_dtype)
+                outs[1][0, :, lanes] = dv_acc[j].astype(in_dtype)
+                if masked:
+                    outs[2][0, j] = accs[2][j]
+
+        _each_head(pl, head, group, lay)
+
+    dkv_out_specs = [specs["k"], specs["k"]]
     dkv_out_shapes = [
         jax.ShapeDtypeStruct(kh.shape, in_dtype),
         jax.ShapeDtypeStruct(vh.shape, in_dtype),
     ]
     dkv_scratch = [
-        pltpu.VMEM((block_k, D), jnp.float32),
-        pltpu.VMEM((block_k, D), jnp.float32),
+        pltpu.VMEM((lay.heads, block_k, D), jnp.float32),
+        pltpu.VMEM((lay.heads, block_k, D), jnp.float32),
     ]
     if masked:
-        # Per-head dbias partials [BH, S, 1]: each (bh, ki) block is owned
-        # by one contiguous qi sweep, so no cross-head accumulation races;
-        # the cheap head reduction happens in XLA below.
-        dkv_out_specs.append(pl.BlockSpec(
-            (1, block_k, 1), lambda bh, ki, qi: (bh, ki, 0)))
+        # Per-head dbias partials [rows, heads, S, 1]: each (head, ki)
+        # block is owned by one contiguous qi sweep, so no cross-head
+        # accumulation races; the cheap head reduction happens in XLA
+        # below.
+        dkv_out_specs.append(specs["k_scalar"])
         dkv_out_shapes.append(
-            jax.ShapeDtypeStruct((B * H, S, 1), jnp.float32))
-        dkv_scratch.append(pltpu.VMEM((block_k, 1), jnp.float32))
+            jax.ShapeDtypeStruct((R, lay.row_heads, S, 1), jnp.float32))
+        dkv_scratch.append(pltpu.VMEM((lay.heads, block_k, 1), jnp.float32))
+    k_tables = _tile_walk(n_q, n_k, block_q, block_k, causal,
+                          kv_major=True)
     dkv_call = pl.pallas_call(
         dkv_kernel,
         name=DKV_NAME,
-        grid=(B * H, n_k, n_q),
-        in_specs=dkv_in_specs,
-        out_specs=dkv_out_specs,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(k_tables),
+            grid=(R, lay.groups, k_tables[0].size),
+            in_specs=[specs["q"], specs["k"], specs["k"], specs["q"],
+                      specs["q_row"], specs["q_row"]] + extra_specs,
+            out_specs=dkv_out_specs,
+            scratch_shapes=dkv_scratch),
         out_shape=dkv_out_shapes,
-        scratch_shapes=dkv_scratch,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )
+    # the same bytes seen as row vectors: [rows, heads, 1, T]
+    as_rows = [x.reshape(x.shape[:2] + (1, T)) for x in (lse, delta)]
     with jax.named_scope(DKV_NAME):
-        outs = dkv_call(*dkv_args)
+        outs = dkv_call(*k_tables, qh, kh, vh, gh, *as_rows, *extra_args)
     if masked:
         dk, dv, dbias_part = outs
-        dbias = dbias_part[:, :, 0].reshape(B, H, S).sum(axis=1)  # [B, S]
+        dbias = dbias_part.reshape(B, H, S).sum(axis=1)       # [B, S]
     else:
         dk, dv = outs
         dbias = None
 
-    return (_from_bh(dq, B, H), _from_bh(dk, B, H), _from_bh(dv, B, H),
-            dbias)
+    def back(x):
+        return (_from_bh(x, B, H) if lay.fold
+                else x.reshape(x.shape[:2] + (H, D)))
+
+    return back(dq), back(dk), back(dv), dbias
 
 
 # ---------------------------------------------------------------------------
@@ -823,8 +1071,19 @@ def _flash_pallas_on_mesh(q, k, v, key_bias, dropout_seed,
       jnp.asarray(dropout_head_offset, jnp.int32))
 
 
+def _fit_block(block, length):
+    """The tile a sequence of ``length`` is cut in under the bound
+    ``block``: the whole sequence where it is shorter, else ``block``
+    halved until it divides the length (1536 under 1024: 512) or is down
+    to a lane group's 128."""
+    block = min(block, length)
+    while length % block and block % 2 == 0 and block > LANES:
+        block //= 2
+    return block
+
+
 def flash_attention(q, k, v, causal=True, sm_scale=None,
-                    block_q=512, block_k=512, implementation="auto",
+                    block_q=1024, block_k=1024, implementation="auto",
                     key_padding_mask=None, key_bias=None,
                     dropout_rate=0.0, dropout_seed=None,
                     dropout_head_offset=0, dropout_num_heads=None):
@@ -832,6 +1091,11 @@ def flash_attention(q, k, v, causal=True, sm_scale=None,
 
     ``implementation``: "auto" (pallas on TPU, xla elsewhere), "pallas"
     (interpreter mode off-TPU — slow, for parity tests), "xla", or "dense".
+    ``block_q`` / ``block_k``: the pallas kernels' tile of queries and of
+    keys (a sequence they do not divide is cut in the next halving that
+    does). The kernels read q, k, v as ``[B, T, H*D]``, as the
+    projections wrote them, a 128-lane group of heads a grid step: no
+    transpose is made for them, whatever the head size and count.
     ``key_padding_mask`` [B, S] bool (True = attend) or ``key_bias``
     [B, S] additive fp32 (soft penalties honored exactly, with true
     gradients on every implementation): applied to scores everywhere;
@@ -888,8 +1152,8 @@ def flash_attention(q, k, v, causal=True, sm_scale=None,
                                     key_bias=bias, **drop_kw)
     if implementation == "pallas":
         T = q.shape[1]
-        bq = min(block_q, T)
-        bk = min(block_k, k.shape[1])
+        bq = _fit_block(block_q, T)
+        bk = _fit_block(block_k, k.shape[1])
         if T % bq != 0 or k.shape[1] % bk != 0:
             if on_tpu:
                 # the caller asked for the kernel (or "auto" chose it):
@@ -903,6 +1167,12 @@ def flash_attention(q, k, v, causal=True, sm_scale=None,
             # CPU tests, where odd toy shapes use the blockwise oracle
             return _blockwise_attention(q, k, v, causal, sm_scale,
                                         key_bias=bias, **drop_kw)
+        if on_tpu and bq % LANES and bq != T:
+            # dK/dV reads lse and delta as row vectors: a q tile is
+            # whole lane groups or the whole sequence
+            raise ValueError(
+                f"flash_attention: block_q {bq} is neither a multiple of "
+                f"{LANES} nor the q length {T}")
         return _flash_pallas_on_mesh(
             q, k, v, bias, dropout_seed, dropout_head_offset, causal,
             sm_scale, bq, bk, float(dropout_rate), dropout_num_heads,

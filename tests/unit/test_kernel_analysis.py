@@ -32,6 +32,8 @@ from deepspeed_tpu.analysis.audit import (
 from deepspeed_tpu.analysis.cost import estimate_step_cost
 from deepspeed_tpu.analysis.kernels import (
     analyze_kernels,
+    causal_dead_tile_fraction,
+    causal_rectangle_dead_fraction,
     paged_dead_block_fraction,
 )
 from deepspeed_tpu.analysis.rules import (
@@ -192,6 +194,74 @@ def test_seeded_unclamped_elision_shortfall():
     assert op.index_map_evaluated
     assert op.elided_fraction == pytest.approx(expected)
     assert _kernel_rule_findings(ana, expected_elision=expected) == []
+
+
+def _rectangle_walk(n_tiles, block):
+    """A flash-forward-shaped call (by name and operand order) whose
+    grid is the whole (rows, q tile, kv tile) rectangle with the plain
+    maps, as the training kernels had until PR 30: a dead tile's K and
+    V are fetched and only the arithmetic is skipped."""
+    T = n_tiles * block
+
+    def kernel(q_ref, k_ref, v_ref, o_ref):
+        o_ref[...] = q_ref[...]
+
+    def fn(q, k, v):
+        tile = lambda which: pl.BlockSpec(
+            (1, block, 128), [lambda r, i, j: (r, i, 0),
+                              lambda r, i, j: (r, j, 0)][which])
+        return pl.pallas_call(
+            kernel, name="ds_flash_fwd", grid=(2, n_tiles, n_tiles),
+            in_specs=[tile(0), tile(1), tile(1)], out_specs=tile(0),
+            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+            interpret=True)(q, k, v)
+
+    x = jnp.zeros((2, T, 128), jnp.float32)
+    return analyze_kernels(fn, (x, x, x))
+
+
+@pytest.mark.parametrize("n_tiles,dead", [(2, 0.25), (8, 0.4375)],
+                         ids=["T1024-2x2", "T4096-8x8"])
+def test_seeded_rectangle_walk_fetches_dead_tiles(n_tiles, dead):
+    """The cells' tilings (T 1024 and T 4096 in blocks of 512, here in
+    blocks of 8): a grid over the whole rectangle visits the tiles the
+    causal mask kills, a quarter and 7/16 of them, and fails the
+    contract that they are no part of the walk."""
+    ana = _rectangle_walk(n_tiles, 8)
+    (kernel,) = ana.kernels
+    assert kernel.tile_blocks == (8, 8)
+    assert kernel.tile_rectangle == 2 * n_tiles * n_tiles
+    assert causal_dead_tile_fraction(kernel) == pytest.approx(dead)
+    assert causal_rectangle_dead_fraction(n_tiles, n_tiles, 8, 8) == \
+        pytest.approx(dead)
+    (finding,) = _kernel_rule_findings(ana, expected_elision=dead)
+    assert finding.rule == "kernel_dma"
+    assert finding.severity == SEV_WARNING
+    assert finding.details["proved_elision"] == 0.0
+
+
+@pytest.mark.parametrize("seq,block", [(256, 128), (1024, 128)],
+                         ids=["2x2", "8x8"])
+def test_flash_train_walks_no_dead_tile(seq, block):
+    """The training kernels read their tiles from a table of the live
+    ones: no grid step lands on a tile the mask kills, and the tiles
+    left out are exactly the rectangle's dead share (0.25, 0.4375)."""
+    report = audit_flash_train(seq=seq, head_dim=64, block_q=block,
+                               block_k=block)
+    assert report.findings == []
+    assert report.stats["dead_tile_fraction"] == {
+        "ds_flash_fwd": 0.0, "ds_flash_dq": 0.0, "ds_flash_dkv": 0.0}
+    n = seq // block
+    assert report.stats["expected_elision"] == pytest.approx(
+        1 - (n + 1) / (2 * n))
+    for kd in report.stats["kernels"]["kernels"].values():
+        assert kd["grid"][-1] == n * (n + 1) // 2
+
+
+def test_dead_tile_fraction_is_none_without_a_tile_walk():
+    x = jnp.zeros((64, 128), jnp.float32)
+    ana = analyze_kernels(_elision_fn(clamped=True), (x,))
+    assert [causal_dead_tile_fraction(k) for k in ana.kernels] == [None]
 
 
 # ---------------------------------------------------------------------------

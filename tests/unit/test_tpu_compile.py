@@ -61,22 +61,49 @@ def compiled_text(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-# the OLMoE cell (`benchmarks/suite`): 2 rows x 4096 tokens, 16 heads x 128
+# what the training flash kernels see in the benchmark's cells
+# (`benchmarks/suite`): GPT-2 medium's 8 rows of 1024 x 16 heads x 64; two
+# of GPT-2 XL's rows a chip with its 25 heads (the last lane group half
+# full); OLMoE's 2 rows of 4096 x 16 heads x 128, and the one row its
+# driver hands the program and the reference together (`both`)
 OLMOE_SHAPE = (2, 4096, 16, 128)
+FLASH_SHAPES = {"gpt2-350m": (B, T, H, D), "gpt2-xl-25-heads": (2, T, 25, D),
+                "olmoe-t4096-d128": OLMOE_SHAPE,
+                "olmoe-both-1-row": (1, 4096, 16, 128),
+                # folded: a head size that is no divisor or multiple of
+                # the 128 lanes (GPT-2 2.7B's 80)
+                "d80-folded": (2, T, 32, 80)}
 
 
-@pytest.mark.parametrize("shape", [(B, T, H, D), OLMOE_SHAPE],
-                         ids=["gpt2-350m", "olmoe-t4096-d128"])
+def _flash_entry(shape, **kw):
+    """The kernel entry `flash_attention` wraps, with its default
+    blocks: the public function picks interpret mode off the first
+    device (the CPU here), so the tests steer this one."""
+    import inspect
+    fa = _flash_module()
+    bound = inspect.signature(fa.flash_attention).parameters
+    blocks = [fa._fit_block(bound[b].default, shape[1])
+              for b in ("block_q", "block_k")]
+
+    def fwd(q, k, v, key_bias=None, seed=None):
+        return fa._flash_pallas(
+            q, k, v, key_bias, seed, 0, kw.get("causal", True),
+            shape[-1] ** -0.5, *blocks, kw.get("dropout", 0.0), None, False)
+    return fwd
+
+
+def _flash_module():
+    import importlib
+    # the package re-exports the function under the module's name
+    return importlib.import_module(
+        "deepspeed_tpu.ops.pallas.flash_attention")
+
+
+@pytest.mark.parametrize("shape", list(FLASH_SHAPES))
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
 def test_flash_attention_compiles(chip, grad, shape):
-    # `flash_attention` picks interpret mode off the first device (the
-    # CPU here), so the test steers the kernel entry it wraps, with the
-    # public function's default blocks
-    from deepspeed_tpu.ops.pallas.flash_attention import _flash_pallas
-
-    def fwd(q, k, v):
-        return _flash_pallas(q, k, v, None, None, 0, True,
-                             shape[-1] ** -0.5, 512, 512, 0.0, None, False)
+    shape = FLASH_SHAPES[shape]
+    fwd = _flash_entry(shape)
 
     def loss(q, k, v):
         return fwd(q, k, v).astype(jnp.float32).sum()
@@ -88,6 +115,49 @@ def test_flash_attention_compiles(chip, grad, shape):
     for name in ("ds_flash_fwd",) + (("ds_flash_dq", "ds_flash_dkv")
                                      if grad else ()):
         assert name in text
+
+
+@pytest.mark.parametrize("case", ["gpt2-dropout", "xl-dropout",
+                                  "bert-bias-dropout", "t4096-dropout"])
+def test_flash_attention_variants_compile(chip, case):
+    """What no cell runs: in-kernel dropout (its hash and positions are
+    more score-sized values in VMEM, at tiles of 1024), the key bias with
+    its gradient, a call that is not causal."""
+    shape, causal, bias = {
+        "gpt2-dropout": ((B, T, H, D), True, False),
+        "xl-dropout": ((2, T, 25, D), True, False),
+        "bert-bias-dropout": ((8, 512, 16, 64), False, True),
+        "t4096-dropout": ((1, 4096, 4, 128), True, False)}[case]
+    fwd = _flash_entry(shape, causal=causal, dropout=0.1)
+
+    def loss(q, k, v, key_bias, seed):
+        return fwd(q, k, v, key_bias, seed).astype(jnp.float32).sum()
+
+    x = chip(shape, jnp.bfloat16)
+    text = compiled_text(
+        jax.grad(loss, argnums=(0, 1, 2) + ((3,) if bias else ())),
+        x, x, x, chip(shape[:2], jnp.float32) if bias else None,
+        chip((), jnp.int32))
+    for name in ("ds_flash_fwd", "ds_flash_dq", "ds_flash_dkv"):
+        assert name in text
+
+
+def test_flash_kernel_grids(chip):
+    """Steps a kernel launches at the cells' shapes, read from the
+    lowered calls: (rows, lane groups, live tiles). Medium: 8 rows x 8
+    pairs of heads x the one tile T 1024 makes; OLMoE: 2 x 16 heads x 10
+    of 16 tiles (the six the causal mask kills are no part of the walk).
+    Until PR 30 the grid was (rows x heads, q tiles, kv tiles) over the
+    whole square: 128 x 2 x 2 and 32 x 8 x 8."""
+    for shape, grid in (((B, T, H, D), (8, 8, 1)),
+                        ((2, T, 25, D), (2, 13, 1)),
+                        (OLMOE_SHAPE, (2, 16, 10))):
+        fwd = _flash_entry(shape)
+        x = chip(shape, jnp.bfloat16)
+        lowered = jax.jit(jax.grad(
+            lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))).lower(x, x, x)
+        assert kernel_grids(lowered.as_text()) == [grid] * 3, shape
 
 
 @pytest.mark.parametrize("bank", [(64, 2048, 1024), (64, 1024, 2048)],
@@ -426,3 +496,98 @@ def test_tp_sharded_flash_decode_compiles(topo, monkeypatch):
     assert "tpu_custom_call" in text
     assert payload_shaped_copies(text, (n_pages, H // 4, D, PAGE)) == []
     assert "all-gather" not in text and "all-to-all" not in text
+
+
+def _flash_neighbours(hlo_text):
+    """For each flash custom call of a compiled program: the opcodes of
+    what makes its array operands and of what takes its results, seen
+    through the ops that move no data (`bitcast`, `reshape`,
+    `get-tuple-element`, `tuple`). ``{kernel name: [set of producers'
+    opcodes, set of users' opcodes]}`` per call."""
+    import re
+
+    ops, users = {}, {}
+    for line in hlo_text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = .*?\s([\w-]+)\((.*)", line)
+        if not m:
+            continue
+        name, opcode, rest = m.groups()
+        operands = re.findall(r"%([\w.-]+)", rest.split("), ")[0])
+        ops[name] = (opcode, operands, line)
+        for o in operands:
+            users.setdefault(o, []).append(name)
+    free = {"bitcast", "reshape", "get-tuple-element", "tuple"}
+
+    def producers(name):
+        opcode, operands, _ = ops.get(name, ("parameter", [], ""))
+        if opcode in free:
+            return set().union(*(producers(o) for o in operands))
+        return {opcode}
+
+    def takers(name):
+        out = set()
+        for u in users.get(name, []):
+            opcode = ops[u][0]
+            out |= takers(u) if opcode in free else {opcode}
+        return out
+
+    calls = {}
+    for name, (opcode, operands, line) in ops.items():
+        kernel = re.match(r"(ds_flash_\w+?)(?:\.\d+)?$", name)
+        if opcode == "custom-call" and kernel:
+            calls.setdefault(kernel.group(1), []).append(
+                (set().union(*(producers(o) for o in operands)),
+                 takers(name)))
+    return calls
+
+
+@pytest.mark.parametrize("model", ["gpt2-medium", "olmoe"])
+def test_train_step_moves_nothing_round_the_flash_kernels(
+        chip, monkeypatch, model):
+    """The backward of the loss as the train steps trace it, compiled
+    for one chip at the cells' widths (two of GPT-2 medium's layers,
+    OLMoE's one): exactly one call of each of the three kernels a layer,
+    and neither a `copy` nor a `transpose` feeds one or takes its
+    result. Until PR 30 each layer paid ten such re-layouts: q, k, v,
+    the output and its cotangent folded to `[B*H, T, D]` and back
+    (`copy` 11.5 ms a step in the medium cell)."""
+    _compiled_not_interpreted(monkeypatch,
+                              "deepspeed_tpu.ops.pallas.flash_attention")
+    if model == "gpt2-medium":
+        from deepspeed_tpu.models.gpt2 import (
+            GPT2Config, GPT2LMHead, make_gpt2_loss_fn)
+        n_layer, ids = 2, (B, T)
+        net = GPT2LMHead(GPT2Config(
+            vocab_size=50257, n_positions=T, n_embd=H * D, n_layer=n_layer,
+            n_head=H, use_flash_attention=True))
+        loss_fn = make_gpt2_loss_fn(net)
+    else:
+        from deepspeed_tpu.models.olmoe import (
+            OlmoeLM, make_olmoe_loss_fn, olmoe_1b_7b)
+        _compiled_not_interpreted(monkeypatch, "deepspeed_tpu.moe.dropless")
+        n_layer, ids = 1, OLMOE_SHAPE[:2]
+        net = OlmoeLM(olmoe_1b_7b(n_layer=n_layer,
+                                  use_flash_attention=True))
+        loss_fn = make_olmoe_loss_fn(net)
+    shapes = jax.eval_shape(
+        lambda: net.init({"params": jax.random.PRNGKey(0)},
+                         jnp.zeros((1, 8), jnp.int32))["params"])
+    params = jax.tree_util.tree_map(lambda s: chip(s.shape, s.dtype),
+                                    shapes)
+
+    def step(params, batch):
+        cast = jax.tree_util.tree_map(
+            lambda x: x.astype(jnp.bfloat16), params)
+        out = loss_fn(cast, batch, None)
+        return out[0] if isinstance(out, tuple) else out
+
+    text = compiled_text(jax.grad(step), params,
+                         {"input_ids": chip(ids, jnp.int32)})
+    calls = _flash_neighbours(text)
+    assert {k: len(v) for k, v in calls.items()} == {
+        "ds_flash_fwd": n_layer, "ds_flash_dq": n_layer,
+        "ds_flash_dkv": n_layer}
+    for kernel, each in calls.items():
+        for made_by, taken_by in each:
+            assert not {"copy", "transpose"} & (made_by | taken_by), (
+                kernel, made_by, taken_by)
