@@ -36,6 +36,18 @@ Optional int8/fp8 storage reuses the wire-codec recipe from
 zero guard, round+clip for int) at per-(page, position, head) scale
 granularity — one f32 scale per head vector, the KV analog of the
 per-chunk wire scales.
+
+**Recurrent leaves beside the pool** (ISSUE 31). A layer that keeps a
+state instead of keys and values (a state-space mixer) holds, under its
+name in the same cache tree, leaves with one entry a batch row: fixed
+size whatever the row's length, owned by the row's SLOT, overwritten
+from zero by the prefill chunk that starts a prompt, carried from chunk
+to chunk and from decode step to decode step, never paged. The spec
+lists them (``recurrent_layers``, ``recurrent_leaves``); both kinds of
+leaf are donated to and updated in place by the same two programs.
+What moves or shares pages (the prefix cache, park/resume, speculative
+verify, the disaggregated hand-off, a ``model`` mesh axis) knows no
+state and refuses such a spec: :class:`RecurrentStateUnsupported`.
 """
 
 import dataclasses
@@ -43,41 +55,89 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from deepspeed_tpu.runtime.comm.codecs import CODECS, get_codec
 
 
+class RecurrentStateUnsupported(ValueError):
+    """A serving feature that moves, shares or rolls back pages was
+    asked of a model whose cache holds per-slot recurrent leaves, which
+    that feature cannot carry yet. Raised when the engine (or the
+    feature) is built, before anything is traced."""
+
+    def __init__(self, feature, why):
+        self.feature = feature
+        super().__init__(
+            f"{feature} cannot serve a model with a recurrent state "
+            f"beside its KV pages: {why}")
+
+
 @dataclasses.dataclass(frozen=True)
 class KVCacheSpec:
-    """Static shape + storage format of one engine's KV cache."""
-    n_layer: int
+    """Static shape + storage format of one engine's cache: the page
+    pools of the layers that keep keys and values and, for a hybrid
+    model, the per-slot leaves of the layers that keep a state."""
+    n_layer: int                    # layers that hold pages
     max_batch: int
     max_seq: int
-    n_head: int
+    n_head: int                     # the pool's heads: key/value heads
     head_dim: int
     dtype: Any = jnp.bfloat16       # storage dtype (codec dtype when quantized)
     codec: Optional[str] = None     # None | "int8" | "f8e4m3fn" | "f8e5m2"
     stacked: bool = False           # scan_layers layout (leading layer axis)
     page_size: int = 0              # positions a page; divides max_seq
     n_pages: int = 0                # pool pages incl. the trash page
+    # the page layers' names in the cache tree; () = h_<i> (stacked: h)
+    layers: tuple = ()
+    # layers that hold recurrent leaves, and those leaves, alike in
+    # every such layer: (leaf, shape with its max_batch axis, dtype)
+    recurrent_layers: tuple = ()
+    recurrent_leaves: tuple = ()
 
     @property
     def pages_per_row(self):
         """Page-table width: pages covering one row's max_seq span."""
         return self.max_seq // self.page_size
 
+    @property
+    def state_bytes_per_slot(self):
+        """Bytes of recurrent state one batch row owns, all layers."""
+        per_layer = sum(
+            int(np.prod(shape)) * jnp.dtype(dtype).itemsize
+            for _, shape, dtype in self.recurrent_leaves) // self.max_batch
+        return per_layer * len(self.recurrent_layers)
 
-def spec_for_model(cfg, max_batch, max_seq, kv_cache_dtype=None,
-                   page_size=0, n_pages=0):
-    """Resolve a :class:`KVCacheSpec` from a ``GPT2Config`` and the
+
+def refuse_recurrent(spec, feature, why):
+    """The one check of everything that knows pages only."""
+    if spec.recurrent_layers:
+        raise RecurrentStateUnsupported(feature, why)
+
+
+def spec_for_model(model, *args, **kwargs):
+    """The :class:`KVCacheSpec` a model (or its config) describes for
+    itself: ``model.cache_spec(max_batch, max_seq, kv_cache_dtype=None,
+    page_size=0, n_pages=0)``, which builds it with
+    :func:`page_pool_spec`."""
+    return model.cache_spec(*args, **kwargs)
+
+
+def page_pool_spec(max_batch, max_seq, *, n_layer, n_head, head_dim,
+                   compute_dtype, n_positions, stacked=False,
+                   kv_cache_dtype=None, page_size=0, n_pages=0,
+                   **recurrent):
+    """A :class:`KVCacheSpec` from a model's own numbers (``n_layer``
+    layers with ``n_head`` key/value heads of ``head_dim``) and the
     ``inference.kv_cache_dtype`` knob (None = model compute dtype,
     "bf16"/"f32" = plain storage, a codec name = quantized storage).
     ``page_size`` must divide ``max_seq``; ``n_pages=0`` defaults to
     every row filling its ``max_seq`` span at once, plus the trash
-    page."""
+    page. ``recurrent``: the spec's ``layers`` / ``recurrent_layers`` /
+    ``recurrent_leaves``."""
     codec = None
     if kv_cache_dtype is None:
-        dtype = cfg.dtype
+        dtype = compute_dtype
     elif kv_cache_dtype == "bf16":
         dtype = jnp.bfloat16
     elif kv_cache_dtype in ("f32", "fp32"):
@@ -89,10 +149,10 @@ def spec_for_model(cfg, max_batch, max_seq, kv_cache_dtype=None,
         raise ValueError(
             f"kv_cache_dtype must be None, 'bf16', 'f32', or a codec "
             f"name from {sorted(CODECS)}; got {kv_cache_dtype!r}")
-    if max_seq > cfg.n_positions:
+    if max_seq > n_positions:
         raise ValueError(
             f"max seq bucket {max_seq} exceeds the model's n_positions "
-            f"{cfg.n_positions}")
+            f"{n_positions}")
     page_size, n_pages = int(page_size), int(n_pages)
     if page_size < 1 or max_seq % page_size:
         raise ValueError(
@@ -107,11 +167,11 @@ def spec_for_model(cfg, max_batch, max_seq, kv_cache_dtype=None,
             f"n_pages must be >= 2 (page 0 is the trash page), "
             f"got {n_pages}")
     return KVCacheSpec(
-        n_layer=cfg.n_layer, max_batch=int(max_batch),
-        max_seq=int(max_seq), n_head=cfg.n_head,
-        head_dim=cfg.n_embd // cfg.n_head, dtype=dtype, codec=codec,
-        stacked=bool(cfg.scan_layers), page_size=page_size,
-        n_pages=n_pages)
+        n_layer=int(n_layer), max_batch=int(max_batch),
+        max_seq=int(max_seq), n_head=int(n_head),
+        head_dim=int(head_dim), dtype=dtype, codec=codec,
+        stacked=bool(stacked), page_size=page_size,
+        n_pages=n_pages, **recurrent)
 
 
 def payload_shape(spec):
@@ -140,8 +200,13 @@ def init_kv_cache(spec):
         return {"h": jax.tree_util.tree_map(
             lambda a: jnp.broadcast_to(a, (spec.n_layer,) + a.shape),
             layer)}
-    return {f"h_{i}": jax.tree_util.tree_map(jnp.array, layer)
-            for i in range(spec.n_layer)}
+    names = spec.layers or [f"h_{i}" for i in range(spec.n_layer)]
+    cache = {name: jax.tree_util.tree_map(jnp.array, layer)
+             for name in names}
+    for name in spec.recurrent_layers:
+        cache[name] = {leaf: jnp.zeros(shape, dtype)
+                       for leaf, shape, dtype in spec.recurrent_leaves}
+    return cache
 
 
 def kv_cache_nbytes(cache):
@@ -156,7 +221,7 @@ def cache_dtype_census(cache):
     flat = jax.tree_util.tree_flatten_with_path(cache)[0]
     for path, leaf in flat:
         key = str(getattr(path[-1], "key", path[-1]))
-        if key.endswith("_scale"):
+        if key not in ("k", "v"):
             continue
         dt = str(jnp.dtype(leaf.dtype))
         census[dt] = census.get(dt, 0) + 1
@@ -187,7 +252,8 @@ def kv_partition_specs(spec, model_axis="model"):
 
     if spec.stacked:
         return {"h": per_layer()}
-    return {f"h_{i}": per_layer() for i in range(spec.n_layer)}
+    names = spec.layers or [f"h_{i}" for i in range(spec.n_layer)]
+    return {name: per_layer() for name in names}
 
 
 # ---------------------------------------------------------------------------
@@ -293,8 +359,10 @@ def paged_write_kv(layer_cache, k_new, v_new, positions, page_table):
     contiguous per row. Two shapes exist:
 
     - prefill (``B == 1``): the whole chunk into a single page
-      (:func:`_write_chunk`) — the engine pins ``page_size %
-      prefill_chunk == 0`` so a chunk never straddles pages.
+      (:func:`_write_chunk`), or a chunk of several whole pages page by
+      page — the engine pins ``page_size % prefill_chunk == 0`` or
+      ``prefill_chunk % page_size == 0``, so a chunk never straddles a
+      page it does not fill.
     - decode (``T == 1``) and speculative verify (``B > 1, T > 1``):
       token by token (:func:`_write_tokens`) — each (row, step) token
       resolves its own (page, slot) through the table, so a verify
@@ -320,10 +388,20 @@ def paged_write_kv(layer_cache, k_new, v_new, positions, page_table):
     if codec is not None:
         new["k"], new["k_scale"] = _quantize(k_new, codec)
         new["v"], new["v_scale"] = _quantize(v_new, codec)
-    if B == 1:
+    if B == 1 and T <= page_size:
         return {name: _write_chunk(layer_cache[name], vals[0],
                                    pages[0, 0], offs[0, 0])
                 for name, vals in new.items()}
+    if B == 1:
+        # a chunk of several whole pages (it starts on a page boundary:
+        # the engine pins prefill_chunk % page_size == 0), page by page
+        out = dict(layer_cache)
+        for j in range(0, T, page_size):
+            for name, vals in new.items():
+                out[name] = _write_chunk(out[name],
+                                         vals[0, j:j + page_size],
+                                         pages[0, j], 0)
+        return out
     return _write_tokens(
         layer_cache,
         {name: vals.reshape((B * T,) + vals.shape[2:])
@@ -355,7 +433,7 @@ def paged_read_kv(layer_cache, page_table, dtype):
 
 
 def _flash_attend_paged(q, layer_cache, positions, page_table, block_k,
-                        mesh):
+                        mesh, scale=None):
     """Flash split-K attention straight over the STORAGE pool:
     quantized pools stream int8/f8 payloads + f32 scales into the
     kernel and never materialize a dequantized copy. The kernel
@@ -380,7 +458,7 @@ def _flash_attend_paged(q, layer_cache, positions, page_table, block_k,
     if mesh is None:
         return flash_decode_paged(q, layer_cache["k"], layer_cache["v"],
                                   pos, page_table, *scales,
-                                  block_k=block_k)
+                                  block_k=block_k, scale=scale)
 
     from jax.sharding import PartitionSpec as P
     head = P(None, None, "model", None)
@@ -389,7 +467,7 @@ def _flash_attend_paged(q, layer_cache, positions, page_table, block_k,
         ((P(None, "model", None),) * 2 if scales else ())
     sharded = jax.shard_map(
         lambda q_, k_, v_, p_, t_, *s_: flash_decode_paged(
-            q_, k_, v_, p_, t_, *s_, block_k=block_k),
+            q_, k_, v_, p_, t_, *s_, block_k=block_k, scale=scale),
         mesh=mesh, in_specs=in_specs, out_specs=head, check_vma=False)
     return sharded(q, layer_cache["k"], layer_cache["v"], pos,
                    page_table, *scales)
@@ -397,11 +475,15 @@ def _flash_attend_paged(q, layer_cache, positions, page_table, block_k,
 
 def cached_attention(q, k_new, v_new, layer_cache, positions,
                      compute_dtype, page_table, impl="dense",
-                     block_k=128, mesh=None, mask=None):
+                     block_k=128, mesh=None, mask=None, scale=None):
     """Write this chunk's k/v, then attend over the whole cache row.
 
     ``q``/``k_new``/``v_new``: ``[B, T, H, D]`` (T = 1 for a decode
-    step, ``prefill_chunk`` for a prefill chunk); ``positions``:
+    step, ``prefill_chunk`` for a prefill chunk); with grouped-query
+    attention ``q`` has a multiple ``G`` of the ``H`` heads of
+    ``k_new``/``v_new`` and of the pool, and query head ``h`` attends
+    over key head ``h // G``. ``scale`` multiplies the scores (``None``:
+    ``1 / sqrt(D)``). ``positions``:
     ``[B, T]`` absolute token positions, contiguous per row;
     ``page_table``: ``[B, pages_per_row]`` int32, the rows' physical
     pages. Returns ``(y [B, T, H, D], updated layer_cache)``. Writes
@@ -431,16 +513,30 @@ def cached_attention(q, k_new, v_new, layer_cache, positions,
                                  page_table)
     if impl == "flash" and q.shape[1] == 1:
         y = _flash_attend_paged(q, layer_cache, positions, page_table,
-                                block_k, mesh)
+                                block_k, mesh, scale)
         return y.astype(compute_dtype), layer_cache
     if mask is None:
         mask = attention_mask(layer_cache, positions, page_table)
     k_full, v_full = paged_read_kv(layer_cache, page_table, compute_dtype)
-    D = q.shape[-1]
-    scale = 1.0 / jnp.sqrt(jnp.asarray(D, compute_dtype))
-    att = jnp.einsum("bthd,bshd->bhts", q, k_full) * scale
-    att = jnp.where(mask[:, None], att, jnp.finfo(att.dtype).min)
-    att = jax.nn.softmax(att.astype(jnp.float32),
-                         axis=-1).astype(compute_dtype)
-    y = jnp.einsum("bhts,bshd->bthd", att, v_full)
-    return y, layer_cache
+    B, T, Hq, D = q.shape
+    H = k_full.shape[2]
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(jnp.asarray(D, compute_dtype))
+    else:
+        scale = jnp.asarray(scale, compute_dtype)
+    if Hq == H:
+        att = jnp.einsum("bthd,bshd->bhts", q, k_full) * scale
+        att = jnp.where(mask[:, None], att, jnp.finfo(att.dtype).min)
+        att = jax.nn.softmax(att.astype(jnp.float32),
+                             axis=-1).astype(compute_dtype)
+        return jnp.einsum("bhts,bshd->bthd", att, v_full), layer_cache
+    # grouped queries: the G query heads of a group share a key head
+    # (scores kept in float32 from the product to the softmax)
+    qg = q.reshape(B, T, H, Hq // H, D)
+    att = jnp.einsum("bthgd,bshd->bhgts", qg, k_full,
+                     preferred_element_type=jnp.float32)
+    att = jnp.where(mask[:, None, None], att * scale.astype(jnp.float32),
+                    jnp.finfo(jnp.float32).min)
+    att = jax.nn.softmax(att, axis=-1).astype(compute_dtype)
+    y = jnp.einsum("bhgts,bshd->bthgd", att, v_full)
+    return y.reshape(B, T, Hq, D), layer_cache

@@ -33,11 +33,38 @@ ring buffer until PR 28; the key is still read, and anything but
 ``"paged"`` is refused.)
 
 With a mesh whose ``model`` axis is >1 the engine places params with
-the model's Megatron PartitionSpecs (`models/gpt2.py:
-gpt2_partition_specs` — the `parallel/tensor_parallel.py` layout) and
+the model's Megatron PartitionSpecs (``model.partition_specs``;
+`models/gpt2.py:gpt2_partition_specs` — the
+`parallel/tensor_parallel.py` layout) and
 the cache with head-sharded specs (`cache.kv_partition_specs`), so
 decode matmuls and attention run tensor-parallel with GSPMD inserting
 the row-parallel psums.
+
+**The model's side of the seam** (ISSUE 31; `models/gpt2.py:GPT2LMHead`
+and `models/granite_hybrid.py:GraniteHybridLM` both answer it, and the
+engine asks nothing else of a model):
+
+- ``model.cache_spec(max_batch, max_seq, kv_cache_dtype=None,
+  page_size=0, n_pages=0)`` -> the :class:`~deepspeed_tpu.inference.
+  cache.KVCacheSpec` the model needs: its page pools (layers, key/value
+  heads, head size) and, for a layer that keeps a state instead of keys
+  and values, per-slot recurrent leaves;
+- ``model.serve_apply(params, cache, tokens [B, T], positions [B, T],
+  page_table [B, pages_per_row], slots [B], n_valid [B], attn_impl=,
+  attn_block_k=, attn_mesh=)`` -> ``(logits [B, vocab] at each row's
+  last real token, cache)``. ``slots`` are the rows' batch slots (whose
+  recurrent leaves they own) and ``n_valid`` how many of a row's ``T``
+  tokens are real: a prefill chunk is one row of ``prefill_chunk``
+  tokens, the last chunk's tail padding; a decode step is every row
+  with one token, ``n_valid`` 0 where the slot holds no request;
+- ``model.partition_specs(params)`` where a ``model`` mesh axis is
+  wanted.
+
+A model with recurrent leaves is served with the prefix cache off, and
+what cannot carry a state yet (an explicit ``prefix_cache``,
+speculative decoding, a tier, a ``model`` axis, page gathers for
+park/resume) refuses it when the engine is built
+(:class:`~deepspeed_tpu.inference.cache.RecurrentStateUnsupported`).
 """
 
 import numpy as np
@@ -51,8 +78,9 @@ from deepspeed_tpu.inference.cache import (
     init_kv_cache,
     kv_cache_nbytes,
     kv_partition_specs,
-    spec_for_model,
+    refuse_recurrent,
 )
+from deepspeed_tpu.inference.paging import TRASH_PAGE
 from deepspeed_tpu.telemetry.spans import Span, enclosing_attr
 
 DEFAULT_MAX_BATCH = 8
@@ -74,10 +102,12 @@ def _cfg_get(config, key, default):
 
 
 class InferenceEngine:
-    """Jitted autoregressive decode over a GPT-2 family model.
+    """Jitted autoregressive decode over a model that answers the
+    serving protocol (the module docstring).
 
-    ``model`` is a :class:`~deepspeed_tpu.models.gpt2.GPT2LMHead`
-    (unrolled or ``scan_layers``); ``params`` its param tree (matching
+    ``model`` is e.g. a :class:`~deepspeed_tpu.models.gpt2.GPT2LMHead`
+    (unrolled or ``scan_layers``) or a :class:`~deepspeed_tpu.models.
+    granite_hybrid.GraniteHybridLM`; ``params`` its param tree (matching
     layout). ``config`` is the validated ``inference`` block
     (`runtime/config.py:InferenceConfig`) or a plain dict with the same
     keys; ``session`` an optional
@@ -92,7 +122,6 @@ class InferenceEngine:
     def __init__(self, model, params, config=None, mesh=None,
                  session=None):
         self.model = model
-        cfg = model.config
         self.max_batch = int(_cfg_get(config, "max_batch",
                                       DEFAULT_MAX_BATCH))
         buckets = _cfg_get(config, "seq_buckets", DEFAULT_SEQ_BUCKETS)
@@ -118,7 +147,7 @@ class InferenceEngine:
                 f"the switch are gone); drop the key or set 'paged'")
         self.page_size = int(_cfg_get(config, "page_size", 0))
         self.n_pages = int(_cfg_get(config, "n_pages", 0))
-        self.prefix_cache = bool(_cfg_get(config, "prefix_cache", True))
+        prefix_cache = _cfg_get(config, "prefix_cache", None)
         self.host_park_threshold = float(_cfg_get(
             config, "host_park_threshold", DEFAULT_HOST_PARK_THRESHOLD))
         # disaggregated serving (ISSUE 20): a tiered engine runs ONE of
@@ -179,12 +208,13 @@ class InferenceEngine:
             # for the bytes/session win, coarse enough that page
             # tables stay short.
             self.page_size = min(2 * self.prefill_chunk, self.max_seq)
-        if self.page_size % self.prefill_chunk:
-            # a prefill chunk must land inside ONE page (the prefill
-            # write is a single dynamic_update_slice).
+        if self.page_size % self.prefill_chunk and \
+                self.prefill_chunk % self.page_size:
+            # a prefill chunk lands inside ONE page (a single
+            # dynamic_update_slice) or covers whole pages.
             raise ValueError(
                 f"page_size {self.page_size} must be a multiple of "
-                f"prefill_chunk {self.prefill_chunk}")
+                f"prefill_chunk {self.prefill_chunk} (or divide it)")
         if self.max_seq % self.page_size:
             raise ValueError(
                 f"page_size {self.page_size} must divide max_seq "
@@ -196,12 +226,30 @@ class InferenceEngine:
             raise ValueError(
                 f"attention block_k {self.attention_block_k} must "
                 f"divide page_size {self.page_size}")
-        self.spec = spec_for_model(cfg, self.max_batch, self.max_seq,
-                                   self.kv_cache_dtype,
-                                   page_size=self.page_size,
-                                   n_pages=self.n_pages)
+        self.spec = model.cache_spec(self.max_batch, self.max_seq,
+                                     self.kv_cache_dtype,
+                                     page_size=self.page_size,
+                                     n_pages=self.n_pages)
         self.n_pages = self.spec.n_pages
         self.pages_per_row = self.spec.pages_per_row
+        # what knows pages only refuses a recurrent state here, before
+        # anything is placed or traced
+        self.recurrent = bool(self.spec.recurrent_layers)
+        if prefix_cache:
+            refuse_recurrent(
+                self.spec, "inference.prefix_cache",
+                "a shared page says nothing of the state after it "
+                "(drop the key: such a model is served with it off)")
+        self.prefix_cache = (not self.recurrent) if prefix_cache is None \
+            else bool(prefix_cache)
+        if self.tier is not None:
+            refuse_recurrent(
+                self.spec, f"the disaggregated {self.tier!r} tier",
+                "the hand-off moves pages and no state")
+        if mesh is not None and dict(mesh.shape).get("model", 1) > 1:
+            refuse_recurrent(
+                self.spec, "a 'model' mesh axis (tensor parallelism)",
+                "the state's heads are not sharded")
         # the flash kernel's visit set, for decode()'s counters
         self._paged_grid_blocks = None
         if self.attention_impl == "flash":
@@ -219,6 +267,7 @@ class InferenceEngine:
         self.mesh = mesh
         self.session = session
         self._sample_key = jax.random.PRNGKey(self.sampling_seed)
+        self._small_ints = {}           # see _one_int
 
         self._cache_shardings = None
         if mesh is not None and dict(mesh.shape).get("model", 1) > 1:
@@ -229,11 +278,10 @@ class InferenceEngine:
             # second step — breaking the 2-program contract under TP.
             self._sample_key = jax.device_put(
                 self._sample_key, NamedSharding(mesh, PartitionSpec()))
-            from deepspeed_tpu.models.gpt2 import gpt2_partition_specs
             params = jax.tree_util.tree_map(
                 lambda leaf, spec: jax.device_put(
                     leaf, NamedSharding(mesh, spec)),
-                params, gpt2_partition_specs(params))
+                params, model.partition_specs(params))
             self._cache_shardings = jax.tree_util.tree_map(
                 lambda spec: NamedSharding(mesh, spec),
                 kv_partition_specs(self.spec),
@@ -280,13 +328,14 @@ class InferenceEngine:
             jax.lax.with_sharding_constraint, cache,
             self._cache_shardings)
 
-    def _prefill_fn(self, params, cache, tokens, positions, page_table):
-        # prefill addresses the POOL through the chunk's page table;
-        # the whole cache flows through so donation updates it in place.
-        logits, cache = self.model.apply(
-            {"params": params}, tokens, deterministic=True,
-            positions=positions, kv_cache=cache,
-            kv_page_table=page_table)
+    def _prefill_fn(self, params, cache, tokens, positions, page_table,
+                    slots, n_valid):
+        # prefill addresses the POOL through the chunk's page table and
+        # the row's recurrent leaves (if the model has any) through its
+        # slot; the whole cache flows through so donation updates it in
+        # place. The logits are the last real token's.
+        logits, cache = self.model.serve_apply(
+            params, cache, tokens, positions, page_table, slots, n_valid)
         # fp32 on the way out: host-side sampling/parity reads full
         # precision regardless of compute dtype (a no-op for f32 models,
         # so fp32 parity with the full forward stays bit-exact).
@@ -298,13 +347,14 @@ class InferenceEngine:
         # off self at trace time): they select the traced graph, never
         # ride as runtime values — changing them means a new engine.
         mesh = self.mesh if self._cache_shardings is not None else None
-        logits, cache = self.model.apply(
-            {"params": params}, tokens[:, None], deterministic=True,
-            positions=positions[:, None], kv_cache=cache,
+        # row i sits in slot i; a row whose table starts with the trash
+        # page holds no request (its token is not real)
+        live = (page_tables[:, 0] != TRASH_PAGE).astype(jnp.int32)
+        logits, cache = self.model.serve_apply(
+            params, cache, tokens[:, None], positions[:, None],
+            page_tables, jnp.arange(self.max_batch, dtype=jnp.int32), live,
             attn_impl=self.attention_impl,
-            attn_block_k=self.attention_block_k, attn_mesh=mesh,
-            kv_page_table=page_tables)
-        logits = logits[:, 0]
+            attn_block_k=self.attention_block_k, attn_mesh=mesh)
         from deepspeed_tpu.inference.sampling import sample_logits
         next_tokens, key = sample_logits(
             logits, key, temperature=self.temperature,
@@ -321,8 +371,10 @@ class InferenceEngine:
         generated token reads.
 
         ``page_table`` (``[pages_per_row]`` ints, pages covering the
-        prompt allocated by the scheduler) addresses the pool (``slot``
-        names the row for callers' bookkeeping only), and ``start``
+        prompt allocated by the scheduler) addresses the pool, ``slot``
+        names the row (whose recurrent leaves, in a model that has
+        them, the prompt's first chunk overwrites from zero and each
+        later chunk carries on), and ``start``
         (chunk-aligned) resumes mid-prompt — a prefix-cache hit skips
         the chunks the shared pages already hold; a parked-session
         resume restarts at the session's frontier. The skipped span's KV is bit-identical by
@@ -360,6 +412,12 @@ class InferenceEngine:
                 f"prefill start {start} must be chunk-aligned "
                 f"(chunk={chunk})")
         attrs["chunks"] = (padded - start) // chunk
+        attrs["pad_tokens"] = padded - n
+        if start:
+            refuse_recurrent(
+                self.spec, f"a prefill resumed at token {start}",
+                "the state before it was never computed")
+        slots = self._one_int(int(slot))
         from deepspeed_tpu.runtime.resilience import fault_injection
         last = None
         for ci in range(start // chunk, padded // chunk):
@@ -370,10 +428,21 @@ class InferenceEngine:
             pc = jnp.arange(ci * chunk, (ci + 1) * chunk,
                             dtype=jnp.int32)[None, :]
             logits, self.cache = self._prefill(
-                self.params, self.cache, tc, pc, pt)
+                self.params, self.cache, tc, pc, pt, slots,
+                self._one_int(min(chunk, n - ci * chunk)))
             if ci == last_chunk:
-                last = np.asarray(logits[0, (n - 1) % chunk])
+                last = np.asarray(logits[0])
         return last
+
+    def _one_int(self, value):
+        """``[value]`` as a device array, uploaded once a value: a
+        prefill's slot and each chunk's count of real tokens are the
+        same few numbers call after call."""
+        arr = self._small_ints.get(value)
+        if arr is None:
+            arr = self._small_ints[value] = jnp.asarray(
+                np.asarray([value], np.int32))
+        return arr
 
     def decode(self, tokens, positions, page_tables):
         """One decode step for every cache row at once. ``tokens`` /
@@ -398,6 +467,13 @@ class InferenceEngine:
                 positions, page_tables, self.attention_block_k)
             attrs = {"kv_blocks_live": live,
                      "kv_blocks_launched": launched}
+        if self.recurrent:
+            # rows whose state the step moves on, against those whose
+            # state it reads and writes back (all of them: the update is
+            # one masked pass over every slot)
+            attrs = dict(attrs or {}, ssm_rows_live=int(np.count_nonzero(
+                np.asarray(page_tables)[:, 0] != TRASH_PAGE)),
+                ssm_rows_touched=self.max_batch)
         # four spans, so that a gap on the device can be laid to the
         # part of the call the host was in: the uploads, the dispatch,
         # the wait for the tokens (the device's own time), the logits'
@@ -418,6 +494,10 @@ class InferenceEngine:
 
     # -- host-RAM page tier -------------------------------------------------
 
+    def _refuse_page_moves(self, feature):
+        refuse_recurrent(self.spec, feature,
+                         "pages are copied and no state with them")
+
     def gather_pages(self, page_ids):
         """Snapshot the given physical pages to host RAM: a per-layer
         ``{"k": [n, H, D, page_size], ...}`` numpy pytree, copied with
@@ -429,6 +509,7 @@ class InferenceEngine:
         program stays transfer-free."""
         from deepspeed_tpu.runtime.resilience.hotckpt import (
             _snapshot_to_host)
+        self._refuse_page_moves("gather_pages (park / hand-off)")
         ids = jnp.asarray(np.asarray(page_ids, np.int32))
         axis = 1 if self.spec.stacked else 0
         gathered = jax.tree_util.tree_map(
@@ -444,6 +525,7 @@ class InferenceEngine:
         prefill→decode page copy is device-to-device and keyed purely
         by page ids. The copies are materialized eagerly so they can't
         alias pool buffers a later donated prefill call invalidates."""
+        self._refuse_page_moves("gather_pages_device (hand-off)")
         ids = jnp.asarray(np.asarray(page_ids, np.int32))
         axis = 1 if self.spec.stacked else 0
         gathered = jax.tree_util.tree_map(
@@ -455,6 +537,7 @@ class InferenceEngine:
         """Inverse of :meth:`gather_pages`: write a host page snapshot
         back into (freshly allocated) physical pages — the resume half
         of the host tier."""
+        self._refuse_page_moves("scatter_pages (resume / hand-off)")
         ids = np.asarray(page_ids, np.int32)
         axis = 1 if self.spec.stacked else 0
 
@@ -538,6 +621,14 @@ class InferenceEngine:
                 jnp.zeros((self.max_batch, self.pages_per_row), jnp.int32),
                 self._sample_key)
 
+    def prefill_lowering_args(self):
+        """The avals one prefill chunk is called with."""
+        one = jnp.zeros((1,), jnp.int32)
+        return (self.params, self.cache,
+                jnp.zeros((1, self.prefill_chunk), jnp.int32),
+                jnp.zeros((1, self.prefill_chunk), jnp.int32),
+                jnp.zeros((1, self.pages_per_row), jnp.int32), one, one)
+
     def decode_hlo(self):
         """Compiled HLO text of the decode program (audit/bench food)."""
         args = self.decode_lowering_args()
@@ -556,6 +647,9 @@ class InferenceEngine:
                  "page_size": self.page_size,
                  "n_pages": self.n_pages,
                  "pages_per_row": self.pages_per_row}
+        if self.recurrent:
+            facts["recurrent_layers"] = len(self.spec.recurrent_layers)
+            facts["state_bytes_per_slot"] = self.spec.state_bytes_per_slot
         if self.tier is not None:
             facts["tier"] = self.tier
         if self.speculative is not None:

@@ -43,6 +43,7 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
+from deepspeed_tpu.inference.cache import RecurrentStateUnsupported
 from deepspeed_tpu.runtime.resilience import fault_injection
 from deepspeed_tpu.runtime.resilience.checkpoint import _leaf_checksums
 
@@ -293,6 +294,8 @@ class RowPaging:
     resumed: bool = False
     prefill_chunks: int = 0         # chunks actually run
     prefill_chunks_skipped: int = 0
+    slot: Optional[int] = None      # batch slot whose recurrent leaves
+    #                                 the row owns (None: it owns none)
 
     def table(self, pages_per_row):
         t = np.zeros(pages_per_row, np.int32)
@@ -305,7 +308,19 @@ class PagedCacheManager:
     mapping → mid-prompt prefill plan), per-step page growth, and the
     park/evacuate/resume ladder. Owns the allocator, the radix tree and
     the host store; talks to the engine only through
-    ``gather_pages``/``scatter_pages`` and static facts."""
+    ``gather_pages``/``scatter_pages`` and static facts.
+
+    Two kinds of cache, one manager: where the engine's spec lists
+    recurrent leaves (`inference/cache.py`), a row also owns its batch
+    slot's state from :meth:`admit` to :meth:`release`
+    (``state_rows_live`` of ``state_rows_total`` slots,
+    ``state_bytes_live``). The state is not allocated or zeroed here:
+    the slot's leaves are there for the engine's life and the prompt's
+    first prefill chunk overwrites them from zero. It is neither shared
+    nor parked, so such an engine has no radix tree, and a request with
+    a ``session_id`` is refused
+    (:class:`~deepspeed_tpu.inference.cache.RecurrentStateUnsupported`).
+    """
 
     def __init__(self, engine, session=None):
         self.engine = engine
@@ -329,6 +344,29 @@ class PagedCacheManager:
         self.pages_evacuated = 0
         self.pages_paged_in = 0
         self.host_pages_corrupt = 0
+        # slots whose recurrent leaves a live row owns
+        spec = getattr(engine, "spec", None)    # (a test's pool engine
+        #                                         has none)
+        self.recurrent = bool(getattr(spec, "recurrent_layers", ()))
+        self.state_rows_total = engine.max_batch if self.recurrent else 0
+        self.state_bytes_per_slot = \
+            spec.state_bytes_per_slot if self.recurrent else 0
+        self._state_owner = {}          # slot -> the row that owns it
+
+    @property
+    def state_rows_live(self):
+        return len(self._state_owner)
+
+    @property
+    def state_bytes_live(self):
+        return len(self._state_owner) * self.state_bytes_per_slot
+
+    def _refuse_session(self, session_id):
+        if session_id and self.recurrent:
+            raise RecurrentStateUnsupported(
+                f"park/resume (session {session_id!r})",
+                "a parked session's pages would come back without the "
+                "state that followed them")
 
     # -- bookkeeping ---------------------------------------------------------
 
@@ -374,6 +412,9 @@ class PagedCacheManager:
             "pages_paged_in": self.pages_paged_in,
             "host_pages_corrupt": self.host_pages_corrupt,
             "host_tier_bytes": self.host_store.nbytes,
+            "state_rows_live": self.state_rows_live,
+            "state_rows_total": self.state_rows_total,
+            "state_bytes_live": self.state_bytes_live,
         }
 
     # -- eviction ladder -----------------------------------------------------
@@ -429,12 +470,21 @@ class PagedCacheManager:
 
     # -- admission -----------------------------------------------------------
 
-    def admit(self, prompt, session_id=None):
+    def admit(self, prompt, session_id=None, slot=None):
         """Page plan for a new request: resume its parked session if
         the prompt extends one, else walk the radix tree for a shared
         prefix; allocate private pages for the rest of the prompt span.
         Returns a :class:`RowPaging` or None when the pool can't back
-        the request right now (the scheduler leaves it queued)."""
+        the request right now (the scheduler leaves it queued).
+        ``slot``: the batch slot the request takes; with recurrent
+        leaves the row owns that slot's state until :meth:`release`."""
+        self._refuse_session(session_id)
+        if self.recurrent:
+            if slot is None:
+                raise ValueError("a model with a recurrent state is "
+                                 "admitted into a named slot")
+            if slot in self._state_owner:
+                raise ValueError(f"slot {slot} still owns a live state")
         self._clock += 1
         n = len(prompt)
         chunk = self.engine.prefill_chunk
@@ -487,6 +537,11 @@ class PagedCacheManager:
             # entirely interned still runs its final page's chunks.
             matched = self.radix.match(prompt)
             m = min(len(matched), (n - 1) // self.page_size)
+            # whole chunks only: a chunk of several pages writes all of
+            # them, and a shared page is never written (other live rows
+            # read it), so a matched page inside the chunk that prefill
+            # restarts in is not shared but filled again, privately
+            m -= m % max(1, chunk // self.page_size)
             if m:
                 for p in matched[:m]:
                     self.allocator.incref(p)
@@ -516,17 +571,24 @@ class PagedCacheManager:
         if resumed:
             self.sessions_resumed += 1
         padded_chunks = -(-n // chunk)
-        return RowPaging(
+        row = RowPaging(
             pages=pages, start=start, prefix_hit=prefix_hit,
             resumed=resumed,
             prefill_chunks=padded_chunks - start // chunk,
             prefill_chunks_skipped=start // chunk)
+        if self.recurrent:
+            row.slot = slot
+            self._state_owner[slot] = row
+        return row
 
     def adopt(self, row):
         """Count a row whose pages were taken from the allocator
         directly (the disaggregated decode tier installs a handoff's
         pages itself) among the live rows; :meth:`release` uncounts
         it."""
+        if self.recurrent:
+            raise RecurrentStateUnsupported(
+                "a handed-off row", "its pages arrive without a state")
         self.pages_live += len(row.pages)
 
     def after_prefill(self, row, prompt):
@@ -573,8 +635,12 @@ class PagedCacheManager:
         under pressure) keyed by the token history their KV covers, so
         a follow-up request on the session resumes without re-prefill;
         otherwise every reference drops back to the allocator."""
+        self._refuse_session(session_id)
         self._clock += 1
         self.pages_live -= len(row.pages)
+        if self._state_owner.get(row.slot) is row:
+            del self._state_owner[row.slot]     # the slot's next tenant
+            #                                     starts from zero
         if session_id and kv_tokens:
             covered = min(len(kv_tokens),
                           len(row.pages) * self.page_size)

@@ -196,6 +196,9 @@ class ContinuousBatchingScheduler:
         if request.max_new_tokens < 1:
             raise ValueError(
                 f"request {request.rid}: max_new_tokens must be >= 1")
+        # park/resume knows pages only: a session on a model with a
+        # recurrent state is refused here, typed, not served cold
+        self.paging._refuse_session(request.session_id)
         if request.submit_t is None:    # survives redispatch resubmits
             request.submit_t = clock()
         if request.arrival_t is None:
@@ -380,7 +383,8 @@ class ContinuousBatchingScheduler:
         cannot back its prompt."""
         session = self.session
         with Span("pages", session):
-            row = self.paging.admit(req.prompt, session_id=req.session_id)
+            row = self.paging.admit(req.prompt, session_id=req.session_id,
+                                    slot=i)
         if row is None:
             return False
         self.queue.popleft()
@@ -425,6 +429,13 @@ class ContinuousBatchingScheduler:
                     attrs["pages_live"] = self.paging.pages_live
                     attrs["pages_resident"] = alloc.resident_pages
                     attrs["pages_total"] = alloc.n_pages - 1
+                    if self.paging.recurrent:
+                        attrs["state_rows_live"] = \
+                            self.paging.state_rows_live
+                        attrs["state_rows_total"] = \
+                            self.paging.state_rows_total
+                        attrs["state_bytes_live"] = \
+                            self.paging.state_bytes_live
         finally:
             self._stamp_returned()
 

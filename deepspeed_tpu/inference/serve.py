@@ -131,6 +131,36 @@ def _scale_blocks(params, scale):
     return walk(params, ())
 
 
+def _gpt2_tiny_model(args):
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.models.gpt2 import GPT2LMHead, gpt2_tiny
+    model = GPT2LMHead(gpt2_tiny(n_embd=32, dtype=jnp.float32,
+                                 scan_layers=args.scan_layers))
+    return model, lambda rng: model.init(
+        rng, jnp.zeros((1, 8), jnp.int32))["params"]
+
+
+def _hybrid_model(preset):
+    def build(args, dtype=None):
+        from deepspeed_tpu.models import granite_hybrid as gh
+        kw = {} if dtype is None else {"dtype": dtype,
+                                       "param_dtype": dtype}
+        model = gh.GraniteHybridLM(getattr(gh, preset)(**kw))
+        return model, lambda rng: gh.init_granite_hybrid_params(
+            model, rng)
+    return build
+
+
+# --model: preset name -> builder of (model, weights from a key), in
+# the preset's own dtype (float32 for the test-size GPT-2, bfloat16 for
+# a hybrid of state-space and attention layers) or in a checkpoint's.
+# The engine serves every preset through the same protocol.
+MODELS = {"gpt2-tiny": _gpt2_tiny_model,
+          "granite-hybrid-tiny": _hybrid_model("granite_hybrid_tiny"),
+          "granite-4.0-h-micro": _hybrid_model("granite_4_0_h_micro")}
+
+
 def _load_checkpoint_model(args, jax, jnp):
     """Serve a real trained checkpoint: resolve + load a
     `runtime/resilience/checkpoint.py` manifest, take its fp32 master
@@ -162,6 +192,25 @@ def _load_checkpoint_model(args, jax, jnp):
         raise SystemExit(
             f"ds_tpu_serve: checkpoint {path} carries no 'params' tree")
     params = state["params"]
+    if "embed" in params and "wte" not in params:
+        # a hybrid checkpoint (`models/granite_hybrid.py`): the preset
+        # --model names, held to the leaves' shapes, served in the dtype
+        # the leaves were saved in (no float32 copy)
+        params = jax.tree_util.tree_map(jnp.asarray, params)
+        if args.model == "gpt2-tiny":
+            raise SystemExit(
+                f"ds_tpu_serve: checkpoint {path} holds a hybrid model; "
+                f"name its preset with --model")
+        model, _ = MODELS[args.model](args, params["embed"].dtype)
+        cfg = model.config
+        if params["embed"].shape != (cfg.vocab_size, cfg.hidden_size):
+            raise SystemExit(
+                f"ds_tpu_serve: checkpoint {path}'s embedding "
+                f"{params['embed'].shape} is not {args.model}'s")
+        return model, params, {
+            "tag": tag, "path": path, "n_layer": cfg.num_hidden_layers,
+            "n_embd": cfg.hidden_size, "vocab_size": cfg.vocab_size,
+            "param_layout": "per_layer"}
     topo = (meta or {}).get("topology") or {}
     saved_tp = int((topo.get("mesh_shape") or {}).get("model", 1) or 1)
     if saved_tp > 1:
@@ -670,6 +719,11 @@ def main(argv=None):
     parser.add_argument("--ckpt-tag", default=None,
                         help="checkpoint tag to load (default: the "
                              "newest valid one)")
+    parser.add_argument("--model", default="gpt2-tiny", choices=MODELS,
+                        help="the seeded model to serve, in its preset's "
+                             "dtype (or the preset a hybrid checkpoint "
+                             "holds, served in the dtype it was saved "
+                             "in)")
     parser.add_argument("--n-head", type=int, default=4,
                         help="attention heads for --checkpoint serving "
                              "(not recoverable from param shapes)")
@@ -821,7 +875,6 @@ def main(argv=None):
     from deepspeed_tpu.inference.engine import InferenceEngine
     from deepspeed_tpu.inference.scheduler import (
         ContinuousBatchingScheduler)
-    from deepspeed_tpu.models.gpt2 import GPT2LMHead, gpt2_tiny
     from deepspeed_tpu.telemetry.session import TelemetrySession
 
     inf_cfg = {"max_batch": 2, "seq_buckets": (16, 32),
@@ -944,12 +997,9 @@ def main(argv=None):
         model, params, ckpt_info = _load_checkpoint_model(args, jax, jnp)
         cfg = model.config
     else:
-        cfg = gpt2_tiny(n_embd=32, dtype=jnp.float32,
-                        scan_layers=args.scan_layers)
-        model = GPT2LMHead(cfg)
-        toks = jnp.zeros((1, 8), jnp.int32)
-        params = model.init(jax.random.PRNGKey(args.seed),
-                            toks)["params"]
+        model, init = MODELS[args.model](args)
+        params = init(jax.random.PRNGKey(args.seed))
+        cfg = model.config
     if args.block_scale is not None:
         params = _scale_blocks(params, args.block_scale)
     engine = InferenceEngine(model, params, config=inf_cfg,

@@ -408,6 +408,9 @@ def build_speculative(engine, config):
     grow = float(_cfg_get(spec_cfg, "min_accept_to_grow", 0.0))
     if not enabled or k == 0:
         return None
+    from deepspeed_tpu.inference.cache import refuse_recurrent
+    refuse_recurrent(engine.spec, "inference.speculative",
+                     "a rejected draft would have to roll the state back")
     if k < 0:
         raise ValueError(f"speculative k must be >= 0, got {k}")
     n_layer = engine.model.config.n_layer

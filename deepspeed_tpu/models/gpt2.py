@@ -66,6 +66,18 @@ class GPT2Config:
     #                                  unstack_gpt2_layer_params for
     #                                  checkpoint conversion.
 
+    def cache_spec(self, max_batch, max_seq, kv_cache_dtype=None,
+                   page_size=0, n_pages=0):
+        """The serving cache this model needs (the engine's protocol,
+        `inference/engine.py`): one page pool a layer, every head."""
+        from deepspeed_tpu.inference.cache import page_pool_spec
+        return page_pool_spec(
+            max_batch, max_seq, n_layer=self.n_layer, n_head=self.n_head,
+            head_dim=self.n_embd // self.n_head, compute_dtype=self.dtype,
+            n_positions=self.n_positions, stacked=self.scan_layers,
+            kv_cache_dtype=kv_cache_dtype, page_size=page_size,
+            n_pages=n_pages)
+
 
 # Sizes follow the reference perf-harness configs
 # (`tests/model/Megatron_GPT2/run_perf_baseline.py:18-60`).
@@ -404,6 +416,33 @@ class GPT2LMHead(nn.Module):
         if kv_cache is not None:
             return logits, new_kv
         return logits
+
+    # -- the serving engine's protocol (`inference/engine.py`) -------------
+
+    @nn.nowrap
+    def cache_spec(self, *args, **kwargs):
+        return self.config.cache_spec(*args, **kwargs)
+
+    @nn.nowrap
+    def partition_specs(self, params):
+        return gpt2_partition_specs(params)
+
+    @nn.nowrap
+    def serve_apply(self, params, cache, tokens, positions, page_table,
+                    slots, n_valid, **attn):
+        """``[B, T]`` tokens at explicit positions through the cache;
+        returns ``(logits [B, vocab] at each row's last real token, the
+        cache)``. ``n_valid`` is how many of a row's ``T`` tokens are
+        real; ``slots`` (the rows' slots) matters to a model that keeps
+        a state: keys and values are addressed by position and page, and
+        padding is masked by position."""
+        del slots
+        logits, cache = self.apply(
+            {"params": params}, tokens, deterministic=True,
+            positions=positions, kv_cache=cache,
+            kv_page_table=page_table, **attn)
+        last = jnp.maximum(n_valid - 1, 0)[:, None, None]
+        return jnp.take_along_axis(logits, last, axis=1)[:, 0], cache
 
 
 def cross_entropy_sum_and_count(logits, labels, ignore_index=-100):

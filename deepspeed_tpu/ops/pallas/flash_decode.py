@@ -169,8 +169,16 @@ def _check_paged_vmem(heads, head_dim, block_k, kv_dtype, quant):
             f"kernel may use — lower attention_block_k (or page_size)")
 
 
-def _paged_decode_kernel(H, D, block_k, bpp, quant):
+def _paged_decode_kernel(H, D, block_k, bpp, quant, G=1, scale=None):
     """The paged kernel's body: one grid step = one row's live span.
+
+    ``H`` is the pool's (key/value) heads; with ``G`` > 1 query heads
+    to each (grouped-query attention) the query block is ``[H, G, D]``
+    and the ``G`` queries of a group attend over the one fetched
+    ``(D, block_k)`` block of their key head: scores, running max and
+    sum and the output carry a group axis (``rows`` below), the fetches
+    do not change. ``G`` = 1 is the kernel as it was. ``scale``
+    multiplies the scores (``None``: ``D ** -0.5``).
 
     Scalar-prefetch args: ``[B]`` positions and ``[B, pages_per_row]``
     page tables (SMEM). ``k_hbm`` / ``v_hbm`` (and the scale pools) are
@@ -181,6 +189,13 @@ def _paged_decode_kernel(H, D, block_k, bpp, quant):
     softmax state is the loop's carry: running max and sum ``[H, 1]``,
     output ``[H, D]``.
     """
+
+    rows = (H,) if G == 1 else (H, G)
+    scale = D ** -0.5 if scale is None else float(scale)
+
+    def over_heads(a):
+        """A per-(head, position) array against the scores' rows."""
+        return a if G == 1 else a[:, None, :]
 
     def kernel(pos_ref, pt_ref, q_ref, k_hbm, v_hbm, *refs):
         refs = list(refs)
@@ -227,7 +242,9 @@ def _paged_decode_kernel(H, D, block_k, bpp, quant):
             n_blocks = p // block_k + 1
             for c in copies(0, 0):
                 c.start()
-            qb = q_ref[0].astype(jnp.float32)[:, None, :]   # [H, 1, D]
+            qb = q_ref[0].astype(jnp.float32)       # [H, D] | [H, G, D]
+            if G == 1:
+                qb = qb[:, None, :]                             # [H, 1, D]
 
             def block(i, carry):
                 m_prev, l_prev, acc = carry
@@ -243,15 +260,15 @@ def _paged_decode_kernel(H, D, block_k, bpp, quant):
                 s = jax.lax.dot_general(
                     qb, kb, (((2,), (1,)), ((0,), (0,))),
                     preferred_element_type=jnp.float32
-                ).reshape(H, block_k)
+                ).reshape(rows + (block_k,))
                 if quant:
                     # fused dequant: scale the SCORES by the key scales
                     # (dot distributes over the per-position scalar);
                     # rows are [H, bk] f32, lane-major like the scores
-                    s = s * ksbuf[slot]
-                s = s * (D ** -0.5)
+                    s = s * over_heads(ksbuf[slot])
+                s = s * scale
                 k_pos = i * block_k + jax.lax.broadcasted_iota(
-                    jnp.int32, (H, block_k), 1)
+                    jnp.int32, rows + (block_k,), len(rows))
                 s = jnp.where(k_pos <= p, s, DEFAULT_MASK_VALUE)
                 m_new = jnp.maximum(m_prev,
                                     s.max(axis=-1, keepdims=True))
@@ -259,18 +276,19 @@ def _paged_decode_kernel(H, D, block_k, bpp, quant):
                 corr = jnp.exp(m_prev - m_new)
                 l_new = l_prev * corr + pr.sum(axis=-1, keepdims=True)
                 if quant:
-                    pr = pr * vsbuf[slot]
+                    pr = pr * over_heads(vsbuf[slot])
                 vb = vbuf[slot].astype(jnp.float32)         # [H, D, bk]
                 pv = jax.lax.dot_general(
-                    pr[:, None, :], vb, (((2,), (2,)), ((0,), (0,))),
-                    preferred_element_type=jnp.float32)     # [H, 1, D]
-                return m_new, l_new, acc * corr + pv.reshape(H, D)
+                    pr[:, None, :] if G == 1 else pr, vb,
+                    (((2,), (2,)), ((0,), (0,))),
+                    preferred_element_type=jnp.float32)     # [H, G, D]
+                return m_new, l_new, acc * corr + pv.reshape(rows + (D,))
 
             _, l, acc = jax.lax.fori_loop(
                 0, n_blocks, block,
-                (jnp.full((H, 1), -jnp.inf, jnp.float32),
-                 jnp.zeros((H, 1), jnp.float32),
-                 jnp.zeros((H, D), jnp.float32)))
+                (jnp.full(rows + (1,), -jnp.inf, jnp.float32),
+                 jnp.zeros(rows + (1,), jnp.float32),
+                 jnp.zeros(rows + (D,), jnp.float32)))
             o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
     return kernel
@@ -278,11 +296,14 @@ def _paged_decode_kernel(H, D, block_k, bpp, quant):
 
 def flash_decode_paged(q, k, v, positions, page_tables, k_scale=None,
                        v_scale=None, block_k=DEFAULT_BLOCK_K,
-                       interpret=None):
+                       interpret=None, scale=None):
     """Split-K flash decode over a paged KV pool.
 
-    ``q``: ``[B, 1, H, D]`` compute-dtype query (the decode step's
-    single token per row). ``k``/``v``: the POOL buffers
+    ``q``: ``[B, 1, Hq, D]`` compute-dtype query (the decode step's
+    single token per row); ``Hq`` is the pool's ``H`` or a multiple of
+    it (grouped-query attention: query head ``h`` attends over key head
+    ``h // (Hq // H)``). ``scale`` multiplies the scores (``None``:
+    ``D ** -0.5``). ``k``/``v``: the POOL buffers
     ``[n_pages, H, D, page_size]`` in storage dtype (scales ``[n_pages, H, page_size]`` when quantized —
     `inference/cache.py` paged layout). ``page_tables``: ``[B,
     pages_per_row]`` int32 physical page ids per row (entry 0 = the
@@ -304,11 +325,13 @@ def flash_decode_paged(q, k, v, positions, page_tables, k_scale=None,
     the fetch one slab of one page.
     """
     n_pages, H, D, page_size = k.shape
-    B = q.shape[0]
-    if q.shape != (B, 1, H, D):
+    B, Hq = q.shape[0], q.shape[2]
+    G = Hq // H
+    if q.shape != (B, 1, G * H, D) or G < 1:
         raise ValueError(
-            f"flash_decode_paged takes one query token per row: q "
-            f"shape {q.shape} != {(B, 1, H, D)}")
+            f"flash_decode_paged takes one query token per row, and a "
+            f"whole number of query heads to each of the pool's {H}: q "
+            f"shape {q.shape} != {(B, 1, 'G *', H, D)}")
     if page_tables.shape[0] != B:
         raise ValueError(
             f"page_tables rows {page_tables.shape[0]} != batch {B}")
@@ -320,12 +343,17 @@ def flash_decode_paged(q, k, v, positions, page_tables, k_scale=None,
     block_k = _validate_block_k(block_k, page_size, interpret)
     _check_paged_vmem(H, D, block_k, k.dtype, quant)
 
+    # the query and the output as the kernel sees them: [B, H, D], or
+    # with a group axis [B, H, G, D] (a reshape of the model's layout:
+    # query head h is (h // G, h % G))
+    qshape = (H, D) if G == 1 else (H, G, D)
+
     def row(b, pos_ref, pt_ref):
-        return (b, 0, 0)
+        return (b,) + (0,) * len(qshape)
 
     pool = pl.BlockSpec(memory_space=pl.ANY)
-    in_specs = [pl.BlockSpec((1, H, D), row), pool, pool]
-    args = [q.reshape(B, H, D), k, v]
+    in_specs = [pl.BlockSpec((1,) + qshape, row), pool, pool]
+    args = [q.reshape((B,) + qshape), k, v]
     scratch = [pltpu.VMEM((2, H, D, block_k), k.dtype),
                pltpu.VMEM((2, H, D, block_k), v.dtype)]
     if quant:
@@ -339,17 +367,18 @@ def flash_decode_paged(q, k, v, positions, page_tables, k_scale=None,
         num_scalar_prefetch=2,
         grid=(B,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, H, D), row),
+        out_specs=pl.BlockSpec((1,) + qshape, row),
         scratch_shapes=scratch,
     )
     call = pl.pallas_call(
-        _paged_decode_kernel(H, D, block_k, page_size // block_k, quant),
+        _paged_decode_kernel(H, D, block_k, page_size // block_k, quant,
+                             G, scale),
         name=DECODE_PAGED_NAME,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B,) + qshape, q.dtype),
         interpret=interpret,
     )
     with jax.named_scope(DECODE_PAGED_NAME):
         out = call(jnp.asarray(positions, jnp.int32),
                    jnp.asarray(page_tables, jnp.int32), *args)
-    return out.reshape(B, 1, H, D)
+    return out.reshape(B, 1, Hq, D)

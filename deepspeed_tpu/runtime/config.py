@@ -1159,10 +1159,10 @@ class DeepSpeedConfig:
                 f"inference: page_size must be an int >= 0 (0 = auto), "
                 f"got {ps!r}")
         if ps:
-            if ps % pc:
+            if ps % pc and pc % ps:
                 raise ValueError(
                     f"inference: page_size must be a multiple of "
-                    f"prefill_chunk={pc}; got {ps}")
+                    f"prefill_chunk={pc} (or divide it); got {ps}")
             if max(buckets) % ps:
                 raise ValueError(
                     f"inference: page_size must divide the largest seq "
@@ -1176,7 +1176,8 @@ class DeepSpeedConfig:
             raise ValueError(
                 "inference: n_pages must be >= 2 when set (page 0 is "
                 "the reserved trash page); got 1")
-        if not isinstance(inf.prefix_cache, bool):
+        if inf.prefix_cache is not None and \
+                not isinstance(inf.prefix_cache, bool):
             raise ValueError(
                 f"inference: prefix_cache must be a bool, "
                 f"got {inf.prefix_cache!r}")

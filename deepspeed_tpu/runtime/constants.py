@@ -505,7 +505,8 @@ INFERENCE_SAMPLING_SEED_DEFAULT = 0
 INFERENCE_KV_LAYOUT = "kv_layout"
 
 # Tokens per page. 0 = auto (two prefill chunks).
-# Must be a multiple of prefill_chunk and divide max(seq_buckets);
+# Must be a multiple of prefill_chunk (or divide it) and divide
+# max(seq_buckets);
 # flash block_k clamps to it.
 INFERENCE_PAGE_SIZE = "page_size"
 INFERENCE_PAGE_SIZE_DEFAULT = 0
@@ -521,7 +522,9 @@ INFERENCE_N_PAGES_DEFAULT = 0
 # prefix matches interned pages map them copy-on-write and skip the
 # shared span's prefill chunks.
 INFERENCE_PREFIX_CACHE = "prefix_cache"
-INFERENCE_PREFIX_CACHE_DEFAULT = True
+# None: on for a model whose cache is pages only, off for one with a
+# recurrent state beside them (an explicit true refuses such a model)
+INFERENCE_PREFIX_CACHE_DEFAULT = None
 
 # Host-RAM tier pressure threshold: while free pages /
 # n_pages sits below this fraction, parked sessions' pages are

@@ -618,15 +618,17 @@ def test_serving_dimensions_include_tier_knobs():
 
 @_slow
 def test_bad_chunk_candidate_is_typed_rejection():
-    """`prefill_chunk` 8 against page_size 4 cannot build — the tuner
-    reports the typed `candidate_build_error`, never a silent skip."""
+    """`prefill_chunk` 8 against page_size 12 cannot build (neither
+    divides the other; a chunk of whole pages can, since PR 31) — the
+    tuner reports the typed `candidate_build_error`, never a silent
+    skip."""
     from deepspeed_tpu.analysis.tune import (
         REJECT_BUILD_ERROR, evaluate_serving_candidate)
 
     res = evaluate_serving_candidate(
         {"train_batch_size": 8,
-         "inference": {"seq_buckets": [16, 32], "prefill_chunk": 8,
-                       "page_size": 4, "max_batch": 2}},
+         "inference": {"seq_buckets": [24, 48], "prefill_chunk": 8,
+                       "page_size": 12, "max_batch": 2}},
         model_overrides={"n_embd": 32},
         label="chunk8", dimension="chunk")
     assert res.reject_reason == REJECT_BUILD_ERROR

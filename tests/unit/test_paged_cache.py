@@ -262,6 +262,26 @@ class TestPagedCacheManager:
         assert rb.pages[1] != ra.pages[1]
         assert len({ra.pages[1], rb.pages[1]}) == 2
 
+    def test_a_chunk_of_several_pages_shares_whole_chunks_only(self):
+        # chunk 8 over pages of 4: three pages match, and the third lies
+        # in the chunk prefill restarts in, which writes both its pages
+        _, mgr = _mgr(n_pages=12, pages_per_row=5, prefill_chunk=8)
+        a = list(range(20))
+        ra = mgr.admit(a)
+        mgr.after_prefill(ra, a)
+        b = a[:12] + [60, 61, 62, 63, 64]
+        rb = mgr.admit(b)
+        assert rb.prefix_hit and rb.start == 8
+        assert rb.pages[:2] == ra.pages[:2]
+        assert not set(rb.pages[2:]) & set(ra.pages)
+        assert rb.prefill_chunks == 2 and rb.prefill_chunks_skipped == 1
+        # the matched third page stays the first row's and the tree's
+        assert mgr.allocator.refcount(ra.pages[2]) == 2
+        # one matched page is less than a chunk: no hit, nothing shared
+        rc = mgr.admit(a[:4] + [70, 71, 72, 73, 74])
+        assert not rc.prefix_hit and rc.start == 0
+        assert not set(rc.pages) & set(ra.pages)
+
     def test_dry_pool_defers_without_leaking(self):
         _, mgr = _mgr(n_pages=4)               # 3 usable pages
         live = mgr.admit(list(range(8)))       # takes 2, still mapped

@@ -219,6 +219,53 @@ def test_cow_divergence_leaves_shared_pages_intact():
     assert eng.compile_counts() == {"prefill": 1, "decode": 1}
 
 
+def test_prefix_hit_under_a_chunk_of_two_pages_writes_no_shared_page():
+    """``prefill_chunk`` 16 over pages of 8 with the prefix cache on:
+    row "b" matches three of live row "a"'s pages and restarts at the
+    chunk floor, 16. It shares the two pages before it; the third it
+    fills again in a page of its own, so the bytes "a" reads and the
+    tokens "a" goes on to generate are what they are without "b"."""
+    rng = np.random.default_rng(5)
+    base = rng.integers(0, 64, 24).tolist()         # three whole pages
+    a = Request("a", base + rng.integers(0, 64, 5).tolist(),
+                max_new_tokens=3)
+    b = Request("b", base + rng.integers(0, 64, 3).tolist(),
+                max_new_tokens=3)
+
+    _, _, alone_eng = _build(False, None, seq_buckets=(48,),
+                             prefill_chunk=16, page_size=8,
+                             prefix_cache=False)
+    alone = _serve(ContinuousBatchingScheduler(alone_eng),
+                   [Request("a", a.prompt, max_new_tokens=3)])["a"]
+
+    _, _, eng = _build(False, None, seq_buckets=(48,), prefill_chunk=16,
+                       page_size=8)
+    sched = ContinuousBatchingScheduler(eng)
+    sched.submit(a)
+    sched.step()                                    # "a" prefilled, live
+    row_a = next(s for s in sched.slots if s is not None)
+    pages_a = list(row_a.paging.pages[:3])
+    before = jax.tree_util.tree_map(
+        lambda leaf: np.asarray(leaf[np.asarray(pages_a)]), eng.cache)
+    sched.submit(b)
+    sched.step()                                    # "b" admitted
+    row_b = next(s for s in sched.slots
+                 if s is not None and s is not row_a)
+    assert row_b.paging.prefix_hit and row_b.paging.start == 16
+    assert row_b.paging.pages[:2] == pages_a[:2]
+    assert row_b.paging.pages[2] not in row_a.paging.pages
+    after = jax.tree_util.tree_map(
+        lambda leaf: np.asarray(leaf[np.asarray(pages_a)]), eng.cache)
+    jax.tree_util.tree_map(np.testing.assert_array_equal, before, after)
+    sched.run()
+    done = {c.rid: c for c in sched.completions}
+    assert done["a"].tokens == alone.tokens
+    cold = _serve(ContinuousBatchingScheduler(alone_eng),
+                  [Request("b2", b.prompt, max_new_tokens=3)])["b2"]
+    assert done["b"].tokens == cold.tokens
+    assert eng.compile_counts() == {"prefill": 1, "decode": 1}
+
+
 def test_prefix_cache_off_never_hits():
     rng = np.random.default_rng(4)
     base = rng.integers(0, 64, 12).tolist()

@@ -400,6 +400,62 @@ def test_checkpoint_layout_conversion_with_speculative(tmp_path,
     assert result["checkpoint"]["param_layout"] == "per_layer"
 
 
+def _save_hybrid_checkpoint(tmp_path, seed):
+    import jax
+    import numpy as np
+
+    from deepspeed_tpu.models import granite_hybrid as gh
+    from deepspeed_tpu.runtime.resilience.checkpoint import (
+        CheckpointManager)
+
+    model = gh.GraniteHybridLM(gh.granite_hybrid_tiny())
+    params = gh.init_granite_hybrid_params(model,
+                                           jax.random.PRNGKey(seed))
+    host = jax.tree_util.tree_map(np.asarray, params)
+    mgr = CheckpointManager(save_dir=str(tmp_path),
+                            io_retry_base_s=0.001)
+    mgr.save(str(tmp_path), "step3", {"params": host},
+             {"global_steps": 3})
+    return str(tmp_path)
+
+
+_HYBRID_ARGS = ["--model", "granite-hybrid-tiny", "--synthetic", "3",
+                "--max-new", "3", "--expect-compiles", "2", "--json",
+                "--seed", "5"]
+
+
+def test_hybrid_preset_serves_from_a_seed_and_from_its_checkpoint(
+        tmp_path, capsys):
+    """``--model`` picks the preset; the same weights, from ``--seed``
+    or from a checkpoint saved in bfloat16, give the same completions
+    through the same two programs, in the dtype they were saved in."""
+    assert main(_HYBRID_ARGS) == 0
+    seeded = json.loads(capsys.readouterr().out)
+    assert seeded["ok"] is True and len(seeded["completions"]) == 3
+    ckpt_dir = _save_hybrid_checkpoint(tmp_path / "ckpt", seed=5)
+    assert main(_HYBRID_ARGS + ["--checkpoint", ckpt_dir]) == 0
+    loaded = json.loads(capsys.readouterr().out)
+    assert loaded["ok"] is True
+    assert ([c["tokens"] for c in loaded["completions"]]
+            == [c["tokens"] for c in seeded["completions"]])
+    ck = loaded["checkpoint"]
+    assert ck["tag"] == "step3" and ck["n_layer"] == 6
+    assert ck["n_embd"] == 64 and ck["vocab_size"] == 256
+
+
+@pytest.mark.parametrize("model, message", [
+    ("gpt2-tiny", "name its preset with --model"),
+    ("granite-4.0-h-micro", "is not granite-4.0-h-micro's"),
+])
+def test_hybrid_checkpoint_under_the_wrong_preset_exits(
+        tmp_path, model, message):
+    ckpt_dir = _save_hybrid_checkpoint(tmp_path / "ckpt", seed=0)
+    with pytest.raises(SystemExit) as e:
+        main(["--checkpoint", ckpt_dir, "--model", model,
+              "--synthetic", "2"])
+    assert message in str(e.value)
+
+
 def test_checkpoint_missing_dir_exits(tmp_path):
     with pytest.raises(SystemExit) as e:
         main(["--checkpoint", str(tmp_path / "nope"),

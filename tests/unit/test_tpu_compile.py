@@ -354,6 +354,85 @@ def test_block_sparse_attention_compiles(chip):
     assert "tpu_custom_call" in text
 
 
+
+# --- the hybrid model's serving programs (ISSUE 31) -------------------------
+
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "float32", "int8"])
+def test_grouped_query_flash_decode_compiles(chip, kv_dtype):
+    """The decode kernel with 4 query heads to each of 8 key heads and
+    the model's own score scale, at the hybrid cell's shapes: 48 rows,
+    pages of 128, a pool of 48 x 36 pages."""
+    from deepspeed_tpu.ops.pallas.flash_decode import flash_decode_paged
+
+    dt = jnp.dtype(kv_dtype)
+    n_pages, per = ROWS * 36 + 1, 36
+    q = chip((ROWS, 1, 32, 64),
+             dt if kv_dtype == "float32" else jnp.bfloat16)
+    kv = chip((n_pages, 8, 64, PAGE), dt)
+    scales = (chip((n_pages, 8, PAGE), jnp.float32),) * 2 \
+        if dt.itemsize == 1 else ()
+
+    def fn(q, k, v, pos, pt, *s):
+        return flash_decode_paged(q, k, v, pos, pt, *s, interpret=False,
+                                  scale=1 / 64)
+    lowered = jax.jit(fn).lower(q, kv, kv, chip((ROWS,), jnp.int32),
+                                chip((ROWS, per), jnp.int32), *scales)
+    assert kernel_grids(lowered.as_text()) == [(ROWS,)]
+    assert "ds_flash_decode_paged" in lowered.compile().as_text()
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_hybrid_serving_programs_compile(chip, monkeypatch, program):
+    """Both programs of granite-4.0-h-micro at its published widths (one
+    mixer layer and one attention layer of the 40: the layers repeat),
+    cache donated, as the engine calls them: a prefill chunk of 512 in
+    slot 7 and a decode step of 48 rows. The state is updated where it
+    lies: no copy in the program has its shape."""
+    from deepspeed_tpu.analysis.hlo import payload_shaped_copies
+    from deepspeed_tpu.inference.cache import init_kv_cache
+    from deepspeed_tpu.models import granite_hybrid as gh
+
+    _compiled_not_interpreted(monkeypatch,
+                              "deepspeed_tpu.ops.pallas.flash_decode")
+    cfg = gh.granite_4_0_h_micro(
+        num_hidden_layers=2, layer_types=(gh.MAMBA, gh.ATTENTION))
+    model = gh.GraniteHybridLM(cfg)
+    spec = cfg.cache_spec(ROWS, 4608, page_size=PAGE)
+    abstract = lambda tree: jax.tree_util.tree_map(     # noqa: E731
+        lambda a: chip(a.shape, a.dtype), tree)
+    params = abstract(jax.eval_shape(
+        lambda k: gh.init_granite_hybrid_params(model, k),
+        jax.random.PRNGKey(0)))
+    cache = abstract(jax.eval_shape(lambda: init_kv_cache(spec)))
+    i32 = lambda *shape: chip(shape, jnp.int32)         # noqa: E731
+
+    if program == "prefill":
+        def fn(params, cache, tokens, positions, table, slots, n_valid):
+            return model.serve_apply(params, cache, tokens, positions,
+                                     table, slots, n_valid)
+        args = (i32(1, 512), i32(1, 512), i32(1, 36), i32(1), i32(1))
+    else:
+        def fn(params, cache, tokens, positions, tables):
+            live = (tables[:, 0] != 0).astype(jnp.int32)
+            return model.serve_apply(
+                params, cache, tokens[:, None], positions[:, None], tables,
+                jnp.arange(ROWS, dtype=jnp.int32), live,
+                attn_impl="flash", attn_block_k=PAGE)
+        args = (i32(ROWS), i32(ROWS), i32(ROWS, 36))
+    compiled = jax.jit(fn, donate_argnums=1).lower(
+        params, cache, *args).compile()
+    text = compiled.as_text()
+    assert ("tpu_custom_call" in text) == (program == "decode")
+    assert "ds_ssm_scan" in text and "ds_ssm_conv" in text
+    state = (ROWS, 64, 64, 128)
+    assert payload_shaped_copies(text, state) == []
+    assert payload_shaped_copies(text, (spec.n_pages, 8, 64, PAGE)) == []
+    # every cache leaf goes out where it came in
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree_util.tree_leaves(cache))
+    assert compiled.memory_analysis().alias_size_in_bytes == cache_bytes
+
+
 class _AnswersTpu:
     """Stands in for `jax` inside one kernel module: the kernels ask
     `jax.devices()` whether to interpret, and here that is the CPU."""
