@@ -431,9 +431,13 @@ def _scratch_avals(eqn):
 
 
 def _scratch_bytes(eqn):
-    """Declared scratch bytes (a semaphore holds none)."""
+    """Declared VMEM scratch bytes (a semaphore holds none, and a few
+    scalars in SMEM are not VMEM)."""
+    from jax.experimental.pallas import tpu as pltpu
     total = 0
     for aval in _scratch_avals(eqn):
+        if getattr(aval, "memory_space", None) == pltpu.SMEM:
+            continue
         try:
             item = np.dtype(getattr(aval, "dtype", np.float32)).itemsize
         except TypeError:           # a semaphore's dtype is no data type
@@ -490,21 +494,32 @@ def _block_of(bm):
 def _paged_decode_walk(scalar_vals, blocks, scratch):
     """`ops/pallas/flash_decode.py:flash_decode_paged`'s declared walk:
     ``{operand index: (block shape, dense fetches, fetched)}`` for the
-    pool operands it leaves in HBM. Slot ``j`` of the scratch is the
-    ``(2, *block)`` double buffer of the ``j``-th such operand; a dense
-    grid would visit every row's ``pages_per_row * page / block_k``
-    blocks, the kernel fetches the live rows' live ones
+    pool operands it leaves in HBM: the pool's leaves as inputs and,
+    aliased to them, as outputs. Slot ``j`` of the scratch is the
+    ``(2, *block)`` double buffer of the ``j``-th leaf; a dense grid
+    would visit every row's ``pages_per_row * page / block_k`` blocks,
+    the kernel fetches the live rows' live ones
     (:func:`~deepspeed_tpu.ops.pallas.flash_decode.paged_grid_blocks`,
-    which is its loop bound)."""
-    from deepspeed_tpu.ops.pallas.flash_decode import paged_grid_blocks
+    which is its loop bound), priced on the inputs, and writes back the
+    one block a live row's position falls in (the loop it replaced
+    wrote a page's slab for every row of the batch: the dense form),
+    priced on the outputs."""
+    from deepspeed_tpu.ops.pallas.flash_decode import (TRASH_PAGE,
+                                                       paged_grid_blocks)
     positions, tables = scalar_vals[:2]
     pools = [i for i, b in enumerate(blocks) if b.in_hbm]
+    leaves = len(pools) // 2
     block_k = scratch[0][-1]
     page = blocks[pools[0]].array_shape[-1]
-    dense = tables.shape[0] * tables.shape[1] * (page // block_k)
+    rows = tables.shape[0]
+    dense = rows * tables.shape[1] * (page // block_k)
     _, launched = paged_grid_blocks(positions, tables, block_k)
-    return {i: (scratch[j][1:], dense, launched)
-            for j, i in enumerate(pools)}
+    written = int(np.count_nonzero(tables[:, 0] != TRASH_PAGE))
+    walk = {i: (scratch[j][1:], dense, launched)
+            for j, i in enumerate(pools[:leaves])}
+    walk.update({i: (scratch[j][1:], rows * (page // block_k), written)
+                 for j, i in enumerate(pools[leaves:])})
+    return walk
 
 
 # kernels that fetch by manual DMA, by pallas_call name: what they

@@ -11,7 +11,10 @@ padding at ``head_dim`` 64 — XLA re-lays nothing out around the
 kernel. The price is that one position is one lane of every tile of
 its page, so a write reads and writes back the page's whole ``[n_head,
 head_dim, page_size]`` slab (:func:`paged_write_kv`), indexing
-dynamically on the page axis alone. The pool is addressed through
+dynamically on the page axis alone; a flash decode step's write costs
+no read of its own, because the kernel has the row's last block in
+VMEM already and writes it back with the new lane in it
+(:func:`cached_attention`). The pool is addressed through
 per-row page tables (``[B, pages_per_row]`` int32) that enter the
 compiled programs as plain data. The pool shape and the table shape
 are both static, so admission, eviction, page allocation, freeing,
@@ -19,9 +22,10 @@ prefix sharing and host-tier park/resume are pure host-side metadata
 churn and never change a compiled shape, which is what keeps the
 decode loop at exactly one compile (`engine.compile_counts`).
 Physical page 0 is the TRASH page: the allocator never hands it out,
-unallocated table entries point at it, and inactive decode rows write
-their garbage token there, so every gather/scatter stays in-bounds
-without per-row branches.
+unallocated table entries point at it, and the dense decode step's
+inactive rows write their garbage token there (the flash kernel writes
+nothing for them), so every gather/scatter stays in-bounds without
+per-row branches.
 
 Causality comes from explicit positions, not shapes: every write lands
 at the token's absolute position and every read masks cache index
@@ -350,6 +354,20 @@ def _write_chunk(buf, vals, page, off):
     return jax.lax.dynamic_update_index_in_dim(buf, slab, page, 0)
 
 
+def _new_leaves(layer_cache, k_new, v_new):
+    """A chunk's keys and values as the pool stores them, under the
+    pool's leaf names, position-major: ``[B, T, H, D]`` payloads and,
+    for a codec pool (quantized here), ``[B, T, H]`` scales, one per
+    (position, head)."""
+    codec = _codec_of(layer_cache)
+    if codec is None:
+        return {"k": k_new, "v": v_new}     # cast where they are written
+    new = {}
+    new["k"], new["k_scale"] = _quantize(k_new, codec)
+    new["v"], new["v_scale"] = _quantize(v_new, codec)
+    return new
+
+
 def paged_write_kv(layer_cache, k_new, v_new, positions, page_table):
     """Write one chunk's keys/values into the page pool through a
     page table. ``layer_cache`` holds ``[n_pages, H, D, page_size]``
@@ -376,18 +394,12 @@ def paged_write_kv(layer_cache, k_new, v_new, positions, page_table):
     scale per (page, slot, head); the flash kernel's fused dequant
     reads them beside the payload.
     """
-    codec = _codec_of(layer_cache)
     page_size = layer_cache["k"].shape[-1]
     B, T = positions.shape
     pages = jnp.take_along_axis(page_table, positions // page_size,
                                 axis=1)                     # [B, T]
     offs = positions % page_size
-
-    # position-major: [B, T, H, D] payloads, [B, T, H] scales
-    new = {"k": k_new, "v": v_new}
-    if codec is not None:
-        new["k"], new["k_scale"] = _quantize(k_new, codec)
-        new["v"], new["v_scale"] = _quantize(v_new, codec)
+    new = _new_leaves(layer_cache, k_new, v_new)
     if B == 1 and T <= page_size:
         return {name: _write_chunk(layer_cache[name], vals[0],
                                    pages[0, 0], offs[0, 0])
@@ -432,45 +444,44 @@ def paged_read_kv(layer_cache, page_table, dtype):
                         gather(layer_cache["v_scale"]), dtype))
 
 
-def _flash_attend_paged(q, layer_cache, positions, page_table, block_k,
-                        mesh, scale=None):
-    """Flash split-K attention straight over the STORAGE pool:
-    quantized pools stream int8/f8 payloads + f32 scales into the
+def _flash_attend_paged(q, new, layer_cache, positions, page_table,
+                        block_k, mesh, scale=None):
+    """A flash decode step straight over the STORAGE pool: the step's
+    ``new`` keys and values (:func:`_new_leaves`) go into the pool and
+    the rows attend over it in one kernel; returns ``(y, layer_cache)``.
+    Quantized pools stream int8/f8 payloads + f32 scales into the
     kernel and never materialize a dequantized copy. The kernel
     fetches each row's live KV blocks out of the pool through the
     scalar-prefetched page table
-    (`ops/pallas/flash_decode.py:flash_decode_paged`) — this code
-    gathers and transposes nothing, and neither does XLA around the
-    call: the pool's tiled layout is the kernel's. A row whose table
+    (`ops/pallas/flash_decode.py:flash_decode_paged`), puts the row's
+    new lane into the last of them and writes that block back — this
+    code gathers, transposes and writes nothing, and neither does XLA
+    around the call: the pool's tiled layout is the kernel's, and the
+    pool it returns is the buffer it was handed. A row whose table
     starts with the trash page holds no request: the kernel runs
-    nothing for it and its output is zeros (the scheduler ignores such
-    rows). Under TP the pool shards on its head axis 1
-    (`kv_partition_specs`) and each shard's kernel sees its local
-    heads; the query and the output keep the model's ``[B, 1, H, D]``
-    layout."""
+    nothing for it, writes nothing, and its output is zeros (the
+    scheduler ignores such rows). Under TP the pool shards on its head
+    axis 1 (`kv_partition_specs`), in and out, and each shard's kernel
+    sees its local heads; the query and the output keep the model's
+    ``[B, 1, H, D]`` layout."""
     from deepspeed_tpu.ops.pallas import flash_decode_paged
 
-    pos = positions[:, 0]
-    scales = ()
-    if "k_scale" in layer_cache:
-        scales = (layer_cache["k_scale"], layer_cache["v_scale"])
-
-    if mesh is None:
-        return flash_decode_paged(q, layer_cache["k"], layer_cache["v"],
-                                  pos, page_table, *scales,
+    def attend(q_, new_, pool_, pos_, table_):
+        return flash_decode_paged(q_, new_, pool_, pos_, table_,
                                   block_k=block_k, scale=scale)
 
-    from jax.sharding import PartitionSpec as P
-    head = P(None, None, "model", None)
-    pool = P(None, "model", None, None)
-    in_specs = (head, pool, pool, P(None), P(None, None)) + \
-        ((P(None, "model", None),) * 2 if scales else ())
-    sharded = jax.shard_map(
-        lambda q_, k_, v_, p_, t_, *s_: flash_decode_paged(
-            q_, k_, v_, p_, t_, *s_, block_k=block_k, scale=scale),
-        mesh=mesh, in_specs=in_specs, out_specs=head, check_vma=False)
-    return sharded(q, layer_cache["k"], layer_cache["v"], pos,
-                   page_table, *scales)
+    if mesh is not None:
+        from jax.sharding import PartitionSpec as P
+        head = P(None, None, "model", None)
+        # payloads [.., H, D] and scales [.., H] alike, as are the
+        # pool's [n_pages, H, ..] leaves
+        heads = {name: P(None, None, "model") for name in new}
+        pool = {name: P(None, "model") for name in layer_cache}
+        attend = jax.shard_map(
+            attend, mesh=mesh,
+            in_specs=(head, heads, pool, P(None), P(None, None)),
+            out_specs=(head, pool), check_vma=False)
+    return attend(q, new, layer_cache, positions[:, 0], page_table)
 
 
 def cached_attention(q, k_new, v_new, layer_cache, positions,
@@ -487,15 +498,22 @@ def cached_attention(q, k_new, v_new, layer_cache, positions,
     ``[B, T]`` absolute token positions, contiguous per row;
     ``page_table``: ``[B, pages_per_row]`` int32, the rows' physical
     pages. Returns ``(y [B, T, H, D], updated layer_cache)``. Writes
-    route through :func:`paged_write_kv`.
+    route through :func:`paged_write_kv`, but for a flash decode step.
 
     ``impl="flash"`` routes decode steps (T == 1) through the Pallas
     split-K kernel (`ops/pallas/flash_decode.py`): online-softmax over
     ``block_k``-sized cache blocks, only the blocks a row has filled,
     and quantized storage dequantized IN-kernel (scales as a side
-    input — no fp32 cache copy). Prefill chunks (T > 1) always use the
-    dense path over :func:`paged_read_kv`'s gathered view, which stays
-    the parity oracle. ``mesh``: a TP mesh whose ``model`` axis shards
+    input — no fp32 cache copy). **That kernel also makes the step's
+    write**: a live row's new key and value go into the block holding
+    its position, which the kernel has fetched anyway, and the block
+    goes back to the pool; rows without a request write nothing (the
+    48-slab loop of :func:`_write_tokens` wrote all of them, whatever
+    they held: 56 % of the chat cell's decode step, `PERF.md` section
+    6, PR 33). Prefill chunks and speculative verify (T > 1) always
+    use :func:`paged_write_kv` and the dense path over
+    :func:`paged_read_kv`'s gathered view, which stays the parity
+    oracle, as does the dense decode step. ``mesh``: a TP mesh whose ``model`` axis shards
     the pool's head dim — the flash call then runs under ``shard_map``
     per local head shard. ``mask``: a precomputed
     :func:`attention_mask` (dense path only) so multi-layer callers
@@ -509,12 +527,13 @@ def cached_attention(q, k_new, v_new, layer_cache, positions,
     until a real token overwrites the slot. Pages only change where
     bytes live, never what the mask admits.
     """
+    if impl == "flash" and q.shape[1] == 1:
+        y, layer_cache = _flash_attend_paged(
+            q, _new_leaves(layer_cache, k_new, v_new), layer_cache,
+            positions, page_table, block_k, mesh, scale)
+        return y.astype(compute_dtype), layer_cache
     layer_cache = paged_write_kv(layer_cache, k_new, v_new, positions,
                                  page_table)
-    if impl == "flash" and q.shape[1] == 1:
-        y = _flash_attend_paged(q, layer_cache, positions, page_table,
-                                block_k, mesh, scale)
-        return y.astype(compute_dtype), layer_cache
     if mask is None:
         mask = attention_mask(layer_cache, positions, page_table)
     k_full, v_full = paged_read_kv(layer_cache, page_table, compute_dtype)

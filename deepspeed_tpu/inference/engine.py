@@ -460,20 +460,27 @@ class InferenceEngine:
                 "— decode belongs to the decode tier")
         session = self.session
         attrs = None
+        if self._paged_grid_blocks is not None or self.recurrent:
+            # the rows that hold a request
+            rows = int(np.count_nonzero(
+                np.asarray(page_tables)[:, 0] != TRASH_PAGE))
         if self._paged_grid_blocks is not None:
             # how far the kernel's grid follows the cache: KV blocks (of
             # all heads) the live rows hold against those it visits
             live, launched = self._paged_grid_blocks(
                 positions, page_tables, self.attention_block_k)
+            # and its write: the rows whose block it writes back are the
+            # rows that hold a request (the loop it replaced wrote every
+            # row of the batch, whatever it held)
             attrs = {"kv_blocks_live": live,
-                     "kv_blocks_launched": launched}
+                     "kv_blocks_launched": launched,
+                     "kv_rows_live": rows, "kv_rows_written": rows}
         if self.recurrent:
             # rows whose state the step moves on, against those whose
             # state it reads and writes back (all of them: the update is
             # one masked pass over every slot)
-            attrs = dict(attrs or {}, ssm_rows_live=int(np.count_nonzero(
-                np.asarray(page_tables)[:, 0] != TRASH_PAGE)),
-                ssm_rows_touched=self.max_batch)
+            attrs = dict(attrs or {}, ssm_rows_live=rows,
+                         ssm_rows_touched=self.max_batch)
         # four spans, so that a gap on the device can be laid to the
         # part of the call the host was in: the uploads, the dispatch,
         # the wait for the tokens (the device's own time), the logits'

@@ -53,6 +53,8 @@ cached-attention path stays available as the parity oracle behind
 ``inference.attention.impl``.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -147,15 +149,19 @@ def paged_grid_blocks(positions, page_tables, block_k):
 
 def paged_vmem_bytes(heads, head_dim, block_k, kv_dtype, quant):
     """VMEM the paged kernel's step holds: two slots each of the K and
-    V ``(H, D, block_k)`` blocks (and of their scale rows), and the
-    pipelined query and output blocks. (The float32 operands of the
-    dots are made a head at a time, never a whole block: a described
-    v5e compiles int8 blocks of 12 MB and refuses float32 ones of 16.)"""
+    V ``(H, D, block_k)`` blocks (and of their scale rows), the
+    pipelined query and output blocks, and the pipelined block of the
+    step's new keys and values, float32, a row to a lane (and of their
+    scales). (The float32 operands of the dots are made a head at a
+    time, never a whole block: a described v5e compiles int8 blocks of
+    12 MB and refuses float32 ones of 16.)"""
     elems = int(heads) * int(head_dim) * int(block_k)
     need = 2 * 2 * elems * jnp.dtype(kv_dtype).itemsize
+    new = 2 * 2 * int(heads) * int(head_dim) * _LANES * 4
     if quant:
         need += 2 * 2 * int(heads) * int(block_k) * 4
-    return need + 2 * 2 * int(heads) * int(head_dim) * 4
+        new += 2 * 2 * int(heads) * _LANES * 4
+    return need + new + 2 * 2 * int(heads) * int(head_dim) * 4
 
 
 def _check_paged_vmem(heads, head_dim, block_k, kv_dtype, quant):
@@ -170,7 +176,8 @@ def _check_paged_vmem(heads, head_dim, block_k, kv_dtype, quant):
 
 
 def _paged_decode_kernel(H, D, block_k, bpp, quant, G=1, scale=None):
-    """The paged kernel's body: one grid step = one row's live span.
+    """The paged kernel's body: one grid step = one row's live span,
+    the row's new key and value written into it.
 
     ``H`` is the pool's (key/value) heads; with ``G`` > 1 query heads
     to each (grouped-query attention) the query block is ``[H, G, D]``
@@ -181,35 +188,51 @@ def _paged_decode_kernel(H, D, block_k, bpp, quant, G=1, scale=None):
     multiplies the scores (``None``: ``D ** -0.5``).
 
     Scalar-prefetch args: ``[B]`` positions and ``[B, pages_per_row]``
-    page tables (SMEM). ``k_hbm`` / ``v_hbm`` (and the scale pools) are
-    the whole pool, left in HBM; ``kbuf`` / ``vbuf`` are the two VMEM
-    slots of a ``(H, D, block_k)`` block, ``sem`` one DMA semaphore per
-    (operand, slot). Block ``i`` of the row is waited for in slot ``i %
-    2`` while block ``i + 1`` streams into the other. The online-
-    softmax state is the loop's carry: running max and sum ``[H, 1]``,
-    output ``[H, D]``.
+    page tables (SMEM). ``pools`` (K, V and, quantized, their scales)
+    are the whole pool, left in HBM: the call's outputs, which alias
+    its pool inputs, so one buffer is read and written. ``bufs`` are
+    the two VMEM slots of each operand's ``(H, D, block_k)`` block,
+    ``sem`` one DMA semaphore per (operand, slot, direction). Block
+    ``i`` of the row is waited for in slot ``i % 2`` while block ``i +
+    1`` streams into the other. The online-softmax state is the loop's
+    carry: running max and sum ``[H, 1]``, output ``[H, D]``.
+
+    **The write** (PR 33). The row's last block ``p // block_k`` holds
+    position ``p``, which this step fills: once fetched, lane ``p %
+    block_k`` of every head is replaced in its slot by the row's new
+    key and value (``new_ref``: ``[2, H, D, 128]`` float32, row ``b``
+    on lane ``b % 128``, turned so that it lands on the lane it goes
+    to), the slot is DMA'd back to where it came from, and the block
+    is attended over as any other. The page is the row's alone
+    (`inference/paging.py`: writes never target shared pages), so no
+    other grid step reads or writes it. The write-back is not waited
+    for in the row's own step: ``pend[slot]`` (SMEM) says that one is
+    under way from ``slot``, and whoever fetches into that slot next,
+    or the last grid step, waits first (``settle``). A row without a
+    request starts no DMA and writes nothing.
     """
 
     rows = (H,) if G == 1 else (H, G)
     scale = D ** -0.5 if scale is None else float(scale)
+    n_pool = 4 if quant else 2
 
     def over_heads(a):
         """A per-(head, position) array against the scores' rows."""
         return a if G == 1 else a[:, None, :]
 
-    def kernel(pos_ref, pt_ref, q_ref, k_hbm, v_hbm, *refs):
+    def kernel(pos_ref, pt_ref, q_ref, new_ref, *refs):
         refs = list(refs)
-        ks_hbm = refs.pop(0) if quant else None
-        vs_hbm = refs.pop(0) if quant else None
-        o_ref, kbuf, vbuf = refs[:3]
-        refs = refs[3:]
-        ksbuf = refs.pop(0) if quant else None
-        vsbuf = refs.pop(0) if quant else None
-        sem, = refs
+        snew_ref = refs.pop(0) if quant else None
+        del refs[:n_pool]           # the pool as handed in: see pools
+        o_ref = refs.pop(0)
+        pools, bufs = refs[:n_pool], refs[n_pool:2 * n_pool]
+        sem, pend = refs[2 * n_pool:]
         b = pl.program_id(0)
         p = pos_ref[b]
 
-        def copies(i, slot):
+        def copies(i, slot, back=False):
+            """Block ``i`` of the row into ``slot``, or (``back``) out
+            of it to where it came from."""
             # the table is read for blocks the row has filled only
             # (i <= p // block_k): no unallocated entry is dereferenced
             page = pt_ref[b, i // bpp]
@@ -218,18 +241,29 @@ def _paged_decode_kernel(H, D, block_k, bpp, quant, G=1, scale=None):
             else:
                 lanes = pl.ds(pl.multiple_of((i % bpp) * block_k,
                                              block_k), block_k)
-            out = [
-                pltpu.make_async_copy(k_hbm.at[page, :, :, lanes],
-                                      kbuf.at[slot], sem.at[0, slot]),
-                pltpu.make_async_copy(v_hbm.at[page, :, :, lanes],
-                                      vbuf.at[slot], sem.at[1, slot])]
-            if quant:
-                out += [
-                    pltpu.make_async_copy(ks_hbm.at[page, :, lanes],
-                                          ksbuf.at[slot], sem.at[2, slot]),
-                    pltpu.make_async_copy(vs_hbm.at[page, :, lanes],
-                                          vsbuf.at[slot], sem.at[3, slot])]
+            out = []
+            for j, (hbm, buf) in enumerate(zip(pools, bufs)):
+                hbm = hbm.at[page, :, :, lanes] if j < 2 \
+                    else hbm.at[page, :, lanes]
+                ends = (buf.at[slot], hbm) if back else (hbm, buf.at[slot])
+                out.append(pltpu.make_async_copy(
+                    *ends, sem.at[j, slot, int(back)]))
             return out
+
+        def settle(slot):
+            """``slot`` may be fetched into again: the write-back an
+            earlier row started from it, if any, has landed."""
+            @pl.when(pend[slot] != 0)
+            def _landed():
+                # a wait reads the semaphore and the block's size
+                for c in copies(0, slot, back=True):
+                    c.wait()
+                pend[slot] = 0
+
+        @pl.when(b == 0)
+        def _nothing_under_way():
+            pend[0] = 0
+            pend[1] = 0
 
         live = pt_ref[b, 0] != TRASH_PAGE
 
@@ -240,11 +274,34 @@ def _paged_decode_kernel(H, D, block_k, bpp, quant, G=1, scale=None):
         @pl.when(live)
         def _row():
             n_blocks = p // block_k + 1
+            settle(0)
             for c in copies(0, 0):
                 c.start()
             qb = q_ref[0].astype(jnp.float32)       # [H, D] | [H, G, D]
             if G == 1:
                 qb = qb[:, None, :]                             # [H, 1, D]
+
+            def put(slot):
+                """The row's new key and value (and scales) onto lane
+                ``p % block_k`` of the block in ``slot``, every other
+                lane as it was fetched."""
+                off = p % block_k
+                # row b's column, turned from lane b % 128 to lane off
+                turn = (off - b) % _LANES
+                new = pltpu.roll(new_ref[...], turn, 3)  # [2, H, D, 128]
+                snew = pltpu.roll(snew_ref[...], turn, 2) if quant else None
+                for j, buf in enumerate(bufs):
+                    col = new[j] if j < 2 else snew[j - 2]
+                    if block_k <= _LANES:
+                        col = col[..., :block_k]
+                    else:
+                        col = jnp.concatenate(
+                            [col] * (block_k // _LANES), axis=-1)
+                    blk = buf[slot]
+                    hit = jax.lax.broadcasted_iota(
+                        jnp.int32, blk.shape, blk.ndim - 1) == off
+                    buf[slot] = jnp.where(
+                        hit, col, blk.astype(jnp.float32)).astype(blk.dtype)
 
             def block(i, carry):
                 m_prev, l_prev, acc = carry
@@ -252,11 +309,19 @@ def _paged_decode_kernel(H, D, block_k, bpp, quant, G=1, scale=None):
 
                 @pl.when(i + 1 < n_blocks)
                 def _prefetch():
+                    settle(1 - slot)
                     for c in copies(i + 1, 1 - slot):
                         c.start()
                 for c in copies(i, slot):
                     c.wait()
-                kb = kbuf[slot].astype(jnp.float32)         # [H, D, bk]
+
+                @pl.when(i + 1 == n_blocks)
+                def _write():
+                    put(slot)
+                    for c in copies(i, slot, back=True):
+                        c.start()
+                    pend[slot] = 1
+                kb = bufs[0][slot].astype(jnp.float32)      # [H, D, bk]
                 s = jax.lax.dot_general(
                     qb, kb, (((2,), (1,)), ((0,), (0,))),
                     preferred_element_type=jnp.float32
@@ -265,7 +330,7 @@ def _paged_decode_kernel(H, D, block_k, bpp, quant, G=1, scale=None):
                     # fused dequant: scale the SCORES by the key scales
                     # (dot distributes over the per-position scalar);
                     # rows are [H, bk] f32, lane-major like the scores
-                    s = s * over_heads(ksbuf[slot])
+                    s = s * over_heads(bufs[2][slot])
                 s = s * scale
                 k_pos = i * block_k + jax.lax.broadcasted_iota(
                     jnp.int32, rows + (block_k,), len(rows))
@@ -276,8 +341,8 @@ def _paged_decode_kernel(H, D, block_k, bpp, quant, G=1, scale=None):
                 corr = jnp.exp(m_prev - m_new)
                 l_new = l_prev * corr + pr.sum(axis=-1, keepdims=True)
                 if quant:
-                    pr = pr * over_heads(vsbuf[slot])
-                vb = vbuf[slot].astype(jnp.float32)         # [H, D, bk]
+                    pr = pr * over_heads(bufs[3][slot])
+                vb = bufs[1][slot].astype(jnp.float32)      # [H, D, bk]
                 pv = jax.lax.dot_general(
                     pr[:, None, :] if G == 1 else pr, vb,
                     (((2,), (2,)), ((0,), (0,))),
@@ -291,43 +356,69 @@ def _paged_decode_kernel(H, D, block_k, bpp, quant, G=1, scale=None):
                  jnp.zeros(rows + (D,), jnp.float32)))
             o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
+        @pl.when(b == pl.num_programs(0) - 1)
+        def _drain():
+            settle(0)
+            settle(1)
+
     return kernel
 
 
-def flash_decode_paged(q, k, v, positions, page_tables, k_scale=None,
-                       v_scale=None, block_k=DEFAULT_BLOCK_K,
-                       interpret=None, scale=None):
-    """Split-K flash decode over a paged KV pool.
+def _rows_on_lanes(k_new, v_new, dtype):
+    """A decode step's new keys and values (or their scales), two ``[B,
+    1, ...]`` arrays, rounded to the pool's ``dtype``, as one float32
+    ``[2, ..., ceil(B / 128) * 128]``: a row to a lane, which is where
+    a position lies in the pool's blocks. Every storage dtype's values
+    are float32 values."""
+    x = jnp.stack([k_new[:, 0], v_new[:, 0]]).astype(dtype).astype(
+        jnp.float32)
+    x = jnp.moveaxis(x, 1, -1)
+    pad = -x.shape[-1] % _LANES
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+
+
+def flash_decode_paged(q, new, pool, positions, page_tables,
+                       block_k=DEFAULT_BLOCK_K, interpret=None, scale=None):
+    """One decode step's attention over a paged KV pool, the step's own
+    keys and values written into the pool on the way: returns ``(out,
+    pool)``.
 
     ``q``: ``[B, 1, Hq, D]`` compute-dtype query (the decode step's
     single token per row); ``Hq`` is the pool's ``H`` or a multiple of
     it (grouped-query attention: query head ``h`` attends over key head
     ``h // (Hq // H)``). ``scale`` multiplies the scores (``None``:
-    ``D ** -0.5``). ``k``/``v``: the POOL buffers
-    ``[n_pages, H, D, page_size]`` in storage dtype (scales ``[n_pages, H, page_size]`` when quantized —
-    `inference/cache.py` paged layout). ``page_tables``: ``[B,
-    pages_per_row]`` int32 physical page ids per row (entry 0 = the
-    trash page for unallocated slots). ``positions``: ``[B]`` int32,
-    each row's current write position (the mask admits cache index
-    ``s`` iff ``s <= positions[b]`` — identical to the dense oracle's).
-    Returns ``[B, 1, H, D]`` in ``q.dtype``. ``interpret=None``
-    auto-selects: compiled kernel on TPU, Pallas interpret mode
-    elsewhere.
+    ``D ** -0.5``). ``pool``: a layer's leaves, ``k`` / ``v``
+    ``[n_pages, H, D, page_size]`` in storage dtype and, quantized,
+    ``k_scale`` / ``v_scale`` ``[n_pages, H, page_size]``
+    (`inference/cache.py` paged layout). ``new``: the same keys with
+    what the step adds at each row's position, ``[B, 1, H, D]`` (cast to
+    the pool's dtype here; for a codec pool the payload quantized
+    outside, as every write's is) and ``[B, 1, H]`` scales. ``page_tables``: ``[B, pages_per_row]`` int32 physical
+    page ids per row (entry 0 = the trash page for unallocated slots).
+    ``positions``: ``[B]`` int32, each row's current write position
+    (the mask admits cache index ``s`` iff ``s <= positions[b]`` —
+    identical to the dense oracle's). ``out`` is ``[B, 1, Hq, D]`` in
+    ``q.dtype``; the returned pool's leaves alias the ones handed in
+    (``input_output_aliases``), so a caller that donates its pool has
+    nothing pool-shaped copied. ``interpret=None`` auto-selects:
+    compiled kernel on TPU, Pallas interpret mode elsewhere.
 
     One grid step a row; the row's ``positions[b] // block_k + 1`` live
     blocks are fetched from the pool by manual DMA inside it, all heads
     to a block, so neither a block past a row's position nor a table
-    entry past its occupancy is ever touched. A row whose table starts
-    with the trash page (no request in that slot: the scheduler hands
-    such rows position 0 and an all-zero table) runs nothing and
+    entry past its occupancy is ever touched. The last of them holds
+    the position the step fills: it gets the row's ``new`` lane in VMEM
+    before its scores are made and is written back whole, every other
+    lane as it was fetched. A row whose table starts with the trash
+    page (no request in that slot: the scheduler hands such rows
+    position 0 and an all-zero table) runs nothing, writes nothing and
     returns zeros. ``block_k`` clamps to ``page_size`` and must tile it
     — a KV block never straddles a page boundary, which is what keeps
     the fetch one slab of one page.
     """
-    n_pages, H, D, page_size = k.shape
+    H, D, page_size = pool["k"].shape[1:]
     B, Hq = q.shape[0], q.shape[2]
-    G = Hq // H
-    if q.shape != (B, 1, G * H, D) or G < 1:
+    if q.shape != (B, 1, Hq // H * H, D) or Hq < H:
         raise ValueError(
             f"flash_decode_paged takes one query token per row, and a "
             f"whole number of query heads to each of the pool's {H}: q "
@@ -337,11 +428,30 @@ def flash_decode_paged(q, k, v, positions, page_tables, k_scale=None,
             f"page_tables rows {page_tables.shape[0]} != batch {B}")
     if interpret is None:
         interpret = jax.devices()[0].platform != "tpu"
-    if (k_scale is None) != (v_scale is None):
-        raise ValueError("pass both k_scale and v_scale or neither")
-    quant = k_scale is not None
+    if set(new) != set(pool) or ("k_scale" in pool) != ("v_scale" in pool):
+        raise ValueError(
+            f"the step's new leaves {sorted(new)} must be the pool's "
+            f"{sorted(pool)}: k and v, with both scales or neither")
     block_k = _validate_block_k(block_k, page_size, interpret)
-    _check_paged_vmem(H, D, block_k, k.dtype, quant)
+    _check_paged_vmem(H, D, block_k, pool["k"].dtype, "k_scale" in pool)
+    return _paged_call(q, new, pool, jnp.asarray(positions, jnp.int32),
+                       jnp.asarray(page_tables, jnp.int32), block_k=block_k,
+                       interpret=bool(interpret), scale=scale)
+
+
+# jitted, so that a model's layers share one trace and one lowering of
+# the kernel: in a process that holds a large engine every traced
+# equation of a kernel body costs milliseconds (`PERF.md`, PR 30), and
+# the decode program calls this once a layer
+@functools.partial(jax.jit, static_argnames=("block_k", "interpret", "scale"))
+def _paged_call(q, new, pool, positions, page_tables, *, block_k, interpret,
+                scale):
+    k = pool["k"]
+    H, D, page_size = k.shape[1:]
+    B, Hq = q.shape[0], q.shape[2]
+    G = Hq // H
+    quant = "k_scale" in pool
+    names = ("k", "v") + (("k_scale", "v_scale") if quant else ())
 
     # the query and the output as the kernel sees them: [B, H, D], or
     # with a group axis [B, H, G, D] (a reshape of the model's layout:
@@ -351,23 +461,33 @@ def flash_decode_paged(q, k, v, positions, page_tables, k_scale=None,
     def row(b, pos_ref, pt_ref):
         return (b,) + (0,) * len(qshape)
 
-    pool = pl.BlockSpec(memory_space=pl.ANY)
-    in_specs = [pl.BlockSpec((1,) + qshape, row), pool, pool]
-    args = [q.reshape((B,) + qshape), k, v]
+    def lanes_of(ndim):
+        # the block of 128 rows that holds row b
+        return lambda b, pos_ref, pt_ref: (0,) * (ndim - 1) + (b // _LANES,)
+
+    anywhere = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [pl.BlockSpec((1,) + qshape, row),
+                pl.BlockSpec((2, H, D, _LANES), lanes_of(4))]
+    args = [q.reshape((B,) + qshape),
+            _rows_on_lanes(new["k"], new["v"], k.dtype)]
     scratch = [pltpu.VMEM((2, H, D, block_k), k.dtype),
-               pltpu.VMEM((2, H, D, block_k), v.dtype)]
+               pltpu.VMEM((2, H, D, block_k), k.dtype)]
     if quant:
-        in_specs += [pool, pool]
-        args += [k_scale, v_scale]
+        in_specs.append(pl.BlockSpec((2, H, _LANES), lanes_of(3)))
+        args.append(_rows_on_lanes(new["k_scale"], new["v_scale"],
+                                   jnp.float32))
         scratch += [pltpu.VMEM((2, H, block_k), jnp.float32),
                     pltpu.VMEM((2, H, block_k), jnp.float32)]
-    scratch.append(pltpu.SemaphoreType.DMA((4 if quant else 2, 2)))
+    scratch += [pltpu.SemaphoreType.DMA((len(names), 2, 2)),
+                pltpu.SMEM((2,), jnp.int32)]
+    leaves = [pool[name] for name in names]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1,) + qshape, row),
+        in_specs=in_specs + [anywhere] * len(leaves),
+        out_specs=[pl.BlockSpec((1,) + qshape, row)]
+        + [anywhere] * len(leaves),
         scratch_shapes=scratch,
     )
     call = pl.pallas_call(
@@ -375,10 +495,14 @@ def flash_decode_paged(q, k, v, positions, page_tables, k_scale=None,
                              G, scale),
         name=DECODE_PAGED_NAME,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B,) + qshape, q.dtype),
+        out_shape=[jax.ShapeDtypeStruct((B,) + qshape, q.dtype)]
+        + [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in leaves],
+        # operand 2 + len(args) + j (the two scalar operands count) is
+        # the pool's leaf j, and so is output 1 + j
+        input_output_aliases={2 + len(args) + j: 1 + j
+                              for j in range(len(leaves))},
         interpret=interpret,
     )
     with jax.named_scope(DECODE_PAGED_NAME):
-        out = call(jnp.asarray(positions, jnp.int32),
-                   jnp.asarray(page_tables, jnp.int32), *args)
-    return out.reshape(B, 1, Hq, D)
+        out, *leaves = call(positions, page_tables, *args, *leaves)
+    return out.reshape(B, 1, Hq, D), dict(zip(names, leaves))

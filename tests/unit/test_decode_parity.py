@@ -108,7 +108,10 @@ def test_paged_flash_parity_with_a_dead_row(kvdt, atol):
                   if rec[0] == "decode"]
         want = sum(pos[r] // 8 + 1 for r in live)
         assert attrs == {"kv_blocks_live": want,
-                         "kv_blocks_launched": want}
+                         "kv_blocks_launched": want,
+                         # and it writes the live rows' blocks only
+                         "kv_rows_live": len(live),
+                         "kv_rows_written": len(live)}
         for r in live:
             pos[r] += 1
     assert pos[2] == 32 == eng.max_seq      # the last slot was attended

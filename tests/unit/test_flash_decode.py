@@ -11,6 +11,17 @@ the three things its time rests on being safe: a stale tail, the trash
 page and every page a row does not own may hold NaN; no table entry
 past a row's occupancy is read; what it visits is `paged_grid_blocks`.
 
+**The step's write** (PR 33): the same call puts each live row's new
+key and value into the block holding its position and writes that block
+back. It is held to the code it replaced (`inference/cache.py:
+_write_tokens`, then the read-only arithmetic): the output and the WHOLE
+pool, every grouping, pool dtype and block size, position 0, a block's
+first lane, a page's last, one block and several, dead rows between
+live ones (the trash page and every page no live row owns untouched),
+two rows sharing prefix pages. The tests of the read alone hand the
+kernel, as the step's new lane, the lane the pool already holds
+(`attend`), so the pool must come back as it went in.
+
 The mask-hoist pin: the dense cached path builds its ``[max_batch, 1,
 max_seq]`` position mask ONCE per decode step (`models/gpt2.py`
 computes it in ``GPT2LMHead`` and threads it to every block), so the
@@ -18,36 +29,69 @@ lowered decode program's count of iotas over ``max_seq`` must not scale
 with ``n_layer`` — before the hoist each layer re-emitted the mask iota.
 """
 
+import json
+import os
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference.cache import _quantize
+from deepspeed_tpu.inference.cache import (_new_leaves, _quantize,
+                                           _write_tokens)
 from deepspeed_tpu.ops.pallas.flash_decode import (
     KernelGeometryError, check_decode_geometry, flash_decode_paged,
     paged_grid_blocks)
 
 
+def attend(q, k, v, positions, tables, k_scale=None, v_scale=None, **kw):
+    """The kernel's read alone: each live row's new lane is the one the
+    pool holds at its position already, so the call must hand the pool
+    back bit for bit; returns the attention output."""
+    pool = {"k": jnp.asarray(k), "v": jnp.asarray(v)}
+    if k_scale is not None:
+        pool.update(k_scale=jnp.asarray(k_scale),
+                    v_scale=jnp.asarray(v_scale))
+    positions, tables = np.asarray(positions), np.asarray(tables)
+    page = pool["k"].shape[-1]
+    live = tables[:, 0] != 0
+    pages = np.where(live, tables[np.arange(len(tables)),
+                                  positions // page], 0)
+    # [B, 1, H, D] payloads and [B, 1, H] scales, as a write's are
+    new = {name: leaf[pages, ..., positions % page][:, None]
+           for name, leaf in pool.items()}
+    out, after = flash_decode_paged(q, new, pool, positions, tables, **kw)
+    for name, leaf in pool.items():
+        np.testing.assert_array_equal(
+            np.asarray(after[name]).view(np.uint8),
+            np.asarray(leaf).view(np.uint8), err_msg=name)
+    return out
+
+
 def test_input_validation():
     q, k, v, positions, tables = _paged_case(1, 4, 16, poison=0.0)
+    new, pool = {"k": q, "v": q}, {"k": k, "v": v}
     with pytest.raises(ValueError, match="one query token"):
-        flash_decode_paged(np.concatenate([q, q], 1), k, v, positions,
-                           tables)
+        flash_decode_paged(np.concatenate([q, q], 1), new, pool,
+                           positions, tables)
     with pytest.raises(ValueError, match="page_tables rows"):
-        flash_decode_paged(q, k, v, positions, tables[:-1])
+        flash_decode_paged(q, new, pool, positions, tables[:-1])
     # a block never straddles a page: it divides page_size
     with pytest.raises(KernelGeometryError, match="multiple"):
-        flash_decode_paged(q, k, v, positions, tables, block_k=12)
+        flash_decode_paged(q, new, pool, positions, tables, block_k=12)
     with pytest.raises(KernelGeometryError, match=">= 1"):
-        flash_decode_paged(q, k, v, positions, tables, block_k=0)
-    with pytest.raises(ValueError, match="both k_scale and v_scale"):
-        flash_decode_paged(
-            q, k, v, positions, tables,
-            k_scale=np.ones(k.shape[:2] + k.shape[3:], np.float32))
+        flash_decode_paged(q, new, pool, positions, tables, block_k=0)
+    with pytest.raises(ValueError, match="both scales or neither"):
+        one = np.ones(k.shape[:2] + k.shape[3:], np.float32)
+        flash_decode_paged(q, dict(new, k_scale=q[..., 0]),
+                           dict(pool, k_scale=one), positions, tables)
+    with pytest.raises(ValueError, match="new leaves"):
+        flash_decode_paged(q, {"k": q}, pool, positions, tables)
     # past the page, block_k clamps to it
-    out = flash_decode_paged(q, k, v, positions, tables, block_k=4 * PAGE)
+    out = attend(q, k, v, positions, tables, block_k=4 * PAGE)
     np.testing.assert_allclose(
         np.asarray(out), _paged_ref(q, k, v, positions, tables), atol=2e-6)
 
@@ -147,8 +191,7 @@ def _paged_ref(q, k, v, positions, tables):
                          [(16, 64), (25, 64), (4, 64), (16, 128)])
 def test_paged_matches_dense_reference(heads, head_dim, block_k):
     q, k, v, positions, tables = _paged_case(0, heads, head_dim)
-    out = np.asarray(flash_decode_paged(q, k, v, positions, tables,
-                                        block_k=block_k))
+    out = np.asarray(attend(q, k, v, positions, tables, block_k=block_k))
     np.testing.assert_allclose(out, _paged_ref(q, k, v, positions, tables),
                                atol=2e-6)
     dead = [b for b, (_, live) in enumerate(ROWS) if not live]
@@ -174,8 +217,7 @@ def test_paged_every_pool_dtype(storage):
         scales = {"k_scale": ks, "v_scale": vs}
         k_d = np.asarray(k_s.astype(jnp.float32) * ks[:, :, None, :])
         v_d = np.asarray(v_s.astype(jnp.float32) * vs[:, :, None, :])
-    out = flash_decode_paged(q, k_s, v_s, positions, tables, block_k=8,
-                             **scales)
+    out = attend(q, k_s, v_s, positions, tables, block_k=8, **scales)
     np.testing.assert_allclose(np.asarray(out),
                                _paged_ref(q, k_d, v_d, positions, tables),
                                atol=2e-6)
@@ -189,14 +231,13 @@ def test_paged_stale_tail_and_foreign_pages_are_invisible():
     q, k, v, positions, tables = _paged_case(2, 4, 16)
     clean_k, clean_v = np.nan_to_num(k), np.nan_to_num(v)
     clean_t = np.where(tables > k.shape[0], 0, tables)
-    clean = flash_decode_paged(q, clean_k, clean_v, positions, clean_t,
-                               block_k=8)
+    clean = attend(q, clean_k, clean_v, positions, clean_t, block_k=8)
     for b, (pos, live) in enumerate(ROWS):
         if live and (pos + 1) % PAGE:
             last = tables[b, pos // PAGE]
             k[last, :, :, pos % PAGE + 1:] = 1e4
             v[last, :, :, pos % PAGE + 1:] = -1e4
-    poisoned = flash_decode_paged(q, k, v, positions, tables, block_k=8)
+    poisoned = attend(q, k, v, positions, tables, block_k=8)
     assert np.isfinite(np.asarray(poisoned)).all()
     np.testing.assert_array_equal(np.asarray(poisoned), np.asarray(clean))
 
@@ -222,14 +263,23 @@ def test_paged_tp_shard_map_matches_unsharded():
     q, k, v, positions, tables = _paged_case(4, 16, 16, poison=0.0)
     mesh = Mesh(np.array(jax.devices()[:4]).reshape(4), ("model",))
     head, pool = P(None, None, "model", None), P(None, "model", None, None)
+    k_new, v_new = (jnp.asarray(np.random.default_rng(5).standard_normal(
+        (2,) + q.shape), jnp.float32))
+
+    def call(q_, kn_, vn_, k_, v_, p_, t_):
+        out, pool_ = flash_decode_paged(
+            q_, {"k": kn_, "v": vn_}, {"k": k_, "v": v_}, p_, t_, block_k=8)
+        return out, pool_["k"], pool_["v"]
     sharded = jax.shard_map(
-        lambda q_, k_, v_, p_, t_: flash_decode_paged(
-            q_, k_, v_, p_, t_, block_k=8),
-        mesh=mesh, in_specs=(head, pool, pool, P(None), P(None, None)),
-        out_specs=head, check_vma=False)
-    out = sharded(*(jnp.asarray(x) for x in (q, k, v, positions, tables)))
-    ref = flash_decode_paged(q, k, v, positions, tables, block_k=8)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+        call, mesh=mesh,
+        in_specs=(head, head, head, pool, pool, P(None), P(None, None)),
+        out_specs=(head, pool, pool), check_vma=False)
+    args = tuple(jnp.asarray(x)
+                 for x in (q, k_new, v_new, k, v, positions, tables))
+    # the pool goes in and comes out on its head axis, written
+    for got, want in zip(sharded(*args), call(*args)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert not np.array_equal(np.asarray(call(*args)[1]), k)
 
 
 @pytest.mark.parametrize("storage,block_k,fits", [
@@ -255,3 +305,254 @@ def test_paged_blocks_must_fit_vmem(storage, block_k, fits):
         check_decode_geometry(*args, 16, 64, quant)
     # a TP=4 shard of the same pool holds a quarter of the heads
     assert check_decode_geometry(*args, 4, 64, quant) == block_k
+
+
+# ---------------------------------------------------------------------------
+# the step's write, inside the kernel (PR 33)
+# ---------------------------------------------------------------------------
+
+# (position, live): the pool's first lane (one block), a dead row, the
+# last lane of a row's last page (several blocks), a page's first lane,
+# a second dead row between live ones, mid-page, a page's last lane,
+# and two rows that share their first two pages (a prefix) and write
+# their own third: at lane 8, a block's first lane when block_k is 8,
+# and at lane 3
+WRITE_ROWS = [(0, True), (0, False), (PAGE * N_PT - 1, True), (PAGE, True),
+              (0, False), (21, True), (PAGE - 1, True),
+              (2 * PAGE + 8, True), (2 * PAGE + 3, True)]
+SHARED = (7, 8)
+
+
+def _write_case(seed, heads, head_dim, group, storage):
+    """A pool filled everywhere (so that any stray write shows), the
+    rows of ``WRITE_ROWS`` and a step's new keys and values: ``(q, new,
+    pool, positions, tables)``, ``pool`` as the engine holds it (a
+    codec pool with its scale leaves), ``new`` as `cached_attention`
+    hands it to the kernel."""
+    rng = np.random.default_rng(seed)
+    n_rows = len(WRITE_ROWS)
+    n_pages = n_rows * N_PT + 1
+    shape = (n_pages, heads, head_dim, PAGE)
+    k, v = (rng.standard_normal(shape).astype(np.float32) for _ in "kv")
+    if storage == "float32":
+        pool = {"k": jnp.asarray(k), "v": jnp.asarray(v)}
+    else:
+        pool = {}
+        for name, x in (("k", k), ("v", v)):
+            payload, scale = _quantize(jnp.asarray(x).swapaxes(2, 3),
+                                       storage)
+            pool[name] = payload.swapaxes(2, 3)
+            pool[name + "_scale"] = scale
+    q = rng.standard_normal((n_rows, 1, heads * group, head_dim)).astype(
+        np.float32)
+    k_new, v_new = (jnp.asarray(rng.standard_normal(
+        (n_rows, 1, heads, head_dim)), jnp.float32) for _ in "kv")
+    positions = np.zeros(n_rows, np.int32)
+    tables = np.zeros((n_rows, N_PT), np.int32)
+    free = list(rng.permutation(np.arange(1, n_pages)))
+    for b, (pos, live) in enumerate(WRITE_ROWS):
+        if live:
+            positions[b] = pos
+            for i in range(pos // PAGE + 1):
+                tables[b, i] = free.pop()
+    tables[SHARED[1], :2] = tables[SHARED[0], :2]
+    return (q, _new_leaves(pool, k_new, v_new), pool, positions, tables)
+
+
+def _dequantized(pool):
+    if "k_scale" not in pool:
+        return np.asarray(pool["k"]), np.asarray(pool["v"])
+    return tuple(np.asarray(pool[n].astype(jnp.float32)
+                            * pool[n + "_scale"][:, :, None, :])
+                 for n in "kv")
+
+
+@pytest.mark.parametrize("block_k", [PAGE, PAGE // 2],
+                         ids=["block=page", "block<page"])
+@pytest.mark.parametrize("storage", ["float32", "int8", "f8e4m3fn"])
+@pytest.mark.parametrize("group", [1, 4])
+def test_fused_write_is_the_loop_then_the_read(group, storage, block_k):
+    """The fused call against what it replaced: `_write_tokens` (every
+    row's token into its page's slab, dead rows' into the trash page),
+    then the read-only arithmetic over the written pool."""
+    q, new, pool, positions, tables = _write_case(
+        11 + group, 4, 16, group, storage)
+    before = {name: np.asarray(leaf) for name, leaf in pool.items()}
+    pages = tables[np.arange(len(tables)), positions // PAGE]
+    want = _write_tokens(pool, {n: x[:, 0] for n, x in new.items()},
+                         jnp.asarray(pages), jnp.asarray(positions % PAGE))
+    out, got = flash_decode_paged(q, new, pool, positions, tables,
+                                  block_k=block_k)
+    assert set(got) == set(pool)
+    for name, leaf in got.items():
+        leaf, loop = np.asarray(leaf), np.asarray(want[name])
+        assert leaf.dtype == before[name].dtype
+        # every page but the trash page, payload and scales, bit for bit
+        np.testing.assert_array_equal(leaf[1:].view(np.uint8),
+                                      loop[1:].view(np.uint8), err_msg=name)
+        # the loop put the dead rows' tokens there; the kernel nothing
+        np.testing.assert_array_equal(leaf[0].view(np.uint8),
+                                      before[name][0].view(np.uint8))
+        # and the live rows' lanes did change (scales may round alike)
+        if name in "kv":
+            written = [b for b, (_, live) in enumerate(WRITE_ROWS) if live]
+            assert all((leaf[pages[b], ..., positions[b] % PAGE]
+                        != before[name][pages[b], ..., positions[b] % PAGE]
+                        ).any() for b in written)
+    # the shared prefix pages are no row's to write
+    for name in got:
+        np.testing.assert_array_equal(
+            np.asarray(got[name])[tables[SHARED[0], :2]],
+            before[name][tables[SHARED[0], :2]])
+    # the new lane is attended over: the read alone, over the written
+    # pool, gives the same output bit for bit, and float64 agrees
+    scales = {n: want[n] for n in want if n.endswith("_scale")}
+    again = attend(q, want["k"], want["v"], positions, tables,
+                   block_k=block_k, **scales)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(again))
+    k_d, v_d = _dequantized(want)
+    q64 = q.reshape(len(q), 1, 4, group, 16)
+    for g in range(group):
+        np.testing.assert_allclose(
+            np.asarray(out).reshape(q64.shape)[:, :, :, g],
+            _paged_ref(q64[:, :, :, g], k_d, v_d, positions, tables),
+            atol=2e-6)
+
+
+def test_fused_write_under_jit_with_the_pool_donated():
+    """As the engine calls it: the pool donated, the call's pool leaves
+    aliased input to output. Two steps in a row: the second attends
+    over what the first wrote."""
+    q, new, pool, positions, tables = _write_case(3, 4, 16, 1, "float32")
+    step = jax.jit(lambda pool, q, new, pos, pt: flash_decode_paged(
+        q, new, pool, pos, pt, block_k=8), donate_argnums=(0,))
+    live = np.array([alive for _, alive in WRITE_ROWS])
+    room = live & (positions % PAGE < PAGE - 1)    # the next lane is theirs
+    pages = tables[np.arange(len(tables)), positions // PAGE]
+    loop = _write_tokens(pool, {n: x[:, 0] for n, x in new.items()},
+                         jnp.asarray(pages), jnp.asarray(positions % PAGE))
+    nxt = np.where(room, positions + 1, positions)
+    loop = _write_tokens(loop, {n: 2 * x[:, 0] for n, x in new.items()},
+                         jnp.asarray(pages), jnp.asarray(nxt % PAGE))
+    _, pool = step(pool, q, new, positions, tables)
+    out, pool = step(pool, q, {n: 2 * x for n, x in new.items()}, nxt,
+                     tables)
+    for name in "kv":
+        np.testing.assert_array_equal(np.asarray(pool[name])[1:],
+                                      np.asarray(loop[name])[1:])
+    np.testing.assert_allclose(
+        np.asarray(out), _paged_ref(q, *_dequantized(loop), nxt, tables),
+        atol=2e-6)
+
+
+# ---------------------------------------------------------------------------
+# who loads this kernel's file and runs none of it (PR 33)
+# ---------------------------------------------------------------------------
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+_IMPORT_WHAT_THESE_FILES_IMPORT = """
+import ast, importlib, json, sys
+sys.path.insert(0, sys.argv[1])
+for path in sys.argv[2:]:
+    for node in ast.walk(ast.parse(open(path).read())):
+        names = []
+        if isinstance(node, ast.Import):
+            names = [a.name for a in node.names]
+        elif isinstance(node, ast.ImportFrom) and node.level == 0:
+            names = [node.module] + [f"{node.module}.{a.name}"
+                                     for a in node.names]
+        for name in names:
+            try:
+                importlib.import_module(name)
+            except ImportError:
+                pass        # a name taken from a module, not a module
+from jax._src import xla_bridge
+print(json.dumps({
+    "modules": sorted(m for m in sys.modules
+                      if m.startswith("deepspeed_tpu")),
+    "backend_up": xla_bridge.backends_are_initialized()}))
+"""
+
+
+def _training_cells():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    return [m for m in manifest["end_to_end"]
+            if m["name"] == "train_tokens_per_s_per_chip"][0]["workloads"]
+
+
+def _driver_file(cell):
+    suite = os.path.join(ROOT, "benchmarks", "suite")
+    with open(os.path.join(suite, "workloads", cell + ".json")) as f:
+        return os.path.join(suite, "drivers", json.load(f)["driver"] + ".py")
+
+
+@pytest.fixture(scope="module")
+def training_process():
+    """What a training cell's process has loaded once it has imported
+    everything `run.py` and the training drivers import, at module
+    level or inside a function: a fresh interpreter, nothing run."""
+    files = [os.path.join(ROOT, "benchmarks", "suite", "run.py")] + sorted(
+        {_driver_file(cell) for cell in _training_cells()})
+    done = subprocess.run(
+        [sys.executable, "-c", _IMPORT_WHAT_THESE_FILES_IMPORT, ROOT] + files,
+        capture_output=True, text=True, timeout=600,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert done.returncode == 0, done.stderr[-2000:]
+    return files, json.loads(done.stdout.splitlines()[-1])
+
+
+@pytest.mark.parametrize("cell", _training_cells())
+def test_training_processes_load_no_serving_code(training_process, cell):
+    """The separation PR 32's refusal turned on (its `setup_s` in
+    `train-olmoe-1b-7b-seq4096`, a process that runs none of the
+    change): a training cell's process imports nothing under
+    `deepspeed_tpu/inference/`, and of the files the fused write
+    touches it loads `ops/pallas/flash_decode.py` alone, through
+    `ops/pallas/__init__.py`, for its definitions."""
+    files, facts = training_process
+    assert _driver_file(cell) in files
+    loaded = set(facts["modules"])
+    assert len(loaded) > 20 and "deepspeed_tpu.runtime.engine" in loaded
+    assert not [m for m in loaded if m.startswith("deepspeed_tpu.inference")]
+    touched = {"deepspeed_tpu.inference.cache",
+               "deepspeed_tpu.inference.engine",
+               "deepspeed_tpu.analysis.kernels",
+               "deepspeed_tpu.ops.pallas.flash_decode"}
+    assert touched & loaded == {"deepspeed_tpu.ops.pallas.flash_decode"}
+    # importing all of it built no array: no backend was brought up
+    assert facts["backend_up"] is False
+
+
+def test_the_kernels_file_is_definitions_at_module_level():
+    """Importing `deepspeed_tpu.ops.pallas` costs a training process
+    what reading the file costs: `flash_decode.py`'s module level is a
+    docstring, imports, constants, functions and a class: no array is
+    built there and nothing traced (the one decorator is the `jax.jit`
+    round `_paged_call`, which wraps at import and traces at the first
+    decode step, in a process that has one)."""
+    import ast
+
+    from deepspeed_tpu.ops.pallas import flash_decode
+    with open(flash_decode.__file__) as f:
+        tree = ast.parse(f.read())
+
+    def constant(node):
+        return isinstance(node, ast.Constant) or (
+            isinstance(node, ast.BinOp) and constant(node.left)
+            and constant(node.right))
+    for node in tree.body:
+        if isinstance(node, (ast.Import, ast.ImportFrom)):
+            continue
+        if isinstance(node, (ast.FunctionDef, ast.ClassDef)):
+            assert [ast.unparse(d) for d in node.decorator_list] in (
+                [], ["functools.partial(jax.jit, static_argnames="
+                     "('block_k', 'interpret', 'scale'))"]), node.name
+            continue
+        if isinstance(node, ast.Expr):
+            assert isinstance(node.value, ast.Constant)     # the docstring
+            continue
+        assert isinstance(node, ast.Assign) and constant(node.value), \
+            ast.unparse(node)

@@ -23,7 +23,7 @@ from deepspeed_tpu.inference.scheduler import (ContinuousBatchingScheduler,
                                                Request)
 from deepspeed_tpu.models import granite_hybrid as gh
 from deepspeed_tpu.ops import ssm
-from deepspeed_tpu.ops.pallas.flash_decode import flash_decode_paged
+from tests.unit.test_flash_decode import attend
 
 CHUNK, PAGE, SEQ, ROWS = 16, 8, 64, 3
 INF = {"max_batch": ROWS, "seq_buckets": (SEQ,), "prefill_chunk": CHUNK,
@@ -300,8 +300,9 @@ def test_grouped_query_decode_kernel_against_dense(group, scale):
     q = rng.normal(size=(B, 1, H * group, D)).astype(np.float32)
     positions = np.array([5, 31, 16], np.int32)
     pool, tables = pool_with(k, v, page)
-    got = flash_decode_paged(q, pool["k"], pool["v"], positions, tables,
-                             block_k=page, scale=scale)
+    # the kernel's read alone: the lane the pool holds goes in again
+    got = attend(q, pool["k"], pool["v"], positions, tables,
+                 block_k=page, scale=scale)
     sc = D ** -0.5 if scale is None else scale
     for b in range(B):
         n = positions[b] + 1
@@ -336,17 +337,18 @@ def test_gpt2_call_of_the_decode_kernel_is_unchanged():
     scores ``[H, block_k]``, no group axis."""
     from deepspeed_tpu.ops.pallas import flash_decode as fd
     H, D, page = 4, 16, 8
-    args = (jnp.zeros((2, 1, H, D)), jnp.zeros((5, H, D, page)),
-            jnp.zeros((5, H, D, page)), jnp.zeros((2,), jnp.int32),
+    new = {"k": jnp.zeros((2, 1, H, D)), "v": jnp.zeros((2, 1, H, D))}
+    pool = {"k": jnp.zeros((5, H, D, page)), "v": jnp.zeros((5, H, D, page))}
+    args = (new, pool, jnp.zeros((2,), jnp.int32),
             jnp.ones((2, 2), jnp.int32))
-    text = str(jax.make_jaxpr(lambda *a: fd.flash_decode_paged(
-        *a, block_k=page))(*args))
+    text = str(jax.make_jaxpr(lambda q, *a: fd.flash_decode_paged(
+        q, *a, block_k=page))(jnp.zeros((2, 1, H, D)), *args))
     assert f"f32[{H},1,{D}]" in text and f"f32[{H},{page}]" in text
     grouped = str(jax.make_jaxpr(lambda q, *a: fd.flash_decode_paged(
-        q, *a, block_k=page))(jnp.zeros((2, 1, 2 * H, D)), *args[1:]))
+        q, *a, block_k=page))(jnp.zeros((2, 1, 2 * H, D)), *args))
     assert f"f32[{H},2,{page}]" in grouped
     with pytest.raises(ValueError, match="whole number of query heads"):
-        fd.flash_decode_paged(jnp.zeros((2, 1, H + 1, D)), *args[1:])
+        fd.flash_decode_paged(jnp.zeros((2, 1, H + 1, D)), *args)
 
 
 # --- what cannot hold a state refuses, typed, before any trace -------------

@@ -264,3 +264,120 @@ class TestCachedAttention:
                                       tables)
         assert np.array_equal(np.asarray(y_poisoned),
                               np.asarray(y_clean))
+
+
+class TestFlashDecodeStepWrites:
+    """A flash decode step (``T == 1``) makes its write inside the
+    kernel (PR 33): `cached_attention` calls no `paged_write_kv` for it.
+    The dense decode step, which keeps the loop, is the oracle: output
+    and the whole pool."""
+
+    # (codec, tolerance on the output: the dense path dequantizes the
+    # pool to compute dtype, the kernel rescales scores and weights)
+    STORAGE = [(None, 2e-6), ("int8", 2e-6), ("f8e4m3fn", 2e-6)]
+
+    @staticmethod
+    def _step(codec, group=1, max_batch=4, seed=5):
+        from deepspeed_tpu.runtime.comm.codecs import CODECS
+        dtype = jnp.float32 if codec is None else CODECS[codec].dtype
+        spec = _spec(dtype=dtype, codec=codec, max_batch=max_batch,
+                     max_seq=16, page_size=8)
+        rng = np.random.default_rng(seed)
+        H, D = spec.n_head, spec.head_dim
+        layer = init_kv_cache(spec)["h_0"]
+        tables = np.asarray(_tables(spec)).copy()
+        # rows 0 and 3 hold requests (8 and 3 tokens in), rows 1 and 2
+        # none: position 0, an all-trash table
+        tables[1:3] = 0
+        fill = jnp.asarray(rng.normal(size=(max_batch, 8, H, D)),
+                           jnp.float32)
+        for b, n in ((0, 8), (3, 3)):
+            layer = paged_write_kv(
+                layer, fill[b:b + 1, :n], fill[b:b + 1, :n],
+                jnp.arange(n, dtype=jnp.int32)[None], tables[b:b + 1])
+        q = jnp.asarray(rng.normal(size=(max_batch, 1, H * group, D)),
+                        jnp.float32)
+        k, v = (jnp.asarray(rng.normal(size=(max_batch, 1, H, D)),
+                            jnp.float32) for _ in "kv")
+        pos = jnp.asarray([[8], [0], [0], [3]], jnp.int32)
+        return q, k, v, layer, pos, jnp.asarray(tables)
+
+    @pytest.mark.parametrize("block_k", [8, 4], ids=["block=page",
+                                                     "block<page"])
+    @pytest.mark.parametrize("codec,atol", STORAGE)
+    @pytest.mark.parametrize("group", [1, 4])
+    def test_flash_step_equals_dense_step(self, group, codec, atol,
+                                          block_k):
+        q, k, v, layer, pos, tables = self._step(codec, group)
+        y_d, dense = cached_attention(q, k, v, layer, pos, jnp.float32,
+                                      tables)
+        y_f, flash = cached_attention(q, k, v, layer, pos, jnp.float32,
+                                      tables, impl="flash",
+                                      block_k=block_k)
+        live = [0, 3]
+        np.testing.assert_allclose(np.asarray(y_f)[live],
+                                   np.asarray(y_d)[live], atol=atol)
+        assert not np.asarray(y_f)[[1, 2]].any()
+        assert set(flash) == set(layer)
+        for name in layer:
+            # the whole pool but the trash page, where the loop puts the
+            # rows without a request and the kernel puts nothing
+            np.testing.assert_array_equal(np.asarray(flash[name])[1:],
+                                          np.asarray(dense[name])[1:],
+                                          err_msg=name)
+            np.testing.assert_array_equal(np.asarray(flash[name])[0],
+                                          np.asarray(layer[name])[0])
+        # row 0 began a page (position 8 is its second page's lane 0)
+        assert np.asarray(flash["k"])[int(tables[0, 1]), ..., 0].any()
+
+    def test_the_flash_step_traces_no_loop_and_no_slab_write(self):
+        """What went: the 48-slab loop, two leaves a layer. The dense
+        step and a verify chunk (``B > 1, T > 1``) keep it."""
+        q, k, v, layer, pos, tables = self._step(None)
+
+        def prims(fn, *args):
+            """Primitives of the traced program, the bodies of nested
+            jits included, a kernel's own body not."""
+            out, todo = set(), [jax.make_jaxpr(fn)(*args).jaxpr]
+            while todo:
+                for eqn in todo.pop().eqns:
+                    out.add(eqn.primitive.name)
+                    if eqn.primitive.name != "pallas_call":
+                        todo += [getattr(p, "jaxpr", p)
+                                 for p in eqn.params.values()
+                                 if hasattr(getattr(p, "jaxpr", p), "eqns")]
+            return out
+
+        def step(**kw):
+            return prims(lambda *a: cached_attention(
+                *a, jnp.float32, tables, **kw), q, k, v, layer, pos)
+        def loops(ps):      # a `fori_loop` of known length traces to a scan
+            return bool({"scan", "while"} & ps) and \
+                "dynamic_update_slice" in ps
+
+        flash = step(impl="flash", block_k=8)
+        assert "pallas_call" in flash and not loops(flash)
+        assert not {"scan", "while", "dynamic_update_slice"} & flash
+        assert loops(step()) and "pallas_call" not in step()
+        chunk = jnp.concatenate([pos, pos + 1], 1)
+        verify = prims(lambda q, k, v: cached_attention(
+            q, k, v, layer, chunk, jnp.float32, tables, impl="flash",
+            block_k=8), *(jnp.tile(x, (1, 2, 1, 1)) for x in (q, k, v)))
+        assert loops(verify) and "pallas_call" not in verify
+
+    def test_write_tokens_has_the_callers_it_is_kept_for(self):
+        """`_write_tokens` stays for speculative verify and the dense
+        decode step; the flash decode arm must not reach it."""
+        import inspect
+
+        from deepspeed_tpu.inference import cache
+        assert "_write_tokens(" not in inspect.getsource(
+            cache._flash_attend_paged)
+        src = inspect.getsource(cache.cached_attention)
+        assert src.index("_flash_attend_paged(") < src.index(
+            "paged_write_kv(")
+        callers = [name for name, fn in inspect.getmembers(
+            cache, inspect.isfunction)
+            if "_write_tokens(" in inspect.getsource(fn)
+            and name != "_write_tokens"]
+        assert callers == ["paged_write_kv"]

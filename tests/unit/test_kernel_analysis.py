@@ -297,11 +297,11 @@ def test_stock_decode_zero_findings(paged_report):
         assert kd["races"] == []
         assert kd["tiling"] == []
         pools = [op for op in kd["operands"].values()
-                 if op["manual_dma"]]
+                 if op["manual_dma"] and op["kind"] == "input"]
         # the kernel leaves the pool in HBM and walks it: K and V
         # launch exactly the live blocks (q and out are one row block a
         # grid step, with nothing to elide), and only the two double
-        # buffers are VMEM
+        # buffers are VMEM scratch
         assert len(pools) == 2 and kd["grid"] == [2]
         assert all(op["elided_fraction"] == pytest.approx(
             TOY_EXPECTED_ELISION) for op in pools)
@@ -403,7 +403,9 @@ def test_paged_kernel_is_priced_by_its_walk():
     """`flash_decode_paged` leaves the pool in `ANY` memory and fetches
     by manual DMA: no block of it is pipelined (the two slots are
     scratch), and its traffic is the declared walk's — the live rows'
-    live blocks against the dense rectangle."""
+    live blocks against the dense rectangle on the pool as input, the
+    one block a live row's position falls in, written back, on the pool
+    as output (the same buffer: the call aliases them)."""
     from deepspeed_tpu.ops.pallas import flash_decode_paged
 
     H, D, page = 2, 8, 8
@@ -411,29 +413,33 @@ def test_paged_kernel_is_priced_by_its_walk():
     pool = jnp.asarray(rng.normal(size=(9, H, D, page)), jnp.float32)
     q = jnp.asarray(rng.normal(size=(4, 1, H, D)), jnp.float32)
     ana = analyze_kernels(
-        lambda *a: flash_decode_paged(*a, block_k=8),
-        (q, pool, pool, jnp.asarray(WALK_POSITIONS),
-         jnp.asarray(WALK_TABLES)))
+        lambda q, new, pool, pos, pt: flash_decode_paged(
+            q, new, pool, pos, pt, block_k=8),
+        (q, {"k": q, "v": q}, {"k": pool, "v": pool},
+         jnp.asarray(WALK_POSITIONS), jnp.asarray(WALK_TABLES)))
     k, = ana.kernels
     assert k.name == "ds_flash_decode_paged" and k.grid == (4,)
     ops = {op.name: op for op in k.operands}
     block = H * D * 8 * 4
-    for name in ("in1", "in2"):                     # K and V
+    for name, traffic in (("in2", (16, 8)), ("in3", (16, 8)),   # K and V
+                          ("out1", (4, 3)), ("out2", (4, 3))):  # written
         op = ops[name]
         assert op.manual_dma and op.index_map_evaluated
         assert op.block_shape == (H, D, 8) and op.block_bytes == block
-        assert (op.total_fetches, op.dma_fetches) == (16, 8)
-    assert not ops["in0"].manual_dma and not ops["out0"].manual_dma
-    # VMEM: q and out double-buffered, plus the four scratch slots
-    row = H * D * 4
+        assert (op.total_fetches, op.dma_fetches) == traffic
+    assert not any(ops[name].manual_dma for name in ("in0", "in1", "out0"))
+    # VMEM: q, out and the step's new lanes double-buffered, plus the
+    # four scratch slots (the two flags in SMEM are not VMEM)
+    row, new = H * D * 4, 2 * H * D * 128 * 4
     assert k.scratch_bytes == 4 * block
-    assert k.vmem_bytes == 2 * 2 * row + 4 * block
-    assert k.dma_bytes == 2 * 8 * block + 2 * 4 * row
+    assert k.vmem_bytes == 2 * (2 * row + new) + 4 * block
+    # the new lanes' one block of 128 rows is fetched once
+    assert k.dma_bytes == 2 * 8 * block + 2 * 3 * block + 2 * 4 * row + new
     # the contract the audit declares for this scenario holds, and a
     # kernel that walked every block would not meet it
     expected = paged_dead_block_fraction(WALK_POSITIONS, WALK_TABLES, 8, 8)
     assert _kernel_rule_findings(ana, expected) == []
-    for name in ("in1", "in2"):
+    for name in ("in2", "in3"):
         ops[name].dma_fetches = 16
     dense, = _kernel_rule_findings(ana, expected)
     assert dense.rule == "kernel_dma" and dense.severity == SEV_WARNING
@@ -461,17 +467,18 @@ def test_flash_decode_geometry_errors():
     q = jnp.asarray(rng.normal(size=(B, 1, H, D)), jnp.float32)
     pos = jnp.zeros((B,), jnp.int32)
     n_pages, page_size, ppr = 5, 16, 2
-    pool_k = jnp.zeros((n_pages, H, D, page_size), jnp.float32)
-    pool_v = jnp.zeros((n_pages, H, D, page_size), jnp.float32)
+    pool = {"k": jnp.zeros((n_pages, H, D, page_size), jnp.float32),
+            "v": jnp.zeros((n_pages, H, D, page_size), jnp.float32)}
+    new = {"k": q, "v": q}
     tables = jnp.zeros((B, ppr), jnp.int32)
 
     assert issubclass(KernelGeometryError, ValueError)
     # block_k < 1 is a typed geometry error, not a ZeroDivisionError
     with pytest.raises(KernelGeometryError, match=">= 1"):
-        flash_decode_paged(q, pool_k, pool_v, pos, tables, block_k=0)
+        flash_decode_paged(q, new, pool, pos, tables, block_k=0)
     # block_k must divide page_size, validated before lowering
     with pytest.raises(KernelGeometryError, match="multiple"):
-        flash_decode_paged(q, pool_k, pool_v, pos, tables, block_k=3)
+        flash_decode_paged(q, new, pool, pos, tables, block_k=3)
 
     # the compiled-only rule (interpret=False is what a TPU build
     # checks; the engine runs the same check when it is built): every

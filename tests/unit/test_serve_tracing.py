@@ -213,7 +213,8 @@ def test_step_attrs_are_the_steps_counters(served):
     decodes = [r[3] for r in records if r[0] == "serve/step/decode"]
     flash = sched.engine.attention_impl == "flash"
     assert decodes and all(
-        (set(a or ()) == {"kv_blocks_live", "kv_blocks_launched"})
+        (set(a or ()) == {"kv_blocks_live", "kv_blocks_launched",
+                          "kv_rows_live", "kv_rows_written"})
         == flash for a in decodes)
     assert steps[-1]["live_rows"] == 0 == steps[-1]["queue_depth"]
 

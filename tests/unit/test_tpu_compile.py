@@ -202,29 +202,50 @@ DECODE_GEOMETRIES = {"medium": (16, 64), "xl": (25, 64), "tp4": (4, 64),
                      "d128": (16, 128)}
 
 
+def decode_call(chip, rows, heads, head_dim, kv_dtype, per, group=1,
+                **kw):
+    """``(fn, args)``: `flash_decode_paged` compiled, not interpreted,
+    over abstract arrays on the chip: ``rows`` rows of ``per`` pages,
+    the pool (``fn``'s first argument, to donate) and the step's new
+    leaves in ``kv_dtype``, scale leaves beside a codec's. A served
+    checkpoint computes in f32 (q and the cache both); the other
+    storage dtypes sit under a bf16 model."""
+    from deepspeed_tpu.ops.pallas.flash_decode import flash_decode_paged
+
+    dt = jnp.dtype(kv_dtype)
+    n_pages = rows * per + 1
+    pool = {"k": chip((n_pages, heads, head_dim, PAGE), dt)}
+    new = {"k": chip((rows, 1, heads, head_dim), dt)}
+    if dt.itemsize == 1:
+        pool["k_scale"] = chip((n_pages, heads, PAGE), jnp.float32)
+        new["k_scale"] = chip((rows, 1, heads), jnp.float32)
+    for tree in (pool, new):
+        tree.update({"v" + name[1:]: leaf for name, leaf in tree.items()})
+    q = chip((rows, 1, heads * group, head_dim),
+             dt if kv_dtype == "float32" else jnp.bfloat16)
+
+    def fn(pool, q, new, pos, pt):
+        return flash_decode_paged(q, new, pool, pos, pt, interpret=False,
+                                  **kw)
+    return fn, (pool, q, new, chip((rows,), jnp.int32),
+                chip((rows, per), jnp.int32))
+
+
 @pytest.mark.parametrize("kv_dtype", KV_DTYPES)
 @pytest.mark.parametrize("geometry", list(DECODE_GEOMETRIES))
 def test_flash_decode_compiles(chip, geometry, kv_dtype):
-    from deepspeed_tpu.ops.pallas.flash_decode import flash_decode_paged
+    """Every pool dtype and geometry, the step's write with it: the new
+    lanes rolled to their place, selected into the block in float32 and
+    rounded back to the storage dtype, the block DMA'd back. With the
+    pool donated the call's aliasing holds: nothing pool-shaped is
+    copied round the kernel."""
+    from deepspeed_tpu.analysis.hlo import payload_shaped_copies
 
     heads, head_dim = DECODE_GEOMETRIES[geometry]
-    dt = jnp.dtype(kv_dtype)
-    quant = dt.itemsize == 1
-    # a served checkpoint computes in f32 (q and the cache both); the
-    # other storage dtypes sit under a bf16 model
-    q = chip((B, 1, heads, head_dim),
-             dt if kv_dtype == "float32" else jnp.bfloat16)
-    pos = chip((B,), jnp.int32)
-    n_pages = B * (T // PAGE) + 1
-    kv = chip((n_pages, heads, head_dim, PAGE), dt)
-    tables = chip((B, T // PAGE), jnp.int32)
-    scales = (chip((n_pages, heads, PAGE), jnp.float32),) * 2 \
-        if quant else ()
-
-    def fn(q, k, v, pos, pt, *s):
-        return flash_decode_paged(q, k, v, pos, pt, *s, interpret=False)
-    assert "tpu_custom_call" in compiled_text(fn, q, kv, kv, pos, tables,
-                                              *scales)
+    fn, args = decode_call(chip, B, heads, head_dim, kv_dtype, T // PAGE)
+    text = jax.jit(fn, donate_argnums=0).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert payload_shaped_copies(text, args[0]["k"].shape) == []
 
 
 # (rows, tokens a row): the decode step, one prefill chunk, a
@@ -268,6 +289,19 @@ def test_paged_layer_copies_no_pool(chip, monkeypatch, program, kv_dtype):
     assert ("tpu_custom_call" in text) == (program == "decode")
     # the kernel takes the 4-D pool as it is (`ANY` memory)
     assert payload_shaped_copies(text, pool) == []
+    # the decode step's write is the kernel's (PR 33): the program
+    # holds no loop and no update of a slab of a pool leaf; the other
+    # two keep theirs (`_write_chunk`, `_write_tokens`)
+    leaf = "[%d,%d,%d,%d]" % pool       # an op's result precedes its opcode
+    slab_writes = [line for line in text.splitlines()
+                   if leaf in line.partition("dynamic-update-slice(")[0]
+                   and "dynamic-update-slice(" in line]
+    loops = [line for line in text.splitlines() if " while(" in line]
+    if program == "decode":
+        assert slab_writes == [] and loops == []
+    else:
+        assert slab_writes
+        assert bool(loops) == (program == "verify")
 
 
 def kernel_grids(lowered_text):
@@ -307,15 +341,8 @@ def test_flash_decode_grid(chip):
     launched rows x heads x blocks = 6,144 whatever the rows held
     (`PERF.md` section 6), and a change that brings that back fails
     here."""
-    from deepspeed_tpu.ops.pallas.flash_decode import flash_decode_paged
-
-    q = chip((ROWS, 1, H, D), jnp.float32)
-    pos = chip((ROWS,), jnp.int32)
-    kv = chip((N_PAGES, H, D, PAGE), jnp.float32)
-    lowered = jax.jit(lambda q, k, v, pos, pt: flash_decode_paged(
-        q, k, v, pos, pt, interpret=False)).lower(
-            q, kv, kv, pos, chip((ROWS, T // PAGE), jnp.int32))
-    assert kernel_grids(lowered.as_text()) == [(ROWS,)]
+    fn, args = decode_call(chip, ROWS, H, D, "float32", T // PAGE)
+    assert kernel_grids(jax.jit(fn).lower(*args).as_text()) == [(ROWS,)]
 
 
 @pytest.mark.parametrize("shape", [(50257, 1024), (1024, 4096), (1024,)],
@@ -362,21 +389,9 @@ def test_grouped_query_flash_decode_compiles(chip, kv_dtype):
     """The decode kernel with 4 query heads to each of 8 key heads and
     the model's own score scale, at the hybrid cell's shapes: 48 rows,
     pages of 128, a pool of 48 x 36 pages."""
-    from deepspeed_tpu.ops.pallas.flash_decode import flash_decode_paged
-
-    dt = jnp.dtype(kv_dtype)
-    n_pages, per = ROWS * 36 + 1, 36
-    q = chip((ROWS, 1, 32, 64),
-             dt if kv_dtype == "float32" else jnp.bfloat16)
-    kv = chip((n_pages, 8, 64, PAGE), dt)
-    scales = (chip((n_pages, 8, PAGE), jnp.float32),) * 2 \
-        if dt.itemsize == 1 else ()
-
-    def fn(q, k, v, pos, pt, *s):
-        return flash_decode_paged(q, k, v, pos, pt, *s, interpret=False,
-                                  scale=1 / 64)
-    lowered = jax.jit(fn).lower(q, kv, kv, chip((ROWS,), jnp.int32),
-                                chip((ROWS, per), jnp.int32), *scales)
+    fn, args = decode_call(chip, ROWS, 8, 64, kv_dtype, 36, group=4,
+                           scale=1 / 64)
+    lowered = jax.jit(fn).lower(*args)
     assert kernel_grids(lowered.as_text()) == [(ROWS,)]
     assert "ds_flash_decode_paged" in lowered.compile().as_text()
 
@@ -560,8 +575,8 @@ def test_tp_sharded_flash_decode_compiles(topo, monkeypatch):
 
     q = on((B, 1, H, D), jnp.bfloat16, None, None, "model")
     positions = on((B, 1), jnp.int32)
-    # the whole layer: GSPMD partitions the write over the pool's
-    # head axis, the kernel runs under `shard_map`
+    # the whole layer: the kernel, the step's write in it, runs under
+    # `shard_map`, the pool in and out on its head axis
     n_pages = B * (T // PAGE) + 1
     kv = on((n_pages, H, D, PAGE), jnp.bfloat16, None, "model")
     tables = on((B, T // PAGE), jnp.int32)
@@ -575,6 +590,7 @@ def test_tp_sharded_flash_decode_compiles(topo, monkeypatch):
     assert "tpu_custom_call" in text
     assert payload_shaped_copies(text, (n_pages, H // 4, D, PAGE)) == []
     assert "all-gather" not in text and "all-to-all" not in text
+    assert " while(" not in text and "dynamic-update-slice(" not in text
 
 
 def _flash_neighbours(hlo_text):
