@@ -52,6 +52,29 @@ leaf are donated to and updated in place by the same two programs.
 What moves or shares pages (the prefix cache, park/resume, speculative
 verify, the disaggregated hand-off, a ``model`` mesh axis) knows no
 state and refuses such a spec: :class:`RecurrentStateUnsupported`.
+
+**A latent pool** (ISSUE 34). A layer of latent attention
+(`models/mla_moe.py`) keeps, for a token, one compressed vector that
+stands for every head's key and value, and one rotary key that all
+heads share: ``latent_v_dim + rope`` numbers (512 + 64) where per-head
+keys and values would be ``2 x heads x head_dim`` (32,768). Its pool is
+**one leaf a layer**, ``k`` ``[n_pages, 1, latent_v_dim + rope,
+page_size]``: one "head", no ``v`` leaf, positions on the lanes like
+every other pool. In the absorbed form the whole vector is the key of
+every query head and its leading ``latent_v_dim`` entries are the
+value, so the decode kernel reads a block once for both
+(`flash_decode_paged`), and :func:`payload_shape`,
+:func:`_layer_leaves`, the writes and :func:`paged_read_kv` are the
+same code with one leaf less. A prefill chunk attends over the row's
+live prefix in blocks (:func:`latent_prefill_attention`): the dense
+path's ``[heads, chunk, bucket]`` scores are 4.4 GB at 64 heads, a
+chunk of 1024 and a bucket of 16,896. Pages are pages: the allocator,
+the prefix cache and park/resume move latent pages like any others.
+What knows per-head vectors refuses such a spec
+(:class:`LatentPoolUnsupported`): the int8 / fp8 codecs (one scale a
+576-wide vector is another precision than one a head of 64; not
+measured), a ``model`` mesh axis (one head cannot be split), and
+speculative verify and the tiers, which nothing has run on one.
 """
 
 import dataclasses
@@ -77,6 +100,19 @@ class RecurrentStateUnsupported(ValueError):
             f"beside its KV pages: {why}")
 
 
+class LatentPoolUnsupported(ValueError):
+    """A serving feature that assumes per-head keys and values (a
+    ``v`` leaf, a scale a head vector, a head axis to shard) was asked
+    of a latent pool. Raised when the spec or the engine is built,
+    before anything is traced."""
+
+    def __init__(self, feature, why):
+        self.feature = feature
+        super().__init__(
+            f"{feature} cannot serve a pool of latents (one leaf a "
+            f"layer, one head): {why}")
+
+
 @dataclasses.dataclass(frozen=True)
 class KVCacheSpec:
     """Static shape + storage format of one engine's cache: the page
@@ -98,6 +134,9 @@ class KVCacheSpec:
     # every such layer: (leaf, shape with its max_batch axis, dtype)
     recurrent_layers: tuple = ()
     recurrent_leaves: tuple = ()
+    # > 0: a latent pool. One leaf a layer (``k``, no ``v``), whose
+    # first ``latent_v_dim`` of ``head_dim`` entries are also the value
+    latent_v_dim: int = 0
 
     @property
     def pages_per_row(self):
@@ -119,6 +158,13 @@ def refuse_recurrent(spec, feature, why):
         raise RecurrentStateUnsupported(feature, why)
 
 
+def refuse_latent(spec, feature, why):
+    """The one check of everything that knows per-head keys and values
+    only."""
+    if spec.latent_v_dim:
+        raise LatentPoolUnsupported(feature, why)
+
+
 def spec_for_model(model, *args, **kwargs):
     """The :class:`KVCacheSpec` a model (or its config) describes for
     itself: ``model.cache_spec(max_batch, max_seq, kv_cache_dtype=None,
@@ -130,15 +176,16 @@ def spec_for_model(model, *args, **kwargs):
 def page_pool_spec(max_batch, max_seq, *, n_layer, n_head, head_dim,
                    compute_dtype, n_positions, stacked=False,
                    kv_cache_dtype=None, page_size=0, n_pages=0,
-                   **recurrent):
+                   latent_v_dim=0, **recurrent):
     """A :class:`KVCacheSpec` from a model's own numbers (``n_layer``
     layers with ``n_head`` key/value heads of ``head_dim``) and the
     ``inference.kv_cache_dtype`` knob (None = model compute dtype,
     "bf16"/"f32" = plain storage, a codec name = quantized storage).
     ``page_size`` must divide ``max_seq``; ``n_pages=0`` defaults to
     every row filling its ``max_seq`` span at once, plus the trash
-    page. ``recurrent``: the spec's ``layers`` / ``recurrent_layers`` /
-    ``recurrent_leaves``."""
+    page. ``latent_v_dim`` > 0: a latent pool (one head, one leaf a
+    layer; plain storage only). ``recurrent``: the spec's ``layers`` /
+    ``recurrent_layers`` / ``recurrent_leaves``."""
     codec = None
     if kv_cache_dtype is None:
         dtype = compute_dtype
@@ -153,6 +200,19 @@ def page_pool_spec(max_batch, max_seq, *, n_layer, n_head, head_dim,
         raise ValueError(
             f"kv_cache_dtype must be None, 'bf16', 'f32', or a codec "
             f"name from {sorted(CODECS)}; got {kv_cache_dtype!r}")
+    if latent_v_dim and (int(n_head) != 1 or
+                         not 0 < int(latent_v_dim) <= int(head_dim)):
+        raise ValueError(
+            f"a latent pool has one head whose first latent_v_dim "
+            f"entries are the value: got n_head {n_head}, head_dim "
+            f"{head_dim}, latent_v_dim {latent_v_dim}")
+    if latent_v_dim and codec is not None:
+        raise LatentPoolUnsupported(
+            f"inference.kv_cache_dtype {kv_cache_dtype!r}",
+            "the codecs keep one scale a head vector, and a latent is "
+            "one vector of compressed keys and values beside a rotary "
+            "key: one scale over all of it is a precision nobody has "
+            "measured")
     if max_seq > n_positions:
         raise ValueError(
             f"max seq bucket {max_seq} exceeds the model's n_positions "
@@ -175,18 +235,24 @@ def page_pool_spec(max_batch, max_seq, *, n_layer, n_head, head_dim,
         max_seq=int(max_seq), n_head=int(n_head),
         head_dim=int(head_dim), dtype=dtype, codec=codec,
         stacked=bool(stacked), page_size=page_size,
-        n_pages=n_pages, **recurrent)
+        n_pages=n_pages, latent_v_dim=int(latent_v_dim), **recurrent)
 
 
 def payload_shape(spec):
-    """The shape of one layer's K (or V) pool."""
+    """The shape of one layer's K (or V) pool; of a latent pool's one
+    leaf, ``[n_pages, 1, latent_v_dim + rope, page_size]``."""
     return (spec.n_pages, spec.n_head, spec.head_dim, spec.page_size)
+
+
+def _payload_names(spec):
+    """A layer's payload leaves: keys and values, or the latent alone."""
+    return ("k",) if spec.latent_v_dim else ("k", "v")
 
 
 def _layer_leaves(spec):
     shape = payload_shape(spec)
-    leaves = {"k": jnp.zeros(shape, spec.dtype),
-              "v": jnp.zeros(shape, spec.dtype)}
+    leaves = {name: jnp.zeros(shape, spec.dtype)
+              for name in _payload_names(spec)}
     if spec.codec is not None:
         # one scale per (position, head): the payload less head_dim
         sshape = shape[:2] + shape[3:]
@@ -240,6 +306,9 @@ def kv_partition_specs(spec, model_axis="model"):
     row-parallel ``c_proj`` psum GSPMD inserts is the only combine.
     The pool keeps heads on axis 1 (``[n_pages, H, D, page_size]``)."""
     from jax.sharding import PartitionSpec as P
+    refuse_latent(spec, "a 'model' mesh axis over the cache's heads",
+                  "a latent is one head, and every query head reads all "
+                  "of it")
     lead = (None,) if spec.stacked else ()
     # no trailing None after the sharded head axis: jit keys compiled
     # programs on the exact sharding object, and GSPMD canonicalizes
@@ -358,7 +427,14 @@ def _new_leaves(layer_cache, k_new, v_new):
     """A chunk's keys and values as the pool stores them, under the
     pool's leaf names, position-major: ``[B, T, H, D]`` payloads and,
     for a codec pool (quantized here), ``[B, T, H]`` scales, one per
-    (position, head)."""
+    (position, head). A latent pool (no ``v`` leaf) takes ``k_new``
+    alone."""
+    if ("v" in layer_cache) != (v_new is not None):
+        raise ValueError(
+            f"the pool's leaves are {sorted(layer_cache)}: new values go "
+            f"with a v leaf, and a latent pool takes its latents alone")
+    if v_new is None:
+        return {"k": k_new}
     codec = _codec_of(layer_cache)
     if codec is None:
         return {"k": k_new, "v": v_new}     # cast where they are written
@@ -426,7 +502,8 @@ def paged_read_kv(layer_cache, page_table, dtype):
     key/value buffers in compute ``dtype`` (S = pages_per_row *
     page_size) — the dense oracle's view of the pool. Trash /
     unallocated entries gather page 0's garbage, which the position
-    mask hides like any stale slot."""
+    mask hides like any stale slot. A latent pool has no values of its
+    own: ``(latents, None)``."""
     codec = _codec_of(layer_cache)
 
     def gather(buf):
@@ -435,6 +512,8 @@ def paged_read_kv(layer_cache, page_table, dtype):
         B, n_pt, ps = g.shape[:3]
         return g.reshape((B, n_pt * ps) + g.shape[3:])
 
+    if "v" not in layer_cache:
+        return gather(layer_cache["k"]).astype(dtype), None
     if codec is None:
         return (gather(layer_cache["k"]).astype(dtype),
                 gather(layer_cache["v"]).astype(dtype))
@@ -445,7 +524,7 @@ def paged_read_kv(layer_cache, page_table, dtype):
 
 
 def _flash_attend_paged(q, new, layer_cache, positions, page_table,
-                        block_k, mesh, scale=None):
+                        block_k, mesh, scale=None, v_dim=None):
     """A flash decode step straight over the STORAGE pool: the step's
     ``new`` keys and values (:func:`_new_leaves`) go into the pool and
     the rows attend over it in one kernel; returns ``(y, layer_cache)``.
@@ -468,7 +547,7 @@ def _flash_attend_paged(q, new, layer_cache, positions, page_table,
 
     def attend(q_, new_, pool_, pos_, table_):
         return flash_decode_paged(q_, new_, pool_, pos_, table_,
-                                  block_k=block_k, scale=scale)
+                                  block_k=block_k, scale=scale, v_dim=v_dim)
 
     if mesh is not None:
         from jax.sharding import PartitionSpec as P
@@ -484,9 +563,87 @@ def _flash_attend_paged(q, new, layer_cache, positions, page_table,
     return attend(q, new, layer_cache, positions[:, 0], page_table)
 
 
+def _walk_pages(pages_per_row, page_size, limit):
+    """Pages to a block of the prefill walk: the largest divisor of the
+    table's width whose block holds at most ``limit`` positions."""
+    return max(n for n in range(1, pages_per_row + 1)
+               if pages_per_row % n == 0 and
+               (n == 1 or n * page_size <= limit))
+
+
+# positions to a block of a prefill chunk's walk over a latent pool: the
+# float32 scores of a block are [heads, chunk, WALK_BLOCK]
+WALK_BLOCK = 1024
+
+
+def latent_prefill_attention(q, layer_cache, positions, page_table, *,
+                             expand, scale, compute_dtype):
+    """One prompt's chunk of queries over the row's live prefix in a
+    latent pool, the chunk's own latents already written: blocks of
+    whole pages, gathered through the table one after the other under a
+    running max and sum (float32), so that the largest array is
+    ``[heads, chunk, block]`` whatever the bucket. Only the blocks up
+    to the chunk's last position are visited (a ``while`` over ``pos //
+    block + 1``), and the mask is the pool's own: cache index ``s`` for
+    the query at ``p`` iff ``s <= p``.
+
+    Each block is expanded as it is met: ``expand(latents [S, D]) ->
+    (k [S, H, dk], v [S, H, dv])`` is the caller's up-projection beside
+    the shared rotary key. ``q`` is ``[1, T, H, dk]``; returns ``[1, T,
+    H, dv]``. That costs ``dk + dv`` multiply-adds a query-key pair and
+    the block's expansion once a chunk; scoring the latents as they lie
+    (the decode step's absorbed form: ``D + v_dim`` a pair, nothing
+    expanded) read 5 to 7 % slower at every prefix on the chip
+    (`PERF.md` section 6, PR 34), so a chunk has this one form.
+    """
+    pool = layer_cache["k"]
+    page_size = pool.shape[-1]
+    _, T, H, _ = q.shape
+    n_table = page_table.shape[-1]
+    bp = _walk_pages(n_table, page_size, WALK_BLOCK)
+    S = bp * page_size
+    pos = positions[0]                                   # [T]
+    n_blocks = pos[-1] // S + 1
+    qh = jnp.swapaxes(q[0], 0, 1)                        # [H, T, dq]
+
+    def block(i, carry):
+        m_prev, l_prev, acc = carry
+        pages = jax.lax.dynamic_slice_in_dim(page_table[0], i * bp, bp)
+        lat = jnp.take(pool, pages, axis=0)[:, 0]        # [bp, D, page]
+        lat = jnp.moveaxis(lat, -1, 1).reshape(S, -1)    # [S, D]
+        lat = lat.astype(compute_dtype)
+        kb, vb = expand(lat)                             # [S, H, dk|dv]
+        s = jnp.einsum("htd,shd->hts", qh, kb,
+                       preferred_element_type=jnp.float32)
+        k_pos = i * S + jnp.arange(S)
+        s = jnp.where(k_pos[None, None, :] <= pos[None, :, None],
+                      s * scale, jnp.finfo(jnp.float32).min)
+        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+        pr = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_new = l_prev * corr + pr.sum(-1, keepdims=True)
+        pr = pr.astype(compute_dtype)
+        pv = jnp.einsum("hts,shv->htv", pr, vb,
+                        preferred_element_type=jnp.float32)
+        return m_new, l_new, acc * corr + pv
+
+    # the output's width, from the expansion's shapes alone
+    dv = jax.eval_shape(
+        expand, jax.ShapeDtypeStruct((S, pool.shape[2]),
+                                     compute_dtype))[1].shape[-1]
+    _, l, acc = jax.lax.fori_loop(
+        0, n_blocks, block,
+        (jnp.full((H, T, 1), -jnp.inf, jnp.float32),
+         jnp.zeros((H, T, 1), jnp.float32),
+         jnp.zeros((H, T, dv), jnp.float32)))
+    y = (acc / jnp.maximum(l, 1e-30)).astype(compute_dtype)
+    return jnp.swapaxes(y, 0, 1)[None]                   # [1, T, H, dv]
+
+
 def cached_attention(q, k_new, v_new, layer_cache, positions,
                      compute_dtype, page_table, impl="dense",
-                     block_k=128, mesh=None, mask=None, scale=None):
+                     block_k=128, mesh=None, mask=None, scale=None,
+                     v_dim=None, expand=None):
     """Write this chunk's k/v, then attend over the whole cache row.
 
     ``q``/``k_new``/``v_new``: ``[B, T, H, D]`` (T = 1 for a decode
@@ -526,23 +683,49 @@ def cached_attention(q, k_new, v_new, layer_cache, positions,
     for padded chunk tails / recycled-page remnants it hides everything
     until a real token overwrites the slot. Pages only change where
     bytes live, never what the mask admits.
+
+    **A latent pool** (no ``v`` leaf; ``v_new`` None, ``v_dim`` the
+    values' width, ``scale`` given): ``k_new`` is the chunk's latents
+    ``[B, T, 1, D]``. A decode step takes every head's query in the
+    latent's own space (the absorbed form), runs the same kernel
+    (which reads a block once for keys and values) or the dense oracle
+    below, and ``y`` comes back ``[B, 1, Hq, v_dim]``. One prompt's
+    chunk (``B == 1``, ``T > 1``) is written by :func:`paged_write_kv`
+    and attends by :func:`latent_prefill_attention` through the
+    caller's ``expand``, with ``q`` of the expanded width.
     """
+    latent = "v" not in layer_cache
+    if latent and (v_new is not None or v_dim is None or scale is None):
+        raise ValueError(
+            "a latent pool takes the chunk's latents alone (v_new None) "
+            "with the values' width v_dim and the scores' scale")
     if impl == "flash" and q.shape[1] == 1:
         y, layer_cache = _flash_attend_paged(
             q, _new_leaves(layer_cache, k_new, v_new), layer_cache,
-            positions, page_table, block_k, mesh, scale)
+            positions, page_table, block_k, mesh, scale, v_dim)
         return y.astype(compute_dtype), layer_cache
     layer_cache = paged_write_kv(layer_cache, k_new, v_new, positions,
                                  page_table)
+    if latent and q.shape[1] > 1:
+        if q.shape[0] != 1 or expand is None:
+            raise ValueError(
+                "a latent pool attends several tokens at once only as "
+                "one prompt's chunk (one row), through expand")
+        return latent_prefill_attention(
+            q, layer_cache, positions, page_table, expand=expand,
+            scale=scale, compute_dtype=compute_dtype), layer_cache
     if mask is None:
         mask = attention_mask(layer_cache, positions, page_table)
     k_full, v_full = paged_read_kv(layer_cache, page_table, compute_dtype)
+    if latent:
+        v_full = k_full[..., :v_dim]
     B, T, Hq, D = q.shape
     H = k_full.shape[2]
     if scale is None:
         scale = 1.0 / jnp.sqrt(jnp.asarray(D, compute_dtype))
     else:
-        scale = jnp.asarray(scale, compute_dtype)
+        # a latent's scale (0.1447) is no bfloat16 number
+        scale = jnp.asarray(scale, jnp.float32 if latent else compute_dtype)
     if Hq == H:
         att = jnp.einsum("bthd,bshd->bhts", q, k_full) * scale
         att = jnp.where(mask[:, None], att, jnp.finfo(att.dtype).min)
@@ -558,4 +741,4 @@ def cached_attention(q, k_new, v_new, layer_cache, positions,
                     jnp.finfo(jnp.float32).min)
     att = jax.nn.softmax(att, axis=-1).astype(compute_dtype)
     y = jnp.einsum("bhgts,bshd->bthgd", att, v_full)
-    return y.reshape(B, T, Hq, D), layer_cache
+    return y.reshape(B, T, Hq, v_full.shape[-1]), layer_cache

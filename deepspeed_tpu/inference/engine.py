@@ -41,8 +41,9 @@ decode matmuls and attention run tensor-parallel with GSPMD inserting
 the row-parallel psums.
 
 **The model's side of the seam** (ISSUE 31; `models/gpt2.py:GPT2LMHead`
-and `models/granite_hybrid.py:GraniteHybridLM` both answer it, and the
-engine asks nothing else of a model):
+`models/granite_hybrid.py:GraniteHybridLM` and
+`models/mla_moe.py:MlaMoeLM` answer it, and the engine asks nothing
+else of a model):
 
 - ``model.cache_spec(max_batch, max_seq, kv_cache_dtype=None,
   page_size=0, n_pages=0)`` -> the :class:`~deepspeed_tpu.inference.
@@ -57,6 +58,10 @@ engine asks nothing else of a model):
   tokens are real: a prefill chunk is one row of ``prefill_chunk``
   tokens, the last chunk's tail padding; a decode step is every row
   with one token, ``n_valid`` 0 where the slot holds no request;
+- optionally ``model.serve_counters``, names of int32 scalars that
+  ``serve_apply`` then returns third, as a dict (the expert layers'
+  pairs of `models/mla_moe.py`): a decode step carries them home
+  behind its tokens and puts them on its ``decode`` span;
 - ``model.partition_specs(params)`` where a ``model`` mesh axis is
   wanted.
 
@@ -65,6 +70,11 @@ what cannot carry a state yet (an explicit ``prefix_cache``,
 speculative decoding, a tier, a ``model`` axis, page gathers for
 park/resume) refuses it when the engine is built
 (:class:`~deepspeed_tpu.inference.cache.RecurrentStateUnsupported`).
+A model whose pool holds latents (one leaf a layer, one head) is
+refused by what knows per-head keys and values: an int8 / fp8 pool, a
+``model`` axis, speculative decoding, a tier
+(:class:`~deepspeed_tpu.inference.cache.LatentPoolUnsupported`); its
+pages are shared, parked and resumed like any others.
 """
 
 import numpy as np
@@ -78,6 +88,7 @@ from deepspeed_tpu.inference.cache import (
     init_kv_cache,
     kv_cache_nbytes,
     kv_partition_specs,
+    refuse_latent,
     refuse_recurrent,
 )
 from deepspeed_tpu.inference.paging import TRASH_PAGE
@@ -250,6 +261,18 @@ class InferenceEngine:
             refuse_recurrent(
                 self.spec, "a 'model' mesh axis (tensor parallelism)",
                 "the state's heads are not sharded")
+            refuse_latent(
+                self.spec, "a 'model' mesh axis (tensor parallelism)",
+                "a latent is one head, and every query head reads all "
+                "of it")
+        if self.tier is not None:
+            refuse_latent(
+                self.spec, f"the disaggregated {self.tier!r} tier",
+                "the hand-off has not been run on a pool without a v "
+                "leaf")
+        # counters the model's decode step returns beside its logits
+        # (`models/mla_moe.py`: the expert layers' pairs), by name
+        self._counter_names = tuple(getattr(model, "serve_counters", ()))
         # the flash kernel's visit set, for decode()'s counters
         self._paged_grid_blocks = None
         if self.attention_impl == "flash":
@@ -263,7 +286,8 @@ class InferenceEngine:
             self.attention_block_k = check_decode_geometry(
                 self.attention_block_k, self.page_size, self.spec.dtype,
                 self.spec.n_head // tp, self.spec.head_dim,
-                self.spec.codec is not None)
+                self.spec.codec is not None,
+                latent=bool(self.spec.latent_v_dim))
         self.mesh = mesh
         self.session = session
         self._sample_key = jax.random.PRNGKey(self.sampling_seed)
@@ -335,7 +359,8 @@ class InferenceEngine:
         # slot; the whole cache flows through so donation updates it in
         # place. The logits are the last real token's.
         logits, cache = self.model.serve_apply(
-            params, cache, tokens, positions, page_table, slots, n_valid)
+            params, cache, tokens, positions, page_table, slots,
+            n_valid)[:2]
         # fp32 on the way out: host-side sampling/parity reads full
         # precision regardless of compute dtype (a no-op for f32 models,
         # so fp32 parity with the full forward stays bit-exact).
@@ -350,7 +375,7 @@ class InferenceEngine:
         # row i sits in slot i; a row whose table starts with the trash
         # page holds no request (its token is not real)
         live = (page_tables[:, 0] != TRASH_PAGE).astype(jnp.int32)
-        logits, cache = self.model.serve_apply(
+        logits, cache, *counters = self.model.serve_apply(
             params, cache, tokens[:, None], positions[:, None],
             page_tables, jnp.arange(self.max_batch, dtype=jnp.int32), live,
             attn_impl=self.attention_impl,
@@ -359,6 +384,12 @@ class InferenceEngine:
         next_tokens, key = sample_logits(
             logits, key, temperature=self.temperature,
             top_k=self.top_k, top_p=self.top_p)
+        if self._counter_names:
+            # behind the tokens, so that they come back in the tokens'
+            # own transfer: decode() takes them off again
+            next_tokens = jnp.concatenate([next_tokens, jnp.stack([
+                counters[0][name].astype(next_tokens.dtype)
+                for name in self._counter_names])])
         return next_tokens, logits.astype(jnp.float32), key, \
             self._pin_cache(cache)
 
@@ -460,6 +491,8 @@ class InferenceEngine:
                 "— decode belongs to the decode tier")
         session = self.session
         attrs = None
+        if self._counter_names:
+            attrs = {}
         if self._paged_grid_blocks is not None or self.recurrent:
             # the rows that hold a request
             rows = int(np.count_nonzero(
@@ -472,9 +505,9 @@ class InferenceEngine:
             # and its write: the rows whose block it writes back are the
             # rows that hold a request (the loop it replaced wrote every
             # row of the batch, whatever it held)
-            attrs = {"kv_blocks_live": live,
-                     "kv_blocks_launched": launched,
-                     "kv_rows_live": rows, "kv_rows_written": rows}
+            attrs = dict(attrs or {}, kv_blocks_live=live,
+                         kv_blocks_launched=launched,
+                         kv_rows_live=rows, kv_rows_written=rows)
         if self.recurrent:
             # rows whose state the step moves on, against those whose
             # state it reads and writes back (all of them: the update is
@@ -495,6 +528,10 @@ class InferenceEngine:
                     self.params, self.cache, *args, self._sample_key)
             with Span("wait_tokens", session):
                 nxt = np.asarray(nxt)
+                if self._counter_names:
+                    attrs.update(zip(self._counter_names,
+                                     nxt[self.max_batch:].tolist()))
+                    nxt = nxt[:self.max_batch]
             with Span("logits_d2h", session):
                 logits = np.asarray(logits)
         return nxt, logits

@@ -233,13 +233,17 @@ def _conv_init(taps):
 
 
 class GatedMLP(nn.Module):
-    """``(silu(g) * u) W_out`` with ``[g, u] = x W_in``; no bias."""
-    config: GraniteHybridConfig
+    """``(silu(g) * u) W_out`` with ``[g, u] = x W_in``; no bias.
+    ``width``: the inner width where the configuration has several
+    (`models/mla_moe.py`); 0 is ``shared_intermediate_size``."""
+    config: Any
+    width: int = 0
 
     @nn.compact
     def __call__(self, x):
         cfg = self.config
-        C, I = cfg.hidden_size, cfg.shared_intermediate_size
+        C = cfg.hidden_size
+        I = self.width or cfg.shared_intermediate_size
         gu = _linear(self, "w_in", cfg, (C, 2 * I), x)
         y = jax.nn.silu(gu[..., :I]) * gu[..., I:]
         return _linear(self, "w_out", cfg, (I, C), y)

@@ -23,6 +23,21 @@ said how batch rows lie on its mesh
 routing its own tokens through its copy of the experts, the counters
 and the losses' sums added up over the chips.
 
+**Routing is a function** (`softmax_top_k`, OLMoE's; `sigmoid_top_k`,
+DeepSeek-V3's and Kimi-K2's: sigmoid scores, a bias that moves the
+choice and no weight, the chosen scores renormalised and scaled), and
+**an expert layer can hold a share** (ISSUE 34): given ``first_expert``
+the banks are the ``bank.shape[0]`` experts from there on of the
+router's ``router.shape[1]``, as one chip of an expert-parallel
+deployment holds them. Routing still runs over all experts; the pairs
+whose expert is held are sorted to the front by expert and run through
+the same grouped matmuls, whose grid follows the group sizes, so the
+rows behind them (pairs of experts held elsewhere, pairs of masked
+tokens) cost no tile; those rows come out as zeros, by a mask on the
+row index and not by what the kernel left there. On one chip the layer
+runs without its exchange: what the other chips would add is not
+computed and nothing stands in for it.
+
 The four phases carry ``jax.named_scope`` names (``ds_moe_route``,
 ``ds_moe_dispatch``, ``ds_moe_experts``, ``ds_moe_combine``) that reach
 the compiled program's op metadata, by which a trace's ops are laid to
@@ -107,23 +122,70 @@ def router_logits(x, router):
                    precision=jax.lax.Precision.HIGHEST)
 
 
-def _dropless_moe(x, router, w_gate, w_up, w_down, top_k):
+def softmax_top_k(x, router, top_k):
+    """OLMoE's routing: ``p = softmax(x router)`` over all experts, the
+    ``top_k`` most probable, their probabilities as they are (not
+    renormalised). Returns ``(weights [N, k] float32, experts [N, k],
+    aux)``; ``aux`` holds what the router losses sum (``prob_sum``
+    ``[E]``, ``z_sum``)."""
+    logits = router_logits(x, router)
+    # not exp(logits - lse): a v5e's float32 log leaves lse off by
+    # 1e-4, and with it every probability of the token by 6e-5
+    probs = jax.nn.softmax(logits, axis=-1)
+    lse = jax.nn.logsumexp(logits, axis=-1)     # the z-loss's
+    weights, experts = jax.lax.top_k(probs, top_k)      # [N, k]
+    return weights, experts, {"prob_sum": probs.sum(0),
+                              "z_sum": jnp.sum(lse * lse)}
+
+
+def sigmoid_top_k(bias, scaling, renormalise=True):
+    """DeepSeek-V3's routing without groups (``noaux_tc`` with one
+    group, Kimi-K2's): ``s = sigmoid(x router)``; the ``top_k`` largest
+    of ``s + bias`` are chosen (``bias`` ``[E]`` moves the choice and
+    nothing else); their weights are ``s``, over their sum if
+    ``renormalise``, times ``scaling``. All float32. Returns a routing
+    function for `dropless_moe`."""
+    def route(x, router, top_k):
+        scores = jax.nn.sigmoid(router_logits(x, router))
+        _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
+        if renormalise:
+            weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+        return weights * scaling, experts, {}
+    return route
+
+
+def _sort_pairs(keys, n_groups):
+    """``(group_sizes [n_groups], order, inverse)`` of the pairs sorted
+    by ``keys`` (a pair's group; ``n_groups`` for a pair that belongs to
+    none, which sorts behind them all). order: the pair in each sorted
+    row; inverse: each pair's row."""
+    # a key of n_groups is out of bounds, and a scatter drops it
+    group_sizes = jnp.zeros((n_groups,), jnp.int32).at[keys].add(1)
+    order = jnp.argsort(keys, stable=True).astype(jnp.int32)
+    inverse = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.size, dtype=jnp.int32), unique_indices=True)
+    return group_sizes, order, inverse
+
+
+def _dropless_moe(x, router, w_gate, w_up, w_down, top_k,
+                  route=softmax_top_k, first_expert=None, token_mask=None):
     """`dropless_moe` on the tokens of one chip."""
     n_tokens, n_experts = x.shape[0], router.shape[1]
     with jax.named_scope("ds_moe_route"):
-        logits = router_logits(x, router)
-        # not exp(logits - lse): a v5e's float32 log leaves lse off by
-        # 1e-4, and with it every probability of the token by 6e-5
-        probs = jax.nn.softmax(logits, axis=-1)
-        lse = jax.nn.logsumexp(logits, axis=-1)     # the z-loss's
-        weights, experts = jax.lax.top_k(probs, top_k)      # [N, k]
+        weights, experts, aux = route(x, router, top_k)
         pair_expert = experts.reshape(-1)
-        group_sizes = jnp.zeros((n_experts,), jnp.int32).at[
-            pair_expert].add(1)
-        # order: the pair in each sorted row; inverse: each pair's row
-        order = jnp.argsort(pair_expert, stable=True).astype(jnp.int32)
-        inverse = jnp.zeros_like(order).at[order].set(
-            jnp.arange(order.size, dtype=jnp.int32), unique_indices=True)
+        if first_expert is None:
+            group_sizes, order, inverse = _sort_pairs(pair_expert,
+                                                      n_experts)
+        else:
+            n_held = w_gate.shape[0]
+            local = pair_expert - first_expert
+            held = (local >= 0) & (local < n_held)
+            if token_mask is not None:
+                held &= jnp.repeat(token_mask, top_k)
+            group_sizes, order, inverse = _sort_pairs(
+                jnp.where(held, local, n_held), n_held)
     with jax.named_scope("ds_moe_dispatch"):
         rows = _gather_tokens(x, order, inverse, top_k)     # [N k, M]
     with jax.named_scope("ds_moe_experts"):
@@ -132,6 +194,12 @@ def _dropless_moe(x, router, w_gate, w_up, w_down, top_k):
             grouped_matmul(rows, w_gate.astype(dt), group_sizes)) * \
             grouped_matmul(rows, w_up.astype(dt), group_sizes)
         out = grouped_matmul(hidden, w_down.astype(dt), group_sizes)
+        if first_expert is not None:
+            # rows behind the held groups belong to no expert here: the
+            # grouped matmuls visit no tile of them and leave there
+            # what was in memory; zeros by the row's index
+            live = jnp.arange(out.shape[0]) < group_sizes.sum()
+            out = jnp.where(live[:, None], out, jnp.zeros_like(out))
     with jax.named_scope("ds_moe_combine"):
         out = _gather_pairs(out, order, inverse).reshape(
             n_tokens, top_k, -1)
@@ -140,18 +208,34 @@ def _dropless_moe(x, router, w_gate, w_up, w_down, top_k):
         "chosen": experts,
         "weights": weights,
         "tokens_per_expert": group_sizes,
-        "prob_sum": probs.sum(0),
-        "z_sum": jnp.sum(lse * lse),
         "dropped": n_tokens * top_k - group_sizes.sum(),
+        **aux,
     }
     return y.astype(x.dtype), stats
 
 
-def dropless_moe(x, router, w_gate, w_up, w_down, top_k):
+class ExpertExchangeUnsupported(NotImplementedError):
+    """A layer that holds a share of the experts was traced under a
+    mesh placement: the exchange of tokens between the chips that hold
+    the other shares is not written yet (ROADMAP Reach A2)."""
+
+
+def dropless_moe(x, router, w_gate, w_up, w_down, top_k,
+                 route=softmax_top_k, first_expert=None, token_mask=None):
     """``y[t] = sum over the top_k experts e of token t of
     p[t, e] * w_down[e] (silu(w_gate[e] x[t]) * w_up[e] x[t])`` with
     ``p = softmax(x router)`` in float32 over all experts, the chosen
-    probabilities used as they are (not renormalised).
+    probabilities used as they are (not renormalised); another
+    ``route`` (`sigmoid_top_k`) gives other experts and weights.
+
+    ``first_expert`` (an int, or None: the banks hold every expert):
+    the banks hold the ``w_gate.shape[0]`` experts from ``first_expert``
+    on of the router's ``router.shape[1]``. Pairs of other experts, and
+    every pair of a token where ``token_mask`` ``[N]`` is False (a row
+    without a request, a chunk's padding), add exactly zero, cost no
+    tile of the grouped matmuls, and are counted in ``dropped``
+    (``tokens_per_expert`` is then ``[held]``: the pairs each held
+    expert took). One chip only (:class:`ExpertExchangeUnsupported`).
 
     ``x`` ``[N, M]`` tokens; ``router`` ``[M, E]``; ``w_gate``, ``w_up``
     ``[E, M, I]``; ``w_down`` ``[E, I, M]``. The expert products run in
@@ -170,7 +254,12 @@ def dropless_moe(x, router, w_gate, w_up, w_down, top_k):
     by construction); the last four summed over all chips' tokens."""
     placed = placement()
     if placed is None or placed[0].shape[placed[1]] == 1:
-        return _dropless_moe(x, router, w_gate, w_up, w_down, top_k)
+        return _dropless_moe(x, router, w_gate, w_up, w_down, top_k,
+                             route, first_expert, token_mask)
+    if first_expert is not None:
+        raise ExpertExchangeUnsupported(
+            "dropless_moe with a share of the experts runs on one chip: "
+            "no exchange of tokens between shares exists yet")
     mesh, rows, _ = placed
     if x.shape[0] % mesh.shape[rows]:
         raise ValueError(
@@ -178,7 +267,8 @@ def dropless_moe(x, router, w_gate, w_up, w_down, top_k):
             f"{mesh.shape[rows]} devices of mesh axis {rows!r}")
 
     def local(x, router, w_gate, w_up, w_down):
-        y, stats = _dropless_moe(x, router, w_gate, w_up, w_down, top_k)
+        y, stats = _dropless_moe(x, router, w_gate, w_up, w_down, top_k,
+                                 route)
         for key in ("tokens_per_expert", "prob_sum", "z_sum", "dropped"):
             stats[key] = jax.lax.psum(stats[key], rows)
         return y, stats

@@ -46,6 +46,17 @@ engine's counters and the static analyzer. The scores are one dot
 batched over the heads, each a ``[1, D] x [D, block_k]`` product at
 the MXU's default precision.
 
+**A latent pool** (ISSUE 34): a pool with no ``v`` leaf holds one
+"head" a layer, a token's compressed key-value latent beside its shared
+rotary key (`models/mla_moe.py`), and every query head attends over it
+(``G`` = all the query heads, ``H`` = 1). The kernel is the same: the
+values are the leading ``v_dim`` sublanes of the key block it has
+fetched, so a block is read once and is both operands; the scores are a
+``[G, D] x [D, block_k]`` product, the first decode shape here whose
+queries fill rows of the MXU, and its operands stay in the pool's
+bfloat16 (the other pools' one-row products go through float32
+copies). The step's write is one leaf's lane instead of two.
+
 The kernel compiles for the chip (`tests/unit/test_tpu_compile.py`
 pins that against a described v5e, its grid included). Off-TPU it
 runs in Pallas interpret mode (CPU test meshes); the dense
@@ -111,7 +122,7 @@ def _validate_block_k(block_k, page_size, interpret):
 
 
 def check_decode_geometry(block_k, page_size, kv_dtype, heads, head_dim,
-                          quant):
+                          quant, latent=False):
     """The call-time block validation, for the device this process
     compiles for — so the serving engine refuses, typed, a geometry the
     chip's compiler would refuse when it is BUILT, not at the first
@@ -121,7 +132,7 @@ def check_decode_geometry(block_k, page_size, kv_dtype, heads, head_dim,
     VMEM."""
     interpret = jax.devices()[0].platform != "tpu"
     block_k = _validate_block_k(block_k, page_size, interpret)
-    _check_paged_vmem(heads, head_dim, block_k, kv_dtype, quant)
+    _check_paged_vmem(heads, head_dim, block_k, kv_dtype, quant, latent)
     return block_k
 
 
@@ -147,25 +158,30 @@ def paged_grid_blocks(positions, page_tables, block_k):
     return blocks, blocks
 
 
-def paged_vmem_bytes(heads, head_dim, block_k, kv_dtype, quant):
+def paged_vmem_bytes(heads, head_dim, block_k, kv_dtype, quant,
+                     latent=False):
     """VMEM the paged kernel's step holds: two slots each of the K and
-    V ``(H, D, block_k)`` blocks (and of their scale rows), the
+    V ``(H, D, block_k)`` blocks (of the one block of a ``latent`` pool,
+    which has no V; and of their scale rows), the
     pipelined query and output blocks, and the pipelined block of the
     step's new keys and values, float32, a row to a lane (and of their
     scales). (The float32 operands of the dots are made a head at a
     time, never a whole block: a described v5e compiles int8 blocks of
     12 MB and refuses float32 ones of 16.)"""
     elems = int(heads) * int(head_dim) * int(block_k)
-    need = 2 * 2 * elems * jnp.dtype(kv_dtype).itemsize
-    new = 2 * 2 * int(heads) * int(head_dim) * _LANES * 4
+    leaves = 1 if latent else 2
+    need = leaves * 2 * elems * jnp.dtype(kv_dtype).itemsize
+    new = leaves * 2 * int(heads) * int(head_dim) * _LANES * 4
     if quant:
         need += 2 * 2 * int(heads) * int(block_k) * 4
         new += 2 * 2 * int(heads) * _LANES * 4
     return need + new + 2 * 2 * int(heads) * int(head_dim) * 4
 
 
-def _check_paged_vmem(heads, head_dim, block_k, kv_dtype, quant):
-    need = paged_vmem_bytes(heads, head_dim, block_k, kv_dtype, quant)
+def _check_paged_vmem(heads, head_dim, block_k, kv_dtype, quant,
+                      latent=False):
+    need = paged_vmem_bytes(heads, head_dim, block_k, kv_dtype, quant,
+                            latent)
     if need > PAGED_VMEM_BUDGET:
         raise KernelGeometryError(
             f"paged flash decode keeps two (heads={heads}, "
@@ -175,7 +191,8 @@ def _check_paged_vmem(heads, head_dim, block_k, kv_dtype, quant):
             f"kernel may use — lower attention_block_k (or page_size)")
 
 
-def _paged_decode_kernel(H, D, block_k, bpp, quant, G=1, scale=None):
+def _paged_decode_kernel(H, D, block_k, bpp, quant, G=1, scale=None,
+                         v_dim=None):
     """The paged kernel's body: one grid step = one row's live span,
     the row's new key and value written into it.
 
@@ -185,7 +202,11 @@ def _paged_decode_kernel(H, D, block_k, bpp, quant, G=1, scale=None):
     ``(D, block_k)`` block of their key head: scores, running max and
     sum and the output carry a group axis (``rows`` below), the fetches
     do not change. ``G`` = 1 is the kernel as it was. ``scale``
-    multiplies the scores (``None``: ``D ** -0.5``).
+    multiplies the scores (``None``: ``D ** -0.5``). ``v_dim``: the
+    pool is a latent one, with no V leaf: the values are the first
+    ``v_dim`` of the fetched key block's ``D`` sublanes, the output is
+    ``v_dim`` wide, and the two products take their operands as the
+    pool stores them (no float32 copies: ``G`` queries fill MXU rows).
 
     Scalar-prefetch args: ``[B]`` positions and ``[B, pages_per_row]``
     page tables (SMEM). ``pools`` (K, V and, quantized, their scales)
@@ -214,7 +235,10 @@ def _paged_decode_kernel(H, D, block_k, bpp, quant, G=1, scale=None):
 
     rows = (H,) if G == 1 else (H, G)
     scale = D ** -0.5 if scale is None else float(scale)
-    n_pool = 4 if quant else 2
+    latent = v_dim is not None
+    n_pay = 1 if latent else 2          # payload leaves: K (and V)
+    n_pool = n_pay + (2 if quant else 0)
+    Dv = v_dim if latent else D
 
     def over_heads(a):
         """A per-(head, position) array against the scores' rows."""
@@ -243,7 +267,7 @@ def _paged_decode_kernel(H, D, block_k, bpp, quant, G=1, scale=None):
                                              block_k), block_k)
             out = []
             for j, (hbm, buf) in enumerate(zip(pools, bufs)):
-                hbm = hbm.at[page, :, :, lanes] if j < 2 \
+                hbm = hbm.at[page, :, :, lanes] if j < n_pay \
                     else hbm.at[page, :, lanes]
                 ends = (buf.at[slot], hbm) if back else (hbm, buf.at[slot])
                 out.append(pltpu.make_async_copy(
@@ -277,7 +301,8 @@ def _paged_decode_kernel(H, D, block_k, bpp, quant, G=1, scale=None):
             settle(0)
             for c in copies(0, 0):
                 c.start()
-            qb = q_ref[0].astype(jnp.float32)       # [H, D] | [H, G, D]
+            # [H, D] | [H, G, D]
+            qb = q_ref[0] if latent else q_ref[0].astype(jnp.float32)
             if G == 1:
                 qb = qb[:, None, :]                             # [H, 1, D]
 
@@ -288,10 +313,10 @@ def _paged_decode_kernel(H, D, block_k, bpp, quant, G=1, scale=None):
                 off = p % block_k
                 # row b's column, turned from lane b % 128 to lane off
                 turn = (off - b) % _LANES
-                new = pltpu.roll(new_ref[...], turn, 3)  # [2, H, D, 128]
+                new = pltpu.roll(new_ref[...], turn, 3)  # [n_pay, H, D, 128]
                 snew = pltpu.roll(snew_ref[...], turn, 2) if quant else None
                 for j, buf in enumerate(bufs):
-                    col = new[j] if j < 2 else snew[j - 2]
+                    col = new[j] if j < n_pay else snew[j - n_pay]
                     if block_k <= _LANES:
                         col = col[..., :block_k]
                     else:
@@ -321,7 +346,9 @@ def _paged_decode_kernel(H, D, block_k, bpp, quant, G=1, scale=None):
                     for c in copies(i, slot, back=True):
                         c.start()
                     pend[slot] = 1
-                kb = bufs[0][slot].astype(jnp.float32)      # [H, D, bk]
+                kb = bufs[0][slot]                          # [H, D, bk]
+                if not latent:
+                    kb = kb.astype(jnp.float32)
                 s = jax.lax.dot_general(
                     qb, kb, (((2,), (1,)), ((0,), (0,))),
                     preferred_element_type=jnp.float32
@@ -342,18 +369,22 @@ def _paged_decode_kernel(H, D, block_k, bpp, quant, G=1, scale=None):
                 l_new = l_prev * corr + pr.sum(axis=-1, keepdims=True)
                 if quant:
                     pr = pr * over_heads(bufs[3][slot])
-                vb = bufs[1][slot].astype(jnp.float32)      # [H, D, bk]
+                if latent:
+                    # the values are the key block's leading sublanes
+                    vb, pr = kb[:, :Dv], pr.astype(kb.dtype)
+                else:
+                    vb = bufs[1][slot].astype(jnp.float32)  # [H, D, bk]
                 pv = jax.lax.dot_general(
                     pr[:, None, :] if G == 1 else pr, vb,
                     (((2,), (2,)), ((0,), (0,))),
-                    preferred_element_type=jnp.float32)     # [H, G, D]
-                return m_new, l_new, acc * corr + pv.reshape(rows + (D,))
+                    preferred_element_type=jnp.float32)     # [H, G, Dv]
+                return m_new, l_new, acc * corr + pv.reshape(rows + (Dv,))
 
             _, l, acc = jax.lax.fori_loop(
                 0, n_blocks, block,
                 (jnp.full(rows + (1,), -jnp.inf, jnp.float32),
                  jnp.zeros(rows + (1,), jnp.float32),
-                 jnp.zeros(rows + (D,), jnp.float32)))
+                 jnp.zeros(rows + (Dv,), jnp.float32)))
             o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
         @pl.when(b == pl.num_programs(0) - 1)
@@ -364,13 +395,13 @@ def _paged_decode_kernel(H, D, block_k, bpp, quant, G=1, scale=None):
     return kernel
 
 
-def _rows_on_lanes(k_new, v_new, dtype):
-    """A decode step's new keys and values (or their scales), two ``[B,
-    1, ...]`` arrays, rounded to the pool's ``dtype``, as one float32
-    ``[2, ..., ceil(B / 128) * 128]``: a row to a lane, which is where
-    a position lies in the pool's blocks. Every storage dtype's values
-    are float32 values."""
-    x = jnp.stack([k_new[:, 0], v_new[:, 0]]).astype(dtype).astype(
+def _rows_on_lanes(new, dtype):
+    """A decode step's new keys and values (or their scales; a latent
+    pool's keys alone), ``[B, 1, ...]`` arrays, rounded to the pool's
+    ``dtype``, as one float32 ``[len(new), ..., ceil(B / 128) * 128]``:
+    a row to a lane, which is where a position lies in the pool's
+    blocks. Every storage dtype's values are float32 values."""
+    x = jnp.stack([a[:, 0] for a in new]).astype(dtype).astype(
         jnp.float32)
     x = jnp.moveaxis(x, 1, -1)
     pad = -x.shape[-1] % _LANES
@@ -378,7 +409,8 @@ def _rows_on_lanes(k_new, v_new, dtype):
 
 
 def flash_decode_paged(q, new, pool, positions, page_tables,
-                       block_k=DEFAULT_BLOCK_K, interpret=None, scale=None):
+                       block_k=DEFAULT_BLOCK_K, interpret=None, scale=None,
+                       v_dim=None):
     """One decode step's attention over a paged KV pool, the step's own
     keys and values written into the pool on the way: returns ``(out,
     pool)``.
@@ -395,6 +427,9 @@ def flash_decode_paged(q, new, pool, positions, page_tables,
     the pool's dtype here; for a codec pool the payload quantized
     outside, as every write's is) and ``[B, 1, H]`` scales. ``page_tables``: ``[B, pages_per_row]`` int32 physical
     page ids per row (entry 0 = the trash page for unallocated slots).
+    A pool without a ``v`` leaf is a latent one (``new`` has none
+    either): the values are the leading ``v_dim`` (required, at most
+    ``D``) entries of each key, and ``out`` is ``[B, 1, Hq, v_dim]``.
     ``positions``: ``[B]`` int32, each row's current write position
     (the mask admits cache index ``s`` iff ``s <= positions[b]`` —
     identical to the dense oracle's). ``out`` is ``[B, 1, Hq, D]`` in
@@ -428,35 +463,48 @@ def flash_decode_paged(q, new, pool, positions, page_tables,
             f"page_tables rows {page_tables.shape[0]} != batch {B}")
     if interpret is None:
         interpret = jax.devices()[0].platform != "tpu"
-    if set(new) != set(pool) or ("k_scale" in pool) != ("v_scale" in pool):
+    latent = "v" not in pool
+    if set(new) != set(pool) or set(pool) not in (
+            {"k"}, {"k", "v"}, {"k", "v", "k_scale", "v_scale"}):
         raise ValueError(
             f"the step's new leaves {sorted(new)} must be the pool's "
-            f"{sorted(pool)}: k and v, with both scales or neither")
+            f"{sorted(pool)}: k and v with both scales or neither, or "
+            f"(a latent pool) k alone")
+    if latent != (v_dim is not None) or (latent and not 0 < v_dim <= D):
+        raise ValueError(
+            f"v_dim {v_dim} goes with a latent pool (k alone, its values "
+            f"the first v_dim <= {D} entries of a key) and with no other; "
+            f"the pool's leaves are {sorted(pool)}")
     block_k = _validate_block_k(block_k, page_size, interpret)
-    _check_paged_vmem(H, D, block_k, pool["k"].dtype, "k_scale" in pool)
+    _check_paged_vmem(H, D, block_k, pool["k"].dtype, "k_scale" in pool,
+                      latent)
     return _paged_call(q, new, pool, jnp.asarray(positions, jnp.int32),
                        jnp.asarray(page_tables, jnp.int32), block_k=block_k,
-                       interpret=bool(interpret), scale=scale)
+                       interpret=bool(interpret), scale=scale, v_dim=v_dim)
 
 
 # jitted, so that a model's layers share one trace and one lowering of
 # the kernel: in a process that holds a large engine every traced
 # equation of a kernel body costs milliseconds (`PERF.md`, PR 30), and
 # the decode program calls this once a layer
-@functools.partial(jax.jit, static_argnames=("block_k", "interpret", "scale"))
+@functools.partial(jax.jit, static_argnames=("block_k", "interpret", "scale",
+                                             "v_dim"))
 def _paged_call(q, new, pool, positions, page_tables, *, block_k, interpret,
-                scale):
+                scale, v_dim=None):
     k = pool["k"]
     H, D, page_size = k.shape[1:]
     B, Hq = q.shape[0], q.shape[2]
     G = Hq // H
     quant = "k_scale" in pool
-    names = ("k", "v") + (("k_scale", "v_scale") if quant else ())
+    payload = ("k",) if v_dim is not None else ("k", "v")
+    names = payload + (("k_scale", "v_scale") if quant else ())
+    Dv = D if v_dim is None else v_dim
 
     # the query and the output as the kernel sees them: [B, H, D], or
     # with a group axis [B, H, G, D] (a reshape of the model's layout:
     # query head h is (h // G, h % G))
     qshape = (H, D) if G == 1 else (H, G, D)
+    oshape = qshape[:-1] + (Dv,)
 
     def row(b, pos_ref, pt_ref):
         return (b,) + (0,) * len(qshape)
@@ -467,14 +515,13 @@ def _paged_call(q, new, pool, positions, page_tables, *, block_k, interpret,
 
     anywhere = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [pl.BlockSpec((1,) + qshape, row),
-                pl.BlockSpec((2, H, D, _LANES), lanes_of(4))]
+                pl.BlockSpec((len(payload), H, D, _LANES), lanes_of(4))]
     args = [q.reshape((B,) + qshape),
-            _rows_on_lanes(new["k"], new["v"], k.dtype)]
-    scratch = [pltpu.VMEM((2, H, D, block_k), k.dtype),
-               pltpu.VMEM((2, H, D, block_k), k.dtype)]
+            _rows_on_lanes([new[name] for name in payload], k.dtype)]
+    scratch = [pltpu.VMEM((2, H, D, block_k), k.dtype) for _ in payload]
     if quant:
         in_specs.append(pl.BlockSpec((2, H, _LANES), lanes_of(3)))
-        args.append(_rows_on_lanes(new["k_scale"], new["v_scale"],
+        args.append(_rows_on_lanes([new["k_scale"], new["v_scale"]],
                                    jnp.float32))
         scratch += [pltpu.VMEM((2, H, block_k), jnp.float32),
                     pltpu.VMEM((2, H, block_k), jnp.float32)]
@@ -486,16 +533,16 @@ def _paged_call(q, new, pool, positions, page_tables, *, block_k, interpret,
         num_scalar_prefetch=2,
         grid=(B,),
         in_specs=in_specs + [anywhere] * len(leaves),
-        out_specs=[pl.BlockSpec((1,) + qshape, row)]
+        out_specs=[pl.BlockSpec((1,) + oshape, row)]
         + [anywhere] * len(leaves),
         scratch_shapes=scratch,
     )
     call = pl.pallas_call(
         _paged_decode_kernel(H, D, block_k, page_size // block_k, quant,
-                             G, scale),
+                             G, scale, v_dim),
         name=DECODE_PAGED_NAME,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B,) + qshape, q.dtype)]
+        out_shape=[jax.ShapeDtypeStruct((B,) + oshape, q.dtype)]
         + [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in leaves],
         # operand 2 + len(args) + j (the two scalar operands count) is
         # the pool's leaf j, and so is output 1 + j
@@ -505,4 +552,4 @@ def _paged_call(q, new, pool, positions, page_tables, *, block_k, interpret,
     )
     with jax.named_scope(DECODE_PAGED_NAME):
         out, *leaves = call(positions, page_tables, *args, *leaves)
-    return out.reshape(B, 1, Hq, D), dict(zip(names, leaves))
+    return out.reshape(B, 1, Hq, Dv), dict(zip(names, leaves))

@@ -51,7 +51,9 @@ def attend(q, k, v, positions, tables, k_scale=None, v_scale=None, **kw):
     """The kernel's read alone: each live row's new lane is the one the
     pool holds at its position already, so the call must hand the pool
     back bit for bit; returns the attention output."""
-    pool = {"k": jnp.asarray(k), "v": jnp.asarray(v)}
+    pool = {"k": jnp.asarray(k)}
+    if v is not None:           # a latent pool has no values of its own
+        pool["v"] = jnp.asarray(v)
     if k_scale is not None:
         pool.update(k_scale=jnp.asarray(k_scale),
                     v_scale=jnp.asarray(v_scale))
@@ -90,6 +92,15 @@ def test_input_validation():
                            dict(pool, k_scale=one), positions, tables)
     with pytest.raises(ValueError, match="new leaves"):
         flash_decode_paged(q, {"k": q}, pool, positions, tables)
+    # a latent pool (k alone) says how much of a key is its value, and
+    # no other pool does
+    with pytest.raises(ValueError, match="v_dim"):
+        flash_decode_paged(q, {"k": q}, {"k": k}, positions, tables)
+    with pytest.raises(ValueError, match="v_dim"):
+        flash_decode_paged(q, new, pool, positions, tables, v_dim=8)
+    with pytest.raises(ValueError, match="v_dim"):
+        flash_decode_paged(q, {"k": q}, {"k": k}, positions, tables,
+                           v_dim=17)
     # past the page, block_k clamps to it
     out = attend(q, k, v, positions, tables, block_k=4 * PAGE)
     np.testing.assert_allclose(
@@ -166,9 +177,10 @@ def _paged_case(seed, heads, head_dim, poison=float("nan")):
     return q, k, v, positions, tables
 
 
-def _paged_ref(q, k, v, positions, tables):
+def _paged_ref(q, k, v, positions, tables, scale=None):
     """float64, straight from the pool; a dead row gives zeros."""
-    out = np.zeros(q.shape, np.float64)
+    scale = q.shape[-1] ** -0.5 if scale is None else scale
+    out = np.zeros(q.shape[:-1] + v.shape[2:3], np.float64)
     for b in range(q.shape[0]):
         if tables[b, 0] == 0:
             continue
@@ -177,23 +189,47 @@ def _paged_ref(q, k, v, positions, tables):
         kk = np.concatenate([k[p] for p in pages], -1)[..., :n]
         vv = np.concatenate([v[p] for p in pages], -1)[..., :n]
         s = np.einsum("hd,hds->hs", q[b, 0].astype(np.float64),
-                      kk.astype(np.float64)) * q.shape[-1] ** -0.5
+                      kk.astype(np.float64)) * scale
         w = np.exp(s - s.max(-1, keepdims=True))
         out[b, 0] = np.einsum("hs,hds->hd", w / w.sum(-1, keepdims=True),
                               vv.astype(np.float64))
     return out
 
 
+# a latent pool (ISSUE 34): one 40-wide "head" whose first 32 entries are
+# also the value, under 4 query heads and the model's own scale
+LATENT_D, LATENT_V, LATENT_G, LATENT_SCALE = 40, 32, 4, 0.3
+
+
+def _latent_ref(q, k, positions, tables):
+    """Each query head over the one latent head, float64."""
+    return np.concatenate(
+        [_paged_ref(q[:, :, g:g + 1], k, k[:, :, :LATENT_V], positions,
+                    tables, LATENT_SCALE) for g in range(q.shape[2])], 2)
+
+
 # GPT-2 medium's 16 heads, XL's 25, a TP=4 shard's 4; head size 64 and
-# OLMoE's 128
+# OLMoE's 128; a latent pool
 @pytest.mark.parametrize("block_k", [8, 16])
 @pytest.mark.parametrize("heads,head_dim",
-                         [(16, 64), (25, 64), (4, 64), (16, 128)])
+                         [(16, 64), (25, 64), (4, 64), (16, 128),
+                          ("latent", LATENT_D)])
 def test_paged_matches_dense_reference(heads, head_dim, block_k):
-    q, k, v, positions, tables = _paged_case(0, heads, head_dim)
-    out = np.asarray(attend(q, k, v, positions, tables, block_k=block_k))
-    np.testing.assert_allclose(out, _paged_ref(q, k, v, positions, tables),
-                               atol=2e-6)
+    if heads == "latent":
+        _, k, _, positions, tables = _paged_case(0, 1, head_dim)
+        q = np.random.default_rng(5).standard_normal(
+            (len(ROWS), 1, LATENT_G, head_dim)).astype(np.float32)
+        out = np.asarray(attend(q, k, None, positions, tables,
+                                block_k=block_k, v_dim=LATENT_V,
+                                scale=LATENT_SCALE))
+        assert out.shape == (len(ROWS), 1, LATENT_G, LATENT_V)
+        want = _latent_ref(q, k, positions, tables)
+    else:
+        q, k, v, positions, tables = _paged_case(0, heads, head_dim)
+        out = np.asarray(attend(q, k, v, positions, tables,
+                                block_k=block_k))
+        want = _paged_ref(q, k, v, positions, tables)
+    np.testing.assert_allclose(out, want, atol=2e-6)
     dead = [b for b, (_, live) in enumerate(ROWS) if not live]
     assert not out[dead].any()          # no request: zeros, not garbage
 
@@ -334,7 +370,9 @@ def _write_case(seed, heads, head_dim, group, storage):
     n_pages = n_rows * N_PT + 1
     shape = (n_pages, heads, head_dim, PAGE)
     k, v = (rng.standard_normal(shape).astype(np.float32) for _ in "kv")
-    if storage == "float32":
+    if storage == "latent":
+        pool = {"k": jnp.asarray(k)}
+    elif storage == "float32":
         pool = {"k": jnp.asarray(k), "v": jnp.asarray(v)}
     else:
         pool = {}
@@ -356,10 +394,14 @@ def _write_case(seed, heads, head_dim, group, storage):
             for i in range(pos // PAGE + 1):
                 tables[b, i] = free.pop()
     tables[SHARED[1], :2] = tables[SHARED[0], :2]
+    if storage == "latent":
+        v_new = None
     return (q, _new_leaves(pool, k_new, v_new), pool, positions, tables)
 
 
 def _dequantized(pool):
+    if "v" not in pool:
+        return np.asarray(pool["k"]), np.asarray(pool["k"])[:, :, :12]
     if "k_scale" not in pool:
         return np.asarray(pool["k"]), np.asarray(pool["v"])
     return tuple(np.asarray(pool[n].astype(jnp.float32)
@@ -369,20 +411,25 @@ def _dequantized(pool):
 
 @pytest.mark.parametrize("block_k", [PAGE, PAGE // 2],
                          ids=["block=page", "block<page"])
-@pytest.mark.parametrize("storage", ["float32", "int8", "f8e4m3fn"])
+@pytest.mark.parametrize("storage", ["float32", "int8", "f8e4m3fn",
+                                     "latent"])
 @pytest.mark.parametrize("group", [1, 4])
 def test_fused_write_is_the_loop_then_the_read(group, storage, block_k):
     """The fused call against what it replaced: `_write_tokens` (every
     row's token into its page's slab, dead rows' into the trash page),
-    then the read-only arithmetic over the written pool."""
+    then the read-only arithmetic over the written pool. ``latent``: a
+    pool of one leaf and one head, the values the first 12 of a key's
+    16 entries, every query head over it: one lane written, not two."""
+    latent = {"v_dim": 12} if storage == "latent" else {}
+    heads = 1 if latent else 4
     q, new, pool, positions, tables = _write_case(
-        11 + group, 4, 16, group, storage)
+        11 + group, heads, 16, group, storage)
     before = {name: np.asarray(leaf) for name, leaf in pool.items()}
     pages = tables[np.arange(len(tables)), positions // PAGE]
     want = _write_tokens(pool, {n: x[:, 0] for n, x in new.items()},
                          jnp.asarray(pages), jnp.asarray(positions % PAGE))
     out, got = flash_decode_paged(q, new, pool, positions, tables,
-                                  block_k=block_k)
+                                  block_k=block_k, **latent)
     assert set(got) == set(pool)
     for name, leaf in got.items():
         leaf, loop = np.asarray(leaf), np.asarray(want[name])
@@ -407,14 +454,15 @@ def test_fused_write_is_the_loop_then_the_read(group, storage, block_k):
     # the new lane is attended over: the read alone, over the written
     # pool, gives the same output bit for bit, and float64 agrees
     scales = {n: want[n] for n in want if n.endswith("_scale")}
-    again = attend(q, want["k"], want["v"], positions, tables,
-                   block_k=block_k, **scales)
+    again = attend(q, want["k"], want.get("v"), positions, tables,
+                   block_k=block_k, **scales, **latent)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(again))
     k_d, v_d = _dequantized(want)
-    q64 = q.reshape(len(q), 1, 4, group, 16)
+    q64 = q.reshape(len(q), 1, heads, group, 16)
+    out64 = np.asarray(out).reshape(q64.shape[:-1] + (-1,))
     for g in range(group):
         np.testing.assert_allclose(
-            np.asarray(out).reshape(q64.shape)[:, :, :, g],
+            out64[:, :, :, g],
             _paged_ref(q64[:, :, :, g], k_d, v_d, positions, tables),
             atol=2e-6)
 
@@ -549,7 +597,8 @@ def test_the_kernels_file_is_definitions_at_module_level():
         if isinstance(node, (ast.FunctionDef, ast.ClassDef)):
             assert [ast.unparse(d) for d in node.decorator_list] in (
                 [], ["functools.partial(jax.jit, static_argnames="
-                     "('block_k', 'interpret', 'scale'))"]), node.name
+                     "('block_k', 'interpret', 'scale', 'v_dim'))"]), \
+                node.name
             continue
         if isinstance(node, ast.Expr):
             assert isinstance(node.value, ast.Constant)     # the docstring

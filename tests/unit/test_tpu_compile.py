@@ -448,6 +448,108 @@ def test_hybrid_serving_programs_compile(chip, monkeypatch, program):
     assert compiled.memory_analysis().alias_size_in_bytes == cache_bytes
 
 
+# --- the latent-attention model's serving programs (ISSUE 34) ---------------
+
+# the cell's engine: 32 rows, a bucket of 17,408 (136 pages), a pool of
+# 2,048 pages and the trash page, one 576-wide latent a token a layer
+MLA_ROWS, MLA_BUCKET, MLA_PAGES = 32, 17408, 2049
+
+
+def test_latent_flash_decode_compiles(chip):
+    """The decode kernel over a pool of latents at the published sizes:
+    one leaf of one 576-wide head, 64 absorbed query heads over it, the
+    values the leading 512 sublanes of the block it fetched, one lane
+    written. One grid step a row; nothing pool-shaped is copied."""
+    from deepspeed_tpu.analysis.hlo import payload_shaped_copies
+    from deepspeed_tpu.ops.pallas.flash_decode import flash_decode_paged
+
+    pool = {"k": chip((MLA_PAGES, 1, 576, PAGE), jnp.bfloat16)}
+    new = {"k": chip((MLA_ROWS, 1, 1, 576), jnp.bfloat16)}
+    q = chip((MLA_ROWS, 1, 64, 576), jnp.bfloat16)
+
+    def fn(pool, q, new, pos, pt):
+        return flash_decode_paged(q, new, pool, pos, pt, interpret=False,
+                                  scale=0.1447, v_dim=512)
+    lowered = jax.jit(fn, donate_argnums=0).lower(
+        pool, q, new, chip((MLA_ROWS,), jnp.int32),
+        chip((MLA_ROWS, MLA_BUCKET // PAGE), jnp.int32))
+    assert kernel_grids(lowered.as_text()) == [(MLA_ROWS,)]
+    text = lowered.compile().as_text()
+    assert "ds_flash_decode_paged" in text
+    assert payload_shaped_copies(text, pool["k"].shape) == []
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_mla_moe_serving_programs_compile(chip, monkeypatch, program):
+    """Both programs of Kimi-K2.7-Code's share at its published widths
+    (the dense layer and one expert layer of the seven: the expert
+    layers repeat), cache donated, as the engine calls them: a prefill
+    chunk of 1024 and a decode step of 32 rows over a bucket of 17,408.
+    **No ``[heads, chunk, bucket]`` array in either**: the dense path's
+    scores would be 64 x 1024 x 17,408 x 4 B = 4.6 GB; the largest
+    buffer either program holds besides its arguments is a block of the
+    walk, and all its temporaries together are under a quarter of that.
+    The pool is updated where it lies."""
+    import re
+
+    from deepspeed_tpu.analysis.hlo import payload_shaped_copies
+    from deepspeed_tpu.inference.cache import init_kv_cache
+    from deepspeed_tpu.models import mla_moe as mm
+
+    for name in ("deepspeed_tpu.ops.pallas.flash_decode",
+                 "deepspeed_tpu.moe.dropless"):
+        _compiled_not_interpreted(monkeypatch, name)
+    cfg = mm.kimi_k2_share(n_layer=2)
+    model = mm.MlaMoeLM(cfg)
+    spec = cfg.cache_spec(MLA_ROWS, MLA_BUCKET, page_size=PAGE,
+                          n_pages=MLA_PAGES)
+    abstract = lambda tree: jax.tree_util.tree_map(     # noqa: E731
+        lambda a: chip(a.shape, a.dtype), tree)
+    params = abstract(jax.eval_shape(
+        lambda k: mm.init_mla_moe_params(model, k), jax.random.PRNGKey(0)))
+    cache = abstract(jax.eval_shape(lambda: init_kv_cache(spec)))
+    i32 = lambda *shape: chip(shape, jnp.int32)         # noqa: E731
+    per_row = MLA_BUCKET // PAGE
+
+    if program == "prefill":
+        def fn(params, cache, tokens, positions, table, slots, n_valid):
+            return model.serve_apply(params, cache, tokens, positions,
+                                     table, slots, n_valid)
+        args = (i32(1, 1024), i32(1, 1024), i32(1, per_row), i32(1), i32(1))
+    else:
+        def fn(params, cache, tokens, positions, tables):
+            live = (tables[:, 0] != 0).astype(jnp.int32)
+            return model.serve_apply(
+                params, cache, tokens[:, None], positions[:, None], tables,
+                jnp.arange(MLA_ROWS, dtype=jnp.int32), live,
+                attn_impl="flash", attn_block_k=PAGE)
+        args = (i32(MLA_ROWS), i32(MLA_ROWS), i32(MLA_ROWS, per_row))
+    compiled = jax.jit(fn, donate_argnums=1).lower(
+        params, cache, *args).compile()
+    text = compiled.as_text()
+    # 3 grouped matmuls a expert layer; the decode kernel a layer
+    assert text.count("tpu_custom_call") >= (5 if program == "decode"
+                                              else 3)
+    for scope in ("ds_mla_project", "ds_moe_route", "ds_moe_experts",
+                  "ds_moe_shared", "ds_mla_prefill_attn" if
+                  program == "prefill" else "ds_flash_decode_paged"):
+        assert scope in text, scope
+    dense_scores = 64 * 1024 * MLA_BUCKET * 4
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < dense_scores / 4
+    # no array of the program has the dense scores' element count
+    sizes = {int(np.prod([int(d) for d in dims.split(",")]))
+             for dims in re.findall(r"(?:f32|bf16)\[([\d,]+)\]", text)}
+    weights = max(a.size for a in jax.tree_util.tree_leaves(params))
+    assert max(sizes) <= weights < dense_scores // 4
+    assert payload_shaped_copies(text, (MLA_PAGES, 1, 576, PAGE)) == []
+    assert payload_shaped_copies(text, (MLA_PAGES, 576, PAGE)) == []
+    # every cache leaf goes out where it came in
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree_util.tree_leaves(cache))
+    assert memory.alias_size_in_bytes == cache_bytes
+
+
 class _AnswersTpu:
     """Stands in for `jax` inside one kernel module: the kernels ask
     `jax.devices()` whether to interpret, and here that is the CPU."""
