@@ -571,48 +571,71 @@ def _walk_pages(pages_per_row, page_size, limit):
                (n == 1 or n * page_size <= limit))
 
 
-# positions to a block of a prefill chunk's walk over a latent pool: the
-# float32 scores of a block are [heads, chunk, WALK_BLOCK]
+# positions to a block of a prefill chunk's walk over a latent pool: a
+# head's float32 scores of a block are [chunk, WALK_BLOCK]
 WALK_BLOCK = 1024
 
 
+def latent_walk_block(pages_per_row, page_size):
+    """Positions to a block of the walk over a row of ``pages_per_row``
+    pages: a chunk whose last query sits at ``p`` visits ``p // block +
+    1`` of them (the engine's counters reckon so too)."""
+    return _walk_pages(pages_per_row, page_size, WALK_BLOCK) * page_size
+
+
 def latent_prefill_attention(q, layer_cache, positions, page_table, *,
-                             expand, scale, compute_dtype):
+                             expand, scale, compute_dtype, impl="dense"):
     """One prompt's chunk of queries over the row's live prefix in a
     latent pool, the chunk's own latents already written: blocks of
     whole pages, gathered through the table one after the other under a
-    running max and sum (float32), so that the largest array is
-    ``[heads, chunk, block]`` whatever the bucket. Only the blocks up
-    to the chunk's last position are visited (a ``while`` over ``pos //
-    block + 1``), and the mask is the pool's own: cache index ``s`` for
-    the query at ``p`` iff ``s <= p``.
+    running max and sum (float32). Only the blocks up to the chunk's
+    last position are visited (a ``while`` over ``pos // block + 1``),
+    and the mask is the pool's own: cache index ``s`` for the query at
+    ``p`` iff ``s <= p``.
 
     Each block is expanded as it is met: ``expand(latents [S, D]) ->
-    (k [S, H, dk], v [S, H, dv])`` is the caller's up-projection beside
-    the shared rotary key. ``q`` is ``[1, T, H, dk]``; returns ``[1, T,
-    H, dv]``. That costs ``dk + dv`` multiply-adds a query-key pair and
-    the block's expansion once a chunk; scoring the latents as they lie
-    (the decode step's absorbed form: ``D + v_dim`` a pair, nothing
-    expanded) read 5 to 7 % slower at every prefix on the chip
-    (`PERF.md` section 6, PR 34), so a chunk has this one form.
+    (k [S, H, dk], k_shared [S, dr], v [H, dv, S])`` is the caller's
+    up-projection (each as the product that makes it lies: keys a
+    position a row, values a position a lane) beside the rotary key the
+    heads share. ``q`` is ``[1, T, H, dk + dr]``, a head's shared
+    entries last; returns ``[1, T, H, dv]``. That costs ``dk + dr +
+    dv`` multiply-adds a query-key pair and the block's expansion once
+    a chunk; scoring the latents as they lie (the decode step's
+    absorbed form: ``D + v_dim`` a pair, nothing expanded) read 5 to 7
+    % slower at every prefix on the chip (`PERF.md` section 6, PR 34).
+
+    What takes a block into the running max and sum is ``impl``'s:
+    ``"flash"`` the kernel (`ops/pallas/latent_prefill.py`: a head's
+    ``[block, chunk]`` scores stay in VMEM, a block on the diagonal is
+    computed up to the diagonal), ``"dense"`` plain XLA, the parity
+    oracle, whose largest array is the float32 ``[heads, chunk,
+    block]`` scores. Same operands, same precision, same order.
     """
     pool = layer_cache["k"]
     page_size = pool.shape[-1]
     _, T, H, _ = q.shape
-    n_table = page_table.shape[-1]
-    bp = _walk_pages(n_table, page_size, WALK_BLOCK)
-    S = bp * page_size
+    S = latent_walk_block(page_table.shape[-1], page_size)
+    bp = S // page_size
     pos = positions[0]                                   # [T]
     n_blocks = pos[-1] // S + 1
-    qh = jnp.swapaxes(q[0], 0, 1)                        # [H, T, dq]
+    flash = impl == "flash"
+    # the kernel's tile is keys first: a query to a lane
+    qh = jnp.transpose(q[0], (1, 2, 0) if flash else (1, 0, 2))
 
     def block(i, carry):
-        m_prev, l_prev, acc = carry
         pages = jax.lax.dynamic_slice_in_dim(page_table[0], i * bp, bp)
         lat = jnp.take(pool, pages, axis=0)[:, 0]        # [bp, D, page]
         lat = jnp.moveaxis(lat, -1, 1).reshape(S, -1)    # [S, D]
         lat = lat.astype(compute_dtype)
-        kb, vb = expand(lat)                             # [S, H, dk|dv]
+        kb, shared, vb = expand(lat)        # [S, H, dk], [S, dr], [H, dv, S]
+        if flash:
+            from deepspeed_tpu.ops.pallas import flash_prefill_latent_block
+            return flash_prefill_latent_block(
+                qh, kb, shared, vb, carry, pos[0], i * S, scale=scale)
+        m_prev, l_prev, acc = carry
+        kb = jnp.concatenate(
+            [kb, jnp.broadcast_to(shared[:, None], (S, H, shared.shape[1]))],
+            -1)
         s = jnp.einsum("htd,shd->hts", qh, kb,
                        preferred_element_type=jnp.float32)
         k_pos = i * S + jnp.arange(S)
@@ -623,21 +646,21 @@ def latent_prefill_attention(q, layer_cache, positions, page_table, *,
         corr = jnp.exp(m_prev - m_new)
         l_new = l_prev * corr + pr.sum(-1, keepdims=True)
         pr = pr.astype(compute_dtype)
-        pv = jnp.einsum("hts,shv->htv", pr, vb,
+        pv = jnp.einsum("hts,hvs->htv", pr, vb,
                         preferred_element_type=jnp.float32)
         return m_new, l_new, acc * corr + pv
 
     # the output's width, from the expansion's shapes alone
     dv = jax.eval_shape(
         expand, jax.ShapeDtypeStruct((S, pool.shape[2]),
-                                     compute_dtype))[1].shape[-1]
+                                     compute_dtype))[2].shape[1]
+    stat, out = ((H, 1, T), (H, dv, T)) if flash else ((H, T, 1), (H, T, dv))
     _, l, acc = jax.lax.fori_loop(
         0, n_blocks, block,
-        (jnp.full((H, T, 1), -jnp.inf, jnp.float32),
-         jnp.zeros((H, T, 1), jnp.float32),
-         jnp.zeros((H, T, dv), jnp.float32)))
+        (jnp.full(stat, -jnp.inf, jnp.float32),
+         jnp.zeros(stat, jnp.float32), jnp.zeros(out, jnp.float32)))
     y = (acc / jnp.maximum(l, 1e-30)).astype(compute_dtype)
-    return jnp.swapaxes(y, 0, 1)[None]                   # [1, T, H, dv]
+    return jnp.transpose(y, (2, 0, 1) if flash else (1, 0, 2))[None]
 
 
 def cached_attention(q, k_new, v_new, layer_cache, positions,
@@ -670,7 +693,8 @@ def cached_attention(q, k_new, v_new, layer_cache, positions,
     6, PR 33). Prefill chunks and speculative verify (T > 1) always
     use :func:`paged_write_kv` and the dense path over
     :func:`paged_read_kv`'s gathered view, which stays the parity
-    oracle, as does the dense decode step. ``mesh``: a TP mesh whose ``model`` axis shards
+    oracle, as does the dense decode step (a latent pool's chunk has a
+    kernel of its own: below). ``mesh``: a TP mesh whose ``model`` axis shards
     the pool's head dim — the flash call then runs under ``shard_map``
     per local head shard. ``mask``: a precomputed
     :func:`attention_mask` (dense path only) so multi-layer callers
@@ -691,8 +715,11 @@ def cached_attention(q, k_new, v_new, layer_cache, positions,
     (which reads a block once for keys and values) or the dense oracle
     below, and ``y`` comes back ``[B, 1, Hq, v_dim]``. One prompt's
     chunk (``B == 1``, ``T > 1``) is written by :func:`paged_write_kv`
-    and attends by :func:`latent_prefill_attention` through the
-    caller's ``expand``, with ``q`` of the expanded width.
+    and attends by :func:`latent_prefill_attention`'s walk over the
+    row's live blocks through the caller's ``expand``, with ``q`` of
+    the expanded width; a block is taken in by the prefill kernel
+    (`ops/pallas/latent_prefill.py`) under ``impl="flash"`` and by
+    plain XLA, the parity oracle, under ``impl="dense"``.
     """
     latent = "v" not in layer_cache
     if latent and (v_new is not None or v_dim is None or scale is None):
@@ -713,7 +740,8 @@ def cached_attention(q, k_new, v_new, layer_cache, positions,
                 "one prompt's chunk (one row), through expand")
         return latent_prefill_attention(
             q, layer_cache, positions, page_table, expand=expand,
-            scale=scale, compute_dtype=compute_dtype), layer_cache
+            scale=scale, compute_dtype=compute_dtype,
+            impl=impl), layer_cache
     if mask is None:
         mask = attention_mask(layer_cache, positions, page_table)
     k_full, v_full = paged_read_kv(layer_cache, page_table, compute_dtype)

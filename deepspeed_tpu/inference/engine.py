@@ -358,9 +358,14 @@ class InferenceEngine:
         # the row's recurrent leaves (if the model has any) through its
         # slot; the whole cache flows through so donation updates it in
         # place. The logits are the last real token's.
+        # A latent pool's chunk obeys ``attention_impl`` as a decode
+        # step does (the prefill kernel); any other pool's chunk takes
+        # the dense path whatever it says, and is not told.
+        attn = {"attn_impl": self.attention_impl} \
+            if self.spec.latent_v_dim else {}
         logits, cache = self.model.serve_apply(
             params, cache, tokens, positions, page_table, slots,
-            n_valid)[:2]
+            n_valid, **attn)[:2]
         # fp32 on the way out: host-side sampling/parity reads full
         # precision regardless of compute dtype (a no-op for f32 models,
         # so fp32 parity with the full forward stays bit-exact).
@@ -444,6 +449,16 @@ class InferenceEngine:
                 f"(chunk={chunk})")
         attrs["chunks"] = (padded - start) // chunk
         attrs["pad_tokens"] = padded - n
+        if self.spec.latent_v_dim:
+            # blocks of the walk over the prompt's calls, and those the
+            # prefill kernel took in: all of them or none
+            from deepspeed_tpu.inference.cache import latent_walk_block
+            block = latent_walk_block(pt.shape[-1], self.page_size)
+            blocks = sum((ci * chunk + chunk - 1) // block + 1
+                         for ci in range(start // chunk, padded // chunk))
+            attrs["attn_blocks"] = blocks
+            attrs["attn_blocks_kernel"] = \
+                blocks if self.attention_impl == "flash" else 0
         if start:
             refuse_recurrent(
                 self.spec, f"a prefill resumed at token {start}",

@@ -47,7 +47,9 @@ runs that through `flash_decode_paged`. A prefill chunk walks the
 row's live prefix in blocks (`cache.latent_prefill_attention`),
 expanding each block through ``W_ukv``: fewer operations a query-key
 pair at a chunk of 1024 than scoring it absorbed, and faster on the
-chip at every prefix (`PERF.md` section 6 (PR 34) has both readings).
+chip at every prefix (`PERF.md` section 6 (PR 34) has both readings);
+under ``attention_impl: "flash"`` a block's scores are the prefill
+kernel's (`ops/pallas/latent_prefill.py`).
 
 **The share.** ``experts_held = (first, count)``: the banks hold
 ``count`` of the router's ``n_routed_experts``; routing runs over all
@@ -313,11 +315,13 @@ class LatentAttention(nn.Module):
                 q_in = jnp.concatenate([q[..., :dn], q_rope], -1)
 
                 def expand(lat):
-                    kv = jnp.einsum("sc,chm->shm", lat[:, :rkv], w_ukv)
-                    shared = jnp.broadcast_to(
-                        lat[:, None, rkv:], kv.shape[:2] + (dr,))
-                    return (jnp.concatenate([kv[..., :dn], shared], -1),
-                            kv[..., dn:])
+                    # each as its product lies (keys a position a row,
+                    # values a position a lane), the rotary key once:
+                    # what the prefill kernel takes
+                    c = lat[:, :rkv]
+                    return (jnp.einsum("sc,chn->shn", c, w_ukv[..., :dn]),
+                            lat[:, rkv:],
+                            jnp.einsum("chv,sc->hvs", w_ukv[..., dn:], c))
         with jax.named_scope("ds_mla_prefill_attn" if T > 1
                              else "ds_mla_decode_attn"):
             y, layer_cache = cached_attention(

@@ -23,6 +23,7 @@ from deepspeed_tpu.ops.pallas.flash_decode import (
     flash_decode_paged,
 )
 from deepspeed_tpu.ops.pallas.fused_adam import pallas_adam_update
+from deepspeed_tpu.ops.pallas.latent_prefill import flash_prefill_latent_block
 
 __all__ = [
     "DEFAULT_BLOCK_K",
@@ -31,5 +32,6 @@ __all__ = [
     "dense_attention",
     "flash_attention",
     "flash_decode_paged",
+    "flash_prefill_latent_block",
     "pallas_adam_update",
 ]

@@ -479,12 +479,16 @@ def test_latent_flash_decode_compiles(chip):
     assert payload_shaped_copies(text, pool["k"].shape) == []
 
 
-@pytest.mark.parametrize("program", ["prefill", "decode"])
+@pytest.mark.parametrize("program", ["prefill", "prefill-flash", "decode"])
 def test_mla_moe_serving_programs_compile(chip, monkeypatch, program):
     """Both programs of Kimi-K2.7-Code's share at its published widths
     (the dense layer and one expert layer of the seven: the expert
     layers repeat), cache donated, as the engine calls them: a prefill
-    chunk of 1024 and a decode step of 32 rows over a bucket of 17,408.
+    chunk of 1024 (its walk in XLA, and under ``"flash"`` through the
+    prefill kernel, which then holds the only ``[1024, 1024]`` scores:
+    no ``[64, 1024, 1024]`` array is left in the program, and the
+    kernel's call lies inside the scope the benchmark's metric reads)
+    and a decode step of 32 rows over a bucket of 17,408.
     **No ``[heads, chunk, bucket]`` array in either**: the dense path's
     scores would be 64 x 1024 x 17,408 x 4 B = 4.6 GB; the largest
     buffer either program holds besides its arguments is a block of the
@@ -497,6 +501,7 @@ def test_mla_moe_serving_programs_compile(chip, monkeypatch, program):
     from deepspeed_tpu.models import mla_moe as mm
 
     for name in ("deepspeed_tpu.ops.pallas.flash_decode",
+                 "deepspeed_tpu.ops.pallas.latent_prefill",
                  "deepspeed_tpu.moe.dropless"):
         _compiled_not_interpreted(monkeypatch, name)
     cfg = mm.kimi_k2_share(n_layer=2)
@@ -511,10 +516,12 @@ def test_mla_moe_serving_programs_compile(chip, monkeypatch, program):
     i32 = lambda *shape: chip(shape, jnp.int32)         # noqa: E731
     per_row = MLA_BUCKET // PAGE
 
-    if program == "prefill":
+    if program != "decode":
+        impl = "flash" if program == "prefill-flash" else "dense"
+
         def fn(params, cache, tokens, positions, table, slots, n_valid):
             return model.serve_apply(params, cache, tokens, positions,
-                                     table, slots, n_valid)
+                                     table, slots, n_valid, attn_impl=impl)
         args = (i32(1, 1024), i32(1, 1024), i32(1, per_row), i32(1), i32(1))
     else:
         def fn(params, cache, tokens, positions, tables):
@@ -528,11 +535,11 @@ def test_mla_moe_serving_programs_compile(chip, monkeypatch, program):
         params, cache, *args).compile()
     text = compiled.as_text()
     # 3 grouped matmuls a expert layer; the decode kernel a layer
-    assert text.count("tpu_custom_call") >= (5 if program == "decode"
-                                              else 3)
+    assert text.count("tpu_custom_call") >= {"decode": 5, "prefill": 3,
+                                             "prefill-flash": 5}[program]
     for scope in ("ds_mla_project", "ds_moe_route", "ds_moe_experts",
-                  "ds_moe_shared", "ds_mla_prefill_attn" if
-                  program == "prefill" else "ds_flash_decode_paged"):
+                  "ds_moe_shared", "ds_flash_decode_paged" if
+                  program == "decode" else "ds_mla_prefill_attn"):
         assert scope in text, scope
     dense_scores = 64 * 1024 * MLA_BUCKET * 4
     memory = compiled.memory_analysis()
@@ -542,6 +549,16 @@ def test_mla_moe_serving_programs_compile(chip, monkeypatch, program):
              for dims in re.findall(r"(?:f32|bf16)\[([\d,]+)\]", text)}
     weights = max(a.size for a in jax.tree_util.tree_leaves(params))
     assert max(sizes) <= weights < dense_scores // 4
+    if program != "decode":
+        # a block's scores: the XLA walk's largest value, the kernel's
+        # own business
+        assert (64 * 1024 * 1024 in sizes) == (program == "prefill")
+    if program == "prefill-flash":
+        from benchmarks.suite.readers.scope_time import scopes_of
+        kernel = {k: v for k, v in scopes_of(text, "ds_m").items()
+                  if k.startswith("ds_flash_prefill_latent")}
+        assert len(kernel) == 2     # a layer
+        assert all("ds_mla_prefill_attn" in v for v in kernel.values())
     assert payload_shaped_copies(text, (MLA_PAGES, 1, 576, PAGE)) == []
     assert payload_shaped_copies(text, (MLA_PAGES, 576, PAGE)) == []
     # every cache leaf goes out where it came in
