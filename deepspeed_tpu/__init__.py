@@ -14,6 +14,11 @@ from deepspeed_tpu.runtime.lr_schedules import add_tuning_arguments
 from deepspeed_tpu.runtime.activation_checkpointing import (
     checkpointing)
 from deepspeed_tpu.utils.logging import logger, log_dist
+from deepspeed_tpu.telemetry import compile_cache as _compile_cache
+
+# the compile ledger and the collector's record go on the span ring from
+# here on: a harness's weights and a reference's jits are caught too
+_compile_cache.install()
 
 
 def _parse_version(version_str):
@@ -54,42 +59,47 @@ def initialize(args=None,
     lr_scheduler)`` for drop-in familiarity.
     """
     from deepspeed_tpu.runtime.pipe.module import PipelineModule
+    from deepspeed_tpu.telemetry.spans import Span
 
     log_dist(f"deepspeed_tpu info: version={__version__}, "
              f"git-hash={git_hash}, git-branch={git_branch}", ranks=[0])
 
-    if isinstance(model, PipelineModule):
-        from deepspeed_tpu.runtime.pipe.engine import PipelineEngine
-        engine = PipelineEngine(args=args,
-                                model=model,
-                                optimizer=optimizer,
-                                model_parameters=model_parameters,
-                                training_data=training_data,
-                                lr_scheduler=lr_scheduler,
-                                mpu=mpu,
-                                dist_init_required=dist_init_required,
-                                collate_fn=collate_fn,
-                                config=config,
-                                config_params=config_params,
-                                mesh=mesh,
-                                seed=seed)
-    else:
-        engine = DeepSpeedEngine(args=args,
-                                 model=model,
-                                 optimizer=optimizer,
-                                 model_parameters=model_parameters,
-                                 training_data=training_data,
-                                 lr_scheduler=lr_scheduler,
-                                 mpu=mpu,
-                                 dist_init_required=dist_init_required,
-                                 collate_fn=collate_fn,
-                                 config=config,
-                                 config_params=config_params,
-                                 loss_fn=loss_fn,
-                                 params=params,
-                                 param_specs=param_specs,
-                                 mesh=mesh,
-                                 seed=seed)
+    # the engine's construction on the span ring, kept past any window:
+    # ``setup/engine`` with the placing of the parameters and the
+    # optimizer state inside it (and every trace and compile they cost)
+    with Span("setup/engine"):
+        if isinstance(model, PipelineModule):
+            from deepspeed_tpu.runtime.pipe.engine import PipelineEngine
+            engine = PipelineEngine(args=args,
+                                    model=model,
+                                    optimizer=optimizer,
+                                    model_parameters=model_parameters,
+                                    training_data=training_data,
+                                    lr_scheduler=lr_scheduler,
+                                    mpu=mpu,
+                                    dist_init_required=dist_init_required,
+                                    collate_fn=collate_fn,
+                                    config=config,
+                                    config_params=config_params,
+                                    mesh=mesh,
+                                    seed=seed)
+        else:
+            engine = DeepSpeedEngine(args=args,
+                                     model=model,
+                                     optimizer=optimizer,
+                                     model_parameters=model_parameters,
+                                     training_data=training_data,
+                                     lr_scheduler=lr_scheduler,
+                                     mpu=mpu,
+                                     dist_init_required=dist_init_required,
+                                     collate_fn=collate_fn,
+                                     config=config,
+                                     config_params=config_params,
+                                     loss_fn=loss_fn,
+                                     params=params,
+                                     param_specs=param_specs,
+                                     mesh=mesh,
+                                     seed=seed)
 
     return_items = [
         engine,

@@ -132,6 +132,13 @@ class InferenceEngine:
 
     def __init__(self, model, params, config=None, mesh=None,
                  session=None):
+        # the construction on the span ring, kept past any window:
+        # ``setup/engine`` with the placing of the parameters and the
+        # pool's allocation inside it
+        with Span("setup/engine"):
+            self._build(model, params, config, mesh, session)
+
+    def _build(self, model, params, config, mesh, session):
         self.model = model
         self.max_batch = int(_cfg_get(config, "max_batch",
                                       DEFAULT_MAX_BATCH))
@@ -302,19 +309,22 @@ class InferenceEngine:
             # second step — breaking the 2-program contract under TP.
             self._sample_key = jax.device_put(
                 self._sample_key, NamedSharding(mesh, PartitionSpec()))
-            params = jax.tree_util.tree_map(
-                lambda leaf, spec: jax.device_put(
-                    leaf, NamedSharding(mesh, spec)),
-                params, model.partition_specs(params))
+            with Span("params"):
+                params = jax.tree_util.tree_map(
+                    lambda leaf, spec: jax.device_put(
+                        leaf, NamedSharding(mesh, spec)),
+                    params, model.partition_specs(params))
             self._cache_shardings = jax.tree_util.tree_map(
                 lambda spec: NamedSharding(mesh, spec),
                 kv_partition_specs(self.spec),
                 is_leaf=lambda x: not isinstance(x, dict))
-            cache = jax.tree_util.tree_map(
-                jax.device_put, init_kv_cache(self.spec),
-                self._cache_shardings)
+            with Span("pool"):
+                cache = jax.tree_util.tree_map(
+                    jax.device_put, init_kv_cache(self.spec),
+                    self._cache_shardings)
         else:
-            cache = init_kv_cache(self.spec)
+            with Span("pool"):
+                cache = init_kv_cache(self.spec)
         self.params = params
         self.cache = cache
 
@@ -425,9 +435,11 @@ class InferenceEngine:
             raise ValueError(
                 f"prompt length {n} outside (0, max_seq={self.max_seq}]")
         # one span for the whole prompt, its uploads included; ``rid``
-        # is the enclosing (scheduler's ``admit``) span's, so the call
-        # takes no new argument
-        attrs = {"rid": enclosing_attr("rid")}
+        # and ``rows_waiting`` (the rows in decode that wait while this
+        # prompt is prefilled) are the enclosing (scheduler's ``admit``)
+        # span's, so the call takes no new argument
+        attrs = {"rid": enclosing_attr("rid"),
+                 "rows_waiting": enclosing_attr("rows_waiting")}
         with Span("prefill", self.session, attrs):
             return self._prefill_chunks(slot, prompt, page_table, start,
                                         attrs)

@@ -32,9 +32,16 @@ has returned: the token exists on the host), ``first_return_t`` (the
 ``serve/request`` record in the span ring. Every ``step()`` is one
 ``serve/step`` span whose attrs carry that step's counters, read at the
 step's end (``live_rows``, ``batch``, ``max_batch``, ``queue_depth``,
-``tokens``, ``pages_live``, ``pages_resident``, ``pages_total``), with
+``tokens``, ``pages_live``, ``pages_resident``, ``pages_total``;
+``gc_s``, the collector's seconds over the step; and, on a step that
+closes 50 ms or more after the last one that had them, ``cpu_s`` /
+``cpu_wall_s``, the thread's CPU and wall seconds since then
+(``spans.CpuMark``): by these a stall is read afterwards), with
 children ``expire``, ``admit`` (one per request taken up, attrs
-``rid``; under it ``pages``, the engine's ``prefill`` and ``sample``), ``grow``, ``inputs``, the engine's ``decode`` (under
+``rid`` and ``rows_waiting``, the rows that hold a request in decode
+while this one's prompt is prefilled; under it ``pages``, the engine's
+``prefill``, which takes both over, and ``sample``), ``grow``,
+``inputs``, the engine's ``decode`` (under
 it ``upload``, ``dispatch``, ``wait_tokens``, ``logits_d2h``; a
 speculative engine has ``draft`` and ``verify`` instead) and ``book``.
 
@@ -55,7 +62,8 @@ from typing import List, Optional
 import numpy as np
 
 from deepspeed_tpu.runtime.resilience import fault_injection
-from deepspeed_tpu.telemetry.spans import Span, clock, record
+from deepspeed_tpu.telemetry.spans import (
+    CpuMark, Span, clock, collector, record)
 
 
 @dataclasses.dataclass
@@ -180,8 +188,10 @@ class ContinuousBatchingScheduler:
         # first token is not out before that step returns
         self._unreturned = []
         self._step_attrs = {}           # the running step's counters
+        self._cpu_mark = CpuMark()
         from deepspeed_tpu.inference.paging import PagedCacheManager
-        self.paging = PagedCacheManager(engine, session=self.session)
+        with Span("setup/engine/paging"):
+            self.paging = PagedCacheManager(engine, session=self.session)
 
     # -- request lifecycle --------------------------------------------------
 
@@ -368,7 +378,8 @@ class ContinuousBatchingScheduler:
                     self.queue[0].arrival_step > self.step_count:
                 break
             req = self.queue[0]
-            attrs = {"rid": req.rid}
+            attrs = {"rid": req.rid, "rows_waiting": sum(
+                s is not None for s in self.slots)}
             with Span("admit", self.session, attrs):
                 if not self._admit_one(i, req):
                     # pool can't back the prompt right now even after
@@ -415,11 +426,18 @@ class ContinuousBatchingScheduler:
         attrs = self._step_attrs = {
             "step": self.step_count, "max_batch": self.engine.max_batch,
             "batch": 0, "tokens": 0}
+        gc0 = collector.seconds
         try:
             with Span("serve/step", self.session, attrs):
                 try:
                     return self._step()
                 finally:
+                    # the collector's seconds over the step and, every
+                    # 50 ms or more, the thread's CPU seconds beside the
+                    # wall's: wall without CPU is a blocked or
+                    # descheduled thread
+                    attrs["gc_s"] = collector.seconds - gc0
+                    self._cpu_mark.stamp(attrs)
                     # the step's counters, read where they are true:
                     # at its end, as a caller polling after it would
                     attrs["live_rows"] = sum(

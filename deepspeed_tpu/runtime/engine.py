@@ -79,8 +79,8 @@ from deepspeed_tpu.ops.lamb.fused_lamb import init_lamb_state, lamb_update
 from deepspeed_tpu.parallel.collectives import record_collective_sites
 from deepspeed_tpu.parallel.mesh import build_mesh
 from deepspeed_tpu.telemetry import (
-    StepAnomalyDetector, TelemetrySession, TraceProfiler, null_span,
-    set_default_session)
+    Span, StepAnomalyDetector, TelemetrySession, TraceProfiler,
+    set_default_session, spans)
 from deepspeed_tpu.telemetry.timers import (
     SynchronizedWallClockTimer, ThroughputTimer)
 from jax import shard_map
@@ -562,12 +562,16 @@ class DeepSpeedEngine:
             # Copy (never alias) the caller's params: the compiled train
             # step donates the engine's buffers, and donating the caller's
             # arrays would delete them out from under the caller.
-            fp32 = jax.tree_util.tree_map(
-                lambda p: jnp.array(p, dtype=jnp.float32, copy=True), params)
-            self.params = jax.device_put(fp32, self._shardings["param"])
-            self.opt_state = jax.jit(
-                self.opt_init_fn,
-                out_shardings=self._opt_state_shardings())(self.params)
+            # (both under `initialize`'s ``setup/engine`` span)
+            with Span("params"):
+                fp32 = jax.tree_util.tree_map(
+                    lambda p: jnp.array(p, dtype=jnp.float32, copy=True),
+                    params)
+                self.params = jax.device_put(fp32, self._shardings["param"])
+            with Span("optimizer_state"):
+                self.opt_state = jax.jit(
+                    self.opt_init_fn,
+                    out_shardings=self._opt_state_shardings())(self.params)
         self.device_state = self._init_device_state()
 
         # --- data --------------------------------------------------------
@@ -605,6 +609,7 @@ class DeepSpeedEngine:
         # tests and health guards read without file I/O.
         tl = self._config.telemetry
         self.telemetry = None
+        self._cpu_mark = spans.CpuMark()    # train/step's cpu_s, always on
         self.metrics_history = collections.deque(maxlen=tl.history)
         self._batch_tokens = None
         self._anomaly_detector = None
@@ -1709,8 +1714,7 @@ class DeepSpeedEngine:
             # Nested under the caller's `dispatch` span: the host-Adam
             # phase shows up as its own range inside the step's dispatch
             # window on both the event log and the xplane trace.
-            with (self.telemetry.span if self.telemetry is not None
-                  else null_span)("host_adam"):
+            with Span("host_adam", self.telemetry):
                 opt = self.cpu_optimizer
                 bf16 = self.compute_dtype == jnp.bfloat16
                 lr, b1 = float(metrics["lr"]), float(metrics["beta1"])
@@ -2618,11 +2622,26 @@ class DeepSpeedEngine:
         # before this step consumes a batch (the dataloader position in
         # the checkpoint must not run ahead of the optimizer state).
         self._check_preemption()
-        # Telemetry-off fast path: `tele is None` is the only per-step
-        # cost, and `span` degrades to a shared no-op context manager
-        # (pinned by the overhead micro-benchmark test).
+        # The step on the span ring, telemetry on or off, as a serving
+        # step is: ``train/step`` with ``dispatch`` inside it, carrying
+        # the collector's seconds over the step and the thread's CPU
+        # seconds beside the wall's since the last step that had them
+        # (``spans.CpuMark``: wall without CPU is a blocked or
+        # descheduled thread). It feeds no session: the step event's
+        # phases are its children.
+        attrs = {"step": self.global_steps}
+        gc0 = spans.collector.seconds
+        with Span("train/step", attrs=attrs):
+            try:
+                return self._train_batch(batch)
+            finally:
+                attrs["gc_s"] = spans.collector.seconds - gc0
+                self._cpu_mark.stamp(attrs)
+
+    def _train_batch(self, batch):
+        # Telemetry off, a span lands in the ring and nowhere else (its
+        # cost is pinned by the overhead micro-benchmark test).
         tele = self.telemetry
-        span = tele.span if tele is not None else null_span
         watchdog = tele.watchdog if tele is not None else None
         step_wall_t0 = time.perf_counter() if tele is not None else 0.0
         if watchdog is not None:
@@ -2630,7 +2649,7 @@ class DeepSpeedEngine:
         if batch is None:
             assert self._data_iter is not None, \
                 "no training_data given; pass a batch explicitly"
-            with span("data_load"):
+            with Span("data_load", tele):
                 batch = next(self._data_iter)
         first_compile = self._compiled_train_step is None
         if first_compile:
@@ -2659,7 +2678,7 @@ class DeepSpeedEngine:
         if self.wall_clock_breakdown():
             self.timers("train_batch").start()
         self.tput_timer.start()
-        with span("dispatch"):
+        with Span("dispatch", tele):
             placed = self._shard_batch(batch)
             # Fault harness: a host-side sleep here simulates a stuck
             # collective/straggler inside the step — the watchdog test
@@ -2671,7 +2690,7 @@ class DeepSpeedEngine:
                 fault_injection.maybe_kill("step", self.global_steps)
                 hang_s = fault_injection.hang_seconds(self.global_steps)
                 if hang_s > 0.0:
-                    with span("injected_hang"):
+                    with Span("injected_hang", tele):
                         time.sleep(hang_s)
             # Derive the step rng from the CHECKPOINTED step counter rather
             # than an in-memory split chain: a resumed engine replays the
@@ -2690,7 +2709,7 @@ class DeepSpeedEngine:
                 # Compile-time audit: lowering here both triggers the one
                 # real compile (the step call below is then a jit-cache
                 # hit) and hands the audit the exact HLO that will execute.
-                with span("compile"):
+                with Span("compile", tele):
                     self._run_compile_audit(placed, step_rng, lr_in)
             # Collective confessions for the flight recorder: the first
             # call traces the step, and the overlap/ring helpers log one
@@ -2729,7 +2748,7 @@ class DeepSpeedEngine:
             # Telemetry syncs here too — the step event's wall time must
             # cover device execution, and device_wait IS the async-
             # dispatch slack (host-bound runs show it near zero).
-            with span("device_wait"):
+            with Span("device_wait", tele):
                 jax.block_until_ready(metrics["loss"])
         self.tput_timer.stop()
         if self.wall_clock_breakdown():
@@ -2781,10 +2800,16 @@ class DeepSpeedEngine:
                     log_dist(f"analysis[{f.rule}/{f.severity}]: "
                              f"{f.message}", ranks=[0])
                 if tele is not None:
+                    # whose jit cache grew is the detector's finding;
+                    # which function and how long, the compile ledger's
+                    from deepspeed_tpu.telemetry import compile_cache
+                    compiled = compile_cache.last_compile() or {}
                     tele.emit("recompile", step=self.global_steps,
                               cache_size=findings[0].details["cache_size"],
                               expected=findings[0].details["expected"],
-                              message=findings[0].message)
+                              message=findings[0].message,
+                              fun=compiled.get("fun"),
+                              compile_seconds=compiled.get("seconds"))
                     self._arm_anomaly_trace("recompile")
                 if an.fail_on_findings:
                     raise AuditError(AuditReport(flavor="live",
@@ -3099,7 +3124,7 @@ class DeepSpeedEngine:
             tag = f"global_step{self.global_steps}"
         tele = self.telemetry
         t0 = time.perf_counter()
-        with (tele.span if tele is not None else null_span)("checkpoint"):
+        with Span("checkpoint", tele):
             state = self._checkpoint_state_tree()
             meta = self._checkpoint_meta(client_state)
             extra_manifest = {

@@ -8,10 +8,12 @@ layers, bench.py, and the ``ds_tpu_metrics`` CLI share:
   (`registry.py`).
 - :class:`TelemetrySession` / :func:`get_default_session` — registry +
   event log + span API bundled per run (`session.py`).
-- :func:`null_span` — the telemetry-off no-op fast path (`spans.py`).
-- :class:`Span` — the span; `spans.py` also has the one clock of spans
-  and request stamps (``spans.clock``) and the process-wide ring every
-  closed span lands in (``spans.recent`` / ``spans.record``).
+- :class:`Span` — the span (without a session: the telemetry-off
+  path); `spans.py` also has the one clock of spans and request stamps
+  (``spans.clock``), the process-wide ring every closed span lands in
+  (``spans.recent`` / ``spans.record``) and the collector's callback;
+  `compile_cache.py` puts every trace, lowering and compile on that
+  ring.
 - :data:`SCHEMA_VERSION` — the event-log version tag, also embedded in
   ``ds_tpu_audit --json`` so audits and telemetry join (`events.py`).
 - The synchronized timers and the trace-window profiler that moved here
@@ -37,7 +39,7 @@ from deepspeed_tpu.telemetry.registry import (  # noqa: F401
     Counter, Gauge, Histogram, MetricsRegistry)
 from deepspeed_tpu.telemetry.session import (  # noqa: F401
     TelemetrySession, get_default_session, set_default_session)
-from deepspeed_tpu.telemetry.spans import Span, null_span  # noqa: F401
+from deepspeed_tpu.telemetry.spans import Span  # noqa: F401
 from deepspeed_tpu.telemetry.timers import (  # noqa: F401
     SynchronizedWallClockTimer, ThroughputTimer)
 
@@ -62,7 +64,6 @@ __all__ = [
     "device_report",
     "get_default_session",
     "install_crash_hooks",
-    "null_span",
     "set_default_session",
     "uninstall_crash_hooks",
 ]

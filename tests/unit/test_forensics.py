@@ -296,7 +296,8 @@ def test_injected_hang_trips_watchdog_and_postmortem_renders(
     dump = read_dump(str(dumps[0]))
     assert dump["watchdog"]["step"] == 4
     # the dump caught the main thread inside the injected-hang span
-    assert dump["in_flight_phases"]["MainThread"] == "dispatch/injected_hang"
+    assert dump["in_flight_phases"]["MainThread"] == \
+        "train/step/dispatch/injected_hang"
     assert any("injected_hang" in "\n".join(t["stack"])
                for t in dump["threads"])
     assert any(e.get("event") == "step" for e in dump["events"])

@@ -161,16 +161,24 @@ def test_queue_timeout_is_recorded_without_the_later_stamps():
 # ---------------------------------------------------------------------------
 
 def _children(records, parent):
-    """Direct children of ``parent`` (a record) among ``records``."""
+    """Direct children of ``parent`` (a record) among ``records``, in
+    the order they opened (a kept one, the ``admit`` that compiled, is
+    merged among the others by close time, and another file's hand-made
+    record at a far clock reading may stand between them)."""
     path, t0, t1, _ = parent
     depth = path.count("/") + 1
-    return [r for r in records
-            if r[0].startswith(path + "/") and r[0].count("/") == depth
-            and t0 <= r[1] and r[2] <= t1]
+    return sorted((r for r in records
+                   if r[0].startswith(path + "/")
+                   and r[0].count("/") == depth
+                   and t0 <= r[1] and r[2] <= t1), key=lambda r: r[1])
 
 
 def test_every_step_holds_its_children(served):
     _, records, sched = served
+    # the spans: not the compile ledger's and the collector's records,
+    # which fall where they fall (tests/unit/test_ring_records.py)
+    records = [r for r in records
+               if "/jax/" not in r[0] and not r[0].endswith("/gc")]
     steps = [r for r in records if r[0] == "serve/step"]
     assert len(steps) == sched.step_count
     seen = set()
@@ -191,9 +199,10 @@ def test_every_step_holds_its_children(served):
     want = {"serve/step/" + n
             for n in ("expire", "admit", "inputs", "decode", "book")}
     assert want <= seen and "serve/step/grow" in seen
-    # no span of the serving path lies outside a step
+    # no span of the serving path lies outside a step (but the
+    # scheduler's construction)
     for path, t0, t1, _ in records:
-        if path != "serve/request":
+        if path != "serve/request" and not path.startswith("setup/engine"):
             assert path.startswith("serve/step"), path
 
 
@@ -378,13 +387,18 @@ def test_flight_recorder_reads_the_ring(tmp_path):
     with session.span("dispatch"):
         with session.span("compile"):
             snap = rec.snapshot("probe")
-    log = [(p["kind"], p["path"]) for p in snap["phase_log"]]
+    def spans_of(snapshot):
+        # a collection may fall anywhere and leaves a record of its own
+        return [p for p in snapshot["phase_log"]
+                if not p["path"].endswith("/gc")]
+
+    log = [(p["kind"], p["path"]) for p in spans_of(snap)]
     # closed: enter and exit; open: enter only
     assert log == [("enter", "no_session"), ("exit", "no_session"),
                    ("enter", "dispatch"), ("enter", "dispatch/compile")]
-    assert abs(snap["phase_log"][-1]["t"] - time.time()) < 5.0
-    assert snap["phase_log"][1]["duration_s"] >= 0
-    log = rec.snapshot("after")["phase_log"]
+    assert abs(spans_of(snap)[-1]["t"] - time.time()) < 5.0
+    assert spans_of(snap)[1]["duration_s"] >= 0
+    log = spans_of(rec.snapshot("after"))
     assert [(p["kind"], p["path"]) for p in log][2:] == [
         ("enter", "dispatch"), ("enter", "dispatch/compile"),
         ("exit", "dispatch/compile"), ("exit", "dispatch")]
