@@ -125,7 +125,7 @@ class InferenceEngine:
     :class:`~deepspeed_tpu.telemetry.session.TelemetrySession` the
     scheduler emits ``decode_step`` events through. :meth:`prefill` and
     :meth:`decode` open their own spans (``prefill``; ``decode`` with
-    ``upload``, ``dispatch``, ``wait_tokens``, ``logits_d2h``), which
+    ``upload``, ``dispatch``, ``wait_tokens``), which
     land in the process-wide span ring with or without a session and
     nest under the scheduler's ``serve/step``.
     """
@@ -376,9 +376,9 @@ class InferenceEngine:
         logits, cache = self.model.serve_apply(
             params, cache, tokens, positions, page_table, slots,
             n_valid, **attn)[:2]
-        # fp32 on the way out: host-side sampling/parity reads full
-        # precision regardless of compute dtype (a no-op for f32 models,
-        # so fp32 parity with the full forward stays bit-exact).
+        # fp32 on the way out: a reader gets full precision whatever the
+        # compute dtype (a no-op for f32 models: parity stays bit-exact).
+        # prefill() copies this one row home; decode() copies no logits.
         return logits.astype(jnp.float32), self._pin_cache(cache)
 
     def _decode_fn(self, params, cache, tokens, positions, page_tables,
@@ -506,10 +506,14 @@ class InferenceEngine:
         """One decode step for every cache row at once. ``tokens`` /
         ``positions``: ``[max_batch]`` int arrays (inactive rows padded
         with zeros — their outputs are meaningless and ignored).
-        Returns ``(next_tokens [max_batch], logits [max_batch, vocab])``
-        as numpy; sampling (greedy argmax, or temperature/top-k/top-p
-        with the threaded PRNG key) happens in-program so it costs no
-        extra device round trip. ``page_tables``: ``[max_batch,
+        Returns ``(next_tokens, logits)``: the tokens ``[max_batch]`` as
+        numpy, the float32 logits ``[max_batch, vocab]`` as the
+        ``jax.Array`` the program produced, still on the device. A
+        caller that reads them pays for the copy (``np.asarray``, or an
+        index); the scheduler reads none. Sampling (greedy argmax, or
+        temperature/top-k/top-p with the threaded PRNG key) happens
+        in-program so it costs no extra device round trip.
+        ``page_tables``: ``[max_batch,
         pages_per_row]`` (inactive rows all zeros — their garbage token
         lands on the trash page)."""
         if self.tier == "prefill":
@@ -541,10 +545,10 @@ class InferenceEngine:
             # one masked pass over every slot)
             attrs = dict(attrs or {}, ssm_rows_live=rows,
                          ssm_rows_touched=self.max_batch)
-        # four spans, so that a gap on the device can be laid to the
+        # three spans, so that a gap on the device can be laid to the
         # part of the call the host was in: the uploads, the dispatch,
-        # the wait for the tokens (the device's own time), the logits'
-        # copy to the host
+        # the wait for the tokens (the device's own time). The logits
+        # stay where the program left them
         with Span("decode", session, attrs):
             with Span("upload", session):
                 args = [jnp.asarray(np.asarray(tokens, np.int32)),
@@ -559,8 +563,6 @@ class InferenceEngine:
                     attrs.update(zip(self._counter_names,
                                      nxt[self.max_batch:].tolist()))
                     nxt = nxt[:self.max_batch]
-            with Span("logits_d2h", session):
-                logits = np.asarray(logits)
         return nxt, logits
 
     # -- host-RAM page tier -------------------------------------------------

@@ -42,7 +42,8 @@ children ``expire``, ``admit`` (one per request taken up, attrs
 while this one's prompt is prefilled; under it ``pages``, the engine's
 ``prefill``, which takes both over, and ``sample``), ``grow``,
 ``inputs``, the engine's ``decode`` (under
-it ``upload``, ``dispatch``, ``wait_tokens``, ``logits_d2h``; a
+it ``upload``, ``dispatch``, ``wait_tokens``: the step's tokens come
+home and its logits stay on the device, which nothing here reads; a
 speculative engine has ``draft`` and ``verify`` instead) and ``book``.
 
 The scheduler delegates page mapping to

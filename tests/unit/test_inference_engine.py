@@ -16,6 +16,7 @@ Two halves:
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.inference.engine import InferenceEngine
@@ -157,16 +158,15 @@ class TestSchedulerLogic:
         assert session.registry.counter("decode_tokens_total").value > 0
 
 
-def _tiny_engine(**cfg_kw):
+def _tiny_engine(cls=InferenceEngine, **cfg_kw):
     cfg = GPT2Config(vocab_size=64, n_positions=64, n_embd=32,
                      n_layer=2, n_head=4, dtype=jnp.float32)
     model = GPT2LMHead(cfg)
-    import jax
     params = model.init(jax.random.PRNGKey(0),
                         jnp.zeros((1, 8), jnp.int32))["params"]
     inf = {"max_batch": 2, "seq_buckets": (16, 32), "prefill_chunk": 4}
     inf.update(cfg_kw)
-    return InferenceEngine(model, params, config=inf)
+    return cls(model, params, config=inf)
 
 
 class TestEngineValidation:
@@ -269,3 +269,111 @@ class TestRecompileContract:
         assert (facts["page_size"], facts["pages_per_row"],
                 facts["n_pages"]) == (8, 4, 2 * 4 + 1)
         assert "kv_layout" not in facts
+
+
+# ---------------------------------------------------------------------------
+# decode() brings home the tokens; the logits stay on the device (PR 37)
+# ---------------------------------------------------------------------------
+
+SAMPLING = {
+    "greedy": {},
+    "temperature": {"temperature": 0.8, "top_k": 16, "top_p": 0.9,
+                    "sampling_seed": 3},
+}
+
+
+class CopiesLogits(InferenceEngine):
+    """``decode`` as it was before PR 37: the logits copied to the host
+    on every step, whoever reads them."""
+
+    def decode(self, tokens, positions, page_tables):
+        nxt, logits = super().decode(tokens, positions, page_tables)
+        return nxt, np.asarray(logits)
+
+
+def _decode_steps(eng, steps=10):
+    """Prefill row 0 (row 1 holds no request) and decode ``steps``
+    tokens, each the step before's; returns the tokens the row was fed
+    (the prompt, then one a step) and ``[(tokens, logits)]`` as
+    ``decode`` handed them back."""
+    tables = identity_tables(eng)
+    tables[1] = 0
+    fed = [5, 9, 2, 40, 11]
+    tok = eng.sample_first(eng.prefill(0, fed, tables[0]))
+    out = []
+    for _ in range(steps):
+        t = np.asarray([tok, 0], np.int32)
+        p = np.asarray([len(fed), 0], np.int32)
+        fed = [*fed, tok]
+        nxt, logits = eng.decode(t, p, tables)
+        out.append((nxt, logits))
+        tok = int(nxt[0])
+    return fed, out
+
+
+@pytest.mark.parametrize("sampling", sorted(SAMPLING))
+class TestDecodeLeavesLogitsOnTheDevice:
+    def test_tokens_are_numpy_and_logits_the_programs_array(self,
+                                                            sampling):
+        eng = _tiny_engine(**SAMPLING[sampling])
+        program, made = eng._decode, []
+
+        def watched(*args):
+            made.append(program(*args))
+            return made[-1]
+
+        eng._decode = watched
+        _, steps = _decode_steps(eng, steps=3)
+        assert len(made) == 3
+        for (nxt, logits), result in zip(steps, made):
+            assert type(nxt) is np.ndarray
+            assert nxt.shape == (2,) and nxt.dtype == np.int32
+            assert isinstance(logits, jax.Array)
+            assert logits is result[1]          # untouched: no copy made
+            assert logits.shape == (2, 64)
+            assert logits.dtype == jnp.float32
+
+    def test_logits_read_as_the_copied_ones_did(self, sampling):
+        """Bit for bit what the previous form handed back, and within
+        the parity tests' limit of their reference: the full forward
+        over the tokens the engine itself drew."""
+        eng = _tiny_engine(**SAMPLING[sampling])
+        old = _tiny_engine(CopiesLogits, **SAMPLING[sampling])
+        fed, new_steps = _decode_steps(eng)
+        old_fed, old_steps = _decode_steps(old)
+        assert fed == old_fed
+        ref = np.asarray(eng.model.apply(
+            {"params": eng.params}, jnp.asarray([fed], jnp.int32),
+            deterministic=True)[0], np.float32)
+        first = len(fed) - len(new_steps)
+        for i, ((nxt, logits), (old_nxt, old_logits)) in enumerate(
+                zip(new_steps, old_steps)):
+            assert type(old_logits) is np.ndarray
+            np.testing.assert_array_equal(nxt, old_nxt)
+            np.testing.assert_array_equal(np.asarray(logits), old_logits)
+            # a row and its argmax answer with no copy of the whole
+            np.testing.assert_array_equal(logits[0], old_logits[0])
+            assert int(logits[0].argmax()) == int(old_logits[0].argmax())
+            np.testing.assert_allclose(logits[0], ref[first + i],
+                                       atol=2e-6)
+
+    def test_ten_steps_compile_each_program_once(self, sampling):
+        eng = _tiny_engine(**SAMPLING[sampling])
+        _decode_steps(eng, steps=10)
+        assert eng.compile_counts() == {"prefill": 1, "decode": 1}
+        assert eng.recompile_findings() == []
+
+    def test_a_fixed_stream_draws_the_same_tokens(self, sampling):
+        def run(eng):
+            rng = np.random.default_rng(0)
+            reqs = [Request(f"r{i}",
+                            rng.integers(0, 64,
+                                         int(rng.integers(2, 20))).tolist(),
+                            max_new_tokens=int(rng.integers(2, 8)))
+                    for i in range(5)]
+            comps = ContinuousBatchingScheduler(eng).run(reqs)
+            return {c.rid: c.tokens for c in comps}
+
+        new = run(_tiny_engine(**SAMPLING[sampling]))
+        old = run(_tiny_engine(CopiesLogits, **SAMPLING[sampling]))
+        assert len(new) == 5 and new == old

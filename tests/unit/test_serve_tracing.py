@@ -29,7 +29,7 @@ from tests.unit.test_inference_engine import StubEngine
 
 STAMPS = ("arrival_t", "submit_t", "admit_t", "first_token_t",
           "first_return_t", "finish_t")
-DECODE_CHILDREN = {"upload", "dispatch", "wait_tokens", "logits_d2h"}
+DECODE_CHILDREN = {"upload", "dispatch", "wait_tokens"}
 
 
 def build_engine(session=None, **overrides):
@@ -204,6 +204,21 @@ def test_every_step_holds_its_children(served):
     for path, t0, t1, _ in records:
         if path != "serve/request" and not path.startswith("setup/engine"):
             assert path.startswith("serve/step"), path
+
+
+def test_no_step_copies_the_logits_home(served):
+    """Since PR 37 ``engine.decode`` hands the logits back on the device
+    and the scheduler drops them: no span of the copy, under any name a
+    step could give it, and what is left of the decode call is its
+    three children."""
+    comps, records, _ = served
+    assert sum(len(c.tokens) for c in comps) > len(comps)   # it decoded
+    assert not [r[0] for r in records if "logits" in r[0]
+                or r[0].endswith("d2h")]
+    under = {r[0] for r in records
+             if r[0].startswith("serve/step/decode/")
+             and "/jax/" not in r[0] and not r[0].endswith("/gc")}
+    assert under == {"serve/step/decode/" + n for n in DECODE_CHILDREN}
 
 
 def test_step_attrs_are_the_steps_counters(served):
