@@ -25,12 +25,15 @@ whose Mamba layer is Bamba's; Dao & Gu 2024, arXiv:2405.21060), with
   attention_multiplier)`` under the causal mask.
 - Mamba-2: ``[z, xBC, dt] = n W_in``; ``xBC = silu(conv(xBC) + b)``
   (depthwise, causal, ``mamba_d_conv`` taps), split into ``x`` (heads of
-  ``mamba_d_head``), ``B`` and ``C`` (``mamba_d_state`` wide, one group
-  shared by the heads); ``dt = softplus(dt + dt_bias)``,
+  ``mamba_d_head``), ``B`` and ``C`` (``mamba_n_groups`` groups of
+  ``mamba_d_state``: head ``h`` reads group ``h // (heads / groups)``;
+  Granite has one group, which every head reads); ``dt = softplus(dt +
+  dt_bias)``,
   ``A = -exp(A_log)``; ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t``,
   ``y_t = S_t C_t + D x_t``; ``y = RMSNorm(y * silu(z)) * w`` (the gate
-  before the norm, one group over the whole inner width);
-  ``out = y W_out``.
+  before the norm; the statistics over each group's ``d_inner /
+  mamba_n_groups`` channels, with one group over the whole inner
+  width); ``out = y W_out``.
 
 This file is the serving model: it runs through a cache
 (`inference/cache.py`: page pools for the attention layers, per-slot
@@ -95,8 +98,8 @@ class GraniteHybridConfig:
             raise ValueError(
                 f"layer_types must name {self.num_hidden_layers} layers, "
                 f"each '{MAMBA}' or '{ATTENTION}'; got {self.layer_types}")
-        if self.mamba_n_groups != 1:
-            raise ValueError("one B/C group only (mamba_n_groups 1)")
+        if self.mamba_n_heads % self.mamba_n_groups:
+            raise ValueError("mamba_n_groups must divide mamba_n_heads")
         if self.mamba_n_heads * self.mamba_d_head != \
                 self.mamba_expand * self.hidden_size:
             raise ValueError("mamba_n_heads x mamba_d_head must be "
@@ -287,7 +290,7 @@ class Mamba2Mixer(nn.Module):
       the recurrence for every row; a row with ``n_valid`` 0 holds no
       request and keeps its leaves.
     """
-    config: GraniteHybridConfig
+    config: Any     # a GraniteHybridConfig, or one with its mamba_* names
 
     @nn.compact
     def __call__(self, x, leaves, positions, slots, n_valid):
@@ -295,7 +298,17 @@ class Mamba2Mixer(nn.Module):
         B, T, C = x.shape
         H, P, N, K = cfg.mamba_n_heads, cfg.mamba_d_head, \
             cfg.mamba_d_state, cfg.mamba_d_conv
+        G = cfg.mamba_n_groups
         d_in, d_conv = cfg.d_inner, cfg.conv_dim
+
+        def maps(u):
+            """``B`` and ``C`` of the convolved channels ``u`` ``[rows,
+            d_conv]``: ``[rows, N]``, with groups ``[rows, G, N]``."""
+            b, c = u[:, d_in:d_in + G * N], u[:, d_in + G * N:]
+            if G > 1:
+                b, c = (m.reshape(-1, G, N) for m in (b, c))
+            return b, c
+
         pd = cfg.param_dtype
         with jax.named_scope("ds_ssm_in_proj"):
             w_in = self.param("in_proj", _in_proj_init(cfg),
@@ -322,8 +335,7 @@ class Mamba2Mixer(nn.Module):
             xs = u[:, :d_in].reshape(B, H, P)
             with jax.named_scope("ds_ssm_scan"):
                 y, state = ssm.ssm_decode_step(
-                    xs, dt[:, 0], A, u[:, d_in:d_in + N], u[:, d_in + N:],
-                    state, live)
+                    xs, dt[:, 0], A, *maps(u), state, live)
             y = y[:, None]                              # [B, 1, H, P]
             xs = xs[:, None]
         elif B == 1:
@@ -344,8 +356,7 @@ class Mamba2Mixer(nn.Module):
                 # the ragged tail: dt = 0 decays nothing, adds nothing
                 dt_row = jnp.where(jnp.arange(T)[:, None] < n, dt[0], 0.0)
                 y, s1 = ssm.ssd_chunked_scan(
-                    xs, dt_row, A, u[:, d_in:d_in + N], u[:, d_in + N:],
-                    s0, cfg.mamba_chunk_size)
+                    xs, dt_row, A, *maps(u), s0, cfg.mamba_chunk_size)
                 state = jax.lax.dynamic_update_index_in_dim(
                     state, s1, slot, 0)
             y, xs = y[None], xs[None]                   # [1, T, H, P]
@@ -358,9 +369,12 @@ class Mamba2Mixer(nn.Module):
             y = y + D.astype(jnp.float32)[:, None] * xs.astype(jnp.float32)
             y = y.reshape(B, T, d_in) * jax.nn.silu(z.astype(jnp.float32))
             w = self.param("norm_weight", nn.initializers.ones, (d_in,), pd)
+            if G > 1:       # each group's channels have their own statistics
+                y = y.reshape(B, T, G, d_in // G)
             y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True)
                                   + cfg.rms_norm_eps)
-            y = (y * w.astype(jnp.float32)).astype(cfg.dtype)
+            y = (y.reshape(B, T, d_in) *
+                 w.astype(jnp.float32)).astype(cfg.dtype)
         with jax.named_scope("ds_ssm_out_proj"):
             y = _linear(self, "out_proj", cfg, (d_in, C), y)
         return y, {"ssm": state, "conv": window}

@@ -1,4 +1,4 @@
-"""Dropless top-k routing over a bank of gated experts.
+"""Dropless top-k routing over banks of experts.
 
 `moe/layer.py` routes GShard-style: one-hot ``[B, S, E, C]`` dispatch and
 combine tensors and a fixed capacity, so tokens over capacity are
@@ -38,6 +38,16 @@ row index and not by what the kernel left there. On one chip the layer
 runs without its exchange: what the other chips would add is not
 computed and nothing stands in for it.
 
+**An expert has one of two forms**, told by the banks given: three
+banks, ``w_down (silu(w_gate r) * w_up r)`` (OLMoE's, DeepSeek-V3's), or
+two (``w_gate`` None), ``w_down relu(w_up r)^2`` (Nemotron-H's: no gate
+matrix, so two grouped matmuls). And **what the router reads need not
+be what the experts take** (ISSUE 41): given ``rows``, routing runs on
+the tokens ``x`` while the rows that are sorted, gathered, multiplied
+and summed are ``rows`` (Nemotron-H's experts work on a 1024-wide
+projection of the 4096-wide tokens the router scores); the output is
+then as wide as ``rows``.
+
 The four phases carry ``jax.named_scope`` names (``ds_moe_route``,
 ``ds_moe_dispatch``, ``ds_moe_experts``, ``ds_moe_combine``) that reach
 the compiled program's op metadata, by which a trace's ops are laid to
@@ -70,10 +80,17 @@ def grouped_matmul(rows, bank, group_sizes):
     Interpret mode wherever the first device is not a TPU."""
     from jax.experimental.pallas.ops.tpu import megablox
 
+    # a tile of rows is whole sublanes (Mosaic refuses a block of 4 rows:
+    # 2 decode rows x 22 pairs): rows are padded up to eight; the padding
+    # lies behind every group, so no tile of it is visited
+    n_rows = rows.shape[0]
+    if n_rows % 8:
+        rows = jnp.pad(rows, ((0, -n_rows % 8), (0, 0)))
     tiling = (math.gcd(rows.shape[0], 256), min(rows.shape[1], 1024),
               min(bank.shape[2], 1024))
-    return megablox.gmm(rows, bank, group_sizes, rows.dtype, tiling,
-                        interpret=jax.devices()[0].platform != "tpu")
+    out = megablox.gmm(rows, bank, group_sizes, rows.dtype, tiling,
+                       interpret=jax.devices()[0].platform != "tpu")
+    return out[:n_rows]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -169,9 +186,11 @@ def _sort_pairs(keys, n_groups):
 
 
 def _dropless_moe(x, router, w_gate, w_up, w_down, top_k,
-                  route=softmax_top_k, first_expert=None, token_mask=None):
+                  route=softmax_top_k, first_expert=None, token_mask=None,
+                  rows=None):
     """`dropless_moe` on the tokens of one chip."""
     n_tokens, n_experts = x.shape[0], router.shape[1]
+    taken = x if rows is None else rows
     with jax.named_scope("ds_moe_route"):
         weights, experts, aux = route(x, router, top_k)
         pair_expert = experts.reshape(-1)
@@ -179,7 +198,7 @@ def _dropless_moe(x, router, w_gate, w_up, w_down, top_k,
             group_sizes, order, inverse = _sort_pairs(pair_expert,
                                                       n_experts)
         else:
-            n_held = w_gate.shape[0]
+            n_held = w_up.shape[0]
             local = pair_expert - first_expert
             held = (local >= 0) & (local < n_held)
             if token_mask is not None:
@@ -187,12 +206,16 @@ def _dropless_moe(x, router, w_gate, w_up, w_down, top_k,
             group_sizes, order, inverse = _sort_pairs(
                 jnp.where(held, local, n_held), n_held)
     with jax.named_scope("ds_moe_dispatch"):
-        rows = _gather_tokens(x, order, inverse, top_k)     # [N k, M]
+        rows = _gather_tokens(taken, order, inverse, top_k)  # [N k, M]
     with jax.named_scope("ds_moe_experts"):
-        dt = x.dtype
-        hidden = jax.nn.silu(
-            grouped_matmul(rows, w_gate.astype(dt), group_sizes)) * \
-            grouped_matmul(rows, w_up.astype(dt), group_sizes)
+        dt = taken.dtype
+        if w_gate is None:
+            hidden = jnp.square(jax.nn.relu(
+                grouped_matmul(rows, w_up.astype(dt), group_sizes)))
+        else:
+            hidden = jax.nn.silu(
+                grouped_matmul(rows, w_gate.astype(dt), group_sizes)) * \
+                grouped_matmul(rows, w_up.astype(dt), group_sizes)
         out = grouped_matmul(hidden, w_down.astype(dt), group_sizes)
         if first_expert is not None:
             # rows behind the held groups belong to no expert here: the
@@ -211,7 +234,7 @@ def _dropless_moe(x, router, w_gate, w_up, w_down, top_k,
         "dropped": n_tokens * top_k - group_sizes.sum(),
         **aux,
     }
-    return y.astype(x.dtype), stats
+    return y.astype(taken.dtype), stats
 
 
 class ExpertExchangeUnsupported(NotImplementedError):
@@ -221,12 +244,19 @@ class ExpertExchangeUnsupported(NotImplementedError):
 
 
 def dropless_moe(x, router, w_gate, w_up, w_down, top_k,
-                 route=softmax_top_k, first_expert=None, token_mask=None):
+                 route=softmax_top_k, first_expert=None, token_mask=None,
+                 rows=None):
     """``y[t] = sum over the top_k experts e of token t of
     p[t, e] * w_down[e] (silu(w_gate[e] x[t]) * w_up[e] x[t])`` with
     ``p = softmax(x router)`` in float32 over all experts, the chosen
     probabilities used as they are (not renormalised); another
     ``route`` (`sigmoid_top_k`) gives other experts and weights.
+
+    ``w_gate`` None: an expert is two banks, ``w_down[e]
+    relu(w_up[e] r)^2``. ``rows`` ``[N, L]`` (or None: the tokens
+    themselves): what the experts take of each token, where that is not
+    what the router reads; the banks are then ``[E, L, I]`` and ``[E,
+    I, L]`` and ``y`` is ``[N, L]``.
 
     ``first_expert`` (an int, or None: the banks hold every expert):
     the banks hold the ``w_gate.shape[0]`` experts from ``first_expert``
@@ -255,28 +285,31 @@ def dropless_moe(x, router, w_gate, w_up, w_down, top_k,
     placed = placement()
     if placed is None or placed[0].shape[placed[1]] == 1:
         return _dropless_moe(x, router, w_gate, w_up, w_down, top_k,
-                             route, first_expert, token_mask)
+                             route, first_expert, token_mask, rows)
     if first_expert is not None:
         raise ExpertExchangeUnsupported(
             "dropless_moe with a share of the experts runs on one chip: "
             "no exchange of tokens between shares exists yet")
-    mesh, rows, _ = placed
-    if x.shape[0] % mesh.shape[rows]:
+    mesh, axis, _ = placed
+    if x.shape[0] % mesh.shape[axis]:
         raise ValueError(
             f"dropless_moe: {x.shape[0]} tokens do not divide over the "
-            f"{mesh.shape[rows]} devices of mesh axis {rows!r}")
+            f"{mesh.shape[axis]} devices of mesh axis {axis!r}")
 
-    def local(x, router, w_gate, w_up, w_down):
+    def local(x, rows, router, w_gate, w_up, w_down):
         y, stats = _dropless_moe(x, router, w_gate, w_up, w_down, top_k,
-                                 route)
+                                 route, rows=rows)
         for key in ("tokens_per_expert", "prob_sum", "z_sum", "dropped"):
-            stats[key] = jax.lax.psum(stats[key], rows)
+            stats[key] = jax.lax.psum(stats[key], axis)
         return y, stats
 
-    tokens = P(rows, None)
+    tokens = P(axis, None)
     return jax.shard_map(
-        local, mesh=mesh, in_specs=(tokens, P(), P(), P(), P()),
+        local, mesh=mesh,
+        # None (no rows apart from the tokens, no gate bank) is an
+        # empty tree: its spec is never read
+        in_specs=(tokens, tokens, P(), P(), P(), P()),
         out_specs=(tokens, {
             "chosen": tokens, "weights": tokens, "tokens_per_expert": P(),
             "prob_sum": P(), "z_sum": P(), "dropped": P()}),
-        check_vma=False)(x, router, w_gate, w_up, w_down)
+        check_vma=False)(x, rows, router, w_gate, w_up, w_down)

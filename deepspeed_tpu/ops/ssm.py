@@ -9,6 +9,12 @@ group, ``A < 0`` one scalar a head)::
     S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t        S: [P, N]
     y_t = S_t C_t + D x_t
 
+**Groups.** ``B`` / ``C`` come as ``[T, N]`` (one group: every head
+reads the same maps; Granite 4.0-H) or as ``[T, G, N]`` (``G`` groups:
+head ``h`` of ``H`` reads group ``h // (H / G)``; Nemotron-H has 8).
+The one-group form is the program it was before groups existed: the
+group axis appears in no shape of it.
+
 **Prefill** (:func:`ssd_chunked_scan`) is the state-space-duality form
 (Dao & Gu 2024, arXiv:2405.21060, section 6): the sequence is cut into
 chunks of ``chunk`` tokens; inside a chunk the output is one masked
@@ -46,10 +52,10 @@ def ssd_chunked_scan(x, dt, A, B, C, state, chunk):
 
     ``x`` ``[T, H, P]`` (compute dtype), ``dt`` ``[T, H]`` float32
     (after softplus; 0 on padding), ``A`` ``[H]`` float32 (negative),
-    ``B`` / ``C`` ``[T, N]`` (one group), ``state`` ``[H, P, N]``
-    float32 (the state before the first token). ``T`` is a multiple of
-    ``chunk``. Returns ``(y [T, H, P] float32 without the D term,
-    state after the last token)``.
+    ``B`` / ``C`` ``[T, N]`` (one group) or ``[T, G, N]``, ``state``
+    ``[H, P, N]`` float32 (the state before the first token). ``T`` is
+    a multiple of ``chunk``. Returns ``(y [T, H, P] float32 without the
+    D term, state after the last token)``.
     """
     T, H, P = x.shape
     N = B.shape[-1]
@@ -59,53 +65,69 @@ def ssd_chunked_scan(x, dt, A, B, C, state, chunk):
                          f"chunk {Q}")
     c = T // Q
     dtype = x.dtype
+    # the heads' axes and their letters: ``h``, or with groups ``gh``
+    # (group, head within it), the maps then carrying the ``g``
+    if B.ndim == 2:
+        hs, g, h = (H,), "", "h"
+    else:
+        hs, g, h = (B.shape[1], H // B.shape[1]), "g", "gh"
+    ms = B.shape[1:]
     with jax.named_scope(SSD_PREFILL_NAME):
-        a = (dt * A).reshape(c, Q, H)
-        acum = jnp.cumsum(a, axis=1)                    # [c, Q, H] <= 0
-        xdt = (x.astype(_F32) * dt[..., None]).reshape(c, Q, H, P)
-        Bc, Cc = B.reshape(c, Q, N), C.reshape(c, Q, N)
+        a = (dt * A).reshape(c, Q, *hs)
+        acum = jnp.cumsum(a, axis=1)                    # [c, Q, *hs] <= 0
+        xdt = (x.astype(_F32) * dt[..., None]).reshape(c, Q, *hs, P)
+        Bc, Cc = B.reshape(c, Q, *ms), C.reshape(c, Q, *ms)
 
         # inside a chunk: y_i = sum_{j<=i} (C_i.B_j) decay(j->i) dt_j x_j
-        G = jnp.einsum("cin,cjn->cij", Cc, Bc,
+        G = jnp.einsum(f"ci{g}n,cj{g}n->c{g}ij", Cc, Bc,
                        preferred_element_type=_F32)
-        acum_h = jnp.moveaxis(acum, 2, 1)               # [c, H, Q]
-        seg = acum_h[:, :, :, None] - acum_h[:, :, None, :]
+        acum_h = jnp.moveaxis(acum, 1, -1)              # [c, *hs, Q]
+        seg = acum_h[..., :, None] - acum_h[..., None, :]
         causal = jnp.tril(jnp.ones((Q, Q), bool))
-        M = G[:, None] * jnp.exp(jnp.where(causal, seg, -jnp.inf))
-        y = jnp.einsum("chij,cjhp->cihp", M.astype(dtype),
+        M = jnp.expand_dims(G, G.ndim - 2) * \
+            jnp.exp(jnp.where(causal, seg, -jnp.inf))
+        y = jnp.einsum(f"c{h}ij,cj{h}p->ci{h}p", M.astype(dtype),
                        xdt.astype(dtype), preferred_element_type=_F32)
 
         # what each chunk adds to the state at its end, and its decay
-        to_end = jnp.exp(acum[:, -1:, :] - acum)        # [c, Q, H]
-        add = jnp.einsum("cjhp,cjn->chpn",
+        to_end = jnp.exp(acum[:, -1:] - acum)           # [c, Q, *hs]
+        add = jnp.einsum(f"cj{h}p,cj{g}n->c{h}pn",
                          (xdt * to_end[..., None]).astype(dtype), Bc,
                          preferred_element_type=_F32)
-        whole = jnp.exp(acum[:, -1, :])                 # [c, H]
+        whole = jnp.exp(acum[:, -1])                    # [c, *hs]
 
         # from chunk to chunk: c is small (a prefill call holds a few)
+        state = state.reshape(*hs, P, N)
         before = []
         for i in range(c):
             before.append(state)
-            state = whole[i][:, None, None] * state + add[i]
-        before = jnp.stack(before)                      # [c, H, P, N]
+            state = whole[i][..., None, None] * state + add[i]
+        before = jnp.stack(before)                      # [c, *hs, P, N]
         y = y + jnp.exp(acum)[..., None] * jnp.einsum(
-            "cin,chpn->cihp", Cc.astype(_F32), before,
+            f"ci{g}n,c{h}pn->ci{h}p", Cc.astype(_F32), before,
             precision=_HIGHEST, preferred_element_type=_F32)
-    return y.reshape(T, H, P), state
+    return y.reshape(T, H, P), state.reshape(H, P, N)
+
+
+def _per_head(m, H):
+    """A step's maps ``[R, N]`` or ``[R, G, N]`` as every head of
+    ``[R, H, P, N]`` reads them."""
+    if m.ndim == 2:
+        return m.astype(_F32)[:, None, None, :]
+    return jnp.repeat(m.astype(_F32), H // m.shape[1], axis=1)[:, :, None]
 
 
 def ssm_decode_step(x, dt, A, B, C, state, live):
     """One step of every row. ``x`` ``[R, H, P]``, ``dt`` ``[R, H]``
-    float32, ``A`` ``[H]``, ``B`` / ``C`` ``[R, N]``, ``state``
-    ``[R, H, P, N]`` float32, ``live`` ``[R]`` bool. Returns ``(y
+    float32, ``A`` ``[H]``, ``B`` / ``C`` ``[R, N]`` or ``[R, G, N]``,
+    ``state`` ``[R, H, P, N]`` float32, ``live`` ``[R]`` bool. Returns ``(y
     [R, H, P] float32 without the D term, new state)``; a row that is
     not live keeps its state."""
     with jax.named_scope(SSM_DECODE_NAME):
         decay = jnp.exp(dt * A)[:, :, None, None]
         xdt = x.astype(_F32) * dt[..., None]
-        new = decay * state + \
-            xdt[..., None] * B.astype(_F32)[:, None, None, :]
-        y = jnp.sum(new * C.astype(_F32)[:, None, None, :], axis=-1)
+        new = decay * state + xdt[..., None] * _per_head(B, x.shape[1])
+        y = jnp.sum(new * _per_head(C, x.shape[1]), axis=-1)
         state = jnp.where(live[:, None, None, None], new, state)
     return y, state
 
