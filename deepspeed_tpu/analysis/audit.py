@@ -325,6 +325,7 @@ def _engine_context(engine, hlo_text, expected, pinfo, jaxpr_facts=None):
         flavor=flavor,
         n_devices=int(engine.mesh.shape.get("data", 1)),
         compute_dtype=compute,
+        platform=jax.devices()[0].platform,
         zero_stage=engine.zero_optimization_stage(),
         comm_quantized=cfg.comm_quantization.enabled,
         offload=engine._offload,

@@ -221,11 +221,15 @@ def make_grad_accumulator(loss_fn, compute_dtype, accum, constrain=None,
     constraining only after the scan would leave the carry layout to XLA's
     guess).
 
-    ``cast_params`` overrides the default fp32→compute-dtype cast — the
-    ZeRO-3 path passes the cast-then-gather transform
-    (`zero/sharding.py:make_param_caster` or the explicit
-    `zero/stage3.py:make_gather_on_use_caster`) so param all-gathers ride
-    the wire at 16 bit.
+    ``cast_params`` overrides the default fp32→compute-dtype cast — an
+    engine whose masters lie sharded passes the cast-then-gather transform
+    (`zero/sharding.py:make_param_caster` at ZeRO stages 1 and 2, or
+    stage 3's explicit `zero/stage3.py:make_gather_on_use_caster`) so
+    param all-gathers ride the wire at 16 bit. Without a ``remat_policy``
+    an accumulation scan applies it ONCE, in front of the scan: the
+    16-bit copy is gathered a step, not a microbatch, and each
+    microbatch's cotangent goes back through the transform's own
+    backward (fp32 first, then the masters' layout).
 
     ``remat_policy`` wraps the microbatch forward in ``jax.checkpoint``
     with that policy — the explicit ZeRO-3 step passes
@@ -269,21 +273,33 @@ def make_grad_accumulator(loss_fn, compute_dtype, accum, constrain=None,
                  "path: the 16-bit cast-then-gather wire does not apply; "
                  "param gathers will ride at fp32", ranks=[0])
 
-    def forward(p, micro_batch, rng, loss_kwargs):
-        if fp8_plan is None:
-            return loss_fn(cast_params(p), micro_batch, rng, **loss_kwargs)
-        # fp8: the differentiated argument is (params, fp8_state); the
-        # scope only needs to span the forward trace — the qdq
-        # custom_vjps carry everything the backward needs in residuals.
-        p, f8 = p
-        with fp8_scope(fp8_plan, f8):
-            return loss_fn(cast_params(p), micro_batch, rng, **loss_kwargs)
+    def forward_of(cast):
+        def forward(p, micro_batch, rng, loss_kwargs):
+            if fp8_plan is None:
+                return loss_fn(cast(p), micro_batch, rng, **loss_kwargs)
+            # fp8: the differentiated argument is (params, fp8_state); the
+            # scope only needs to span the forward trace — the qdq
+            # custom_vjps carry everything the backward needs in residuals.
+            p, f8 = p
+            with fp8_scope(fp8_plan, f8):
+                return loss_fn(cast(p), micro_batch, rng, **loss_kwargs)
+        return forward
 
+    forward = forward_of(cast_params)
     if remat_policy is not None:
         forward = jax.checkpoint(forward, policy=remat_policy)
+    # A caster that gathers is applied once in front of an accumulation
+    # scan and the scan differentiates the loss of the 16-bit tree (a
+    # gather inside the body is one a microbatch: XLA hoists no
+    # collective out of a loop). Not under a remat policy, which drops
+    # the gathered copy on purpose, nor for the default cast, which
+    # moves nothing.
+    hoist = user_caster is not None and remat_policy is None and \
+        accum > 1 and direct is None
+    forward_cast = forward_of(lambda p: p)
 
     def micro_grads(params, micro_batch, rng, scale, loss_kwargs,
-                    fp8_state=None):
+                    fp8_state=None, forward=forward):
         """``(loss, grads[, fp8 state], scalars)`` of one microbatch."""
         if direct is not None:
             return (*direct(params, micro_batch, rng, scale,
@@ -325,6 +341,13 @@ def make_grad_accumulator(loss_fn, compute_dtype, accum, constrain=None,
             lambda p: jnp.zeros(p.shape, jnp.float32), params)
         if constrain is not None:
             zeros = constrain(zeros)
+        fwd, uncast = forward, None
+        if hoist:
+            params, uncast = jax.vjp(cast_params, params)
+            fwd = forward_cast
+
+        def to_masters(g):
+            return g if uncast is None else uncast(g)[0]
 
         def summed(per_micro):
             return jax.tree_util.tree_map(lambda x: x.sum(0), per_micro)
@@ -339,8 +362,9 @@ def make_grad_accumulator(loss_fn, compute_dtype, accum, constrain=None,
                 g_acc, f8_acc, loss_acc, key = carry
                 key, sub = jax.random.split(key)
                 loss, g, f8_new, scalars = micro_grads(
-                    params, micro, sub, scale, loss_kwargs, fp8_state)
-                g_acc = jax.tree_util.tree_map(jnp.add, g_acc, g)
+                    params, micro, sub, scale, loss_kwargs, fp8_state, fwd)
+                g_acc = jax.tree_util.tree_map(jnp.add, g_acc,
+                                               to_masters(g))
                 if constrain is not None:
                     g_acc = constrain(g_acc)
                 f8_acc = jax.tree_util.tree_map(jnp.maximum, f8_acc,
@@ -357,8 +381,8 @@ def make_grad_accumulator(loss_fn, compute_dtype, accum, constrain=None,
             g_acc, loss_acc, key = carry
             key, sub = jax.random.split(key)
             loss, g, scalars = micro_grads(params, micro, sub, scale,
-                                           loss_kwargs)
-            g_acc = jax.tree_util.tree_map(jnp.add, g_acc, g)
+                                           loss_kwargs, forward=fwd)
+            g_acc = jax.tree_util.tree_map(jnp.add, g_acc, to_masters(g))
             if constrain is not None:
                 g_acc = constrain(g_acc)
             return (g_acc, loss_acc + loss, key), scalars
@@ -489,10 +513,11 @@ class DeepSpeedEngine:
         # --- shardings & placement ---------------------------------------
         base_specs = param_specs if param_specs is not None else \
             jax.tree_util.tree_map(lambda _: PartitionSpec(), params)
-        self._shardings = build_zero_shardings(
-            params, base_specs, self.mesh, self.zero_optimization_stage())
         self._offload = bool(self._config.zero_enabled and
                              self._config.zero_config.cpu_offload)
+        self._shardings = build_zero_shardings(
+            params, base_specs, self.mesh, self.zero_optimization_stage(),
+            sharded_masters=self._sharded_masters())
         if self._config.zero_config.offload_16bit_grads and \
                 not self._offload:
             log_dist("offload_16bit_grads: true has no effect without "
@@ -563,11 +588,23 @@ class DeepSpeedEngine:
             # step donates the engine's buffers, and donating the caller's
             # arrays would delete them out from under the caller.
             # (both under `initialize`'s ``setup/engine`` span)
+            # Where the masters' layout shards them, one program copies
+            # and slices on the device: a ``device_put`` that reshards
+            # took 3.0 s for GPT-2 XL's tree on four chips, the program
+            # 0.4 s warm (my chip run, PR 42), and no whole float32
+            # copy stands beside the caller's.
             with Span("params"):
-                fp32 = jax.tree_util.tree_map(
-                    lambda p: jnp.array(p, dtype=jnp.float32, copy=True),
-                    params)
-                self.params = jax.device_put(fp32, self._shardings["param"])
+                def copied(tree):
+                    return jax.tree_util.tree_map(
+                        lambda p: jnp.array(p, dtype=jnp.float32, copy=True),
+                        tree)
+                layout = self._shardings["param"]
+                if all(s.is_fully_replicated
+                       for s in jax.tree_util.tree_leaves(layout)):
+                    self.params = jax.device_put(copied(params), layout)
+                else:
+                    self.params = jax.jit(
+                        copied, out_shardings=layout)(params)
             with Span("optimizer_state"):
                 self.opt_state = jax.jit(
                     self.opt_init_fn,
@@ -1089,15 +1126,71 @@ class DeepSpeedEngine:
 
         return theta_fn
 
-    def _make_train_step(self):
+    def _step_kind(self):
+        """Which builder makes this engine's train step. Only ``dense``
+        differentiates the loss under GSPMD through ``cast_params``;
+        every other kind runs the loss inside its own ``shard_map`` with
+        the parameters' specs as ``in_specs`` (``pipeline``: the 1F1B
+        program's own casts), or keeps 16-bit parameters (``offload``)."""
+        if self._offload:
+            return "offload"
         if self.optimizer_name == ONEBIT_ADAM_OPTIMIZER:
+            return "onebit"
+        if self.sparse_gradients_enabled():
+            return "sparse"
+        if self._config.comm_quantization.enabled:
+            return "quantized"
+        if getattr(self.loss_fn, "direct_value_and_grad", None) is not None:
+            return "pipeline"
+        return "dense"
+
+    def _sharded_masters(self):
+        """Whether the float32 masters lie sharded over ``data`` between
+        steps at ZeRO stages 1 and 2, as ``m`` and ``v`` do, and every
+        program begins with the gather of their 16-bit copy
+        (:meth:`_param_caster`). Under float32 compute the copy is the
+        master, the gather would carry the same bytes at either end of
+        the step, and the replicated layout stays; so it does for the
+        step kinds that hand the parameters to a manual region."""
+        return (self.zero_optimization_stage() >= 1
+                and self.compute_dtype != jnp.float32
+                and self.dp_world_size > 1
+                and self._step_kind() == "dense")
+
+    def _param_caster(self):
+        """``cast(params) -> compute-dtype params`` of the programs that
+        read the masters under GSPMD (the dense train step, ``eval_batch``,
+        ``backward``): cast-then-gather where they lie sharded over
+        ``data`` (`zero/sharding.py:make_param_caster`), else None for
+        the plain cast. ``param_gather`` of the ``compile`` event is its
+        static split."""
+        if not hasattr(self, "_param_caster_cache"):
+            caster = None
+            if self._step_kind() == "dense" and \
+                    self.compute_dtype != jnp.float32:
+                caster = make_param_caster(
+                    self.params, self._shardings["param"], self.mesh,
+                    self.compute_dtype)
+            self._param_caster_cache = caster
+        return self._param_caster_cache
+
+    def _cast_for_loss(self):
+        """:meth:`_param_caster`, or the plain cast where it is None:
+        what ``eval_batch`` and ``backward`` hand their loss."""
+        compute_dtype = self.compute_dtype
+        return self._param_caster() or (lambda p: jax.tree_util.tree_map(
+            lambda x: x.astype(compute_dtype), p))
+
+    def _make_train_step(self):
+        kind = self._step_kind()
+        if kind == "onebit":
             if getattr(self.loss_fn, "direct_value_and_grad_local",
                        None) is not None:
                 return self._make_pipeline_onebit_train_step()
             return self._make_onebit_train_step()
-        if self.sparse_gradients_enabled():
+        if kind == "sparse":
             return self._make_sparse_grad_train_step()
-        if self._config.comm_quantization.enabled:
+        if kind == "quantized":
             return self._make_quantized_train_step()
         accum = self._engine_accum_steps()
         compute_dtype = self.compute_dtype
@@ -1118,15 +1211,17 @@ class DeepSpeedEngine:
         static_scale = self.static_loss_scale
         grad_constrain = (lambda g: constrain_tree(g, grad_shardings)) \
             if grad_shardings is not None else None
-        # ZeRO-3: per-use param gathers ride the wire at compute dtype
-        # (cast-then-gather, exact) — the analog of the reference
-        # gathering updated fp16 (not fp32 master) params at stage 1
-        # (stage1.py:692). Default is the explicit gather-on-use schedule
+        # Sharded masters reach the loss through a cast-then-gather: the
+        # wire carries the compute dtype, exactly (the reference gathers
+        # the updated fp16 shards, never the fp32 masters: stage1.py:692).
+        # Stages 1 and 2 (and `gather_on_use: false` stage 3, the bench
+        # A/B baseline) gather the whole tree once, at the head of the
+        # step, and keep the 16-bit copy for the backward
+        # (`zero/sharding.py:make_param_caster`; placement is XLA's).
+        # Stage 3's default is the explicit gather-on-use schedule
         # (`zero/stage3.py`): dep-chained per-leaf rings + a remat policy
         # that re-gathers in the backward instead of saving the gathered
-        # copies. `gather_on_use: false` keeps the legacy spec-sharded
-        # caster (`zero/sharding.py:make_param_caster`), where gather
-        # placement is XLA's — the bench A/B baseline.
+        # copies.
         fp8_cfg = self._config.fp8
         fp8_plan = fp8_cfg.plan()
         if fp8_plan is not None and \
@@ -1138,10 +1233,17 @@ class DeepSpeedEngine:
         caster = None
         remat_policy = None
         self._zero3_plan = None
-        if self.zero_optimization_stage() >= 3 and \
-                compute_dtype != jnp.float32:
-            zc = self._config.zero_config
-            if zc.gather_on_use:
+        self._param_gather_plan = None
+        zc = self._config.zero_config
+        if compute_dtype != jnp.float32:
+            if self.zero_optimization_stage() < 3 or not zc.gather_on_use:
+                caster = self._param_caster()
+                if self._sharded_masters():
+                    # None: no leaf has a dimension the axis divides
+                    self._param_gather_plan = getattr(
+                        caster, "plan", {"gather_leaves": 0,
+                                         "gather_bytes": 0})
+            else:
                 caster, plan = make_gather_on_use_caster(
                     self.params, param_shardings, self.mesh, compute_dtype,
                     chunks=int(zc.gather_chunks or 1),
@@ -1152,9 +1254,6 @@ class DeepSpeedEngine:
                 if caster is not None:
                     self._zero3_plan = plan
                     remat_policy = zero3_remat_policy()
-            else:
-                caster = make_param_caster(self.params, param_shardings,
-                                           self.mesh, compute_dtype)
         accumulate = make_grad_accumulator(loss_fn, compute_dtype, accum,
                                            constrain=grad_constrain,
                                            cast_params=caster,
@@ -2539,6 +2638,11 @@ class DeepSpeedEngine:
                  "batch_tokens": self._batch_tokens}
         if compile_seconds is not None:
             facts["compile_seconds"] = round(compile_seconds, 4)
+        if getattr(self, "_param_gather_plan", None) is not None:
+            # whether the tree took the 16-bit gather: leaves and bytes
+            # on the wire a step, and what stayed as it lay (no dimension
+            # the axis divides, a tuple sub-spec)
+            facts["param_gather"] = dict(self._param_gather_plan)
         if self._config.compilation_cache_dir:
             from deepspeed_tpu.telemetry import compile_cache
             cc = compile_cache.counts()
@@ -2905,13 +3009,11 @@ class DeepSpeedEngine:
     def eval_batch(self, batch):
         """Forward-only loss over a global batch (no grad, no state change)."""
         if self._compiled_eval_step is None:
-            compute_dtype = self.compute_dtype
             loss_fn = self.loss_fn
+            cast = self._cast_for_loss()
 
             def eval_step(params, batch):
-                cast = jax.tree_util.tree_map(
-                    lambda x: x.astype(compute_dtype), params)
-                return loss_fn(cast, batch, None)
+                return loss_fn(cast(params), batch, None)
 
             self._compiled_eval_step = jax.jit(eval_step)
         placed = self._place_rows(batch)
@@ -2952,14 +3054,12 @@ class DeepSpeedEngine:
             batch = self._pending_batch
         assert batch is not None, "call forward(batch) first or pass batch="
         if not hasattr(self, "_micro_grad_fn"):
-            compute_dtype = self.compute_dtype
             loss_fn = self.loss_fn
+            cast = self._cast_for_loss()
 
             def grad_fn(params, b, rng, scale):
                 def f(p):
-                    cast = jax.tree_util.tree_map(
-                        lambda x: x.astype(compute_dtype), p)
-                    loss = loss_fn(cast, b, rng)
+                    loss = loss_fn(cast(p), b, rng)
                     return loss * scale, loss
                 (_, loss), grads = jax.value_and_grad(f, has_aux=True)(params)
                 return loss, grads

@@ -266,8 +266,9 @@ def test_headerless_snippet_peak_is_flat():
 def test_peak_memory_orders_dense_above_zero_stages():
     """The ZeRO claim, statically: sharding optimizer state across the
     8-device data axis must lower the per-device static peak — dense >
-    ZeRO-1 >= ZeRO-2 > ZeRO-3 (stage 3 additionally shards the fp32
-    params and gathers on use) — and each estimate must sit inside the
+    ZeRO-1 >= ZeRO-2 >= ZeRO-3 (under 16-bit compute every stage keeps
+    the fp32 masters sharded; stage 3 gathers on use what stages 1 and 2
+    gather once a step) — and each estimate must sit inside the
     tolerance band of XLA's own buffer assignment (liveness is an upper
     bound; buffer reuse can only push the real number down)."""
     from deepspeed_tpu.analysis.audit import (
@@ -288,7 +289,7 @@ def test_peak_memory_orders_dense_above_zero_stages():
 
     assert peaks["dense"] > peaks["zero1"], peaks
     assert peaks["zero1"] >= peaks["zero2"], peaks
-    assert peaks["zero2"] > peaks["zero3"], peaks
+    assert peaks["zero2"] >= peaks["zero3"], peaks
     # dense-family ratios measure ~1.0 on CPU; keep a band wide enough
     # for backend drift but tight enough to catch a broken walk.
     for flavor, r in ratios.items():

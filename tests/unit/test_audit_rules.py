@@ -160,6 +160,45 @@ def test_f32_all_reduce_in_bf16_run_is_reported():
                      compute_dtype="f32").findings == []
 
 
+@pytest.mark.parametrize("stage", [1, 2])
+def test_f32_param_gather_at_zero12_is_the_upcast_it_now_is(stage):
+    """Stages 1 and 2 gather the 16-bit copy of sharded masters
+    (`zero/sharding.py:make_param_caster`), so a parameter-sized fp32
+    all-gather in a bf16 step is no longer the masters' refresh but a
+    silent upcast — except in the step kinds that keep replicated fp32
+    masters, and in a program the CPU backend compiled (it re-widens
+    the 16-bit gather)."""
+    from jax.sharding import Mesh, PartitionSpec as P
+    from jax import shard_map
+    mesh = Mesh(np.array(jax.devices()[:4]), ("d",))
+
+    def gather_of(dtype):
+        mapped = shard_map(
+            lambda x: jax.lax.all_gather(x, "d", axis=0, tiled=True),
+            mesh=mesh, in_specs=(P("d"),), out_specs=P(None),
+            check_vma=False)
+        return jax.jit(mapped).lower(
+            jnp.ones((512, 512), dtype)).compile().as_text()
+
+    M = 512 * 512 * 4
+    kw = dict(rules=["dtype_hygiene"], compute_dtype="bf16",
+              zero_stage=stage, param_bytes=M, n_devices=4)
+    report = audit_hlo(gather_of(jnp.float32), **kw)
+    assert [f.details["family"] for f in report.findings] == \
+        ["all-gather"], report.to_text()
+    assert report.findings[0].details["f32_bytes"] == M
+    # what stays allowed one parameter-sized fp32 gather
+    for allowed in ({"platform": "cpu"}, {"comm_quantized": True},
+                    {"pipeline": True}, {"flavor": "sparse"}):
+        assert audit_hlo(gather_of(jnp.float32),
+                         **kw, **allowed).findings == [], allowed
+    # and the 16-bit gather is clean under the strict budget (as a
+    # native-bf16 backend spells it; the CPU would re-widen it)
+    bf16 = gather_of(jnp.float32).replace("f32[", "bf16[")
+    assert "bf16[512,512]" in bf16 and "f32[" not in bf16
+    assert audit_hlo(bf16, **kw).findings == []
+
+
 def test_host_callback_in_step_is_reported():
     def on_host(x):
         return np.asarray(x) + 1.0

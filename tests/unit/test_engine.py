@@ -77,16 +77,41 @@ def test_zero_stages_match_baseline():
         np.testing.assert_allclose(ref, got, rtol=1e-4, err_msg=f"stage{stage}")
 
 
-def test_zero_opt_state_is_sharded():
-    cfg = base_config(bf16={"enabled": True},
-                      zero_optimization={"stage": 1})
+@pytest.mark.parametrize("stage", [1, 2])
+@pytest.mark.parametrize("precision", ["bf16", "fp16"])
+def test_zero_opt_state_is_sharded(stage, precision):
+    cfg = base_config(zero_optimization={"stage": stage},
+                      **{precision: {"enabled": True}})
     engine = make_engine(cfg)
     m_leaf = engine.opt_state.m["linear_0"]["kernel"]
     # 16x16 kernel over 8-way data axis → each shard holds 1/8 of rows or cols
     assert not m_leaf.sharding.is_fully_replicated
-    # params stay replicated at stage 1
+    # where the step gathers their 16-bit copy, the float32 masters lie
+    # as the moments do
     p_leaf = engine.params["linear_0"]["kernel"]
-    assert p_leaf.sharding.is_fully_replicated
+    assert p_leaf.dtype == jnp.float32
+    assert p_leaf.sharding == m_leaf.sharding
+    assert engine._sharded_masters()
+
+
+@pytest.mark.parametrize("stage", [1, 2])
+def test_zero_masters_stay_replicated_under_float32_compute(stage):
+    # The config refuses ZeRO without fp16/bf16 today; the layout rule
+    # still reads the compute dtype (the copy IS the master there, and a
+    # gather at either end of the step carries the same bytes), so it is
+    # asked directly: the same engine, computing in float32.
+    cfg = base_config(bf16={"enabled": True},
+                      zero_optimization={"stage": stage})
+    engine = make_engine(cfg)
+    engine.compute_dtype = jnp.float32
+    assert not engine._sharded_masters()
+    from deepspeed_tpu.runtime.zero.sharding import build_zero_shardings
+    specs = jax.tree_util.tree_map(
+        lambda _: jax.sharding.PartitionSpec(), engine.params)
+    sh = build_zero_shardings(engine.params, specs, engine.mesh, stage,
+                              sharded_masters=engine._sharded_masters())
+    assert sh["param"]["linear_0"]["kernel"].is_fully_replicated
+    assert not sh["opt"]["linear_0"]["kernel"].is_fully_replicated
 
 
 def test_zero3_params_sharded():

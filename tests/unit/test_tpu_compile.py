@@ -630,6 +630,71 @@ def test_engine_loss_over_data_mesh_compiles(topo, monkeypatch, program):
     assert "tpu_custom_call" in compiled_text(fn, params, batch)
 
 
+def test_zero2_step_gathers_the_bf16_copy_on_the_tpu(topo, monkeypatch):
+    """The ZeRO-1/2 layout of 16-bit compute as the TPU's partitioner
+    leaves it: the float32 masters sharded over `data=4`, the step's
+    loss differentiated through the cast-then-gather of the whole tree
+    (`zero/sharding.py:make_param_caster`), the gradient back in the
+    masters' layout. Every parameter-sized all-gather of the compiled
+    program is bf16 — none is re-widened to float32, as the CPU backend
+    re-widens them — and the flash kernels are still in it. One layer
+    of GPT-2 at XL's width (25 heads), as `train-gpt2-xl-zero-4chip`
+    runs 48 of."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from deepspeed_tpu.analysis.hlo import collective_ops
+    from deepspeed_tpu.models.gpt2 import (
+        GPT2Config, GPT2LMHead, make_gpt2_loss_fn)
+    from deepspeed_tpu.parallel.mesh import build_mesh
+    from deepspeed_tpu.runtime.engine import (
+        make_grad_accumulator, place_kernels_on_mesh)
+    from deepspeed_tpu.runtime.zero.sharding import (
+        build_zero_shardings, constrain_tree, make_param_caster)
+
+    _compiled_not_interpreted(monkeypatch,
+                              "deepspeed_tpu.ops.pallas.flash_attention")
+    mesh = build_mesh({"data": 4}, devices=topo.devices)
+    model = GPT2LMHead(GPT2Config(
+        vocab_size=50257, n_positions=T, n_embd=1600, n_layer=1,
+        n_head=25, dtype=jnp.bfloat16, use_flash_attention=True))
+    loss_fn = place_kernels_on_mesh(make_gpt2_loss_fn(model), mesh)
+    shapes = jax.eval_shape(
+        lambda: model.init({"params": jax.random.PRNGKey(0)},
+                           jnp.zeros((2, T), jnp.int32))["params"])
+    sh = build_zero_shardings(
+        shapes, jax.tree_util.tree_map(lambda _: P(), shapes), mesh, 2,
+        sharded_masters=True)
+    cast = make_param_caster(shapes, sh["param"], mesh, jnp.bfloat16)
+    assert cast.plan["replicated_leaves"] == 0
+    accumulate = make_grad_accumulator(
+        loss_fn, jnp.bfloat16, 1, cast_params=cast,
+        constrain=lambda g: constrain_tree(g, sh["grad"]))
+
+    def step(params, batch, rng):
+        loss, grads, _ = accumulate(params, batch, rng,
+                                    jnp.asarray(1.0, jnp.float32))
+        return loss, constrain_tree(grads, sh["grad"])
+
+    params = jax.tree_util.tree_map(
+        lambda s, h: jax.ShapeDtypeStruct(s.shape, jnp.float32, sharding=h),
+        shapes, sh["param"])
+    batch = {"input_ids": jax.ShapeDtypeStruct(
+        (1, B, T), jnp.int32, sharding=NamedSharding(mesh, P(None, "data")))}
+    rng = jax.ShapeDtypeStruct((2,), jnp.uint32,
+                               sharding=NamedSharding(mesh, P()))
+    text = compiled_text(step, params, batch, rng)
+    assert "tpu_custom_call" in text
+    gathered = {}
+    for op in collective_ops(text):
+        if op["op"] == "all-gather":
+            for dtype, nbytes in op["dtype_bytes"].items():
+                gathered[dtype] = max(gathered.get(dtype, 0), nbytes)
+    # the largest leaf, the embedding, rides as bf16; no float32 gather
+    # is the size of even the smallest sharded leaf (a 1600-wide bias)
+    assert gathered.get("bf16", 0) >= 50257 * 1600 * 2, gathered
+    assert gathered.get("f32", 0) < 1600 * 4, gathered
+
+
 @pytest.mark.parametrize("program", ["eval_batch", "train_step"])
 def test_olmoe_loss_over_data_mesh_compiles(topo, monkeypatch, program):
     """The same for OLMoE: besides the flash kernels its step holds the

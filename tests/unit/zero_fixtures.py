@@ -40,11 +40,13 @@ def loss_fn(params, batch, rng=None):
     return jnp.mean(jnp.square(x - batch["y"]))
 
 
-def build_engine(stage, accum=1):
+def build_engine(stage, accum=1, precision=None, **extra):
+    """``precision``: the config's precision block, bf16 when None (e.g.
+    ``{"fp16": {"enabled": True, "initial_scale_power": 20}}``)."""
     cfg = base_config(train_batch_size=16 * accum,
                       gradient_accumulation_steps=accum,
-                      bf16={"enabled": True},
-                      zero_optimization={"stage": stage})
+                      zero_optimization={"stage": stage},
+                      **(precision or {"bf16": {"enabled": True}}), **extra)
     params = init_params(jax.random.PRNGKey(0))
     engine, _, _, _ = deepspeed_tpu.initialize(
         config=cfg, loss_fn=loss_fn, params=params)
