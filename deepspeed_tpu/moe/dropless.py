@@ -23,7 +23,9 @@ said how batch rows lie on its mesh
 routing its own tokens through its copy of the experts, the counters
 and the losses' sums added up over the chips.
 
-**Routing is a function** (`softmax_top_k`, OLMoE's; `sigmoid_top_k`,
+**Routing is a function** (`softmax_top_k`, OLMoE's;
+`softmax_top_k_renorm`, Qwen3-MoE's: the chosen probabilities over
+their sum; `sigmoid_top_k`,
 DeepSeek-V3's and Kimi-K2's: sigmoid scores, a bias that moves the
 choice and no weight, the chosen scores renormalised and scaled), and
 **an expert layer can hold a share** (ISSUE 34): given ``first_expert``
@@ -153,6 +155,18 @@ def softmax_top_k(x, router, top_k):
     weights, experts = jax.lax.top_k(probs, top_k)      # [N, k]
     return weights, experts, {"prob_sum": probs.sum(0),
                               "z_sum": jnp.sum(lse * lse)}
+
+
+def softmax_top_k_renorm(x, router, top_k):
+    """Qwen3-MoE's routing (``norm_topk_prob``): ``p = softmax(x
+    router)`` over all experts, the ``top_k`` most probable, their
+    probabilities over their sum, so a token's weights add up to 1
+    over all its chosen experts, held here or not. All float32. A
+    function beside `softmax_top_k` and not a flag inside it: OLMoE's
+    training step runs that one bit for bit."""
+    probs = jax.nn.softmax(router_logits(x, router), axis=-1)
+    weights, experts = jax.lax.top_k(probs, top_k)      # [N, k]
+    return weights / weights.sum(-1, keepdims=True), experts, {}
 
 
 def sigmoid_top_k(bias, scaling, renormalise=True):

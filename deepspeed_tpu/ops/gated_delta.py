@@ -1,0 +1,136 @@
+"""Gated delta rule (Gated DeltaNet) sequence ops for the serving path:
+the chunked form a prefill call runs and the one-step update a decode
+step runs. The short causal convolution before them is `ops/ssm.py`'s.
+
+The recurrence, per value head (``k_t``, ``q_t`` the head's ``K``-wide
+key and query, ``v_t`` its ``V``-wide value, ``g_t <= 0`` and ``beta_t``
+in (0, 1) one scalar each a head and token)::
+
+    S   <- exp(g_t) S                       S: [K, V]
+    d_t  = beta_t (v_t - S^T k_t)           the delta: what S lacks of v_t
+    S   <- S + k_t (x) d_t
+    o_t  = S^T q_t
+
+Mamba-2's update (`ops/ssm.py`) writes ``x (x) B`` whatever the state
+holds; here **the write depends on a read of the state through the
+key**, so one step is a matrix-vector product before the outer product,
+and a chunk of tokens cannot be folded into one masked product: the
+deltas of a chunk depend on each other.
+
+**Prefill** (:func:`gated_delta_chunked`; Yang, Kautz & Hatamizadeh
+2024, arXiv:2412.06464, section 3.3, the WY form): the sequence is cut
+into chunks of ``chunk`` tokens. With ``G_i`` the running sum of ``g``
+inside a chunk, the deltas solve a unit lower-triangular system::
+
+    (I - A) D = beta (v - e^G k S_in),   A_ij = -beta_i (k_i . k_j) e^(G_i - G_j)  (j < i)
+
+by forward substitution (`jax.lax.linalg.triangular_solve`; exact, no
+series), once for both right-hand sides: ``U = (I - A)^-1 (beta v)``
+and ``W = (I - A)^-1 (beta k e^G)``, so a chunk's deltas are ``U - W
+S_in``. Its output is ``(q e^G) S_in`` plus the causal ``(q k^T e^(G_i
+- G_j))`` times the deltas, and ``S_out = e^(G_C) S_in + (k e^(G_C -
+G))^T`` times them. The state passes from chunk to chunk under
+``lax.scan`` and from call to call in float32. **Decode**
+(:func:`gated_delta_step`) is the recurrence itself, one step for
+every row.
+
+Precision, fixed by the configuration (`models/qwen3_next.py`): ``g``,
+``beta``, every decay, the system, its solution and the state are
+float32 whatever the activations are; the products of activations
+(``k k^T``, ``q k^T``) take their operands in the compute dtype and
+accumulate in float32; every product that reads or writes the state
+runs on float32 operands at the highest precision.
+
+Padding: a token with ``g`` 0 and ``beta`` 0 decays nothing and writes
+nothing (its delta is 0 whatever ``k`` and ``v`` are), so the caller
+masks a ragged tail by zeroing both; a dead decode row keeps its state
+bit for bit (``live``).
+
+Both are plain XLA; the mixer (`models/qwen3_next.py`) calls them under
+the scopes ``ds_gdn_scan`` and ``ds_gdn_step``.
+"""
+
+import jax
+import jax.numpy as jnp
+
+_F32 = jnp.float32
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def gated_delta_chunked(q, k, v, g, beta, state, chunk):
+    """One sequence through the recurrence in chunks.
+
+    ``q``, ``k`` ``[T, H, K]`` (compute dtype; normalised and scaled by
+    the caller, one a value head), ``v`` ``[T, H, V]``, ``g`` ``[T, H]``
+    float32 (<= 0; 0 on padding), ``beta`` ``[T, H]`` float32 (0 on
+    padding), ``state`` ``[H, K, V]`` float32 (the state before the
+    first token). ``T`` is a multiple of ``chunk``. Returns ``(o [T, H,
+    V] float32, state after the last token)``.
+    """
+    T, H, _ = q.shape
+    V = v.shape[-1]
+    Q = min(int(chunk), T)
+    if T % Q:
+        raise ValueError(f"sequence {T} is not a multiple of the delta "
+                         f"rule's chunk {Q}")
+    c = T // Q
+    # chunk-major, heads before tokens: [c, H, Q, .]
+    def heads_first(a):
+        return jnp.moveaxis(a.reshape(c, Q, H, *a.shape[2:]), 2, 1)
+    qc, kc, vc = heads_first(q), heads_first(k), heads_first(v)
+    gc, bc = heads_first(g), heads_first(beta)          # [c, H, Q]
+    G = jnp.cumsum(gc, axis=-1)                         # <= 0
+    seg = G[..., :, None] - G[..., None, :]             # G_i - G_j
+    lower = jnp.tril(jnp.ones((Q, Q), bool))
+    decay = jnp.exp(jnp.where(lower, seg, -jnp.inf))    # 0 above
+    strict = jnp.tril(jnp.ones((Q, Q), bool), -1)
+
+    # the system: (I - A) [U | W] = beta [v | k e^G]
+    kk = jnp.einsum("chik,chjk->chij", kc, kc,
+                    preferred_element_type=_F32)
+    system = jnp.where(strict, bc[..., None] * kk * decay, 0.0) + \
+        jnp.eye(Q, dtype=_F32)                          # I - A
+    rhs = jnp.concatenate(
+        [vc.astype(_F32),
+         kc.astype(_F32) * jnp.exp(G)[..., None]], axis=-1) * \
+        bc[..., None]
+    solved = jax.lax.linalg.triangular_solve(
+        system, rhs, left_side=True, lower=True, unit_diagonal=True)
+    U, W = solved[..., :V], solved[..., V:]
+
+    # what a chunk reads of the state, and what it leaves to it
+    qk = jnp.einsum("chik,chjk->chij", qc, kc,
+                    preferred_element_type=_F32) * decay
+    q_in = qc.astype(_F32) * jnp.exp(G)[..., None]      # q e^G
+    k_out = kc.astype(_F32) * jnp.exp(G[..., -1:] - G)[..., None]
+    whole = jnp.exp(G[..., -1])                         # [c, H]
+
+    def one(S, xs):
+        U_i, W_i, qk_i, q_i, k_i, whole_i = xs
+        delta = U_i - jnp.einsum("hik,hkv->hiv", W_i, S,
+                                 precision=_HIGHEST)
+        o = jnp.einsum("hik,hkv->hiv", q_i, S, precision=_HIGHEST) + \
+            jnp.einsum("hij,hjv->hiv", qk_i, delta, precision=_HIGHEST)
+        S = whole_i[:, None, None] * S + jnp.einsum(
+            "hik,hiv->hkv", k_i, delta, precision=_HIGHEST)
+        return S, o
+
+    # o: [c, H, Q, V]
+    state, o = jax.lax.scan(one, state, (U, W, qk, q_in, k_out, whole))
+    o = jnp.moveaxis(o, 1, 2).reshape(T, H, V)
+    return o, state
+
+
+def gated_delta_step(q, k, v, g, beta, state, live):
+    """One step of every row. ``q``, ``k`` ``[R, H, K]``, ``v`` ``[R, H,
+    V]``, ``g``, ``beta`` ``[R, H]`` float32, ``state`` ``[R, H, K, V]``
+    float32, ``live`` ``[R]`` bool. Returns ``(o [R, H, V] float32, new
+    state)``; a row that is not live keeps its state."""
+    k32, q32 = k.astype(_F32), q.astype(_F32)
+    decayed = jnp.exp(g)[..., None, None] * state
+    read = jnp.sum(decayed * k32[..., None], axis=-2)   # S^T k
+    delta = beta[..., None] * (v.astype(_F32) - read)
+    new = decayed + k32[..., None] * delta[..., None, :]
+    o = jnp.sum(new * q32[..., None], axis=-2)          # S^T q
+    state = jnp.where(live[:, None, None, None], new, state)
+    return o, state

@@ -1,0 +1,114 @@
+"""`test_tpu_compile.py` for Qwen3-Next (ISSUE 43): the decode kernel at
+the cell's attention geometry (head size 256, 8 query heads to each of
+2 key heads) and both serving programs of the share at the published
+widths, compiled (not interpreted) for a described ``v5e:2x2`` chip. A
+file of its own, as `test_tpu_compile_nemotron_h.py` is; the fixtures
+and helpers are `test_tpu_compile.py`'s."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from tests.unit.test_tpu_compile import (       # noqa: F401 (fixtures)
+    PAGE, _compiled_not_interpreted, chip, decode_call, kernel_grids, topo)
+
+# the cell's engine: 128 rows, a bucket of 5,120 (40 pages), a pool of
+# 4,096 pages and the trash page, 2 key heads of 256
+ROWS, BUCKET, PAGES, CHUNK = 128, 5120, 4097, 1024
+
+
+def test_decode_geometry_at_head_size_256():
+    """Twice the VMEM a block of a head that any accepted cell asks for,
+    far inside the budget: 2 heads x 256 x 128 positions, two slots each
+    of keys and values."""
+    from deepspeed_tpu.ops.pallas import flash_decode as fd
+
+    assert fd.check_decode_geometry(PAGE, PAGE, jnp.bfloat16, 2, 256,
+                                    False) == PAGE
+    need = fd.paged_vmem_bytes(2, 256, PAGE, jnp.bfloat16, False)
+    assert need == 2 * fd.paged_vmem_bytes(2, 128, PAGE, jnp.bfloat16, False)
+    assert need < fd.PAGED_VMEM_BUDGET // 8
+    with pytest.raises(fd.KernelGeometryError):
+        fd.check_decode_geometry(4096, 4096, jnp.float32, 2, 256, False)
+
+
+def test_two_key_heads_of_eight_queries_at_256_decode_compiles(chip):
+    """The decode kernel with 8 query heads to each of 2 key heads of
+    256, 128 rows over 40 pages a row: one grid step a row, nothing
+    pool-shaped copied."""
+    from deepspeed_tpu.analysis.hlo import payload_shaped_copies
+
+    fn, args = decode_call(chip, ROWS, 2, 256, "bfloat16", BUCKET // PAGE,
+                           group=8)
+    lowered = jax.jit(fn, donate_argnums=0).lower(*args)
+    assert kernel_grids(lowered.as_text()) == [(ROWS,)]
+    text = lowered.compile().as_text()
+    assert "ds_flash_decode_paged" in text
+    assert payload_shaped_copies(text, args[0]["k"].shape) == []
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_qwen3_next_serving_programs_compile(chip, monkeypatch, program):
+    """Both programs of the share at its published widths (one period:
+    three Gated DeltaNet blocks and an attention block, each with its
+    expert layer), cache donated, as the engine calls them: a prefill
+    chunk of 1024 (sixteen chunks of the delta rule) in a slot and a
+    decode step of 128 rows. Three grouped matmuls an expert layer;
+    the state and the pool are
+    updated where they lie; every scope the benchmark's metrics read is
+    in the compiled text."""
+    from deepspeed_tpu.analysis.hlo import payload_shaped_copies
+    from deepspeed_tpu.inference.cache import init_kv_cache
+    from deepspeed_tpu.models import qwen3_next as qn
+
+    for name in ("deepspeed_tpu.ops.pallas.flash_decode",
+                 "deepspeed_tpu.moe.dropless"):
+        _compiled_not_interpreted(monkeypatch, name)
+    cfg = qn.qwen3_next_80b_share(n_layer=4)
+    model = qn.Qwen3NextLM(cfg)
+    spec = cfg.cache_spec(ROWS, BUCKET, page_size=PAGE, n_pages=PAGES)
+    abstract = lambda tree: jax.tree_util.tree_map(     # noqa: E731
+        lambda a: chip(a.shape, a.dtype), tree)
+    params = abstract(jax.eval_shape(
+        lambda k: qn.init_qwen3_next_params(model, k),
+        jax.random.PRNGKey(0)))
+    cache = abstract(jax.eval_shape(lambda: init_kv_cache(spec)))
+    i32 = lambda *shape: chip(shape, jnp.int32)         # noqa: E731
+    per_row = BUCKET // PAGE
+
+    if program == "prefill":
+        def fn(params, cache, tokens, positions, table, slots, n_valid):
+            return model.serve_apply(params, cache, tokens, positions,
+                                     table, slots, n_valid)
+        args = (i32(1, CHUNK), i32(1, CHUNK), i32(1, per_row), i32(1),
+                i32(1))
+    else:
+        def fn(params, cache, tokens, positions, tables):
+            live = (tables[:, 0] != 0).astype(jnp.int32)
+            return model.serve_apply(
+                params, cache, tokens[:, None], positions[:, None], tables,
+                jnp.arange(ROWS, dtype=jnp.int32), live,
+                attn_impl="flash", attn_block_k=PAGE)
+        args = (i32(ROWS), i32(ROWS), i32(ROWS, per_row))
+    compiled = jax.jit(fn, donate_argnums=1).lower(
+        params, cache, *args).compile()
+    text = compiled.as_text()
+    # three grouped matmuls (gate, up, down) in each of the four blocks,
+    # and in decode the attention block's kernel
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == \
+        {"prefill": 12, "decode": 13}[program]
+    for scope in ("ds_gdn_conv", "ds_attn_gate", "ds_moe_route",
+                  "ds_moe_dispatch", "ds_moe_experts", "ds_moe_combine",
+                  "ds_moe_shared",
+                  "ds_gdn_scan" if program == "prefill" else "ds_gdn_step"):
+        assert scope in text, scope
+    assert ("ds_flash_decode_paged" in text) == (program == "decode")
+    assert payload_shaped_copies(text, (ROWS, 32, 128, 128)) == []
+    assert payload_shaped_copies(text, (PAGES, 2, 256, PAGE)) == []
+    # every cache leaf goes out where it came in
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree_util.tree_leaves(cache))
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == cache_bytes
+    # beside the weights and the cache a call holds under 2 GB
+    assert memory.temp_size_in_bytes < 2e9, memory.temp_size_in_bytes
