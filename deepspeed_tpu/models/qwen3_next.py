@@ -341,14 +341,15 @@ class GatedDeltaNet(nn.Module):
         g = -A * jax.nn.softplus(ba[..., Hv:] + dt_bias.astype(jnp.float32))
         state, window = leaves["gdn"], leaves["conv"]
 
-        def heads(u):
+        def heads(u, repeat):
             """The convolved channels ``u`` ``[rows, conv_dim]`` as the
-            recurrence takes them: ``q``, ``k`` ``[rows, Hv, K]`` (unit
-            length, ``q`` scaled, a key head repeated for its value
-            heads), ``v`` ``[rows, Hv, V]``."""
+            recurrence takes them: ``q``, ``k`` ``[rows, Hk, K]`` (unit
+            length, ``q`` scaled; under ``repeat`` a key head repeated
+            for its value heads, as the step takes them), ``v``
+            ``[rows, Hv, V]``."""
             q = _l2_normalised(u[:, :d_k].reshape(-1, Hk, K)) * K ** -0.5
             k = _l2_normalised(u[:, d_k:2 * d_k].reshape(-1, Hk, K))
-            q, k = (jnp.repeat(a.astype(cfg.dtype), Hv // Hk, axis=1)
+            q, k = (jnp.repeat(a.astype(cfg.dtype), repeat, axis=1)
                     for a in (q, k))
             return q, k, u[:, 2 * d_k:].reshape(-1, Hv, V)
 
@@ -360,7 +361,7 @@ class GatedDeltaNet(nn.Module):
                 u = jax.nn.silu(u).astype(cfg.dtype)
             with jax.named_scope("ds_gdn_step"):
                 o, state = gated_delta.gated_delta_step(
-                    *heads(u), g[:, 0], beta[:, 0], state, live)
+                    *heads(u, Hv // Hk), g[:, 0], beta[:, 0], state, live)
             o = o[:, None]                              # [B, 1, Hv, V]
         elif B == 1:
             slot, n = slots[0], n_valid[0]
@@ -380,7 +381,7 @@ class GatedDeltaNet(nn.Module):
                 # nothing
                 real = jnp.arange(T)[:, None] < n
                 o, s1 = gated_delta.gated_delta_chunked(
-                    *heads(u), jnp.where(real, g[0], 0.0),
+                    *heads(u, 1), jnp.where(real, g[0], 0.0),
                     jnp.where(real, beta[0], 0.0), s0,
                     cfg.delta_chunk_size)
                 state = jax.lax.dynamic_update_index_in_dim(

@@ -24,13 +24,14 @@ inside a chunk, the deltas solve a unit lower-triangular system::
 
     (I - A) D = beta (v - e^G k S_in),   A_ij = -beta_i (k_i . k_j) e^(G_i - G_j)  (j < i)
 
-by forward substitution (`jax.lax.linalg.triangular_solve`; exact, no
-series), once for both right-hand sides: ``U = (I - A)^-1 (beta v)``
+by forward substitution (exact, no series: a product of ``A``'s powers
+cancels where a chunk's keys are alike), once for both right-hand
+sides: ``U = (I - A)^-1 (beta v)``
 and ``W = (I - A)^-1 (beta k e^G)``, so a chunk's deltas are ``U - W
 S_in``. Its output is ``(q e^G) S_in`` plus the causal ``(q k^T e^(G_i
 - G_j))`` times the deltas, and ``S_out = e^(G_C) S_in + (k e^(G_C -
-G))^T`` times them. The state passes from chunk to chunk under
-``lax.scan`` and from call to call in float32. **Decode**
+G))^T`` times them. The state passes from chunk to chunk and from call
+to call in float32. **Decode**
 (:func:`gated_delta_step`) is the recurrence itself, one step for
 every row.
 
@@ -46,34 +47,60 @@ nothing (its delta is 0 whatever ``k`` and ``v`` are), so the caller
 masks a ragged tail by zeroing both; a dead decode row keeps its state
 bit for bit (``live``).
 
-Both are plain XLA; the mixer (`models/qwen3_next.py`) calls them under
-the scopes ``ds_gdn_scan`` and ``ds_gdn_step``.
+Which form runs where: :func:`gated_delta_chunked`, the name the mixer
+(`models/qwen3_next.py`) calls under the scope ``ds_gdn_scan``, is one
+Pallas kernel call (`ops/pallas/gated_delta.py`: a grid step takes two
+chunks of a key head, a chunk's ``decay``, ``k k^T``, ``q k^T`` and
+system live and die in VMEM, the substitution is blocked at 16 rows,
+the heads' state stays in VMEM from the call's first chunk to its
+last), compiled on a TPU and in Pallas interpret mode elsewhere; one
+path, whatever the shapes. :func:`gated_delta_chunked_plain` is the same
+algebra in plain XLA (`jax.lax.linalg.triangular_solve`, the chunks
+under ``lax.scan``): the form the tests hold the kernel to, run by no
+program (on the chip 2.14 ms a layer a 1,024-token call against the
+kernel's 0.67, two thirds of it XLA's ``InvertDiagBlocksLowerTriangular``;
+`PERF.md` section 6, PR 44). The step (``ds_gdn_step``) is plain XLA.
 """
 
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.ops.pallas import gated_delta as _kernel
+
 _F32 = jnp.float32
 _HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _chunk_of(T, chunk):
+    Q = min(int(chunk), T)
+    if T % Q:
+        raise ValueError(f"sequence {T} is not a multiple of the delta "
+                         f"rule's chunk {Q}")
+    return Q
 
 
 def gated_delta_chunked(q, k, v, g, beta, state, chunk):
     """One sequence through the recurrence in chunks.
 
-    ``q``, ``k`` ``[T, H, K]`` (compute dtype; normalised and scaled by
-    the caller, one a value head), ``v`` ``[T, H, V]``, ``g`` ``[T, H]``
-    float32 (<= 0; 0 on padding), ``beta`` ``[T, H]`` float32 (0 on
-    padding), ``state`` ``[H, K, V]`` float32 (the state before the
-    first token). ``T`` is a multiple of ``chunk``. Returns ``(o [T, H,
-    V] float32, state after the last token)``.
+    ``q``, ``k`` ``[T, Hk, K]`` (compute dtype; normalised and scaled by
+    the caller; a key head serves ``H / Hk`` consecutive value heads),
+    ``v`` ``[T, H, V]``, ``g`` ``[T, H]`` float32 (<= 0; 0 on padding),
+    ``beta`` ``[T, H]`` float32 (0 on padding), ``state`` ``[H, K, V]``
+    float32 (the state before the first token). ``T`` is a multiple of
+    ``chunk``. Returns ``(o [T, H, V] float32, state after the last
+    token)``.
     """
-    T, H, _ = q.shape
+    return _kernel.gated_delta_chunked(q, k, v, g, beta, state,
+                                       _chunk_of(q.shape[0], chunk))
+
+
+def gated_delta_chunked_plain(q, k, v, g, beta, state, chunk):
+    """:func:`gated_delta_chunked` in plain XLA."""
+    T, H = v.shape[:2]
     V = v.shape[-1]
-    Q = min(int(chunk), T)
-    if T % Q:
-        raise ValueError(f"sequence {T} is not a multiple of the delta "
-                         f"rule's chunk {Q}")
+    Q = _chunk_of(T, chunk)
     c = T // Q
+    q, k = (jnp.repeat(a, H // a.shape[1], axis=1) for a in (q, k))
     # chunk-major, heads before tokens: [c, H, Q, .]
     def heads_first(a):
         return jnp.moveaxis(a.reshape(c, Q, H, *a.shape[2:]), 2, 1)

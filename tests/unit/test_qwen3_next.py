@@ -28,6 +28,7 @@ from deepspeed_tpu.models import qwen3_next as qn
 from deepspeed_tpu.moe.dropless import (dropless_moe, softmax_top_k,
                                         softmax_top_k_renorm)
 from deepspeed_tpu.ops import gated_delta
+from tests.unit.test_gated_delta_kernel import recurrence as _recurrence
 
 CHUNK, PAGE, SEQ, ROWS = 16, 8, 64, 3
 INF = {"max_batch": ROWS, "seq_buckets": (SEQ,), "prefill_chunk": CHUNK,
@@ -270,19 +271,6 @@ def test_rows_in_any_order_do_not_disturb_each_other(tiny):
 
 # --- the delta rule ----------------------------------------------------------
 
-def _recurrence(q, k, v, g, beta, state):
-    """Token by token, float64 numpy."""
-    q, k, v, g, beta, S = (np.asarray(a, np.float64)
-                           for a in (q, k, v, g, beta, state))
-    out = []
-    for t in range(len(q)):
-        S = np.exp(g[t])[:, None, None] * S
-        d = beta[t][:, None] * (v[t] - np.einsum("hkv,hk->hv", S, k[t]))
-        S = S + k[t][:, :, None] * d[:, None, :]
-        out.append(np.einsum("hkv,hk->hv", S, q[t]))
-    return np.stack(out), S
-
-
 def _delta_case(seed, T=32, H=4, K=8, V=6):
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
     unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
@@ -294,29 +282,38 @@ def _delta_case(seed, T=32, H=4, K=8, V=6):
     return q, k, v, g, beta, jax.random.normal(ks[5], (H, K, V))
 
 
+# the entry point the mixer calls (one kernel call since PR 44; Pallas
+# interpret mode here) and the same algebra in plain XLA
+FORMS = {"entry": lambda *a: gated_delta.gated_delta_chunked(*a),
+         "plain": lambda *a: gated_delta.gated_delta_chunked_plain(*a)}
+
+
+@pytest.mark.parametrize("form", sorted(FORMS))
 @pytest.mark.parametrize("chunk", [4, 8, 32])
-def test_chunked_delta_rule_against_the_recurrence(chunk):
+def test_chunked_delta_rule_against_the_recurrence(chunk, form):
     q, k, v, g, beta, s0 = _delta_case(chunk)
     want_o, want_s = _recurrence(q, k, v, g, beta, s0)
-    o, s1 = gated_delta.gated_delta_chunked(q, k, v, g, beta, s0, chunk)
+    o, s1 = FORMS[form](q, k, v, g, beta, s0, chunk)
     np.testing.assert_allclose(o, want_o, atol=2e-5)
     np.testing.assert_allclose(s1, want_s, atol=2e-5)
 
 
+@pytest.mark.parametrize("form", sorted(FORMS))
 @pytest.mark.parametrize("n", [1, 5, 8, 13, 31])
-def test_chunked_delta_rule_ragged_and_across_calls(n):
+def test_chunked_delta_rule_ragged_and_across_calls(n, form):
     """``n`` real tokens of 32, the tail's ``g`` and ``beta`` zeroed:
     the state is the recurrence's after ``n``; a second call that starts
     from it gives what one call over both gives."""
     q, k, v, g, beta, s0 = _delta_case(7)
     real = (jnp.arange(32) < n)[:, None]
-    o, s1 = gated_delta.gated_delta_chunked(
+    chunked = FORMS[form]
+    o, s1 = chunked(
         q, k, v, jnp.where(real, g, 0.0), jnp.where(real, beta, 0.0), s0, 8)
     want_o, want_s = _recurrence(q[:n], k[:n], v[:n], g[:n], beta[:n], s0)
     np.testing.assert_allclose(o[:n], want_o, atol=2e-5)
     np.testing.assert_allclose(s1, want_s, atol=2e-5)
     q2, k2, v2, g2, beta2, _ = _delta_case(8)
-    o2, s2 = gated_delta.gated_delta_chunked(q2, k2, v2, g2, beta2, s1, 8)
+    o2, s2 = chunked(q2, k2, v2, g2, beta2, s1, 8)
     cat = lambda a, b: np.concatenate([np.asarray(a)[:n], np.asarray(b)])
     both_o, both_s = _recurrence(cat(q, q2), cat(k, k2), cat(v, v2),
                                  cat(g, g2), cat(beta, beta2), s0)
@@ -342,12 +339,13 @@ def test_delta_step_against_the_recurrence_with_a_dead_row():
                                           np.asarray(state[i]))
 
 
-def test_beta_and_the_decay_are_seen():
+@pytest.mark.parametrize("form", sorted(FORMS))
+def test_beta_and_the_decay_are_seen(form):
     """``beta`` 1 everywhere or ``g`` 0 everywhere is another result."""
     q, k, v, g, beta, s0 = _delta_case(11)
-    o, _ = gated_delta.gated_delta_chunked(q, k, v, g, beta, s0, 8)
+    o, _ = FORMS[form](q, k, v, g, beta, s0, 8)
     for g_, b_ in ((g, jnp.ones_like(beta)), (jnp.zeros_like(g), beta)):
-        other, _ = gated_delta.gated_delta_chunked(q, k, v, g_, b_, s0, 8)
+        other, _ = FORMS[form](q, k, v, g_, b_, s0, 8)
         assert np.abs(other - o).max() > 0.05 * np.abs(o).max()
 
 
@@ -468,12 +466,14 @@ def test_expert_layer_with_dead_tokens_against_a_loop():
 # --- the programs' text --------------------------------------------------------
 
 # sha1 of the lowered (StableHLO) text of this model's two tiny
-# programs as PR 43 left them (the twin of `tests/unit/test_nemotron_h.
-# py::test_accepted_tiny_programs_lower_to_the_text_they_lowered_to`,
-# whose digests pin granite's, Kimi's and OLMoE's). A later PR that
-# changes one on purpose takes the new hash from this test's message.
+# programs as PR 43 left them, the prefill program's as PR 44 did (its
+# delta rule became one kernel call, in interpret mode here); the twin
+# of `tests/unit/test_nemotron_h.py::
+# test_accepted_tiny_programs_lower_to_the_text_they_lowered_to`, whose
+# digests pin granite's, Kimi's and OLMoE's. A later PR that changes
+# one on purpose takes the new hash from this test's message.
 LOWERED = {
-    "qwen3_next.prefill": "db8746338d481bd9c7cb9bed201d62e79c10adbe",
+    "qwen3_next.prefill": "b6a21bed42840a0ece664a963d47ad47c1b88a06",
     "qwen3_next.decode": "7c79557a6a1f867c131ae44295253c0cd38c4b46",
 }
 

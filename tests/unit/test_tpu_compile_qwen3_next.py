@@ -1,6 +1,7 @@
 """`test_tpu_compile.py` for Qwen3-Next (ISSUE 43): the decode kernel at
 the cell's attention geometry (head size 256, 8 query heads to each of
-2 key heads) and both serving programs of the share at the published
+2 key heads), the chunked delta rule's kernel at the cell's widths
+(ISSUE 44) and both serving programs of the share at the published
 widths, compiled (not interpreted) for a described ``v5e:2x2`` chip. A
 file of its own, as `test_tpu_compile_nemotron_h.py` is; the fixtures
 and helpers are `test_tpu_compile.py`'s."""
@@ -47,14 +48,46 @@ def test_two_key_heads_of_eight_queries_at_256_decode_compiles(chip):
     assert payload_shaped_copies(text, args[0]["k"].shape) == []
 
 
+def test_the_chunked_delta_rule_kernel_compiles(chip, monkeypatch):
+    """One prefill call's delta rule at the cell's widths: 1,024 tokens,
+    16 key heads of 128 serving 32 value heads of 128, chunks of 64. One
+    Mosaic kernel whose grid is a key head by two chunks a step; the
+    unrepeated ``[T, Hk, K]`` queries and keys go in as they lie (no
+    copy or transpose of an operand), and nothing of XLA's triangular
+    solve is left."""
+    from deepspeed_tpu.ops import gated_delta
+    from deepspeed_tpu.ops.pallas.gated_delta import GATED_DELTA_NAME
+
+    _compiled_not_interpreted(monkeypatch,
+                              "deepspeed_tpu.ops.pallas.gated_delta")
+    T, Hk, Hv, K, V, Q = CHUNK, 16, 32, 128, 128, 64
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    args = (chip((T, Hk, K), bf16), chip((T, Hk, K), bf16),
+            chip((T, Hv, V), bf16), chip((T, Hv), f32), chip((T, Hv), f32),
+            chip((Hv, K, V), f32))
+    lowered = jax.jit(
+        lambda *a: gated_delta.gated_delta_chunked(*a, Q)).lower(*args)
+    assert kernel_grids(lowered.as_text()) == [(Hk, T // Q // 2)]
+    text = lowered.compile().as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+    assert GATED_DELTA_NAME in text
+    assert "riangular" not in text
+    big = [line for line in text.splitlines()
+           if (" copy(" in line or " transpose(" in line)
+           and ("[%d,%d]" % (T, Hk * K) in line
+                or "[%d,%d]" % (T, Hv * V) in line)]
+    assert big == []
+
+
 @pytest.mark.parametrize("program", ["prefill", "decode"])
 def test_qwen3_next_serving_programs_compile(chip, monkeypatch, program):
     """Both programs of the share at its published widths (one period:
     three Gated DeltaNet blocks and an attention block, each with its
     expert layer), cache donated, as the engine calls them: a prefill
     chunk of 1024 (sixteen chunks of the delta rule) in a slot and a
-    decode step of 128 rows. Three grouped matmuls an expert layer;
-    the state and the pool are
+    decode step of 128 rows. Three grouped matmuls an expert layer
+    and, in prefill, one delta-rule kernel a Gated DeltaNet block (no
+    triangular solve of XLA's); the state and the pool are
     updated where they lie; every scope the benchmark's metrics read is
     in the compiled text."""
     from deepspeed_tpu.analysis.hlo import payload_shaped_copies
@@ -62,6 +95,7 @@ def test_qwen3_next_serving_programs_compile(chip, monkeypatch, program):
     from deepspeed_tpu.models import qwen3_next as qn
 
     for name in ("deepspeed_tpu.ops.pallas.flash_decode",
+                 "deepspeed_tpu.ops.pallas.gated_delta",
                  "deepspeed_tpu.moe.dropless"):
         _compiled_not_interpreted(monkeypatch, name)
     cfg = qn.qwen3_next_80b_share(n_layer=4)
@@ -93,10 +127,14 @@ def test_qwen3_next_serving_programs_compile(chip, monkeypatch, program):
     compiled = jax.jit(fn, donate_argnums=1).lower(
         params, cache, *args).compile()
     text = compiled.as_text()
-    # three grouped matmuls (gate, up, down) in each of the four blocks,
-    # and in decode the attention block's kernel
+    # three grouped matmuls (gate, up, down) in each of the four blocks;
+    # in prefill the three delta rules' kernel, in decode the attention
+    # block's
     assert text.count("custom_call_target=\"tpu_custom_call\"") == \
-        {"prefill": 12, "decode": 13}[program]
+        {"prefill": 15, "decode": 13}[program]
+    assert text.count("ds_gated_delta_chunked") >= \
+        (3 if program == "prefill" else 0)
+    assert "riangular" not in text
     for scope in ("ds_gdn_conv", "ds_attn_gate", "ds_moe_route",
                   "ds_moe_dispatch", "ds_moe_experts", "ds_moe_combine",
                   "ds_moe_shared",
