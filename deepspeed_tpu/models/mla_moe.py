@@ -88,8 +88,10 @@ YARN = (("beta_fast", 32), ("beta_slow", 1), ("factor", 64), ("mscale", 1),
         ("mscale_all_dim", 1), ("original_max_position_embeddings", 4096),
         ("type", "yarn"))
 # what a decode step's span carries of the expert layers, summed over
-# them (`inference/engine.py` reads the names)
-COUNTERS = ("moe_pairs_routed", "moe_pairs_held", "moe_experts_touched")
+# them (`inference/engine.py` reads the names); the last: the sorted
+# rows the dispatch filled, whole tiles (`moe/dropless.py`)
+COUNTERS = ("moe_pairs_routed", "moe_pairs_held", "moe_experts_touched",
+            "moe_rows_visited")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -349,7 +351,8 @@ def _held_experts(x, mask, router, bias, w_gate, w_up, w_down, *, top_k,
         first_expert=first_expert, token_mask=mask)
     sizes = stats["tokens_per_expert"]
     counters = jnp.stack([mask.sum().astype(jnp.int32) * top_k, sizes.sum(),
-                          (sizes > 0).sum().astype(jnp.int32)])
+                          (sizes > 0).sum().astype(jnp.int32),
+                          stats["rows_visited"]])
     return y, counters
 
 
@@ -362,7 +365,7 @@ def _bias_init(cfg):
 
 class HeldExperts(nn.Module):
     """The routed experts this chip holds, and the shared expert.
-    Returns ``(y, counters [3])`` (`COUNTERS`): ``mask`` ``[B, T]`` says
+    Returns ``(y, counters [4])`` (`COUNTERS`): ``mask`` ``[B, T]`` says
     which tokens are real."""
     config: MlaMoeConfig
 

@@ -74,9 +74,10 @@ PATTERN = ("MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
 # what a decode step's span carries of the expert layers
 # (`inference/engine.py` reads the names): the first three summed over
 # the layers as `models/mla_moe.py`'s, the fullest held expert's pairs
-# (largest over the layers), and held experts x expert layers
+# (largest over the layers), held experts x expert layers, and the
+# sorted rows the layers' dispatch filled (whole tiles, summed)
 COUNTERS = ("moe_pairs_routed", "moe_pairs_held", "moe_experts_touched",
-            "moe_pairs_max", "moe_experts_held")
+            "moe_pairs_max", "moe_experts_held", "moe_rows_visited")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -250,15 +251,16 @@ def _held_experts(x, latent, mask, router, bias, w_up, w_down, *, top_k,
         first_expert=first_expert, token_mask=mask, rows=latent)
     sizes = stats["tokens_per_expert"]
     counters = jnp.stack([mask.sum().astype(jnp.int32) * top_k, sizes.sum(),
-                          (sizes > 0).sum().astype(jnp.int32), sizes.max()])
+                          (sizes > 0).sum().astype(jnp.int32), sizes.max(),
+                          stats["rows_visited"]])
     return y, counters
 
 
 class LatentExperts(nn.Module):
     """The routed experts this chip holds, in their latent, and the
-    shared expert at full width. Returns ``(y, counters [4])`` (the
-    first four of `COUNTERS`, this layer's); ``mask`` ``[B, T]`` says
-    which tokens are real."""
+    shared expert at full width. Returns ``(y, counters [5])`` (the
+    first four of `COUNTERS` and its last, this layer's); ``mask``
+    ``[B, T]`` says which tokens are real."""
     config: NemotronHConfig
 
     @nn.compact
@@ -354,9 +356,10 @@ class NemotronHLM(nn.Module):
         logits = jnp.dot(h, head.astype(cfg.dtype),
                          preferred_element_type=jnp.float32)
         counted = jnp.stack(counted) if counted else \
-            jnp.zeros((1, 4), jnp.int32)
+            jnp.zeros((1, 5), jnp.int32)
         values = [*counted[:, :3].sum(0), counted[:, 3].max(),
-                  jnp.int32(cfg.experts_held[1] * len(cfg.names(EXPERTS)))]
+                  jnp.int32(cfg.experts_held[1] * len(cfg.names(EXPERTS))),
+                  counted[:, 4].sum()]
         return logits, new_cache, dict(zip(COUNTERS, values))
 
     # -- the serving engine's protocol (`inference/engine.py`) -------------

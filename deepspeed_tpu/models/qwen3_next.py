@@ -85,12 +85,13 @@ from deepspeed_tpu.ops import gated_delta, ssm
 
 DELTA, ATTENTION = "linear_attention", "full_attention"
 # what a decode step's span carries (`inference/engine.py` reads the
-# names): the expert layers' five as `models/nemotron_h.py`'s, and the
+# names): the expert layers' five as `models/nemotron_h.py`'s, the
 # rows whose delta-rule state the step moved on against those it read
-# and wrote back
+# and wrote back, and last the sorted rows the expert layers' dispatch
+# filled (`moe/dropless.py`: whole tiles, summed over the layers)
 COUNTERS = ("moe_pairs_routed", "moe_pairs_held", "moe_experts_touched",
             "moe_pairs_max", "moe_experts_held", "gdn_rows_live",
-            "gdn_rows_touched")
+            "gdn_rows_touched", "moe_rows_visited")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -448,14 +449,15 @@ def _held_experts(x, mask, router, w_gate, w_up, w_down, *, top_k,
         first_expert=first_expert, token_mask=mask)
     sizes = stats["tokens_per_expert"]
     counters = jnp.stack([mask.sum().astype(jnp.int32) * top_k, sizes.sum(),
-                          (sizes > 0).sum().astype(jnp.int32), sizes.max()])
+                          (sizes > 0).sum().astype(jnp.int32), sizes.max(),
+                          stats["rows_visited"]])
     return y, counters
 
 
 class SparseExperts(nn.Module):
     """The routed experts this chip holds and the gated shared expert.
-    Returns ``(y, counters [4])`` (the first four of `COUNTERS`, this
-    layer's); ``mask`` ``[B, T]`` says which tokens are real."""
+    Returns ``(y, counters [5])`` (the first four of `COUNTERS` and its
+    last, this layer's); ``mask`` ``[B, T]`` says which tokens are real."""
     config: Qwen3NextConfig
 
     @nn.compact
@@ -548,7 +550,8 @@ class Qwen3NextLM(nn.Module):
         # on the rows that hold a request and touches all of them
         values = [*counted[:, :3].sum(0), counted[:, 3].max(),
                   jnp.int32(cfg.experts_held[1] * len(cfg.layer_types)),
-                  (n_valid > 0).sum().astype(jnp.int32), jnp.int32(B)]
+                  (n_valid > 0).sum().astype(jnp.int32), jnp.int32(B),
+                  counted[:, 4].sum()]
         return logits, new_cache, dict(zip(COUNTERS, values))
 
     # -- the serving engine's protocol (`inference/engine.py`) -------------

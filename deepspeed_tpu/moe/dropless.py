@@ -35,10 +35,19 @@ deployment holds them. Routing still runs over all experts; the pairs
 whose expert is held are sorted to the front by expert and run through
 the same grouped matmuls, whose grid follows the group sizes, so the
 rows behind them (pairs of experts held elsewhere, pairs of masked
-tokens) cost no tile; those rows come out as zeros, by a mask on the
-row index and not by what the kernel left there. On one chip the layer
-runs without its exchange: what the other chips would add is not
-computed and nothing stands in for it.
+tokens) cost no tile. That holds for the gathers and the combine too
+(ISSUE 45, `_held_moe`): dispatch, the activation between the grouped
+matmuls and the combine are loops over the row tiles that hold held
+pairs, to a bound that is a value on the device; the dispatch fills
+those tiles of a buffer that is otherwise never written, and the
+combine adds each tile's rows to their tokens, so a row behind the
+held pairs is neither fetched nor multiplied nor summed, and adds zero
+by its index and not by what a kernel left there. Shapes stay static
+and nothing is dropped at any held count. A share is served and never
+differentiated, so this path is apart from the whole layer's
+(`_dropless_moe`), whose rows move by gathers with hand-written
+cotangents. On one chip the layer runs without its exchange: what the
+other chips would add is not computed and nothing stands in for it.
 
 **An expert has one of two forms**, told by the banks given: three
 banks, ``w_down (silu(w_gate r) * w_up r)`` (OLMoE's, DeepSeek-V3's), or
@@ -200,25 +209,14 @@ def _sort_pairs(keys, n_groups):
 
 
 def _dropless_moe(x, router, w_gate, w_up, w_down, top_k,
-                  route=softmax_top_k, first_expert=None, token_mask=None,
-                  rows=None):
-    """`dropless_moe` on the tokens of one chip."""
+                  route=softmax_top_k, rows=None):
+    """`dropless_moe` on the tokens of one chip, every expert held."""
     n_tokens, n_experts = x.shape[0], router.shape[1]
     taken = x if rows is None else rows
     with jax.named_scope("ds_moe_route"):
         weights, experts, aux = route(x, router, top_k)
-        pair_expert = experts.reshape(-1)
-        if first_expert is None:
-            group_sizes, order, inverse = _sort_pairs(pair_expert,
-                                                      n_experts)
-        else:
-            n_held = w_up.shape[0]
-            local = pair_expert - first_expert
-            held = (local >= 0) & (local < n_held)
-            if token_mask is not None:
-                held &= jnp.repeat(token_mask, top_k)
-            group_sizes, order, inverse = _sort_pairs(
-                jnp.where(held, local, n_held), n_held)
+        group_sizes, order, inverse = _sort_pairs(experts.reshape(-1),
+                                                  n_experts)
     with jax.named_scope("ds_moe_dispatch"):
         rows = _gather_tokens(taken, order, inverse, top_k)  # [N k, M]
     with jax.named_scope("ds_moe_experts"):
@@ -231,12 +229,6 @@ def _dropless_moe(x, router, w_gate, w_up, w_down, top_k,
                 grouped_matmul(rows, w_gate.astype(dt), group_sizes)) * \
                 grouped_matmul(rows, w_up.astype(dt), group_sizes)
         out = grouped_matmul(hidden, w_down.astype(dt), group_sizes)
-        if first_expert is not None:
-            # rows behind the held groups belong to no expert here: the
-            # grouped matmuls visit no tile of them and leave there
-            # what was in memory; zeros by the row's index
-            live = jnp.arange(out.shape[0]) < group_sizes.sum()
-            out = jnp.where(live[:, None], out, jnp.zeros_like(out))
     with jax.named_scope("ds_moe_combine"):
         out = _gather_pairs(out, order, inverse).reshape(
             n_tokens, top_k, -1)
@@ -249,6 +241,114 @@ def _dropless_moe(x, router, w_gate, w_up, w_down, top_k,
         **aux,
     }
     return y.astype(taken.dtype), stats
+
+
+def _unwritten_rows(like, n_rows):
+    """``[n_rows, like.shape[1]]`` of ``like``'s dtype that nothing has
+    written: a kernel with no body, its output left in the device's
+    memory. `_held_moe` fills the tiles it uses; zeros would be a pass
+    over every row (117 MB a Kimi layer, 185 us: PERF.md, PR 45).
+    ``like`` is handed in, unread, so that two layers' calls are not
+    one call to the compiler, which would then copy the buffer for the
+    second."""
+    from jax.experimental import pallas as pl
+
+    anywhere = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        lambda like, out: None, in_specs=[anywhere], out_specs=anywhere,
+        out_shape=jax.ShapeDtypeStruct((n_rows, like.shape[1]), like.dtype),
+        name="ds_moe_unwritten_rows",
+        interpret=jax.devices()[0].platform != "tpu")(like)
+
+
+def _held_moe(x, router, w_gate, w_up, w_down, top_k, route, first_expert,
+              token_mask=None, rows=None):
+    """`dropless_moe` on a share of the experts (``first_expert``):
+    served, never differentiated. The ``H`` held pairs (held expert,
+    live token) sort to rows ``[0, H)``; everything outside the grouped
+    matmuls is a loop over the ``ceil(H / tile)`` row tiles that hold
+    them (``tile`` the grouped matmul's row tile), ``H`` a value on the
+    device, so the rows behind cost neither a fetch nor a sum. The
+    grouped matmuls run once a bank on the whole ``[N top_k, .]`` buffer
+    and visit the same tiles."""
+    n_tokens, n_held = x.shape[0], w_up.shape[0]
+    taken = x if rows is None else rows
+    dt = taken.dtype
+    n_pairs = n_tokens * top_k
+    tile = math.gcd(n_pairs, 256)
+    with jax.named_scope("ds_moe_route"):
+        weights, experts, aux = route(x, router, top_k)
+        local = experts.reshape(-1) - first_expert
+        held = (local >= 0) & (local < n_held)
+        if token_mask is not None:
+            held &= jnp.repeat(token_mask, top_k)
+        # not `_sort_pairs`: no row is fetched back by an inverse here,
+        # and its scatter-add counts a pair at a time (197 us at 22,528
+        # pairs, a compare and a sum 14: PERF.md, PR 45)
+        keys = jnp.where(held, local, n_held)
+        group_sizes = (keys[:, None] == jnp.arange(n_held)).sum(
+            0, dtype=jnp.int32)
+        order = jnp.argsort(keys, stable=True).astype(jnp.int32)
+        n_live = group_sizes.sum()
+        tiles = (n_live + tile - 1) // tile
+
+    def pairs_of(i):
+        return jax.lax.dynamic_slice(order, (i * tile,), (tile,))
+
+    def rows_of(buf, i):
+        return jax.lax.dynamic_slice(buf, (i * tile, 0),
+                                     (tile, buf.shape[1]))
+
+    def over_tiles(body, init):
+        return jax.lax.fori_loop(0, tiles, body, init)
+
+    with jax.named_scope("ds_moe_dispatch"):
+        def fill(i, buf):
+            return jax.lax.dynamic_update_slice(
+                buf, taken[pairs_of(i) // top_k], (i * tile, 0))
+        rows = over_tiles(fill, _unwritten_rows(taken, n_pairs))
+    with jax.named_scope("ds_moe_experts"):
+        gate = None if w_gate is None else \
+            grouped_matmul(rows, w_gate.astype(dt), group_sizes)
+        up = grouped_matmul(rows, w_up.astype(dt), group_sizes)
+
+        # behind the live tiles the grouped matmuls left what was in
+        # memory, and the next one reads none of it
+        def activate(i, hidden):
+            u = rows_of(hidden, i)
+            h = jnp.square(jax.nn.relu(u)) if gate is None else \
+                jax.nn.silu(rows_of(gate, i)) * u
+            return jax.lax.dynamic_update_slice(hidden, h, (i * tile, 0))
+        out = grouped_matmul(over_tiles(activate, up), w_down.astype(dt),
+                             group_sizes)
+    with jax.named_scope("ds_moe_combine"):
+        pair_weight = weights.reshape(-1)
+        token = jnp.arange(n_tokens)[:, None]
+
+        # a tile's rows, scaled, are added to their tokens by a 0/1
+        # selector at the highest precision (exact: one term a product);
+        # a row past the held pairs adds zero by its index, not by what
+        # the kernel left there
+        def add(i, y):
+            pairs = pairs_of(i)
+            live = i * tile + jnp.arange(tile) < n_live
+            part = pair_weight[pairs][:, None] * \
+                rows_of(out, i).astype(jnp.float32)
+            part = jnp.where(live[:, None], part, 0.0)
+            pick = (token == pairs // top_k).astype(jnp.float32)
+            return y + jnp.dot(pick, part,
+                               precision=jax.lax.Precision.HIGHEST)
+        y = over_tiles(add, jnp.zeros((n_tokens, out.shape[1]),
+                                      jnp.float32))
+    stats = {
+        "chosen": experts,
+        "weights": weights,
+        "tokens_per_expert": group_sizes,
+        "dropped": n_pairs - n_live,
+        "rows_visited": tiles * tile,
+        **aux,
+    }
+    return y.astype(dt), stats
 
 
 class ExpertExchangeUnsupported(NotImplementedError):
@@ -298,8 +398,11 @@ def dropless_moe(x, router, w_gate, w_up, w_down, top_k,
     by construction); the last four summed over all chips' tokens."""
     placed = placement()
     if placed is None or placed[0].shape[placed[1]] == 1:
-        return _dropless_moe(x, router, w_gate, w_up, w_down, top_k,
-                             route, first_expert, token_mask, rows)
+        if first_expert is None:
+            return _dropless_moe(x, router, w_gate, w_up, w_down, top_k,
+                                 route, rows)
+        return _held_moe(x, router, w_gate, w_up, w_down, top_k, route,
+                         first_expert, token_mask, rows)
     if first_expert is not None:
         raise ExpertExchangeUnsupported(
             "dropless_moe with a share of the experts runs on one chip: "

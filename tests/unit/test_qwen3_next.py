@@ -467,14 +467,16 @@ def test_expert_layer_with_dead_tokens_against_a_loop():
 
 # sha1 of the lowered (StableHLO) text of this model's two tiny
 # programs as PR 43 left them, the prefill program's as PR 44 did (its
-# delta rule became one kernel call, in interpret mode here); the twin
+# delta rule became one kernel call, in interpret mode here), both as
+# PR 45 did (the held experts became `moe/dropless.py:_held_moe`, loops
+# over the live row tiles, and a counter more rides home); the twin
 # of `tests/unit/test_nemotron_h.py::
 # test_accepted_tiny_programs_lower_to_the_text_they_lowered_to`, whose
 # digests pin granite's, Kimi's and OLMoE's. A later PR that changes
 # one on purpose takes the new hash from this test's message.
 LOWERED = {
-    "qwen3_next.prefill": "b6a21bed42840a0ece664a963d47ad47c1b88a06",
-    "qwen3_next.decode": "7c79557a6a1f867c131ae44295253c0cd38c4b46",
+    "qwen3_next.prefill": "81e866cd1bb3f245811a6e8e4a068839a18e963c",
+    "qwen3_next.decode": "4c29440c263998317b2e522b8442ea79118e748e",
 }
 
 

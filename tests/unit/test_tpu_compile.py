@@ -304,6 +304,25 @@ def test_paged_layer_copies_no_pool(chip, monkeypatch, program, kv_dtype):
         assert bool(loops) == (program == "verify")
 
 
+def held_experts_calls(text, pairs, width):
+    """``(Mosaic calls, grouped matmuls among them, unwritten buffers
+    among them)`` of a compiled serving program whose expert layers
+    hold a share (`moe/dropless.py:_held_moe`, ISSUE 45), having seen
+    that no ``[pairs, width]`` bfloat16 buffer of sorted rows is zeroed
+    or copied: the dispatch fills the live tiles of a buffer that a
+    kernel with no body hands over, and the grouped matmuls are the
+    calls they were, none inside a loop."""
+    import re
+
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert [line for line in text.splitlines() if re.search(
+        r"= bf16\[%d,%d\]\S* (copy|broadcast)\(" % (pairs, width),
+        line)] == []
+    return (len(calls), sum("gmm" in line.split("=")[0] for line in calls),
+            sum("ds_moe_unwritten_rows" in line for line in calls))
+
+
 def kernel_grids(lowered_text):
     """The grid of every Mosaic kernel in a lowered (StableHLO) text, in
     order: each `tpu_custom_call` carries its serialized Mosaic module,
@@ -537,6 +556,11 @@ def test_mla_moe_serving_programs_compile(chip, monkeypatch, program):
     # 3 grouped matmuls a expert layer; the decode kernel a layer
     assert text.count("tpu_custom_call") >= {"decode": 5, "prefill": 3,
                                              "prefill-flash": 5}[program]
+    # the expert layer's, and its unwritten buffer of sorted rows (117
+    # MB in prefill, of which one tile is filled: ISSUE 45)
+    pairs = (MLA_ROWS if program == "decode" else 1024) * \
+        cfg.num_experts_per_tok
+    assert held_experts_calls(text, pairs, cfg.hidden_size)[1:] == (3, 1)
     for scope in ("ds_mla_project", "ds_moe_route", "ds_moe_experts",
                   "ds_moe_shared", "ds_flash_decode_paged" if
                   program == "decode" else "ds_mla_prefill_attn"):

@@ -9,7 +9,8 @@ import jax.numpy as jnp
 import pytest
 
 from tests.unit.test_tpu_compile import (       # noqa: F401 (fixtures)
-    PAGE, _compiled_not_interpreted, chip, decode_call, kernel_grids, topo)
+    PAGE, _compiled_not_interpreted, chip, decode_call, held_experts_calls,
+    kernel_grids, topo)
 
 # the cell's engine: 96 rows, a bucket of 5,120 (40 pages), a pool of
 # 3,840 pages and the trash page, 2 key heads of 128
@@ -77,9 +78,14 @@ def test_nemotron_h_serving_programs_compile(chip, monkeypatch, program):
     compiled = jax.jit(fn, donate_argnums=1).lower(
         params, cache, *args).compile()
     text = compiled.as_text()
-    # two grouped matmuls (up, down), and in decode the kernel
-    assert text.count("custom_call_target=\"tpu_custom_call\"") == \
-        {"prefill": 2, "decode": 3}[program]
+    # two grouped matmuls (up, down), as before the held path became a
+    # loop over live tiles (ISSUE 45: 10 a decode program of the cell's
+    # five expert layers), and the layer's unwritten buffer of sorted
+    # rows; in decode the attention kernel
+    pairs = (CHUNK if program == "prefill" else ROWS) * \
+        cfg.num_experts_per_tok
+    assert held_experts_calls(text, pairs, cfg.moe_latent_size) == \
+        ({"prefill": 3, "decode": 4}[program], 2, 1)
     for scope in ("ds_ssm_in_proj", "ds_ssm_conv", "ds_ssm_scan",
                   "ds_ssm_gate_norm", "ds_ssm_out_proj", "ds_moe_route",
                   "ds_moe_dispatch", "ds_moe_experts", "ds_moe_combine",

@@ -11,7 +11,8 @@ import jax.numpy as jnp
 import pytest
 
 from tests.unit.test_tpu_compile import (       # noqa: F401 (fixtures)
-    PAGE, _compiled_not_interpreted, chip, decode_call, kernel_grids, topo)
+    PAGE, _compiled_not_interpreted, chip, decode_call, held_experts_calls,
+    kernel_grids, topo)
 
 # the cell's engine: 128 rows, a bucket of 5,120 (40 pages), a pool of
 # 4,096 pages and the trash page, 2 key heads of 256
@@ -127,11 +128,15 @@ def test_qwen3_next_serving_programs_compile(chip, monkeypatch, program):
     compiled = jax.jit(fn, donate_argnums=1).lower(
         params, cache, *args).compile()
     text = compiled.as_text()
-    # three grouped matmuls (gate, up, down) in each of the four blocks;
-    # in prefill the three delta rules' kernel, in decode the attention
-    # block's
-    assert text.count("custom_call_target=\"tpu_custom_call\"") == \
-        {"prefill": 15, "decode": 13}[program]
+    # three grouped matmuls (gate, up, down) in each of the four blocks,
+    # as before the held path became a loop over live tiles (ISSUE 45:
+    # 24 a decode program of the cell's eight blocks), and a block's
+    # unwritten buffer of sorted rows; in prefill the three delta rules'
+    # kernel, in decode the attention block's
+    pairs = (CHUNK if program == "prefill" else ROWS) * \
+        cfg.num_experts_per_tok
+    assert held_experts_calls(text, pairs, cfg.hidden_size) == \
+        ({"prefill": 19, "decode": 17}[program], 12, 4)
     assert text.count("ds_gated_delta_chunked") >= \
         (3 if program == "prefill" else 0)
     assert "riangular" not in text
