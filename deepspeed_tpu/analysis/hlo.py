@@ -12,12 +12,10 @@ computations, the call graph (``while`` body/condition, ``calls=``,
 ``to_apply=``, conditional branches) is walked from ENTRY, and each
 computation gets an execution multiplier — a collective inside a
 ``lax.scan``-lowered ``while`` with ``known_trip_count n=K`` counts K
-times, not once. This fixes the historical flat-program limitation of
-``utils/hlo_analysis.py`` (each op counted ONCE, so the executed-1F1B
-pipeline's per-tick ``collective-permute`` volume was unpinnable); that
-module is now a thin compatibility shim over this one. Text without any
+times, not once (counted ONCE, the executed-1F1B pipeline's per-tick
+``collective-permute`` volume was unpinnable). Text without any
 computation headers (hand-written snippets in tests) falls back to flat
-counting, and ``trip_aware=False`` restores the old behavior exactly.
+counting, and ``trip_aware=False`` counts every op once.
 """
 
 import re

@@ -87,6 +87,8 @@ from jax import shard_map
 from deepspeed_tpu.utils.logging import log_dist, logger
 
 MEMORY_OPT_ALLREDUCE_SIZE = 500000000
+# a step's batch, [accum, rows, ...]: the rows lie over ``data``
+_BATCH_ROWS = PartitionSpec(None, "data")
 
 
 class DeviceState(NamedTuple):
@@ -394,6 +396,122 @@ def make_grad_accumulator(loss_fn, compute_dtype, accum, constrain=None,
     return accumulate
 
 
+class _StepInputs(NamedTuple):
+    """What every kind of train step reads of the engine, gathered once
+    (`DeepSpeedEngine._step_inputs`)."""
+    accum: int
+    compute_dtype: Any
+    fp16: bool
+    clip: float
+    lr_fn: Optional[Callable]
+    mom_fn: Callable
+    opt_update: Callable
+    scale_args: dict
+    dynamic: bool
+    static_scale: float
+    pld_fn: Optional[Callable]
+    detect: bool        # the NaN guard forces the finiteness check on
+    nan_skip: bool      # and its verdict skips the update
+    fault_on: bool      # fault injection is configured on
+
+
+def _loss_scale(c, dstate):
+    return dstate.loss_scale.cur_scale if (c.fp16 and c.dynamic) \
+        else jnp.asarray(c.static_scale, jnp.float32)
+
+
+def _step_head(c, accumulate, params, dstate, batch, rng, fp8_state=None,
+               grad_fault=None, manual=False):
+    """The head of every train step: the loss scale, the progressive
+    layer drop's theta, and ``accumulate``'s scaled gradients. Returns
+    ``(scale, loss_sum, grads, loss_scalars, fp8_new)``, ``fp8_new``
+    None without an ``fp8_state``.
+
+    ``manual``: the caller is inside a ``shard_map`` over ``data`` and
+    sees its own rows, so the rng is folded by the shard's index.
+    ``grad_fault``: the fault harness's multiplier, for the steps that
+    take one (`DeepSpeedEngine._make_train_step`)."""
+    scale = _loss_scale(c, dstate)
+    if manual:
+        rng = jax.random.fold_in(rng, jax.lax.axis_index("data"))
+    loss_kw = {"pld_theta": c.pld_fn(dstate.global_step)} \
+        if c.pld_fn is not None else None
+    loss_sum, grads, *fp8_new, loss_scalars = accumulate(
+        params, batch, rng, scale, loss_kw, fp8_state)
+    if grad_fault is not None:
+        grads = jax.tree_util.tree_map(lambda g: g * grad_fault, grads)
+    return scale, loss_sum, grads, loss_scalars, \
+        (fp8_new[0] if fp8_new else None)
+
+
+def _step_tail(c, params, opt_state, dstate, lr_in, scale, loss_sum, grads,
+               unscaled=False, constrain=None, vote=None, norm_reduce=None,
+               clip_norm_reduce=None, loss_reduce=None,
+               param_shardings=None, opt_shardings=None, loss_scalars=None,
+               extra_metrics=None, carried=None):
+    """Everything after a step kind has brought its gradients together:
+    :func:`grad_epilogue` -> lr and beta1 of the step -> the optimizer's
+    update -> overflow skips it (reference stage2.py:1341-1362) ->
+    :func:`loss_scale_epilogue` -> :func:`step_metrics`. Returns
+    ``(params, opt_state, dstate, metrics)``.
+
+    What a kind asks of it: ``unscaled`` when its sync has already
+    divided by scale x accum (the int8 exchange quantizes finite,
+    scale-free values); ``constrain`` and the two shardings where GSPMD
+    partitions the update, ``param_shardings`` over the parameters and
+    ``opt_shardings`` over the optimizer state's ``m`` and ``v`` (its
+    fields shaped like the parameters); ``vote``, ``norm_reduce`` and
+    ``clip_norm_reduce`` as :func:`grad_epilogue` takes them and
+    ``loss_reduce`` as :func:`step_metrics` does, where a manual region
+    still holds local gradients; ``extra_metrics`` of its own.
+
+    ``opt_state=None`` leaves the update out (ZeRO-Offload's is the
+    host's): the clipped gradients come back in the parameters' place
+    and ``beta1`` rides with the metrics. ``carried``, an ``(old, new)``
+    pair of one more state the step threads, is selected like the
+    optimizer's and returned fifth: an overflowed step keeps the OLD
+    fp8 amax histories, or an inf/nan cotangent amax would poison the
+    delayed scales for the next amax_history_len steps."""
+    grads, overflow, nonfinite, grad_norm, applied_norm = grad_epilogue(
+        grads, jnp.asarray(1.0, jnp.float32) if unscaled else scale,
+        1 if unscaled else c.accum, c.fp16, c.clip, constrain=constrain,
+        vote=vote, norm_reduce=norm_reduce,
+        clip_norm_reduce=clip_norm_reduce, detect_nonfinite=c.detect,
+        nan_skip=c.nan_skip)
+
+    lr = c.lr_fn(dstate.global_step) if c.lr_fn is not None else lr_in
+    beta1 = c.mom_fn(dstate.global_step)
+
+    def select(old, new, shardings=None):
+        kept = jax.tree_util.tree_map(
+            lambda o, n: jnp.where(overflow, o, n), old, new)
+        return kept if shardings is None else \
+            constrain_tree(kept, shardings)
+
+    if opt_state is None:
+        params_out, opt_out = grads, None
+        extra_metrics = dict(extra_metrics or {}, beta1=beta1)
+    else:
+        new_params, new_opt = c.opt_update(params, grads, opt_state, lr,
+                                           beta1)
+        params_out = select(params, new_params, param_shardings)
+        opt_out = type(opt_state)(**{
+            name: select(getattr(opt_state, name), getattr(new_opt, name),
+                         opt_shardings if name in ("m", "v") else None)
+            for name in opt_state._fields})
+
+    dstate_out = loss_scale_epilogue(dstate, overflow, c.fp16, c.dynamic,
+                                     c.scale_args)
+    metrics = step_metrics(loss_sum, c.accum, grad_norm, applied_norm, lr,
+                           scale, overflow, loss_reduce=loss_reduce,
+                           dstate=dstate_out, nonfinite=nonfinite,
+                           loss_scalars=loss_scalars)
+    metrics.update(extra_metrics or {})
+    if carried is not None:
+        return params_out, opt_out, dstate_out, metrics, select(*carried)
+    return params_out, opt_out, dstate_out, metrics
+
+
 def place_kernels_on_mesh(loss_fn, mesh):
     """``loss_fn``, traced with its Pallas attention placed on ``mesh``.
 
@@ -475,7 +593,7 @@ class DeepSpeedEngine:
             raise
         self.mesh = mesh if mesh is not None else build_mesh(
             (config.get("mesh") if isinstance(config, dict) else None))
-        # `_make_train_step` hands out the scalars a loss function may
+        # `_dense_step` hands out the scalars a loss function may
         # return beside its loss; every other program takes the loss alone
         self._loss_with_scalars = place_kernels_on_mesh(loss_fn, self.mesh)
         self.loss_fn = loss_alone(self._loss_with_scalars)
@@ -704,7 +822,7 @@ class DeepSpeedEngine:
         self._last_metrics = {}
         # Error-feedback residual state for the int8 quantized all-reduce
         # (`runtime/comm/quantized.py`); populated lazily by
-        # `_make_quantized_train_step` when comm_quantization.error_feedback
+        # `_quantized_step` when comm_quantization.error_feedback
         # is on. Ephemeral comm state — intentionally not checkpointed.
         self._qcomm_residuals = None
 
@@ -1181,36 +1299,100 @@ class DeepSpeedEngine:
         return self._param_caster() or (lambda p: jax.tree_util.tree_map(
             lambda x: x.astype(compute_dtype), p))
 
+    def _step_inputs(self):
+        rz = self._config.resilience
+        return _StepInputs(
+            accum=self._engine_accum_steps(),
+            compute_dtype=self.compute_dtype,
+            fp16=self._config.fp16_enabled,
+            clip=float(self._config.gradient_clipping or 0.0),
+            lr_fn=self._lr_fn, mom_fn=self._mom_fn,
+            opt_update=self._opt_update, scale_args=self._scale_args(),
+            dynamic=self.dynamic_loss_scale,
+            static_scale=self.static_loss_scale,
+            pld_fn=self._pld_theta_fn(),
+            detect=rz.nan_guard_action is not None,
+            nan_skip=rz.nan_guard_action == ACTION_SKIP_STEP,
+            fault_on=bool(rz.fault_injection))
+
     def _make_train_step(self):
+        """The compiled train step of this engine's kind
+        (:meth:`_step_kind`). Every kind is :func:`_step_head`, its own
+        way of bringing the gradients together, :func:`_step_tail`, and
+        a wrap (`donated_jit`, under :meth:`_over_data` where the kind
+        runs in a manual region); the builders below hold what is a
+        kind's own. The second entry says whether the step takes the
+        fault harness's ``grad_fault`` argument."""
         kind = self._step_kind()
-        if kind == "onebit":
-            if getattr(self.loss_fn, "direct_value_and_grad_local",
-                       None) is not None:
-                return self._make_pipeline_onebit_train_step()
-            return self._make_onebit_train_step()
-        if kind == "sparse":
-            return self._make_sparse_grad_train_step()
-        if kind == "quantized":
-            return self._make_quantized_train_step()
-        accum = self._engine_accum_steps()
-        compute_dtype = self.compute_dtype
-        fp16 = self._config.fp16_enabled
-        clip = float(self._config.gradient_clipping or 0.0)
-        prescale = self._config.prescale_gradients
-        predivide = float(self._config.gradient_predivide_factor or 1.0)
-        lr_fn = self._lr_fn
-        mom_fn = self._mom_fn
-        opt_update = self._opt_update
-        loss_fn = self._loss_with_scalars
+        if kind == "onebit" and getattr(
+                self.loss_fn, "direct_value_and_grad_local",
+                None) is not None:
+            kind = "pipeline x onebit"
+        build, takes_fault = {
+            "dense": (self._dense_step, True),
+            "pipeline": (self._dense_step, True),
+            "offload": (self._offload_step, True),
+            "quantized": (self._quantized_step, False),
+            "sparse": (self._sparse_step, False),
+            "onebit": (self._onebit_step, False),
+            "pipeline x onebit": (self._pipeline_onebit_step, False),
+        }[kind]
+        c = self._step_inputs()
+        self._fault_arg = c.fault_on and takes_fault
+        if c.fault_on and not takes_fault:
+            log_dist(f"fault_injection: the {kind} step does not take the "
+                     "grad_fault argument; NaN-grad injection is inert on "
+                     "this path", ranks=[0])
+        return build(c)
+
+    def _gspmd_layouts(self):
+        """What :func:`_step_tail` takes where GSPMD partitions the
+        update: the ZeRO-2 gradient layout and the output shardings."""
         grad_shardings = self._shardings["grad"] if \
             self.zero_optimization_stage() >= 2 else None
-        param_shardings = self._shardings["param"]
-        opt_shardings = self._shardings["opt"]
-        scale_args = self._scale_args()
-        dynamic = self.dynamic_loss_scale
-        static_scale = self.static_loss_scale
-        grad_constrain = (lambda g: constrain_tree(g, grad_shardings)) \
-            if grad_shardings is not None else None
+        return dict(
+            constrain=(lambda g: constrain_tree(g, grad_shardings))
+            if grad_shardings is not None else None,
+            param_shardings=self._shardings["param"],
+            opt_shardings=self._shardings["opt"])
+
+    def _over_data(self, what, fn, in_specs, out_specs):
+        """``fn`` as a manual region over the ``data`` axis: each shard
+        sees its own rows of the batch (``_BATCH_ROWS``) and its local
+        gradients. The specs are pytree prefixes, so one ``P()`` stands
+        for a whole replicated tree, the metrics' dict included."""
+        for ax, size in self.mesh.shape.items():
+            assert ax == "data" or size == 1, (
+                f"{what} supports pure data parallelism; mesh axis "
+                f"{ax!r} has size {size}")
+        return shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
+    def _persisting(self, inner, attr, ensure=None):
+        """Host-side wrapper of a step that threads one more state than
+        the engine's three, as its seventh argument (donated) and last
+        output: the state lives on the engine as ``attr`` between calls
+        (``ensure(batch, rng)`` allocates it on the first). ``.inner``
+        is the jitted step, for `analysis/audit.py` to lower."""
+        engine = self
+
+        def compiled(params, opt_state, dstate, batch, rng, lr_in, *fault):
+            state = ensure(batch, rng) if ensure is not None \
+                else getattr(engine, attr)
+            *out, state = inner(params, opt_state, dstate, batch, rng,
+                                lr_in, state, *fault)
+            setattr(engine, attr, state)
+            return tuple(out)
+
+        compiled.inner = inner
+        return compiled
+
+    def _dense_step(self, c):
+        """``dense`` and ``pipeline``: GSPMD brings the gradients
+        together (the mean loss over the data-sharded batch), so between
+        head and tail there is nothing."""
+        loss_fn = self._loss_with_scalars
+        layouts = self._gspmd_layouts()
         # Sharded masters reach the loss through a cast-then-gather: the
         # wire carries the compute dtype, exactly (the reference gathers
         # the updated fp16 shards, never the fp32 masters: stage1.py:692).
@@ -1235,7 +1417,7 @@ class DeepSpeedEngine:
         self._zero3_plan = None
         self._param_gather_plan = None
         zc = self._config.zero_config
-        if compute_dtype != jnp.float32:
+        if c.compute_dtype != jnp.float32:
             if self.zero_optimization_stage() < 3 or not zc.gather_on_use:
                 caster = self._param_caster()
                 if self._sharded_masters():
@@ -1245,7 +1427,8 @@ class DeepSpeedEngine:
                                          "gather_bytes": 0})
             else:
                 caster, plan = make_gather_on_use_caster(
-                    self.params, param_shardings, self.mesh, compute_dtype,
+                    self.params, layouts["param_shardings"], self.mesh,
+                    c.compute_dtype,
                     chunks=int(zc.gather_chunks or 1),
                     prefetch=bool(zc.prefetch),
                     bidirectional=bool(zc.bidirectional),
@@ -1254,75 +1437,32 @@ class DeepSpeedEngine:
                 if caster is not None:
                     self._zero3_plan = plan
                     remat_policy = zero3_remat_policy()
-        accumulate = make_grad_accumulator(loss_fn, compute_dtype, accum,
-                                           constrain=grad_constrain,
+        accumulate = make_grad_accumulator(loss_fn, c.compute_dtype, c.accum,
+                                           constrain=layouts["constrain"],
                                            cast_params=caster,
                                            remat_policy=remat_policy,
                                            fp8_plan=fp8_plan)
-        pld_fn = self._pld_theta_fn()
-        detect, nan_skip, fault_on = self._nan_guard_flags()
-        self._fault_arg = fault_on
 
         def train_step(params, opt_state, dstate, batch, rng, lr_in,
                        fp8_state=None, grad_fault=None):
-            scale = dstate.loss_scale.cur_scale if (fp16 and dynamic) \
-                else jnp.asarray(static_scale, jnp.float32)
-            loss_kw = {"pld_theta": pld_fn(dstate.global_step)} \
-                if pld_fn is not None else None
-            if fp8_state is None:
-                loss_sum, grads, loss_scalars = accumulate(
-                    params, batch, rng, scale, loss_kw)
-                f8_new = None
-            else:
-                loss_sum, grads, f8_new, loss_scalars = accumulate(
-                    params, batch, rng, scale, loss_kw, fp8_state)
-            if fault_on:
-                grads = jax.tree_util.tree_map(lambda g: g * grad_fault,
-                                               grads)
-
-            # Unscale + average over microbatches. The reference's
-            # prescale_gradients / gradient_predivide_factor knobs
-            # (allreduce_bucket pre/post scaling, engine.py:1082) exist to
-            # keep fp16 reductions in range; here the cross-replica mean is
-            # computed by XLA in fp32, so they are accepted for config
-            # compatibility but are intentionally no-ops.
-            grads, overflow, nonfinite, grad_norm, applied_norm = \
-                grad_epilogue(grads, scale, accum, fp16, clip,
-                              constrain=grad_constrain,
-                              detect_nonfinite=detect, nan_skip=nan_skip)
-
-            lr = lr_fn(dstate.global_step) if lr_fn is not None else lr_in
-            beta1 = mom_fn(dstate.global_step)
-            new_params, new_opt = opt_update(params, grads, opt_state, lr, beta1)
-
-            # Overflow → skip the update (reference stage2.py:1341-1362).
-            def select(old, new):
-                return jax.tree_util.tree_map(
-                    lambda o, n: jnp.where(overflow, o, n), old, new)
-            params_out = constrain_tree(select(params, new_params),
-                                        param_shardings)
-            opt_out = type(opt_state)(
-                m=constrain_tree(select(opt_state.m, new_opt.m), opt_shardings),
-                v=constrain_tree(select(opt_state.v, new_opt.v), opt_shardings),
-                step=jnp.where(overflow, opt_state.step, new_opt.step))
-
-            dstate_out = loss_scale_epilogue(dstate, overflow, fp16, dynamic,
-                                             scale_args)
-            metrics = step_metrics(loss_sum, accum, grad_norm, applied_norm,
-                                   lr, scale, overflow, dstate=dstate_out,
-                                   nonfinite=nonfinite,
-                                   loss_scalars=loss_scalars)
-            if fp8_state is not None:
-                # Overflowed steps keep the OLD amax histories: an
-                # inf/nan cotangent amax would otherwise poison the
-                # delayed scales for the next amax_history_len steps.
-                f8_out = select(fp8_state, f8_new)
-                return params_out, opt_out, dstate_out, metrics, f8_out
-            return params_out, opt_out, dstate_out, metrics
+            scale, loss_sum, grads, loss_scalars, f8_new = _step_head(
+                c, accumulate, params, dstate, batch, rng, fp8_state,
+                grad_fault)
+            # The reference's prescale_gradients /
+            # gradient_predivide_factor knobs (allreduce_bucket pre/post
+            # scaling, engine.py:1082) exist to keep fp16 reductions in
+            # range; here the cross-replica mean is computed by XLA in
+            # fp32, so they are accepted for config compatibility but are
+            # intentionally no-ops.
+            return _step_tail(
+                c, params, opt_state, dstate, lr_in, scale, loss_sum, grads,
+                loss_scalars=loss_scalars,
+                carried=None if fp8_state is None else (fp8_state, f8_new),
+                **layouts)
 
         # Inputs arrive pre-placed (device_put with committed shardings);
-        # outputs are pinned by the constrain_tree calls above, so plain jit
-        # with donation suffices.
+        # outputs are pinned by the tail's constraints, so plain jit with
+        # donation suffices.
         if fp8_plan is None:
             def train_step_plain(params, opt_state, dstate, batch, rng,
                                  lr_in, grad_fault=None):
@@ -1330,23 +1470,12 @@ class DeepSpeedEngine:
                                   lr_in, None, grad_fault)
             return donated_jit(train_step_plain, (0, 1, 2))
 
-        # fp8: the amax-history state threads through the step exactly
-        # like the 1-bit error-feedback residuals — a trailing donated
-        # argument the host-side wrapper persists on the engine between
-        # calls. Discovery (allocating the per-site bundles) is lazy on
-        # the first batch.
+        # fp8: the amax-history state rides the step as the 1-bit
+        # error-feedback residuals do. Discovery (allocating the
+        # per-site bundles) is lazy on the first batch.
         self._fp8_state = getattr(self, "_fp8_state", None)
-        inner = donated_jit(train_step, (0, 1, 2, 6))
-        engine = self
-
-        def compiled(params, opt_state, dstate, batch, rng, lr_in, *fault):
-            state = engine._ensure_fp8_state(batch, rng)
-            (params, opt_state, dstate, metrics,
-             engine._fp8_state) = inner(params, opt_state, dstate, batch,
-                                        rng, lr_in, state, *fault)
-            return params, opt_state, dstate, metrics
-
-        compiled.inner = inner
+        compiled = self._persisting(donated_jit(train_step, (0, 1, 2, 6)),
+                                    "_fp8_state", self._ensure_fp8_state)
         compiled.fp8 = True
         return compiled
 
@@ -1387,16 +1516,6 @@ class DeepSpeedEngine:
         log_dist(f"fp8: delayed scaling active over {len(keys)} dot "
                  f"site(s)", ranks=[0])
         return self._fp8_state
-
-    def _nan_guard_flags(self):
-        """(detect_nonfinite, nan_skip, fault_on) for the step factories:
-        whether the in-jit finiteness detector is forced on, whether its
-        verdict skips the update, and whether the compiled step takes the
-        fault-injection ``grad_fault`` multiplier argument."""
-        rz = self._config.resilience
-        detect = rz.nan_guard_action is not None
-        nan_skip = rz.nan_guard_action == ACTION_SKIP_STEP
-        return detect, nan_skip, bool(rz.fault_injection)
 
     # ------------------------------------------------------------------
     # resilience: preemption + guard actions
@@ -1508,88 +1627,58 @@ class DeepSpeedEngine:
             capture_steps=tl.anomaly_trace_capture_steps,
             trace_dir=self.trace_profiler.trace_dir)
 
-    def _make_quantized_train_step(self):
-        """Compiled step with the int8 chunk-scaled gradient all-reduce
+    def _quantized_step(self, c):
+        """``quantized``: the int8 chunk-scaled gradient all-reduce
         (`runtime/comm/quantized.py`) in place of the fp32 GSPMD mean.
 
         Hybrid structure: gradient compute + quantized exchange run inside
         ``shard_map`` over the ``data`` axis (each rank sees local grads,
-        exactly like the 1-bit path), but the epilogue and optimizer
-        update run OUTSIDE, in GSPMD — so the ZeRO-1/2 sharded master
-        update (and its param-refresh all-gather) composes unchanged, and
-        the wire carries int8 grads + fp32 param refresh only."""
+        exactly like the 1-bit path), but the tail runs OUTSIDE, in GSPMD
+        — so the ZeRO-1/2 sharded update (and its param-refresh
+        all-gather) composes unchanged, and the wire carries int8 grads +
+        fp32 param refresh only."""
         from deepspeed_tpu.runtime.comm.quantized import (
             init_residuals, quantized_allreduce_tree)
 
         cq = self._config.comm_quantization
-        for ax, size in self.mesh.shape.items():
-            assert ax == "data" or size == 1, (
-                f"comm_quantization supports pure data parallelism; mesh "
-                f"axis {ax!r} has size {size}")
         assert getattr(self.loss_fn, "direct_value_and_grad", None) is None \
             and getattr(self.loss_fn, "direct_value_and_grad_local",
                         None) is None, (
             "comm_quantization needs jax.grad-able loss_fn (the pipeline's "
             "direct value-and-grad runs its own data-plane reduction)")
 
-        accum = self._engine_accum_steps()
-        compute_dtype = self.compute_dtype
-        fp16 = self._config.fp16_enabled
-        clip = float(self._config.gradient_clipping or 0.0)
-        lr_fn = self._lr_fn
-        mom_fn = self._mom_fn
-        opt_update = self._opt_update
-        loss_fn = self.loss_fn
-        scale_args = self._scale_args()
-        dynamic = self.dynamic_loss_scale
-        static_scale = self.static_loss_scale
         chunk_size = int(cq.chunk_size)
         bucket_bytes = int(cq.bucket_mb) * 1024 * 1024
         ef = bool(cq.error_feedback)
-        world = self.dp_world_size
-        grad_shardings = self._shardings["grad"] if \
-            self.zero_optimization_stage() >= 2 else None
-        param_shardings = self._shardings["param"]
-        opt_shardings = self._shardings["opt"]
-        grad_constrain = (lambda g: constrain_tree(g, grad_shardings)) \
-            if grad_shardings is not None else None
-        accumulate = make_grad_accumulator(loss_fn, compute_dtype, accum)
-        pld_fn = self._pld_theta_fn()
-        detect, nan_skip, fault_on = self._nan_guard_flags()
-        if fault_on:
-            log_dist("fault_injection: the quantized step does not take "
-                     "the grad_fault argument; NaN-grad injection is inert "
-                     "on this path", ranks=[0])
+        accumulate = make_grad_accumulator(self.loss_fn, c.compute_dtype,
+                                           c.accum)
+        layouts = self._gspmd_layouts()
 
+        P = PartitionSpec
+        rep = P()
         if ef and self._qcomm_residuals is None:
-            res = init_residuals(self.params, world, bucket_bytes,
-                                 chunk_size)
-            row = NamedSharding(self.mesh, PartitionSpec("data", None))
+            res = init_residuals(self.params, self.dp_world_size,
+                                 bucket_bytes, chunk_size)
+            row = NamedSharding(self.mesh, P("data", None))
             self._qcomm_residuals = jax.device_put(res, jax.tree_util.
                                                    tree_map(lambda _: row,
                                                             res))
-        n_buckets = len(self._qcomm_residuals["worker"]) if ef else 0
 
         def sync_local(params, dstate, batch, rng, residuals):
             """shard_map body: local grads → unscale → overflow vote →
             bucketed int8 exchange. Returns replicated (loss, grads,
             overflow) + this rank's new residual rows."""
-            scale = dstate.loss_scale.cur_scale if (fp16 and dynamic) \
-                else jnp.asarray(static_scale, jnp.float32)
-            rng = jax.random.fold_in(rng, jax.lax.axis_index("data"))
-            loss_kw = {"pld_theta": pld_fn(dstate.global_step)} \
-                if pld_fn is not None else None
-            loss_sum, grads, _ = accumulate(params, batch, rng, scale,
-                                            loss_kw)
+            scale, loss_sum, grads, _, _ = _step_head(
+                c, accumulate, params, dstate, batch, rng, manual=True)
 
             # Unscale BEFORE the exchange (the GSPMD path unscales after
             # its allreduce): absmax quantization scales must be computed
             # on finite values, and EF residuals must not depend on the
             # running loss scale.
-            denom = scale * accum
+            denom = scale * c.accum
             grads = jax.tree_util.tree_map(
                 lambda g: g.astype(jnp.float32) / denom, grads)
-            if fp16:
+            if c.fp16:
                 # Overflow is voted on LOCAL grads pre-quantization — an
                 # inf/nan absmax poisons the int8 encoding (inf/inf = nan),
                 # so overflowed steps ship zeros and are skipped anyway.
@@ -1614,82 +1703,33 @@ class DeepSpeedEngine:
                            "server": [s[None] for s in new_r["server"]]}
             return loss_sum, avg, overflow, res_out
 
-        P = PartitionSpec
-        rep = P()
-        param_specs = jax.tree_util.tree_map(lambda _: rep, self.params)
-        dstate_specs = jax.tree_util.tree_map(lambda _: rep,
-                                              self.device_state)
-        grad_specs = param_specs
-        res_specs = {"worker": [P("data", None)] * n_buckets,
-                     "server": [P("data", None)] * n_buckets} if ef else rep
-        res_out_specs = res_specs if ef else rep
-        synced = shard_map(
-            sync_local, mesh=self.mesh,
-            in_specs=(param_specs, dstate_specs, P(None, "data"), rep,
-                      res_specs),
-            out_specs=(rep, grad_specs, rep, res_out_specs),
-            check_vma=False)
+        res_specs = P("data", None) if ef else rep
+        synced = self._over_data(
+            "comm_quantization", sync_local,
+            in_specs=(rep, rep, _BATCH_ROWS, rep, res_specs),
+            out_specs=(rep, rep, rep, res_specs))
 
         def train_step(params, opt_state, dstate, batch, rng, lr_in,
                        residuals):
             loss_sum, grads, voted, new_res = synced(params, dstate, batch,
                                                      rng, residuals)
-            # GSPMD epilogue on the replicated, already-averaged gradient:
-            # scale/accum are 1 (the shard_map body unscaled), the vote ORs
-            # in the pre-quantization cross-rank overflow.
-            grads, overflow, nonfinite, grad_norm, applied_norm = \
-                grad_epilogue(
-                    grads, jnp.asarray(1.0, jnp.float32), 1, fp16, clip,
-                    constrain=grad_constrain, vote=lambda o: o | voted,
-                    detect_nonfinite=detect, nan_skip=nan_skip)
-
-            lr = lr_fn(dstate.global_step) if lr_fn is not None else lr_in
-            beta1 = mom_fn(dstate.global_step)
-            new_params, new_opt = opt_update(params, grads, opt_state, lr,
-                                             beta1)
-
-            def select(old, new):
-                return jax.tree_util.tree_map(
-                    lambda o, n: jnp.where(overflow, o, n), old, new)
-            params_out = constrain_tree(select(params, new_params),
-                                        param_shardings)
-            opt_out = type(opt_state)(
-                m=constrain_tree(select(opt_state.m, new_opt.m),
-                                 opt_shardings),
-                v=constrain_tree(select(opt_state.v, new_opt.v),
-                                 opt_shardings),
-                step=jnp.where(overflow, opt_state.step, new_opt.step))
-
-            dstate_out = loss_scale_epilogue(dstate, overflow, fp16,
-                                             dynamic, scale_args)
-            scale = dstate.loss_scale.cur_scale if (fp16 and dynamic) \
-                else jnp.asarray(static_scale, jnp.float32)
-            metrics = step_metrics(loss_sum, accum, grad_norm, applied_norm,
-                                   lr, scale, overflow, dstate=dstate_out,
-                                   nonfinite=nonfinite)
-            return params_out, opt_out, dstate_out, metrics, new_res
+            # The tail on the replicated, already-averaged gradient: the
+            # vote ORs in the pre-quantization cross-rank overflow.
+            return (*_step_tail(
+                c, params, opt_state, dstate, lr_in, _loss_scale(c, dstate),
+                loss_sum, grads, unscaled=True, vote=lambda o: o | voted,
+                **layouts), new_res)
 
         if not ef:
             # Signature-compatible with the dense step: residuals pinned
             # to None so jit sees the same 6 logical inputs.
             def train_step_no_res(params, opt_state, dstate, batch, rng,
                                   lr_in):
-                out = train_step(params, opt_state, dstate, batch, rng,
-                                 lr_in, None)
-                return out[0], out[1], out[2], out[3]
+                return train_step(params, opt_state, dstate, batch, rng,
+                                  lr_in, None)[:4]
             return donated_jit(train_step_no_res, (0, 1, 2))
-
-        inner = donated_jit(train_step, (0, 1, 2, 6))
-        engine = self
-
-        def compiled(params, opt_state, dstate, batch, rng, lr_in):
-            params, opt_state, dstate, metrics, engine._qcomm_residuals = \
-                inner(params, opt_state, dstate, batch, rng, lr_in,
-                      engine._qcomm_residuals)
-            return params, opt_state, dstate, metrics
-
-        compiled.inner = inner
-        return compiled
+        return self._persisting(donated_jit(train_step, (0, 1, 2, 6)),
+                                "_qcomm_residuals")
 
     def _upload_offload_params(self):
         """Device copy of the host fp32 masters at compute dtype (init /
@@ -1711,29 +1751,19 @@ class DeepSpeedEngine:
                 else v.astype(self.compute_dtype), opt.params())
         return jax.device_put(tree, self._shardings["param"])
 
-    def _make_offload_grad_step(self):
-        """Compiled gradient-only step for ZeRO-Offload: loss/grads/
-        overflow/clip/loss-scale on device, the optimizer update on the
-        host C++ Adam (reference stage2.py:1410-1423)."""
-        accum = self._engine_accum_steps()
-        fp16 = self._config.fp16_enabled
-        clip = float(self._config.gradient_clipping or 0.0)
-        lr_fn = self._lr_fn
-        mom_fn = self._mom_fn
-        loss_fn = self.loss_fn
-        scale_args = self._scale_args()
-        dynamic = self.dynamic_loss_scale
-        static_scale = self.static_loss_scale
-        compute_dtype = self.compute_dtype
+    def _offload_step(self, c):
+        """``offload``: the gradient-only step of ZeRO-Offload:
+        loss/grads/overflow/clip/loss-scale on device, the optimizer
+        update on the host C++ Adam (reference stage2.py:1410-1423)."""
         # bf16 only: it shares fp32's exponent range, so casting the
         # UNSCALED gradient is safe. fp16 would flush components under
         # ~6e-5 to zero/subnormal — the reference avoids this by moving
         # still-scaled fp16 grads (stage2.py:793); our epilogue unscales
         # on device, so fp16 transfer would defeat loss scaling.
         grads_16bit = (self._config.zero_config.offload_16bit_grads and
-                       compute_dtype == jnp.bfloat16)
-        accumulate = make_grad_accumulator(loss_fn, compute_dtype, accum)
-        pld_fn = self._pld_theta_fn()
+                       c.compute_dtype == jnp.bfloat16)
+        accumulate = make_grad_accumulator(self.loss_fn, c.compute_dtype,
+                                           c.accum)
         # Offload×DP: emit the gradient as a flat [D, chunk] array sharded
         # over the data axis — each process D2H-pulls only its shard (1/D
         # of the wire), the stage-2 partition the reference implements
@@ -1741,42 +1771,25 @@ class DeepSpeedEngine:
         flat_dp = (self._off_D, self._off_chunk) if self._offload_dp \
             else None
         mesh = self.mesh
-        detect, nan_skip, fault_on = self._nan_guard_flags()
-        self._fault_arg = fault_on
 
         def grad_step(params, dstate, batch, rng, lr_in, grad_fault=None):
-            scale = dstate.loss_scale.cur_scale if (fp16 and dynamic) \
-                else jnp.asarray(static_scale, jnp.float32)
-            loss_kw = {"pld_theta": pld_fn(dstate.global_step)} \
-                if pld_fn is not None else None
-            loss_sum, grads, _ = accumulate(params, batch, rng, scale,
-                                            loss_kw)
-            if fault_on:
-                grads = jax.tree_util.tree_map(lambda g: g * grad_fault,
-                                               grads)
+            scale, loss_sum, grads, _, _ = _step_head(
+                c, accumulate, params, dstate, batch, rng,
+                grad_fault=grad_fault)
             # No ZeRO grad-sharding constraint on the TREE: single-process
             # offload fetches the full gradient to host RAM; offload×DP
             # instead reshards the FLAT gradient to [D, chunk] over the
             # data axis below (flat_dp) so each process pulls only its
             # 1/D shard — the stage-2 partition, applied post-epilogue.
-            grads, overflow, nonfinite, grad_norm, applied_norm = \
-                grad_epilogue(grads, scale, accum, fp16, clip,
-                              detect_nonfinite=detect, nan_skip=nan_skip)
+            grads, _, dstate_out, metrics = _step_tail(
+                c, params, None, dstate, lr_in, scale, loss_sum, grads)
             if grads_16bit:
                 # Reference parity: stage-2 offload moves fp16 grads to
                 # pinned host memory (stage2.py:793) — 16-bit halves the
                 # D2H wire; the host C++ Adam widens to fp32 during its
                 # existing copy into the flat grad buffer (no extra pass).
                 grads = jax.tree_util.tree_map(
-                    lambda g: g.astype(compute_dtype), grads)
-            lr = lr_fn(dstate.global_step) if lr_fn is not None else lr_in
-            beta1 = mom_fn(dstate.global_step)
-            dstate_out = loss_scale_epilogue(dstate, overflow, fp16, dynamic,
-                                             scale_args)
-            metrics = step_metrics(loss_sum, accum, grad_norm, applied_norm,
-                                   lr, scale, overflow, dstate=dstate_out,
-                                   nonfinite=nonfinite)
-            metrics["beta1"] = beta1
+                    lambda g: g.astype(c.compute_dtype), grads)
             if flat_dp is not None:
                 D, chunk = flat_dp
                 leaves = jax.tree_util.tree_leaves(grads)
@@ -2058,8 +2071,8 @@ class DeepSpeedEngine:
                 ][:16])
         return flags
 
-    def _make_sparse_grad_train_step(self):
-        """Compiled step with CSR sparse embedding-gradient communication
+    def _sparse_step(self, c):
+        """``sparse``: CSR sparse embedding-gradient communication
         (reference `runtime/engine.py:177-183` auto-conversion and
         `engine.py:1157-1213` sparse allreduce).
 
@@ -2082,42 +2095,17 @@ class DeepSpeedEngine:
         from deepspeed_tpu.runtime.csr_tensor import (csr_allreduce,
                                                       dense_to_csr)
 
-        for ax, size in self.mesh.shape.items():
-            assert ax == "data" or size == 1, (
-                f"sparse_gradients supports pure data parallelism; mesh "
-                f"axis {ax!r} has size {size}")
         assert self.zero_optimization_stage() == 0, (
             "sparse_gradients is incompatible with ZeRO (the reference's "
             "CSR path is the non-ZeRO allreduce fallback, engine.py:1127)")
 
-        accum = self._engine_accum_steps()
-        compute_dtype = self.compute_dtype
-        fp16 = self._config.fp16_enabled
-        clip = float(self._config.gradient_clipping or 0.0)
-        lr_fn = self._lr_fn
-        mom_fn = self._mom_fn
-        opt_update = self._opt_update
-        loss_fn = self.loss_fn
-        scale_args = self._scale_args()
-        dynamic = self.dynamic_loss_scale
-        static_scale = self.static_loss_scale
-        accumulate = make_grad_accumulator(loss_fn, compute_dtype, accum)
+        accumulate = make_grad_accumulator(self.loss_fn, c.compute_dtype,
+                                           c.accum)
         sparse_flags = self._sparse_grad_flags()
-        pld_fn = self._pld_theta_fn()
-        detect, nan_skip, fault_on = self._nan_guard_flags()
-        if fault_on:
-            log_dist("fault_injection: the sparse-grad step does not take "
-                     "the grad_fault argument; NaN-grad injection is inert "
-                     "on this path", ranks=[0])
 
         def step_local(params, opt_state, dstate, batch, rng, lr_in):
-            scale = dstate.loss_scale.cur_scale if (fp16 and dynamic) \
-                else jnp.asarray(static_scale, jnp.float32)
-            rng = jax.random.fold_in(rng, jax.lax.axis_index("data"))
-            loss_kw = {"pld_theta": pld_fn(dstate.global_step)} \
-                if pld_fn is not None else None
-            loss_sum, grads, _ = accumulate(params, batch, rng, scale,
-                                            loss_kw)
+            scale, loss_sum, grads, _, _ = _step_head(
+                c, accumulate, params, dstate, batch, rng, manual=True)
 
             # Static token budget: rows touched locally per boundary is
             # bounded by the number of id elements in the local batch.
@@ -2163,166 +2151,58 @@ class DeepSpeedEngine:
 
             # Grads are now replicated-global, so no cross-shard vote or
             # norm reduction is needed past this point.
-            grads, overflow, nonfinite, grad_norm, applied_norm = \
-                grad_epilogue(grads, scale, accum, fp16, clip,
-                              detect_nonfinite=detect, nan_skip=nan_skip)
+            return _step_tail(
+                c, params, opt_state, dstate, lr_in, scale, loss_sum, grads,
+                loss_reduce=lambda l: jax.lax.pmean(l, "data"),
+                extra_metrics={"sparse_grad_dropped": dropped,
+                               "sparse_grad_dense_fallbacks": fallbacks})
 
-            lr = lr_fn(dstate.global_step) if lr_fn is not None else lr_in
-            beta1 = mom_fn(dstate.global_step)
-            new_params, new_opt = opt_update(params, grads, opt_state, lr,
-                                             beta1)
+        rep = PartitionSpec()
+        return donated_jit(self._over_data(
+            "sparse_gradients", step_local,
+            in_specs=(rep, rep, rep, _BATCH_ROWS, rep, rep),
+            out_specs=rep), (0, 1, 2))
 
-            def select(old, new):
-                return jax.tree_util.tree_map(
-                    lambda o, n: jnp.where(overflow, o, n), old, new)
-            params_out = select(params, new_params)
-            opt_out = type(opt_state)(
-                m=select(opt_state.m, new_opt.m),
-                v=select(opt_state.v, new_opt.v),
-                step=jnp.where(overflow, opt_state.step, new_opt.step))
-
-            dstate_out = loss_scale_epilogue(dstate, overflow, fp16, dynamic,
-                                             scale_args)
-            metrics = step_metrics(
-                loss_sum, accum, grad_norm, applied_norm, lr, scale,
-                overflow, loss_reduce=lambda l: jax.lax.pmean(l, "data"),
-                dstate=dstate_out, nonfinite=nonfinite)
-            metrics["sparse_grad_dropped"] = dropped
-            metrics["sparse_grad_dense_fallbacks"] = fallbacks
-            return params_out, opt_out, dstate_out, metrics
-
-        P = PartitionSpec
-        rep = P()
-        param_specs = jax.tree_util.tree_map(lambda _: rep, self.params)
-        opt_specs = type(self.opt_state)(
-            m=jax.tree_util.tree_map(lambda _: rep, self.opt_state.m),
-            v=jax.tree_util.tree_map(lambda _: rep, self.opt_state.v),
-            step=rep)
-        dstate_specs = jax.tree_util.tree_map(lambda _: rep,
-                                              self.device_state)
-        metrics_specs = {k: rep for k in ("loss", "grad_norm",
-                                          "applied_grad_norm", "lr",
-                                          "loss_scale", "overflow",
-                                          "skipped_steps",
-                                          "consecutive_skipped_steps",
-                                          "grad_nonfinite",
-                                          "sparse_grad_dropped",
-                                          "sparse_grad_dense_fallbacks")}
-        mapped = shard_map(
-            step_local, mesh=self.mesh,
-            in_specs=(param_specs, opt_specs, dstate_specs, P(None, "data"),
-                      rep, rep),
-            out_specs=(param_specs, opt_specs, dstate_specs, metrics_specs),
-            check_vma=False)
-        return donated_jit(mapped, (0, 1, 2))
-
-    def _make_onebit_train_step(self):
-        """Compiled 1-bit Adam step: shard_map over the ``data`` axis so
-        each shard sees *local* gradients, which the optimizer averages
-        itself — densely (pmean) during warmup, with the 1-bit
-        error-feedback collective after ``freeze_step`` (the analog of the
-        reference disabling engine allreduce at onebit_adam.py:372 and
-        running its MPI data plane)."""
+    def _onebit_step(self, c):
+        """``onebit``: 1-bit Adam. Each shard keeps its *local*
+        gradients, which the optimizer averages itself — densely (pmean)
+        during warmup, with the 1-bit error-feedback collective after
+        ``freeze_step`` (the analog of the reference disabling engine
+        allreduce at onebit_adam.py:372 and running its MPI data
+        plane)."""
         from deepspeed_tpu.runtime.fp16.onebit_adam import OnebitAdamState
 
-        for ax, size in self.mesh.shape.items():
-            assert ax == "data" or size == 1, (
-                f"OneBitAdam supports pure data parallelism; mesh axis "
-                f"{ax!r} has size {size}")
-
-        accum = self._engine_accum_steps()
-        compute_dtype = self.compute_dtype
-        fp16 = self._config.fp16_enabled
-        clip = float(self._config.gradient_clipping or 0.0)
-        lr_fn = self._lr_fn
-        mom_fn = self._mom_fn
-        opt_update = self._opt_update
-        loss_fn = self.loss_fn
-        scale_args = self._scale_args()
-        dynamic = self.dynamic_loss_scale
-        static_scale = self.static_loss_scale
-        accumulate = make_grad_accumulator(loss_fn, compute_dtype, accum)
-        pld_fn = self._pld_theta_fn()
-        detect, nan_skip, fault_on = self._nan_guard_flags()
-        if fault_on:
-            log_dist("fault_injection: the 1-bit Adam step does not take "
-                     "the grad_fault argument; NaN-grad injection is inert "
-                     "on this path", ranks=[0])
+        accumulate = make_grad_accumulator(self.loss_fn, c.compute_dtype,
+                                           c.accum)
 
         def step_local(params, opt_state, dstate, batch, rng, lr_in):
-            scale = dstate.loss_scale.cur_scale if (fp16 and dynamic) \
-                else jnp.asarray(static_scale, jnp.float32)
-            rng = jax.random.fold_in(rng, jax.lax.axis_index("data"))
-            loss_kw = {"pld_theta": pld_fn(dstate.global_step)} \
-                if pld_fn is not None else None
-            loss_sum, grads, _ = accumulate(params, batch, rng, scale,
-                                            loss_kw)
-
+            scale, loss_sum, grads, _, _ = _step_head(
+                c, accumulate, params, dstate, batch, rng, manual=True)
             # Cross-shard overflow vote (reference stage2.py:1527-1551);
             # norms are pmean'd local-shard diagnostics (a true global norm
             # would need the dense allreduce this optimizer avoids), and
             # clipping scales by the pmax norm so every shard applies the
             # same (conservative, rank-consistent) factor.
-            grads, overflow, nonfinite, grad_norm, applied_norm = \
-                grad_epilogue(
-                    grads, scale, accum, fp16, clip,
-                    vote=lambda o: jax.lax.pmax(
-                        o.astype(jnp.int32), "data") > 0,
-                    norm_reduce=lambda n: jax.lax.pmean(n, "data"),
-                    clip_norm_reduce=lambda n: jax.lax.pmax(n, "data"),
-                    detect_nonfinite=detect, nan_skip=nan_skip)
-
-            lr = lr_fn(dstate.global_step) if lr_fn is not None else lr_in
-            beta1 = mom_fn(dstate.global_step)
-            new_params, new_opt = opt_update(params, grads, opt_state, lr,
-                                             beta1)
-
-            def select(old, new):
-                return jax.tree_util.tree_map(
-                    lambda o, n: jnp.where(overflow, o, n), old, new)
-            params_out = select(params, new_params)
-            opt_out = OnebitAdamState(
-                m=select(opt_state.m, new_opt.m),
-                v=select(opt_state.v, new_opt.v),
-                step=jnp.where(overflow, opt_state.step, new_opt.step),
-                worker_error=select(opt_state.worker_error,
-                                    new_opt.worker_error),
-                server_error=select(opt_state.server_error,
-                                    new_opt.server_error))
-
-            dstate_out = loss_scale_epilogue(dstate, overflow, fp16, dynamic,
-                                             scale_args)
-            metrics = step_metrics(
-                loss_sum, accum, grad_norm, applied_norm, lr, scale,
-                overflow, loss_reduce=lambda l: jax.lax.pmean(l, "data"),
-                dstate=dstate_out, nonfinite=nonfinite)
-            return params_out, opt_out, dstate_out, metrics
+            return _step_tail(
+                c, params, opt_state, dstate, lr_in, scale, loss_sum, grads,
+                vote=lambda o: jax.lax.pmax(
+                    o.astype(jnp.int32), "data") > 0,
+                norm_reduce=lambda n: jax.lax.pmean(n, "data"),
+                clip_norm_reduce=lambda n: jax.lax.pmax(n, "data"),
+                loss_reduce=lambda l: jax.lax.pmean(l, "data"))
 
         P = PartitionSpec
         rep = P()
         opt_specs = OnebitAdamState(
-            m=jax.tree_util.tree_map(lambda _: rep, self.opt_state.m),
-            v=jax.tree_util.tree_map(lambda _: rep, self.opt_state.v),
-            step=rep, worker_error=P("data", None), server_error=P("data"))
-        param_specs = jax.tree_util.tree_map(lambda _: rep, self.params)
-        dstate_specs = jax.tree_util.tree_map(lambda _: rep,
-                                              self.device_state)
-        metrics_specs = {k: rep for k in ("loss", "grad_norm",
-                                          "applied_grad_norm", "lr",
-                                          "loss_scale", "overflow",
-                                          "skipped_steps",
-                                          "consecutive_skipped_steps",
-                                          "grad_nonfinite")}
-        mapped = shard_map(
-            step_local, mesh=self.mesh,
-            in_specs=(param_specs, opt_specs, dstate_specs, P(None, "data"),
-                      rep, rep),
-            out_specs=(param_specs, opt_specs, dstate_specs, metrics_specs),
-            check_vma=False)
-        return donated_jit(mapped, (0, 1, 2))
+            m=rep, v=rep, step=rep, worker_error=P("data", None),
+            server_error=P("data"))
+        return donated_jit(self._over_data(
+            "OneBitAdam", step_local,
+            in_specs=(rep, opt_specs, rep, _BATCH_ROWS, rep, rep),
+            out_specs=(rep, opt_specs, rep, rep)), (0, 1, 2))
 
-    def _make_pipeline_onebit_train_step(self):
-        """Compiled step for the pipeline x 1-bit Adam composition
+    def _pipeline_onebit_step(self, c):
+        """``pipeline x onebit``: the pipeline x 1-bit Adam composition
         (BASELINE config 5; beyond the reference, whose OnebitAdam rides
         the fp16-optimizer path only): the 1F1B program runs with
         ``data_local=True`` — its dense psum over ``data`` is skipped and
@@ -2348,22 +2228,10 @@ class DeepSpeedEngine:
                 f"pipeline OneBitAdam supports pipe x model x data meshes; "
                 f"axis {ax!r} has size {size}")
         direct_local = self.loss_fn.direct_value_and_grad_local
-        fp16 = self._config.fp16_enabled
-        clip = float(self._config.gradient_clipping or 0.0)
-        lr_fn = self._lr_fn
-        mom_fn = self._mom_fn
-        opt_update = self._opt_update
-        scale_args = self._scale_args()
-        dynamic = self.dynamic_loss_scale
-        static_scale = self.static_loss_scale
+        fp16, clip, opt_update = c.fp16, c.clip, c.opt_update
         mesh = self.mesh
         model_size = mesh.shape.get("model", 1)
         tree_map = jax.tree_util.tree_map
-        detect, nan_skip, fault_on = self._nan_guard_flags()
-        if fault_on:
-            log_dist("fault_injection: the pipeline 1-bit step does not "
-                     "take the grad_fault argument; NaN-grad injection is "
-                     "inert on this path", ranks=[0])
 
         P = PartitionSpec
         param_specs = tree_map(lambda ns: ns.spec, self._shardings["param"])
@@ -2506,17 +2374,17 @@ class DeepSpeedEngine:
             check_vma=False)
 
         def train_step(params, opt_state, dstate, batch, rng, lr_in):
-            scale = dstate.loss_scale.cur_scale if (fp16 and dynamic) \
-                else jnp.asarray(static_scale, jnp.float32)
+            scale = _loss_scale(c, dstate)
             micro = tree_map(lambda x: x[0], batch)   # accum dim == 1
             loss, grads = direct_local(params, micro, rng, scale)
 
             # Unscale + overflow + clip on the STACKED (data-local) grads
             # — reductions only, never a dense cross-data averaging.
             grads = tree_map(lambda g: g.astype(jnp.float32) / scale, grads)
-            nonfinite = check_overflow(grads) if (fp16 or detect) \
+            nonfinite = check_overflow(grads) if (fp16 or c.detect) \
                 else jnp.asarray(False)
-            overflow = nonfinite if (fp16 or nan_skip) else jnp.asarray(False)
+            overflow = nonfinite if (fp16 or c.nan_skip) \
+                else jnp.asarray(False)
             # Per-data-slice norms: sum of squares over every dim but the
             # stacked axis; identical on all ranks, so clipping by the max
             # slice norm is rank-consistent (the DP onebit's pmax analog).
@@ -2534,8 +2402,9 @@ class DeepSpeedEngine:
                 grads = tree_map(lambda g: g * factor, grads)
                 applied_norm = grad_norm * factor
 
-            lr = lr_fn(dstate.global_step) if lr_fn is not None else lr_in
-            beta1 = mom_fn(dstate.global_step)
+            lr = c.lr_fn(dstate.global_step) if c.lr_fn is not None \
+                else lr_in
+            beta1 = c.mom_fn(dstate.global_step)
             new_params, new_m, new_v, new_we, new_se, new_step = mapped_upd(
                 params, grads, opt_state.m, opt_state.v,
                 opt_state.worker_error, opt_state.server_error,
@@ -2544,7 +2413,7 @@ class DeepSpeedEngine:
                                       worker_error=new_we,
                                       server_error=new_se)
             dstate_out = loss_scale_epilogue(dstate, overflow, fp16,
-                                             dynamic, scale_args)
+                                             c.dynamic, c.scale_args)
             metrics = step_metrics(loss, 1, grad_norm, applied_norm, lr,
                                    scale, overflow, dstate=dstate_out,
                                    nonfinite=nonfinite)
@@ -2757,8 +2626,7 @@ class DeepSpeedEngine:
                 batch = next(self._data_iter)
         first_compile = self._compiled_train_step is None
         if first_compile:
-            self._compiled_train_step = self._make_offload_grad_step() \
-                if self._offload else self._make_train_step()
+            self._compiled_train_step = self._make_train_step()
         if tele is not None and self._batch_tokens is None:
             # Rows x second dim of the first leaf: tokens for LM batches
             # ([rows, seq] ids), rows x features otherwise — consistent

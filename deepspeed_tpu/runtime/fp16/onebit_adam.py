@@ -61,7 +61,7 @@ def pipeline_mp_mask(params, model):
     """Per-leaf bools in ``tree_leaves(params['body'])`` order: True for
     model-sharded ``mp_*`` leaves. The single source of truth for the 3D
     1-bit layout — both the error-buffer sizing here and the engine's
-    group split (`engine.py:_make_pipeline_onebit_train_step`) consume
+    group split (`engine.py:_pipeline_onebit_step`) consume
     it, so the slice offsets cannot drift from the group sizes."""
     from deepspeed_tpu.runtime.pipe.pipeline import _is_mp_leaf
     return [model > 1 and _is_mp_leaf(path, leaf)
@@ -92,7 +92,7 @@ def _pipeline_local_sizes(params, num_stages, model=1):
 def init_pipeline_onebit_state(params, world: int, num_stages: int,
                                model: int = 1) -> OnebitAdamState:
     """State for the pipeline x 1-bit composition
-    (`engine.py:_make_pipeline_onebit_train_step`): m/v mirror the
+    (`engine.py:_pipeline_onebit_step`): m/v mirror the
     (stacked, pipe-sharded) params; error-feedback buffers are per
     (stage[, model-rank], data-rank) over the device-LOCAL flat parameter
     count — every device runs its own compressed collective over ``data``

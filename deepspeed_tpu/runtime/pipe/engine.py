@@ -163,7 +163,7 @@ class PipelineEngine(DeepSpeedEngine):
             overlap=overlap, fp8=fp8_plan)
         # 1-bit Adam composition: same 1F1B program, but gradients come
         # back data-LOCAL (stacked data axis) for the compressed
-        # collective to average (engine._make_pipeline_onebit_train_step).
+        # collective to average (engine._pipeline_onebit_step).
         loss_fn.direct_value_and_grad_local = make_pipeline_value_and_grad_fn(
             self.pipeline_parts, mesh, self.micro_batches,
             compute_dtype=compute_dtype, data_local=True,

@@ -7,8 +7,7 @@ traces for xprof/tensorboard, synchronized host timers, and per-step
 device-time deltas. This module supplies the trace capture and the
 per-step record; `telemetry/timers.py` supplies the synchronized timers.
 
-Moved here from ``deepspeed_tpu/utils/profiler.py`` (now a deprecation
-shim): the trace window is the device half of the telemetry story — the
+The trace window is the device half of the telemetry story — the
 step-phase spans (`telemetry/spans.py`) emit ``TraceAnnotation``s, so a
 trace captured by this window shows the host phases as named ranges on
 the xplane timeline.

@@ -7,10 +7,8 @@ clock, we block on outstanding async XLA dispatch with
 ``jax.effects_barrier()`` (device work in JAX is async-dispatched; the barrier
 is the TPU-correct way to make host wall-clock measurements meaningful).
 
-Moved here from ``deepspeed_tpu/utils/timer.py`` (now a deprecation
-shim) as part of the unified telemetry package — these are the
-*synchronized* timers behind ``wall_clock_breakdown``; the un-synchronized
-per-phase spans live in `telemetry/spans.py`.
+These are the *synchronized* timers behind ``wall_clock_breakdown``; the
+un-synchronized per-phase spans live in `telemetry/spans.py`.
 """
 
 import time

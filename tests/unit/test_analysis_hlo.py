@@ -1,9 +1,8 @@
 """Unit tests for the HLO parser core (`deepspeed_tpu/analysis/hlo.py`).
 
-The old `utils/hlo_analysis.py` counted every collective ONCE even when
-it sat inside a ``while``/``scan`` body (the documented LIMITATION);
-`analysis/hlo.py` fixes that with trip-count-aware accounting. These
-tests pin the fix against a *real* lowered scan-with-psum program plus
+A flat count takes every collective ONCE even when it sits inside a
+``while``/``scan`` body; `analysis/hlo.py` accounts trip-count-aware.
+These tests pin that against a *real* lowered scan-with-psum program plus
 synthetic HLO for the formats jax's CPU lowering doesn't emit (fp8
 dtypes, ``backend_config`` trip counts, infeed/outfeed).
 """

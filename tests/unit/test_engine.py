@@ -18,6 +18,7 @@ from tests.unit.simple_model import (
     simple_init_params,
     simple_loss_fn,
 )
+from tests.unit.test_csr import _embed_loss, _embed_params
 
 
 def make_engine(config, seed=0, **kw):
@@ -255,3 +256,87 @@ def test_static_loss_scale_invariance_validates_prescale_noop():
     pre = curve(2.0 ** 14, prescale=True)
     np.testing.assert_allclose(big, base, rtol=1e-6)
     np.testing.assert_allclose(pre, big, rtol=0)
+
+
+# ---------------------------------------------------------------------------
+# the step kinds share one tail (`runtime/engine.py:_step_tail`)
+# ---------------------------------------------------------------------------
+
+STEP_METRICS = {"loss", "grad_norm", "applied_grad_norm", "lr", "loss_scale",
+                "overflow", "skipped_steps", "consecutive_skipped_steps",
+                "grad_nonfinite"}
+# kind -> (what the config adds, the metrics that are the kind's own)
+STEP_KINDS = {
+    "dense": ({}, set()),
+    "quantized": ({"comm_quantization": {"enabled": True, "chunk_size": 64,
+                                         "error_feedback": True}}, set()),
+    "sparse": ({"sparse_gradients": True},
+               {"sparse_grad_dropped", "sparse_grad_dense_fallbacks"}),
+    "onebit": ({"optimizer": {"type": "OneBitAdam",
+                              "params": {"lr": 1e-2, "freeze_step": 1}}},
+               set()),
+    "offload": ({"zero_optimization": {"stage": 2, "cpu_offload": True}},
+                {"beta1"}),
+}
+
+
+def _weighted_embed_loss(params, batch, rng=None):
+    """`test_csr.py`'s toy loss times the mean of the batch's weight
+    ``w``, which is how a batch overflows fp16."""
+    return _embed_loss(params, batch, rng) * jnp.mean(batch["w"])
+
+
+def _kind_engine(kind):
+    cfg = base_config(fp16={"enabled": True, "initial_scale_power": 4,
+                            "hysteresis": 1}, gradient_clipping=1.0)
+    cfg.update(STEP_KINDS[kind][0])
+    engine, _, _, _ = deepspeed_tpu.initialize(
+        config=cfg, loss_fn=_weighted_embed_loss,
+        params=_embed_params(jax.random.PRNGKey(0)))
+    assert engine._step_kind() == kind
+    return engine
+
+
+def _kind_batch(weight=1.0):
+    rng = np.random.default_rng(0)
+    return {"ids": rng.integers(0, 64, size=(16, 8)).astype(np.int32),
+            "label": rng.integers(0, 64, size=(16,)).astype(np.int32),
+            "w": np.full((16,), weight, np.float32)}
+
+
+def _kind_state(engine):
+    """Parameters and every field of the optimizer's state, as numpy."""
+    opt = engine.cpu_optimizer
+    state = engine.opt_state._asdict() if opt is None else {
+        "master": opt.master.copy(), "m": opt.exp_avg.copy(),
+        "v": opt.exp_avg_sq.copy(), "step": opt._step}
+    return jax.tree_util.tree_map(np.asarray, (engine.params, state))
+
+
+@pytest.mark.parametrize("kind", sorted(STEP_KINDS))
+def test_step_metrics_are_the_shared_keys_and_the_kinds_own(kind):
+    engine = _kind_engine(kind)
+    engine.train_batch(_kind_batch())
+    assert set(engine.step_metrics) == STEP_METRICS | STEP_KINDS[kind][1]
+    assert not bool(engine.step_metrics["overflow"])
+
+
+@pytest.mark.parametrize("kind", sorted(STEP_KINDS))
+def test_overflowed_step_keeps_params_and_every_optimizer_field(kind):
+    engine = _kind_engine(kind)
+    engine.train_batch(_kind_batch())        # moments and residuals move
+    before = _kind_state(engine)
+    engine.train_batch(_kind_batch(weight=1e30))
+    assert bool(engine.step_metrics["overflow"])
+    after = _kind_state(engine)
+    assert jax.tree_util.tree_structure(before) == \
+        jax.tree_util.tree_structure(after)
+    for path, leaf in jax.tree_util.tree_leaves_with_path(before):
+        other = dict(jax.tree_util.tree_leaves_with_path(after))[path]
+        np.testing.assert_array_equal(leaf, other, err_msg=str(path))
+    assert engine.skipped_steps == 1
+    assert engine.loss_scale == 2 ** 3       # halved
+    # and the step after it moves them again
+    engine.train_batch(_kind_batch())
+    moved = _kind_state(engine)[0]["head"]["kernel"]
+    assert not np.array_equal(moved, before[0]["head"]["kernel"])

@@ -74,20 +74,3 @@ def test_matches_real_compiled_allreduce():
     # all-reduce, or reduce-scatter+all-gather — either way the summed
     # payload is within 2x of the 512 KB result size.
     assert expected * 0.9 <= cb["total"] <= expected * 2.2, cb
-
-
-def test_compat_shim_reexports_and_warns():
-    """The utils/ shim still works but carries a DeprecationWarning;
-    its callables are the analysis.hlo objects, not copies."""
-    import importlib
-    import sys
-    import warnings
-
-    sys.modules.pop("deepspeed_tpu.utils.hlo_analysis", None)
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        shim = importlib.import_module("deepspeed_tpu.utils.hlo_analysis")
-    assert any(issubclass(w.category, DeprecationWarning) for w in caught), \
-        [str(w.message) for w in caught]
-    assert shim.collective_bytes is collective_bytes
-    assert shim.ring_send_bytes is ring_send_bytes

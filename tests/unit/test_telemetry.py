@@ -462,28 +462,6 @@ def test_disabled_overhead_is_two_ring_spans():
     assert per_iter < 50e-6, f"span path costs {per_iter * 1e6:.1f}us"
 
 
-# ---------------------------------------------------------------------------
-# deprecation shims
-# ---------------------------------------------------------------------------
-
-def test_utils_timer_shim_warns_and_reexports():
-    import importlib
-    import deepspeed_tpu.utils.timer as shim
-    with pytest.warns(DeprecationWarning, match="utils.timer"):
-        shim = importlib.reload(shim)
-    from deepspeed_tpu.telemetry.timers import SynchronizedWallClockTimer
-    assert shim.SynchronizedWallClockTimer is SynchronizedWallClockTimer
-
-
-def test_utils_profiler_shim_warns_and_reexports():
-    import importlib
-    import deepspeed_tpu.utils.profiler as shim
-    with pytest.warns(DeprecationWarning, match="utils.profiler"):
-        shim = importlib.reload(shim)
-    from deepspeed_tpu.telemetry.profiler import TraceProfiler
-    assert shim.TraceProfiler is TraceProfiler
-
-
 def test_session_default_first_wins():
     a, b = TelemetrySession(), TelemetrySession()
     assert set_default_session(a, replace=False) is a

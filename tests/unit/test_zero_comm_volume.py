@@ -3,7 +3,7 @@
 Companion to ``test_zero_memory.py``: the ZeRO paper's headline comm
 claims — stages 1/2 move the same order of traffic as plain DP, stage 3
 costs 1.5x the DP baseline — are compile-time facts under XLA, readable
-off the partitioned HLO (`utils/hlo_analysis.py`). The reference can't
+off the partitioned HLO (`analysis/hlo.py`). The reference can't
 test this at all (NCCL traffic is invisible to torch); here it is pinned.
 
 Measured structure on the 8-device mesh (output-bytes basis, M = fp32
