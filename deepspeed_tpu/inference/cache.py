@@ -75,6 +75,27 @@ What knows per-head vectors refuses such a spec
 576-wide vector is another precision than one a head of 64; not
 measured), a ``model`` mesh axis (one head cannot be split), and
 speculative verify and the tiers, which nothing has run on one.
+
+**Groups of page layers** (ISSUE 47). A model whose page layers are not
+all alike (`models/mimo_v2.py`: full layers with 4 key heads beside
+window layers with 8, keys of 192 over values of 128 in both) lists
+them as :class:`PageGroup` s: each with its layers' names, its key
+heads, its key and value widths and its window (0 = full). A layer's
+``k`` leaf is ``[n_pages, heads, head_dim, page]`` and its ``v`` leaf
+``[n_pages, heads, v_dim, page]``. A full group's pages are the pool
+above. **A window group's rows keep a ring**: ``window // page_size +
+1`` pages a row, from a pool of ``max_batch`` rings (and its own trash
+page 0); position ``p`` lies in ring entry ``(p // page_size) % ring``
+at lane ``p % page_size``, so a row holds what its window can reach
+and the page being written, whatever its length. A row's page table
+is one array: the full groups' ``pages_per_row`` entries, then the
+ring's (``table_width``; :func:`split_table`). What a ring entry holds
+is known by position, never by where it lies: entry ``j`` of a row at
+``p`` holds the largest position ``<= p`` that maps to it, and the mask
+admits it iff that position is inside the window. The models served
+before are one group with equal widths and no window, and their specs,
+trees and programs are what they were. What moves, shares or rolls back
+pages refuses a ring (:class:`WindowRingUnsupported`).
 """
 
 import dataclasses
@@ -113,6 +134,38 @@ class LatentPoolUnsupported(ValueError):
             f"layer, one head): {why}")
 
 
+class WindowRingUnsupported(ValueError):
+    """A serving feature that moves, shares or rolls back pages was
+    asked of a model with a window group, whose rows keep a ring of
+    pages that are overwritten as the row grows. Raised when the engine
+    (or the feature) is built, before anything is traced."""
+
+    def __init__(self, feature, why):
+        self.feature = feature
+        super().__init__(
+            f"{feature} cannot serve a model whose window layers keep a "
+            f"ring of pages: {why}")
+
+
+@dataclasses.dataclass(frozen=True)
+class PageGroup:
+    """Page layers that are alike: their names in the cache tree, their
+    key heads, the widths of a key and of a value, their window (0: a
+    full layer, whose row keeps every page) and their pool's pages
+    (trash page included)."""
+    name: str
+    layers: tuple
+    n_head: int
+    head_dim: int
+    v_dim: int
+    window: int = 0
+    n_pages: int = 0
+
+    def bytes_per_token(self, itemsize):
+        return len(self.layers) * self.n_head * \
+            (self.head_dim + self.v_dim) * itemsize
+
+
 @dataclasses.dataclass(frozen=True)
 class KVCacheSpec:
     """Static shape + storage format of one engine's cache: the page
@@ -137,11 +190,39 @@ class KVCacheSpec:
     # > 0: a latent pool. One leaf a layer (``k``, no ``v``), whose
     # first ``latent_v_dim`` of ``head_dim`` entries are also the value
     latent_v_dim: int = 0
+    # page layers that are not all alike, as PageGroups; () = one group
+    # of ``layers`` with ``n_head`` heads of ``head_dim`` and no window
+    groups: tuple = ()
 
     @property
     def pages_per_row(self):
-        """Page-table width: pages covering one row's max_seq span."""
+        """Pages covering one row's max_seq span: a full group's part
+        of a row's page table, and all of it where no group has a
+        window."""
         return self.max_seq // self.page_size
+
+    @property
+    def page_groups(self):
+        """The spec's groups; of a spec that lists none, its one."""
+        if self.groups:
+            return self.groups
+        names = self.layers or (("h",) if self.stacked else tuple(
+            f"h_{i}" for i in range(self.n_layer)))
+        return (PageGroup("pages", tuple(names), self.n_head, self.head_dim,
+                          self.head_dim, 0, self.n_pages),)
+
+    @property
+    def ring_pages(self):
+        """Pages of a row's ring (0: no group has a window): what the
+        window reaches and the page being written."""
+        window = max((g.window for g in self.groups), default=0)
+        return window // self.page_size + 1 if window else 0
+
+    @property
+    def table_width(self):
+        """A row's page table: ``pages_per_row`` entries of the full
+        groups' pool, then ``ring_pages`` of the window groups'."""
+        return self.pages_per_row + self.ring_pages
 
     @property
     def state_bytes_per_slot(self):
@@ -156,6 +237,13 @@ def refuse_recurrent(spec, feature, why):
     """The one check of everything that knows pages only."""
     if spec.recurrent_layers:
         raise RecurrentStateUnsupported(feature, why)
+
+
+def refuse_window_ring(spec, feature, why):
+    """The one check of everything that takes a row's pages for the
+    whole of its past."""
+    if spec.ring_pages:
+        raise WindowRingUnsupported(feature, why)
 
 
 def refuse_latent(spec, feature, why):
@@ -176,7 +264,7 @@ def spec_for_model(model, *args, **kwargs):
 def page_pool_spec(max_batch, max_seq, *, n_layer, n_head, head_dim,
                    compute_dtype, n_positions, stacked=False,
                    kv_cache_dtype=None, page_size=0, n_pages=0,
-                   latent_v_dim=0, **recurrent):
+                   latent_v_dim=0, groups=(), **recurrent):
     """A :class:`KVCacheSpec` from a model's own numbers (``n_layer``
     layers with ``n_head`` key/value heads of ``head_dim``) and the
     ``inference.kv_cache_dtype`` knob (None = model compute dtype,
@@ -184,8 +272,13 @@ def page_pool_spec(max_batch, max_seq, *, n_layer, n_head, head_dim,
     ``page_size`` must divide ``max_seq``; ``n_pages=0`` defaults to
     every row filling its ``max_seq`` span at once, plus the trash
     page. ``latent_v_dim`` > 0: a latent pool (one head, one leaf a
-    layer; plain storage only). ``recurrent``: the spec's ``layers`` /
-    ``recurrent_layers`` / ``recurrent_leaves``."""
+    layer; plain storage only). ``groups``: page layers that are not all
+    alike, as ``(name, layers, n_head, head_dim, v_dim, window)`` each
+    (plain storage, unstacked; ``n_layer``, ``n_head``, ``head_dim`` are
+    then the groups' own: pass those of the first). A full group's pool
+    has ``n_pages``; a window group's ``max_batch`` rings and a trash
+    page. ``recurrent``: the spec's ``layers`` / ``recurrent_layers`` /
+    ``recurrent_leaves``."""
     codec = None
     if kv_cache_dtype is None:
         dtype = compute_dtype
@@ -230,18 +323,55 @@ def page_pool_spec(max_batch, max_seq, *, n_layer, n_head, head_dim,
         raise ValueError(
             f"n_pages must be >= 2 (page 0 is the trash page), "
             f"got {n_pages}")
+    if groups:
+        groups = _page_groups(groups, int(max_batch), page_size, n_pages)
+        if codec is not None or stacked or latent_v_dim:
+            raise ValueError(
+                "groups of page layers are kept in plain storage, "
+                "unstacked, with keys and values of their own: no codec, "
+                "no scan_layers, no latent pool")
+        recurrent["layers"] = tuple(n for g in groups for n in g.layers)
+        n_layer = len(recurrent["layers"])
     return KVCacheSpec(
         n_layer=int(n_layer), max_batch=int(max_batch),
         max_seq=int(max_seq), n_head=int(n_head),
         head_dim=int(head_dim), dtype=dtype, codec=codec,
         stacked=bool(stacked), page_size=page_size,
-        n_pages=n_pages, latent_v_dim=int(latent_v_dim), **recurrent)
+        n_pages=n_pages, latent_v_dim=int(latent_v_dim),
+        groups=tuple(groups), **recurrent)
 
 
-def payload_shape(spec):
+def _page_groups(groups, max_batch, page_size, n_pages):
+    """:class:`PageGroup` s from ``page_pool_spec``'s tuples, each with
+    its pool's pages. The window groups share a row's ring, so they
+    share a window, which is whole pages (a ring entry is a page)."""
+    out = [PageGroup(str(name), tuple(layers), int(n_head), int(head_dim),
+                     int(v_dim), int(window))
+           for name, layers, n_head, head_dim, v_dim, window in groups]
+    windows = {g.window for g in out if g.window}
+    if len(windows) > 1 or any(w % page_size for w in windows):
+        raise ValueError(
+            f"window groups share one ring a row: one window, a whole "
+            f"number of pages of {page_size}; got {sorted(windows)}")
+    if any(not g.layers or g.n_head < 1 or g.v_dim < 1 or
+           g.v_dim > g.head_dim or g.window < 0 for g in out):
+        raise ValueError(
+            f"a group has layers, heads, and values no wider than its "
+            f"keys; got {out}")
+    ring = max(windows, default=0) // page_size + 1
+    return tuple(dataclasses.replace(
+        g, n_pages=max_batch * ring + 1 if g.window else n_pages)
+        for g in out)
+
+
+def payload_shape(spec, group=None, leaf="k"):
     """The shape of one layer's K (or V) pool; of a latent pool's one
-    leaf, ``[n_pages, 1, latent_v_dim + rope, page_size]``."""
-    return (spec.n_pages, spec.n_head, spec.head_dim, spec.page_size)
+    leaf, ``[n_pages, 1, latent_v_dim + rope, page_size]``. ``group``: a
+    :class:`PageGroup` of the spec (default: its first), whose ``v``
+    leaf may be narrower than its ``k``."""
+    g = group or spec.page_groups[0]
+    return (g.n_pages, g.n_head, g.v_dim if leaf == "v" else g.head_dim,
+            spec.page_size)
 
 
 def _payload_names(spec):
@@ -249,9 +379,9 @@ def _payload_names(spec):
     return ("k",) if spec.latent_v_dim else ("k", "v")
 
 
-def _layer_leaves(spec):
-    shape = payload_shape(spec)
-    leaves = {name: jnp.zeros(shape, spec.dtype)
+def _layer_leaves(spec, group=None):
+    shape = payload_shape(spec, group)
+    leaves = {name: jnp.zeros(payload_shape(spec, group, name), spec.dtype)
               for name in _payload_names(spec)}
     if spec.codec is not None:
         # one scale per (position, head): the payload less head_dim
@@ -265,14 +395,15 @@ def init_kv_cache(spec):
     """Zero-filled cache pytree keyed like the model's params: per-layer
     ``h_<i>`` subtrees (unrolled) or one stacked ``h`` subtree
     (``scan_layers``)."""
-    layer = _layer_leaves(spec)
     if spec.stacked:
         return {"h": jax.tree_util.tree_map(
             lambda a: jnp.broadcast_to(a, (spec.n_layer,) + a.shape),
-            layer)}
-    names = spec.layers or [f"h_{i}" for i in range(spec.n_layer)]
-    cache = {name: jax.tree_util.tree_map(jnp.array, layer)
-             for name in names}
+            _layer_leaves(spec))}
+    cache = {}
+    for group in spec.page_groups:
+        layer = _layer_leaves(spec, group)
+        for name in group.layers:
+            cache[name] = jax.tree_util.tree_map(jnp.array, layer)
     for name in spec.recurrent_layers:
         cache[name] = {leaf: jnp.zeros(shape, dtype)
                        for leaf, shape, dtype in spec.recurrent_leaves}
@@ -309,6 +440,11 @@ def kv_partition_specs(spec, model_axis="model"):
     refuse_latent(spec, "a 'model' mesh axis over the cache's heads",
                   "a latent is one head, and every query head reads all "
                   "of it")
+    if spec.groups:
+        raise ValueError(
+            "a 'model' mesh axis over groups of page layers with heads of "
+            "their own has not been built: each group's heads would be "
+            "split by the axis")
     lead = (None,) if spec.stacked else ()
     # no trailing None after the sharded head axis: jit keys compiled
     # programs on the exact sharding object, and GSPMD canonicalizes
@@ -444,7 +580,16 @@ def _new_leaves(layer_cache, k_new, v_new):
     return new
 
 
-def paged_write_kv(layer_cache, k_new, v_new, positions, page_table):
+def split_table(page_table, ring_pages):
+    """A row's page table as ``(the full groups' entries, the ring's)``
+    (`KVCacheSpec.table_width`); the ring's part is empty where no
+    group has a window."""
+    width = page_table.shape[-1] - ring_pages
+    return page_table[..., :width], page_table[..., width:]
+
+
+def paged_write_kv(layer_cache, k_new, v_new, positions, page_table,
+                   ring=False, n_valid=None):
     """Write one chunk's keys/values into the page pool through a
     page table. ``layer_cache`` holds ``[n_pages, H, D, page_size]``
     pool leaves (scales ``[n_pages, H, page_size]``);
@@ -469,11 +614,23 @@ def paged_write_kv(layer_cache, k_new, v_new, positions, page_table):
     A pool that stores a codec dtype quantizes on the way in, one
     scale per (page, slot, head); the flash kernel's fused dequant
     reads them beside the payload.
+
+    ``ring``: ``page_table`` is the rows' ring (a window group's): a
+    position's entry is its page's number modulo the table's width, and
+    a chunk of more pages than the ring holds overwrites its own first
+    pages with its last, in order. ``n_valid`` (``[B]``): tokens of a
+    row past it are padding and go to the trash page (a ring has no
+    entry to spare for them: written, a padded page would take the
+    place of one the window still reaches).
     """
     page_size = layer_cache["k"].shape[-1]
     B, T = positions.shape
-    pages = jnp.take_along_axis(page_table, positions // page_size,
-                                axis=1)                     # [B, T]
+    entry = positions // page_size
+    if ring:
+        entry = entry % page_table.shape[1]
+    pages = jnp.take_along_axis(page_table, entry, axis=1)  # [B, T]
+    if n_valid is not None:
+        pages = jnp.where(jnp.arange(T)[None] < n_valid[:, None], pages, 0)
     offs = positions % page_size
     new = _new_leaves(layer_cache, k_new, v_new)
     if B == 1 and T <= page_size:
@@ -524,7 +681,8 @@ def paged_read_kv(layer_cache, page_table, dtype):
 
 
 def _flash_attend_paged(q, new, layer_cache, positions, page_table,
-                        block_k, mesh, scale=None, v_dim=None):
+                        block_k, mesh, scale=None, v_dim=None, window=0,
+                        sink=None):
     """A flash decode step straight over the STORAGE pool: the step's
     ``new`` keys and values (:func:`_new_leaves`) go into the pool and
     the rows attend over it in one kernel; returns ``(y, layer_cache)``.
@@ -547,7 +705,8 @@ def _flash_attend_paged(q, new, layer_cache, positions, page_table,
 
     def attend(q_, new_, pool_, pos_, table_):
         return flash_decode_paged(q_, new_, pool_, pos_, table_,
-                                  block_k=block_k, scale=scale, v_dim=v_dim)
+                                  block_k=block_k, scale=scale, v_dim=v_dim,
+                                  window=window, sink=sink)
 
     if mesh is not None:
         from jax.sharding import PartitionSpec as P
@@ -663,10 +822,158 @@ def latent_prefill_attention(q, layer_cache, positions, page_table, *,
     return jnp.transpose(y, (2, 0, 1) if flash else (1, 0, 2))[None]
 
 
+def _sink_stats(sink, rows):
+    """The running max and sum an online softmax starts from: a sink's
+    logit and its weight of 1 in the denominator (``sink`` ``[Hq]``, a
+    query head each, against ``rows`` = ``(H, G, ...)``), or nothing."""
+    if sink is None:
+        return (jnp.full(rows, -jnp.inf, jnp.float32),
+                jnp.zeros(rows, jnp.float32))
+    m = sink.astype(jnp.float32).reshape(rows[:2] + (1,) * (len(rows) - 2))
+    return jnp.broadcast_to(m, rows), jnp.ones(rows, jnp.float32)
+
+
+def paged_prefill_attention(q, layer_cache, positions, page_table, *, scale,
+                            compute_dtype, sink=None):
+    """One prompt's chunk of queries over the row's live prefix in a
+    pool of per-head keys and values, the chunk's own already written:
+    :func:`latent_prefill_attention`'s walk (blocks of whole pages
+    through the table, a running max and sum in float32, the blocks up
+    to the chunk's last position and no further) with nothing to expand.
+    ``q`` ``[1, T, Hq, D]`` over ``H`` key heads of ``D`` and values of
+    ``Dv``; returns ``[1, T, Hq, Dv]``. The largest array is a block's
+    float32 scores ``[Hq, T, WALK_BLOCK]``: nothing as long as the bucket
+    is built. ``sink``: a logit a query head in the denominator."""
+    k_pool, v_pool = layer_cache["k"], layer_cache["v"]
+    H, D, page_size = k_pool.shape[1:]
+    Dv = v_pool.shape[2]
+    _, T, Hq, _ = q.shape
+    G = Hq // H
+    S = latent_walk_block(page_table.shape[-1], page_size)
+    bp = S // page_size
+    pos = positions[0]
+    n_blocks = pos[-1] // S + 1
+    # a key head's G query heads share its block: [H, G x T, D]
+    qh = jnp.transpose(q[0].reshape(T, H, G, D), (1, 2, 0, 3)).reshape(
+        H, G * T, D)
+    q_pos = jnp.tile(pos, G)                                # [G x T]
+    scale = jnp.asarray(scale, jnp.float32)
+
+    def block(i, carry):
+        m_prev, l_prev, acc = carry
+        pages = jax.lax.dynamic_slice_in_dim(page_table[0], i * bp, bp)
+
+        def rows(pool):             # [bp, H, d, page] -> [H, d, S]
+            blk = jnp.take(pool, pages, axis=0)
+            return jnp.transpose(blk, (1, 2, 0, 3)).reshape(
+                H, pool.shape[2], S).astype(compute_dtype)
+
+        s = jnp.einsum("hrd,hds->hrs", qh, rows(k_pool),
+                       preferred_element_type=jnp.float32)
+        k_pos = i * S + jnp.arange(S)
+        s = jnp.where(k_pos[None, None, :] <= q_pos[None, :, None],
+                      s * scale, jnp.finfo(jnp.float32).min)
+        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+        pr = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_new = l_prev * corr + pr.sum(-1, keepdims=True)
+        pv = jnp.einsum("hrs,hvs->hrv", pr.astype(compute_dtype),
+                        rows(v_pool), preferred_element_type=jnp.float32)
+        return m_new, l_new, acc * corr + pv
+
+    m0, l0 = _sink_stats(sink, (H, G, T, 1))
+    _, l, acc = jax.lax.fori_loop(
+        0, n_blocks, block,
+        (m0.reshape(H, G * T, 1), l0.reshape(H, G * T, 1),
+         jnp.zeros((H, G * T, Dv), jnp.float32)))
+    y = (acc / jnp.maximum(l, 1e-30)).astype(compute_dtype)
+    return jnp.transpose(y.reshape(H, G, T, Dv), (2, 0, 1, 3)).reshape(
+        1, T, Hq, Dv)
+
+
+def _grouped_softmax(q, k, v, seen, scale, sink, compute_dtype):
+    """``q`` ``[N, T, Hq, D]`` over ``k`` ``[N, S, H, D]`` and ``v``
+    ``[N, S, H, Dv]`` under ``seen`` ``[N, T, S]``, a query head over key
+    head ``h // G``; the scores and the softmax float32, a ``sink``'s
+    weight in the denominator and in no value. ``[N, T, Hq, Dv]``."""
+    N, T, Hq, D = q.shape
+    H = k.shape[2]
+    qg = q.reshape(N, T, H, Hq // H, D)
+    s = jnp.einsum("nthgd,nshd->nhgts", qg, k,
+                   preferred_element_type=jnp.float32)
+    s = jnp.where(seen[:, None, None], s * jnp.asarray(scale, jnp.float32),
+                  jnp.finfo(jnp.float32).min)
+    m = s.max(-1, keepdims=True)
+    if sink is not None:
+        b = sink.astype(jnp.float32).reshape(H, Hq // H, 1, 1)
+        m = jnp.maximum(m, b)
+    pr = jnp.exp(s - m)
+    l = pr.sum(-1, keepdims=True)
+    if sink is not None:
+        l = l + jnp.exp(b - m)
+    pr = (pr / jnp.maximum(l, 1e-30)).astype(compute_dtype)
+    y = jnp.einsum("nhgts,nshd->nthgd", pr, v)
+    return y.reshape(N, T, Hq, v.shape[-1])
+
+
+def window_prefill_attention(q, k_new, v_new, layer_cache, positions, ring,
+                             *, window, scale, compute_dtype, sink=None):
+    """One prompt's chunk over its own keys and the ``window`` positions
+    before it, which the row's ring still holds (read here, **before**
+    the chunk is written over them): a band. The chunk's queries go in
+    blocks of ``window`` (of the whole chunk where ``window`` does not
+    divide it), a block over its own keys and the ``window`` before
+    them, so the scores are ``[blocks, Hq, window, 2 x window]`` whatever
+    the row's length. Query ``t`` sees ``j`` iff ``0 <= t - j <
+    window``. ``q`` ``[1, T, Hq, D]``, ``k_new`` / ``v_new`` ``[1, T, H,
+    D | Dv]``, ``ring`` ``[1, ring_pages]``; returns ``[1, T, Hq, Dv]``."""
+    _, T, Hq, D = q.shape
+    W = int(window)
+    held_k, held_v = paged_read_kv(layer_cache, ring, compute_dtype)
+    c0 = positions[0, 0]
+    # what the ring holds of [c0 - W, c0): position p lies at p modulo
+    # the ring's span (before the prompt's start: masked below)
+    before = (c0 - W + jnp.arange(W)) % held_k.shape[1]
+    k_ext = jnp.concatenate([held_k[0][before], k_new[0].astype(compute_dtype)])
+    v_ext = jnp.concatenate([held_v[0][before], v_new[0].astype(compute_dtype)])
+    bq = W if T % W == 0 else T
+    nb = T // bq
+    span = lambda a: jnp.stack([a[j * bq:j * bq + W + bq] for j in range(nb)])
+    r = jnp.arange(bq)[:, None]
+    c = jnp.arange(W + bq)[None, :]
+    # query r of block j is at c0 + j bq + r, key c at c0 - W + j bq + c
+    seen = (c > r) & (c <= r + W)
+    k_pos = c0 - W + jnp.arange(nb)[:, None, None] * bq + c[None]
+    seen = seen[None] & (k_pos >= 0)
+    y = _grouped_softmax(q[0].reshape(nb, bq, Hq, D), span(k_ext),
+                         span(v_ext), seen, scale, sink, compute_dtype)
+    return y.reshape(1, T, Hq, y.shape[-1])
+
+
+def _dense_attend(q, layer_cache, positions, page_table, window, scale,
+                  sink, compute_dtype):
+    """The dense oracle of a group's decode step (and of a full layer's
+    chunk), the step's keys already written: the row's gathered pages
+    under the mask by position. Of a ring, entry ``j`` holds the largest
+    position ``<= p`` that is ``j`` modulo the ring's span, seen iff it
+    is no further back than the window."""
+    k, v = paged_read_kv(layer_cache, page_table, compute_dtype)
+    S = k.shape[1]
+    p = positions[:, :, None]                               # [B, T, 1]
+    at = jnp.arange(S)[None, None, :]
+    if window:
+        held = p - (p - at) % S
+        seen = (held >= 0) & (p - held < window)
+    else:
+        seen = at <= p
+    return _grouped_softmax(q, k, v, seen, scale, sink, compute_dtype)
+
+
 def cached_attention(q, k_new, v_new, layer_cache, positions,
                      compute_dtype, page_table, impl="dense",
                      block_k=128, mesh=None, mask=None, scale=None,
-                     v_dim=None, expand=None):
+                     v_dim=None, expand=None, window=0, sink=None,
+                     n_valid=None, walk=False):
     """Write this chunk's k/v, then attend over the whole cache row.
 
     ``q``/``k_new``/``v_new``: ``[B, T, H, D]`` (T = 1 for a decode
@@ -720,7 +1027,29 @@ def cached_attention(q, k_new, v_new, layer_cache, positions,
     the expanded width; a block is taken in by the prefill kernel
     (`ops/pallas/latent_prefill.py`) under ``impl="flash"`` and by
     plain XLA, the parity oracle, under ``impl="dense"``.
+
+    **A group of page layers with a window, a sink or values narrower
+    than its keys** (``walk`` True; `models/mimo_v2.py`; ``scale``
+    given). ``window`` > 0: ``page_table`` is the rows' ring, the decode
+    kernel walks the window's blocks and no others, and one prompt's
+    chunk attends by :func:`window_prefill_attention` (a band over the
+    ring's last ``window`` positions, read before the chunk is written,
+    ``n_valid`` of whose tokens are real). ``window`` 0: the chunk is
+    written and attends by :func:`paged_prefill_attention`'s walk over
+    the row's live blocks; nothing bucket-long is built. ``sink``
+    ``[Hq]``: a learned logit a query head in every softmax's
+    denominator. ``impl="dense"`` decodes by :func:`_dense_attend`, the
+    parity oracle. Each program under a scope of its own:
+    ``ds_attn_prefill_window`` / ``ds_attn_prefill_full``, a decode step
+    ``ds_attn_decode_window`` / ``ds_attn_decode_full`` around the
+    kernel's ``ds_flash_decode_paged``.
     """
+    if walk:
+        return _grouped_attention(
+            q, k_new, v_new, layer_cache, positions, compute_dtype,
+            page_table, impl, block_k, scale, window, sink, n_valid)
+    if window or sink is not None:
+        raise ValueError("a window or a sink goes with walk=True")
     latent = "v" not in layer_cache
     if latent and (v_new is not None or v_dim is None or scale is None):
         raise ValueError(
@@ -770,3 +1099,43 @@ def cached_attention(q, k_new, v_new, layer_cache, positions,
     att = jax.nn.softmax(att, axis=-1).astype(compute_dtype)
     y = jnp.einsum("bhgts,bshd->bthgd", att, v_full)
     return y.reshape(B, T, Hq, v_full.shape[-1]), layer_cache
+
+
+def _grouped_attention(q, k_new, v_new, layer_cache, positions,
+                       compute_dtype, page_table, impl, block_k, scale,
+                       window, sink, n_valid):
+    """:func:`cached_attention` under ``walk``: its docstring's last
+    part."""
+    kind = "window" if window else "full"
+    B, T = positions.shape
+    if T == 1:
+        with jax.named_scope(f"ds_attn_decode_{kind}"):
+            if impl == "flash":
+                y, layer_cache = _flash_attend_paged(
+                    q, _new_leaves(layer_cache, k_new, v_new), layer_cache,
+                    positions, page_table, block_k, None, scale,
+                    window=window, sink=sink)
+                return y.astype(compute_dtype), layer_cache
+            layer_cache = paged_write_kv(layer_cache, k_new, v_new,
+                                         positions, page_table,
+                                         ring=bool(window))
+            return _dense_attend(q, layer_cache, positions, page_table,
+                                 window, scale, sink,
+                                 compute_dtype), layer_cache
+    if B != 1:
+        raise ValueError(
+            "a group of page layers attends several tokens at once only "
+            "as one prompt's chunk (one row)")
+    with jax.named_scope(f"ds_attn_prefill_{kind}"):
+        if window:
+            y = window_prefill_attention(
+                q, k_new, v_new, layer_cache, positions, page_table,
+                window=window, scale=scale, compute_dtype=compute_dtype,
+                sink=sink)
+            return y, paged_write_kv(layer_cache, k_new, v_new, positions,
+                                     page_table, ring=True, n_valid=n_valid)
+        layer_cache = paged_write_kv(layer_cache, k_new, v_new, positions,
+                                     page_table)
+        return paged_prefill_attention(
+            q, layer_cache, positions, page_table, scale=scale,
+            compute_dtype=compute_dtype, sink=sink), layer_cache

@@ -75,6 +75,15 @@ refused by what knows per-head keys and values: an int8 / fp8 pool, a
 ``model`` axis, speculative decoding, a tier
 (:class:`~deepspeed_tpu.inference.cache.LatentPoolUnsupported`); its
 pages are shared, parked and resumed like any others.
+A model whose page layers are not all alike lists them as groups in its
+``cache_spec`` (`inference/cache.py:PageGroup`: heads, key and value
+widths, window); no knob says so. Where a group has a window a row's
+``page_table`` is ``spec.table_width`` wide, the full groups' entries
+then the row's ring, and ``serve_apply`` takes it apart
+(`cache.split_table`). Such a model is served with the prefix cache
+off, and an explicit ``prefix_cache``, speculative decoding, a tier, a
+``model`` axis and page gathers refuse it
+(:class:`~deepspeed_tpu.inference.cache.WindowRingUnsupported`).
 """
 
 import numpy as np
@@ -90,6 +99,7 @@ from deepspeed_tpu.inference.cache import (
     kv_partition_specs,
     refuse_latent,
     refuse_recurrent,
+    refuse_window_ring,
 )
 from deepspeed_tpu.inference.paging import TRASH_PAGE
 from deepspeed_tpu.telemetry.spans import Span, enclosing_attr
@@ -250,6 +260,9 @@ class InferenceEngine:
                                      n_pages=self.n_pages)
         self.n_pages = self.spec.n_pages
         self.pages_per_row = self.spec.pages_per_row
+        # a row's page table: wider than its pages by a window group's ring
+        self.table_width = self.spec.table_width
+        self._window = max(g.window for g in self.spec.page_groups)
         # what knows pages only refuses a recurrent state here, before
         # anything is placed or traced
         self.recurrent = bool(self.spec.recurrent_layers)
@@ -258,9 +271,19 @@ class InferenceEngine:
                 self.spec, "inference.prefix_cache",
                 "a shared page says nothing of the state after it "
                 "(drop the key: such a model is served with it off)")
-        self.prefix_cache = (not self.recurrent) if prefix_cache is None \
-            else bool(prefix_cache)
+        if prefix_cache:
+            refuse_window_ring(
+                self.spec, "inference.prefix_cache",
+                "a shared page of a full layer says nothing of the ring "
+                "beside it, which holds the sharer's last window only "
+                "(drop the key: such a model is served with it off)")
+        self.prefix_cache = \
+            (not self.recurrent and not self.spec.ring_pages) \
+            if prefix_cache is None else bool(prefix_cache)
         if self.tier is not None:
+            refuse_window_ring(
+                self.spec, f"the disaggregated {self.tier!r} tier",
+                "the hand-off moves a row's pages and no ring")
             refuse_recurrent(
                 self.spec, f"the disaggregated {self.tier!r} tier",
                 "the hand-off moves pages and no state")
@@ -272,6 +295,9 @@ class InferenceEngine:
                 self.spec, "a 'model' mesh axis (tensor parallelism)",
                 "a latent is one head, and every query head reads all "
                 "of it")
+            refuse_window_ring(
+                self.spec, "a 'model' mesh axis (tensor parallelism)",
+                "the groups' heads are not sharded")
         if self.tier is not None:
             refuse_latent(
                 self.spec, f"the disaggregated {self.tier!r} tier",
@@ -290,11 +316,13 @@ class InferenceEngine:
             self._paged_grid_blocks = paged_grid_blocks
             # the kernel holds all the heads a device has
             tp = dict(mesh.shape).get("model", 1) if mesh is not None else 1
-            self.attention_block_k = check_decode_geometry(
-                self.attention_block_k, self.page_size, self.spec.dtype,
-                self.spec.n_head // tp, self.spec.head_dim,
-                self.spec.codec is not None,
-                latent=bool(self.spec.latent_v_dim))
+            for group in self.spec.page_groups:
+                self.attention_block_k = check_decode_geometry(
+                    self.attention_block_k, self.page_size, self.spec.dtype,
+                    group.n_head // tp, group.head_dim,
+                    self.spec.codec is not None,
+                    latent=bool(self.spec.latent_v_dim),
+                    v_dim=group.v_dim if self.spec.groups else None)
         self.mesh = mesh
         self.session = session
         self._sample_key = jax.random.PRNGKey(self.sampling_seed)
@@ -461,16 +489,20 @@ class InferenceEngine:
                 f"(chunk={chunk})")
         attrs["chunks"] = (padded - start) // chunk
         attrs["pad_tokens"] = padded - n
-        if self.spec.latent_v_dim:
-            # blocks of the walk over the prompt's calls, and those the
-            # prefill kernel took in: all of them or none
+        if self.spec.latent_v_dim or self.spec.groups:
+            # blocks of the walk over the prompt's calls: a latent pool's
+            # (and those the prefill kernel took in: all of them or
+            # none), or a full group's layers'
             from deepspeed_tpu.inference.cache import latent_walk_block
-            block = latent_walk_block(pt.shape[-1], self.page_size)
+            block = latent_walk_block(self.pages_per_row, self.page_size)
             blocks = sum((ci * chunk + chunk - 1) // block + 1
                          for ci in range(start // chunk, padded // chunk))
-            attrs["attn_blocks"] = blocks
-            attrs["attn_blocks_kernel"] = \
-                blocks if self.attention_impl == "flash" else 0
+            if self.spec.latent_v_dim:
+                attrs["attn_blocks"] = blocks
+                attrs["attn_blocks_kernel"] = \
+                    blocks if self.attention_impl == "flash" else 0
+            else:
+                attrs["attn_prefix_blocks_full"] = blocks
         if start:
             refuse_recurrent(
                 self.spec, f"a prefill resumed at token {start}",
@@ -530,7 +562,8 @@ class InferenceEngine:
                 np.asarray(page_tables)[:, 0] != TRASH_PAGE))
         if self._paged_grid_blocks is not None:
             # how far the kernel's grid follows the cache: KV blocks (of
-            # all heads) the live rows hold against those it visits
+            # all heads) the live rows hold against those it visits (of
+            # a full group's layers; a window group's beside them below)
             live, launched = self._paged_grid_blocks(
                 positions, page_tables, self.attention_block_k)
             # and its write: the rows whose block it writes back are the
@@ -539,6 +572,14 @@ class InferenceEngine:
             attrs = dict(attrs or {}, kv_blocks_live=live,
                          kv_blocks_launched=launched,
                          kv_rows_live=rows, kv_rows_written=rows)
+        window = self._window
+        if self._paged_grid_blocks is not None and window:
+            # a window layer's walk: the blocks the kernel visits
+            # against those the rows' windows reach
+            seen, walked = self._paged_grid_blocks(
+                positions, page_tables, self.attention_block_k, window)
+            attrs.update(attn_blocks_in_window=seen,
+                         attn_blocks_visited_window=walked)
         if self.recurrent:
             # rows whose state the step moves on, against those whose
             # state it reads and writes back (all of them: the update is
@@ -570,6 +611,8 @@ class InferenceEngine:
     def _refuse_page_moves(self, feature):
         refuse_recurrent(self.spec, feature,
                          "pages are copied and no state with them")
+        refuse_window_ring(self.spec, feature,
+                           "pages are copied and no ring with them")
 
     def gather_pages(self, page_ids):
         """Snapshot the given physical pages to host RAM: a per-layer
@@ -691,7 +734,7 @@ class InferenceEngine:
         return (self.params, self.cache,
                 jnp.zeros((self.max_batch,), jnp.int32),
                 jnp.zeros((self.max_batch,), jnp.int32),
-                jnp.zeros((self.max_batch, self.pages_per_row), jnp.int32),
+                jnp.zeros((self.max_batch, self.table_width), jnp.int32),
                 self._sample_key)
 
     def prefill_lowering_args(self):
@@ -700,7 +743,7 @@ class InferenceEngine:
         return (self.params, self.cache,
                 jnp.zeros((1, self.prefill_chunk), jnp.int32),
                 jnp.zeros((1, self.prefill_chunk), jnp.int32),
-                jnp.zeros((1, self.pages_per_row), jnp.int32), one, one)
+                jnp.zeros((1, self.table_width), jnp.int32), one, one)
 
     def decode_hlo(self):
         """Compiled HLO text of the decode program (audit/bench food)."""
@@ -720,6 +763,13 @@ class InferenceEngine:
                  "page_size": self.page_size,
                  "n_pages": self.n_pages,
                  "pages_per_row": self.pages_per_row}
+        if self.spec.groups:
+            facts["table_width"] = self.table_width
+            facts["groups"] = {
+                g.name: {"layers": len(g.layers), "n_head": g.n_head,
+                         "head_dim": g.head_dim, "v_dim": g.v_dim,
+                         "window": g.window, "n_pages": g.n_pages}
+                for g in self.spec.groups}
         if self.recurrent:
             facts["recurrent_layers"] = len(self.spec.recurrent_layers)
             facts["state_bytes_per_slot"] = self.spec.state_bytes_per_slot

@@ -43,7 +43,8 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from deepspeed_tpu.inference.cache import RecurrentStateUnsupported
+from deepspeed_tpu.inference.cache import (RecurrentStateUnsupported,
+                                           WindowRingUnsupported)
 from deepspeed_tpu.runtime.resilience import fault_injection
 from deepspeed_tpu.runtime.resilience.checkpoint import _leaf_checksums
 
@@ -296,10 +297,17 @@ class RowPaging:
     prefill_chunks_skipped: int = 0
     slot: Optional[int] = None      # batch slot whose recurrent leaves
     #                                 the row owns (None: it owns none)
+    # the row's ring in the window groups' pool (none: no window group)
+    ring: List[int] = dataclasses.field(default_factory=list)
 
-    def table(self, pages_per_row):
-        t = np.zeros(pages_per_row, np.int32)
+    def table(self, width):
+        """The row's page table, ``width`` entries: its pages from the
+        left and, where it has one, its ring as the last entries
+        (`inference/cache.py:KVCacheSpec.table_width`)."""
+        t = np.zeros(width, np.int32)
         t[:len(self.pages)] = self.pages
+        if self.ring:
+            t[-len(self.ring):] = self.ring
         return t
 
 
@@ -320,6 +328,15 @@ class PagedCacheManager:
     nor parked, so such an engine has no radix tree, and a request with
     a ``session_id`` is refused
     (:class:`~deepspeed_tpu.inference.cache.RecurrentStateUnsupported`).
+
+    **A window group's ring** (ISSUE 47). Where the spec has a group
+    with a window, a row also takes ``ring_pages`` (``window //
+    page_size + 1``) pages of that group's own pool when it is admitted
+    and returns them when it is released; it never takes more, whatever
+    its length (the compiled programs reuse them as a ring). The pool
+    holds ``max_batch`` rings, so a free slot always finds one. A ring
+    is neither shared nor parked nor handed off
+    (:class:`~deepspeed_tpu.inference.cache.WindowRingUnsupported`).
     """
 
     def __init__(self, engine, session=None):
@@ -352,6 +369,14 @@ class PagedCacheManager:
         self.state_bytes_per_slot = \
             spec.state_bytes_per_slot if self.recurrent else 0
         self._state_owner = {}          # slot -> the row that owns it
+        # the groups a spec lists, and the window groups' pool of rings
+        self.groups = tuple(getattr(spec, "groups", ()))
+        self.ring_pages = int(getattr(spec, "ring_pages", 0))
+        self.table_width = self.pages_per_row + self.ring_pages
+        self.ring_allocator = PageAllocator(
+            engine.max_batch * self.ring_pages + 1) \
+            if self.ring_pages else None
+        self.ring_pages_live = 0
 
     @property
     def state_rows_live(self):
@@ -362,6 +387,11 @@ class PagedCacheManager:
         return len(self._state_owner) * self.state_bytes_per_slot
 
     def _refuse_session(self, session_id):
+        if session_id and self.ring_pages:
+            raise WindowRingUnsupported(
+                f"park/resume (session {session_id!r})",
+                "a parked row's ring holds its last window and nothing "
+                "of the prompt before it")
         if session_id and self.recurrent:
             raise RecurrentStateUnsupported(
                 f"park/resume (session {session_id!r})",
@@ -378,7 +408,33 @@ class PagedCacheManager:
         bytes / n_pages) — the unit the bytes/session accounting and
         the bench A/B row count in."""
         from deepspeed_tpu.inference.cache import kv_cache_nbytes
+        if self.ring_pages:
+            # of the groups whose rows keep every page
+            return sum(b for g, b in self._group_page_bytes()
+                       if not g.window)
         return kv_cache_nbytes(self.engine.cache) // self.engine.n_pages
+
+    def _group_page_bytes(self):
+        """``(group, bytes of one of its pages over its layers)``."""
+        spec = self.engine.spec
+        item = np.dtype(spec.dtype).itemsize
+        return [(g, g.bytes_per_token(item) * self.page_size)
+                for g in self.groups]
+
+    def group_facts(self):
+        """By group of page layers: pages in live rows' tables and in
+        the pool, and their bytes. A full group's live pages are the
+        manager's ``pages_live``; a window group's the live rows' rings,
+        ``ring_pages`` each whatever the rows' lengths."""
+        out = {}
+        for g, page_bytes in self._group_page_bytes():
+            live = self.ring_pages_live if g.window else self.pages_live
+            out[g.name] = {
+                "window": g.window, "layers": len(g.layers),
+                "pages_live": live, "pages_total": g.n_pages - 1,
+                "page_bytes": page_bytes, "bytes_live": live * page_bytes,
+                "bytes_total": (g.n_pages - 1) * page_bytes}
+        return out
 
     # NB: the radix tree defines __len__, so an EMPTY tree is falsy —
     # these guards must be identity checks or a cold cache would
@@ -415,6 +471,8 @@ class PagedCacheManager:
             "state_rows_live": self.state_rows_live,
             "state_rows_total": self.state_rows_total,
             "state_bytes_live": self.state_bytes_live,
+            "ring_pages": self.ring_pages,
+            "groups": self.group_facts() if self.groups else {},
         }
 
     # -- eviction ladder -----------------------------------------------------
@@ -566,6 +624,19 @@ class PagedCacheManager:
             fresh.append(p)
         pages.extend(fresh)
 
+        ring = []
+        if self.ring_pages:
+            ring = [self.ring_allocator.alloc()
+                    for _ in range(self.ring_pages)]
+            if None in ring:    # more rows than the pool has rings
+                for q in ring:
+                    if q is not None:
+                        self.ring_allocator.decref(q)
+                for q in pages:     # (none shared: a ring has no radix)
+                    self.allocator.decref(q)
+                return None
+            self.ring_pages_live += len(ring)
+
         self.sessions_admitted += 1
         self.pages_live += len(pages)
         if resumed:
@@ -575,7 +646,7 @@ class PagedCacheManager:
             pages=pages, start=start, prefix_hit=prefix_hit,
             resumed=resumed,
             prefill_chunks=padded_chunks - start // chunk,
-            prefill_chunks_skipped=start // chunk)
+            prefill_chunks_skipped=start // chunk, ring=ring)
         if self.recurrent:
             row.slot = slot
             self._state_owner[slot] = row
@@ -589,6 +660,9 @@ class PagedCacheManager:
         if self.recurrent:
             raise RecurrentStateUnsupported(
                 "a handed-off row", "its pages arrive without a state")
+        if self.ring_pages:
+            raise WindowRingUnsupported(
+                "a handed-off row", "its pages arrive without a ring")
         self.pages_live += len(row.pages)
 
     def after_prefill(self, row, prompt):
@@ -638,6 +712,10 @@ class PagedCacheManager:
         self._refuse_session(session_id)
         self._clock += 1
         self.pages_live -= len(row.pages)
+        for p in row.ring:
+            self.ring_allocator.decref(p)
+        self.ring_pages_live -= len(row.ring)
+        row.ring = []
         if self._state_owner.get(row.slot) is row:
             del self._state_owner[row.slot]     # the slot's next tenant
             #                                     starts from zero

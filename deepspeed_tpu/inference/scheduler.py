@@ -402,7 +402,7 @@ class ContinuousBatchingScheduler:
         self.queue.popleft()
         admit_t = clock()
         last_logits = self.engine.prefill(
-            i, req.prompt, page_table=row.table(self.paging.pages_per_row),
+            i, req.prompt, page_table=row.table(self.paging.table_width),
             start=row.start)
         self.paging.after_prefill(row, req.prompt)
         with Span("sample", session):
@@ -448,6 +448,14 @@ class ContinuousBatchingScheduler:
                     attrs["pages_live"] = self.paging.pages_live
                     attrs["pages_resident"] = alloc.resident_pages
                     attrs["pages_total"] = alloc.n_pages - 1
+                    if self.paging.ring_pages:
+                        # by group: kv_pages_live_full, kv_bytes_live_window
+                        groups = self.paging.group_facts()
+                        for name, g in groups.items():
+                            attrs[f"kv_pages_live_{name}"] = g["pages_live"]
+                            attrs[f"kv_bytes_live_{name}"] = g["bytes_live"]
+                        attrs["kv_bytes_live"] = sum(
+                            g["bytes_live"] for g in groups.values())
                     if self.paging.recurrent:
                         attrs["state_rows_live"] = \
                             self.paging.state_rows_live
@@ -467,10 +475,10 @@ class ContinuousBatchingScheduler:
         for i in active:
             tokens[i] = self.slots[i].pending
             positions[i] = self.slots[i].next_pos
-        page_tables = np.zeros((mb, self.paging.pages_per_row), np.int32)
+        page_tables = np.zeros((mb, self.paging.table_width), np.int32)
         for i in active:
             page_tables[i] = self.slots[i].paging.table(
-                self.paging.pages_per_row)
+                self.paging.table_width)
         return tokens, positions, page_tables
 
     def _step(self):

@@ -409,7 +409,11 @@ def build_speculative(engine, config):
     if not enabled or k == 0:
         return None
     from deepspeed_tpu.inference.cache import (refuse_latent,
-                                               refuse_recurrent)
+                                               refuse_recurrent,
+                                               refuse_window_ring)
+    refuse_window_ring(engine.spec, "inference.speculative",
+                       "a rejected draft's keys would have overwritten "
+                       "what the ring held of the window before them")
     refuse_recurrent(engine.spec, "inference.speculative",
                      "a rejected draft would have to roll the state back")
     refuse_latent(engine.spec, "inference.speculative",

@@ -57,6 +57,19 @@ queries fill rows of the MXU, and its operands stay in the pool's
 bfloat16 (the other pools' one-row products go through float32
 copies). The step's write is one leaf's lane instead of two.
 
+**Values narrower than keys, a window, a sink** (ISSUE 47,
+`models/mimo_v2.py`). A pool's ``v`` leaf may be narrower than its
+``k`` leaf (keys of 192 over values of 128): the value block's slots
+and the output are as wide as the ``v`` leaf. With ``window`` > 0 the
+row's table is a **ring** (`inference/cache.py`): position ``p`` lies
+in entry ``(p // page_size) % ring``, the walk starts at the block of
+``p - window + 1`` and not at 0, and a key is admitted iff ``p - window
+< s <= p``, by its position and not by where it lies; the step's write
+goes to the ring entry of ``p``. ``sink``: a learned logit a query head
+that joins the softmax's denominator and no value: the running max
+starts at it and the running sum at 1. With none of the three the
+kernel is traced as it was.
+
 The kernel compiles for the chip (`tests/unit/test_tpu_compile.py`
 pins that against a described v5e, its grid included). Off-TPU it
 runs in Pallas interpret mode (CPU test meshes); the dense
@@ -122,17 +135,18 @@ def _validate_block_k(block_k, page_size, interpret):
 
 
 def check_decode_geometry(block_k, page_size, kv_dtype, heads, head_dim,
-                          quant, latent=False):
+                          quant, latent=False, v_dim=None):
     """The call-time block validation, for the device this process
     compiles for — so the serving engine refuses, typed, a geometry the
     chip's compiler would refuse when it is BUILT, not at the first
     decode step (and never by serving through another path). Returns
     the clamped ``block_k``. ``heads`` x ``head_dim`` is what one
     device holds of the pool: the kernel's all-head blocks must fit
-    VMEM."""
+    VMEM. ``v_dim``: the values' width where it is not the keys'."""
     interpret = jax.devices()[0].platform != "tpu"
     block_k = _validate_block_k(block_k, page_size, interpret)
-    _check_paged_vmem(heads, head_dim, block_k, kv_dtype, quant, latent)
+    _check_paged_vmem(heads, head_dim, block_k, kv_dtype, quant, latent,
+                      v_dim)
     return block_k
 
 
@@ -144,22 +158,28 @@ TRASH_PAGE = 0
 PAGED_VMEM_BUDGET = 16 * 2 ** 20
 
 
-def paged_grid_blocks(positions, page_tables, block_k):
+def paged_grid_blocks(positions, page_tables, block_k, window=0):
     """``(live, launched)`` KV blocks a layer for one decode step's
     inputs, in blocks of all heads: ``live`` is what the rows hold, the
     sum over live rows of ``pos // block_k + 1``; ``launched`` is what
     :func:`flash_decode_paged` visits. The two are equal by
     construction: the kernel's loop bound is this arithmetic. (The
     grid before PR 27 visited ``rows x pages_per_row x page_size /
-    block_k`` whatever the rows held.) Host-side, numpy."""
+    block_k`` whatever the rows held.) With ``window`` > 0 a row holds
+    the blocks its window reaches, from that of ``p - window + 1`` to
+    that of ``p``, and the kernel's walk starts there. Host-side,
+    numpy."""
     positions = np.asarray(positions).reshape(-1)
     live = np.asarray(page_tables)[:, 0] != TRASH_PAGE
-    blocks = int((positions[live] // int(block_k) + 1).sum())
+    pos = positions[live]
+    first = np.maximum(pos - int(window) + 1, 0) // int(block_k) \
+        if window else 0
+    blocks = int((pos // int(block_k) - first + 1).sum())
     return blocks, blocks
 
 
 def paged_vmem_bytes(heads, head_dim, block_k, kv_dtype, quant,
-                     latent=False):
+                     latent=False, v_dim=None):
     """VMEM the paged kernel's step holds: two slots each of the K and
     V ``(H, D, block_k)`` blocks (of the one block of a ``latent`` pool,
     which has no V; and of their scale rows), the
@@ -167,10 +187,14 @@ def paged_vmem_bytes(heads, head_dim, block_k, kv_dtype, quant,
     step's new keys and values, float32, a row to a lane (and of their
     scales). (The float32 operands of the dots are made a head at a
     time, never a whole block: a described v5e compiles int8 blocks of
-    12 MB and refuses float32 ones of 16.)"""
+    12 MB and refuses float32 ones of 16.) ``v_dim``: the V block's
+    sublanes where they are fewer than the K block's."""
     elems = int(heads) * int(head_dim) * int(block_k)
     leaves = 1 if latent else 2
     need = leaves * 2 * elems * jnp.dtype(kv_dtype).itemsize
+    if v_dim is not None and not latent:
+        need -= 2 * int(heads) * (int(head_dim) - int(v_dim)) * \
+            int(block_k) * jnp.dtype(kv_dtype).itemsize
     new = leaves * 2 * int(heads) * int(head_dim) * _LANES * 4
     if quant:
         need += 2 * 2 * int(heads) * int(block_k) * 4
@@ -179,9 +203,9 @@ def paged_vmem_bytes(heads, head_dim, block_k, kv_dtype, quant,
 
 
 def _check_paged_vmem(heads, head_dim, block_k, kv_dtype, quant,
-                      latent=False):
+                      latent=False, v_dim=None):
     need = paged_vmem_bytes(heads, head_dim, block_k, kv_dtype, quant,
-                            latent)
+                            latent, v_dim)
     if need > PAGED_VMEM_BUDGET:
         raise KernelGeometryError(
             f"paged flash decode keeps two (heads={heads}, "
@@ -192,7 +216,8 @@ def _check_paged_vmem(heads, head_dim, block_k, kv_dtype, quant,
 
 
 def _paged_decode_kernel(H, D, block_k, bpp, quant, G=1, scale=None,
-                         v_dim=None):
+                         v_dim=None, kv_dim=None, window=0, ring=0,
+                         sink=False):
     """The paged kernel's body: one grid step = one row's live span,
     the row's new key and value written into it.
 
@@ -231,6 +256,14 @@ def _paged_decode_kernel(H, D, block_k, bpp, quant, G=1, scale=None,
     under way from ``slot``, and whoever fetches into that slot next,
     or the last grid step, waits first (``settle``). A row without a
     request starts no DMA and writes nothing.
+
+    ``kv_dim``: the V leaf's sublanes where they are fewer than ``D``
+    (the new value comes padded to ``D`` beside the new key). ``window``
+    > 0: the table is a ring of ``ring`` pages, the walk starts at the
+    block of ``p - window + 1`` and a key is admitted by its position
+    inside the window. ``sink``: a ref of one logit a query head comes
+    after the new keys and values; the running max starts at it and the
+    running sum at 1 (its weight in the denominator), with no value.
     """
 
     rows = (H,) if G == 1 else (H, G)
@@ -238,7 +271,7 @@ def _paged_decode_kernel(H, D, block_k, bpp, quant, G=1, scale=None,
     latent = v_dim is not None
     n_pay = 1 if latent else 2          # payload leaves: K (and V)
     n_pool = n_pay + (2 if quant else 0)
-    Dv = v_dim if latent else D
+    Dv = v_dim if latent else (kv_dim or D)
 
     def over_heads(a):
         """A per-(head, position) array against the scores' rows."""
@@ -247,6 +280,7 @@ def _paged_decode_kernel(H, D, block_k, bpp, quant, G=1, scale=None,
     def kernel(pos_ref, pt_ref, q_ref, new_ref, *refs):
         refs = list(refs)
         snew_ref = refs.pop(0) if quant else None
+        sink_ref = refs.pop(0) if sink else None
         del refs[:n_pool]           # the pool as handed in: see pools
         o_ref = refs.pop(0)
         pools, bufs = refs[:n_pool], refs[n_pool:2 * n_pool]
@@ -259,7 +293,7 @@ def _paged_decode_kernel(H, D, block_k, bpp, quant, G=1, scale=None,
             of it to where it came from."""
             # the table is read for blocks the row has filled only
             # (i <= p // block_k): no unallocated entry is dereferenced
-            page = pt_ref[b, i // bpp]
+            page = pt_ref[b, (i // bpp) % ring if window else i // bpp]
             if bpp == 1:
                 lanes = slice(None)         # a whole page: contiguous
             else:
@@ -298,9 +332,17 @@ def _paged_decode_kernel(H, D, block_k, bpp, quant, G=1, scale=None,
         @pl.when(live)
         def _row():
             n_blocks = p // block_k + 1
-            settle(0)
-            for c in copies(0, 0):
-                c.start()
+            if window:
+                # the block that holds the window's first position
+                first = jnp.maximum(p - window + 1, 0) // block_k
+                settle(first % 2)
+                for c in copies(first, first % 2):
+                    c.start()
+            else:
+                first = 0
+                settle(0)
+                for c in copies(0, 0):
+                    c.start()
             # [H, D] | [H, G, D]
             qb = q_ref[0] if latent else q_ref[0].astype(jnp.float32)
             if G == 1:
@@ -317,6 +359,8 @@ def _paged_decode_kernel(H, D, block_k, bpp, quant, G=1, scale=None,
                 snew = pltpu.roll(snew_ref[...], turn, 2) if quant else None
                 for j, buf in enumerate(bufs):
                     col = new[j] if j < n_pay else snew[j - n_pay]
+                    if j == 1 and Dv != D:
+                        col = col[:, :Dv]   # the value came padded to D
                     if block_k <= _LANES:
                         col = col[..., :block_k]
                     else:
@@ -361,7 +405,10 @@ def _paged_decode_kernel(H, D, block_k, bpp, quant, G=1, scale=None,
                 s = s * scale
                 k_pos = i * block_k + jax.lax.broadcasted_iota(
                     jnp.int32, rows + (block_k,), len(rows))
-                s = jnp.where(k_pos <= p, s, DEFAULT_MASK_VALUE)
+                seen = k_pos <= p
+                if window:
+                    seen = jnp.logical_and(seen, k_pos > p - window)
+                s = jnp.where(seen, s, DEFAULT_MASK_VALUE)
                 m_new = jnp.maximum(m_prev,
                                     s.max(axis=-1, keepdims=True))
                 pr = jnp.exp(s - m_new)
@@ -380,11 +427,15 @@ def _paged_decode_kernel(H, D, block_k, bpp, quant, G=1, scale=None,
                     preferred_element_type=jnp.float32)     # [H, G, Dv]
                 return m_new, l_new, acc * corr + pv.reshape(rows + (Dv,))
 
+            if sink:
+                stat0 = (sink_ref[...].astype(jnp.float32),
+                         jnp.ones(rows + (1,), jnp.float32))
+            else:
+                stat0 = (jnp.full(rows + (1,), -jnp.inf, jnp.float32),
+                         jnp.zeros(rows + (1,), jnp.float32))
             _, l, acc = jax.lax.fori_loop(
-                0, n_blocks, block,
-                (jnp.full(rows + (1,), -jnp.inf, jnp.float32),
-                 jnp.zeros(rows + (1,), jnp.float32),
-                 jnp.zeros(rows + (Dv,), jnp.float32)))
+                first, n_blocks, block,
+                stat0 + (jnp.zeros(rows + (Dv,), jnp.float32),))
             o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
         @pl.when(b == pl.num_programs(0) - 1)
@@ -410,7 +461,7 @@ def _rows_on_lanes(new, dtype):
 
 def flash_decode_paged(q, new, pool, positions, page_tables,
                        block_k=DEFAULT_BLOCK_K, interpret=None, scale=None,
-                       v_dim=None):
+                       v_dim=None, window=0, sink=None):
     """One decode step's attention over a paged KV pool, the step's own
     keys and values written into the pool on the way: returns ``(out,
     pool)``.
@@ -450,6 +501,13 @@ def flash_decode_paged(q, new, pool, positions, page_tables,
     returns zeros. ``block_k`` clamps to ``page_size`` and must tile it
     — a KV block never straddles a page boundary, which is what keeps
     the fetch one slab of one page.
+
+    The ``v`` leaf may be narrower than the ``k`` leaf (``[n_pages, H,
+    Dv, page_size]``): ``out`` is then ``Dv`` wide. ``window`` > 0:
+    ``page_tables`` is the rows' ring (position ``p`` in entry ``(p //
+    page_size) % width``) and a row attends over the last ``window``
+    positions, its own among them. ``sink``: ``[Hq]`` float32, a logit
+    a query head in the softmax's denominator that carries no value.
     """
     H, D, page_size = pool["k"].shape[1:]
     B, Hq = q.shape[0], q.shape[2]
@@ -476,11 +534,23 @@ def flash_decode_paged(q, new, pool, positions, page_tables,
             f"the first v_dim <= {D} entries of a key) and with no other; "
             f"the pool's leaves are {sorted(pool)}")
     block_k = _validate_block_k(block_k, page_size, interpret)
+    kv_dim = None if latent else pool["v"].shape[2]
+    if (window or sink is not None or kv_dim not in (None, D)) and \
+            "k_scale" in pool:
+        raise ValueError(
+            "a window, a sink and values narrower than keys go with a "
+            "pool in plain storage")
+    if window and (window < 1 or
+                   (page_tables.shape[1] - 1) * page_size < window):
+        raise ValueError(
+            f"a ring of {page_tables.shape[1]} pages of {page_size} "
+            f"cannot hold a window of {window} and the page being written")
     _check_paged_vmem(H, D, block_k, pool["k"].dtype, "k_scale" in pool,
-                      latent)
+                      latent, kv_dim)
     return _paged_call(q, new, pool, jnp.asarray(positions, jnp.int32),
-                       jnp.asarray(page_tables, jnp.int32), block_k=block_k,
-                       interpret=bool(interpret), scale=scale, v_dim=v_dim)
+                       jnp.asarray(page_tables, jnp.int32), sink,
+                       block_k=block_k, interpret=bool(interpret),
+                       scale=scale, v_dim=v_dim, window=int(window))
 
 
 # jitted, so that a model's layers share one trace and one lowering of
@@ -488,9 +558,9 @@ def flash_decode_paged(q, new, pool, positions, page_tables,
 # equation of a kernel body costs milliseconds (`PERF.md`, PR 30), and
 # the decode program calls this once a layer
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret", "scale",
-                                             "v_dim"))
-def _paged_call(q, new, pool, positions, page_tables, *, block_k, interpret,
-                scale, v_dim=None):
+                                             "v_dim", "window"))
+def _paged_call(q, new, pool, positions, page_tables, sink=None, *, block_k,
+                interpret, scale, v_dim=None, window=0):
     k = pool["k"]
     H, D, page_size = k.shape[1:]
     B, Hq = q.shape[0], q.shape[2]
@@ -498,7 +568,7 @@ def _paged_call(q, new, pool, positions, page_tables, *, block_k, interpret,
     quant = "k_scale" in pool
     payload = ("k",) if v_dim is not None else ("k", "v")
     names = payload + (("k_scale", "v_scale") if quant else ())
-    Dv = D if v_dim is None else v_dim
+    Dv = v_dim if v_dim is not None else pool["v"].shape[2]
 
     # the query and the output as the kernel sees them: [B, H, D], or
     # with a group axis [B, H, G, D] (a reshape of the model's layout:
@@ -516,15 +586,24 @@ def _paged_call(q, new, pool, positions, page_tables, *, block_k, interpret,
     anywhere = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [pl.BlockSpec((1,) + qshape, row),
                 pl.BlockSpec((len(payload), H, D, _LANES), lanes_of(4))]
-    args = [q.reshape((B,) + qshape),
-            _rows_on_lanes([new[name] for name in payload], k.dtype)]
-    scratch = [pltpu.VMEM((2, H, D, block_k), k.dtype) for _ in payload]
+    fresh = [new[name] for name in payload]
+    if v_dim is None and Dv != D:
+        # beside the key in one array: the value padded to the key's width
+        fresh[1] = jnp.pad(fresh[1], [(0, 0)] * 3 + [(0, D - Dv)])
+    args = [q.reshape((B,) + qshape), _rows_on_lanes(fresh, k.dtype)]
+    scratch = [pltpu.VMEM((2, H, pool[name].shape[2], block_k), k.dtype)
+               for name in payload]
     if quant:
         in_specs.append(pl.BlockSpec((2, H, _LANES), lanes_of(3)))
         args.append(_rows_on_lanes([new["k_scale"], new["v_scale"]],
                                    jnp.float32))
         scratch += [pltpu.VMEM((2, H, block_k), jnp.float32),
                     pltpu.VMEM((2, H, block_k), jnp.float32)]
+    if sink is not None:
+        stat = qshape[:-1] + (1,)
+        in_specs.append(pl.BlockSpec(
+            stat, lambda b, pos_ref, pt_ref: (0,) * len(stat)))
+        args.append(sink.astype(jnp.float32).reshape(stat))
     scratch += [pltpu.SemaphoreType.DMA((len(names), 2, 2)),
                 pltpu.SMEM((2,), jnp.int32)]
     leaves = [pool[name] for name in names]
@@ -539,7 +618,8 @@ def _paged_call(q, new, pool, positions, page_tables, *, block_k, interpret,
     )
     call = pl.pallas_call(
         _paged_decode_kernel(H, D, block_k, page_size // block_k, quant,
-                             G, scale, v_dim),
+                             G, scale, v_dim, None if v_dim else Dv, window,
+                             page_tables.shape[1], sink is not None),
         name=DECODE_PAGED_NAME,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B,) + oshape, q.dtype)]

@@ -597,7 +597,7 @@ def test_the_kernels_file_is_definitions_at_module_level():
         if isinstance(node, (ast.FunctionDef, ast.ClassDef)):
             assert [ast.unparse(d) for d in node.decorator_list] in (
                 [], ["functools.partial(jax.jit, static_argnames="
-                     "('block_k', 'interpret', 'scale', 'v_dim'))"]), \
+                     "('block_k', 'interpret', 'scale', 'v_dim', 'window'))"]), \
                 node.name
             continue
         if isinstance(node, ast.Expr):
@@ -605,3 +605,152 @@ def test_the_kernels_file_is_definitions_at_module_level():
             continue
         assert isinstance(node, ast.Assign) and constant(node.value), \
             ast.unparse(node)
+
+
+# ---------------------------------------------------------------------------
+# values narrower than keys, a window over a ring, a sink (ISSUE 47)
+# ---------------------------------------------------------------------------
+
+# sha256 (first 16 hex digits) of the traced call's jaxpr at the parent
+# of PR 47 (`011388f`), the kernel's body included, in interpret mode on
+# the CPU, where the text is path-free (`PERF.md` 7 (as)): the call with
+# none of v_dim < D, window, sink must be traced to what it was traced to
+GEOMETRIES = {
+    "mha": ((2, 2, 16, jnp.float32), "966ddfcad92e067d"),
+    "gqa": ((2, 8, 16, jnp.bfloat16), "4566682bcb207001"),
+    "latent": ((1, 4, 24, jnp.bfloat16, 16), "7f6541179a81eaef"),
+    "int8": ((2, 2, 16, jnp.int8, None, True), "b157ea577fe40a31"),
+}
+
+
+def _traced(H, Hq, D, dtype, latent_v=None, quant=False):
+    B, ps, npg, ppr = 3, 8, 9, 4
+    pool = {"k": jnp.zeros((npg, H, D, ps), dtype)}
+    new = {"k": jnp.zeros((B, 1, H, D), dtype)}
+    if latent_v is None:
+        pool["v"], new["v"] = pool["k"], new["k"]
+    if quant:
+        for name in ("k_scale", "v_scale"):
+            pool[name] = jnp.zeros((npg, H, ps), jnp.float32)
+            new[name] = jnp.zeros((B, 1, H), jnp.float32)
+    q = jnp.zeros((B, 1, Hq, D), jnp.float32)
+
+    def call(q, new, pool, pos, pt):
+        return flash_decode_paged(
+            q, new, pool, pos, pt, block_k=8, interpret=True,
+            scale=(0.1 if latent_v else None), v_dim=latent_v)
+    return str(jax.make_jaxpr(call)(
+        q, new, pool, jnp.zeros((B,), jnp.int32),
+        jnp.zeros((B, ppr), jnp.int32)))
+
+
+@pytest.mark.parametrize("geometry", sorted(GEOMETRIES))
+def test_the_defaults_are_traced_to_what_they_were(geometry):
+    import hashlib
+    args, want = GEOMETRIES[geometry]
+    text = _traced(*args)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == want
+
+
+def _windowed(window, sink, Dv, block_k=8, N=45):
+    """Rows at several positions over pools of garbage through the
+    kernel, their keys written a step at a time; against float64."""
+    rng = np.random.default_rng(window * 7 + Dv)
+    H, G, D, ps = 2, 4, 24, 8
+    ring = window // ps + 1 if window else 0
+    per = ring or 6
+    B = 3
+    k_pool = rng.normal(size=(B * per + 1, H, D, ps)).astype(np.float32) * 9
+    v_pool = rng.normal(size=(B * per + 1, H, Dv, ps)).astype(np.float32) * 9
+    pool = {"k": jnp.asarray(k_pool), "v": jnp.asarray(v_pool)}
+    tables = np.zeros((B, per), np.int32)
+    tables[0] = np.arange(per, 0, -1)
+    tables[2] = np.arange(2 * per + 1, 3 * per + 1)     # row 1: no request
+    starts = np.asarray([0, 0, 3])      # row 2 is three tokens ahead
+    ks = rng.normal(size=(B, N + 3, H, D)).astype(np.float32)
+    vs = rng.normal(size=(B, N + 3, H, Dv)).astype(np.float32)
+    qs = rng.normal(size=(B, N + 3, H * G, D)).astype(np.float32)
+    b = None if sink is None else jnp.asarray(sink, jnp.float32)
+    worst = 0.0
+    for t in range(N):
+        pos = starts + t
+        # (row 2 has its first three positions written by hand)
+        if t == 0:
+            for p in range(3):
+                page = tables[2, (p // ps) % per]
+                k_pool = np.asarray(pool["k"]).copy()
+                v_pool = np.asarray(pool["v"]).copy()
+                k_pool[page, :, :, p % ps] = ks[2, p]
+                v_pool[page, :, :, p % ps] = vs[2, p]
+                pool = {"k": jnp.asarray(k_pool), "v": jnp.asarray(v_pool)}
+        take = lambda a: jnp.asarray(       # noqa: E731
+            np.stack([a[r, pos[r]] for r in range(B)]))[:, None]
+        out, pool = flash_decode_paged(
+            take(qs), {"k": take(ks), "v": take(vs)}, pool,
+            jnp.asarray(pos * (tables[:, 0] != 0)), jnp.asarray(tables),
+            block_k=block_k, scale=0.2, window=window, sink=b)
+        out = np.asarray(out, np.float64)
+        assert not out[1].any()         # the row without a request
+        for r in (0, 2):
+            p = pos[r]
+            lo = max(0, p - window + 1) if window else 0
+            for h in range(H * G):
+                s = 0.2 * ks[r, lo:p + 1, h // G].astype(np.float64) @ \
+                    qs[r, p, h].astype(np.float64)
+                m = max(s.max(), -np.inf if sink is None else sink[h])
+                e = np.exp(s - m)
+                den = e.sum() + (0 if sink is None else np.exp(sink[h] - m))
+                want = (e / den) @ vs[r, lo:p + 1, h // G]
+                worst = max(worst, np.abs(out[r, 0, h] - want).max())
+    return worst, pool, tables
+
+
+SINK = np.linspace(-1.0, 3.0, 8)
+
+
+@pytest.mark.parametrize("window, sink, Dv", [
+    (0, None, 16), (0, SINK, 24), (16, None, 24), (16, SINK, 16),
+    (8, SINK, 16), (24, SINK, 8)])
+def test_window_sink_and_narrow_values_against_float64(window, sink, Dv):
+    """Every combination the kernel's three additions make: the walk
+    starts at the window's first block of a ring that has wrapped, keys
+    are admitted by position, a sink joins the denominator and no value,
+    the V block is narrower than the K block; stale tenants everywhere."""
+    worst, pool, tables = _windowed(window, sink, Dv)
+    assert worst < 2e-5
+    # a page no live row owns is as it was handed in: row 1's span
+    per = tables.shape[1]
+    assert np.asarray(pool["k"])[per + 1:2 * per + 1].std() > 5
+
+
+def test_window_with_blocks_smaller_than_a_page():
+    worst, _, _ = _windowed(16, SINK, 16, block_k=4, N=30)
+    assert worst < 2e-5
+
+
+def test_paged_grid_blocks_of_a_window():
+    """A window's rows hold the blocks from that of ``p - window + 1``
+    to that of ``p``: at most ``window / block_k + 1``, whatever ``p``."""
+    tables = np.asarray([[3, 1], [0, 0], [2, 4]])
+    for pos, want in (([5, 0, 127], 2), ([128, 9, 255], 3), ([300, 0, 4000], 4),
+                      ([255, 0, 256], 3)):
+        live, launched = paged_grid_blocks(pos, tables, 128, window=128)
+        assert live == launched == want
+    assert paged_grid_blocks([300, 0, 4000], tables, 128) == (35, 35)
+
+
+def test_a_ring_too_small_for_its_window_is_refused():
+    pool = {"k": jnp.zeros((5, 2, 16, 8)), "v": jnp.zeros((5, 2, 16, 8))}
+    new = {"k": jnp.zeros((1, 1, 2, 16)), "v": jnp.zeros((1, 1, 2, 16))}
+    with pytest.raises(ValueError, match="cannot hold a window"):
+        flash_decode_paged(jnp.zeros((1, 1, 2, 16)), new, pool,
+                           jnp.zeros((1,), jnp.int32),
+                           jnp.ones((1, 2), jnp.int32), block_k=8, window=16)
+    quant = dict(pool, k_scale=jnp.zeros((5, 2, 8)),
+                 v_scale=jnp.zeros((5, 2, 8)))
+    qnew = dict(new, k_scale=jnp.zeros((1, 1, 2)),
+                v_scale=jnp.zeros((1, 1, 2)))
+    with pytest.raises(ValueError, match="plain storage"):
+        flash_decode_paged(jnp.zeros((1, 1, 2, 16)), qnew, quant,
+                           jnp.zeros((1,), jnp.int32),
+                           jnp.ones((1, 3), jnp.int32), block_k=8, window=16)

@@ -381,3 +381,159 @@ class TestFlashDecodeStepWrites:
             if "_write_tokens(" in inspect.getsource(fn)
             and name != "_write_tokens"]
         assert callers == ["paged_write_kv"]
+
+
+# ---------------------------------------------------------------------------
+# groups of page layers: heads of their own, values narrower than keys, a
+# window over a ring (ISSUE 47)
+# ---------------------------------------------------------------------------
+
+def _two_groups(**kw):
+    from deepspeed_tpu.inference.cache import page_pool_spec
+    kw.setdefault("groups", (("full", ("f0", "f1"), 2, 24, 16, 0),
+                             ("window", ("w0",), 4, 24, 16, 16)))
+    return page_pool_spec(3, 64, n_layer=3, n_head=2, head_dim=24,
+                          compute_dtype=jnp.float32, n_positions=64,
+                          page_size=8, **kw)
+
+
+def test_two_groups_in_one_spec():
+    from deepspeed_tpu.inference.cache import page_pool_spec, payload_shape
+    spec = _two_groups()
+    full, window = spec.page_groups
+    assert (spec.n_layer, spec.layers) == (3, ("f0", "f1", "w0"))
+    assert (spec.pages_per_row, spec.ring_pages, spec.table_width) == \
+        (8, 3, 11)
+    assert full.n_pages == 3 * 8 + 1 and window.n_pages == 3 * 3 + 1
+    cache = init_kv_cache(spec)
+    assert {n: (l["k"].shape, l["v"].shape) for n, l in cache.items()} == {
+        "f0": ((25, 2, 24, 8), (25, 2, 16, 8)),
+        "f1": ((25, 2, 24, 8), (25, 2, 16, 8)),
+        "w0": ((10, 4, 24, 8), (10, 4, 16, 8))}
+    assert payload_shape(spec, window, "v") == (10, 4, 16, 8)
+    assert kv_cache_nbytes(cache) == 4 * 8 * (
+        2 * 25 * 2 * 40 + 10 * 4 * 40)
+    assert full.bytes_per_token(2) == 2 * 2 * 40 * 2
+    # a spec that lists no group is its one group, as it ever was
+    plain = page_pool_spec(3, 64, n_layer=2, n_head=2, head_dim=24,
+                           compute_dtype=jnp.float32, n_positions=64,
+                           page_size=8)
+    (one,) = plain.page_groups
+    assert (one.layers, one.n_head, one.head_dim, one.v_dim, one.window,
+            one.n_pages) == (("h_0", "h_1"), 2, 24, 24, 0, 25)
+    assert plain.groups == () and plain.ring_pages == 0 and \
+        plain.table_width == plain.pages_per_row == 8
+
+
+@pytest.mark.parametrize("kw, match", [
+    ({"groups": (("a", ("x",), 2, 24, 16, 16), ("b", ("y",), 2, 24, 16, 8))},
+     "one window"),
+    ({"groups": (("a", ("x",), 2, 24, 16, 12),)}, "whole number of pages"),
+    ({"groups": (("a", ("x",), 2, 16, 24, 0),)}, "no wider than its keys"),
+    ({"kv_cache_dtype": "int8"}, "plain storage"),
+    ({"stacked": True}, "plain storage"),
+])
+def test_groups_refuse_what_they_do_not_hold(kw, match):
+    with pytest.raises(ValueError, match=match):
+        _two_groups(**kw)
+
+
+def test_each_typed_refusal_of_a_ring():
+    from deepspeed_tpu.inference.cache import (WindowRingUnsupported,
+                                               kv_partition_specs,
+                                               refuse_window_ring,
+                                               split_table)
+    spec = _two_groups()
+    with pytest.raises(WindowRingUnsupported, match="the prefix cache") as e:
+        refuse_window_ring(spec, "the prefix cache", "why")
+    assert e.value.feature == "the prefix cache"
+    refuse_window_ring(_two_groups(groups=(("f", ("x",), 2, 24, 16, 0),)),
+                       "anything", "no ring, nothing refused")
+    with pytest.raises(ValueError, match="groups of page layers"):
+        kv_partition_specs(spec)
+    full, ring = split_table(jnp.arange(22).reshape(2, 11), spec.ring_pages)
+    assert full.shape == (2, 8) and ring.tolist() == [[8, 9, 10],
+                                                      [19, 20, 21]]
+    whole, none = split_table(jnp.arange(8)[None], 0)
+    assert whole.shape == (1, 8) and none.shape == (1, 0)
+
+
+def test_a_ring_write_sends_padding_to_the_trash_page():
+    """A chunk of four pages into a ring of three, two and a half of
+    them real: the ring's entries hold pages 0, 1 and 2 of the chunk,
+    the padded page lands on the trash page and overwrites nothing."""
+    from deepspeed_tpu.inference.cache import paged_write_kv
+    spec = _two_groups()
+    pool = init_kv_cache(spec)["w0"]
+    k = jnp.broadcast_to(jnp.arange(32.0)[None, :, None, None] + 1,
+                         (1, 32, 4, 24))
+    v = k[..., :16]
+    ring = jnp.asarray([[7, 2, 5]], jnp.int32)
+    out = paged_write_kv(pool, k, v, jnp.arange(32)[None], ring, ring=True,
+                         n_valid=jnp.asarray([20]))
+    held = np.asarray(out["k"])[:, 0, 0]            # [pages, lanes]
+    assert held[7].tolist() == list(range(1, 9))
+    assert held[2].tolist() == list(range(9, 17))
+    assert held[5].tolist() == list(range(17, 25))  # its padded lanes too
+    assert held[0].tolist() == list(range(25, 33))  # the trash page
+    assert not held[[1, 3, 4, 6, 8, 9]].any()
+    # without n_valid the padded page would have taken entry 0's place
+    out = paged_write_kv(pool, k, v, jnp.arange(32)[None], ring, ring=True)
+    assert np.asarray(out["k"])[7, 0, 0].tolist() == list(range(25, 33))
+
+
+@pytest.mark.parametrize("chunk", [8, 16, 24])
+def test_window_band_equals_the_dense_oracle(chunk):
+    """A window layer's chunks (narrower than, equal to and wider than
+    the window; the last ragged) through the band, each token then
+    through the dense oracle by position: the same outputs as every key
+    and value kept under the window's mask."""
+    from deepspeed_tpu.inference.cache import cached_attention
+    rng = np.random.default_rng(chunk)
+    spec = _two_groups()
+    pool = jax.tree_util.tree_map(
+        lambda a: jnp.asarray(rng.normal(size=a.shape), jnp.float32) * 7,
+        init_kv_cache(spec)["w0"])
+    N, n_pre = 60, 2 * chunk + 5
+    q = rng.normal(size=(N, 8, 24)).astype(np.float32)
+    k = rng.normal(size=(N, 4, 24)).astype(np.float32)
+    v = rng.normal(size=(N, 4, 16)).astype(np.float32)
+    sink = jnp.asarray(rng.normal(size=8), jnp.float32)
+    ring = jnp.asarray([[4, 9, 1]], jnp.int32)
+    got = []
+    for c0 in range(0, n_pre, chunk):
+        nv = min(chunk, n_pre - c0)
+        pad = lambda a: jnp.asarray(np.concatenate(     # noqa: E731
+            [a[c0:c0 + nv], np.full((chunk - nv,) + a.shape[1:], 50.0,
+                                    np.float32)]))[None]
+        y, pool = cached_attention(
+            pad(q), pad(k), pad(v), pool, jnp.arange(c0, c0 + chunk)[None],
+            jnp.float32, ring, scale=0.2, window=16, sink=sink,
+            n_valid=jnp.asarray([nv]), walk=True)
+        got.append(np.asarray(y[0, :nv]))
+    for t in range(n_pre, N):
+        y, pool = cached_attention(
+            jnp.asarray(q[t])[None, None], jnp.asarray(k[t])[None, None],
+            jnp.asarray(v[t])[None, None], pool, jnp.asarray([[t]]),
+            jnp.float32, ring, scale=0.2, window=16, sink=sink, walk=True)
+        got.append(np.asarray(y[0]))
+    got = np.concatenate(got)
+    for t in range(N):
+        lo = max(0, t - 15)
+        for h in range(8):
+            s = 0.2 * k[lo:t + 1, h // 2] @ q[t, h]
+            s = np.concatenate([s, [float(sink[h])]])
+            w = np.exp(s - s.max())
+            w /= w.sum()
+            want = w[:-1] @ v[lo:t + 1, h // 2]
+            assert np.abs(got[t, h] - want).max() < 2e-5, (t, h)
+
+
+def test_a_window_or_a_sink_goes_with_walk():
+    from deepspeed_tpu.inference.cache import cached_attention
+    spec = _two_groups()
+    pool = init_kv_cache(spec)["w0"]
+    z = jnp.zeros((1, 1, 4, 24))
+    with pytest.raises(ValueError, match="walk=True"):
+        cached_attention(z, z, z[..., :16], pool, jnp.zeros((1, 1), jnp.int32),
+                         jnp.float32, jnp.ones((1, 3), jnp.int32), window=16)

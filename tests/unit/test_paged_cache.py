@@ -648,3 +648,100 @@ class TestHostPageCorruption:
         mgr.release(row, kv_tokens=prompt, session_id="s")
         r2 = mgr.admit(prompt + [9], session_id="s")
         assert r2.resumed and mgr.host_pages_corrupt == 0
+
+
+# ---------------------------------------------------------------------------
+# a window group's rings (ISSUE 47)
+# ---------------------------------------------------------------------------
+
+class _RingEngine(_PoolEngine):
+    """The stub with a spec of two groups: two full layers and three
+    window layers over a window of two pages."""
+
+    def __init__(self, max_batch=3, **kw):
+        import jax.numpy as jnp
+        from deepspeed_tpu.inference.cache import page_pool_spec
+        kw.setdefault("prefix_cache", False)
+        super().__init__(**kw)
+        self.max_batch = max_batch
+        self.spec = page_pool_spec(
+            max_batch, self.page_size * self.pages_per_row, n_layer=5,
+            n_head=2, head_dim=6, compute_dtype=jnp.bfloat16,
+            n_positions=1024, page_size=self.page_size,
+            n_pages=self.n_pages,
+            groups=(("full", ("a", "b"), 2, 6, 4, 0),
+                    ("window", ("c", "d", "e"), 4, 6, 4,
+                     2 * self.page_size)))
+
+
+class TestWindowRings:
+    def _mgr(self, **kw):
+        eng = _RingEngine(**kw)
+        return eng, PagedCacheManager(eng)
+
+    def test_a_row_takes_a_ring_whatever_its_length(self):
+        _, mgr = self._mgr(n_pages=12)
+        assert (mgr.ring_pages, mgr.table_width) == (3, 4 + 3)
+        short = mgr.admit(list(range(3)), slot=0)
+        long = mgr.admit(list(range(15)), slot=1)
+        assert len(short.ring) == len(long.ring) == 3
+        assert (len(short.pages), len(long.pages)) == (1, 4)
+        assert not set(short.ring) & set(long.ring)
+        assert TRASH_PAGE not in short.ring + long.ring
+        # the table: pages from the left, the ring as the last entries
+        t = long.table(mgr.table_width)
+        assert list(t[:4]) == long.pages and list(t[4:]) == long.ring
+        t = short.table(mgr.table_width)
+        assert list(t[:4]) == short.pages + [0] * 3
+        assert list(t[4:]) == short.ring
+        # growing a row takes full pages and never a ring page
+        assert mgr.ensure_position(short, 4) and len(short.ring) == 3
+        assert mgr.ring_pages_live == 6
+
+    def test_facts_by_group(self):
+        _, mgr = self._mgr(n_pages=12)
+        mgr.admit(list(range(9)), slot=0)
+        groups = mgr.facts()["groups"]
+        # bf16: 2 layers x 2 heads x (6 + 4) and 3 x 4 x 10, x 4 a page
+        assert groups["full"] == {
+            "window": 0, "layers": 2, "pages_live": 3, "pages_total": 11,
+            "page_bytes": 320, "bytes_live": 960, "bytes_total": 3520}
+        assert groups["window"] == {
+            "window": 8, "layers": 3, "pages_live": 3, "pages_total": 9,
+            "page_bytes": 960, "bytes_live": 2880, "bytes_total": 8640}
+        assert mgr.page_bytes() == 320 and mgr.facts()["ring_pages"] == 3
+
+    def test_rings_are_reused_after_release(self):
+        _, mgr = self._mgr(n_pages=20)
+        rows = [mgr.admit([1, 2, 3], slot=i) for i in range(3)]
+        assert mgr.ring_allocator.free_pages == 0
+        # more rows than the pool has rings: nothing leaks
+        free = mgr.allocator.free_pages
+        assert mgr.admit([1, 2, 3], slot=0) is None
+        assert mgr.allocator.free_pages == free
+        gone = list(rows[1].ring)
+        mgr.release(rows[1])
+        assert rows[1].ring == [] and mgr.ring_pages_live == 6
+        again = mgr.admit([4, 5], slot=1)
+        assert sorted(again.ring) == sorted(gone)
+        for row in (rows[0], rows[2], again):
+            mgr.release(row)
+        assert mgr.ring_allocator.free_pages == 9 and mgr.pages_live == 0
+
+    def test_what_parks_or_hands_off_refuses_a_ring(self):
+        from deepspeed_tpu.inference.cache import WindowRingUnsupported
+        _, mgr = self._mgr()
+        with pytest.raises(WindowRingUnsupported, match="park/resume"):
+            mgr.admit([1, 2], session_id="s", slot=0)
+        row = mgr.admit([1, 2], slot=0)
+        with pytest.raises(WindowRingUnsupported, match="park/resume"):
+            mgr.release(row, kv_tokens=[1, 2], session_id="s")
+        with pytest.raises(WindowRingUnsupported, match="handed-off"):
+            mgr.adopt(row)
+
+    def test_a_spec_without_groups_has_no_ring(self):
+        _, mgr = _mgr()
+        assert mgr.ring_pages == 0 and mgr.ring_allocator is None
+        assert mgr.table_width == mgr.pages_per_row
+        row = mgr.admit([1, 2, 3])
+        assert row.ring == [] and mgr.facts()["groups"] == {}
