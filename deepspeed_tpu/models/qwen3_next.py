@@ -342,17 +342,15 @@ class GatedDeltaNet(nn.Module):
         g = -A * jax.nn.softplus(ba[..., Hv:] + dt_bias.astype(jnp.float32))
         state, window = leaves["gdn"], leaves["conv"]
 
-        def heads(u, repeat):
+        def heads(u):
             """The convolved channels ``u`` ``[rows, conv_dim]`` as the
             recurrence takes them: ``q``, ``k`` ``[rows, Hk, K]`` (unit
-            length, ``q`` scaled; under ``repeat`` a key head repeated
-            for its value heads, as the step takes them), ``v``
-            ``[rows, Hv, V]``."""
+            length, ``q`` scaled; a key head serves ``Hv / Hk`` value
+            heads), ``v`` ``[rows, Hv, V]``."""
             q = _l2_normalised(u[:, :d_k].reshape(-1, Hk, K)) * K ** -0.5
             k = _l2_normalised(u[:, d_k:2 * d_k].reshape(-1, Hk, K))
-            q, k = (jnp.repeat(a.astype(cfg.dtype), repeat, axis=1)
-                    for a in (q, k))
-            return q, k, u[:, 2 * d_k:].reshape(-1, Hv, V)
+            return q.astype(cfg.dtype), k.astype(cfg.dtype), \
+                u[:, 2 * d_k:].reshape(-1, Hv, V)
 
         if T == 1:
             live = n_valid > 0
@@ -362,7 +360,7 @@ class GatedDeltaNet(nn.Module):
                 u = jax.nn.silu(u).astype(cfg.dtype)
             with jax.named_scope("ds_gdn_step"):
                 o, state = gated_delta.gated_delta_step(
-                    *heads(u, Hv // Hk), g[:, 0], beta[:, 0], state, live)
+                    *heads(u), g[:, 0], beta[:, 0], state, live)
             o = o[:, None]                              # [B, 1, Hv, V]
         elif B == 1:
             slot, n = slots[0], n_valid[0]
@@ -382,7 +380,7 @@ class GatedDeltaNet(nn.Module):
                 # nothing
                 real = jnp.arange(T)[:, None] < n
                 o, s1 = gated_delta.gated_delta_chunked(
-                    *heads(u, 1), jnp.where(real, g[0], 0.0),
+                    *heads(u), jnp.where(real, g[0], 0.0),
                     jnp.where(real, beta[0], 0.0), s0,
                     cfg.delta_chunk_size)
                 state = jax.lax.dynamic_update_index_in_dim(
@@ -546,12 +544,12 @@ class Qwen3NextLM(nn.Module):
         logits = jnp.dot(h, head.astype(cfg.dtype),
                          preferred_element_type=jnp.float32)
         counted = jnp.stack(counted)
-        # the state update is one masked pass over every slot: it moves
-        # on the rows that hold a request and touches all of them
+        # the state update visits the rows that hold a request and no
+        # other (`ops/pallas/gated_delta.py`'s list of live rows)
+        live = (n_valid > 0).sum().astype(jnp.int32)
         values = [*counted[:, :3].sum(0), counted[:, 3].max(),
                   jnp.int32(cfg.experts_held[1] * len(cfg.layer_types)),
-                  (n_valid > 0).sum().astype(jnp.int32), jnp.int32(B),
-                  counted[:, 4].sum()]
+                  live, live, counted[:, 4].sum()]
         return logits, new_cache, dict(zip(COUNTERS, values))
 
     # -- the serving engine's protocol (`inference/engine.py`) -------------

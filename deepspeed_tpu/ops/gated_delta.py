@@ -33,7 +33,7 @@ S_in``. Its output is ``(q e^G) S_in`` plus the causal ``(q k^T e^(G_i
 G))^T`` times them. The state passes from chunk to chunk and from call
 to call in float32. **Decode**
 (:func:`gated_delta_step`) is the recurrence itself, one step for
-every row.
+every row that holds a request.
 
 Precision, fixed by the configuration (`models/qwen3_next.py`): ``g``,
 ``beta``, every decay, the system, its solution and the state are
@@ -45,7 +45,8 @@ runs on float32 operands at the highest precision.
 Padding: a token with ``g`` 0 and ``beta`` 0 decays nothing and writes
 nothing (its delta is 0 whatever ``k`` and ``v`` are), so the caller
 masks a ragged tail by zeroing both; a dead decode row keeps its state
-bit for bit (``live``).
+bit for bit (``live``): the step neither reads nor writes it, and the
+row's output is zero.
 
 Which form runs where: :func:`gated_delta_chunked`, the name the mixer
 (`models/qwen3_next.py`) calls under the scope ``ds_gdn_scan``, is one
@@ -59,7 +60,15 @@ algebra in plain XLA (`jax.lax.linalg.triangular_solve`, the chunks
 under ``lax.scan``): the form the tests hold the kernel to, run by no
 program (on the chip 2.14 ms a layer a 1,024-token call against the
 kernel's 0.67, two thirds of it XLA's ``InvertDiagBlocksLowerTriangular``;
-`PERF.md` section 6, PR 44). The step (``ds_gdn_step``) is plain XLA.
+`PERF.md` section 6, PR 44). :func:`gated_delta_step`, which the mixer
+calls under the scope ``ds_gdn_step``, is one Pallas kernel call too
+(``ds_gdn_step_rows``: a grid step takes one row of a list of the live
+rows made in the program, all its heads' state in one block, and the
+state is the call's own output, so a row off the list costs nothing);
+:func:`gated_delta_step_plain` is the masked pass over every row it
+replaced (on the chip 7.8 ms a decode step's six layers at 128 rows
+whatever is live, against 1.5 at 36 live rows; `PERF.md` section 6,
+PR 50), the tests' yardstick, run by no program.
 """
 
 import jax
@@ -149,15 +158,25 @@ def gated_delta_chunked_plain(q, k, v, g, beta, state, chunk):
 
 
 def gated_delta_step(q, k, v, g, beta, state, live):
-    """One step of every row. ``q``, ``k`` ``[R, H, K]``, ``v`` ``[R, H,
-    V]``, ``g``, ``beta`` ``[R, H]`` float32, ``state`` ``[R, H, K, V]``
-    float32, ``live`` ``[R]`` bool. Returns ``(o [R, H, V] float32, new
-    state)``; a row that is not live keeps its state."""
-    k32, q32 = k.astype(_F32), q.astype(_F32)
+    """One step of every live row. ``q``, ``k`` ``[R, Hk, K]`` (``Hk``
+    dividing ``v``'s ``H`` heads, as in :func:`gated_delta_chunked`),
+    ``v`` ``[R, H, V]``, ``g``, ``beta`` ``[R, H]`` float32, ``state``
+    ``[R, H, K, V]`` float32, ``live`` ``[R]`` bool. Returns ``(o [R, H,
+    V] float32, new state)``; a row that is not live keeps its state,
+    which is neither read nor written, and its ``o`` is zero."""
+    return _kernel.gated_delta_step(q, k, v, g, beta, state, live)
+
+
+def gated_delta_step_plain(q, k, v, g, beta, state, live):
+    """:func:`gated_delta_step` in plain XLA: one masked pass over every
+    row."""
+    H = v.shape[1]
+    q32, k32 = (jnp.repeat(a.astype(_F32), H // a.shape[1], axis=1)
+                for a in (q, k))
     decayed = jnp.exp(g)[..., None, None] * state
     read = jnp.sum(decayed * k32[..., None], axis=-2)   # S^T k
     delta = beta[..., None] * (v.astype(_F32) - read)
     new = decayed + k32[..., None] * delta[..., None, :]
     o = jnp.sum(new * q32[..., None], axis=-2)          # S^T q
-    state = jnp.where(live[:, None, None, None], new, state)
-    return o, state
+    live = live[:, None, None]
+    return jnp.where(live, o, 0.0), jnp.where(live[..., None], new, state)

@@ -1,10 +1,11 @@
-"""The chunked gated delta rule of one prefill call as one kernel.
+"""The gated delta rule's two kernels: the chunked form of one prefill
+call, and one decode step over the rows that hold a request.
 
 `ops/gated_delta.py` has the recurrence and the WY form a prefill call
-runs; this is that form with a chunk's matrices in VMEM. See the
-module's docstring there for the algebra and the precision, which this
-file keeps: float32 ``g``, ``beta``, decays, system, solution and
-state; ``k k^T`` and ``q k^T`` from operands in the compute dtype,
+runs; the first kernel is that form with a chunk's matrices in VMEM.
+See the module's docstring there for the algebra and the precision,
+which this file keeps: float32 ``g``, ``beta``, decays, system, solution
+and state; ``k k^T`` and ``q k^T`` from operands in the compute dtype,
 accumulated in float32; every product that reads or writes the state,
 and every product of the solve, on float32 operands at the highest
 precision.
@@ -47,9 +48,45 @@ precision.
   follow in the same grid step, chunk after chunk; ``W`` and ``q
   e^G`` meet the state in one product.
 
-The call is jitted, so a model's layers share one trace and one
-lowering (`PERF.md`, PR 30). Off-TPU it runs in Pallas interpret mode;
-`tests/unit/test_tpu_compile_qwen3_next.py` compiles it for a
+**The decode step** (`gated_delta_step`, HLO name ``ds_gdn_step_rows``;
+PR 50) is the recurrence itself, and what it costs is the state it
+moves: 2 MB a row a layer in and 2 MB out at the cell's 32 heads of 128
+x 128 float32, against seven operations an element. So the kernel
+visits the rows that hold a request and no other:
+
+- **a list of the live rows**, made in the program from the mixer's
+  ``live`` (a live row's place is the running count of the flags before
+  it; 128 flags, one compare and sum), goes in as scalar-prefetched
+  ``(rows [R], n)`` beside the rows' decays ``e^g`` and ``beta``, one
+  float32 scalar a row a head in SMEM. The grid is ``(R,)``: step ``i <
+  n`` takes row ``rows[i]``'s whole state ``[Hv, K, V]`` as one block,
+  in and out; a step behind the list maps to the block the last live
+  step had, so nothing is fetched, computed or written for it (~0.1 us
+  a step on the chip: 92 dead steps of 128 are 1.5 us a layer; a
+  grid bound that is the count itself compiles too and saves no more
+  than that; `PERF.md` section 6, PR 50).
+- **the state is the call's own output** (``input_output_aliases``):
+  a row off the list is not read, not written and not copied. With no
+  row live the pipeline still writes back the one block the grid ends
+  on, so the first step hands row 0's state through as it came.
+- **in the grid step**, ``q`` and ``k`` come as columns (``[K, 2 Hk]``,
+  float32, transposed outside: a key head's column across the lanes is
+  one lane broadcast, shared by its ``Hv / Hk`` value heads); a head's
+  ``[K, V]`` state meets its scalar decay, the read ``S^T k`` and the
+  output ``S^T q`` are sums over the sublanes, the outer product a
+  sublane broadcast of the delta: float32 products and sums on the
+  vector units, as the plain pass has them, no MXU pass. The heads are
+  unrolled.
+- a dead row's block of ``o`` is never written: the caller's ``where``
+  makes it zero.
+
+On the chip the step runs at the speed of its DMA: a kernel that only
+copies a live row's state through takes the same time (`PERF.md`
+section 6, PR 50).
+
+Both calls are jitted, so a model's layers share one trace and one
+lowering (`PERF.md`, PR 30). Off-TPU they run in Pallas interpret mode;
+`tests/unit/test_tpu_compile_qwen3_next.py` compiles them for a
 described v5e at the serving cell's shape.
 """
 
@@ -211,3 +248,91 @@ def gated_delta_chunked(q, k, v, g, beta, state, chunk):
     return _chunked_call(q, k, v, g.astype(_F32), beta.astype(_F32),
                          state.astype(_F32), chunk=int(chunk),
                          interpret=interpret)
+
+
+# the step kernel's name in the HLO and in a device trace
+GATED_DELTA_STEP_NAME = "ds_gdn_step_rows"
+
+
+def _step_kernel(Hk, group, K, V):
+    Hv = Hk * group
+
+    def kernel(rows_ref, n_ref, decay_ref, beta_ref, qk_ref, v_ref, s_in,
+               o_ref, s_ref):
+        i, n = pl.program_id(0), n_ref[0]
+
+        @pl.when(i < n)
+        def _():
+            at = rows_ref[i] * Hv
+            qk = qk_ref[0]                              # [K, 2 Hk]
+            v32 = v_ref[0].astype(_F32)                 # [Hv, V]
+            for h in range(Hk):
+                # a key head's q and k, columns, across the lanes
+                q = jnp.broadcast_to(qk[:, h:h + 1], (K, V))
+                k = jnp.broadcast_to(qk[:, Hk + h:Hk + h + 1], (K, V))
+                for j in range(h * group, (h + 1) * group):
+                    S = decay_ref[at + j] * s_in[0, j]  # [K, V]
+                    read = jnp.sum(S * k, axis=0, keepdims=True)
+                    delta = beta_ref[at + j] * (v32[j:j + 1] - read)
+                    S = S + k * delta
+                    s_ref[0, j] = S
+                    o_ref[0, j:j + 1] = jnp.sum(S * q, axis=0, keepdims=True)
+
+        # the pipeline writes the block the grid ends on whatever the
+        # steps did: with no row live it is row 0's, handed through
+        @pl.when((i == 0) & (n == 0))
+        def _():
+            s_ref[...] = s_in[...]
+
+    return kernel
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _step_call(q, k, v, g, beta, state, live, *, interpret):
+    R, Hk, K = q.shape
+    Hv, V = v.shape[1:]
+    # the live rows' numbers in order, then zeros; their count
+    idx = jnp.arange(R, dtype=jnp.int32)
+    count = jnp.cumsum(live.astype(jnp.int32))
+    rows = jnp.sum(jnp.where(live & (count - 1 == idx[:, None]), idx, 0),
+                   axis=1)
+    # q and k as columns: [R, K, 2 Hk]
+    qk = jnp.swapaxes(jnp.concatenate([q, k], axis=1).astype(_F32), 1, 2)
+
+    def listed(i, rows_ref, n_ref, *_):
+        # grid steps behind the list stay on its last row: nothing moves
+        return rows_ref[jnp.minimum(i, jnp.maximum(n_ref[0] - 1, 0))]
+    row3 = lambda *a: (listed(*a), 0, 0)                # noqa: E731
+    row4 = lambda *a: (listed(*a), 0, 0, 0)             # noqa: E731
+    call = pl.pallas_call(
+        _step_kernel(Hk, Hv // Hk, K, V),
+        name=GATED_DELTA_STEP_NAME,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(R,),
+            in_specs=[pl.BlockSpec((1, K, 2 * Hk), row3),
+                      pl.BlockSpec((1, Hv, V), row3),
+                      pl.BlockSpec((1, Hv, K, V), row4)],
+            out_specs=[pl.BlockSpec((1, Hv, V), row3),
+                       pl.BlockSpec((1, Hv, K, V), row4)]),
+        out_shape=[jax.ShapeDtypeStruct((R, Hv, V), _F32),
+                   jax.ShapeDtypeStruct(state.shape, _F32)],
+        # operand 6 (the four scalar operands count) is the state, and
+        # so is output 1: a row that is not listed is not touched
+        input_output_aliases={6: 1},
+        interpret=interpret,
+    )
+    with jax.named_scope(GATED_DELTA_STEP_NAME):
+        o, state = call(rows, count[-1:], jnp.exp(g).reshape(-1),
+                        beta.reshape(-1), qk, v, state)
+    # a row off the list: a block of ``o`` nobody wrote
+    return jnp.where(live[:, None, None], o, 0.0), state
+
+
+def gated_delta_step(q, k, v, g, beta, state, live):
+    """`ops.gated_delta.gated_delta_step` as one kernel call over the
+    live rows. The compiled kernel on TPU, Pallas interpret mode
+    elsewhere."""
+    interpret = jax.devices()[0].platform != "tpu"
+    return _step_call(q, k, v, g.astype(_F32), beta.astype(_F32),
+                      state.astype(_F32), live, interpret=interpret)

@@ -1,11 +1,15 @@
-"""The chunked delta rule's kernel (`deepspeed_tpu/ops/pallas/
-gated_delta.py`, ISSUE 44) in Pallas interpret mode, through the entry
-point the mixer calls (`ops/gated_delta.py:gated_delta_chunked`),
-against the token-by-token recurrence in float64: at the serving
-cell's widths (keys and values 128 wide, chunks of 64, a call of 1,024
-tokens, one key head and its two value heads) and at the toy widths the
-engine tests use. `tests/unit/test_tpu_compile_qwen3_next.py` compiles
-it for the chip."""
+"""The delta rule's two kernels (`deepspeed_tpu/ops/pallas/
+gated_delta.py`) in Pallas interpret mode, through the entry points the
+mixer calls (`ops/gated_delta.py`). The chunked form of a prefill call
+(`gated_delta_chunked`, ISSUE 44) against the token-by-token recurrence
+in float64: at the serving cell's widths (keys and values 128 wide,
+chunks of 64, a call of 1,024 tokens, one key head and its two value
+heads) and at the toy widths the engine tests use. The decode step over
+a list of live rows (`gated_delta_step`, ISSUE 50) against the plain
+masked pass it replaced (`gated_delta_step_plain`): live patterns,
+input dtypes, rows and key-head groups, dead rows to the bit, and a
+chain of steps. `tests/unit/test_tpu_compile_qwen3_next.py` compiles
+both for the chip."""
 
 import jax
 import jax.numpy as jnp
@@ -205,3 +209,96 @@ def test_an_engine_built_after_a_patch_prefills_through_it(monkeypatch):
         assert float(jnp.abs(s).max()) > 0
         np.testing.assert_array_equal(
             np.asarray(s), np.asarray(s.astype(jnp.bfloat16), np.float32))
+
+
+# --- the decode step over the live rows ---------------------------------------
+
+# value heads, K, V of the step's toy rows
+STEP_HEADS, STEP_K, STEP_V = 4, 16, 8
+# the kernel and the plain pass do the same float32 products; only the
+# order of a sum over K differs (the read ``S^T k``, then ``S^T q`` of a
+# state that holds the first difference). A float32 sum of K terms in
+# another order differs by at most K roundings of 2^-24 of the terms'
+# absolute sum, which for unit keys is at most sqrt(K) of a column's
+# largest entry: K sqrt(K) 2^-24 for a sum, and the two in a row with
+# the outer product between them under 4 of that, of the largest entry.
+STEP_TOL = 4 * STEP_K * STEP_K ** 0.5 * 2.0 ** -24
+LIVE = {"none": lambda R: [], "all": lambda R: range(R),
+        "one": lambda R: [1], "last": lambda R: [R - 1],
+        "scattered": lambda R: [i for i in range(R) if i % 3 == 0 or i == 5]}
+
+
+def step_case(seed, R, group, dtype):
+    q, k, v, g, beta, _ = case(
+        seed, (R, STEP_HEADS // group, STEP_HEADS, STEP_K, STEP_V, None),
+        dtype)
+    state = jax.random.normal(jax.random.PRNGKey(100 + seed),
+                              (R, STEP_HEADS, STEP_K, STEP_V))
+    return q, k, v, g, beta, state
+
+
+def live_rows(pattern, R):
+    live = np.zeros(R, bool)
+    live[list(LIVE[pattern](R))] = True
+    return live
+
+
+@pytest.mark.parametrize("group", [1, 2], ids=["keys-1x", "keys-2x"])
+@pytest.mark.parametrize("R", [4, 16])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("pattern", sorted(LIVE))
+def test_the_step_over_live_rows_against_the_plain_pass(pattern, dtype, R,
+                                                        group):
+    """A live row's state and output are the plain pass's within
+    `STEP_TOL` of the largest entry; a dead row's state is the input's
+    bytes and its output zero."""
+    q, k, v, g, beta, state = step_case(R + group, R, group,
+                                        jnp.dtype(dtype))
+    live = live_rows(pattern, R)
+    o, new = gated_delta.gated_delta_step(q, k, v, g, beta, state,
+                                          jnp.asarray(live))
+    want_o, want_s = gated_delta.gated_delta_step_plain(
+        q, k, v, g, beta, state, jnp.asarray(live))
+    assert o.dtype == new.dtype == jnp.float32
+    assert o.shape == (R, STEP_HEADS, STEP_V) and new.shape == state.shape
+    np.testing.assert_array_equal(np.asarray(new)[~live],
+                                  np.asarray(state)[~live])
+    assert not np.asarray(o)[~live].any()
+    if live.any():
+        close(np.asarray(o)[live], np.asarray(want_o, np.float64)[live],
+              STEP_TOL)
+        close(np.asarray(new)[live], np.asarray(want_s, np.float64)[live],
+              STEP_TOL)
+        # and it moved: the step is not the identity on a live row
+        assert distance(np.asarray(new)[live],
+                        np.asarray(state, np.float64)[live]) > 0.05
+
+
+def test_sixty_four_steps_from_one_state_do_not_drift():
+    """The same rows live for 64 steps, each from the last one's state:
+    the kernel's chain stays within `STEP_TOL` a step of the plain
+    pass's, the dead rows' states are the first step's input still, and
+    the last step is the recurrence's 64th in float64."""
+    R, steps = 4, 64
+    live = live_rows("scattered", R)
+    state = step_case(0, R, 2, jnp.float32)[-1]
+    got, want, exact = state, state, np.asarray(state, np.float64)
+    for t in range(steps):
+        q, k, v, g, beta, _ = step_case(t + 1, R, 2, jnp.float32)
+        o, got = gated_delta.gated_delta_step(q, k, v, g, beta, got,
+                                              jnp.asarray(live))
+        want_o, want = gated_delta.gated_delta_step_plain(
+            q, k, v, g, beta, want, jnp.asarray(live))
+        rows = [recurrence(q[r:r + 1], k[r:r + 1], v[r:r + 1], g[r:r + 1],
+                           beta[r:r + 1], exact[r]) for r in range(R)]
+        exact = np.stack([s if live[r] else exact[r]
+                          for r, (_, s) in enumerate(rows)])
+    close(np.asarray(got)[live], np.asarray(want, np.float64)[live],
+          STEP_TOL * steps)
+    close(np.asarray(o)[live], np.asarray(want_o, np.float64)[live],
+          STEP_TOL * steps)
+    close(np.asarray(got)[live], exact[live], STEP_TOL * steps)
+    close(np.asarray(o)[live],
+          np.stack([o_[0] for o_, _ in rows])[live], STEP_TOL * steps)
+    np.testing.assert_array_equal(np.asarray(got)[~live],
+                                  np.asarray(state)[~live])

@@ -220,7 +220,53 @@ def test_decode_counters_and_dead_rows(engine, tiny):
     assert 0 <= rec["moe_pairs_held"] <= rec["moe_pairs_routed"]
     assert rec["moe_experts_touched"] <= rec["moe_pairs_held"]
     assert rec["moe_pairs_max"] == (1 if rec["moe_pairs_held"] else 0)
-    assert rec["gdn_rows_live"] == 1 and rec["gdn_rows_touched"] == ROWS
+    # the step's kernel visits the live row and no other (ISSUE 50)
+    assert rec["gdn_rows_live"] == 1 and rec["gdn_rows_touched"] == 1
+
+
+def test_a_decode_step_over_two_of_four_rows_against_the_plain_step(
+        tiny, monkeypatch):
+    """Rows 1 and 3 of four decode, rows 0 and 2 hold what earlier
+    tenants left: the live rows' logits are those of the same step with
+    the plain masked pass (`gated_delta_step_plain`) in the kernel's
+    place, the dead rows' states are the bytes they were, and the step
+    counts the rows it visited."""
+    from deepspeed_tpu.telemetry import spans
+    model, params = tiny
+    rows = 4
+    prompts = [np.random.default_rng(20 + r).integers(0, 256, 9 + 3 * r)
+               .tolist() for r in range(rows)]
+    tokens = np.zeros(rows, np.int32)
+    positions = np.zeros(rows, np.int32)
+    tables = np.zeros((rows, SEQ // PAGE), np.int32)
+    for r in (1, 3):
+        tokens[r], positions[r], tables[r] = 5 + r, len(prompts[r]), table(r)
+
+    def step(eng):
+        for r in range(rows):
+            eng.prefill(r, prompts[r], table(r))
+        before = {r: leaves_of(eng, r) for r in (0, 2)}
+        t0 = spans.clock()
+        logits = np.asarray(eng.decode(tokens, positions, tables)[1])
+        rec = [r for r in spans.recent(t0) if r[0].endswith("decode")
+               and r[3] and "gdn_rows_live" in r[3]][-1][3]
+        return logits, before, {r: leaves_of(eng, r) for r in range(rows)}, \
+            rec
+
+    config = dict(INF, max_batch=rows, attention_impl="flash")
+    got, before, after, rec = step(InferenceEngine(model, params,
+                                                   config=config))
+    assert rec["gdn_rows_live"] == rec["gdn_rows_touched"] == 2
+    for r in (0, 2):
+        for name, (S, _) in after[r].items():
+            np.testing.assert_array_equal(S, before[r][name][0])
+    monkeypatch.setattr(gated_delta, "gated_delta_step",
+                        gated_delta.gated_delta_step_plain)
+    want, _, plain, _ = step(InferenceEngine(model, params, config=config))
+    for r in (1, 3):
+        np.testing.assert_allclose(got[r], want[r], atol=1e-4)
+        for name, (S, _) in after[r].items():
+            np.testing.assert_allclose(S, plain[r][name][0], atol=5e-5)
 
 
 def test_state_leaves_from_admit_to_release_and_a_reused_slot(tiny):
@@ -331,10 +377,12 @@ def test_delta_step_against_the_recurrence_with_a_dead_row():
     for i, t in enumerate(rows):
         wo, ws = _recurrence(q[t:t + 1], k[t:t + 1], v[t:t + 1],
                              g[t:t + 1], beta[t:t + 1], state[i])
-        np.testing.assert_allclose(o[i], wo[0], atol=1e-5)
         if live[i]:
+            np.testing.assert_allclose(o[i], wo[0], atol=1e-5)
             np.testing.assert_allclose(new[i], ws, atol=1e-5)
         else:
+            # not visited: its token is thrown away, its state stays
+            assert not np.asarray(o[i]).any()
             np.testing.assert_array_equal(np.asarray(new[i]),
                                           np.asarray(state[i]))
 
@@ -469,14 +517,17 @@ def test_expert_layer_with_dead_tokens_against_a_loop():
 # programs as PR 43 left them, the prefill program's as PR 44 did (its
 # delta rule became one kernel call, in interpret mode here), both as
 # PR 45 did (the held experts became `moe/dropless.py:_held_moe`, loops
-# over the live row tiles, and a counter more rides home); the twin
+# over the live row tiles, and a counter more rides home), both as
+# PR 50 did (the decode step's delta rule became one kernel call over
+# the live rows, `gdn_rows_touched` the rows it visits in both programs,
+# and the mixer no longer repeats its key heads by one); the twin
 # of `tests/unit/test_nemotron_h.py::
 # test_accepted_tiny_programs_lower_to_the_text_they_lowered_to`, whose
 # digests pin granite's, Kimi's and OLMoE's. A later PR that changes
 # one on purpose takes the new hash from this test's message.
 LOWERED = {
-    "qwen3_next.prefill": "81e866cd1bb3f245811a6e8e4a068839a18e963c",
-    "qwen3_next.decode": "4c29440c263998317b2e522b8442ea79118e748e",
+    "qwen3_next.prefill": "331d8944e8342f7c7f6e44ede8df9a1941394c80",
+    "qwen3_next.decode": "2e228a02abc93256503863e20b3473664f23e4af",
 }
 
 

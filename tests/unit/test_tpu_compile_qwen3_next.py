@@ -1,8 +1,9 @@
 """`test_tpu_compile.py` for Qwen3-Next (ISSUE 43): the decode kernel at
 the cell's attention geometry (head size 256, 8 query heads to each of
 2 key heads), the chunked delta rule's kernel at the cell's widths
-(ISSUE 44) and both serving programs of the share at the published
-widths, compiled (not interpreted) for a described ``v5e:2x2`` chip. A
+(ISSUE 44), the delta rule's step over the live rows (ISSUE 50) and both
+serving programs of the share at the published widths, compiled (not
+interpreted) for a described ``v5e:2x2`` chip. A
 file of its own, as `test_tpu_compile_nemotron_h.py` is; the fixtures
 and helpers are `test_tpu_compile.py`'s."""
 
@@ -80,26 +81,82 @@ def test_the_chunked_delta_rule_kernel_compiles(chip, monkeypatch):
     assert big == []
 
 
+def test_the_delta_rule_step_kernel_compiles(chip, monkeypatch):
+    """One layer's decode step at the cell's widths: 128 slots of 32
+    value heads of 128 x 128 float32 under 16 key heads. One Mosaic
+    kernel, a grid step a slot (a step behind the list of live rows
+    stays on the block it has); the state goes out where it came in,
+    and nothing state-shaped is copied, selected or broadcast round
+    it."""
+    from deepspeed_tpu.analysis.hlo import payload_shaped_copies
+    from deepspeed_tpu.ops import gated_delta
+    from deepspeed_tpu.ops.pallas.gated_delta import GATED_DELTA_STEP_NAME
+
+    _compiled_not_interpreted(monkeypatch,
+                              "deepspeed_tpu.ops.pallas.gated_delta")
+    Hk, Hv, K, V = 16, 32, 128, 128
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    args = (chip((ROWS, Hk, K), bf16), chip((ROWS, Hk, K), bf16),
+            chip((ROWS, Hv, V), bf16), chip((ROWS, Hv), f32),
+            chip((ROWS, Hv), f32), chip((ROWS, Hv, K, V), f32),
+            chip((ROWS,), jnp.bool_))
+    lowered = jax.jit(gated_delta.gated_delta_step,
+                      donate_argnums=5).lower(*args)
+    assert kernel_grids(lowered.as_text()) == [(ROWS,)]
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+    assert GATED_DELTA_STEP_NAME in text
+    assert payload_shaped_copies(text, (ROWS, Hv, K, V)) == []
+    assert _state_shaped(text, Hv, K, V) == [GATED_DELTA_STEP_NAME]
+    assert compiled.memory_analysis().alias_size_in_bytes == \
+        ROWS * Hv * K * V * 4
+
+
+def _state_shaped(text, Hv, K, V):
+    """The name of every instruction of a compiled program that takes or
+    gives a whole ``[ROWS, Hv, K, V]`` float32 state, but the program's
+    parameters and what only passes one on (a tuple and its parts, a
+    bitcast), a Mosaic call by its kernel's name."""
+    import re
+
+    state = "f32[%d,%d,%d,%d]" % (ROWS, Hv, K, V)
+    names = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?(\S+) = .*? ([\w-]+)\(", line)
+        if not m or state not in line or m.group(2) in (
+                "parameter", "tuple", "get-tuple-element", "bitcast"):
+            continue
+        kernel = re.search(r'/(ds_\w+)/pallas_call', line) \
+            if "tpu_custom_call" in line else None
+        names.append(kernel.group(1) if kernel else m.group(1))
+    return names
+
+
 @pytest.mark.parametrize("program", ["prefill", "decode"])
 def test_qwen3_next_serving_programs_compile(chip, monkeypatch, program):
-    """Both programs of the share at its published widths (one period:
-    three Gated DeltaNet blocks and an attention block, each with its
-    expert layer), cache donated, as the engine calls them: a prefill
-    chunk of 1024 (sixteen chunks of the delta rule) in a slot and a
-    decode step of 128 rows. Three grouped matmuls an expert layer
-    and, in prefill, one delta-rule kernel a Gated DeltaNet block (no
-    triangular solve of XLA's); the state and the pool are
+    """Both programs of the share at its published widths, cache
+    donated, as the engine calls them: a prefill chunk of 1024 (sixteen
+    chunks of the delta rule) in a slot, over one period (three Gated
+    DeltaNet blocks and an attention block, each with its expert
+    layer), and a decode step of 128 rows over the cell's two periods.
+    Three grouped matmuls an expert layer; in prefill one delta-rule
+    kernel a Gated DeltaNet block (no triangular solve of XLA's), in
+    decode one step kernel a Gated DeltaNet block, the only
+    instruction that names a whole state; the state and the pool are
     updated where they lie; every scope the benchmark's metrics read is
     in the compiled text."""
     from deepspeed_tpu.analysis.hlo import payload_shaped_copies
     from deepspeed_tpu.inference.cache import init_kv_cache
     from deepspeed_tpu.models import qwen3_next as qn
+    from deepspeed_tpu.ops.pallas.gated_delta import GATED_DELTA_STEP_NAME
 
     for name in ("deepspeed_tpu.ops.pallas.flash_decode",
                  "deepspeed_tpu.ops.pallas.gated_delta",
                  "deepspeed_tpu.moe.dropless"):
         _compiled_not_interpreted(monkeypatch, name)
-    cfg = qn.qwen3_next_80b_share(n_layer=4)
+    periods = {"prefill": 1, "decode": 2}[program]
+    cfg = qn.qwen3_next_80b_share(n_layer=4 * periods)
     model = qn.Qwen3NextLM(cfg)
     spec = cfg.cache_spec(ROWS, BUCKET, page_size=PAGE, n_pages=PAGES)
     abstract = lambda tree: jax.tree_util.tree_map(     # noqa: E731
@@ -128,15 +185,15 @@ def test_qwen3_next_serving_programs_compile(chip, monkeypatch, program):
     compiled = jax.jit(fn, donate_argnums=1).lower(
         params, cache, *args).compile()
     text = compiled.as_text()
-    # three grouped matmuls (gate, up, down) in each of the four blocks,
-    # as before the held path became a loop over live tiles (ISSUE 45:
-    # 24 a decode program of the cell's eight blocks), and a block's
-    # unwritten buffer of sorted rows; in prefill the three delta rules'
-    # kernel, in decode the attention block's
+    # a period's Mosaic calls: three grouped matmuls (gate, up, down) in
+    # each of its four blocks, as before the held path became a loop over
+    # live tiles (ISSUE 45), and a block's unwritten buffer of sorted
+    # rows; in prefill the three delta rules' kernel, in decode the
+    # three delta rules' step (ISSUE 50) and the attention block's
     pairs = (CHUNK if program == "prefill" else ROWS) * \
         cfg.num_experts_per_tok
     assert held_experts_calls(text, pairs, cfg.hidden_size) == \
-        ({"prefill": 19, "decode": 17}[program], 12, 4)
+        ({"prefill": 19, "decode": 40}[program], 12 * periods, 4 * periods)
     assert text.count("ds_gated_delta_chunked") >= \
         (3 if program == "prefill" else 0)
     assert "riangular" not in text
@@ -148,6 +205,17 @@ def test_qwen3_next_serving_programs_compile(chip, monkeypatch, program):
     assert ("ds_flash_decode_paged" in text) == (program == "decode")
     assert payload_shaped_copies(text, (ROWS, 32, 128, 128)) == []
     assert payload_shaped_copies(text, (PAGES, 2, 256, PAGE)) == []
+    if program == "decode":
+        # six step kernels, each under the mixer's scope, and no fusion
+        # that takes or gives a whole state: the kernel's aliased
+        # operand is the only thing that names one
+        calls = [line for line in text.splitlines()
+                 if "tpu_custom_call" in line
+                 and GATED_DELTA_STEP_NAME in line]
+        assert len(calls) == 6
+        assert all("ds_gdn_step/" in line for line in calls)
+        assert _state_shaped(text, 32, 128, 128) == \
+            [GATED_DELTA_STEP_NAME] * 6
     # every cache leaf goes out where it came in
     cache_bytes = sum(a.size * a.dtype.itemsize
                       for a in jax.tree_util.tree_leaves(cache))
