@@ -25,7 +25,8 @@ and the losses' sums added up over the chips.
 
 **Routing is a function** (`softmax_top_k`, OLMoE's;
 `softmax_top_k_renorm`, Qwen3-MoE's: the chosen probabilities over
-their sum; `sigmoid_top_k`,
+their sum; `softmax_top_k_scaled`, Laguna's: those times a factor;
+`sigmoid_top_k`,
 DeepSeek-V3's and Kimi-K2's: sigmoid scores, a bias that moves the
 choice and no weight, the chosen scores renormalised and scaled), and
 **an expert layer can hold a share** (ISSUE 34): given ``first_expert``
@@ -176,6 +177,20 @@ def softmax_top_k_renorm(x, router, top_k):
     probs = jax.nn.softmax(router_logits(x, router), axis=-1)
     weights, experts = jax.lax.top_k(probs, top_k)      # [N, k]
     return weights / weights.sum(-1, keepdims=True), experts, {}
+
+
+def softmax_top_k_scaled(scaling, renormalise=True):
+    """Laguna's routing (``moe_routed_scaling_factor``): the weights of
+    `softmax_top_k_renorm` (of `softmax_top_k` if not ``renormalise``)
+    times ``scaling``. Returns a routing function for `dropless_moe`; a
+    sibling and not an argument of those two, whose lowered text other
+    models' tests pin."""
+    plain = softmax_top_k_renorm if renormalise else softmax_top_k
+
+    def route(x, router, top_k):
+        weights, experts, aux = plain(x, router, top_k)
+        return weights * scaling, experts, aux
+    return route
 
 
 def sigmoid_top_k(bias, scaling, renormalise=True):
