@@ -916,17 +916,44 @@ def _grouped_softmax(q, k, v, seen, scale, sink, compute_dtype):
     return y.reshape(N, T, Hq, v.shape[-1])
 
 
+def band_kernel_takes(impl, window):
+    """Whether a window layer's prefill chunk goes through the band's
+    kernel (`ops/pallas/window_prefill.py`): under ``impl="flash"``, where
+    the window is longer than the kernel's smallest query block. A block
+    of ``bq`` queries computes ``bq + window`` keys a query where XLA's
+    band computes ``2 x window``, so at ``window <= bq`` the kernel saves
+    no work and XLA's score tiles are small enough to stay on the chip.
+    On a v5e, a chunk of 1,024, device time a layer a call (my chip runs,
+    PR 52): window 512 under 72 query heads of 128 (Laguna): XLA 2.36 ms,
+    the kernel 0.36 (0.28 its own, the rest the keys' gather); window 128
+    under 64 heads of 192 over values of 128 with a sink (MiMo): XLA
+    0.171 ms, the kernel 0.42 at 128 queries a block (0.32 its own) and
+    0.28 at 256. The engine's counters ask here too."""
+    from deepspeed_tpu.ops.pallas.window_prefill import QUERY_BLOCK
+    return impl == "flash" and int(window) > QUERY_BLOCK
+
+
 def window_prefill_attention(q, k_new, v_new, layer_cache, positions, ring,
-                             *, window, scale, compute_dtype, sink=None):
+                             *, window, scale, compute_dtype, sink=None,
+                             n_valid=None, impl="dense"):
     """One prompt's chunk over its own keys and the ``window`` positions
     before it, which the row's ring still holds (read here, **before**
-    the chunk is written over them): a band. The chunk's queries go in
-    blocks of ``window`` (of the whole chunk where ``window`` does not
-    divide it), a block over its own keys and the ``window`` before
-    them, so the scores are ``[blocks, Hq, window, 2 x window]`` whatever
-    the row's length. Query ``t`` sees ``j`` iff ``0 <= t - j <
-    window``. ``q`` ``[1, T, Hq, D]``, ``k_new`` / ``v_new`` ``[1, T, H,
-    D | Dv]``, ``ring`` ``[1, ring_pages]``; returns ``[1, T, Hq, Dv]``."""
+    the chunk is written over them): a band. Query ``t`` sees ``j`` iff
+    ``0 <= t - j < window``. ``q`` ``[1, T, Hq, D]``, ``k_new`` /
+    ``v_new`` ``[1, T, H, D | Dv]``, ``ring`` ``[1, ring_pages]``;
+    returns ``[1, T, Hq, Dv]``.
+
+    What attends is ``impl``'s. ``"flash"``, where the window is long
+    enough for it (:func:`band_kernel_takes`): the kernel
+    (`ops/pallas/window_prefill.py`: a key head's query heads over the
+    key blocks their band admits, the scores in VMEM; a block of queries
+    wholly behind the chunk's ``n_valid`` real tokens comes back zero).
+    ``"dense"``, or a short window: plain XLA, the parity oracle: the
+    chunk's queries in blocks of ``window`` (of the whole chunk where
+    ``window`` does not divide it), a block over its own keys and the
+    ``window`` before them, so the scores are ``[blocks, Hq, window, 2 x
+    window]`` whatever the row's length. Same operands, same
+    precision."""
     _, T, Hq, D = q.shape
     W = int(window)
     held_k, held_v = paged_read_kv(layer_cache, ring, compute_dtype)
@@ -934,8 +961,17 @@ def window_prefill_attention(q, k_new, v_new, layer_cache, positions, ring,
     # what the ring holds of [c0 - W, c0): position p lies at p modulo
     # the ring's span (before the prompt's start: masked below)
     before = (c0 - W + jnp.arange(W)) % held_k.shape[1]
-    k_ext = jnp.concatenate([held_k[0][before], k_new[0].astype(compute_dtype)])
-    v_ext = jnp.concatenate([held_v[0][before], v_new[0].astype(compute_dtype)])
+    k_before, v_before = held_k[0][before], held_v[0][before]
+    k_new = k_new[0].astype(compute_dtype)
+    v_new = v_new[0].astype(compute_dtype)
+    if band_kernel_takes(impl, W):
+        from deepspeed_tpu.ops.pallas import window_prefill_band
+        return window_prefill_band(
+            q[0], k_before, v_before, k_new, v_new, c0,
+            T if n_valid is None else jnp.reshape(n_valid, ()),
+            window=W, scale=scale, sink=sink)[None]
+    k_ext = jnp.concatenate([k_before, k_new])
+    v_ext = jnp.concatenate([v_before, v_new])
     bq = W if T % W == 0 else T
     nb = T // bq
     span = lambda a: jnp.stack([a[j * bq:j * bq + W + bq] for j in range(nb)])
@@ -1131,7 +1167,7 @@ def _grouped_attention(q, k_new, v_new, layer_cache, positions,
             y = window_prefill_attention(
                 q, k_new, v_new, layer_cache, positions, page_table,
                 window=window, scale=scale, compute_dtype=compute_dtype,
-                sink=sink)
+                sink=sink, n_valid=n_valid, impl=impl)
             return y, paged_write_kv(layer_cache, k_new, v_new, positions,
                                      page_table, ring=True, n_valid=n_valid)
         layer_cache = paged_write_kv(layer_cache, k_new, v_new, positions,

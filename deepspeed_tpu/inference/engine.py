@@ -397,10 +397,12 @@ class InferenceEngine:
         # slot; the whole cache flows through so donation updates it in
         # place. The logits are the last real token's.
         # A latent pool's chunk obeys ``attention_impl`` as a decode
-        # step does (the prefill kernel); any other pool's chunk takes
-        # the dense path whatever it says, and is not told.
+        # step does (the prefill kernel), and so does a window group's
+        # (the band's kernel; a full group's walk takes the dense path
+        # whatever it is told); any other pool's chunk takes the dense
+        # path whatever the engine's says, and is not told.
         attn = {"attn_impl": self.attention_impl} \
-            if self.spec.latent_v_dim else {}
+            if self.spec.latent_v_dim or self.spec.groups else {}
         logits, cache = self.model.serve_apply(
             params, cache, tokens, positions, page_table, slots,
             n_valid, **attn)[:2]
@@ -493,7 +495,8 @@ class InferenceEngine:
             # blocks of the walk over the prompt's calls: a latent pool's
             # (and those the prefill kernel took in: all of them or
             # none), or a full group's layers'
-            from deepspeed_tpu.inference.cache import latent_walk_block
+            from deepspeed_tpu.inference.cache import (band_kernel_takes,
+                                                       latent_walk_block)
             block = latent_walk_block(self.pages_per_row, self.page_size)
             blocks = sum((ci * chunk + chunk - 1) // block + 1
                          for ci in range(start // chunk, padded // chunk))
@@ -503,6 +506,15 @@ class InferenceEngine:
                     blocks if self.attention_impl == "flash" else 0
             else:
                 attrs["attn_prefix_blocks_full"] = blocks
+                # the window layers' bands, one a layer a call, and
+                # those of them the band's kernel took (all of a group's
+                # or none)
+                bands = [(attrs["chunks"] * len(g.layers), g.window)
+                         for g in self.spec.groups if g.window]
+                attrs["attn_window_calls"] = sum(n for n, _ in bands)
+                attrs["attn_window_calls_kernel"] = sum(
+                    n for n, window in bands
+                    if band_kernel_takes(self.attention_impl, window))
         if start:
             refuse_recurrent(
                 self.spec, f"a prefill resumed at token {start}",

@@ -24,6 +24,7 @@ from deepspeed_tpu.ops.pallas.flash_decode import (
 )
 from deepspeed_tpu.ops.pallas.fused_adam import pallas_adam_update
 from deepspeed_tpu.ops.pallas.latent_prefill import flash_prefill_latent_block
+from deepspeed_tpu.ops.pallas.window_prefill import window_prefill_band
 
 __all__ = [
     "DEFAULT_BLOCK_K",
@@ -34,4 +35,5 @@ __all__ = [
     "flash_decode_paged",
     "flash_prefill_latent_block",
     "pallas_adam_update",
+    "window_prefill_band",
 ]

@@ -2,8 +2,10 @@
 cell's two geometries (8 key heads of 128 under 6 and under 9 queries a
 key head: a ``[H, G, D]`` query block whose ``G`` is no multiple of the 8
 sublanes; the window group over a ring of five pages with no sink) and
-both serving programs of the share at the published widths, compiled
-(not interpreted) for a described ``v5e:2x2`` chip. A file of its own,
+both serving programs of the share at the published widths, and since
+ISSUE 52 the band of a window layer's prefill chunk as one kernel,
+compiled (not interpreted) for a described ``v5e:2x2`` chip. A file of
+its own,
 as `test_tpu_compile_mimo_v2.py` is; the fixtures and helpers are
 `test_tpu_compile.py`'s."""
 
@@ -118,3 +120,32 @@ def test_laguna_serving_programs_compile(chip, monkeypatch, program):
     # their exponentials, a full layer's walk a block's [48, 1024, 1024]:
     # temporaries stay under 2 GB
     assert mem.temp_size_in_bytes < 2 * 2 ** 30, mem.temp_size_in_bytes
+
+
+def test_window_band_kernel_compiles(chip, monkeypatch):
+    """The band of a window layer's chunk as one kernel at the cell's
+    geometry (72 query heads over 8 key heads of 128, window 512, a ring
+    of five pages, no sink), through `cached_attention` under
+    ``impl="flash"`` as the prefill program calls it: a grid step a key
+    head and 128 queries of its 9 heads, under the standing scope, and no
+    ``[.., 512, 1024]`` scores left in the program."""
+    from deepspeed_tpu.inference import cache as kvc
+
+    _compiled_not_interpreted(monkeypatch,
+                              "deepspeed_tpu.ops.pallas.window_prefill")
+    bf16 = jnp.bfloat16
+    pool = {x: chip((ROWS * RING + 1, 8, 128, PAGE), bf16) for x in "kv"}
+
+    def fn(pool, q, k, v, positions, table, n_valid):
+        return kvc.cached_attention(
+            q, k, v, pool, positions, bf16, table, impl="flash",
+            scale=128 ** -0.5, window=512, n_valid=n_valid, walk=True)
+    lowered = jax.jit(fn, donate_argnums=0).lower(
+        pool, chip((1, CHUNK, 72, 128), bf16), chip((1, CHUNK, 8, 128), bf16),
+        chip((1, CHUNK, 8, 128), bf16), chip((1, CHUNK), jnp.int32),
+        chip((1, RING), jnp.int32), chip((1,), jnp.int32))
+    assert kernel_grids(lowered.as_text()) == [(8, CHUNK // 128)]
+    text = lowered.compile().as_text()
+    assert "ds_attn_prefill_window/" in text
+    assert "ds_window_prefill_band" in text
+    assert "512,1024]" not in text and "1024,1536]" not in text

@@ -2,7 +2,9 @@
 the cell's two geometries (4 key heads of 16 queries and 8 of 8, keys
 of 192 over values of 128; the window group over a ring of two pages
 with a sink) and both serving programs of the share at the published
-widths, compiled (not interpreted) for a described ``v5e:2x2`` chip. A
+widths, and since ISSUE 52 the band of a window layer's prefill chunk
+as one kernel, compiled (not interpreted) for a described ``v5e:2x2``
+chip. A
 file of its own, as `test_tpu_compile_qwen3_next.py` is; the fixtures
 and helpers are `test_tpu_compile.py`'s."""
 
@@ -128,3 +130,32 @@ def test_mimo_v2_serving_programs_compile(chip, monkeypatch, program):
     # a full layer's walk holds a block's [64, 1024, 1024] float32 scores
     # and little else: temporaries stay under 2 GB
     assert mem.temp_size_in_bytes < 2 * 2 ** 30, mem.temp_size_in_bytes
+
+
+def test_window_band_kernel_compiles(chip, monkeypatch):
+    """The band's kernel at the cell's geometry (64 query heads over 8
+    key heads, keys of 192 over values of 128, window 128, a sink a query
+    head): a grid step a key head and 128 queries of its 8 heads. The
+    cell's call site keeps XLA's band at this window
+    (`cache.band_kernel_takes`: 128 is no longer than the kernel's query
+    block, and XLA's band is the faster there on the chip), so the kernel
+    is compiled by itself."""
+    from deepspeed_tpu.inference.cache import band_kernel_takes
+    from deepspeed_tpu.ops.pallas import window_prefill as wp
+
+    _compiled_not_interpreted(monkeypatch,
+                              "deepspeed_tpu.ops.pallas.window_prefill")
+    assert not band_kernel_takes("flash", 128)
+    bf16 = jnp.bfloat16
+
+    def fn(q, kb, vb, kn, vn, bounds, sink):
+        return wp.window_prefill_band(
+            q, kb, vb, kn, vn, bounds[0], bounds[1], window=128,
+            scale=192 ** -0.5, sink=sink)
+    lowered = jax.jit(fn).lower(
+        chip((CHUNK, 64, 192), bf16), chip((128, 8, 192), bf16),
+        chip((128, 8, 128), bf16), chip((CHUNK, 8, 192), bf16),
+        chip((CHUNK, 8, 128), bf16), chip((2,), jnp.int32),
+        chip((64,), jnp.float32))
+    assert kernel_grids(lowered.as_text()) == [(8, CHUNK // 128)]
+    assert "ds_window_prefill_band" in lowered.compile().as_text()
