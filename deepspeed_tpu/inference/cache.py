@@ -98,6 +98,7 @@ trees and programs are what they were. What moves, shares or rolls back
 pages refuses a ring (:class:`WindowRingUnsupported`).
 """
 
+import contextlib
 import dataclasses
 from typing import Any, Optional
 
@@ -623,35 +624,38 @@ def paged_write_kv(layer_cache, k_new, v_new, positions, page_table,
     entry to spare for them: written, a padded page would take the
     place of one the window still reaches).
     """
-    page_size = layer_cache["k"].shape[-1]
-    B, T = positions.shape
-    entry = positions // page_size
-    if ring:
-        entry = entry % page_table.shape[1]
-    pages = jnp.take_along_axis(page_table, entry, axis=1)  # [B, T]
-    if n_valid is not None:
-        pages = jnp.where(jnp.arange(T)[None] < n_valid[:, None], pages, 0)
-    offs = positions % page_size
-    new = _new_leaves(layer_cache, k_new, v_new)
-    if B == 1 and T <= page_size:
-        return {name: _write_chunk(layer_cache[name], vals[0],
-                                   pages[0, 0], offs[0, 0])
-                for name, vals in new.items()}
-    if B == 1:
-        # a chunk of several whole pages (it starts on a page boundary:
-        # the engine pins prefill_chunk % page_size == 0), page by page
-        out = dict(layer_cache)
-        for j in range(0, T, page_size):
-            for name, vals in new.items():
-                out[name] = _write_chunk(out[name],
-                                         vals[0, j:j + page_size],
-                                         pages[0, j], 0)
-        return out
-    return _write_tokens(
-        layer_cache,
-        {name: vals.reshape((B * T,) + vals.shape[2:])
-         for name, vals in new.items()},
-        pages.reshape(B * T), offs.reshape(B * T))
+    with jax.named_scope("ds_kv_write"):
+        page_size = layer_cache["k"].shape[-1]
+        B, T = positions.shape
+        entry = positions // page_size
+        if ring:
+            entry = entry % page_table.shape[1]
+        pages = jnp.take_along_axis(page_table, entry, axis=1)  # [B, T]
+        if n_valid is not None:
+            pages = jnp.where(jnp.arange(T)[None] < n_valid[:, None],
+                              pages, 0)
+        offs = positions % page_size
+        new = _new_leaves(layer_cache, k_new, v_new)
+        if B == 1 and T <= page_size:
+            return {name: _write_chunk(layer_cache[name], vals[0],
+                                       pages[0, 0], offs[0, 0])
+                    for name, vals in new.items()}
+        if B == 1:
+            # a chunk of several whole pages (it starts on a page
+            # boundary: the engine pins prefill_chunk % page_size == 0),
+            # page by page
+            out = dict(layer_cache)
+            for j in range(0, T, page_size):
+                for name, vals in new.items():
+                    out[name] = _write_chunk(out[name],
+                                             vals[0, j:j + page_size],
+                                             pages[0, j], 0)
+            return out
+        return _write_tokens(
+            layer_cache,
+            {name: vals.reshape((B * T,) + vals.shape[2:])
+             for name, vals in new.items()},
+            pages.reshape(B * T), offs.reshape(B * T))
 
 
 def paged_read_kv(layer_cache, page_table, dtype):
@@ -1091,50 +1095,62 @@ def cached_attention(q, k_new, v_new, layer_cache, positions,
         raise ValueError(
             "a latent pool takes the chunk's latents alone (v_new None) "
             "with the values' width v_dim and the scores' scale")
-    if impl == "flash" and q.shape[1] == 1:
-        y, layer_cache = _flash_attend_paged(
-            q, _new_leaves(layer_cache, k_new, v_new), layer_cache,
-            positions, page_table, block_k, mesh, scale, v_dim)
-        return y.astype(compute_dtype), layer_cache
-    layer_cache = paged_write_kv(layer_cache, k_new, v_new, positions,
-                                 page_table)
-    if latent and q.shape[1] > 1:
-        if q.shape[0] != 1 or expand is None:
-            raise ValueError(
-                "a latent pool attends several tokens at once only as "
-                "one prompt's chunk (one row), through expand")
-        return latent_prefill_attention(
-            q, layer_cache, positions, page_table, expand=expand,
-            scale=scale, compute_dtype=compute_dtype,
-            impl=impl), layer_cache
-    if mask is None:
-        mask = attention_mask(layer_cache, positions, page_table)
-    k_full, v_full = paged_read_kv(layer_cache, page_table, compute_dtype)
-    if latent:
-        v_full = k_full[..., :v_dim]
-    B, T, Hq, D = q.shape
-    H = k_full.shape[2]
-    if scale is None:
-        scale = 1.0 / jnp.sqrt(jnp.asarray(D, compute_dtype))
-    else:
-        # a latent's scale (0.1447) is no bfloat16 number
-        scale = jnp.asarray(scale, jnp.float32 if latent else compute_dtype)
-    if Hq == H:
-        att = jnp.einsum("bthd,bshd->bhts", q, k_full) * scale
-        att = jnp.where(mask[:, None], att, jnp.finfo(att.dtype).min)
-        att = jax.nn.softmax(att.astype(jnp.float32),
-                             axis=-1).astype(compute_dtype)
-        return jnp.einsum("bhts,bshd->bthd", att, v_full), layer_cache
-    # grouped queries: the G query heads of a group share a key head
-    # (scores kept in float32 from the product to the softmax)
-    qg = q.reshape(B, T, H, Hq // H, D)
-    att = jnp.einsum("bthgd,bshd->bhgts", qg, k_full,
-                     preferred_element_type=jnp.float32)
-    att = jnp.where(mask[:, None, None], att * scale.astype(jnp.float32),
-                    jnp.finfo(jnp.float32).min)
-    att = jax.nn.softmax(att, axis=-1).astype(compute_dtype)
-    y = jnp.einsum("bhgts,bshd->bthgd", att, v_full)
-    return y.reshape(B, T, Hq, v_full.shape[-1]), layer_cache
+    # a latent pool's call lies under its caller's ds_mla_*_attn
+    with contextlib.nullcontext() if latent else \
+            jax.named_scope(plain_scope(q.shape[1])):
+        if impl == "flash" and q.shape[1] == 1:
+            y, layer_cache = _flash_attend_paged(
+                q, _new_leaves(layer_cache, k_new, v_new), layer_cache,
+                positions, page_table, block_k, mesh, scale, v_dim)
+            return y.astype(compute_dtype), layer_cache
+        layer_cache = paged_write_kv(layer_cache, k_new, v_new, positions,
+                                     page_table)
+        if latent and q.shape[1] > 1:
+            if q.shape[0] != 1 or expand is None:
+                raise ValueError(
+                    "a latent pool attends several tokens at once only as "
+                    "one prompt's chunk (one row), through expand")
+            return latent_prefill_attention(
+                q, layer_cache, positions, page_table, expand=expand,
+                scale=scale, compute_dtype=compute_dtype,
+                impl=impl), layer_cache
+        if mask is None:
+            mask = attention_mask(layer_cache, positions, page_table)
+        k_full, v_full = paged_read_kv(layer_cache, page_table,
+                                       compute_dtype)
+        if latent:
+            v_full = k_full[..., :v_dim]
+        B, T, Hq, D = q.shape
+        H = k_full.shape[2]
+        if scale is None:
+            scale = 1.0 / jnp.sqrt(jnp.asarray(D, compute_dtype))
+        else:
+            # a latent's scale (0.1447) is no bfloat16 number
+            scale = jnp.asarray(scale,
+                                jnp.float32 if latent else compute_dtype)
+        if Hq == H:
+            att = jnp.einsum("bthd,bshd->bhts", q, k_full) * scale
+            att = jnp.where(mask[:, None], att, jnp.finfo(att.dtype).min)
+            att = jax.nn.softmax(att.astype(jnp.float32),
+                                 axis=-1).astype(compute_dtype)
+            return jnp.einsum("bhts,bshd->bthd", att, v_full), layer_cache
+        # grouped queries: the G query heads of a group share a key head
+        # (scores kept in float32 from the product to the softmax)
+        qg = q.reshape(B, T, H, Hq // H, D)
+        att = jnp.einsum("bthgd,bshd->bhgts", qg, k_full,
+                         preferred_element_type=jnp.float32)
+        att = jnp.where(mask[:, None, None],
+                        att * scale.astype(jnp.float32),
+                        jnp.finfo(jnp.float32).min)
+        att = jax.nn.softmax(att, axis=-1).astype(compute_dtype)
+        y = jnp.einsum("bhgts,bshd->bthgd", att, v_full)
+        return y.reshape(B, T, Hq, v_full.shape[-1]), layer_cache
+
+
+def plain_scope(tokens):
+    """The scope of :func:`cached_attention` outside page groups and
+    latents, by the tokens a row brings: a decode step or a chunk."""
+    return "ds_attn_decode_plain" if tokens == 1 else "ds_attn_prefill_plain"
 
 
 def _grouped_attention(q, k_new, v_new, layer_cache, positions,

@@ -86,6 +86,8 @@ off, and an explicit ``prefix_cache``, speculative decoding, a tier, a
 (:class:`~deepspeed_tpu.inference.cache.WindowRingUnsupported`).
 """
 
+import copy
+
 import numpy as np
 
 import jax
@@ -102,6 +104,7 @@ from deepspeed_tpu.inference.cache import (
     refuse_window_ring,
 )
 from deepspeed_tpu.inference.paging import TRASH_PAGE
+from deepspeed_tpu.telemetry import programs
 from deepspeed_tpu.telemetry.spans import Span, enclosing_attr
 
 DEFAULT_MAX_BATCH = 8
@@ -362,6 +365,7 @@ class InferenceEngine:
         # allocator churn never reaches a jit boundary.
         self._prefill = donated_jit(self._prefill_fn, donate_argnums=(1,))
         self._decode = donated_jit(self._decode_fn, donate_argnums=(1,))
+        self._register_programs()
 
         # speculative decoding (inference.speculative block): a draft
         # + verify program pair hung off the engine, or None when the
@@ -378,6 +382,23 @@ class InferenceEngine:
                 "break the one-program-per-tier contract")
 
     # -- compiled programs --------------------------------------------------
+
+    def _register_programs(self):
+        """The two programs by name, for whoever joins a profiler trace
+        with their scopes (`telemetry/programs.py`). What is registered
+        is a copy of this engine that holds shapes where it holds
+        arrays and no jitted program, so it neither keeps the
+        parameters and the pool on the device nor the engine alive, and
+        can still trace ``_prefill_fn`` / ``_decode_fn`` once the engine
+        is gone. Nothing is traced or lowered here."""
+        twin = copy.copy(self)
+        twin.params, twin.cache = programs.shapes((self.params, self.cache))
+        twin._prefill = twin._decode = twin.speculative = None
+        donated = self._decode._ds_donate_argnums   # the pool, in both
+        programs.register("prefill", lambda: (
+            twin._prefill_fn, donated, twin.prefill_lowering_args()))
+        programs.register("decode", lambda: (
+            twin._decode_fn, donated, twin.decode_lowering_args()))
 
     def _pin_cache(self, cache):
         """Constrain the output cache to the same shardings the input
@@ -409,7 +430,9 @@ class InferenceEngine:
         # fp32 on the way out: a reader gets full precision whatever the
         # compute dtype (a no-op for f32 models: parity stays bit-exact).
         # prefill() copies this one row home; decode() copies no logits.
-        return logits.astype(jnp.float32), self._pin_cache(cache)
+        with jax.named_scope("ds_head"):
+            logits = logits.astype(jnp.float32)
+        return logits, self._pin_cache(cache)
 
     def _decode_fn(self, params, cache, tokens, positions, page_tables,
                    key):
@@ -426,17 +449,18 @@ class InferenceEngine:
             attn_impl=self.attention_impl,
             attn_block_k=self.attention_block_k, attn_mesh=mesh)
         from deepspeed_tpu.inference.sampling import sample_logits
-        next_tokens, key = sample_logits(
-            logits, key, temperature=self.temperature,
-            top_k=self.top_k, top_p=self.top_p)
-        if self._counter_names:
-            # behind the tokens, so that they come back in the tokens'
-            # own transfer: decode() takes them off again
-            next_tokens = jnp.concatenate([next_tokens, jnp.stack([
-                counters[0][name].astype(next_tokens.dtype)
-                for name in self._counter_names])])
-        return next_tokens, logits.astype(jnp.float32), key, \
-            self._pin_cache(cache)
+        with jax.named_scope("ds_sample"):
+            next_tokens, key = sample_logits(
+                logits, key, temperature=self.temperature,
+                top_k=self.top_k, top_p=self.top_p)
+            if self._counter_names:
+                # behind the tokens, so that they come back in the
+                # tokens' own transfer: decode() takes them off again
+                next_tokens = jnp.concatenate([next_tokens, jnp.stack([
+                    counters[0][name].astype(next_tokens.dtype)
+                    for name in self._counter_names])])
+            logits = logits.astype(jnp.float32)
+        return next_tokens, logits, key, self._pin_cache(cache)
 
     # -- host API -----------------------------------------------------------
 

@@ -139,12 +139,14 @@ class CausalSelfAttention(nn.Module):
         cfg = self.config
         B, T, C = x.shape
         H = cfg.n_head
-        qkv = nn.Dense(3 * C, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-                       dot_general=_fp8_dot("c_attn"), name="c_attn")(x)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(B, T, H, C // H)
-        k = k.reshape(B, T, H, C // H)
-        v = v.reshape(B, T, H, C // H)
+        with jax.named_scope("ds_attn_qkv"):
+            qkv = nn.Dense(3 * C, dtype=cfg.dtype,
+                           param_dtype=cfg.param_dtype,
+                           dot_general=_fp8_dot("c_attn"), name="c_attn")(x)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            q = q.reshape(B, T, H, C // H)
+            k = k.reshape(B, T, H, C // H)
+            v = v.reshape(B, T, H, C // H)
 
         new_cache = None
         if kv_cache is not None:
@@ -163,25 +165,31 @@ class CausalSelfAttention(nn.Module):
             # in training configs — the round-3 gate that forced dense
             # attention whenever dropout was active is gone.
             rate, seed = 0.0, None
-            if not deterministic and cfg.dropout > 0.0:
-                from deepspeed_tpu.ops.pallas.flash_attention import (
-                    dropout_seed_from_rng)
-                rate = cfg.dropout
-                seed = dropout_seed_from_rng(self.make_rng("dropout"))
-            y = flash_attention(q, k, v, causal=True,
-                                dropout_rate=rate, dropout_seed=seed)
+            with jax.named_scope("ds_attn_train"):
+                if not deterministic and cfg.dropout > 0.0:
+                    from deepspeed_tpu.ops.pallas.flash_attention import (
+                        dropout_seed_from_rng)
+                    rate = cfg.dropout
+                    seed = dropout_seed_from_rng(self.make_rng("dropout"))
+                y = flash_attention(q, k, v, causal=True,
+                                    dropout_rate=rate, dropout_seed=seed)
         else:
-            scale = 1.0 / jnp.sqrt(jnp.asarray(C // H, cfg.dtype))
-            att = jnp.einsum("bthd,bshd->bhts", q, k) * scale
-            mask = jnp.tril(jnp.ones((T, T), bool))
-            att = jnp.where(mask[None, None], att, jnp.finfo(att.dtype).min)
-            att = jax.nn.softmax(att.astype(jnp.float32), axis=-1).astype(cfg.dtype)
-            att = nn.Dropout(cfg.dropout)(att, deterministic=deterministic)
-            y = jnp.einsum("bhts,bshd->bthd", att, v)
-        y = y.reshape(B, T, C)
-        y = nn.Dense(C, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-                     dot_general=_fp8_dot("c_proj"), name="c_proj")(y)
-        y = nn.Dropout(cfg.dropout)(y, deterministic=deterministic)
+            with jax.named_scope("ds_attn_train"):
+                scale = 1.0 / jnp.sqrt(jnp.asarray(C // H, cfg.dtype))
+                att = jnp.einsum("bthd,bshd->bhts", q, k) * scale
+                mask = jnp.tril(jnp.ones((T, T), bool))
+                att = jnp.where(mask[None, None], att,
+                                jnp.finfo(att.dtype).min)
+                att = jax.nn.softmax(att.astype(jnp.float32),
+                                     axis=-1).astype(cfg.dtype)
+                att = nn.Dropout(cfg.dropout)(att,
+                                              deterministic=deterministic)
+                y = jnp.einsum("bhts,bshd->bthd", att, v)
+        with jax.named_scope("ds_attn_out"):
+            y = y.reshape(B, T, C)
+            y = nn.Dense(C, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                         dot_general=_fp8_dot("c_proj"), name="c_proj")(y)
+            y = nn.Dropout(cfg.dropout)(y, deterministic=deterministic)
         if kv_cache is not None:
             return y, new_cache
         return y
@@ -228,24 +236,36 @@ class Block(nn.Module):
         ln1 = nn.LayerNorm(dtype=cfg.dtype, name="ln_1")
         ln2 = nn.LayerNorm(dtype=cfg.dtype, name="ln_2")
 
+        # the two halves under their scopes (`telemetry/scopes.py`): the
+        # norm with the projections it feeds, the residual add with the
+        # projection it follows; ``gate`` is PLD's multiplier
+        def attend(h, gate=None, **cached):
+            with jax.named_scope("ds_attn_qkv"):
+                n = ln1(h)
+            out = attn(n, deterministic, **cached)
+            a, new_cache = out if cached else (out, None)
+            with jax.named_scope("ds_attn_out"):
+                h = h + (a if gate is None else gate * a)
+            return (h, new_cache) if cached else h
+
+        def feed(h, gate=None):
+            with jax.named_scope("ds_mlp"):
+                m = mlp(ln2(h), deterministic)
+                return h + (m if gate is None else gate * m)
+
         if kv_cache is not None:
             # incremental decode: PLD never applies (serving is
             # deterministic), and the attention call also returns the
             # layer's updated cache.
-            a, new_cache = attn(ln1(x), deterministic,
-                                positions=positions, kv_cache=kv_cache,
-                                attn_impl=attn_impl,
-                                attn_block_k=attn_block_k,
-                                attn_mesh=attn_mesh, attn_mask=attn_mask,
-                                kv_page_table=kv_page_table)
-            x = x + a
-            x = x + mlp(ln2(x), deterministic)
-            return x, new_cache
+            x, new_cache = attend(
+                x, positions=positions, kv_cache=kv_cache,
+                attn_impl=attn_impl, attn_block_k=attn_block_k,
+                attn_mesh=attn_mesh, attn_mask=attn_mask,
+                kv_page_table=kv_page_table)
+            return feed(x), new_cache
 
         if pld_theta is None or deterministic:
-            x = x + attn(ln1(x), deterministic)
-            x = x + mlp(ln2(x), deterministic)
-            return x
+            return feed(attend(x))
 
         # ``layer_idx`` as a call arg overrides the attribute so the
         # scan_layers path can feed the (traced) loop counter into the
@@ -261,18 +281,10 @@ class Block(nn.Module):
             # multiplicative gate: same dropped-layer values, but the
             # sublayer compute always runs (PLD's FLOP saving is the one
             # thing scan_layers gives up).
-            x = x + jnp.where(coin_a, 1, 0).astype(x.dtype) * \
-                attn(ln1(x), deterministic)
-            x = x + jnp.where(coin_m, 1, 0).astype(x.dtype) * \
-                mlp(ln2(x), deterministic)
-            return x
-        x = jax.lax.cond(coin_a,
-                         lambda h: h + attn(ln1(h), deterministic),
-                         lambda h: h, x)
-        x = jax.lax.cond(coin_m,
-                         lambda h: h + mlp(ln2(h), deterministic),
-                         lambda h: h, x)
-        return x
+            x = attend(x, jnp.where(coin_a, 1, 0).astype(x.dtype))
+            return feed(x, jnp.where(coin_m, 1, 0).astype(x.dtype))
+        x = jax.lax.cond(coin_a, attend, lambda h: h, x)
+        return jax.lax.cond(coin_m, feed, lambda h: h, x)
 
 
 class GPT2LMHead(nn.Module):
@@ -308,16 +320,17 @@ class GPT2LMHead(nn.Module):
                          (cfg.vocab_size, cfg.n_embd), cfg.param_dtype)
         wpe = self.param("wpe", nn.initializers.normal(0.01),
                          (cfg.n_positions, cfg.n_embd), cfg.param_dtype)
-        if positions is None:
-            # training/full-context: positions ARE the sequence index.
-            pos_emb = wpe[None, :T]
-        else:
-            # incremental decode: a [B, T] chunk sits at explicit
-            # absolute positions (past the prefill), so the position
-            # embedding is a gather, not a prefix slice.
-            pos_emb = wpe[positions]
-        x = wte[input_ids].astype(cfg.dtype) + pos_emb.astype(cfg.dtype)
-        x = nn.Dropout(cfg.dropout)(x, deterministic=deterministic)
+        with jax.named_scope("ds_embed"):
+            if positions is None:
+                # training/full-context: positions ARE the sequence index.
+                pos_emb = wpe[None, :T]
+            else:
+                # incremental decode: a [B, T] chunk sits at explicit
+                # absolute positions (past the prefill), so the position
+                # embedding is a gather, not a prefix slice.
+                pos_emb = wpe[positions]
+            x = wte[input_ids].astype(cfg.dtype) + pos_emb.astype(cfg.dtype)
+            x = nn.Dropout(cfg.dropout)(x, deterministic=deterministic)
 
         block_cls = Block
         if cfg.remat:
@@ -341,9 +354,12 @@ class GPT2LMHead(nn.Module):
             # inside the compiled decode program (the flash path masks
             # in-kernel from the positions scalar and needs none; S
             # comes off the page table, not off the pool's shape).
-            from deepspeed_tpu.inference.cache import attention_mask
+            from deepspeed_tpu.inference.cache import (attention_mask,
+                                                       plain_scope)
             layer0 = kv_cache["h" if cfg.scan_layers else "h_0"]
-            attn_mask = attention_mask(layer0, positions, kv_page_table)
+            with jax.named_scope(plain_scope(T)):
+                attn_mask = attention_mask(layer0, positions,
+                                           kv_page_table)
         if cfg.scan_layers and kv_cache is not None:
             # decode over the scanned stack: the per-layer cache slices
             # ride the same lax.scan as the stacked params (in_axes=0
@@ -409,10 +425,11 @@ class GPT2LMHead(nn.Module):
             for i in range(cfg.n_layer):
                 x = block_cls(cfg, layer_idx=i, n_layers=cfg.n_layer,
                               name=f"h_{i}")(x, deterministic, pld_theta)
-        x = nn.LayerNorm(dtype=cfg.dtype, name="ln_f")(x)
-        if return_hidden:
-            return x        # chunked-loss path applies the head itself
-        logits = x @ wte.T.astype(cfg.dtype)
+        with jax.named_scope("ds_head"):
+            x = nn.LayerNorm(dtype=cfg.dtype, name="ln_f")(x)
+            if return_hidden:
+                return x    # chunked-loss path applies the head itself
+            logits = x @ wte.T.astype(cfg.dtype)
         if kv_cache is not None:
             return logits, new_kv
         return logits
@@ -441,8 +458,9 @@ class GPT2LMHead(nn.Module):
             {"params": params}, tokens, deterministic=True,
             positions=positions, kv_cache=cache,
             kv_page_table=page_table, **attn)
-        last = jnp.maximum(n_valid - 1, 0)[:, None, None]
-        return jnp.take_along_axis(logits, last, axis=1)[:, 0], cache
+        with jax.named_scope("ds_head"):
+            last = jnp.maximum(n_valid - 1, 0)[:, None, None]
+            return jnp.take_along_axis(logits, last, axis=1)[:, 0], cache
 
 
 def cross_entropy_sum_and_count(logits, labels, ignore_index=-100):
@@ -590,13 +608,15 @@ def make_gpt2_loss_fn(model: GPT2LMHead):
                 deterministic=rng is None, rngs=rngs,
                 pld_theta=pld_theta if rng is not None else None,
                 return_hidden=True)
-            total, count = chunked_cross_entropy_sum_and_count(
-                hidden, params["wte"], labels, chunk)
-            return total / jnp.maximum(count, 1)
+            with jax.named_scope("ds_loss"):
+                total, count = chunked_cross_entropy_sum_and_count(
+                    hidden, params["wte"], labels, chunk)
+                return total / jnp.maximum(count, 1)
         logits = model.apply({"params": params}, input_ids,
                              deterministic=rng is None, rngs=rngs,
                              pld_theta=pld_theta if rng is not None else None)
-        return cross_entropy_loss(logits, labels)
+        with jax.named_scope("ds_loss"):
+            return cross_entropy_loss(logits, labels)
 
     return loss_fn
 

@@ -264,15 +264,17 @@ class GroupedQueryAttention(nn.Module):
         B, T, C = x.shape
         Hq, Hkv, D = cfg.num_attention_heads, cfg.num_key_value_heads, \
             cfg.head_dim
-        q = _linear(self, "q_proj", cfg, (C, Hq * D), x)
-        k = _linear(self, "k_proj", cfg, (C, Hkv * D), x)
-        v = _linear(self, "v_proj", cfg, (C, Hkv * D), x)
+        with jax.named_scope("ds_attn_qkv"):
+            q = _linear(self, "q_proj", cfg, (C, Hq * D), x)
+            k = _linear(self, "k_proj", cfg, (C, Hkv * D), x)
+            v = _linear(self, "v_proj", cfg, (C, Hkv * D), x)
         y, layer_cache = cached_attention(
             q.reshape(B, T, Hq, D), k.reshape(B, T, Hkv, D),
             v.reshape(B, T, Hkv, D), layer_cache, positions, cfg.dtype,
             page_table, scale=cfg.attention_multiplier, **attn)
-        y = _linear(self, "o_proj", cfg, (Hq * D, C),
-                    y.reshape(B, T, Hq * D))
+        with jax.named_scope("ds_attn_out"):
+            y = _linear(self, "o_proj", cfg, (Hq * D, C),
+                        y.reshape(B, T, Hq * D))
         return y, layer_cache
 
 
@@ -390,17 +392,26 @@ class HybridLayer(nn.Module):
     def __call__(self, h, layer_cache, positions, page_table, slots,
                  n_valid, attn):
         cfg = self.config
-        n = RMSNorm(cfg, name="input_norm")(h)
+        scale = cfg.residual_multiplier
         if self.kind == ATTENTION:
+            # the norm under the scope of the projections it feeds, the
+            # residual add under that of the one it follows
+            with jax.named_scope("ds_attn_qkv"):
+                n = RMSNorm(cfg, name="input_norm")(h)
             y, layer_cache = GroupedQueryAttention(cfg, name="attn")(
                 n, layer_cache, positions, page_table, attn)
-        else:
-            y, layer_cache = Mamba2Mixer(cfg, name="mixer")(
-                n, layer_cache, positions, slots, n_valid)
-        h = h + jnp.asarray(cfg.residual_multiplier, cfg.dtype) * y
-        y = GatedMLP(cfg, name="mlp")(RMSNorm(cfg, name="post_norm")(h))
-        return h + jnp.asarray(cfg.residual_multiplier, cfg.dtype) * y, \
-            layer_cache
+            with jax.named_scope("ds_attn_out"):
+                h = h + jnp.asarray(scale, cfg.dtype) * y
+        else:   # the mixer whole, round its older inner scopes
+            with jax.named_scope("ds_ssm_mixer"):
+                y, layer_cache = Mamba2Mixer(cfg, name="mixer")(
+                    RMSNorm(cfg, name="input_norm")(h), layer_cache,
+                    positions, slots, n_valid)
+                h = h + jnp.asarray(scale, cfg.dtype) * y
+        with jax.named_scope("ds_mlp"):
+            y = GatedMLP(cfg, name="mlp")(RMSNorm(cfg, name="post_norm")(h))
+            h = h + jnp.asarray(scale, cfg.dtype) * y
+        return h, layer_cache
 
 
 class GraniteHybridLM(nn.Module):
@@ -416,8 +427,9 @@ class GraniteHybridLM(nn.Module):
         embed = self.param("embed", _normal(cfg),
                            (cfg.vocab_size, cfg.hidden_size),
                            cfg.param_dtype)
-        h = embed.astype(cfg.dtype)[tokens] * \
-            jnp.asarray(cfg.embedding_multiplier, cfg.dtype)
+        with jax.named_scope("ds_embed"):
+            h = embed.astype(cfg.dtype)[tokens] * \
+                jnp.asarray(cfg.embedding_multiplier, cfg.dtype)
         new_cache = {}
         for i, kind in enumerate(cfg.layer_types):
             name = f"layers_{i}"
@@ -425,12 +437,13 @@ class GraniteHybridLM(nn.Module):
                 h, cache[name], positions, page_table, slots, n_valid,
                 attn)
         # the head reads each row's last real token only
-        last = jnp.maximum(n_valid - 1, 0)[:, None, None]
-        h = jnp.take_along_axis(h, last, axis=1)[:, 0]
-        h = RMSNorm(cfg, name="final_norm")(h)
-        logits = jnp.dot(h, embed.T.astype(cfg.dtype),
-                         preferred_element_type=jnp.float32)
-        return logits / cfg.logits_scaling, new_cache
+        with jax.named_scope("ds_head"):
+            last = jnp.maximum(n_valid - 1, 0)[:, None, None]
+            h = jnp.take_along_axis(h, last, axis=1)[:, 0]
+            h = RMSNorm(cfg, name="final_norm")(h)
+            logits = jnp.dot(h, embed.T.astype(cfg.dtype),
+                             preferred_element_type=jnp.float32)
+            return logits / cfg.logits_scaling, new_cache
 
     # -- the serving engine's protocol (`inference/engine.py`) -------------
 

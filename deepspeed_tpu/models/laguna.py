@@ -318,11 +318,12 @@ class LagunaAttention(nn.Module):
         kind = cfg.kind(self.which)
         B, T, C = x.shape
         Hq, H, D = kind.heads, kind.kv_heads, kind.head_dim
-        q = jnp.dot(x, _param(self, "q_proj", cfg, (C, Hq * D)))
-        k = jnp.dot(x, _param(self, "k_proj", cfg, (C, H * D)))
-        v = jnp.dot(x, _param(self, "v_proj", cfg, (C, H * D)))
-        q = rotary(q.reshape(B, T, Hq, D), positions, kind)
-        k = rotary(k.reshape(B, T, H, D), positions, kind)
+        with jax.named_scope("ds_attn_qkv"):
+            q = jnp.dot(x, _param(self, "q_proj", cfg, (C, Hq * D)))
+            k = jnp.dot(x, _param(self, "k_proj", cfg, (C, H * D)))
+            v = jnp.dot(x, _param(self, "v_proj", cfg, (C, H * D)))
+            q = rotary(q.reshape(B, T, Hq, D), positions, kind)
+            k = rotary(k.reshape(B, T, H, D), positions, kind)
         y, layer_cache = cached_attention(
             q, k, v.reshape(B, T, H, D), layer_cache, positions, cfg.dtype,
             page_table, scale=D ** -0.5, window=kind.window, n_valid=n_valid,
@@ -332,8 +333,9 @@ class LagunaAttention(nn.Module):
                 x, _param(self, "g_proj", cfg, (C, Hq)),
                 preferred_element_type=jnp.float32))        # [B, T, Hq]
             y = (y.astype(jnp.float32) * gate[..., None]).astype(cfg.dtype)
-        y = jnp.dot(y.reshape(B, T, Hq * D),
-                    _param(self, "o_proj", cfg, (Hq * D, C)))
+        with jax.named_scope("ds_attn_out"):
+            y = jnp.dot(y.reshape(B, T, Hq * D),
+                        _param(self, "o_proj", cfg, (Hq * D, C)))
         return y, layer_cache
 
 
@@ -398,17 +400,22 @@ class LagunaLayer(nn.Module):
     def __call__(self, h, layer_cache, positions, page_table, n_valid, mask,
                  attn):
         cfg = self.config
+        # each norm under the scope of what it feeds, each residual add
+        # under that of what it follows (`telemetry/scopes.py`)
+        with jax.named_scope("ds_attn_qkv"):
+            n = RMSNorm(cfg, name="input_norm")(h)
         y, layer_cache = LagunaAttention(cfg, self.which, name="attn")(
-            RMSNorm(cfg, name="input_norm")(h), layer_cache, positions,
-            page_table, n_valid, attn)
-        h = h + y
-        n = RMSNorm(cfg, name="post_attn_norm")(h)
-        if self.dense:
-            y = GatedMLP(cfg, cfg.intermediate_size, name="mlp")(n)
-            counters = jnp.zeros((5,), jnp.int32)
-        else:
-            y, counters = SparseExperts(cfg, name="experts")(n, mask)
-        return h + y, layer_cache, counters
+            n, layer_cache, positions, page_table, n_valid, attn)
+        with jax.named_scope("ds_attn_out"):
+            h = h + y
+        with jax.named_scope("ds_mlp" if self.dense else "ds_experts"):
+            n = RMSNorm(cfg, name="post_attn_norm")(h)
+            if self.dense:
+                y = GatedMLP(cfg, cfg.intermediate_size, name="mlp")(n)
+                counters = jnp.zeros((5,), jnp.int32)
+            else:
+                y, counters = SparseExperts(cfg, name="experts")(n, mask)
+            return h + y, layer_cache, counters
 
 
 class LagunaLM(nn.Module):
@@ -427,14 +434,17 @@ class LagunaLM(nn.Module):
         embed = self.param("embed", _normal(cfg),
                            (cfg.vocab_size, cfg.hidden_size),
                            cfg.param_dtype)
-        h = embed.astype(cfg.dtype)[tokens]
-        # a decode row without a request, a chunk's padded tail
-        mask = jnp.arange(T)[None, :] < n_valid[:, None]
+        with jax.named_scope("ds_embed"):
+            h = embed.astype(cfg.dtype)[tokens]
+            # a decode row without a request, a chunk's padded tail
+            mask = jnp.arange(T)[None, :] < n_valid[:, None]
         # the table's last entries are the row's ring, where there is one
         page_size = next(iter(cache.values()))["k"].shape[-1]
         ring = cfg.sliding_window // page_size + 1 \
             if cfg.names(WINDOW) else 0
-        tables = dict(zip((FULL, WINDOW), split_table(page_table, ring)))
+        with jax.named_scope("ds_embed"):
+            tables = dict(zip((FULL, WINDOW),
+                              split_table(page_table, ring)))
         new_cache, counted = {}, []
         for i, which in enumerate(cfg.layer_kinds):
             name = f"layers_{i}"
@@ -443,19 +453,22 @@ class LagunaLM(nn.Module):
                     h, cache[name], positions, tables[which], n_valid, mask,
                     attn)
             counted.append(c)
-        last = jnp.maximum(n_valid - 1, 0)[:, None, None]
-        h = jnp.take_along_axis(h, last, axis=1)[:, 0]
-        h = RMSNorm(cfg, name="final_norm")(h)
-        head = self.param("lm_head", _normal(cfg),
-                          (cfg.hidden_size, cfg.vocab_size), cfg.param_dtype)
-        logits = jnp.dot(h, head.astype(cfg.dtype),
-                         preferred_element_type=jnp.float32)
-        counted = jnp.stack(counted)
+        with jax.named_scope("ds_head"):
+            last = jnp.maximum(n_valid - 1, 0)[:, None, None]
+            h = jnp.take_along_axis(h, last, axis=1)[:, 0]
+            h = RMSNorm(cfg, name="final_norm")(h)
+            head = self.param("lm_head", _normal(cfg),
+                              (cfg.hidden_size, cfg.vocab_size),
+                              cfg.param_dtype)
+            logits = jnp.dot(h, head.astype(cfg.dtype),
+                             preferred_element_type=jnp.float32)
         sparse = sum(not cfg.is_dense(i)
                      for i in range(cfg.num_hidden_layers))
-        values = [*counted[:, :4].sum(0),
-                  jnp.int32(cfg.experts_held[1] * sparse),
-                  counted[:, 4].max()]
+        with jax.named_scope("ds_sample"):
+            counted = jnp.stack(counted)
+            values = [*counted[:, :4].sum(0),
+                      jnp.int32(cfg.experts_held[1] * sparse),
+                      counted[:, 4].max()]
         return logits, new_cache, dict(zip(COUNTERS, values))
 
     # -- the serving engine's protocol (`inference/engine.py`) -------------

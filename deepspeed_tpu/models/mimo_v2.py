@@ -292,21 +292,23 @@ class MimoAttention(nn.Module):
         kind = cfg.kind(self.which)
         B, T, C = x.shape
         Hq, H, D, Dv = kind.heads, kind.kv_heads, kind.head_dim, kind.v_dim
-        q = jnp.dot(x, _param(self, "q_proj", cfg, (C, Hq * D)))
-        k = jnp.dot(x, _param(self, "k_proj", cfg, (C, H * D)))
-        v = jnp.dot(x, _param(self, "v_proj", cfg, (C, H * Dv)))
-        q = partial_rotary(q.reshape(B, T, Hq, D), positions, kind)
-        k = partial_rotary(k.reshape(B, T, H, D), positions, kind)
-        v = (v.astype(jnp.float32) * cfg.attention_value_scale).astype(
-            cfg.dtype).reshape(B, T, H, Dv)
+        with jax.named_scope("ds_attn_qkv"):
+            q = jnp.dot(x, _param(self, "q_proj", cfg, (C, Hq * D)))
+            k = jnp.dot(x, _param(self, "k_proj", cfg, (C, H * D)))
+            v = jnp.dot(x, _param(self, "v_proj", cfg, (C, H * Dv)))
+            q = partial_rotary(q.reshape(B, T, Hq, D), positions, kind)
+            k = partial_rotary(k.reshape(B, T, H, D), positions, kind)
+            v = (v.astype(jnp.float32) * cfg.attention_value_scale).astype(
+                cfg.dtype).reshape(B, T, H, Dv)
         sink = self.param("sink", _sink_init(cfg), (Hq,), jnp.float32) \
             if kind.sink else None
         y, layer_cache = cached_attention(
             q, k, v, layer_cache, positions, cfg.dtype, page_table,
             scale=D ** -0.5, window=kind.window, sink=sink, n_valid=n_valid,
             walk=True, **attn)
-        y = jnp.dot(y.reshape(B, T, Hq * Dv),
-                    _param(self, "o_proj", cfg, (Hq * Dv, C)))
+        with jax.named_scope("ds_attn_out"):
+            y = jnp.dot(y.reshape(B, T, Hq * Dv),
+                        _param(self, "o_proj", cfg, (Hq * Dv, C)))
         return y, layer_cache
 
 
@@ -348,17 +350,22 @@ class MimoV2Layer(nn.Module):
     def __call__(self, h, layer_cache, positions, page_table, n_valid, mask,
                  attn):
         cfg = self.config
+        # each norm under the scope of what it feeds, each residual add
+        # under that of what it follows (`telemetry/scopes.py`)
+        with jax.named_scope("ds_attn_qkv"):
+            n = RMSNorm(cfg, name="input_norm")(h)
         y, layer_cache = MimoAttention(cfg, self.which, name="attn")(
-            RMSNorm(cfg, name="input_norm")(h), layer_cache, positions,
-            page_table, n_valid, attn)
-        h = h + y
-        n = RMSNorm(cfg, name="post_attn_norm")(h)
-        if self.dense:
-            y = GatedMLP(cfg, cfg.intermediate_size, name="mlp")(n)
-            counters = jnp.zeros((4,), jnp.int32)
-        else:
-            y, counters = RoutedExperts(cfg, name="experts")(n, mask)
-        return h + y, layer_cache, counters
+            n, layer_cache, positions, page_table, n_valid, attn)
+        with jax.named_scope("ds_attn_out"):
+            h = h + y
+        with jax.named_scope("ds_mlp" if self.dense else "ds_experts"):
+            n = RMSNorm(cfg, name="post_attn_norm")(h)
+            if self.dense:
+                y = GatedMLP(cfg, cfg.intermediate_size, name="mlp")(n)
+                counters = jnp.zeros((4,), jnp.int32)
+            else:
+                y, counters = RoutedExperts(cfg, name="experts")(n, mask)
+            return h + y, layer_cache, counters
 
 
 class MimoV2LM(nn.Module):
@@ -377,14 +384,17 @@ class MimoV2LM(nn.Module):
         embed = self.param("embed", _normal(cfg),
                            (cfg.vocab_size, cfg.hidden_size),
                            cfg.param_dtype)
-        h = embed.astype(cfg.dtype)[tokens]
-        # a decode row without a request, a chunk's padded tail
-        mask = jnp.arange(T)[None, :] < n_valid[:, None]
+        with jax.named_scope("ds_embed"):
+            h = embed.astype(cfg.dtype)[tokens]
+            # a decode row without a request, a chunk's padded tail
+            mask = jnp.arange(T)[None, :] < n_valid[:, None]
         # the table's last entries are the row's ring, where there is one
         page_size = next(iter(cache.values()))["k"].shape[-1]
         ring = cfg.sliding_window // page_size + 1 \
             if cfg.names(WINDOW) else 0
-        tables = dict(zip((FULL, WINDOW), split_table(page_table, ring)))
+        with jax.named_scope("ds_embed"):
+            tables = dict(zip((FULL, WINDOW),
+                              split_table(page_table, ring)))
         new_cache, counters, dense = {}, 0, 0
         for i, which in enumerate(cfg.layer_kinds):
             name = f"layers_{i}"
@@ -394,13 +404,15 @@ class MimoV2LM(nn.Module):
                     attn)
             counters = counters + c
             dense += bool(cfg.is_dense(i))
-        last = jnp.maximum(n_valid - 1, 0)[:, None, None]
-        h = jnp.take_along_axis(h, last, axis=1)[:, 0]
-        h = RMSNorm(cfg, name="final_norm")(h)
-        head = self.param("lm_head", _normal(cfg),
-                          (cfg.hidden_size, cfg.vocab_size), cfg.param_dtype)
-        logits = jnp.dot(h, head.astype(cfg.dtype),
-                         preferred_element_type=jnp.float32)
+        with jax.named_scope("ds_head"):
+            last = jnp.maximum(n_valid - 1, 0)[:, None, None]
+            h = jnp.take_along_axis(h, last, axis=1)[:, 0]
+            h = RMSNorm(cfg, name="final_norm")(h)
+            head = self.param("lm_head", _normal(cfg),
+                              (cfg.hidden_size, cfg.vocab_size),
+                              cfg.param_dtype)
+            logits = jnp.dot(h, head.astype(cfg.dtype),
+                             preferred_element_type=jnp.float32)
         held = cfg.experts_held[1] * (cfg.num_hidden_layers - dense)
         return logits, new_cache, dict(zip(
             COUNTERS, [*counters, jnp.int32(held)]))

@@ -403,17 +403,22 @@ class MlaMoeLayer(nn.Module):
     def __call__(self, h, layer_cache, positions, page_table, rope, mask,
                  attn):
         cfg = self.config
+        # each norm under the scope of what it feeds, each residual add
+        # under that of what it follows (`telemetry/scopes.py`)
+        with jax.named_scope("ds_attn_qkv"):
+            n = RMSNorm(cfg, name="input_norm")(h)
         y, layer_cache = LatentAttention(cfg, name="attn")(
-            RMSNorm(cfg, name="input_norm")(h), layer_cache, positions,
-            page_table, rope, attn)
-        h = h + y
-        n = RMSNorm(cfg, name="post_attn_norm")(h)
-        if self.dense:
-            y = GatedMLP(cfg, cfg.intermediate_size, name="mlp")(n)
-            counters = jnp.zeros((len(COUNTERS),), jnp.int32)
-        else:
-            y, counters = HeldExperts(cfg, name="experts")(n, mask)
-        return h + y, layer_cache, counters
+            n, layer_cache, positions, page_table, rope, attn)
+        with jax.named_scope("ds_attn_out"):
+            h = h + y
+        with jax.named_scope("ds_mlp" if self.dense else "ds_experts"):
+            n = RMSNorm(cfg, name="post_attn_norm")(h)
+            if self.dense:
+                y = GatedMLP(cfg, cfg.intermediate_size, name="mlp")(n)
+                counters = jnp.zeros((len(COUNTERS),), jnp.int32)
+            else:
+                y, counters = HeldExperts(cfg, name="experts")(n, mask)
+            return h + y, layer_cache, counters
 
 
 class MlaMoeLM(nn.Module):
@@ -431,23 +436,26 @@ class MlaMoeLM(nn.Module):
         embed = self.param("embed", _normal(cfg),
                            (cfg.vocab_size, cfg.hidden_size),
                            cfg.param_dtype)
-        h = embed.astype(cfg.dtype)[tokens]
-        rope = yarn_cos_sin(cfg, positions)
-        # a decode row without a request, a chunk's padded tail
-        mask = jnp.arange(T)[None, :] < n_valid[:, None]
+        with jax.named_scope("ds_embed"):
+            h = embed.astype(cfg.dtype)[tokens]
+            rope = yarn_cos_sin(cfg, positions)
+            # a decode row without a request, a chunk's padded tail
+            mask = jnp.arange(T)[None, :] < n_valid[:, None]
         new_cache, counters = {}, 0
         for i, name in enumerate(cfg.layer_names()):
             h, new_cache[name], c = MlaMoeLayer(
                 cfg, bool(cfg.is_dense(i)), name=name)(
                     h, cache[name], positions, page_table, rope, mask, attn)
             counters = counters + c
-        last = jnp.maximum(n_valid - 1, 0)[:, None, None]
-        h = jnp.take_along_axis(h, last, axis=1)[:, 0]
-        h = RMSNorm(cfg, name="final_norm")(h)
-        head = self.param("lm_head", _normal(cfg),
-                          (cfg.hidden_size, cfg.vocab_size), cfg.param_dtype)
-        logits = jnp.dot(h, head.astype(cfg.dtype),
-                         preferred_element_type=jnp.float32)
+        with jax.named_scope("ds_head"):
+            last = jnp.maximum(n_valid - 1, 0)[:, None, None]
+            h = jnp.take_along_axis(h, last, axis=1)[:, 0]
+            h = RMSNorm(cfg, name="final_norm")(h)
+            head = self.param("lm_head", _normal(cfg),
+                              (cfg.hidden_size, cfg.vocab_size),
+                              cfg.param_dtype)
+            logits = jnp.dot(h, head.astype(cfg.dtype),
+                             preferred_element_type=jnp.float32)
         return logits, new_cache, dict(zip(COUNTERS, counters))
 
     # -- the serving engine's protocol (`inference/engine.py`) -------------

@@ -306,17 +306,26 @@ class NemotronHBlock(nn.Module):
     def __call__(self, h, layer_cache, positions, page_table, slots,
                  n_valid, mask, attn):
         cfg = self.config
-        n = RMSNorm(cfg, name="norm")(h)
-        counters = None
-        if self.kind == MIXER:
-            y, layer_cache = Mamba2Mixer(cfg, name="mixer")(
-                n, layer_cache, positions, slots, n_valid)
-        elif self.kind == ATTENTION:
+        if self.kind == ATTENTION:
+            # the norm under the scope of the projections it feeds, the
+            # residual add under that of the one it follows
+            with jax.named_scope("ds_attn_qkv"):
+                n = RMSNorm(cfg, name="norm")(h)
             y, layer_cache = GroupedQueryAttention(cfg, name="attn")(
                 n, layer_cache, positions, page_table, attn)
-        else:
-            y, counters = LatentExperts(cfg, name="experts")(n, mask)
-        return h + y, layer_cache, counters
+            with jax.named_scope("ds_attn_out"):
+                return h + y, layer_cache, None
+        # a mixer or an expert layer whole, round its older inner scopes
+        with jax.named_scope("ds_ssm_mixer" if self.kind == MIXER
+                             else "ds_experts"):
+            n = RMSNorm(cfg, name="norm")(h)
+            counters = None
+            if self.kind == MIXER:
+                y, layer_cache = Mamba2Mixer(cfg, name="mixer")(
+                    n, layer_cache, positions, slots, n_valid)
+            else:
+                y, counters = LatentExperts(cfg, name="experts")(n, mask)
+            return h + y, layer_cache, counters
 
 
 class NemotronHLM(nn.Module):
@@ -335,9 +344,10 @@ class NemotronHLM(nn.Module):
         embed = self.param("embed", _normal(cfg),
                            (cfg.vocab_size, cfg.hidden_size),
                            cfg.param_dtype)
-        h = embed.astype(cfg.dtype)[tokens]
-        # a decode row without a request, a chunk's padded tail
-        mask = jnp.arange(T)[None, :] < n_valid[:, None]
+        with jax.named_scope("ds_embed"):
+            h = embed.astype(cfg.dtype)[tokens]
+            # a decode row without a request, a chunk's padded tail
+            mask = jnp.arange(T)[None, :] < n_valid[:, None]
         new_cache, counted = {}, []
         for i, kind in enumerate(cfg.hybrid_override_pattern):
             name = f"layers_{i}"
@@ -348,18 +358,22 @@ class NemotronHLM(nn.Module):
                 new_cache[name] = layer_cache
             if counters is not None:
                 counted.append(counters)
-        last = jnp.maximum(n_valid - 1, 0)[:, None, None]
-        h = jnp.take_along_axis(h, last, axis=1)[:, 0]
-        h = RMSNorm(cfg, name="final_norm")(h)
-        head = self.param("lm_head", _normal(cfg),
-                          (cfg.hidden_size, cfg.vocab_size), cfg.param_dtype)
-        logits = jnp.dot(h, head.astype(cfg.dtype),
-                         preferred_element_type=jnp.float32)
-        counted = jnp.stack(counted) if counted else \
-            jnp.zeros((1, 5), jnp.int32)
-        values = [*counted[:, :3].sum(0), counted[:, 3].max(),
-                  jnp.int32(cfg.experts_held[1] * len(cfg.names(EXPERTS))),
-                  counted[:, 4].sum()]
+        with jax.named_scope("ds_head"):
+            last = jnp.maximum(n_valid - 1, 0)[:, None, None]
+            h = jnp.take_along_axis(h, last, axis=1)[:, 0]
+            h = RMSNorm(cfg, name="final_norm")(h)
+            head = self.param("lm_head", _normal(cfg),
+                              (cfg.hidden_size, cfg.vocab_size),
+                              cfg.param_dtype)
+            logits = jnp.dot(h, head.astype(cfg.dtype),
+                             preferred_element_type=jnp.float32)
+        with jax.named_scope("ds_sample"):
+            counted = jnp.stack(counted) if counted else \
+                jnp.zeros((1, 5), jnp.int32)
+            values = [*counted[:, :3].sum(0), counted[:, 3].max(),
+                      jnp.int32(cfg.experts_held[1] *
+                                len(cfg.names(EXPERTS))),
+                      counted[:, 4].sum()]
         return logits, new_cache, dict(zip(COUNTERS, values))
 
     # -- the serving engine's protocol (`inference/engine.py`) -------------

@@ -117,19 +117,22 @@ class RotaryAttention(nn.Module):
             w = self.param(name, _normal(cfg), (C, C), cfg.param_dtype)
             return jnp.dot(y, w.astype(cfg.dtype))
 
-        q = RMSNorm(cfg, name="q_norm")(proj("q_proj", x))
-        k = RMSNorm(cfg, name="k_norm")(proj("k_proj", x))
-        v = proj("v_proj", x)
-        q, k, v = (a.reshape(B, T, H, C // H) for a in (q, k, v))
-        q, k = rotary(q, cfg.rope_theta), rotary(k, cfg.rope_theta)
-        if cfg.use_flash_attention:
-            from deepspeed_tpu.ops.pallas import flash_attention
-            y = flash_attention(q, k, v, causal=True)
-        else:
-            from deepspeed_tpu.ops.pallas.flash_attention import (
-                dense_attention)
-            y = dense_attention(q, k, v, causal=True)
-        return proj("o_proj", y.reshape(B, T, C))
+        with jax.named_scope("ds_attn_qkv"):
+            q = RMSNorm(cfg, name="q_norm")(proj("q_proj", x))
+            k = RMSNorm(cfg, name="k_norm")(proj("k_proj", x))
+            v = proj("v_proj", x)
+            q, k, v = (a.reshape(B, T, H, C // H) for a in (q, k, v))
+            q, k = rotary(q, cfg.rope_theta), rotary(k, cfg.rope_theta)
+        with jax.named_scope("ds_attn_train"):
+            if cfg.use_flash_attention:
+                from deepspeed_tpu.ops.pallas import flash_attention
+                y = flash_attention(q, k, v, causal=True)
+            else:
+                from deepspeed_tpu.ops.pallas.flash_attention import (
+                    dense_attention)
+                y = dense_attention(q, k, v, causal=True)
+        with jax.named_scope("ds_attn_out"):
+            return proj("o_proj", y.reshape(B, T, C))
 
 
 class SparseExperts(nn.Module):
@@ -161,11 +164,17 @@ class DecoderLayer(nn.Module):
     @nn.compact
     def __call__(self, x):
         cfg = self.config
-        x = x + RotaryAttention(cfg, name="attn")(
-            RMSNorm(cfg, name="input_norm")(x))
-        y, stats = SparseExperts(cfg, name="experts")(
-            RMSNorm(cfg, name="post_attn_norm")(x))
-        return x + y, stats
+        # each norm under the scope of what it feeds, each residual add
+        # under that of what it follows (`telemetry/scopes.py`)
+        with jax.named_scope("ds_attn_qkv"):
+            n = RMSNorm(cfg, name="input_norm")(x)
+        y = RotaryAttention(cfg, name="attn")(n)
+        with jax.named_scope("ds_attn_out"):
+            x = x + y
+        with jax.named_scope("ds_experts"):
+            y, stats = SparseExperts(cfg, name="experts")(
+                RMSNorm(cfg, name="post_attn_norm")(x))
+            return x + y, stats
 
 
 class OlmoeLM(nn.Module):
@@ -179,15 +188,18 @@ class OlmoeLM(nn.Module):
         init = _normal(cfg)
         embed = self.param("embed", init, (cfg.vocab_size, cfg.hidden_size),
                            cfg.param_dtype)
-        x = embed.astype(cfg.dtype)[input_ids]
+        with jax.named_scope("ds_embed"):
+            x = embed.astype(cfg.dtype)[input_ids]
         stats = []
         for i in range(cfg.num_hidden_layers):
             x, s = DecoderLayer(cfg, name=f"layers_{i}")(x)
             stats.append(s)
-        x = RMSNorm(cfg, name="final_norm")(x)
-        head = self.param("lm_head", init,
-                          (cfg.hidden_size, cfg.vocab_size), cfg.param_dtype)
-        logits = jnp.dot(x, head.astype(cfg.dtype))
+        with jax.named_scope("ds_head"):
+            x = RMSNorm(cfg, name="final_norm")(x)
+            head = self.param("lm_head", init,
+                              (cfg.hidden_size, cfg.vocab_size),
+                              cfg.param_dtype)
+            logits = jnp.dot(x, head.astype(cfg.dtype))
         return logits, jax.tree_util.tree_map(lambda *a: jnp.stack(a),
                                               *stats)
 
@@ -220,20 +232,21 @@ def make_olmoe_loss_fn(model: OlmoeLM):
         if labels is None:
             labels = next_token_labels(input_ids)
         logits, stats = model.apply({"params": params}, input_ids)
-        ce = cross_entropy_loss(logits, labels)
-        lb, z = router_losses(stats, cfg)
-        loss = ce + cfg.router_aux_loss_coef * lb + \
-            cfg.router_z_loss_coef * z
-        counts = stats["tokens_per_expert"]
-        scalars = {
-            "moe_ce_loss": ce,
-            "moe_lb_loss": lb,
-            "moe_z_loss": z,
-            "moe_tokens_per_expert_max": counts.max(),
-            "moe_tokens_per_expert_min": counts.min(),
-            "moe_dropped_tokens": stats["dropped"].sum(),
-        }
-        return loss, jax.lax.stop_gradient(scalars)
+        with jax.named_scope("ds_loss"):
+            ce = cross_entropy_loss(logits, labels)
+            lb, z = router_losses(stats, cfg)
+            loss = ce + cfg.router_aux_loss_coef * lb + \
+                cfg.router_z_loss_coef * z
+            counts = stats["tokens_per_expert"]
+            scalars = {
+                "moe_ce_loss": ce,
+                "moe_lb_loss": lb,
+                "moe_z_loss": z,
+                "moe_tokens_per_expert_max": counts.max(),
+                "moe_tokens_per_expert_min": counts.min(),
+                "moe_dropped_tokens": stats["dropped"].sum(),
+            }
+            return loss, jax.lax.stop_gradient(scalars)
 
     return loss_fn
 

@@ -414,26 +414,29 @@ class GatedAttention(nn.Module):
         B, T, C = x.shape
         Hq, Hkv, D = cfg.num_attention_heads, cfg.num_key_value_heads, \
             cfg.head_dim
-        qg = jnp.dot(x, _param(self, "q_proj", cfg, (C, Hq * 2 * D)))
-        qg = qg.reshape(B, T, Hq, 2 * D)
-        q, gate = qg[..., :D], qg[..., D:]
-        k = jnp.dot(x, _param(self, "k_proj", cfg, (C, Hkv * D)))
-        v = jnp.dot(x, _param(self, "v_proj", cfg, (C, Hkv * D)))
-        q = zero_centred_norm(q, _norm_weight(self, "q_norm", cfg, D),
-                              cfg.rms_norm_eps)
-        k = zero_centred_norm(k.reshape(B, T, Hkv, D),
-                              _norm_weight(self, "k_norm", cfg, D),
-                              cfg.rms_norm_eps)
-        q = partial_rotary(q, positions, cfg).astype(cfg.dtype)
-        k = partial_rotary(k, positions, cfg).astype(cfg.dtype)
+        with jax.named_scope("ds_attn_qkv"):
+            qg = jnp.dot(x, _param(self, "q_proj", cfg, (C, Hq * 2 * D)))
+            qg = qg.reshape(B, T, Hq, 2 * D)
+            q, gate = qg[..., :D], qg[..., D:]
+            k = jnp.dot(x, _param(self, "k_proj", cfg, (C, Hkv * D)))
+            v = jnp.dot(x, _param(self, "v_proj", cfg, (C, Hkv * D)))
+            q = zero_centred_norm(q, _norm_weight(self, "q_norm", cfg, D),
+                                  cfg.rms_norm_eps)
+            k = zero_centred_norm(k.reshape(B, T, Hkv, D),
+                                  _norm_weight(self, "k_norm", cfg, D),
+                                  cfg.rms_norm_eps)
+            q = partial_rotary(q, positions, cfg).astype(cfg.dtype)
+            k = partial_rotary(k, positions, cfg).astype(cfg.dtype)
+            v = v.reshape(B, T, Hkv, D)
         y, layer_cache = cached_attention(
-            q, k, v.reshape(B, T, Hkv, D), layer_cache, positions,
-            cfg.dtype, page_table, scale=D ** -0.5, **attn)
+            q, k, v, layer_cache, positions, cfg.dtype, page_table,
+            scale=D ** -0.5, **attn)
         with jax.named_scope("ds_attn_gate"):
             y = (y.astype(jnp.float32) * jax.nn.sigmoid(
                 gate.astype(jnp.float32))).astype(cfg.dtype)
-        y = jnp.dot(y.reshape(B, T, Hq * D),
-                    _param(self, "o_proj", cfg, (Hq * D, C)))
+        with jax.named_scope("ds_attn_out"):
+            y = jnp.dot(y.reshape(B, T, Hq * D),
+                        _param(self, "o_proj", cfg, (Hq * D, C)))
         return y, layer_cache
 
 
@@ -496,17 +499,25 @@ class Qwen3NextBlock(nn.Module):
     def __call__(self, h, layer_cache, positions, page_table, slots,
                  n_valid, mask, attn):
         cfg = self.config
-        n = ZeroCentredRMSNorm(cfg, name="input_norm")(h)
         if self.kind == ATTENTION:
+            # the norm under the scope of the projections it feeds, the
+            # residual add under that of the one it follows
+            with jax.named_scope("ds_attn_qkv"):
+                n = ZeroCentredRMSNorm(cfg, name="input_norm")(h)
             y, layer_cache = GatedAttention(cfg, name="attn")(
                 n, layer_cache, positions, page_table, attn)
-        else:
-            y, layer_cache = GatedDeltaNet(cfg, name="mixer")(
-                n, layer_cache, positions, slots, n_valid)
-        h = h + y
-        y, counters = SparseExperts(cfg, name="experts")(
-            ZeroCentredRMSNorm(cfg, name="post_norm")(h), mask)
-        return h + y, layer_cache, counters
+            with jax.named_scope("ds_attn_out"):
+                h = h + y
+        else:   # the mixer whole, round its older inner scopes
+            with jax.named_scope("ds_gdn_mixer"):
+                y, layer_cache = GatedDeltaNet(cfg, name="mixer")(
+                    ZeroCentredRMSNorm(cfg, name="input_norm")(h),
+                    layer_cache, positions, slots, n_valid)
+                h = h + y
+        with jax.named_scope("ds_experts"):
+            y, counters = SparseExperts(cfg, name="experts")(
+                ZeroCentredRMSNorm(cfg, name="post_norm")(h), mask)
+            return h + y, layer_cache, counters
 
 
 class Qwen3NextLM(nn.Module):
@@ -525,9 +536,10 @@ class Qwen3NextLM(nn.Module):
         embed = self.param("embed", _normal(cfg),
                            (cfg.vocab_size, cfg.hidden_size),
                            cfg.param_dtype)
-        h = embed.astype(cfg.dtype)[tokens]
-        # a decode row without a request, a chunk's padded tail
-        mask = jnp.arange(T)[None, :] < n_valid[:, None]
+        with jax.named_scope("ds_embed"):
+            h = embed.astype(cfg.dtype)[tokens]
+            # a decode row without a request, a chunk's padded tail
+            mask = jnp.arange(T)[None, :] < n_valid[:, None]
         new_cache, counted = {}, []
         for i, kind in enumerate(cfg.layer_types):
             name = f"layers_{i}"
@@ -536,20 +548,23 @@ class Qwen3NextLM(nn.Module):
                     h, cache[name], positions, page_table, slots, n_valid,
                     mask, attn)
             counted.append(counters)
-        last = jnp.maximum(n_valid - 1, 0)[:, None, None]
-        h = jnp.take_along_axis(h, last, axis=1)[:, 0]
-        h = ZeroCentredRMSNorm(cfg, name="final_norm")(h)
-        head = self.param("lm_head", _normal(cfg),
-                          (cfg.hidden_size, cfg.vocab_size), cfg.param_dtype)
-        logits = jnp.dot(h, head.astype(cfg.dtype),
-                         preferred_element_type=jnp.float32)
-        counted = jnp.stack(counted)
-        # the state update visits the rows that hold a request and no
-        # other (`ops/pallas/gated_delta.py`'s list of live rows)
-        live = (n_valid > 0).sum().astype(jnp.int32)
-        values = [*counted[:, :3].sum(0), counted[:, 3].max(),
-                  jnp.int32(cfg.experts_held[1] * len(cfg.layer_types)),
-                  live, live, counted[:, 4].sum()]
+        with jax.named_scope("ds_head"):
+            last = jnp.maximum(n_valid - 1, 0)[:, None, None]
+            h = jnp.take_along_axis(h, last, axis=1)[:, 0]
+            h = ZeroCentredRMSNorm(cfg, name="final_norm")(h)
+            head = self.param("lm_head", _normal(cfg),
+                              (cfg.hidden_size, cfg.vocab_size),
+                              cfg.param_dtype)
+            logits = jnp.dot(h, head.astype(cfg.dtype),
+                             preferred_element_type=jnp.float32)
+        with jax.named_scope("ds_sample"):
+            counted = jnp.stack(counted)
+            # the state update visits the rows that hold a request and no
+            # other (`ops/pallas/gated_delta.py`'s list of live rows)
+            live = (n_valid > 0).sum().astype(jnp.int32)
+            values = [*counted[:, :3].sum(0), counted[:, 3].max(),
+                      jnp.int32(cfg.experts_held[1] * len(cfg.layer_types)),
+                      live, live, counted[:, 4].sum()]
         return logits, new_cache, dict(zip(COUNTERS, values))
 
     # -- the serving engine's protocol (`inference/engine.py`) -------------

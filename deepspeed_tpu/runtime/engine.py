@@ -25,6 +25,7 @@ import json
 import signal
 import socket
 import time
+import weakref
 from typing import Any, Callable, NamedTuple, Optional
 
 import numpy as np
@@ -260,6 +261,7 @@ def make_grad_accumulator(loss_fn, compute_dtype, accum, constrain=None,
         def cast_params(p):
             return jax.tree_util.tree_map(
                 lambda x: x.astype(compute_dtype), p)
+    cast_params = _under_scope("ds_param_cast", cast_params)
 
     # A loss_fn may carry a hand-written (loss, grads) implementation that
     # cannot be expressed as jax.grad of a scalar function — the executed
@@ -415,6 +417,16 @@ class _StepInputs(NamedTuple):
     fault_on: bool      # fault injection is configured on
 
 
+def _under_scope(name, fn):
+    """``fn``, traced under the device-side scope ``name``
+    (`telemetry/scopes.py`)."""
+    @functools.wraps(fn)
+    def scoped(*args, **kwargs):
+        with jax.named_scope(name):
+            return fn(*args, **kwargs)
+    return scoped
+
+
 def _loss_scale(c, dstate):
     return dstate.loss_scale.cur_scale if (c.fp16 and c.dynamic) \
         else jnp.asarray(c.static_scale, jnp.float32)
@@ -472,15 +484,16 @@ def _step_tail(c, params, opt_state, dstate, lr_in, scale, loss_sum, grads,
     optimizer's and returned fifth: an overflowed step keeps the OLD
     fp8 amax histories, or an inf/nan cotangent amax would poison the
     delayed scales for the next amax_history_len steps."""
-    grads, overflow, nonfinite, grad_norm, applied_norm = grad_epilogue(
-        grads, jnp.asarray(1.0, jnp.float32) if unscaled else scale,
-        1 if unscaled else c.accum, c.fp16, c.clip, constrain=constrain,
-        vote=vote, norm_reduce=norm_reduce,
-        clip_norm_reduce=clip_norm_reduce, detect_nonfinite=c.detect,
-        nan_skip=c.nan_skip)
+    with jax.named_scope("ds_grad_epilogue"):
+        grads, overflow, nonfinite, grad_norm, applied_norm = grad_epilogue(
+            grads, jnp.asarray(1.0, jnp.float32) if unscaled else scale,
+            1 if unscaled else c.accum, c.fp16, c.clip, constrain=constrain,
+            vote=vote, norm_reduce=norm_reduce,
+            clip_norm_reduce=clip_norm_reduce, detect_nonfinite=c.detect,
+            nan_skip=c.nan_skip)
 
-    lr = c.lr_fn(dstate.global_step) if c.lr_fn is not None else lr_in
-    beta1 = c.mom_fn(dstate.global_step)
+        lr = c.lr_fn(dstate.global_step) if c.lr_fn is not None else lr_in
+        beta1 = c.mom_fn(dstate.global_step)
 
     def select(old, new, shardings=None):
         kept = jax.tree_util.tree_map(
@@ -492,23 +505,27 @@ def _step_tail(c, params, opt_state, dstate, lr_in, scale, loss_sum, grads,
         params_out, opt_out = grads, None
         extra_metrics = dict(extra_metrics or {}, beta1=beta1)
     else:
-        new_params, new_opt = c.opt_update(params, grads, opt_state, lr,
-                                           beta1)
-        params_out = select(params, new_params, param_shardings)
-        opt_out = type(opt_state)(**{
-            name: select(getattr(opt_state, name), getattr(new_opt, name),
-                         opt_shardings if name in ("m", "v") else None)
-            for name in opt_state._fields})
+        with jax.named_scope("ds_opt_update"):
+            new_params, new_opt = c.opt_update(params, grads, opt_state,
+                                               lr, beta1)
+            params_out = select(params, new_params, param_shardings)
+            opt_out = type(opt_state)(**{
+                name: select(getattr(opt_state, name),
+                             getattr(new_opt, name),
+                             opt_shardings if name in ("m", "v") else None)
+                for name in opt_state._fields})
 
-    dstate_out = loss_scale_epilogue(dstate, overflow, c.fp16, c.dynamic,
-                                     c.scale_args)
-    metrics = step_metrics(loss_sum, c.accum, grad_norm, applied_norm, lr,
-                           scale, overflow, loss_reduce=loss_reduce,
-                           dstate=dstate_out, nonfinite=nonfinite,
-                           loss_scalars=loss_scalars)
-    metrics.update(extra_metrics or {})
-    if carried is not None:
-        return params_out, opt_out, dstate_out, metrics, select(*carried)
+    with jax.named_scope("ds_grad_epilogue"):
+        dstate_out = loss_scale_epilogue(dstate, overflow, c.fp16,
+                                         c.dynamic, c.scale_args)
+        metrics = step_metrics(loss_sum, c.accum, grad_norm, applied_norm,
+                               lr, scale, overflow, loss_reduce=loss_reduce,
+                               dstate=dstate_out, nonfinite=nonfinite,
+                               loss_scalars=loss_scalars)
+        metrics.update(extra_metrics or {})
+        if carried is not None:
+            return params_out, opt_out, dstate_out, metrics, \
+                select(*carried)
     return params_out, opt_out, dstate_out, metrics
 
 
@@ -2492,6 +2509,32 @@ class DeepSpeedEngine:
             pass
         return out
 
+    def _register_train_step(self, placed, step_rng, lr_in):
+        """The step by name, for whoever joins a profiler trace with its
+        scopes (`telemetry/programs.py`): the step's function and the
+        shapes of what this first call hands it; nothing is lowered. A
+        dense step's closure holds no engine, so it can be lowered again
+        once the engine is gone; the other kinds' may, and are
+        registered by a weak reference."""
+        from deepspeed_tpu.analysis.audit import _engine_fn_args
+        from deepspeed_tpu.telemetry import programs
+
+        def unwrapped(engine, batch):
+            fn, args = _engine_fn_args(engine, *batch)
+            return fn.__wrapped__, fn._ds_donate_argnums, \
+                programs.shapes(args)
+
+        if self._step_kind() in ("dense", "pipeline"):
+            # with the call's own arrays: an fp8 step's amax state is
+            # discovered from them (the step's own call would do it next)
+            found = unwrapped(self, (placed, step_rng, lr_in))
+            programs.register("train_step", lambda: found)
+        else:
+            ref, batch = weakref.ref(self), programs.shapes(
+                (placed, step_rng, lr_in))
+            programs.register(
+                "train_step", lambda: ref() and unwrapped(ref(), batch))
+
     def _stamp_compile_facts(self, placed, step_rng, lr_in,
                              compile_seconds=None):
         """Emit the one-shot ``compile`` event: static facts of the
@@ -2677,6 +2720,8 @@ class DeepSpeedEngine:
             # `compile` event's compile_seconds, which a warm persistent
             # cache (compilation_cache_dir) should drive to near zero.
             compile_t0 = time.perf_counter() if first_compile else None
+            if first_compile:
+                self._register_train_step(placed, step_rng, lr_in)
             if first_compile and self._config.analysis.enabled:
                 # Compile-time audit: lowering here both triggers the one
                 # real compile (the step call below is then a jit-cache
