@@ -758,6 +758,38 @@ def payload_shaped_copies(hlo_text, payload_dims):
     return re.findall(r"= \w+\[%s\]\S* copy\([^\n]*" % dims, hlo_text)
 
 
+def parameter_copies(hlo_text, n_params):
+    """``copy`` ops of the entry computation that copy one of the
+    program's first ``n_params`` parameters (a jitted function's
+    flattened arguments in order, so its weights where they come
+    first), directly or through bitcasts: ``[(parameter number, the
+    copy's result type)]``. Each is a re-layout of an argument from the
+    layout it was handed in to the one its consumer reads, paid on every
+    call; a program whose weights lie in the format its compiler asks
+    for has none."""
+    comps, entry = split_computations(hlo_text)
+    ops = {}        # name -> (opcode, result type, first operand)
+    for line in comps.get(entry, ()):
+        m = _PEAK_DEF_RE.match(line)
+        if m:
+            # a parameter's number; any other op's first operand by name
+            operand = re.match(r"\d+|[^%]*%([\w.\-]+)", line[m.end():])
+            ops[m.group("name")] = (
+                m.group("op"), m.group("shape"),
+                (operand.group(1) or operand.group(0)) if operand else "")
+    found = []
+    for opcode, result, source in ops.values():
+        if opcode != "copy":
+            continue
+        while ops.get(source, ("",))[0] == "bitcast":
+            source = ops[source][2]
+        origin = ops.get(source)
+        if origin and origin[0] == "parameter" and \
+                int(origin[2]) < n_params:
+            found.append((int(origin[2]), result))
+    return sorted(found)
+
+
 # Custom-call targets that round-trip through the Python host (jax
 # pure_callback / io_callback / debug.callback lower to these).
 _HOST_CALLBACK_TARGETS = (

@@ -32,6 +32,20 @@ their contents. (``kv_layout`` chose between this pool and a per-row
 ring buffer until PR 28; the key is still read, and anything but
 ``"paged"`` is refused.)
 
+**Where the weights lie** (ISSUE 54). A weight nobody placed lies in
+the default (row-major) format, a jitted program's parameters take the
+format of the arrays it is handed, and the chip's compiler wants a
+projection declared ``[C, H * D]`` the other way round: it re-laid each
+one out in front of its matmul on every call. So the engine asks the
+compiler, once as it is built, which format each weight of the decode
+program should lie in (:func:`asked_weight_formats`) and places the
+leaves that lie otherwise (``InferenceEngine._place_weights``); the two
+jits stay what they are and take the formats of the committed arrays
+they are handed. No option, no list of weights, no model's name: a
+model whose weights lie right pays the question and nothing after, and
+weights on the host's CPU, whose compiler keeps the default layout, are
+not asked about (:func:`_askable`).
+
 With a mesh whose ``model`` axis is >1 the engine places params with
 the model's Megatron PartitionSpecs (``model.partition_specs``;
 `models/gpt2.py:gpt2_partition_specs` — the
@@ -92,6 +106,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Format, Layout
 
 from deepspeed_tpu.analysis.audit import donated_jit
 from deepspeed_tpu.inference.cache import (
@@ -106,6 +121,7 @@ from deepspeed_tpu.inference.cache import (
 from deepspeed_tpu.inference.paging import TRASH_PAGE
 from deepspeed_tpu.telemetry import programs
 from deepspeed_tpu.telemetry.spans import Span, enclosing_attr
+from deepspeed_tpu.utils.logging import logger
 
 DEFAULT_MAX_BATCH = 8
 DEFAULT_SEQ_BUCKETS = (128, 512)
@@ -123,6 +139,43 @@ def _cfg_get(config, key, default):
     else:
         v = getattr(config, key, default)
     return default if v is None else v
+
+
+def asked_weight_formats(fn, args, donate_argnums=()):
+    """The ``Format`` the compiler would have each leaf of ``args[0]``
+    (a program's weights) lie in, left the choice: ``fn`` lowered and
+    compiled for ``args`` (arrays or shapes; every other argument as it
+    is handed in) with the weights as ``Format(Layout.AUTO, <the leaf's
+    sharding>)``, and the weights' part of the executable's
+    ``input_formats``. The executable is dropped: it is the question,
+    not the program. A projection the program multiplies by comes back
+    in the order its matmul reads, where a weight in the default format
+    costs a re-layout ``copy`` on every call."""
+    weights = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding),
+        args[0])
+    auto = jax.tree_util.tree_map(
+        lambda x: Format(Layout.AUTO, x.sharding), weights)
+    asked = jax.jit(
+        fn, in_shardings=(auto,) + (None,) * (len(args) - 1),
+        donate_argnums=donate_argnums).lower(weights, *args[1:]).compile()
+    return asked.input_formats[0][0]
+
+
+def _askable(leaf):
+    """Whether the compiler is asked where ``leaf`` should lie: a device
+    array whose backend names its layout, on an accelerator. XLA's CPU
+    compiler keeps a parameter in the default layout whatever reads it
+    (every leaf of the seven served models' toy programs came back so),
+    so for weights on the host's CPU the question would cost a lowering
+    and a compile of the decode program for an answer known beforehand."""
+    return (isinstance(leaf, jax.Array) and leaf.format.layout is not None
+            and all(d.platform != "cpu" for d in leaf.devices()))
+
+
+def _committed(x):
+    """``x`` committed to where it lies; no bytes move."""
+    return jax.device_put(x, x.sharding)
 
 
 class InferenceEngine:
@@ -332,6 +385,7 @@ class InferenceEngine:
         self._small_ints = {}           # see _one_int
 
         self._cache_shardings = None
+        self._weights_placed = False    # see _place_weights
         if mesh is not None and dict(mesh.shape).get("model", 1) > 1:
             from jax.sharding import NamedSharding, PartitionSpec
             # commit the sampling key (replicated) up front: an
@@ -340,24 +394,12 @@ class InferenceEngine:
             # second step — breaking the 2-program contract under TP.
             self._sample_key = jax.device_put(
                 self._sample_key, NamedSharding(mesh, PartitionSpec()))
-            with Span("params"):
-                params = jax.tree_util.tree_map(
-                    lambda leaf, spec: jax.device_put(
-                        leaf, NamedSharding(mesh, spec)),
-                    params, model.partition_specs(params))
             self._cache_shardings = jax.tree_util.tree_map(
                 lambda spec: NamedSharding(mesh, spec),
                 kv_partition_specs(self.spec),
                 is_leaf=lambda x: not isinstance(x, dict))
-            with Span("pool"):
-                cache = jax.tree_util.tree_map(
-                    jax.device_put, init_kv_cache(self.spec),
-                    self._cache_shardings)
-        else:
-            with Span("pool"):
-                cache = init_kv_cache(self.spec)
-        self.params = params
-        self.cache = cache
+        with Span("pool"):
+            self.cache = self._new_pool()
 
         # cache (arg 1) is donated in both programs: the page pool
         # updates in place instead of doubling HBM every step. Page
@@ -365,7 +407,7 @@ class InferenceEngine:
         # allocator churn never reaches a jit boundary.
         self._prefill = donated_jit(self._prefill_fn, donate_argnums=(1,))
         self._decode = donated_jit(self._decode_fn, donate_argnums=(1,))
-        self._register_programs()
+        self.params = params        # placed below
 
         # speculative decoding (inference.speculative block): a draft
         # + verify program pair hung off the engine, or None when the
@@ -380,6 +422,12 @@ class InferenceEngine:
                 "inference.speculative cannot combine with a tiered "
                 "(disaggregated) engine — the draft/verify pair would "
                 "break the one-program-per-tier contract")
+
+        # last, once nothing can refuse the model any more: placing
+        # traces the decode program
+        with Span("params", attrs={}) as placing:
+            self._place_weights(placing.attrs)
+        self._register_programs()
 
     # -- compiled programs --------------------------------------------------
 
@@ -399,6 +447,76 @@ class InferenceEngine:
             twin._prefill_fn, donated, twin.prefill_lowering_args()))
         programs.register("decode", lambda: (
             twin._decode_fn, donated, twin.decode_lowering_args()))
+
+    def _place_weights(self, counters):
+        """``self.params`` over the mesh where there is a ``model``
+        axis, and every leaf in the format the decode program's
+        compiler asks for (:func:`asked_weight_formats`), once, before
+        either program has run. The decode program runs every step, so
+        it decides; both jits take the formats of the committed arrays
+        they are handed, so no program has a layout written into it. A
+        leaf that lies right stays the array it is;
+        one that does not is copied into its format, leaf by leaf, and
+        the engine keeps the copy alone (the tree it was handed is the
+        caller's, who may build another engine on it: nothing is
+        donated, and the old leaf goes when the caller lets go).
+        ``counters`` (the ``setup/engine/params`` span's attributes)
+        get ``weights_asked``, ``weights_relaid`` and
+        ``weights_relaid_bytes``; where a leaf is not to be asked about
+        (:func:`_askable`: no layout named, the host's CPU) nothing is
+        asked or placed, and all three read 0."""
+        params = self.params
+        if self._cache_shardings is not None:
+            from jax.sharding import NamedSharding
+            params = self.params = jax.tree_util.tree_map(
+                lambda leaf, spec: jax.device_put(
+                    leaf, NamedSharding(self.mesh, spec)),
+                params, self.model.partition_specs(params))
+        counters.update(weights_asked=0, weights_relaid=0,
+                        weights_relaid_bytes=0)
+        leaves, tree = jax.tree_util.tree_flatten_with_path(params)
+        if not all(_askable(leaf) for _, leaf in leaves):
+            return
+        asked = tree.flatten_up_to(asked_weight_formats(
+            self._decode.__wrapped__,
+            programs.shapes(self.decode_lowering_args()),
+            self._decode._ds_donate_argnums))
+        counters["weights_asked"] = len(leaves)
+        placed, relaid = [], []
+        for (path, leaf), want in zip(leaves, asked):
+            if leaf.format.layout != want.layout:
+                relaid.append(f"{jax.tree_util.keystr(path)} "
+                              f"{leaf.dtype.name}{list(leaf.shape)}")
+                counters["weights_relaid_bytes"] += leaf.nbytes
+                leaf = jax.device_put(leaf, want)
+            placed.append(leaf)
+        counters["weights_relaid"] = len(relaid)
+        if relaid:
+            self.params = tree.unflatten(placed)
+            # a placed weight is a committed array, and a program handed
+            # one returns committed results: the pool and the sampling
+            # key go round through both programs, so they start as they
+            # will come back (else the second call of each compiles again)
+            self._weights_placed = True
+            self._sample_key = _committed(self._sample_key)
+            self.cache = jax.tree_util.tree_map(_committed, self.cache)
+            logger.info(
+                "InferenceEngine: %d of %d weights (%d bytes) placed in the "
+                "format the decode program asks for: %s", len(relaid),
+                len(leaves), counters["weights_relaid_bytes"],
+                ", ".join(relaid))
+
+    def _new_pool(self):
+        """A zeroed pool as the programs are handed it: over the mesh
+        under a ``model`` axis, committed to its device beside placed
+        weights."""
+        cache = init_kv_cache(self.spec)
+        if self._cache_shardings is not None:
+            return jax.tree_util.tree_map(
+                jax.device_put, cache, self._cache_shardings)
+        if self._weights_placed:
+            return jax.tree_util.tree_map(_committed, cache)
+        return cache
 
     def _pin_cache(self, cache):
         """Constrain the output cache to the same shardings the input
@@ -722,11 +840,7 @@ class InferenceEngine:
     def reset(self):
         """Zero the cache (rows all free). Compiled programs survive —
         a reset must not cost a recompile."""
-        cache = init_kv_cache(self.spec)
-        if self._cache_shardings is not None:
-            cache = jax.tree_util.tree_map(
-                jax.device_put, cache, self._cache_shardings)
-        self.cache = cache
+        self.cache = self._new_pool()
 
     # -- recompile detector + audit surface ---------------------------------
 

@@ -62,12 +62,14 @@ def register(name, lowering):
 
 def shapes(tree):
     """``tree`` with every array as its ``jax.ShapeDtypeStruct`` (its
-    sharding kept where the array was committed to one)."""
+    format, the sharding and the layout its bytes lie in, kept where
+    the array was committed to one: a jit takes a committed argument's
+    layout, so the twin lowers the program that runs)."""
     def shape(x):
         if not isinstance(x, jax.Array):
             return x
         return jax.ShapeDtypeStruct(
-            x.shape, x.dtype, sharding=x.sharding if x.committed else None)
+            x.shape, x.dtype, sharding=x.format if x.committed else None)
     return jax.tree_util.tree_map(shape, tree)
 
 
