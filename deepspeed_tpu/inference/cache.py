@@ -70,7 +70,14 @@ live prefix in blocks (:func:`latent_prefill_attention`): the dense
 path's ``[heads, chunk, bucket]`` scores are 4.4 GB at 64 heads, a
 chunk of 1024 and a bucket of 16,896. Pages are pages: the allocator,
 the prefix cache and park/resume move latent pages like any others.
-What knows per-head vectors refuses such a spec
+**A latent pool beside recurrent leaves** (ISSUE 55;
+`models/ling_hybrid.py`: one latent layer in eight, a delta-rule state
+in the rest) is one spec with both: the two kinds of leaf lie under
+different layers' names and nothing in the tree or the two programs
+needed to change. What refuses either kind refuses it, and a feature
+that refuses both says both reasons at once
+(:class:`LatentAndRecurrentUnsupported`, caught by either name).
+What knows per-head vectors refuses a latent pool
 (:class:`LatentPoolUnsupported`): the int8 / fp8 codecs (one scale a
 576-wide vector is another precision than one a head of 64; not
 measured), a ``model`` mesh axis (one head cannot be split), and
@@ -232,6 +239,30 @@ class KVCacheSpec:
             int(np.prod(shape)) * jnp.dtype(dtype).itemsize
             for _, shape, dtype in self.recurrent_leaves) // self.max_batch
         return per_layer * len(self.recurrent_layers)
+
+
+class LatentAndRecurrentUnsupported(RecurrentStateUnsupported,
+                                    LatentPoolUnsupported):
+    """A serving feature that refuses a recurrent state and refuses a
+    latent pool was asked of a spec that holds both
+    (`models/ling_hybrid.py`): one error with both reasons, caught by
+    either name."""
+
+    def __init__(self, feature, why_recurrent, why_latent):
+        self.feature = feature
+        ValueError.__init__(
+            self, f"{feature} cannot serve a model with a recurrent state "
+            f"beside a pool of latents: {why_recurrent}; and {why_latent}")
+
+
+def refuse_recurrent_or_latent(spec, feature, why_recurrent, why_latent):
+    """`refuse_recurrent` and `refuse_latent` of one feature: a spec that
+    is both is refused once, with both reasons."""
+    if spec.recurrent_layers and spec.latent_v_dim:
+        raise LatentAndRecurrentUnsupported(feature, why_recurrent,
+                                            why_latent)
+    refuse_recurrent(spec, feature, why_recurrent)
+    refuse_latent(spec, feature, why_latent)
 
 
 def refuse_recurrent(spec, feature, why):

@@ -89,6 +89,11 @@ refused by what knows per-head keys and values: an int8 / fp8 pool, a
 ``model`` axis, speculative decoding, a tier
 (:class:`~deepspeed_tpu.inference.cache.LatentPoolUnsupported`); its
 pages are shared, parked and resumed like any others.
+A model whose cache is both (`models/ling_hybrid.py`: a latent pool
+beside recurrent leaves) is refused by every feature of either list,
+and by one that is on both (a tier, a ``model`` axis, speculative
+decoding) with both reasons in one error
+(:class:`~deepspeed_tpu.inference.cache.LatentAndRecurrentUnsupported`).
 A model whose page layers are not all alike lists them as groups in its
 ``cache_spec`` (`inference/cache.py:PageGroup`: heads, key and value
 widths, window); no knob says so. Where a group has a window a row's
@@ -114,8 +119,8 @@ from deepspeed_tpu.inference.cache import (
     init_kv_cache,
     kv_cache_nbytes,
     kv_partition_specs,
-    refuse_latent,
     refuse_recurrent,
+    refuse_recurrent_or_latent,
     refuse_window_ring,
 )
 from deepspeed_tpu.inference.paging import TRASH_PAGE
@@ -340,25 +345,20 @@ class InferenceEngine:
             refuse_window_ring(
                 self.spec, f"the disaggregated {self.tier!r} tier",
                 "the hand-off moves a row's pages and no ring")
-            refuse_recurrent(
+            refuse_recurrent_or_latent(
                 self.spec, f"the disaggregated {self.tier!r} tier",
-                "the hand-off moves pages and no state")
+                "the hand-off moves pages and no state",
+                "the hand-off has not been run on a pool without a v "
+                "leaf")
         if mesh is not None and dict(mesh.shape).get("model", 1) > 1:
-            refuse_recurrent(
+            refuse_recurrent_or_latent(
                 self.spec, "a 'model' mesh axis (tensor parallelism)",
-                "the state's heads are not sharded")
-            refuse_latent(
-                self.spec, "a 'model' mesh axis (tensor parallelism)",
+                "the state's heads are not sharded",
                 "a latent is one head, and every query head reads all "
                 "of it")
             refuse_window_ring(
                 self.spec, "a 'model' mesh axis (tensor parallelism)",
                 "the groups' heads are not sharded")
-        if self.tier is not None:
-            refuse_latent(
-                self.spec, f"the disaggregated {self.tier!r} tier",
-                "the hand-off has not been run on a pool without a v "
-                "leaf")
         # counters the model's decode step returns beside its logits
         # (`models/mla_moe.py`: the expert layers' pairs), by name
         self._counter_names = tuple(getattr(model, "serve_counters", ()))

@@ -408,17 +408,16 @@ def build_speculative(engine, config):
     grow = float(_cfg_get(spec_cfg, "min_accept_to_grow", 0.0))
     if not enabled or k == 0:
         return None
-    from deepspeed_tpu.inference.cache import (refuse_latent,
-                                               refuse_recurrent,
+    from deepspeed_tpu.inference.cache import (refuse_recurrent_or_latent,
                                                refuse_window_ring)
     refuse_window_ring(engine.spec, "inference.speculative",
                        "a rejected draft's keys would have overwritten "
                        "what the ring held of the window before them")
-    refuse_recurrent(engine.spec, "inference.speculative",
-                     "a rejected draft would have to roll the state back")
-    refuse_latent(engine.spec, "inference.speculative",
-                  "the verify chunk (several rows of several tokens) has "
-                  "no latent form, and the draft truncates GPT-2's layers")
+    refuse_recurrent_or_latent(
+        engine.spec, "inference.speculative",
+        "a rejected draft would have to roll the state back",
+        "the verify chunk (several rows of several tokens) has no latent "
+        "form, and the draft truncates GPT-2's layers")
     if k < 0:
         raise ValueError(f"speculative k must be >= 0, got {k}")
     n_layer = engine.model.config.n_layer

@@ -148,7 +148,9 @@ class MimoV2Config:
             raise MimoV2Unsupported(
                 "sigmoid scores chosen by noaux_tc in one group only "
                 f"(got {self.scoring_func}, {self.topk_method}, n_group "
-                f"{self.n_group}, topk_group {self.topk_group})")
+                f"{self.n_group}, topk_group {self.topk_group}; the group "
+                "stage lives in moe/dropless.py:sigmoid_group_top_k, which "
+                "this model does not route through)")
         if self.n_shared_experts:
             raise MimoV2Unsupported(
                 f"no shared expert is built (n_shared_experts "

@@ -142,7 +142,9 @@ class MlaMoeConfig:
             raise ValueError(
                 "sigmoid scores chosen by noaux_tc in one group only "
                 f"(got {self.scoring_func}, {self.topk_method}, n_group "
-                f"{self.n_group}, topk_group {self.topk_group})")
+                f"{self.n_group}, topk_group {self.topk_group}; the group "
+                "stage lives in moe/dropless.py:sigmoid_group_top_k, which "
+                "this model does not route through)")
         if dict(self.rope_scaling).get("type") != "yarn":
             raise ValueError("rope_scaling must be the YaRN kind")
 

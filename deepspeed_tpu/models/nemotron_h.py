@@ -136,7 +136,9 @@ class NemotronHConfig:
         if (self.n_group, self.topk_group) != (1, 1):
             raise ValueError("the router chooses in one group only "
                              f"(n_group {self.n_group}, topk_group "
-                             f"{self.topk_group})")
+                             f"{self.topk_group}; the group stage lives in "
+                             "moe/dropless.py:sigmoid_group_top_k, which "
+                             "this model does not route through)")
         if self.mlp_hidden_act != "relu2" or not self.use_conv_bias:
             raise ValueError("experts under relu2 and a convolution with "
                              "its bias only")

@@ -28,7 +28,9 @@ and the losses' sums added up over the chips.
 their sum; `softmax_top_k_scaled`, Laguna's: those times a factor;
 `sigmoid_top_k`,
 DeepSeek-V3's and Kimi-K2's: sigmoid scores, a bias that moves the
-choice and no weight, the chosen scores renormalised and scaled), and
+choice and no weight, the chosen scores renormalised and scaled;
+`sigmoid_group_top_k`, that with ``noaux_tc``'s group stage before the
+choice, Ling-3.0's), and
 **an expert layer can hold a share** (ISSUE 34): given ``first_expert``
 the banks are the ``bank.shape[0]`` experts from there on of the
 router's ``router.shape[1]``, as one chip of an expert-parallel
@@ -207,6 +209,39 @@ def sigmoid_top_k(bias, scaling, renormalise=True):
         if renormalise:
             weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
         return weights * scaling, experts, {}
+    return route
+
+
+def sigmoid_group_top_k(bias, scaling, n_group, topk_group,
+                        renormalise=True):
+    """DeepSeek-V3's routing with its group stage (``noaux_tc`` with
+    ``n_group`` > 1; Ling-3.0's): ``s = sigmoid(x router)``, ``c = s +
+    bias``; the experts lie in ``n_group`` groups of consecutive
+    experts, a group's score is the sum of its two largest ``c``, the
+    ``topk_group`` best groups are kept, and the ``top_k`` largest ``c``
+    inside them are chosen; their weights are ``s``, over their sum if
+    ``renormalise``, times ``scaling``. All float32. Returns a routing
+    function for `dropless_moe` whose ``aux`` holds ``kept_groups``
+    ``[N, topk_group]``. A sibling of `sigmoid_top_k` and not an
+    argument of it: other models' lowered text is pinned."""
+    def route(x, router, top_k):
+        scores = jax.nn.sigmoid(router_logits(x, router))
+        choice = scores + bias.astype(jnp.float32)
+        n, e = choice.shape
+        if e % n_group:
+            raise ValueError(f"{e} experts do not lie in {n_group} groups")
+        per = e // n_group
+        group_score = jax.lax.top_k(choice.reshape(n, n_group, per),
+                                    min(2, per))[0].sum(-1)
+        _, kept = jax.lax.top_k(group_score, topk_group)
+        keep = (kept[:, :, None] == jnp.arange(n_group)).any(1)
+        _, experts = jax.lax.top_k(
+            jnp.where(jnp.repeat(keep, per, axis=1), choice, -jnp.inf),
+            top_k)
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
+        if renormalise:
+            weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+        return weights * scaling, experts, {"kept_groups": kept}
     return route
 
 

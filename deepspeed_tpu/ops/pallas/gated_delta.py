@@ -287,23 +287,34 @@ def _step_kernel(Hk, group, K, V):
     return kernel
 
 
+def live_row_list(live):
+    """``(rows [R], count [R])`` of the flags ``live`` ``[R]``: the live
+    rows' numbers in order, then zeros; the running count of live rows
+    (its last entry their number). `ops/pallas/kda.py`'s step walks the
+    same list."""
+    idx = jnp.arange(live.shape[0], dtype=jnp.int32)
+    count = jnp.cumsum(live.astype(jnp.int32))
+    rows = jnp.sum(jnp.where(live & (count - 1 == idx[:, None]), idx, 0),
+                   axis=1)
+    return rows, count
+
+
+def listed_row(i, rows_ref, n_ref, *_):
+    """The row of grid step ``i`` of a walk over `live_row_list`: steps
+    behind the list stay on its last row, so nothing moves for them."""
+    return rows_ref[jnp.minimum(i, jnp.maximum(n_ref[0] - 1, 0))]
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _step_call(q, k, v, g, beta, state, live, *, interpret):
     R, Hk, K = q.shape
     Hv, V = v.shape[1:]
-    # the live rows' numbers in order, then zeros; their count
-    idx = jnp.arange(R, dtype=jnp.int32)
-    count = jnp.cumsum(live.astype(jnp.int32))
-    rows = jnp.sum(jnp.where(live & (count - 1 == idx[:, None]), idx, 0),
-                   axis=1)
+    rows, count = live_row_list(live)
     # q and k as columns: [R, K, 2 Hk]
     qk = jnp.swapaxes(jnp.concatenate([q, k], axis=1).astype(_F32), 1, 2)
 
-    def listed(i, rows_ref, n_ref, *_):
-        # grid steps behind the list stay on its last row: nothing moves
-        return rows_ref[jnp.minimum(i, jnp.maximum(n_ref[0] - 1, 0))]
-    row3 = lambda *a: (listed(*a), 0, 0)                # noqa: E731
-    row4 = lambda *a: (listed(*a), 0, 0, 0)             # noqa: E731
+    row3 = lambda *a: (listed_row(*a), 0, 0)            # noqa: E731
+    row4 = lambda *a: (listed_row(*a), 0, 0, 0)         # noqa: E731
     call = pl.pallas_call(
         _step_kernel(Hk, Hv // Hk, K, V),
         name=GATED_DELTA_STEP_NAME,

@@ -103,6 +103,21 @@ SCOPES = {
                                "delta rule"),
     "ds_gdn_step_rows": ("recurrent state", "kernel: the decode step "
                          "over the live rows"),
+    "ds_kda_mixer": ("recurrent state", "a Kimi Delta Attention mixer "
+                     "whole, with its norm, projections, convolutions, "
+                     "output gate and residual add; the ds_kda_* below "
+                     "lie inside"),
+    "ds_kda_gate": ("recurrent state", "the mixer's two full-rank gate "
+                    "projections and beta's, the bounded decay g a "
+                    "channel, beta and the output gate's sigmoid"),
+    "ds_kda_scan": ("recurrent state", "the per-channel delta rule over "
+                    "a chunk"),
+    "ds_kda_step": ("recurrent state", "the per-channel delta rule's "
+                    "decode step"),
+    "ds_kda_scan_chunks": ("recurrent state", "kernel: the chunked "
+                           "per-channel delta rule"),
+    "ds_kda_step_rows": ("recurrent state", "kernel: the per-channel "
+                         "decode step over the live rows"),
     # --- attention of the grouped, latent and gated kinds ----------------
     "ds_mla_project": ("kernels", "latent attention's down- and "
                        "up-projections, rotary, absorption and output "

@@ -108,15 +108,23 @@ def test_every_name_is_used_documented_and_laid_to_a_layer(name):
 def test_a_new_name_changes_no_accepted_sum(name):
     """Rule (b): a new name neither contains nor is contained in a scope
     string an accepted metric lists (a substring match would then take
-    it, or be taken by it)."""
+    it, or be taken by it). A metric written since the vocabulary was
+    closed sums a name of it, exactly: that name it may be, and a kernel
+    that lies inside that scope may carry it as a prefix
+    (``ds_kda_scan_chunks`` under ``ds_kda_scan``)."""
     for accepted, where in ACCEPTED.items():
+        if accepted not in BEFORE and (
+                name == accepted or name.startswith(accepted + "_")):
+            continue
         assert accepted not in name and name not in accepted, \
             (name, accepted, where)
 
 
 def test_the_older_names_are_the_vocabulary_s():
+    """The metrics from before the vocabulary was closed sum older
+    names; a later metric sums names of the vocabulary, whole."""
     assert BEFORE < set(scopes.SCOPES)
-    assert set(ACCEPTED) <= BEFORE
+    assert set(ACCEPTED) - BEFORE <= set(scopes.SCOPES) - BEFORE
 
 
 def test_innermost_and_chain():
