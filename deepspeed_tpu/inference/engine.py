@@ -657,6 +657,19 @@ class InferenceEngine:
                 attrs["attn_window_calls_kernel"] = sum(
                     n for n, window in bands
                     if band_kernel_takes(self.attention_impl, window))
+        ssm_state = [shape for leaf, shape, _ in self.spec.recurrent_leaves
+                     if leaf == "ssm"]
+        if ssm_state:
+            # the mixers' chunked scans, one a mixer a call, and those of
+            # them the scan's kernel took (`ops/ssm.py`: all of a model's
+            # or none, by the call's shapes)
+            from deepspeed_tpu.ops.ssm import ssd_kernel_takes
+            cfg = self.model.config
+            calls = attrs["chunks"] * len(self.spec.recurrent_layers)
+            attrs["ssd_scan_calls"] = calls
+            attrs["ssd_scan_calls_kernel"] = calls if ssd_kernel_takes(
+                chunk, *ssm_state[0][1:], cfg.mamba_n_groups,
+                cfg.mamba_chunk_size, cfg.dtype) else 0
         if start:
             refuse_recurrent(
                 self.spec, f"a prefill resumed at token {start}",

@@ -32,19 +32,31 @@ Padding: a token whose ``dt`` is 0 decays nothing and adds nothing, so
 the caller masks a ragged tail by zeroing its ``dt``; a dead decode row
 keeps its state bit for bit (``live``).
 
-Both are plain XLA under the scopes ``ds_ssd_prefill`` and
-``ds_ssm_decode`` (inside the mixer's ``ds_ssm_scan``), the names a
-Pallas kernel would carry.
+The prefill scan is one Pallas kernel call a mixer
+(`ops/pallas/ssd_prefill.py`, ``ds_ssd_prefill``) wherever the call's
+shapes meet the chip's tiles, and the plain XLA body below, under a scope
+of the same name, where they do not (`ssd_kernel_takes`: shapes decide,
+no option does); the decode step is plain XLA under ``ds_ssm_decode``.
+Both sit inside the mixer's ``ds_ssm_scan``.
 """
 
 import jax
 import jax.numpy as jnp
 
-SSD_PREFILL_NAME = "ds_ssd_prefill"
+from deepspeed_tpu.ops.pallas import ssd_prefill as _kernel
+
+SSD_PREFILL_NAME = _kernel.SSD_PREFILL_NAME
 SSM_DECODE_NAME = "ds_ssm_decode"
 
 _F32 = jnp.float32
 _HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def ssd_kernel_takes(T, H, P, N, G, chunk, dtype):
+    """Whether `ssd_chunked_scan` runs a call of these shapes as the
+    kernel (all of a model's mixers or none: they share their shapes)."""
+    return _kernel.kernel_takes(T, H, P, N, G, chunk,
+                                jnp.dtype(dtype).itemsize)
 
 
 def ssd_chunked_scan(x, dt, A, B, C, state, chunk):
@@ -55,8 +67,20 @@ def ssd_chunked_scan(x, dt, A, B, C, state, chunk):
     ``B`` / ``C`` ``[T, N]`` (one group) or ``[T, G, N]``, ``state``
     ``[H, P, N]`` float32 (the state before the first token). ``T`` is
     a multiple of ``chunk``. Returns ``(y [T, H, P] float32 without the
-    D term, state after the last token)``.
+    D term, state after the last token)``. The kernel where it takes
+    the shapes, `ssd_chunked_scan_xla` where it does not.
     """
+    T, H, P = x.shape
+    G = 1 if B.ndim == 2 else B.shape[1]
+    if ssd_kernel_takes(T, H, P, B.shape[-1], G, chunk, x.dtype):
+        return _kernel.ssd_chunked_scan(x, dt, A, B, C, state, chunk)
+    return ssd_chunked_scan_xla(x, dt, A, B, C, state, chunk)
+
+
+def ssd_chunked_scan_xla(x, dt, A, B, C, state, chunk):
+    """`ssd_chunked_scan` in plain XLA: the ``[chunk, chunk]`` scores and
+    decays of every chunk and head as whole tensors. What runs where the
+    kernel does not take a call, and the oracle of the kernel's tests."""
     T, H, P = x.shape
     N = B.shape[-1]
     Q = min(int(chunk), T)

@@ -353,7 +353,10 @@ def test_one_group_with_or_without_the_axis():
 # PR that changes one of them on purpose takes the new hash from this
 # test's message.
 LOWERED = {
-    "granite.prefill": "5516490f6c372249a7a0067f7e038cd6bb4ee296",
+    # PR 56: the mixers' chunked scan is `ops/pallas/ssd_prefill.py`'s
+    # kernel (interpreted here); `test_ssd_prefill_kernel.py` holds it to
+    # the XLA body this hash pinned
+    "granite.prefill": "3f42ee9203bfe3d7447abb9d4db7a66b98cfa048",
     "granite.decode": "1d71efc46d13bf7dc4184849c231b2a5f3239723",
     "kimi.prefill": "feb256ea11bbac2bad939fb10bd7e2b3df3f7975",
     "kimi.decode": "bc8536a6fe33c74608adba9b26fbf0827a232987",

@@ -403,6 +403,27 @@ def test_block_sparse_attention_compiles(chip):
 
 # --- the hybrid model's serving programs (ISSUE 31) -------------------------
 
+@pytest.mark.parametrize("cell", ["granite", "nemotron"])
+def test_ssd_prefill_kernel_compiles(chip, cell):
+    """The chunked scan's kernel (`ops/pallas/ssd_prefill.py`) at the two
+    serving cells' calls: 512 tokens of 64 heads over one B/C group in
+    scan chunks of 256, and 1,024 tokens of 128 heads over 8 groups in
+    chunks of 128; sixteen heads a grid step, the chunk axis last."""
+    from deepspeed_tpu.ops.pallas import ssd_prefill
+
+    T, H, G, Q = {"granite": (512, 64, 1, 256),
+                  "nemotron": (1024, 128, 8, 128)}[cell]
+    maps = (T, 128) if G == 1 else (T, G, 128)
+    args = (chip((T, H, 64), jnp.bfloat16), chip((T, H), jnp.float32),
+            chip((H,), jnp.float32), chip(maps, jnp.bfloat16),
+            chip(maps, jnp.bfloat16), chip((H, 64, 128), jnp.float32))
+    assert ssd_prefill.head_block(H, G, 64, 128, Q, 2) == 16
+    lowered = jax.jit(lambda *a: ssd_prefill._scan_call(
+        *a, chunk=Q, interpret=False)).lower(*args)
+    assert kernel_grids(lowered.as_text()) == [(H // 16, T // Q)]
+    assert "ds_ssd_prefill" in lowered.compile().as_text()
+
+
 @pytest.mark.parametrize("kv_dtype", ["bfloat16", "float32", "int8"])
 def test_grouped_query_flash_decode_compiles(chip, kv_dtype):
     """The decode kernel with 4 query heads to each of 8 key heads and
@@ -426,8 +447,9 @@ def test_hybrid_serving_programs_compile(chip, monkeypatch, program):
     from deepspeed_tpu.inference.cache import init_kv_cache
     from deepspeed_tpu.models import granite_hybrid as gh
 
-    _compiled_not_interpreted(monkeypatch,
-                              "deepspeed_tpu.ops.pallas.flash_decode")
+    for name in ("deepspeed_tpu.ops.pallas.flash_decode",
+                 "deepspeed_tpu.ops.pallas.ssd_prefill"):
+        _compiled_not_interpreted(monkeypatch, name)
     cfg = gh.granite_4_0_h_micro(
         num_hidden_layers=2, layer_types=(gh.MAMBA, gh.ATTENTION))
     model = gh.GraniteHybridLM(cfg)
@@ -456,8 +478,14 @@ def test_hybrid_serving_programs_compile(chip, monkeypatch, program):
     compiled = jax.jit(fn, donate_argnums=1).lower(
         params, cache, *args).compile()
     text = compiled.as_text()
-    assert ("tpu_custom_call" in text) == (program == "decode")
+    # a kernel a program: the decode attention, and since ISSUE 56 the
+    # prefill's chunked scan, which reads the mixer's ``x`` and hands on
+    # its ``y`` as they lie (no copy of either layout of 512 x 4096)
+    assert text.count("tpu_custom_call") == 1
+    assert ("ds_ssd_prefill" in text) == (program == "prefill")
     assert "ds_ssm_scan" in text and "ds_ssm_conv" in text
+    for tokens in ((512, 4096), (512, 64, 64)):
+        assert payload_shaped_copies(text, tokens) == []
     state = (ROWS, 64, 64, 128)
     assert payload_shaped_copies(text, state) == []
     assert payload_shaped_copies(text, (spec.n_pages, 8, 64, PAGE)) == []

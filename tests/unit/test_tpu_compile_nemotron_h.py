@@ -46,6 +46,7 @@ def test_nemotron_h_serving_programs_compile(chip, monkeypatch, program):
     from deepspeed_tpu.models import nemotron_h as nh
 
     for name in ("deepspeed_tpu.ops.pallas.flash_decode",
+                 "deepspeed_tpu.ops.pallas.ssd_prefill",
                  "deepspeed_tpu.moe.dropless"):
         _compiled_not_interpreted(monkeypatch, name)
     cfg = nh.nemotron_3_super_share(n_layer=3,
@@ -84,8 +85,13 @@ def test_nemotron_h_serving_programs_compile(chip, monkeypatch, program):
     # rows; in decode the attention kernel
     pairs = (CHUNK if program == "prefill" else ROWS) * \
         cfg.num_experts_per_tok
+    # (a prefill's fourth kernel since ISSUE 56: the mixer's chunked
+    # scan, `ds_ssd_prefill`, over the mixer's own ``x`` and ``y``: no
+    # copy of either layout of 1024 x 8192)
     assert held_experts_calls(text, pairs, cfg.moe_latent_size) == \
-        ({"prefill": 3, "decode": 4}[program], 2, 1)
+        (4, 2, 1)
+    for tokens in ((CHUNK, 8192), (CHUNK, 128, 64)):
+        assert payload_shaped_copies(text, tokens) == []
     for scope in ("ds_ssm_in_proj", "ds_ssm_conv", "ds_ssm_scan",
                   "ds_ssm_gate_norm", "ds_ssm_out_proj", "ds_moe_route",
                   "ds_moe_dispatch", "ds_moe_experts", "ds_moe_combine",

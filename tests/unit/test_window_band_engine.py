@@ -155,6 +155,8 @@ def test_other_specs_spans_carry_no_window_calls(monkeypatch, name):
     assert "attn_window_calls" not in attrs
     assert "attn_window_calls_kernel" not in attrs
     assert ("attn_blocks" in attrs) == (name == "latent_pool")
+    # nor a model without Mamba-2 mixers any scan calls (ISSUE 56)
+    assert ("ssd_scan_calls" in attrs) == (name == "hybrid")
 
 
 @pytest.mark.parametrize("name", ["laguna", "mimo_v2"])
