@@ -195,19 +195,19 @@ def softmax_top_k_scaled(scaling, renormalise=True):
     return route
 
 
-def sigmoid_top_k(bias, scaling, renormalise=True):
+def sigmoid_top_k(bias, scaling, renormalise=True, eps=1e-20):
     """DeepSeek-V3's routing without groups (``noaux_tc`` with one
     group, Kimi-K2's): ``s = sigmoid(x router)``; the ``top_k`` largest
     of ``s + bias`` are chosen (``bias`` ``[E]`` moves the choice and
-    nothing else); their weights are ``s``, over their sum if
-    ``renormalise``, times ``scaling``. All float32. Returns a routing
-    function for `dropless_moe`."""
+    nothing else); their weights are ``s``, over their sum plus ``eps``
+    if ``renormalise`` (LFM2's ``eps`` is 1e-6), times ``scaling``. All
+    float32. Returns a routing function for `dropless_moe`."""
     def route(x, router, top_k):
         scores = jax.nn.sigmoid(router_logits(x, router))
         _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
         weights = jnp.take_along_axis(scores, experts, axis=-1)
         if renormalise:
-            weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+            weights = weights / (weights.sum(-1, keepdims=True) + eps)
         return weights * scaling, experts, {}
     return route
 

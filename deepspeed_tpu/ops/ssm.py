@@ -161,16 +161,23 @@ def causal_conv_prefill(seq, window, weight, bias, n_valid):
     window. ``seq`` ``[T, C]``, ``window`` ``[K-1, C]`` (the K-1 inputs
     before ``seq[0]``; zeros at the start of a prompt), ``weight``
     ``[K, C]`` (``weight[K-1]`` multiplies the current input), ``bias``
-    ``[C]``. Returns ``(out [T, C] float32, the window after the
-    ``n_valid`` true tokens)``: the tail past ``n_valid`` is padding and
-    must not enter the next call's window."""
+    ``[C]`` or None (a convolution without one: no add is traced).
+    Returns ``(out [T, C] float32, the window after the ``n_valid`` true
+    tokens)``: the tail past ``n_valid`` is padding and must not enter
+    the next call's window.
+
+    What goes through is the caller's: ``seq`` may be a gated input (the
+    window then holds the gated values, `models/lfm2_moe.py`'s ``b *
+    x``), and an activation, where the model has one, is applied to
+    ``out`` by the caller. No activation and no gate is applied here."""
     K = weight.shape[0]
     T = seq.shape[0]
     full = jnp.concatenate([window.astype(seq.dtype), seq], axis=0)
     w = weight.astype(_F32)
-    out = bias.astype(_F32)[None, :]
+    out = None if bias is None else bias.astype(_F32)[None, :]
     for k in range(K):
-        out = out + w[k][None, :] * full[k:k + T].astype(_F32)
+        tap = w[k][None, :] * full[k:k + T].astype(_F32)
+        out = tap if out is None else out + tap
     # token t sits at full[t + K - 1]: the K-1 inputs up to the last
     # true token n_valid - 1 start at full[n_valid]
     return out, jax.lax.dynamic_slice_in_dim(full, n_valid, K - 1, 0)
@@ -178,11 +185,18 @@ def causal_conv_prefill(seq, window, weight, bias, n_valid):
 
 def causal_conv_step(new, window, weight, bias, live):
     """One step of every row. ``new`` ``[R, C]``, ``window``
-    ``[K-1, R, C]`` (oldest first). Returns ``(out [R, C] float32, the
-    window moved on by one for live rows)``."""
+    ``[K-1, R, C]`` (oldest first), ``bias`` ``[C]`` or None. Returns
+    ``(out [R, C] float32, the window moved on by one for live rows)``;
+    a row that is not live keeps its window to the bit. As
+    `causal_conv_prefill`: a gated ``new`` is the caller's, and so is
+    any activation of ``out``."""
     K = weight.shape[0]
     w = weight.astype(_F32)
-    out = bias.astype(_F32)[None, :] + w[K - 1][None, :] * new.astype(_F32)
+    if bias is None:
+        out = w[K - 1][None, :] * new.astype(_F32)
+    else:
+        out = bias.astype(_F32)[None, :] + \
+            w[K - 1][None, :] * new.astype(_F32)
     for k in range(K - 1):
         out = out + w[k][None, :] * window[k].astype(_F32)
     moved = jnp.concatenate([window[1:], new.astype(window.dtype)[None]],

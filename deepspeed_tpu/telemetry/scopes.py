@@ -118,7 +118,19 @@ SCOPES = {
                            "per-channel delta rule"),
     "ds_kda_step_rows": ("recurrent state", "kernel: the per-channel "
                          "decode step over the live rows"),
+    "ds_sconv_mixer": ("recurrent state", "a short-convolution mixer "
+                       "whole, with its norm and residual add; the three "
+                       "ds_sconv_* below lie inside"),
+    "ds_sconv_in_proj": ("recurrent state", "the mixer's input "
+                         "projection to b, c and x"),
+    "ds_sconv_taps": ("recurrent state", "the two gates, the taps, and "
+                      "the window's read and write: what is neither "
+                      "projection"),
+    "ds_sconv_out_proj": ("recurrent state", "the mixer's output "
+                          "projection"),
     # --- attention of the grouped, latent and gated kinds ----------------
+    "ds_attn_qk_norm": ("kernels", "the RMS norm a head of queries and "
+                        "keys; inside ds_attn_qkv"),
     "ds_mla_project": ("kernels", "latent attention's down- and "
                        "up-projections, rotary, absorption and output "
                        "projection"),
