@@ -968,6 +968,35 @@ def band_kernel_takes(impl, window):
     return impl == "flash" and int(window) > QUERY_BLOCK
 
 
+def chunk_kernel_takes(impl, rows, tokens, group, head_dim, dtype,
+                       pool_dtype, quantised=False, mesh=None):
+    """Whether a chunk over a pool of per-head pages (no group, no
+    latent) goes through the chunk's kernel
+    (`ops/pallas/chunk_prefill.py`) and not the dense arm: under
+    ``impl="flash"``, one row (a prompt's chunk: speculative verify
+    brings several), ``tokens`` whole query blocks of the kernel's, the
+    queries and the pool both bfloat16 (the MXU's operands as stored: a
+    float32 model's chunk and a codec's pool stay the dense arm's, which
+    widens or dequantises its gathered view), no TP ``mesh`` (a sharded
+    pool's chunk is GSPMD's to cut), and a key head's ``group`` query
+    heads of ``head_dim`` whole lane tiles of the model's ``[T, Hq x
+    D]``, where the kernel cuts its blocks. Decided from the call's
+    shapes, dtypes and pool alone. On a v5e, a layer a call (my chip
+    runs, PR 58; `PERF.md` section 6 has every geometry): 32 query heads
+    over 8 key heads of 64, a chunk of 1,024 in a bucket of 9,216 (LFM2):
+    XLA's dense arm 5.2 ms whatever the prefix, the kernel 0.20 ms at a
+    prefix of 1,024, 0.45 at 3,072, 0.71 at 5,120. The chat cell's
+    float32 chunk of 64 stays XLA's, by its dtype and by the query block
+    (and 16 heads of 64 are no whole lane tile a key head): section 6
+    has what the kernel read there. The engine's counters ask here too."""
+    from deepspeed_tpu.ops.pallas.chunk_prefill import LANES, QUERY_BLOCK
+    return (impl == "flash" and rows == 1 and tokens > 0 and
+            tokens % QUERY_BLOCK == 0 and
+            jnp.dtype(dtype) == jnp.dtype(pool_dtype) == jnp.bfloat16 and
+            not quantised and mesh is None and
+            (group * head_dim) % LANES == 0)
+
+
 def window_prefill_attention(q, k_new, v_new, layer_cache, positions, ring,
                              *, window, scale, compute_dtype, sink=None,
                              n_valid=None, impl="dense"):
@@ -1068,11 +1097,21 @@ def cached_attention(q, k_new, v_new, layer_cache, positions,
     goes back to the pool; rows without a request write nothing (the
     48-slab loop of :func:`_write_tokens` wrote all of them, whatever
     they held: 56 % of the chat cell's decode step, `PERF.md` section
-    6, PR 33). Prefill chunks and speculative verify (T > 1) always
-    use :func:`paged_write_kv` and the dense path over
-    :func:`paged_read_kv`'s gathered view, which stays the parity
-    oracle, as does the dense decode step (a latent pool's chunk has a
-    kernel of its own: below). ``mesh``: a TP mesh whose ``model`` axis shards
+    6, PR 33). Prefill chunks and speculative verify (T > 1) are
+    written by :func:`paged_write_kv`. **One prompt's chunk** (``B ==
+    1``) then attends in one call of the chunk's kernel
+    (`ops/pallas/chunk_prefill.py`: a key head's query heads over the
+    row's live key blocks, fetched from the pool's pages where they lie
+    through the page table, the scores in VMEM; nothing past the chunk's
+    last position is read) where :func:`chunk_kernel_takes` the call:
+    whole query blocks of bfloat16 queries over a bfloat16 pool in plain
+    storage, no ``mesh``, a key head's query heads whole lane tiles.
+    Everything else (``impl="dense"``, speculative verify, a codec's or
+    a sharded pool, a float32 model's chunk, a chunk under the kernel's
+    query block) takes the dense path over :func:`paged_read_kv`'s
+    gathered view, which stays the parity oracle, as does the dense
+    decode step (a latent pool's chunk has a kernel of its own: below).
+    ``mesh``: a TP mesh whose ``model`` axis shards
     the pool's head dim — the flash call then runs under ``shard_map``
     per local head shard. ``mask``: a precomputed
     :func:`attention_mask` (dense path only) so multi-layer callers
@@ -1145,14 +1184,22 @@ def cached_attention(q, k_new, v_new, layer_cache, positions,
                 q, layer_cache, positions, page_table, expand=expand,
                 scale=scale, compute_dtype=compute_dtype,
                 impl=impl), layer_cache
+        B, T, Hq, D = q.shape
+        H = layer_cache["k"].shape[1]
+        if not latent and chunk_kernel_takes(
+                impl, B, T, Hq // H, D, q.dtype, layer_cache["k"].dtype,
+                _codec_of(layer_cache) is not None, mesh):
+            from deepspeed_tpu.ops.pallas import flash_prefill_paged
+            y = flash_prefill_paged(
+                q[0], layer_cache["k"], layer_cache["v"], page_table[0],
+                positions[0, 0], scale=_dense_scale(scale, D, compute_dtype))
+            return y[None].astype(compute_dtype), layer_cache
         if mask is None:
             mask = attention_mask(layer_cache, positions, page_table)
         k_full, v_full = paged_read_kv(layer_cache, page_table,
                                        compute_dtype)
         if latent:
             v_full = k_full[..., :v_dim]
-        B, T, Hq, D = q.shape
-        H = k_full.shape[2]
         if scale is None:
             scale = 1.0 / jnp.sqrt(jnp.asarray(D, compute_dtype))
         else:
@@ -1176,6 +1223,16 @@ def cached_attention(q, k_new, v_new, layer_cache, positions,
         att = jax.nn.softmax(att, axis=-1).astype(compute_dtype)
         y = jnp.einsum("bhgts,bshd->bthgd", att, v_full)
         return y.reshape(B, T, Hq, v_full.shape[-1]), layer_cache
+
+
+def _dense_scale(scale, head_dim, compute_dtype):
+    """The scores' scale as the dense arm's grouped path takes it, a
+    Python number for a kernel: ``scale`` (``None``: ``1 / sqrt(D)``)
+    rounded to ``compute_dtype`` and widened to float32."""
+    dt = np.dtype(compute_dtype)
+    if scale is None:
+        return float((1.0 / np.sqrt(np.asarray(head_dim, dt))).astype(dt))
+    return float(np.asarray(scale, dt))
 
 
 def plain_scope(tokens):

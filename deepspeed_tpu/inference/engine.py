@@ -529,22 +529,28 @@ class InferenceEngine:
             jax.lax.with_sharding_constraint, cache,
             self._cache_shardings)
 
+    @property
+    def _attn_mesh(self):
+        """The TP mesh an attention call is told of: the engine's where
+        its ``model`` axis shards the pool, else none."""
+        return self.mesh if self._cache_shardings is not None else None
+
     def _prefill_fn(self, params, cache, tokens, positions, page_table,
                     slots, n_valid):
         # prefill addresses the POOL through the chunk's page table and
         # the row's recurrent leaves (if the model has any) through its
         # slot; the whole cache flows through so donation updates it in
         # place. The logits are the last real token's.
-        # A latent pool's chunk obeys ``attention_impl`` as a decode
-        # step does (the prefill kernel), and so does a window group's
+        # Every pool's chunk obeys ``attention_impl`` as a decode step
+        # does: a latent pool's (the prefill kernel), a window group's
         # (the band's kernel; a full group's walk takes the dense path
-        # whatever it is told); any other pool's chunk takes the dense
-        # path whatever the engine's says, and is not told.
-        attn = {"attn_impl": self.attention_impl} \
-            if self.spec.latent_v_dim or self.spec.groups else {}
+        # whatever it is told) and a per-head pool's (the chunk's
+        # kernel, where `cache.chunk_kernel_takes` the call's shapes
+        # and dtypes; else, and under a TP mesh, the dense arm).
         logits, cache = self.model.serve_apply(
             params, cache, tokens, positions, page_table, slots,
-            n_valid, **attn)[:2]
+            n_valid, attn_impl=self.attention_impl,
+            attn_mesh=self._attn_mesh)[:2]
         # fp32 on the way out: a reader gets full precision whatever the
         # compute dtype (a no-op for f32 models: parity stays bit-exact).
         # prefill() copies this one row home; decode() copies no logits.
@@ -557,7 +563,6 @@ class InferenceEngine:
         # attention impl / block size / sampling knobs are static (read
         # off self at trace time): they select the traced graph, never
         # ride as runtime values — changing them means a new engine.
-        mesh = self.mesh if self._cache_shardings is not None else None
         # row i sits in slot i; a row whose table starts with the trash
         # page holds no request (its token is not real)
         live = (page_tables[:, 0] != TRASH_PAGE).astype(jnp.int32)
@@ -565,7 +570,8 @@ class InferenceEngine:
             params, cache, tokens[:, None], positions[:, None],
             page_tables, jnp.arange(self.max_batch, dtype=jnp.int32), live,
             attn_impl=self.attention_impl,
-            attn_block_k=self.attention_block_k, attn_mesh=mesh)
+            attn_block_k=self.attention_block_k,
+            attn_mesh=self._attn_mesh)
         from deepspeed_tpu.inference.sampling import sample_logits
         with jax.named_scope("ds_sample"):
             next_tokens, key = sample_logits(
@@ -657,6 +663,14 @@ class InferenceEngine:
                 attrs["attn_window_calls_kernel"] = sum(
                     n for n, window in bands
                     if band_kernel_takes(self.attention_impl, window))
+        else:
+            # the attention layers' chunks over a per-head pool, one a
+            # layer a call, and those of them the chunk's kernel took
+            # (all of a model's or none, by the call's shapes)
+            calls = attrs["chunks"] * self.spec.n_layer
+            attrs["attn_plain_calls"] = calls
+            attrs["attn_plain_calls_kernel"] = \
+                calls if self._chunk_kernel_takes() else 0
         ssm_state = [shape for leaf, shape, _ in self.spec.recurrent_leaves
                      if leaf == "ssm"]
         if ssm_state:
@@ -690,6 +704,20 @@ class InferenceEngine:
             if ci == last_chunk:
                 last = np.asarray(logits[0])
         return last
+
+    def _chunk_kernel_takes(self):
+        """`cache.chunk_kernel_takes` of this engine's prefill call, as
+        the program asks it of each attention layer."""
+        from deepspeed_tpu.inference.cache import chunk_kernel_takes
+        cfg = getattr(self.model, "config", None)
+        q_heads = getattr(cfg, "num_attention_heads", None) or \
+            getattr(cfg, "n_head", None)
+        if not q_heads:
+            return False
+        return chunk_kernel_takes(
+            self.attention_impl, 1, self.prefill_chunk,
+            q_heads // self.spec.n_head, self.spec.head_dim, cfg.dtype,
+            self.spec.dtype, self.spec.codec is not None, self._attn_mesh)
 
     def _one_int(self, value):
         """``[value]`` as a device array, uploaded once a value: a

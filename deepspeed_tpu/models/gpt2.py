@@ -347,13 +347,15 @@ class GPT2LMHead(nn.Module):
             block_cls = nn.remat(Block, prevent_cse=False, policy=policy)
         new_kv = None
         attn_mask = None
-        if kv_cache is not None and attn_impl == "dense":
+        if kv_cache is not None and (attn_impl == "dense" or T > 1):
             # Hoist the dense cached-attention position mask: computed
             # once here and broadcast to every layer, instead of each
             # layer rebuilding the same [B, T, max_seq] iota-compare
-            # inside the compiled decode program (the flash path masks
-            # in-kernel from the positions scalar and needs none; S
-            # comes off the page table, not off the pool's shape).
+            # inside the compiled decode program (the flash decode step
+            # masks in-kernel from the positions scalar and needs none,
+            # and where a chunk goes through its kernel nothing reads
+            # this one; S comes off the page table, not off the pool's
+            # shape).
             from deepspeed_tpu.inference.cache import (attention_mask,
                                                        plain_scope)
             layer0 = kv_cache["h" if cfg.scan_layers else "h_0"]

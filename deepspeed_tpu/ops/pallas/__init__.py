@@ -12,6 +12,7 @@ Every kernel auto-selects Pallas interpret mode off-TPU, so this
 package imports (and the kernels run, slowly) on CPU test meshes.
 """
 
+from deepspeed_tpu.ops.pallas.chunk_prefill import flash_prefill_paged
 from deepspeed_tpu.ops.pallas.flash_attention import (
     DEFAULT_MASK_VALUE,
     dense_attention,
@@ -34,6 +35,7 @@ __all__ = [
     "flash_attention",
     "flash_decode_paged",
     "flash_prefill_latent_block",
+    "flash_prefill_paged",
     "pallas_adam_update",
     "window_prefill_band",
 ]

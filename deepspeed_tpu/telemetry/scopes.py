@@ -43,8 +43,8 @@ SCOPES = {
                              "decode kernel's call or the dense oracle"),
     "ds_attn_prefill_plain": ("kernels", "cached_attention outside page "
                               "groups and latents, a prompt's chunk: the "
-                              "page write, the bucket-long read and the "
-                              "dense scores"),
+                              "page write and the chunk's kernel, or the "
+                              "bucket-long read and the dense scores"),
     "ds_kv_write": ("KV cache", "paged_write_kv: a chunk's (or, under "
                     "the dense oracle, a token's) keys and values into "
                     "their pages; inside the attention's scope"),
@@ -151,6 +151,8 @@ SCOPES = {
     "ds_flash_decode_paged": ("kernels", "kernel: decode over pages"),
     "ds_flash_prefill_latent": ("kernels", "kernel: a latent block into "
                                 "the running softmax"),
+    "ds_flash_prefill_paged": ("kernels", "kernel: a prompt's chunk over a "
+                               "pool of per-head pages"),
     "ds_window_prefill_band": ("kernels", "kernel: a window layer's band"),
 }
 

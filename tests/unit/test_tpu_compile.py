@@ -401,6 +401,61 @@ def test_block_sparse_attention_compiles(chip):
 
 
 
+# --- a prompt's chunk over a per-head page pool (ISSUE 58) -------------------
+
+# cell: query heads, key heads, head size, chunk, bucket, pool pages
+CHUNK_CELLS = {"lfm2": (32, 8, 64, 1024, 9216, 3841),
+               "granite": (32, 8, 64, 512, 4608, 1729),
+               "qwen3-next": (16, 2, 256, 1024, 9216, 4097),
+               "nemotron": (32, 2, 128, 1024, 5120, 3841)}
+
+
+def chunk_kernel_calls(text):
+    """``(calls, those of them under ds_attn_prefill_plain)`` of the
+    chunk's kernel in a compiled program's text."""
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line
+             and "ds_flash_prefill_paged" in line]
+    return len(calls), sum("ds_attn_prefill_plain" in c for c in calls)
+
+
+def scores_of_a_bucket(text, chunk, bucket):
+    """Instructions whose float32 result is as long as the chunk times
+    the bucket (a key head's, a query head's or all heads' scores of
+    the dense arm), in a compiled program's text."""
+    import re
+    return re.findall(r"= f32\[(?:\d+,)*%d,%d\]" % (chunk, bucket), text)
+
+
+@pytest.mark.parametrize("cell", sorted(CHUNK_CELLS))
+def test_chunk_prefill_kernel_compiles(chip, cell):
+    """The chunk's kernel (`ops/pallas/chunk_prefill.py`) at the four
+    serving cells' geometries, the pool read where it lies: a grid of
+    (key heads, query blocks), no copy of anything pool-shaped, no
+    temporaries beside the output."""
+    from deepspeed_tpu.analysis.hlo import payload_shaped_copies
+    from deepspeed_tpu.ops.pallas.chunk_prefill import (chunk_blocks,
+                                                        flash_prefill_paged)
+
+    Hq, H, D, T, bucket, n_pages = CHUNK_CELLS[cell]
+    pool = chip((n_pages, H, D, PAGE), jnp.bfloat16)
+
+    def fn(q, k, v, table, c0):
+        return flash_prefill_paged(q, k, v, table, c0, scale=D ** -0.5,
+                                   interpret=False)
+    lowered = jax.jit(fn).lower(
+        chip((T, Hq, D), jnp.bfloat16), pool, pool,
+        chip((bucket // PAGE,), jnp.int32), chip((), jnp.int32))
+    bq, pages = chunk_blocks(T, Hq // H, PAGE)
+    assert pages == 8 and (Hq // H) * bq in (1024, 2048)
+    assert kernel_grids(lowered.as_text()) == [(H, T // bq)]
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert chunk_kernel_calls(text) == (1, 0)
+    assert payload_shaped_copies(text, (n_pages, H, D, PAGE)) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
 # --- the hybrid model's serving programs (ISSUE 31) -------------------------
 
 @pytest.mark.parametrize("cell", ["granite", "nemotron"])
@@ -448,7 +503,8 @@ def test_hybrid_serving_programs_compile(chip, monkeypatch, program):
     from deepspeed_tpu.models import granite_hybrid as gh
 
     for name in ("deepspeed_tpu.ops.pallas.flash_decode",
-                 "deepspeed_tpu.ops.pallas.ssd_prefill"):
+                 "deepspeed_tpu.ops.pallas.ssd_prefill",
+                 "deepspeed_tpu.ops.pallas.chunk_prefill"):
         _compiled_not_interpreted(monkeypatch, name)
     cfg = gh.granite_4_0_h_micro(
         num_hidden_layers=2, layer_types=(gh.MAMBA, gh.ATTENTION))
@@ -465,7 +521,8 @@ def test_hybrid_serving_programs_compile(chip, monkeypatch, program):
     if program == "prefill":
         def fn(params, cache, tokens, positions, table, slots, n_valid):
             return model.serve_apply(params, cache, tokens, positions,
-                                     table, slots, n_valid)
+                                     table, slots, n_valid,
+                                     attn_impl="flash", attn_block_k=PAGE)
         args = (i32(1, 512), i32(1, 512), i32(1, 36), i32(1), i32(1))
     else:
         def fn(params, cache, tokens, positions, tables):
@@ -481,7 +538,18 @@ def test_hybrid_serving_programs_compile(chip, monkeypatch, program):
     # a kernel a program: the decode attention, and since ISSUE 56 the
     # prefill's chunked scan, which reads the mixer's ``x`` and hands on
     # its ``y`` as they lie (no copy of either layout of 512 x 4096)
-    assert text.count("tpu_custom_call") == 1
+    # and since ISSUE 58 the attention layer's chunk, told "flash" as the
+    # engine tells it: one call of the chunk's kernel under
+    # ds_attn_prefill_plain, no [.., 512, 4608] float32 scores left
+    # (the dense arm's program held 307.4 MB of temporaries here, this
+    # one 4.5 MB), the pool read where it lies
+    assert text.count("tpu_custom_call") == \
+        (2 if program == "prefill" else 1)
+    assert chunk_kernel_calls(text) == \
+        ((1, 1) if program == "prefill" else (0, 0))
+    assert scores_of_a_bucket(text, 512, 4608) == []
+    if program == "prefill":
+        assert compiled.memory_analysis().temp_size_in_bytes < 16e6
     assert ("ds_ssd_prefill" in text) == (program == "prefill")
     assert "ds_ssm_scan" in text and "ds_ssm_conv" in text
     for tokens in ((512, 4096), (512, 64, 64)):

@@ -9,8 +9,8 @@ import jax.numpy as jnp
 import pytest
 
 from tests.unit.test_tpu_compile import (       # noqa: F401 (fixtures)
-    PAGE, _compiled_not_interpreted, chip, decode_call, held_experts_calls,
-    kernel_grids, topo)
+    PAGE, _compiled_not_interpreted, chip, chunk_kernel_calls, decode_call,
+    held_experts_calls, kernel_grids, scores_of_a_bucket, topo)
 
 # the cell's engine: 96 rows, a bucket of 5,120 (40 pages), a pool of
 # 3,840 pages and the trash page, 2 key heads of 128
@@ -47,6 +47,7 @@ def test_nemotron_h_serving_programs_compile(chip, monkeypatch, program):
 
     for name in ("deepspeed_tpu.ops.pallas.flash_decode",
                  "deepspeed_tpu.ops.pallas.ssd_prefill",
+                 "deepspeed_tpu.ops.pallas.chunk_prefill",
                  "deepspeed_tpu.moe.dropless"):
         _compiled_not_interpreted(monkeypatch, name)
     cfg = nh.nemotron_3_super_share(n_layer=3,
@@ -65,7 +66,8 @@ def test_nemotron_h_serving_programs_compile(chip, monkeypatch, program):
     if program == "prefill":
         def fn(params, cache, tokens, positions, table, slots, n_valid):
             return model.serve_apply(params, cache, tokens, positions,
-                                     table, slots, n_valid)
+                                     table, slots, n_valid,
+                                     attn_impl="flash", attn_block_k=PAGE)
         args = (i32(1, CHUNK), i32(1, CHUNK), i32(1, per_row), i32(1),
                 i32(1))
     else:
@@ -88,8 +90,14 @@ def test_nemotron_h_serving_programs_compile(chip, monkeypatch, program):
     # (a prefill's fourth kernel since ISSUE 56: the mixer's chunked
     # scan, `ds_ssd_prefill`, over the mixer's own ``x`` and ``y``: no
     # copy of either layout of 1024 x 8192)
+    # (and its fifth since ISSUE 58: the attention layer's chunk, told
+    # "flash" as the engine tells it: the chunk's kernel under
+    # ds_attn_prefill_plain, no [.., 1024, 5120] float32 scores left)
     assert held_experts_calls(text, pairs, cfg.moe_latent_size) == \
-        (4, 2, 1)
+        (5 if program == "prefill" else 4, 2, 1)
+    assert chunk_kernel_calls(text) == \
+        ((1, 1) if program == "prefill" else (0, 0))
+    assert scores_of_a_bucket(text, CHUNK, BUCKET) == []
     for tokens in ((CHUNK, 8192), (CHUNK, 128, 64)):
         assert payload_shaped_copies(text, tokens) == []
     for scope in ("ds_ssm_in_proj", "ds_ssm_conv", "ds_ssm_scan",
@@ -107,8 +115,10 @@ def test_nemotron_h_serving_programs_compile(chip, monkeypatch, program):
                       for a in jax.tree_util.tree_leaves(cache))
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes == cache_bytes
-    # beside the weights and the cache a call holds under 1 GB
-    assert memory.temp_size_in_bytes < 1e9
+    # beside the weights and the cache a call holds under 1 GB; the
+    # prefill program 157 MB where the dense arm's held 687 MB
+    assert memory.temp_size_in_bytes < \
+        (0.25e9 if program == "prefill" else 1e9)
 
 
 @pytest.mark.parametrize("rows", [44, 2112])

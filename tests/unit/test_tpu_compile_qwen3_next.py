@@ -12,8 +12,8 @@ import jax.numpy as jnp
 import pytest
 
 from tests.unit.test_tpu_compile import (       # noqa: F401 (fixtures)
-    PAGE, _compiled_not_interpreted, chip, decode_call, held_experts_calls,
-    kernel_grids, topo)
+    PAGE, _compiled_not_interpreted, chip, chunk_kernel_calls, decode_call,
+    held_experts_calls, kernel_grids, scores_of_a_bucket, topo)
 
 # the cell's engine: 128 rows, a bucket of 5,120 (40 pages), a pool of
 # 4,096 pages and the trash page, 2 key heads of 256
@@ -153,6 +153,7 @@ def test_qwen3_next_serving_programs_compile(chip, monkeypatch, program):
 
     for name in ("deepspeed_tpu.ops.pallas.flash_decode",
                  "deepspeed_tpu.ops.pallas.gated_delta",
+                 "deepspeed_tpu.ops.pallas.chunk_prefill",
                  "deepspeed_tpu.moe.dropless"):
         _compiled_not_interpreted(monkeypatch, name)
     periods = {"prefill": 1, "decode": 2}[program]
@@ -171,7 +172,8 @@ def test_qwen3_next_serving_programs_compile(chip, monkeypatch, program):
     if program == "prefill":
         def fn(params, cache, tokens, positions, table, slots, n_valid):
             return model.serve_apply(params, cache, tokens, positions,
-                                     table, slots, n_valid)
+                                     table, slots, n_valid,
+                                     attn_impl="flash", attn_block_k=PAGE)
         args = (i32(1, CHUNK), i32(1, CHUNK), i32(1, per_row), i32(1),
                 i32(1))
     else:
@@ -189,11 +191,17 @@ def test_qwen3_next_serving_programs_compile(chip, monkeypatch, program):
     # each of its four blocks, as before the held path became a loop over
     # live tiles (ISSUE 45), and a block's unwritten buffer of sorted
     # rows; in prefill the three delta rules' kernel, in decode the
-    # three delta rules' step (ISSUE 50) and the attention block's
+    # three delta rules' step (ISSUE 50) and the attention block's;
+    # since ISSUE 58 the prefill's attention block's too, told "flash"
+    # as the engine tells it: the chunk's kernel under
+    # ds_attn_prefill_plain, no [.., 1024, bucket] float32 scores left
     pairs = (CHUNK if program == "prefill" else ROWS) * \
         cfg.num_experts_per_tok
     assert held_experts_calls(text, pairs, cfg.hidden_size) == \
-        ({"prefill": 19, "decode": 40}[program], 12 * periods, 4 * periods)
+        ({"prefill": 20, "decode": 40}[program], 12 * periods, 4 * periods)
+    assert chunk_kernel_calls(text) == \
+        ((1, 1) if program == "prefill" else (0, 0))
+    assert scores_of_a_bucket(text, CHUNK, BUCKET) == []
     assert text.count("ds_gated_delta_chunked") >= \
         (3 if program == "prefill" else 0)
     assert "riangular" not in text
@@ -221,5 +229,8 @@ def test_qwen3_next_serving_programs_compile(chip, monkeypatch, program):
                       for a in jax.tree_util.tree_leaves(cache))
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes == cache_bytes
-    # beside the weights and the cache a call holds under 2 GB
-    assert memory.temp_size_in_bytes < 2e9, memory.temp_size_in_bytes
+    # beside the weights and the cache a call holds under 2 GB; the
+    # prefill program (at the cell's bucket of 9,216: 101 MB where the
+    # dense arm's held 687 MB) a quarter of one
+    assert memory.temp_size_in_bytes < \
+        (0.25e9 if program == "prefill" else 2e9), memory.temp_size_in_bytes

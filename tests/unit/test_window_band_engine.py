@@ -1,16 +1,20 @@
 """Who is told the engine's ``attention_impl`` for a prefill chunk, and
 what the ``prefill`` span says of the window layers' bands (ISSUE 52).
 
-`engine._prefill_fn` hands ``attn_impl`` to a model whose spec has page
-groups (the band's kernel under ``"flash"``) or a latent pool (the
-prefill kernel), and to no other: a GPT-2 and a hybrid model receive no
-new argument, so their prefill programs are what they were. The span's
+`engine._prefill_fn` hands ``attn_impl`` (and the TP mesh, ``None``
+here) to every model since ISSUE 58: a spec with page groups (the band's
+kernel under ``"flash"``), a latent pool (the prefill kernel) and a
+per-head pool (the chunk's kernel, where `cache.chunk_kernel_takes` the
+call: never at these toy float32 shapes, so a GPT-2's and a hybrid
+model's prefill programs are what they were). The span's
 ``attn_window_calls`` / ``attn_window_calls_kernel`` are there for a spec
 with page groups and for no other, and the second counts what the call
 site's rule sends to the kernel (`cache.band_kernel_takes`: a window
 longer than the kernel's smallest query block, which the tests below cut
 to 8 so that toy Laguna's window of 16 is long and toy MiMo's of 8 is
-not, as 512 and 128 are against 128 on the chip)."""
+not, as 512 and 128 are against 128 on the chip); a per-head pool's span
+carries ``attn_plain_calls`` / ``attn_plain_calls_kernel`` in their
+place."""
 
 import jax
 import jax.numpy as jnp
@@ -56,13 +60,13 @@ def _mimo():
     return model, mm.init_mimo_v2_params(model, KEY)
 
 
-# name: builder, (prefill_chunk, page_size), is it told
+# name: builder, (prefill_chunk, page_size)
 MODELS = {
-    "gpt2": (_gpt2, (16, 8), False),
-    "hybrid": (_hybrid, (16, 8), False),
-    "latent_pool": (_mla, (16, 8), True),
-    "laguna": (_laguna, (32, 4), True),
-    "mimo_v2": (_mimo, (16, 8), True),
+    "gpt2": (_gpt2, (16, 8)),
+    "hybrid": (_hybrid, (16, 8)),
+    "latent_pool": (_mla, (16, 8)),
+    "laguna": (_laguna, (32, 4)),
+    "mimo_v2": (_mimo, (16, 8)),
 }
 
 
@@ -84,7 +88,7 @@ def build(name, impl, monkeypatch, query_block=8):
     from deepspeed_tpu.ops.pallas import window_prefill as wp
     monkeypatch.setattr(wp, "QUERY_BLOCK", query_block)
     wp._band_call.clear_cache()
-    make, (chunk, page), _ = MODELS[name]
+    make, (chunk, page) = MODELS[name]
     model, params = make()
     told = []
     real = type(model).serve_apply
@@ -112,12 +116,7 @@ def prefill_attrs(eng):
 def test_who_is_told_the_attention_impl(monkeypatch, name, impl):
     eng, told = build(name, impl, monkeypatch)
     eng._prefill.lower(*eng.prefill_lowering_args())
-    assert len(told) == 1
-    if MODELS[name][2]:
-        assert told[0] == {"attn_impl": impl}
-    else:
-        # no new argument: the program is the one it was
-        assert told[0] == {}
+    assert told == [{"attn_impl": impl, "attn_mesh": None}]
 
 
 @pytest.mark.parametrize("impl", ["flash", "dense"])
@@ -155,6 +154,13 @@ def test_other_specs_spans_carry_no_window_calls(monkeypatch, name):
     assert "attn_window_calls" not in attrs
     assert "attn_window_calls_kernel" not in attrs
     assert ("attn_blocks" in attrs) == (name == "latent_pool")
+    # a per-head pool's chunks a layer, none through the chunk's kernel
+    # at a toy float32 chunk of 16 (ISSUE 58)
+    assert ("attn_plain_calls" in attrs) == (name != "latent_pool")
+    if name != "latent_pool":
+        assert attrs["attn_plain_calls"] == \
+            attrs["chunks"] * eng.spec.n_layer > 0
+        assert attrs["attn_plain_calls_kernel"] == 0
     # nor a model without Mamba-2 mixers any scan calls (ISSUE 56)
     assert ("ssd_scan_calls" in attrs) == (name == "hybrid")
 
