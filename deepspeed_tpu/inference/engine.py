@@ -54,10 +54,11 @@ the cache with head-sharded specs (`cache.kv_partition_specs`), so
 decode matmuls and attention run tensor-parallel with GSPMD inserting
 the row-parallel psums.
 
-**The model's side of the seam** (ISSUE 31; `models/gpt2.py:GPT2LMHead`
-`models/granite_hybrid.py:GraniteHybridLM` and
-`models/mla_moe.py:MlaMoeLM` answer it, and the engine asks nothing
-else of a model):
+**The model's side of the seam** (ISSUE 31; nine models answer it:
+`models/gpt2.py:GPT2LMHead` in its own file, and the eight served
+decoders, `granite_hybrid`, `mla_moe`, `nemotron_h`, `qwen3_next`,
+`mimo_v2`, `laguna`, `ling_hybrid` and `lfm2_moe`, through one mixin,
+`models/blocks.py:ServedLM`; the engine asks nothing else of a model):
 
 - ``model.cache_spec(max_batch, max_seq, kv_cache_dtype=None,
   page_size=0, n_pages=0)`` -> the :class:`~deepspeed_tpu.inference.
@@ -74,8 +75,9 @@ else of a model):
   with one token, ``n_valid`` 0 where the slot holds no request;
 - optionally ``model.serve_counters``, names of int32 scalars that
   ``serve_apply`` then returns third, as a dict (the expert layers'
-  pairs of `models/mla_moe.py`): a decode step carries them home
-  behind its tokens and puts them on its ``decode`` span;
+  counters by name, `models/blocks.py:summed_counters`, and what a
+  model adds of its own): a decode step carries them home behind its
+  tokens and puts them on its ``decode`` span;
 - ``model.partition_specs(params)`` where a ``model`` mesh axis is
   wanted.
 

@@ -68,11 +68,13 @@ import jax
 import jax.numpy as jnp
 import flax.linen as nn
 
-from deepspeed_tpu.models.granite_hybrid import GatedMLP
-from deepspeed_tpu.models.mla_moe import (_normal, _param, rotate,
-                                          yarn_inv_freq)
-from deepspeed_tpu.models.olmoe import RMSNorm
-from deepspeed_tpu.models.qwen3_next import partial_rotary
+from deepspeed_tpu.models.blocks import (GatedMLP, RMSNorm, ServedLM,
+                                         expert_counters, head_logits,
+                                         init_served_params,
+                                         last_token, normal, param,
+                                         partial_rotary, rotate,
+                                         summed_counters, token_mask,
+                                         yarn_inv_freq)
 from deepspeed_tpu.moe.dropless import dropless_moe, softmax_top_k_scaled
 
 FULL, WINDOW = "full", "window"
@@ -290,7 +292,7 @@ def rotary(x, positions, kind):
     ``rotary_dim`` entries of each head of ``x`` ``[B, T, H, D]`` at
     ``positions`` ``[B, T]``; the rest pass. ``default``: the plain
     frequencies at the kind's base. ``yarn``: each a blend of itself and
-    itself over ``factor`` (`models/mla_moe.py:yarn_inv_freq`), ``cos``
+    itself over ``factor`` (`models/blocks.py:yarn_inv_freq`), ``cos``
     and ``sin`` times ``attention_factor``. Angles in float32."""
     if kind.rope["rope_type"] == "default":
         return partial_rotary(x, positions, kind)
@@ -319,9 +321,9 @@ class LagunaAttention(nn.Module):
         B, T, C = x.shape
         Hq, H, D = kind.heads, kind.kv_heads, kind.head_dim
         with jax.named_scope("ds_attn_qkv"):
-            q = jnp.dot(x, _param(self, "q_proj", cfg, (C, Hq * D)))
-            k = jnp.dot(x, _param(self, "k_proj", cfg, (C, H * D)))
-            v = jnp.dot(x, _param(self, "v_proj", cfg, (C, H * D)))
+            q = jnp.dot(x, param(self, "q_proj", cfg, (C, Hq * D)))
+            k = jnp.dot(x, param(self, "k_proj", cfg, (C, H * D)))
+            v = jnp.dot(x, param(self, "v_proj", cfg, (C, H * D)))
             q = rotary(q.reshape(B, T, Hq, D), positions, kind)
             k = rotary(k.reshape(B, T, H, D), positions, kind)
         y, layer_cache = cached_attention(
@@ -330,12 +332,12 @@ class LagunaAttention(nn.Module):
             walk=True, **attn)
         with jax.named_scope("ds_attn_gate"):
             gate = jax.nn.sigmoid(jnp.dot(
-                x, _param(self, "g_proj", cfg, (C, Hq)),
+                x, param(self, "g_proj", cfg, (C, Hq)),
                 preferred_element_type=jnp.float32))        # [B, T, Hq]
             y = (y.astype(jnp.float32) * gate[..., None]).astype(cfg.dtype)
         with jax.named_scope("ds_attn_out"):
             y = jnp.dot(y.reshape(B, T, Hq * D),
-                        _param(self, "o_proj", cfg, (Hq * D, C)))
+                        param(self, "o_proj", cfg, (Hq * D, C)))
         return y, layer_cache
 
 
@@ -349,18 +351,13 @@ def _held_experts(x, mask, router, w_gate, w_up, w_down, *, top_k, scaling,
         x, router, w_gate, w_up, w_down, top_k,
         route=softmax_top_k_scaled(scaling, renormalise),
         first_expert=first_expert, token_mask=mask)
-    sizes = stats["tokens_per_expert"]
-    counters = jnp.stack([mask.sum().astype(jnp.int32) * top_k, sizes.sum(),
-                          (sizes > 0).sum().astype(jnp.int32),
-                          stats["rows_visited"], sizes.max()])
-    return y, counters
+    return y, expert_counters(mask, top_k, stats)
 
 
 class SparseExperts(nn.Module):
     """The routed experts this chip holds and the shared expert,
-    ungated. Returns ``(y, counters [5])`` (`COUNTERS` less the experts
-    held, this layer's); ``mask`` ``[B, T]`` says which tokens are
-    real."""
+    ungated. Returns ``(y, the layer's `blocks.ExpertCounters`)``;
+    ``mask`` ``[B, T]`` says which tokens are real."""
     config: LagunaConfig
 
     @nn.compact
@@ -370,7 +367,7 @@ class SparseExperts(nn.Module):
         E, I, S = cfg.num_experts, cfg.moe_intermediate_size, \
             cfg.shared_expert_intermediate_size
         first, held = cfg.experts_held
-        init, pd = _normal(cfg), cfg.param_dtype
+        init, pd = normal(cfg), cfg.param_dtype
         router = self.param("router", init, (C, E), pd)
         w_gate = self.param("w_gate", init, (held, C, I), pd)
         w_up = self.param("w_up", init, (held, C, I), pd)
@@ -382,9 +379,9 @@ class SparseExperts(nn.Module):
             renormalise=cfg.norm_topk_prob, first_expert=first)
         with jax.named_scope("ds_moe_shared"):
             hidden = jax.nn.silu(
-                jnp.dot(x, _param(self, "shared_gate", cfg, (C, S)))) * \
-                jnp.dot(x, _param(self, "shared_up", cfg, (C, S)))
-            shared = jnp.dot(hidden, _param(self, "shared_down", cfg,
+                jnp.dot(x, param(self, "shared_gate", cfg, (C, S)))) * \
+                jnp.dot(x, param(self, "shared_up", cfg, (C, S)))
+            shared = jnp.dot(hidden, param(self, "shared_down", cfg,
                                             (S, C)))
         return y.reshape(B, T, C) + shared, counters
 
@@ -412,13 +409,13 @@ class LagunaLayer(nn.Module):
             n = RMSNorm(cfg, name="post_attn_norm")(h)
             if self.dense:
                 y = GatedMLP(cfg, cfg.intermediate_size, name="mlp")(n)
-                counters = jnp.zeros((5,), jnp.int32)
+                counters = None
             else:
                 y, counters = SparseExperts(cfg, name="experts")(n, mask)
             return h + y, layer_cache, counters
 
 
-class LagunaLM(nn.Module):
+class LagunaLM(ServedLM, nn.Module):
     """The decoder with its untied head, through the serving cache.
     Returns ``(logits [B, vocab_size] float32 at each row's last real
     token, the cache, the counters of `COUNTERS`)``."""
@@ -431,13 +428,12 @@ class LagunaLM(nn.Module):
         from deepspeed_tpu.inference.cache import split_table
         cfg = self.config
         B, T = tokens.shape
-        embed = self.param("embed", _normal(cfg),
+        embed = self.param("embed", normal(cfg),
                            (cfg.vocab_size, cfg.hidden_size),
                            cfg.param_dtype)
         with jax.named_scope("ds_embed"):
             h = embed.astype(cfg.dtype)[tokens]
-            # a decode row without a request, a chunk's padded tail
-            mask = jnp.arange(T)[None, :] < n_valid[:, None]
+            mask = token_mask(n_valid, T)
         # the table's last entries are the row's ring, where there is one
         page_size = next(iter(cache.values()))["k"].shape[-1]
         ring = cfg.sliding_window // page_size + 1 \
@@ -452,41 +448,28 @@ class LagunaLM(nn.Module):
                 cfg, which, cfg.is_dense(i), name=name)(
                     h, cache[name], positions, tables[which], n_valid, mask,
                     attn)
-            counted.append(c)
+            if c is not None:
+                counted.append(c)
         with jax.named_scope("ds_head"):
-            last = jnp.maximum(n_valid - 1, 0)[:, None, None]
-            h = jnp.take_along_axis(h, last, axis=1)[:, 0]
-            h = RMSNorm(cfg, name="final_norm")(h)
-            head = self.param("lm_head", _normal(cfg),
+            h = RMSNorm(cfg, name="final_norm")(last_token(h, n_valid))
+            head = self.param("lm_head", normal(cfg),
                               (cfg.hidden_size, cfg.vocab_size),
                               cfg.param_dtype)
-            logits = jnp.dot(h, head.astype(cfg.dtype),
-                             preferred_element_type=jnp.float32)
-        sparse = sum(not cfg.is_dense(i)
-                     for i in range(cfg.num_hidden_layers))
+            logits = head_logits(h, head, cfg.dtype)
         with jax.named_scope("ds_sample"):
-            counted = jnp.stack(counted)
-            values = [*counted[:, :4].sum(0),
-                      jnp.int32(cfg.experts_held[1] * sparse),
-                      counted[:, 4].max()]
-        return logits, new_cache, dict(zip(COUNTERS, values))
-
-    # -- the serving engine's protocol (`inference/engine.py`) -------------
+            counters = summed_counters(
+                COUNTERS, counted, moe_experts_held=jnp.int32(
+                    cfg.experts_held[1] * len(counted)))
+        return logits, new_cache, counters
 
     @nn.nowrap
-    def cache_spec(self, *args, **kwargs):
-        return self.config.cache_spec(*args, **kwargs)
-
-    @nn.nowrap
-    def serve_apply(self, params, cache, tokens, positions, page_table,
-                    slots, n_valid, attn_impl="dense", attn_block_k=128,
-                    attn_mesh=None):
+    def serve_args(self, cache, tokens, positions, page_table, slots,
+                   n_valid, attn_impl, attn_block_k, attn_mesh):
         del slots       # pages are the cache: a row's slot owns nothing
         if attn_mesh is not None:
             raise LagunaUnsupported("a 'model' mesh axis is not built")
-        return self.apply(
-            {"params": params}, tokens, cache, positions, page_table,
-            n_valid, {"impl": attn_impl, "block_k": attn_block_k})
+        return (tokens, cache, positions, page_table, n_valid,
+                {"impl": attn_impl, "block_k": attn_block_k})
 
 
 # the matrices that write to the stream: out of an attention, a dense
@@ -494,35 +477,8 @@ class LagunaLM(nn.Module):
 _WRITERS = {"o_proj": 0, "w_out": 0, "shared_down": 0, "w_down": 1}
 
 
-def _centred(path, leaf):
-    """A writer's weights less their mean over its input axis
-    (`models/nemotron_h.py:_centred` says why: random weights under
-    SiLU give every token the same mean activation, which an uncentred
-    writer turns into one token-independent vector in the stream, and
-    every token then chooses the same experts)."""
-    axis = _WRITERS.get(path[-1].key)
-    if axis is None:
-        return leaf
-    w = leaf.astype(jnp.float32)
-    return (w - w.mean(axis, keepdims=True)).astype(leaf.dtype)
-
-
 def init_laguna_params(model, rng):
-    """The model's weights from ``rng``, in ``param_dtype``, the writers
-    centred (`_centred`), made on the device in one jitted call (a 2-row
-    toy cache gives the shapes)."""
-    cfg = model.config
-    page = max(cfg.sliding_window, 8)
-    spec = cfg.cache_spec(2, page, page_size=page)
-
-    def init(key):
-        from deepspeed_tpu.inference.cache import init_kv_cache
-        params = model.init(
-            {"params": key}, jnp.zeros((1, page), jnp.int32),
-            init_kv_cache(spec), jnp.arange(page, dtype=jnp.int32)[None],
-            jnp.ones((1, spec.table_width), jnp.int32),
-            jnp.full((1,), page, jnp.int32),
-            {"impl": "dense", "block_k": page})["params"]
-        return jax.tree_util.tree_map_with_path(_centred, params)
-
-    return jax.jit(init)(rng)
+    """The model's weights from ``rng``, the writers centred
+    (`blocks.init_served_params`), over a page that holds a window."""
+    return init_served_params(model, rng, _WRITERS,
+                              page=max(model.config.sliding_window, 8))

@@ -77,11 +77,13 @@ import jax
 import jax.numpy as jnp
 import flax.linen as nn
 
-from deepspeed_tpu.models.granite_hybrid import (GatedMLP, _conv_init,
-                                                 _normal)
-from deepspeed_tpu.models.mla_moe import rotate
-from deepspeed_tpu.models.olmoe import RMSNorm
-from deepspeed_tpu.models.qwen3_next import _param
+from deepspeed_tpu.models import blocks
+from deepspeed_tpu.models.blocks import (GatedMLP, RMSNorm, ServedLM,
+                                         conv_init, expert_counters,
+                                         head_logits, init_served_params,
+                                         last_token, normal,
+                                         normal_bias_init, param, rotate,
+                                         summed_counters, token_mask)
 from deepspeed_tpu.moe.dropless import dropless_moe, sigmoid_top_k
 from deepspeed_tpu.ops import ssm
 
@@ -161,7 +163,7 @@ class Lfm2MoeConfig:
         return self.hidden_size // self.num_attention_heads
 
     @property
-    def rms_norm_eps(self):     # `models/olmoe.py:RMSNorm` reads this name
+    def rms_norm_eps(self):     # `models/blocks.py:RMSNorm` reads this name
         return self.norm_eps
 
     def is_dense(self, i):
@@ -240,8 +242,8 @@ class ShortConv(nn.Module):
         B, T, C = x.shape
         L = cfg.conv_L_cache
         with jax.named_scope("ds_sconv_in_proj"):
-            bcx = jnp.dot(x, _param(self, "in_proj", cfg, (C, 3 * C)))
-        taps = self.param("conv_weight", _conv_init(L), (L, C),
+            bcx = jnp.dot(x, param(self, "in_proj", cfg, (C, 3 * C)))
+        taps = self.param("conv_weight", conv_init(L), (L, C),
                           cfg.param_dtype)
         window = leaves["conv"]
         with jax.named_scope("ds_sconv_taps"):
@@ -268,7 +270,7 @@ class ShortConv(nn.Module):
                     f"every row; got {B} rows of {T} tokens")
             y = (c * z).astype(cfg.dtype)
         with jax.named_scope("ds_sconv_out_proj"):
-            y = jnp.dot(y, _param(self, "out_proj", cfg, (C, C)))
+            y = jnp.dot(y, param(self, "out_proj", cfg, (C, C)))
         return y, {"conv": window}
 
 
@@ -294,11 +296,8 @@ def head_norm(x, w, eps):
 def rope_cos_sin(cfg, positions):
     """``cos`` and ``sin`` ``[B, T, 1, head_dim / 2]`` float32 of the
     plain rotary angles at ``positions``, over the whole head."""
-    d = cfg.head_dim
-    inv = 1.0 / cfg.rope_theta ** (
-        jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = positions.astype(jnp.float32)[..., None, None] * inv
-    return jnp.cos(ang), jnp.sin(ang)
+    return blocks.rope_cos_sin(positions[..., None], cfg.head_dim,
+                               cfg.rope_theta)
 
 
 class NormedGroupedQueryAttention(nn.Module):
@@ -314,9 +313,9 @@ class NormedGroupedQueryAttention(nn.Module):
         Hq, Hkv, D = cfg.num_attention_heads, cfg.num_key_value_heads, \
             cfg.head_dim
         with jax.named_scope("ds_attn_qkv"):
-            q = jnp.dot(x, _param(self, "q_proj", cfg, (C, Hq * D)))
-            k = jnp.dot(x, _param(self, "k_proj", cfg, (C, Hkv * D)))
-            v = jnp.dot(x, _param(self, "v_proj", cfg, (C, Hkv * D)))
+            q = jnp.dot(x, param(self, "q_proj", cfg, (C, Hq * D)))
+            k = jnp.dot(x, param(self, "k_proj", cfg, (C, Hkv * D)))
+            v = jnp.dot(x, param(self, "v_proj", cfg, (C, Hkv * D)))
             with jax.named_scope("ds_attn_qk_norm"):
                 q = head_norm(q.reshape(B, T, Hq, D),
                               _head_norm_weight(self, "q_layernorm", cfg),
@@ -331,17 +330,11 @@ class NormedGroupedQueryAttention(nn.Module):
             cfg.dtype, page_table, scale=D ** -0.5, **attn)
         with jax.named_scope("ds_attn_out"):
             y = jnp.dot(y.reshape(B, T, Hq * D),
-                        _param(self, "o_proj", cfg, (Hq * D, C)))
+                        param(self, "o_proj", cfg, (Hq * D, C)))
         return y, layer_cache
 
 
 # --- experts --------------------------------------------------------------------
-
-def _bias_init(cfg):
-    def init(key, shape, dtype):
-        return cfg.router_bias_range * jax.random.normal(key, shape, dtype)
-    return init
-
 
 # jitted, so that the expert layers share one trace of the routing and of
 # the three grouped matmuls (as `models/qwen3_next.py`'s)
@@ -353,21 +346,13 @@ def _held_experts(x, mask, router, bias, w_gate, w_up, w_down, *, top_k,
         x, router, w_gate, w_up, w_down, top_k,
         route=sigmoid_top_k(bias, scaling, renormalise, eps=ROUTE_EPS),
         first_expert=first_expert, token_mask=mask)
-    sizes = stats["tokens_per_expert"]
-    counters = jnp.stack([mask.sum().astype(jnp.int32) * top_k, sizes.sum(),
-                          (sizes > 0).sum().astype(jnp.int32), sizes.max(),
-                          stats["rows_visited"]])
-    return y, counters
-
-
-N_LAYER_COUNTERS = 5
+    return y, expert_counters(mask, top_k, stats)
 
 
 class HeldExperts(nn.Module):
     """The routed experts this chip holds; no shared expert. Returns
-    ``(y, counters [5])`` (this layer's pairs routed, pairs held, experts
-    touched, fullest expert, rows visited); ``mask`` ``[B, T]`` says
-    which tokens are real."""
+    ``(y, the layer's `blocks.ExpertCounters`)``; ``mask`` ``[B, T]``
+    says which tokens are real."""
     config: Lfm2MoeConfig
 
     @nn.compact
@@ -376,9 +361,10 @@ class HeldExperts(nn.Module):
         B, T, C = x.shape
         E, I = cfg.num_experts, cfg.moe_intermediate_size
         first, held = cfg.experts_held
-        init, pd = _normal(cfg), cfg.param_dtype
+        init, pd = normal(cfg), cfg.param_dtype
         router = self.param("router", init, (C, E), pd)
-        bias = self.param("expert_bias", _bias_init(cfg), (E,), jnp.float32)
+        bias = self.param("expert_bias", normal_bias_init(cfg), (E,),
+                          jnp.float32)
         w_gate = self.param("w_gate", init, (held, C, I), pd)
         w_up = self.param("w_up", init, (held, C, I), pd)
         w_down = self.param("w_down", init, (held, I, C), pd)
@@ -420,13 +406,13 @@ class Lfm2MoeLayer(nn.Module):
             n = RMSNorm(cfg, name="ffn_norm")(h)
             if self.dense:
                 y = GatedMLP(cfg, cfg.intermediate_size, name="mlp")(n)
-                counters = jnp.zeros((N_LAYER_COUNTERS,), jnp.int32)
+                counters = None
             else:
                 y, counters = HeldExperts(cfg, name="experts")(n, mask)
             return h + y, layer_cache, counters
 
 
-class Lfm2MoeLM(nn.Module):
+class Lfm2MoeLM(ServedLM, nn.Module):
     """The decoder with its tied head, through the serving cache.
     Returns ``(logits [B, vocab_size] float32 at each row's last real
     token, the cache, the counters of `COUNTERS`)``."""
@@ -439,14 +425,13 @@ class Lfm2MoeLM(nn.Module):
                  n_valid, attn):
         cfg = self.config
         B, T = tokens.shape
-        embed = self.param("embed", _normal(cfg),
+        embed = self.param("embed", normal(cfg),
                            (cfg.vocab_size, cfg.hidden_size),
                            cfg.param_dtype)
         with jax.named_scope("ds_embed"):
             h = embed.astype(cfg.dtype)[tokens]
             rope = rope_cos_sin(cfg, positions)
-            # a decode row without a request, a chunk's padded tail
-            mask = jnp.arange(T)[None, :] < n_valid[:, None]
+            mask = token_mask(n_valid, T)
         new_cache, counted = {}, []
         for i, kind in enumerate(cfg.layer_types):
             name = f"layers_{i}"
@@ -454,38 +439,19 @@ class Lfm2MoeLM(nn.Module):
                 cfg, kind, bool(cfg.is_dense(i)), name=name)(
                     h, cache[name], positions, page_table, slots, n_valid,
                     rope, mask, attn)
-            counted.append(counters)
-        # the head reads each row's last real token only
+            if counters is not None:
+                counted.append(counters)
         with jax.named_scope("ds_head"):
-            last = jnp.maximum(n_valid - 1, 0)[:, None, None]
-            h = jnp.take_along_axis(h, last, axis=1)[:, 0]
-            h = RMSNorm(cfg, name="embedding_norm")(h)
-            logits = jnp.dot(h, embed.T.astype(cfg.dtype),
-                             preferred_element_type=jnp.float32)
+            h = RMSNorm(cfg, name="embedding_norm")(last_token(h, n_valid))
+            logits = head_logits(h, embed.T, cfg.dtype)
         with jax.named_scope("ds_sample"):
-            counted = jnp.stack(counted)
-            expert_layers = cfg.num_hidden_layers - cfg.num_dense_layers
-            live = (n_valid > 0).sum().astype(jnp.int32)
-            values = [*counted[:, :3].sum(0), counted[:, 3].max(),
-                      jnp.int32(cfg.experts_held[1] * expert_layers),
-                      live, jnp.int32(B), counted[:, 4].sum()]
-        return logits, new_cache, dict(zip(COUNTERS, values))
-
-    # -- the serving engine's protocol (`inference/engine.py`) -------------
-
-    @nn.nowrap
-    def cache_spec(self, *args, **kwargs):
-        return self.config.cache_spec(*args, **kwargs)
-
-    @nn.nowrap
-    def serve_apply(self, params, cache, tokens, positions, page_table,
-                    slots, n_valid, attn_impl="dense", attn_block_k=128,
-                    attn_mesh=None):
-        return self.apply(
-            {"params": params}, tokens, cache, positions, page_table,
-            slots, n_valid,
-            {"impl": attn_impl, "block_k": attn_block_k,
-             "mesh": attn_mesh})
+            counters = summed_counters(
+                COUNTERS, counted,
+                sconv_rows_live=(n_valid > 0).sum().astype(jnp.int32),
+                sconv_rows_touched=jnp.int32(B),
+                moe_experts_held=jnp.int32(
+                    cfg.experts_held[1] * len(counted)))
+        return logits, new_cache, counters
 
 
 # the matrices that write to the stream (out of a mixer, the attention,
@@ -493,30 +459,7 @@ class Lfm2MoeLM(nn.Module):
 _WRITERS = {"out_proj": 0, "o_proj": 0, "w_out": 0, "w_down": 1}
 
 
-def _centred(path, leaf):
-    """A writer's weights less their mean over its input axis
-    (`models/qwen3_next.py:_centred` says why)."""
-    axis = _WRITERS.get(path[-1].key)
-    if axis is None:
-        return leaf
-    w = leaf.astype(jnp.float32)
-    return (w - w.mean(axis, keepdims=True)).astype(leaf.dtype)
-
-
 def init_lfm2_moe_params(model, rng):
-    """The model's weights from ``rng``, in ``param_dtype`` (the router's
-    bias float32), the writers centred (`_centred`), made on the device
-    in one jitted call (a 2-row toy cache gives the shapes)."""
-    spec = model.config.cache_spec(2, 8, page_size=8)
-
-    def init(key):
-        from deepspeed_tpu.inference.cache import init_kv_cache
-        params = model.init(
-            {"params": key}, jnp.zeros((1, 8), jnp.int32),
-            init_kv_cache(spec), jnp.arange(8, dtype=jnp.int32)[None],
-            jnp.zeros((1, 1), jnp.int32), jnp.zeros((1,), jnp.int32),
-            jnp.full((1,), 8, jnp.int32),
-            {"impl": "dense", "block_k": 8, "mesh": None})["params"]
-        return jax.tree_util.tree_map_with_path(_centred, params)
-
-    return jax.jit(init)(rng)
+    """The model's weights from ``rng``, the writers centred
+    (`blocks.init_served_params`)."""
+    return init_served_params(model, rng, _WRITERS)

@@ -74,6 +74,7 @@ choice and weights float32.
 statement of the same mathematics. Serving only.
 """
 
+import collections
 import dataclasses
 import functools
 import math
@@ -83,11 +84,14 @@ import jax
 import jax.numpy as jnp
 import flax.linen as nn
 
-from deepspeed_tpu.models.granite_hybrid import (GatedMLP, _conv_init,
-                                                 _normal)
-from deepspeed_tpu.models.mla_moe import _bias_init, rotate
-from deepspeed_tpu.models.olmoe import RMSNorm
-from deepspeed_tpu.models.qwen3_next import _l2_normalised, _param
+from deepspeed_tpu.models import blocks
+from deepspeed_tpu.models.blocks import (ExpertCounters, GatedMLP, RMSNorm,
+                                         ServedLM, conv_init,
+                                         expert_counters, head_logits,
+                                         init_served_params, l2_normalised,
+                                         last_token, normal, param, rotate,
+                                         summed_counters, token_mask,
+                                         uniform_bias_init)
 from deepspeed_tpu.moe.dropless import dropless_moe, sigmoid_group_top_k
 from deepspeed_tpu.ops import kda, ssm
 
@@ -339,10 +343,10 @@ class KimiDeltaAttention(nn.Module):
             cfg.short_conv_kernel_size
         d, pd = cfg.key_dim, cfg.param_dtype
         qkv = jnp.concatenate(
-            [jnp.dot(x, _param(self, name, cfg, (C, d)))
+            [jnp.dot(x, param(self, name, cfg, (C, d)))
              for name in ("q_proj", "k_proj", "v_proj")], axis=-1)
         conv_w = jnp.concatenate(
-            [self.param(name, _conv_init(taps), (taps, d), pd)
+            [self.param(name, conv_init(taps), (taps, d), pd)
              for name in ("q_conv", "k_conv", "v_conv")], axis=-1)
         no_bias = jnp.zeros((3 * d,), jnp.float32)
         with jax.named_scope("ds_kda_gate"):
@@ -356,10 +360,10 @@ class KimiDeltaAttention(nn.Module):
                 A[:, None] * (a + dt_bias.astype(jnp.float32)
                               ).reshape(B, T, H, K))
             beta = jax.nn.sigmoid(jnp.dot(
-                x, _param(self, "b_proj", cfg, (C, H)),
+                x, param(self, "b_proj", cfg, (C, H)),
                 preferred_element_type=jnp.float32))
             gate = jax.nn.sigmoid(jnp.dot(
-                x, _param(self, "g_proj", cfg, (C, d)),
+                x, param(self, "g_proj", cfg, (C, d)),
                 preferred_element_type=jnp.float32))
         state, window = leaves["kda"], leaves["conv"]
 
@@ -367,8 +371,8 @@ class KimiDeltaAttention(nn.Module):
             """The convolved channels ``u`` ``[rows, 3 H K]`` as the
             recurrence takes them: ``q``, ``k`` (unit length, ``q``
             scaled) and ``v``, each ``[rows, H, K]``."""
-            q = _l2_normalised(u[:, :d].reshape(-1, H, K)) * K ** -0.5
-            k = _l2_normalised(u[:, d:2 * d].reshape(-1, H, K))
+            q = l2_normalised(u[:, :d].reshape(-1, H, K)) * K ** -0.5
+            k = l2_normalised(u[:, d:2 * d].reshape(-1, H, K))
             return q.astype(cfg.dtype), k.astype(cfg.dtype), \
                 u[:, 2 * d:].reshape(-1, H, K)
 
@@ -414,7 +418,7 @@ class KimiDeltaAttention(nn.Module):
                               + cfg.rms_norm_eps)
         y = o * w.astype(jnp.float32) * gate.reshape(B, T, H, K)
         y = jnp.dot(y.reshape(B, T, d).astype(cfg.dtype),
-                    _param(self, "o_proj", cfg, (d, C)))
+                    param(self, "o_proj", cfg, (d, C)))
         return y, {"kda": state, "conv": window}
 
 
@@ -423,11 +427,8 @@ class KimiDeltaAttention(nn.Module):
 def rope_cos_sin(cfg, positions):
     """``cos`` and ``sin`` ``[B, T, rope / 2]`` float32 of the plain
     rotary angles at ``positions``."""
-    r = cfg.qk_rope_head_dim
-    inv = 1.0 / cfg.rope_theta ** (
-        jnp.arange(0, r, 2, dtype=jnp.float32) / r)
-    ang = positions.astype(jnp.float32)[..., None] * inv
-    return jnp.cos(ang), jnp.sin(ang)
+    return blocks.rope_cos_sin(positions, cfg.qk_rope_head_dim,
+                               cfg.rope_theta)
 
 
 class GatedLatentAttention(nn.Module):
@@ -448,14 +449,14 @@ class GatedLatentAttention(nn.Module):
         cos, sin = rope
         absorbed = T == 1       # a decode step; a chunk expands its blocks
         with jax.named_scope("ds_mla_project"):
-            q = jnp.dot(x, _param(self, "q_proj", cfg, (C, H * (dn + dr))))
+            q = jnp.dot(x, param(self, "q_proj", cfg, (C, H * (dn + dr))))
             q = q.reshape(B, T, H, dn + dr)
-            ckv = jnp.dot(x, _param(self, "kv_a_proj", cfg, (C, rkv + dr)))
+            ckv = jnp.dot(x, param(self, "kv_a_proj", cfg, (C, rkv + dr)))
             c_kv = RMSNorm(cfg, name="kv_a_norm")(ckv[..., :rkv])
             q_rope = rotate(q[..., dn:], cos[:, :, None], sin[:, :, None])
             k_rope = rotate(ckv[..., rkv:], cos, sin)
             latent = jnp.concatenate([c_kv, k_rope], -1)[:, :, None]
-            w_ukv = _param(self, "kv_b_proj", cfg,
+            w_ukv = param(self, "kv_b_proj", cfg,
                            (rkv, H * (dn + dv))).reshape(rkv, H, dn + dv)
             expand = None
             if absorbed:
@@ -481,16 +482,24 @@ class GatedLatentAttention(nn.Module):
                 y = jnp.einsum("bthc,chv->bthv", y, w_ukv[..., dn:])
         with jax.named_scope("ds_attn_gate"):
             opened = jax.nn.sigmoid(jnp.dot(
-                x, _param(self, "gate_proj", cfg, (C, H)),
+                x, param(self, "gate_proj", cfg, (C, H)),
                 preferred_element_type=jnp.float32))
             y = (y.astype(jnp.float32) * opened[..., None]).astype(cfg.dtype)
         with jax.named_scope("ds_mla_project"):
             y = jnp.dot(y.reshape(B, T, H * dv),
-                        _param(self, "o_proj", cfg, (H * dv, C)))
+                        param(self, "o_proj", cfg, (H * dv, C)))
         return y, layer_cache
 
 
 # --- experts ------------------------------------------------------------------
+
+# a layer's `blocks.ExpertCounters` and, behind them, the real tokens
+# the layer routed and those of them one of whose kept groups is held
+# here
+GroupedExpertCounters = collections.namedtuple(
+    "GroupedExpertCounters",
+    ExpertCounters._fields + ("tokens_routed", "tokens_held_group"))
+
 
 # jitted, so that the expert layers share one trace of the routing and of
 # the three grouped matmuls (as `models/qwen3_next.py`'s)
@@ -504,7 +513,6 @@ def _held_experts(x, mask, router, bias, w_gate, w_up, w_down, *, top_k,
         route=sigmoid_group_top_k(bias, scaling, n_group, topk_group,
                                   renormalise),
         first_expert=first_expert, token_mask=mask)
-    sizes = stats["tokens_per_expert"]
     with jax.named_scope("ds_moe_route"):
         # the groups that hold a held expert, and the real tokens one of
         # whose kept groups is such a group
@@ -513,20 +521,16 @@ def _held_experts(x, mask, router, bias, w_gate, w_up, w_down, *, top_k,
         held = (groups >= first_expert // per) & \
             (groups <= (first_expert + w_up.shape[0] - 1) // per)
         here = mask & held[stats["kept_groups"]].any(-1)
-    tokens = mask.sum().astype(jnp.int32)
-    counters = jnp.stack([tokens * top_k, sizes.sum(),
-                          (sizes > 0).sum().astype(jnp.int32), sizes.max(),
-                          stats["rows_visited"], tokens,
-                          here.sum().astype(jnp.int32)])
-    return y, counters
+    return y, GroupedExpertCounters(
+        *expert_counters(mask, top_k, stats),
+        tokens_routed=mask.sum().astype(jnp.int32),
+        tokens_held_group=here.sum().astype(jnp.int32))
 
 
 class GroupedExperts(nn.Module):
     """The routed experts this chip holds and the shared expert.
-    Returns ``(y, counters [7])`` (this layer's pairs routed, pairs held,
-    experts touched, fullest expert, rows visited, tokens routed, tokens
-    with a held group kept); ``mask`` ``[B, T]`` says which tokens are
-    real."""
+    Returns ``(y, the layer's `GroupedExpertCounters`)``; ``mask`` ``[B,
+    T]`` says which tokens are real."""
     config: LingHybridConfig
 
     @nn.compact
@@ -535,9 +539,9 @@ class GroupedExperts(nn.Module):
         B, T, C = x.shape
         E, I = cfg.num_experts, cfg.moe_intermediate_size
         first, held = cfg.experts_held
-        init, pd = _normal(cfg), cfg.param_dtype
+        init, pd = normal(cfg), cfg.param_dtype
         router = self.param("router", init, (C, E), pd)
-        bias = self.param("expert_bias", _bias_init(cfg), (E,), jnp.float32)
+        bias = self.param("expert_bias", uniform_bias_init(cfg), (E,), jnp.float32)
         w_gate = self.param("w_gate", init, (held, C, I), pd)
         w_up = self.param("w_up", init, (held, C, I), pd)
         w_down = self.param("w_down", init, (held, I, C), pd)
@@ -552,9 +556,6 @@ class GroupedExperts(nn.Module):
                 cfg, cfg.moe_shared_expert_intermediate_size *
                 cfg.num_shared_experts, name="shared")(x)
         return y.reshape(B, T, C) + shared, counters
-
-
-N_LAYER_COUNTERS = 7
 
 
 class LingHybridLayer(nn.Module):
@@ -587,13 +588,13 @@ class LingHybridLayer(nn.Module):
             n = RMSNorm(cfg, name="post_norm")(h)
             if self.dense:
                 y = GatedMLP(cfg, cfg.intermediate_size, name="mlp")(n)
-                counters = jnp.zeros((N_LAYER_COUNTERS,), jnp.int32)
+                counters = None
             else:
                 y, counters = GroupedExperts(cfg, name="experts")(n, mask)
             return h + y, layer_cache, counters
 
 
-class LingHybridLM(nn.Module):
+class LingHybridLM(ServedLM, nn.Module):
     """The decoder with its untied head, through the serving cache.
     Returns ``(logits [B, vocab_size] float32 at each row's last real
     token, the cache, the counters of `COUNTERS`)``."""
@@ -606,14 +607,13 @@ class LingHybridLM(nn.Module):
                  n_valid, attn):
         cfg = self.config
         B, T = tokens.shape
-        embed = self.param("embed", _normal(cfg),
+        embed = self.param("embed", normal(cfg),
                            (cfg.vocab_size, cfg.hidden_size),
                            cfg.param_dtype)
         with jax.named_scope("ds_embed"):
             h = embed.astype(cfg.dtype)[tokens]
             rope = rope_cos_sin(cfg, positions)
-            # a decode row without a request, a chunk's padded tail
-            mask = jnp.arange(T)[None, :] < n_valid[:, None]
+            mask = token_mask(n_valid, T)
         new_cache, counted = {}, []
         for i, kind in enumerate(cfg.layer_types):
             name = f"layers_{i}"
@@ -621,43 +621,23 @@ class LingHybridLM(nn.Module):
                 cfg, kind, bool(cfg.is_dense(i)), name=name)(
                     h, cache[name], positions, page_table, slots, n_valid,
                     rope, mask, attn)
-            counted.append(counters)
+            if counters is not None:
+                counted.append(counters)
         with jax.named_scope("ds_head"):
-            last = jnp.maximum(n_valid - 1, 0)[:, None, None]
-            h = jnp.take_along_axis(h, last, axis=1)[:, 0]
-            h = RMSNorm(cfg, name="final_norm")(h)
-            head = self.param("lm_head", _normal(cfg),
+            h = RMSNorm(cfg, name="final_norm")(last_token(h, n_valid))
+            head = self.param("lm_head", normal(cfg),
                               (cfg.hidden_size, cfg.vocab_size),
                               cfg.param_dtype)
-            logits = jnp.dot(h, head.astype(cfg.dtype),
-                             preferred_element_type=jnp.float32)
+            logits = head_logits(h, head, cfg.dtype)
         with jax.named_scope("ds_sample"):
-            counted = jnp.stack(counted)
-            expert_layers = sum(not cfg.is_dense(i)
-                                for i in range(cfg.num_hidden_layers))
             # the state update visits the rows that hold a request and no
             # other (`ops/pallas/kda.py`'s list of live rows)
             live = (n_valid > 0).sum().astype(jnp.int32)
-            values = [*counted[:, :3].sum(0), counted[:, 3].max(),
-                      jnp.int32(cfg.experts_held[1] * expert_layers),
-                      live, live, *counted[:, 4:].sum(0)]
-        return logits, new_cache, dict(zip(COUNTERS, values))
-
-    # -- the serving engine's protocol (`inference/engine.py`) -------------
-
-    @nn.nowrap
-    def cache_spec(self, *args, **kwargs):
-        return self.config.cache_spec(*args, **kwargs)
-
-    @nn.nowrap
-    def serve_apply(self, params, cache, tokens, positions, page_table,
-                    slots, n_valid, attn_impl="dense", attn_block_k=128,
-                    attn_mesh=None):
-        return self.apply(
-            {"params": params}, tokens, cache, positions, page_table,
-            slots, n_valid,
-            {"impl": attn_impl, "block_k": attn_block_k,
-             "mesh": attn_mesh})
+            counters = summed_counters(
+                COUNTERS, counted, kda_rows_live=live, kda_rows_touched=live,
+                moe_experts_held=jnp.int32(
+                    cfg.experts_held[1] * len(counted)))
+        return logits, new_cache, counters
 
 
 # the matrices that write to the stream (out of a mixer, the attention,
@@ -665,30 +645,7 @@ class LingHybridLM(nn.Module):
 _WRITERS = {"o_proj": 0, "w_out": 0, "w_down": 1}
 
 
-def _centred(path, leaf):
-    """A writer's weights less their mean over its input axis
-    (`models/qwen3_next.py:_centred` says why)."""
-    axis = _WRITERS.get(path[-1].key)
-    if axis is None:
-        return leaf
-    w = leaf.astype(jnp.float32)
-    return (w - w.mean(axis, keepdims=True)).astype(leaf.dtype)
-
-
 def init_ling_hybrid_params(model, rng):
-    """The model's weights from ``rng``, in ``param_dtype`` (the
-    router's bias float32), the writers centred (`_centred`), made on
-    the device in one jitted call (a 2-row toy cache gives the shapes)."""
-    spec = model.config.cache_spec(2, 8, page_size=8)
-
-    def init(key):
-        from deepspeed_tpu.inference.cache import init_kv_cache
-        params = model.init(
-            {"params": key}, jnp.zeros((1, 8), jnp.int32),
-            init_kv_cache(spec), jnp.arange(8, dtype=jnp.int32)[None],
-            jnp.zeros((1, 1), jnp.int32), jnp.zeros((1,), jnp.int32),
-            jnp.full((1,), 8, jnp.int32),
-            {"impl": "dense", "block_k": 8, "mesh": None})["params"]
-        return jax.tree_util.tree_map_with_path(_centred, params)
-
-    return jax.jit(init)(rng)
+    """The model's weights from ``rng``, the writers centred
+    (`blocks.init_served_params`)."""
+    return init_served_params(model, rng, _WRITERS)

@@ -65,10 +65,13 @@ import jax
 import jax.numpy as jnp
 import flax.linen as nn
 
-from deepspeed_tpu.models.granite_hybrid import GatedMLP
-from deepspeed_tpu.models.mla_moe import _bias_init, _held_experts, _normal
-from deepspeed_tpu.models.olmoe import RMSNorm
-from deepspeed_tpu.models.qwen3_next import partial_rotary
+from deepspeed_tpu.models.blocks import (GatedMLP, RMSNorm, ServedLM,
+                                         head_logits, init_served_params,
+                                         last_token, normal, param,
+                                         partial_rotary,
+                                         sigmoid_held_experts,
+                                         summed_counters, token_mask,
+                                         uniform_bias_init)
 
 FULL, WINDOW = "full", "window"
 # what a decode step's span carries of the expert layers, summed over
@@ -170,7 +173,7 @@ class MimoV2Config:
                     f"key heads divide query heads, the rotary part of a "
                     f"head is even, values are no wider than keys: {kind}")
 
-    # the norms' epsilon under the name `models/olmoe.py:RMSNorm` reads
+    # the norms' epsilon under the name `models/blocks.py:RMSNorm` reads
     @property
     def rms_norm_eps(self):
         return self.layernorm_epsilon
@@ -264,11 +267,6 @@ def mimo_v2_tiny(**kw):
     return MimoV2Config(**kw)
 
 
-def _param(mod, name, cfg, shape):
-    return mod.param(name, _normal(cfg), shape,
-                     cfg.param_dtype).astype(cfg.dtype)
-
-
 def _sink_init(cfg):
     """A sink's logit: about the logarithm of a full window's summed
     ``exp(score)``, so that the sink takes a share of the weight that a
@@ -295,9 +293,9 @@ class MimoAttention(nn.Module):
         B, T, C = x.shape
         Hq, H, D, Dv = kind.heads, kind.kv_heads, kind.head_dim, kind.v_dim
         with jax.named_scope("ds_attn_qkv"):
-            q = jnp.dot(x, _param(self, "q_proj", cfg, (C, Hq * D)))
-            k = jnp.dot(x, _param(self, "k_proj", cfg, (C, H * D)))
-            v = jnp.dot(x, _param(self, "v_proj", cfg, (C, H * Dv)))
+            q = jnp.dot(x, param(self, "q_proj", cfg, (C, Hq * D)))
+            k = jnp.dot(x, param(self, "k_proj", cfg, (C, H * D)))
+            v = jnp.dot(x, param(self, "v_proj", cfg, (C, H * Dv)))
             q = partial_rotary(q.reshape(B, T, Hq, D), positions, kind)
             k = partial_rotary(k.reshape(B, T, H, D), positions, kind)
             v = (v.astype(jnp.float32) * cfg.attention_value_scale).astype(
@@ -310,14 +308,14 @@ class MimoAttention(nn.Module):
             walk=True, **attn)
         with jax.named_scope("ds_attn_out"):
             y = jnp.dot(y.reshape(B, T, Hq * Dv),
-                        _param(self, "o_proj", cfg, (Hq * Dv, C)))
+                        param(self, "o_proj", cfg, (Hq * Dv, C)))
         return y, layer_cache
 
 
 class RoutedExperts(nn.Module):
     """The routed experts this chip holds; there is no shared one.
-    Returns ``(y, counters [4])`` (`models/mla_moe.py:COUNTERS`):
-    ``mask`` ``[B, T]`` says which tokens are real."""
+    Returns ``(y, the layer's `blocks.ExpertCounters`)``: ``mask``
+    ``[B, T]`` says which tokens are real."""
     config: MimoV2Config
 
     @nn.compact
@@ -326,14 +324,14 @@ class RoutedExperts(nn.Module):
         B, T, C = x.shape
         E, I = cfg.n_routed_experts, cfg.moe_intermediate_size
         first, held = cfg.experts_held
-        init, pd = _normal(cfg), cfg.param_dtype
+        init, pd = normal(cfg), cfg.param_dtype
         router = self.param("router", init, (C, E), pd)
-        bias = self.param("e_score_correction_bias", _bias_init(cfg), (E,),
+        bias = self.param("e_score_correction_bias", uniform_bias_init(cfg), (E,),
                           jnp.float32)
         w_gate = self.param("w_gate", init, (held, C, I), pd)
         w_up = self.param("w_up", init, (held, C, I), pd)
         w_down = self.param("w_down", init, (held, I, C), pd)
-        y, counters = _held_experts(
+        y, counters = sigmoid_held_experts(
             x.reshape(B * T, C), mask.reshape(B * T), router, bias, w_gate,
             w_up, w_down, top_k=cfg.num_experts_per_tok,
             scaling=float(cfg.routed_scaling_factor or 1.0),
@@ -364,13 +362,13 @@ class MimoV2Layer(nn.Module):
             n = RMSNorm(cfg, name="post_attn_norm")(h)
             if self.dense:
                 y = GatedMLP(cfg, cfg.intermediate_size, name="mlp")(n)
-                counters = jnp.zeros((4,), jnp.int32)
+                counters = None
             else:
                 y, counters = RoutedExperts(cfg, name="experts")(n, mask)
             return h + y, layer_cache, counters
 
 
-class MimoV2LM(nn.Module):
+class MimoV2LM(ServedLM, nn.Module):
     """The decoder with its untied head, through the serving cache.
     Returns ``(logits [B, vocab_size] float32 at each row's last real
     token, the cache, the counters of `COUNTERS`)``."""
@@ -383,13 +381,12 @@ class MimoV2LM(nn.Module):
         from deepspeed_tpu.inference.cache import split_table
         cfg = self.config
         B, T = tokens.shape
-        embed = self.param("embed", _normal(cfg),
+        embed = self.param("embed", normal(cfg),
                            (cfg.vocab_size, cfg.hidden_size),
                            cfg.param_dtype)
         with jax.named_scope("ds_embed"):
             h = embed.astype(cfg.dtype)[tokens]
-            # a decode row without a request, a chunk's padded tail
-            mask = jnp.arange(T)[None, :] < n_valid[:, None]
+            mask = token_mask(n_valid, T)
         # the table's last entries are the row's ring, where there is one
         page_size = next(iter(cache.values()))["k"].shape[-1]
         ring = cfg.sliding_window // page_size + 1 \
@@ -397,44 +394,33 @@ class MimoV2LM(nn.Module):
         with jax.named_scope("ds_embed"):
             tables = dict(zip((FULL, WINDOW),
                               split_table(page_table, ring)))
-        new_cache, counters, dense = {}, 0, 0
+        new_cache, counted = {}, []
         for i, which in enumerate(cfg.layer_kinds):
             name = f"layers_{i}"
             h, new_cache[name], c = MimoV2Layer(
                 cfg, which, bool(cfg.is_dense(i)), name=name)(
                     h, cache[name], positions, tables[which], n_valid, mask,
                     attn)
-            counters = counters + c
-            dense += bool(cfg.is_dense(i))
+            if c is not None:
+                counted.append(c)
         with jax.named_scope("ds_head"):
-            last = jnp.maximum(n_valid - 1, 0)[:, None, None]
-            h = jnp.take_along_axis(h, last, axis=1)[:, 0]
-            h = RMSNorm(cfg, name="final_norm")(h)
-            head = self.param("lm_head", _normal(cfg),
+            h = RMSNorm(cfg, name="final_norm")(last_token(h, n_valid))
+            head = self.param("lm_head", normal(cfg),
                               (cfg.hidden_size, cfg.vocab_size),
                               cfg.param_dtype)
-            logits = jnp.dot(h, head.astype(cfg.dtype),
-                             preferred_element_type=jnp.float32)
-        held = cfg.experts_held[1] * (cfg.num_hidden_layers - dense)
-        return logits, new_cache, dict(zip(
-            COUNTERS, [*counters, jnp.int32(held)]))
-
-    # -- the serving engine's protocol (`inference/engine.py`) -------------
+            logits = head_logits(h, head, cfg.dtype)
+        return logits, new_cache, summed_counters(
+            COUNTERS, counted,
+            moe_experts_held=jnp.int32(cfg.experts_held[1] * len(counted)))
 
     @nn.nowrap
-    def cache_spec(self, *args, **kwargs):
-        return self.config.cache_spec(*args, **kwargs)
-
-    @nn.nowrap
-    def serve_apply(self, params, cache, tokens, positions, page_table,
-                    slots, n_valid, attn_impl="dense", attn_block_k=128,
-                    attn_mesh=None):
+    def serve_args(self, cache, tokens, positions, page_table, slots,
+                   n_valid, attn_impl, attn_block_k, attn_mesh):
         del slots       # pages are the cache: a row's slot owns nothing
         if attn_mesh is not None:
             raise MimoV2Unsupported("a 'model' mesh axis is not built")
-        return self.apply(
-            {"params": params}, tokens, cache, positions, page_table,
-            n_valid, {"impl": attn_impl, "block_k": attn_block_k})
+        return (tokens, cache, positions, page_table, n_valid,
+                {"impl": attn_impl, "block_k": attn_block_k})
 
 
 # the matrices that write to the stream: out of an attention, a dense
@@ -442,36 +428,8 @@ class MimoV2LM(nn.Module):
 _WRITERS = {"o_proj": 0, "w_out": 0, "w_down": 1}
 
 
-def _centred(path, leaf):
-    """A writer's weights less their mean over its input axis
-    (`models/nemotron_h.py:_centred` says why: random weights under
-    SiLU give every token the same mean activation, which an uncentred
-    writer turns into one token-independent vector in the stream, and
-    every token then chooses the same experts)."""
-    axis = _WRITERS.get(path[-1].key)
-    if axis is None:
-        return leaf
-    w = leaf.astype(jnp.float32)
-    return (w - w.mean(axis, keepdims=True)).astype(leaf.dtype)
-
-
 def init_mimo_v2_params(model, rng):
-    """The model's weights from ``rng``, in ``param_dtype`` (the
-    router's bias and the sinks float32), the writers centred
-    (`_centred`), made on the device in one jitted call (a 2-row toy
-    cache gives the shapes)."""
-    cfg = model.config
-    page = max(cfg.sliding_window, 8)
-    spec = cfg.cache_spec(2, page, page_size=page)
-
-    def init(key):
-        from deepspeed_tpu.inference.cache import init_kv_cache
-        params = model.init(
-            {"params": key}, jnp.zeros((1, page), jnp.int32),
-            init_kv_cache(spec), jnp.arange(page, dtype=jnp.int32)[None],
-            jnp.ones((1, spec.table_width), jnp.int32),
-            jnp.full((1,), page, jnp.int32),
-            {"impl": "dense", "block_k": page})["params"]
-        return jax.tree_util.tree_map_with_path(_centred, params)
-
-    return jax.jit(init)(rng)
+    """The model's weights from ``rng``, the writers centred
+    (`blocks.init_served_params`), over a page that holds a window."""
+    return init_served_params(model, rng, _WRITERS,
+                              page=max(model.config.sliding_window, 8))

@@ -70,19 +70,19 @@ only: no loss, no backward.
 """
 
 import dataclasses
-import functools
 import math
 from typing import Any, Tuple
-
-import numpy as np
 
 import jax
 import jax.numpy as jnp
 import flax.linen as nn
 
-from deepspeed_tpu.models.granite_hybrid import GatedMLP
-from deepspeed_tpu.models.olmoe import RMSNorm
-from deepspeed_tpu.moe.dropless import dropless_moe, sigmoid_top_k
+from deepspeed_tpu.models.blocks import (GatedMLP, RMSNorm, ServedLM,
+                                         head_logits, init_served_params,
+                                         last_token, normal, param, rotate,
+                                         sigmoid_held_experts,
+                                         summed_counters, token_mask,
+                                         uniform_bias_init, yarn_inv_freq)
 
 YARN = (("beta_fast", 32), ("beta_slow", 1), ("factor", 64), ("mscale", 1),
         ("mscale_all_dim", 1), ("original_max_position_embeddings", 4096),
@@ -223,29 +223,6 @@ def yarn_mscale(factor, mscale):
     return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
-def yarn_inv_freq(dim, theta, rope_scaling):
-    """The ``dim // 2`` rotary frequencies under YaRN, float64 numpy:
-    ``theta^(-2i/dim)`` where a dimension turns more than ``beta_fast``
-    times over the original context, that over ``factor`` where it
-    turns fewer than ``beta_slow`` times, a linear blend between."""
-    rs = dict(rope_scaling)
-    factor, orig = rs["factor"], rs["original_max_position_embeddings"]
-    extra = theta ** -(np.arange(0, dim, 2, dtype=np.float64) / dim)
-    inter = extra / factor
-
-    def correction_dim(turns):
-        return dim * math.log(orig / (turns * 2 * math.pi)) / \
-            (2 * math.log(theta))
-
-    low = max(math.floor(correction_dim(rs["beta_fast"])), 0)
-    high = min(math.ceil(correction_dim(rs["beta_slow"])), dim - 1)
-    if low == high:
-        high += 0.001
-    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low) /
-                   (high - low), 0.0, 1.0)
-    return inter * ramp + extra * (1.0 - ramp)
-
-
 def yarn_cos_sin(cfg, positions):
     """``cos`` and ``sin`` ``[B, T, rope / 2]`` float32 of the rotary
     angles at ``positions``, times YaRN's factor on the embedding (1
@@ -259,26 +236,7 @@ def yarn_cos_sin(cfg, positions):
     return jnp.cos(ang) * m, jnp.sin(ang) * m
 
 
-def rotate(x, cos, sin):
-    """Rotary embedding of ``x`` ``[..., d]`` (rotate-half convention)
-    by ``cos`` / ``sin`` ``[..., d / 2]``, in float32."""
-    d = x.shape[-1] // 2
-    x32 = x.astype(jnp.float32)
-    x1, x2 = x32[..., :d], x32[..., d:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           axis=-1).astype(x.dtype)
-
-
 # --- modules ----------------------------------------------------------------
-
-def _normal(cfg):
-    return nn.initializers.normal(cfg.initializer_range)
-
-
-def _param(mod, name, cfg, shape):
-    return mod.param(name, _normal(cfg), shape,
-                     cfg.param_dtype).astype(cfg.dtype)
-
 
 class LatentAttention(nn.Module):
     """Causal latent attention through a latent pool (the module
@@ -299,16 +257,16 @@ class LatentAttention(nn.Module):
         absorbed = T == 1       # a decode step; a chunk expands its blocks
         with jax.named_scope("ds_mla_project"):
             c_q = RMSNorm(cfg, name="q_a_norm")(
-                jnp.dot(x, _param(self, "q_a_proj", cfg, (C, rq))))
-            q = jnp.dot(c_q, _param(self, "q_b_proj", cfg,
+                jnp.dot(x, param(self, "q_a_proj", cfg, (C, rq))))
+            q = jnp.dot(c_q, param(self, "q_b_proj", cfg,
                                     (rq, H * (dn + dr))))
             q = q.reshape(B, T, H, dn + dr)
-            ckv = jnp.dot(x, _param(self, "kv_a_proj", cfg, (C, rkv + dr)))
+            ckv = jnp.dot(x, param(self, "kv_a_proj", cfg, (C, rkv + dr)))
             c_kv = RMSNorm(cfg, name="kv_a_norm")(ckv[..., :rkv])
             q_rope = rotate(q[..., dn:], cos[:, :, None], sin[:, :, None])
             k_rope = rotate(ckv[..., rkv:], cos, sin)
             latent = jnp.concatenate([c_kv, k_rope], -1)[:, :, None]
-            w_ukv = _param(self, "kv_b_proj", cfg,
+            w_ukv = param(self, "kv_b_proj", cfg,
                            (rkv, H * (dn + dv))).reshape(rkv, H, dn + dv)
             expand = None
             if absorbed:
@@ -336,39 +294,14 @@ class LatentAttention(nn.Module):
             if absorbed:
                 y = jnp.einsum("bthc,chv->bthv", y, w_ukv[..., dn:])
             y = jnp.dot(y.reshape(B, T, H * dv),
-                        _param(self, "o_proj", cfg, (H * dv, C)))
+                        param(self, "o_proj", cfg, (H * dv, C)))
         return y, layer_cache
-
-
-# jitted, so that the expert layers share one trace of the routing and
-# of the three grouped matmuls (`PERF.md`, PR 30: every traced equation
-# of a kernel body costs set-up time in a process that holds an engine)
-@functools.partial(jax.jit, static_argnames=(
-    "top_k", "scaling", "renormalise", "first_expert"))
-def _held_experts(x, mask, router, bias, w_gate, w_up, w_down, *, top_k,
-                  scaling, renormalise, first_expert):
-    y, stats = dropless_moe(
-        x, router, w_gate, w_up, w_down, top_k,
-        route=sigmoid_top_k(bias, scaling, renormalise),
-        first_expert=first_expert, token_mask=mask)
-    sizes = stats["tokens_per_expert"]
-    counters = jnp.stack([mask.sum().astype(jnp.int32) * top_k, sizes.sum(),
-                          (sizes > 0).sum().astype(jnp.int32),
-                          stats["rows_visited"]])
-    return y, counters
-
-
-def _bias_init(cfg):
-    def init(key, shape, dtype):
-        r = cfg.router_bias_range
-        return jax.random.uniform(key, shape, dtype, -r, r)
-    return init
 
 
 class HeldExperts(nn.Module):
     """The routed experts this chip holds, and the shared expert.
-    Returns ``(y, counters [4])`` (`COUNTERS`): ``mask`` ``[B, T]`` says
-    which tokens are real."""
+    Returns ``(y, the layer's `blocks.ExpertCounters`)``: ``mask``
+    ``[B, T]`` says which tokens are real."""
     config: MlaMoeConfig
 
     @nn.compact
@@ -377,14 +310,14 @@ class HeldExperts(nn.Module):
         B, T, C = x.shape
         E, I = cfg.n_routed_experts, cfg.moe_intermediate_size
         first, held = cfg.experts_held
-        init, pd = _normal(cfg), cfg.param_dtype
+        init, pd = normal(cfg), cfg.param_dtype
         router = self.param("router", init, (C, E), pd)
-        bias = self.param("e_score_correction_bias", _bias_init(cfg), (E,),
+        bias = self.param("e_score_correction_bias", uniform_bias_init(cfg), (E,),
                           jnp.float32)
         w_gate = self.param("w_gate", init, (held, C, I), pd)
         w_up = self.param("w_up", init, (held, C, I), pd)
         w_down = self.param("w_down", init, (held, I, C), pd)
-        y, counters = _held_experts(
+        y, counters = sigmoid_held_experts(
             x.reshape(B * T, C), mask.reshape(B * T), router, bias, w_gate,
             w_up, w_down, top_k=cfg.num_experts_per_tok,
             scaling=cfg.routed_scaling_factor,
@@ -417,13 +350,13 @@ class MlaMoeLayer(nn.Module):
             n = RMSNorm(cfg, name="post_attn_norm")(h)
             if self.dense:
                 y = GatedMLP(cfg, cfg.intermediate_size, name="mlp")(n)
-                counters = jnp.zeros((len(COUNTERS),), jnp.int32)
+                counters = None
             else:
                 y, counters = HeldExperts(cfg, name="experts")(n, mask)
             return h + y, layer_cache, counters
 
 
-class MlaMoeLM(nn.Module):
+class MlaMoeLM(ServedLM, nn.Module):
     """The decoder with its untied head, through the serving cache.
     Returns ``(logits [B, vocab_size] float32 at each row's last real
     token, the cache, the expert layers' counters summed)``."""
@@ -435,60 +368,38 @@ class MlaMoeLM(nn.Module):
     def __call__(self, tokens, cache, positions, page_table, n_valid, attn):
         cfg = self.config
         B, T = tokens.shape
-        embed = self.param("embed", _normal(cfg),
+        embed = self.param("embed", normal(cfg),
                            (cfg.vocab_size, cfg.hidden_size),
                            cfg.param_dtype)
         with jax.named_scope("ds_embed"):
             h = embed.astype(cfg.dtype)[tokens]
             rope = yarn_cos_sin(cfg, positions)
-            # a decode row without a request, a chunk's padded tail
-            mask = jnp.arange(T)[None, :] < n_valid[:, None]
-        new_cache, counters = {}, 0
+            mask = token_mask(n_valid, T)
+        new_cache, counted = {}, []
         for i, name in enumerate(cfg.layer_names()):
             h, new_cache[name], c = MlaMoeLayer(
                 cfg, bool(cfg.is_dense(i)), name=name)(
                     h, cache[name], positions, page_table, rope, mask, attn)
-            counters = counters + c
+            if c is not None:
+                counted.append(c)
         with jax.named_scope("ds_head"):
-            last = jnp.maximum(n_valid - 1, 0)[:, None, None]
-            h = jnp.take_along_axis(h, last, axis=1)[:, 0]
-            h = RMSNorm(cfg, name="final_norm")(h)
-            head = self.param("lm_head", _normal(cfg),
+            h = RMSNorm(cfg, name="final_norm")(last_token(h, n_valid))
+            head = self.param("lm_head", normal(cfg),
                               (cfg.hidden_size, cfg.vocab_size),
                               cfg.param_dtype)
-            logits = jnp.dot(h, head.astype(cfg.dtype),
-                             preferred_element_type=jnp.float32)
-        return logits, new_cache, dict(zip(COUNTERS, counters))
-
-    # -- the serving engine's protocol (`inference/engine.py`) -------------
+            logits = head_logits(h, head, cfg.dtype)
+        return logits, new_cache, summed_counters(COUNTERS, counted)
 
     @nn.nowrap
-    def cache_spec(self, *args, **kwargs):
-        return self.config.cache_spec(*args, **kwargs)
-
-    @nn.nowrap
-    def serve_apply(self, params, cache, tokens, positions, page_table,
-                    slots, n_valid, attn_impl="dense", attn_block_k=128,
-                    attn_mesh=None):
+    def serve_args(self, cache, tokens, positions, page_table, slots,
+                   n_valid, attn_impl, attn_block_k, attn_mesh):
         del slots       # pages are the cache: a row's slot owns nothing
-        return self.apply(
-            {"params": params}, tokens, cache, positions, page_table,
-            n_valid, {"impl": attn_impl, "block_k": attn_block_k,
-                      "mesh": attn_mesh})
+        return (tokens, cache, positions, page_table, n_valid,
+                {"impl": attn_impl, "block_k": attn_block_k,
+                 "mesh": attn_mesh})
 
 
 def init_mla_moe_params(model, rng):
-    """The model's weights from ``rng``, in ``param_dtype`` (the
-    router's bias float32), made on the device in one jitted call (a
-    2-row toy cache gives the shapes)."""
-    spec = model.config.cache_spec(2, 8, page_size=8)
-
-    def init(key):
-        from deepspeed_tpu.inference.cache import init_kv_cache
-        return model.init(
-            {"params": key}, jnp.zeros((1, 8), jnp.int32),
-            init_kv_cache(spec), jnp.arange(8, dtype=jnp.int32)[None],
-            jnp.zeros((1, 1), jnp.int32), jnp.full((1,), 8, jnp.int32),
-            {"impl": "dense", "block_k": 8, "mesh": None})["params"]
-
-    return jax.jit(init)(rng)
+    """The model's weights from ``rng`` (`blocks.init_served_params`);
+    no writer is centred."""
+    return init_served_params(model, rng, {})

@@ -62,10 +62,12 @@ import jax
 import jax.numpy as jnp
 import flax.linen as nn
 
-from deepspeed_tpu.models.granite_hybrid import (GroupedQueryAttention,
-                                                 Mamba2Mixer, _normal)
-from deepspeed_tpu.models.mla_moe import _bias_init
-from deepspeed_tpu.models.olmoe import RMSNorm
+from deepspeed_tpu.models.blocks import (GroupedQueryAttention, Mamba2Mixer,
+                                         RMSNorm, ServedLM, head_logits,
+                                         init_served_params, last_token,
+                                         expert_counters, normal,
+                                         summed_counters, token_mask,
+                                         uniform_bias_init)
 from deepspeed_tpu.moe.dropless import dropless_moe, sigmoid_top_k
 
 MIXER, ATTENTION, EXPERTS = "M", "*", "E"
@@ -251,18 +253,14 @@ def _held_experts(x, latent, mask, router, bias, w_up, w_down, *, top_k,
         x, router, None, w_up, w_down, top_k,
         route=sigmoid_top_k(bias, scaling, renormalise),
         first_expert=first_expert, token_mask=mask, rows=latent)
-    sizes = stats["tokens_per_expert"]
-    counters = jnp.stack([mask.sum().astype(jnp.int32) * top_k, sizes.sum(),
-                          (sizes > 0).sum().astype(jnp.int32), sizes.max(),
-                          stats["rows_visited"]])
-    return y, counters
+    return y, expert_counters(mask, top_k, stats)
 
 
 class LatentExperts(nn.Module):
     """The routed experts this chip holds, in their latent, and the
-    shared expert at full width. Returns ``(y, counters [5])`` (the
-    first four of `COUNTERS` and its last, this layer's); ``mask``
-    ``[B, T]`` says which tokens are real."""
+    shared expert at full width. Returns ``(y, the layer's
+    `blocks.ExpertCounters`)``; ``mask`` ``[B, T]`` says which tokens are
+    real."""
     config: NemotronHConfig
 
     @nn.compact
@@ -273,9 +271,9 @@ class LatentExperts(nn.Module):
             cfg.moe_latent_size
         S = cfg.moe_shared_expert_intermediate_size * cfg.n_shared_experts
         first, held = cfg.experts_held
-        init, pd, dt = _normal(cfg), cfg.param_dtype, cfg.dtype
+        init, pd, dt = normal(cfg), cfg.param_dtype, cfg.dtype
         router = self.param("router", init, (C, E), pd)
-        bias = self.param("e_score_correction_bias", _bias_init(cfg), (E,),
+        bias = self.param("e_score_correction_bias", uniform_bias_init(cfg), (E,),
                           jnp.float32)
         w_up = self.param("w_up", init, (held, L, I), pd)
         w_down = self.param("w_down", init, (held, I, L), pd)
@@ -330,7 +328,7 @@ class NemotronHBlock(nn.Module):
             return h + y, layer_cache, counters
 
 
-class NemotronHLM(nn.Module):
+class NemotronHLM(ServedLM, nn.Module):
     """The decoder with its untied head, through the serving cache.
     Returns ``(logits [B, vocab_size] float32 at each row's last real
     token, the cache, the expert layers' counters)``."""
@@ -343,13 +341,12 @@ class NemotronHLM(nn.Module):
                  n_valid, attn):
         cfg = self.config
         B, T = tokens.shape
-        embed = self.param("embed", _normal(cfg),
+        embed = self.param("embed", normal(cfg),
                            (cfg.vocab_size, cfg.hidden_size),
                            cfg.param_dtype)
         with jax.named_scope("ds_embed"):
             h = embed.astype(cfg.dtype)[tokens]
-            # a decode row without a request, a chunk's padded tail
-            mask = jnp.arange(T)[None, :] < n_valid[:, None]
+            mask = token_mask(n_valid, T)
         new_cache, counted = {}, []
         for i, kind in enumerate(cfg.hybrid_override_pattern):
             name = f"layers_{i}"
@@ -361,38 +358,16 @@ class NemotronHLM(nn.Module):
             if counters is not None:
                 counted.append(counters)
         with jax.named_scope("ds_head"):
-            last = jnp.maximum(n_valid - 1, 0)[:, None, None]
-            h = jnp.take_along_axis(h, last, axis=1)[:, 0]
-            h = RMSNorm(cfg, name="final_norm")(h)
-            head = self.param("lm_head", _normal(cfg),
+            h = RMSNorm(cfg, name="final_norm")(last_token(h, n_valid))
+            head = self.param("lm_head", normal(cfg),
                               (cfg.hidden_size, cfg.vocab_size),
                               cfg.param_dtype)
-            logits = jnp.dot(h, head.astype(cfg.dtype),
-                             preferred_element_type=jnp.float32)
+            logits = head_logits(h, head, cfg.dtype)
         with jax.named_scope("ds_sample"):
-            counted = jnp.stack(counted) if counted else \
-                jnp.zeros((1, 5), jnp.int32)
-            values = [*counted[:, :3].sum(0), counted[:, 3].max(),
-                      jnp.int32(cfg.experts_held[1] *
-                                len(cfg.names(EXPERTS))),
-                      counted[:, 4].sum()]
-        return logits, new_cache, dict(zip(COUNTERS, values))
-
-    # -- the serving engine's protocol (`inference/engine.py`) -------------
-
-    @nn.nowrap
-    def cache_spec(self, *args, **kwargs):
-        return self.config.cache_spec(*args, **kwargs)
-
-    @nn.nowrap
-    def serve_apply(self, params, cache, tokens, positions, page_table,
-                    slots, n_valid, attn_impl="dense", attn_block_k=128,
-                    attn_mesh=None):
-        return self.apply(
-            {"params": params}, tokens, cache, positions, page_table,
-            slots, n_valid,
-            {"impl": attn_impl, "block_k": attn_block_k,
-             "mesh": attn_mesh})
+            counters = summed_counters(
+                COUNTERS, counted, moe_experts_held=jnp.int32(
+                    cfg.experts_held[1] * len(cfg.names(EXPERTS))))
+        return logits, new_cache, counters
 
 
 # the matrices that write to the stream (out of a mixer, the attention,
@@ -401,39 +376,7 @@ _WRITERS = {"out_proj": 0, "o_proj": 0, "latent_up": 0, "shared_down": 0,
             "w_down": 1}
 
 
-def _centred(path, leaf):
-    """A writer's weights less their mean over its input axis, so that
-    each output's weights sum to zero. Random weights give every token
-    the same positive mean activation (``relu^2``, ``silu``), which an
-    uncentred writer turns into one token-independent vector in the
-    stream; every block adds to it and reads it back through its norm
-    (at the published widths, eleven blocks: 78 % of the stream's
-    energy, and every token then chooses the same experts). A trained
-    model's router bias balances its experts' load; random weights have
-    had no such training, and this is what stands in for it
-    (`configs/nemotron-3-super-120b-a12b.json`, ``centred_why``)."""
-    axis = _WRITERS.get(path[-1].key)
-    if axis is None:
-        return leaf
-    w = leaf.astype(jnp.float32)
-    return (w - w.mean(axis, keepdims=True)).astype(leaf.dtype)
-
-
 def init_nemotron_h_params(model, rng):
-    """The model's weights from ``rng``, in ``param_dtype`` (the
-    router's bias float32), the writers centred (`_centred`), made on
-    the device in one jitted call (a 2-row toy cache gives the
-    shapes)."""
-    spec = model.config.cache_spec(2, 8, page_size=8)
-
-    def init(key):
-        from deepspeed_tpu.inference.cache import init_kv_cache
-        params = model.init(
-            {"params": key}, jnp.zeros((1, 8), jnp.int32),
-            init_kv_cache(spec), jnp.arange(8, dtype=jnp.int32)[None],
-            jnp.zeros((1, 1), jnp.int32), jnp.zeros((1,), jnp.int32),
-            jnp.full((1,), 8, jnp.int32),
-            {"impl": "dense", "block_k": 8, "mesh": None})["params"]
-        return jax.tree_util.tree_map_with_path(_centred, params)
-
-    return jax.jit(init)(rng)
+    """The model's weights from ``rng``, the writers centred
+    (`blocks.init_served_params`)."""
+    return init_served_params(model, rng, _WRITERS)

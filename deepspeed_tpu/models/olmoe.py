@@ -23,6 +23,8 @@ import jax.numpy as jnp
 import flax.linen as nn
 from jax.sharding import PartitionSpec as P
 
+from deepspeed_tpu.models.blocks import RMSNorm, normal
+# the training losses are GPT-2's, as `models/bert.py`'s are
 from deepspeed_tpu.models.gpt2 import cross_entropy_loss, next_token_labels
 from deepspeed_tpu.moe.dropless import dropless_moe
 
@@ -68,25 +70,6 @@ def olmoe_tiny(**kw):
     return OlmoeConfig(**kw)
 
 
-def _normal(cfg):
-    return nn.initializers.normal(cfg.initializer_range)
-
-
-class RMSNorm(nn.Module):
-    """``x / sqrt(mean(x^2) + eps) * weight``, statistics in float32."""
-    config: OlmoeConfig
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.config
-        w = self.param("weight", nn.initializers.ones, (x.shape[-1],),
-                       cfg.param_dtype)
-        x32 = x.astype(jnp.float32)
-        x32 = x32 * jax.lax.rsqrt(
-            jnp.mean(x32 * x32, axis=-1, keepdims=True) + cfg.rms_norm_eps)
-        return (x32 * w.astype(jnp.float32)).astype(cfg.dtype)
-
-
 def rotary(x, theta):
     """Rotary embedding of ``x`` ``[B, T, H, D]`` at positions 0..T-1,
     the rotate-half convention, angles in float32."""
@@ -114,7 +97,7 @@ class RotaryAttention(nn.Module):
         H = cfg.num_attention_heads
 
         def proj(name, y):
-            w = self.param(name, _normal(cfg), (C, C), cfg.param_dtype)
+            w = self.param(name, normal(cfg), (C, C), cfg.param_dtype)
             return jnp.dot(y, w.astype(cfg.dtype))
 
         with jax.named_scope("ds_attn_qkv"):
@@ -146,7 +129,7 @@ class SparseExperts(nn.Module):
         cfg = self.config
         B, T, C = x.shape
         E, I = cfg.num_experts, cfg.intermediate_size
-        init = _normal(cfg)
+        init = normal(cfg)
         router = self.param("router", init, (C, E), cfg.param_dtype)
         w_gate = self.param("w_gate", init, (E, C, I), cfg.param_dtype)
         w_up = self.param("w_up", init, (E, C, I), cfg.param_dtype)
@@ -185,7 +168,7 @@ class OlmoeLM(nn.Module):
     @nn.compact
     def __call__(self, input_ids):
         cfg = self.config
-        init = _normal(cfg)
+        init = normal(cfg)
         embed = self.param("embed", init, (cfg.vocab_size, cfg.hidden_size),
                            cfg.param_dtype)
         with jax.named_scope("ds_embed"):

@@ -77,9 +77,13 @@ import jax
 import jax.numpy as jnp
 import flax.linen as nn
 
-from deepspeed_tpu.models.granite_hybrid import (_a_log_init, _conv_init,
-                                                 _dt_bias_init, _normal)
-from deepspeed_tpu.models.mla_moe import rotate
+from deepspeed_tpu.models.blocks import (ServedLM, a_log_init, conv_init,
+                                         dt_bias_init, expert_counters,
+                                         head_logits,
+                                         init_served_params, l2_normalised,
+                                         last_token, normal, param,
+                                         partial_rotary, summed_counters,
+                                         token_mask)
 from deepspeed_tpu.moe.dropless import dropless_moe, softmax_top_k_renorm
 from deepspeed_tpu.ops import gated_delta, ssm
 
@@ -239,11 +243,6 @@ def qwen3_next_tiny(**kw):
     return Qwen3NextConfig(**kw)
 
 
-def _param(mod, name, cfg, shape):
-    return mod.param(name, _normal(cfg), shape,
-                     cfg.param_dtype).astype(cfg.dtype)
-
-
 def _norm_weight(mod, name, cfg, width):
     """A zero-centred norm's ``w`` (the norm multiplies by ``1 + w``):
     drawn, so that ``1 + w`` differs from 1 and from ``w``."""
@@ -269,30 +268,13 @@ class ZeroCentredRMSNorm(nn.Module):
         return zero_centred_norm(x, w, cfg.rms_norm_eps).astype(cfg.dtype)
 
 
-def partial_rotary(x, positions, cfg):
-    """Rotary embedding (rotate-half) of the first ``rotary_dim``
-    entries of each head of ``x`` ``[B, T, H, D]`` at ``positions``
-    ``[B, T]``; the rest pass. Angles in float32."""
-    r = cfg.rotary_dim
-    inv = 1.0 / cfg.rope_theta ** (
-        jnp.arange(0, r, 2, dtype=jnp.float32) / r)
-    ang = positions.astype(jnp.float32)[..., None] * inv     # [B, T, r/2]
-    cos, sin = jnp.cos(ang)[:, :, None], jnp.sin(ang)[:, :, None]
-    return jnp.concatenate([rotate(x[..., :r], cos, sin), x[..., r:]], -1)
-
-
-def _l2_normalised(x, eps=1e-6):
-    x32 = x.astype(jnp.float32)
-    return x32 * jax.lax.rsqrt(jnp.sum(x32 * x32, -1, keepdims=True) + eps)
-
-
 def _in_proj_ba_init(cfg):
     """The ``b`` columns at ``initializer_range`` (``beta = sigmoid(b)``
     then covers about (0.1, 0.9)); the ``a`` columns small enough that
     the projection moves ``softplus(a + dt_bias)`` by about ``e^(+-0.5)``
-    (as `models/granite_hybrid.py:_in_proj_init`'s ``dt`` columns, and
+    (as `models/blocks.py:_in_proj_init`'s ``dt`` columns, and
     for its reason: drawn wider, most tokens wipe the state)."""
-    wide = _normal(cfg)
+    wide = normal(cfg)
     a_std = 0.5 / math.sqrt(cfg.hidden_size)
 
     def init(key, shape, dtype):
@@ -308,7 +290,7 @@ def _in_proj_ba_init(cfg):
 class GatedDeltaNet(nn.Module):
     """The delta-rule mixer through its slot's recurrent leaves (``gdn``
     ``[rows, Hv, K, V]`` float32, ``conv`` ``[taps - 1, rows, channels]``).
-    Two shapes, as `models/granite_hybrid.py:Mamba2Mixer`'s: a prefill
+    Two shapes, as `models/blocks.py:Mamba2Mixer`'s: a prefill
     chunk (one row, ``n_valid`` of ``T`` tokens real: the slot's leaves
     are read, zeros where the chunk starts the prompt; the padded tail's
     ``g`` and ``beta`` are zeroed; the state after the last real token
@@ -325,17 +307,17 @@ class GatedDeltaNet(nn.Module):
         taps, d_k, d_v = cfg.linear_conv_kernel_dim, cfg.key_dim, \
             cfg.value_dim
         pd = cfg.param_dtype
-        qkvz = jnp.dot(x, _param(self, "in_proj_qkvz", cfg,
+        qkvz = jnp.dot(x, param(self, "in_proj_qkvz", cfg,
                                  (C, cfg.conv_dim + d_v)))
         ba = jnp.dot(x, self.param(
             "in_proj_ba", _in_proj_ba_init(cfg), (C, 2 * Hv),
             pd).astype(cfg.dtype))
         qkv, z = qkvz[..., :cfg.conv_dim], qkvz[..., cfg.conv_dim:]
-        conv_w = self.param("conv_weight", _conv_init(taps),
+        conv_w = self.param("conv_weight", conv_init(taps),
                             (taps, cfg.conv_dim), pd)
         no_bias = jnp.zeros((cfg.conv_dim,), jnp.float32)
-        dt_bias = self.param("dt_bias", _dt_bias_init, (Hv,), pd)
-        A = jnp.exp(self.param("A_log", _a_log_init, (Hv,),
+        dt_bias = self.param("dt_bias", dt_bias_init, (Hv,), pd)
+        A = jnp.exp(self.param("A_log", a_log_init, (Hv,),
                                pd).astype(jnp.float32))
         ba = ba.astype(jnp.float32)
         beta = jax.nn.sigmoid(ba[..., :Hv])
@@ -347,8 +329,8 @@ class GatedDeltaNet(nn.Module):
             recurrence takes them: ``q``, ``k`` ``[rows, Hk, K]`` (unit
             length, ``q`` scaled; a key head serves ``Hv / Hk`` value
             heads), ``v`` ``[rows, Hv, V]``."""
-            q = _l2_normalised(u[:, :d_k].reshape(-1, Hk, K)) * K ** -0.5
-            k = _l2_normalised(u[:, d_k:2 * d_k].reshape(-1, Hk, K))
+            q = l2_normalised(u[:, :d_k].reshape(-1, Hk, K)) * K ** -0.5
+            k = l2_normalised(u[:, d_k:2 * d_k].reshape(-1, Hk, K))
             return q.astype(cfg.dtype), k.astype(cfg.dtype), \
                 u[:, 2 * d_k:].reshape(-1, Hv, V)
 
@@ -397,7 +379,7 @@ class GatedDeltaNet(nn.Module):
         y = o * w.astype(jnp.float32) * jax.nn.silu(
             z.astype(jnp.float32).reshape(B, T, Hv, V))
         y = y.reshape(B, T, d_v).astype(cfg.dtype)
-        y = jnp.dot(y, _param(self, "out_proj", cfg, (d_v, C)))
+        y = jnp.dot(y, param(self, "out_proj", cfg, (d_v, C)))
         return y, {"gdn": state, "conv": window}
 
 
@@ -415,11 +397,11 @@ class GatedAttention(nn.Module):
         Hq, Hkv, D = cfg.num_attention_heads, cfg.num_key_value_heads, \
             cfg.head_dim
         with jax.named_scope("ds_attn_qkv"):
-            qg = jnp.dot(x, _param(self, "q_proj", cfg, (C, Hq * 2 * D)))
+            qg = jnp.dot(x, param(self, "q_proj", cfg, (C, Hq * 2 * D)))
             qg = qg.reshape(B, T, Hq, 2 * D)
             q, gate = qg[..., :D], qg[..., D:]
-            k = jnp.dot(x, _param(self, "k_proj", cfg, (C, Hkv * D)))
-            v = jnp.dot(x, _param(self, "v_proj", cfg, (C, Hkv * D)))
+            k = jnp.dot(x, param(self, "k_proj", cfg, (C, Hkv * D)))
+            v = jnp.dot(x, param(self, "v_proj", cfg, (C, Hkv * D)))
             q = zero_centred_norm(q, _norm_weight(self, "q_norm", cfg, D),
                                   cfg.rms_norm_eps)
             k = zero_centred_norm(k.reshape(B, T, Hkv, D),
@@ -436,7 +418,7 @@ class GatedAttention(nn.Module):
                 gate.astype(jnp.float32))).astype(cfg.dtype)
         with jax.named_scope("ds_attn_out"):
             y = jnp.dot(y.reshape(B, T, Hq * D),
-                        _param(self, "o_proj", cfg, (Hq * D, C)))
+                        param(self, "o_proj", cfg, (Hq * D, C)))
         return y, layer_cache
 
 
@@ -448,17 +430,13 @@ def _held_experts(x, mask, router, w_gate, w_up, w_down, *, top_k,
     y, stats = dropless_moe(
         x, router, w_gate, w_up, w_down, top_k, route=softmax_top_k_renorm,
         first_expert=first_expert, token_mask=mask)
-    sizes = stats["tokens_per_expert"]
-    counters = jnp.stack([mask.sum().astype(jnp.int32) * top_k, sizes.sum(),
-                          (sizes > 0).sum().astype(jnp.int32), sizes.max(),
-                          stats["rows_visited"]])
-    return y, counters
+    return y, expert_counters(mask, top_k, stats)
 
 
 class SparseExperts(nn.Module):
     """The routed experts this chip holds and the gated shared expert.
-    Returns ``(y, counters [5])`` (the first four of `COUNTERS` and its
-    last, this layer's); ``mask`` ``[B, T]`` says which tokens are real."""
+    Returns ``(y, the layer's `blocks.ExpertCounters`)``; ``mask`` ``[B,
+    T]`` says which tokens are real."""
     config: Qwen3NextConfig
 
     @nn.compact
@@ -468,7 +446,7 @@ class SparseExperts(nn.Module):
         E, I, S = cfg.num_experts, cfg.moe_intermediate_size, \
             cfg.shared_expert_intermediate_size
         first, held = cfg.experts_held
-        init, pd = _normal(cfg), cfg.param_dtype
+        init, pd = normal(cfg), cfg.param_dtype
         router = self.param("router", init, (C, E), pd)
         w_gate = self.param("w_gate", init, (held, C, I), pd)
         w_up = self.param("w_up", init, (held, C, I), pd)
@@ -478,12 +456,12 @@ class SparseExperts(nn.Module):
             w_down, top_k=cfg.num_experts_per_tok, first_expert=first)
         with jax.named_scope("ds_moe_shared"):
             hidden = jax.nn.silu(
-                jnp.dot(x, _param(self, "shared_gate", cfg, (C, S)))) * \
-                jnp.dot(x, _param(self, "shared_up", cfg, (C, S)))
-            shared = jnp.dot(hidden, _param(self, "shared_down", cfg,
+                jnp.dot(x, param(self, "shared_gate", cfg, (C, S)))) * \
+                jnp.dot(x, param(self, "shared_up", cfg, (C, S)))
+            shared = jnp.dot(hidden, param(self, "shared_down", cfg,
                                             (S, C)))
             opened = jax.nn.sigmoid(jnp.dot(
-                x, _param(self, "shared_expert_gate", cfg, (C, 1)),
+                x, param(self, "shared_expert_gate", cfg, (C, 1)),
                 preferred_element_type=jnp.float32))
             shared = (opened * shared.astype(jnp.float32)).astype(cfg.dtype)
         return y.reshape(B, T, C) + shared, counters
@@ -520,7 +498,7 @@ class Qwen3NextBlock(nn.Module):
             return h + y, layer_cache, counters
 
 
-class Qwen3NextLM(nn.Module):
+class Qwen3NextLM(ServedLM, nn.Module):
     """The decoder with its untied head, through the serving cache.
     Returns ``(logits [B, vocab_size] float32 at each row's last real
     token, the cache, the counters of `COUNTERS`)``."""
@@ -533,13 +511,12 @@ class Qwen3NextLM(nn.Module):
                  n_valid, attn):
         cfg = self.config
         B, T = tokens.shape
-        embed = self.param("embed", _normal(cfg),
+        embed = self.param("embed", normal(cfg),
                            (cfg.vocab_size, cfg.hidden_size),
                            cfg.param_dtype)
         with jax.named_scope("ds_embed"):
             h = embed.astype(cfg.dtype)[tokens]
-            # a decode row without a request, a chunk's padded tail
-            mask = jnp.arange(T)[None, :] < n_valid[:, None]
+            mask = token_mask(n_valid, T)
         new_cache, counted = {}, []
         for i, kind in enumerate(cfg.layer_types):
             name = f"layers_{i}"
@@ -549,39 +526,21 @@ class Qwen3NextLM(nn.Module):
                     mask, attn)
             counted.append(counters)
         with jax.named_scope("ds_head"):
-            last = jnp.maximum(n_valid - 1, 0)[:, None, None]
-            h = jnp.take_along_axis(h, last, axis=1)[:, 0]
-            h = ZeroCentredRMSNorm(cfg, name="final_norm")(h)
-            head = self.param("lm_head", _normal(cfg),
+            h = ZeroCentredRMSNorm(cfg, name="final_norm")(
+                last_token(h, n_valid))
+            head = self.param("lm_head", normal(cfg),
                               (cfg.hidden_size, cfg.vocab_size),
                               cfg.param_dtype)
-            logits = jnp.dot(h, head.astype(cfg.dtype),
-                             preferred_element_type=jnp.float32)
+            logits = head_logits(h, head, cfg.dtype)
         with jax.named_scope("ds_sample"):
-            counted = jnp.stack(counted)
             # the state update visits the rows that hold a request and no
             # other (`ops/pallas/gated_delta.py`'s list of live rows)
             live = (n_valid > 0).sum().astype(jnp.int32)
-            values = [*counted[:, :3].sum(0), counted[:, 3].max(),
-                      jnp.int32(cfg.experts_held[1] * len(cfg.layer_types)),
-                      live, live, counted[:, 4].sum()]
-        return logits, new_cache, dict(zip(COUNTERS, values))
-
-    # -- the serving engine's protocol (`inference/engine.py`) -------------
-
-    @nn.nowrap
-    def cache_spec(self, *args, **kwargs):
-        return self.config.cache_spec(*args, **kwargs)
-
-    @nn.nowrap
-    def serve_apply(self, params, cache, tokens, positions, page_table,
-                    slots, n_valid, attn_impl="dense", attn_block_k=128,
-                    attn_mesh=None):
-        return self.apply(
-            {"params": params}, tokens, cache, positions, page_table,
-            slots, n_valid,
-            {"impl": attn_impl, "block_k": attn_block_k,
-             "mesh": attn_mesh})
+            counters = summed_counters(
+                COUNTERS, counted, gdn_rows_live=live, gdn_rows_touched=live,
+                moe_experts_held=jnp.int32(
+                    cfg.experts_held[1] * len(cfg.layer_types)))
+        return logits, new_cache, counters
 
 
 # the matrices that write to the stream (out of a mixer, the attention,
@@ -589,33 +548,7 @@ class Qwen3NextLM(nn.Module):
 _WRITERS = {"out_proj": 0, "o_proj": 0, "shared_down": 0, "w_down": 1}
 
 
-def _centred(path, leaf):
-    """A writer's weights less their mean over its input axis
-    (`models/nemotron_h.py:_centred` says why: random weights under
-    SiLU give every token the same mean activation, which an uncentred
-    writer turns into one token-independent vector in the stream, and
-    every token then chooses the same experts)."""
-    axis = _WRITERS.get(path[-1].key)
-    if axis is None:
-        return leaf
-    w = leaf.astype(jnp.float32)
-    return (w - w.mean(axis, keepdims=True)).astype(leaf.dtype)
-
-
 def init_qwen3_next_params(model, rng):
-    """The model's weights from ``rng``, in ``param_dtype``, the writers
-    centred (`_centred`), made on the device in one jitted call (a
-    2-row toy cache gives the shapes)."""
-    spec = model.config.cache_spec(2, 8, page_size=8)
-
-    def init(key):
-        from deepspeed_tpu.inference.cache import init_kv_cache
-        params = model.init(
-            {"params": key}, jnp.zeros((1, 8), jnp.int32),
-            init_kv_cache(spec), jnp.arange(8, dtype=jnp.int32)[None],
-            jnp.zeros((1, 1), jnp.int32), jnp.zeros((1,), jnp.int32),
-            jnp.full((1,), 8, jnp.int32),
-            {"impl": "dense", "block_k": 8, "mesh": None})["params"]
-        return jax.tree_util.tree_map_with_path(_centred, params)
-
-    return jax.jit(init)(rng)
+    """The model's weights from ``rng``, the writers centred
+    (`blocks.init_served_params`)."""
+    return init_served_params(model, rng, _WRITERS)

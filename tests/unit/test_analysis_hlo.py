@@ -8,6 +8,7 @@ dtypes, ``backend_config`` trip counts, infeed/outfeed).
 """
 
 import numpy as np
+import pytest
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
@@ -23,6 +24,9 @@ from deepspeed_tpu.analysis.hlo import (
     while_loops,
 )
 from jax import shard_map
+
+# reads compiled programs: the compiler's normal pipeline (tests/conftest.py)
+pytestmark = pytest.mark.full_compile
 
 SCAN_TRIPS = 6
 SCAN_WIDTH = 4

@@ -22,6 +22,9 @@ from deepspeed_tpu.analysis.rules import (
     rule_flash_decode,
 )
 
+# reads compiled programs: the compiler's normal pipeline (tests/conftest.py)
+pytestmark = pytest.mark.full_compile
+
 
 class TestRuleDecode:
     def test_registered(self):

@@ -37,6 +37,9 @@ from deepspeed_tpu.analysis.rules import (
     rule_trip_count,
 )
 
+# reads compiled programs: the compiler's normal pipeline (tests/conftest.py)
+pytestmark = pytest.mark.full_compile
+
 # Donated buffers per flavor: params + opt m/v (+ dstate); a floor, not
 # an exact count, so model tweaks don't churn the pin. The offload grad
 # step donates only device_state (params stay, masters live on host).

@@ -51,6 +51,7 @@ def test_chunked_loss_fn_grads_match_dense():
                                    err_msg=str(pa))
 
 
+@pytest.mark.full_compile
 @pytest.mark.slow
 def test_chunked_loss_cuts_compiled_logit_memory():
     """Compiled temp bytes of grad(loss) must drop by roughly the logits'

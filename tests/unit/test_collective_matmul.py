@@ -593,6 +593,7 @@ def _pipe_tp_run(overlap):
             compiled.as_text())
 
 
+@pytest.mark.full_compile
 @pytest.mark.slow
 def test_pipe_tp_overlap_parity_and_hlo_pin():
     """The acceptance pin: with chunks=4 the lowered 1F1B TP step (a)
@@ -618,6 +619,7 @@ def test_pipe_tp_overlap_parity_and_hlo_pin():
     assert in_loop_ar == [], in_loop_ar
 
 
+@pytest.mark.full_compile
 @pytest.mark.slow
 def test_audit_pipeline_tp_flavor_clean():
     """End-to-end: the ds_tpu_audit pipeline_tp flavor (overlap enabled,

@@ -26,7 +26,9 @@ from deepspeed_tpu.analysis.cost import (
     resolve_platform,
 )
 
-pytestmark = pytest.mark.filterwarnings("ignore::DeprecationWarning")
+# reads compiled programs: the compiler's normal pipeline (tests/conftest.py)
+pytestmark = [pytest.mark.filterwarnings("ignore::DeprecationWarning"),
+              pytest.mark.full_compile]
 
 
 def _audit(flavor, config_overrides=None):

@@ -591,6 +591,7 @@ def test_rule_decode_tier_pins_and_geometry():
     assert "geometry mismatch" in msgs
 
 
+@pytest.mark.full_compile
 def test_audit_disagg_flavor_is_clean():
     from deepspeed_tpu.analysis.audit import audit_disagg
 
@@ -602,6 +603,7 @@ def test_audit_disagg_flavor_is_clean():
     assert stats["completions"] == 4
 
 
+@pytest.mark.full_compile
 def test_serving_dimensions_include_tier_knobs():
     from deepspeed_tpu.analysis.tune import (
         SERVING_DIMENSION_NAMES, serving_dimensions)
@@ -616,6 +618,7 @@ def test_serving_dimensions_include_tier_knobs():
         ["batch1", "batch2", "batch4"]
 
 
+@pytest.mark.full_compile
 @_slow
 def test_bad_chunk_candidate_is_typed_rejection():
     """`prefill_chunk` 8 against page_size 12 cannot build (neither

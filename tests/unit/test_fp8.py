@@ -280,6 +280,7 @@ def _lowered_fp8_hlo(fp8_on=True):
     return fn.lower(*args).compile().as_text()
 
 
+@pytest.mark.full_compile
 def test_fp8_hlo_dtypes_and_wire_ratio_pin():
     """The lowered fp8 step must contain f8e4m3fn forward operands AND
     f8e5m2 backward cotangents, and its ZeRO-3 ring-gather ppermute
@@ -302,6 +303,7 @@ def test_fp8_hlo_dtypes_and_wire_ratio_pin():
     assert ratio <= 0.30, (ratio, ring, base)
 
 
+@pytest.mark.full_compile
 def test_rule_fp8_seeded_violations():
     """fp8-enabled context over a program with NO fp8 values (or no
     quantized wire) must raise the rule's errors; non-fp8 contexts are
@@ -327,6 +329,7 @@ def test_rule_fp8_seeded_violations():
                                 fp8_wire_dtype="f8e4m3fn")) == []
 
 
+@pytest.mark.full_compile
 @pytest.mark.slow
 def test_audit_fp8_flavor_clean():
     """The stock fp8 flavor — GPT-2-tiny, delayed scaling, quantized

@@ -171,6 +171,7 @@ def test_scan_pld_and_dropout_still_run():
 # compile collapse (the pinned ratios)
 # ---------------------------------------------------------------------------
 
+@pytest.mark.full_compile
 def test_scan_cuts_compile_wall_and_hlo_size():
     """Measured on CPU at 12 layers: ~0.34x HLO chars (and ~0.15x the
     compile wall, which is what the scan is for). The size is what

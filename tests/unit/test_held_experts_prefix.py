@@ -214,10 +214,12 @@ def test_the_held_path_is_for_a_share_and_the_whole_layer_keeps_its_own():
 
 @pytest.mark.parametrize("model", ["mla_moe", "nemotron_h", "qwen3_next"])
 def test_models_count_the_rows_their_dispatch_filled(model):
-    """`_held_experts` of the three models hands back
-    ``moe_rows_visited`` last in a layer's vector, behind
-    ``moe_pairs_held`` at ``[1]``: whole tiles over the held pairs."""
+    """The jitted expert layer of the three models hands back
+    ``rows_visited`` beside ``pairs_held`` among its layer's counters
+    (`models/blocks.py:ExpertCounters`): whole tiles over the held
+    pairs."""
     import importlib
+    from deepspeed_tpu.models import blocks
     mod = importlib.import_module(f"deepspeed_tpu.models.{model}")
     assert mod.COUNTERS[:2] == ("moe_pairs_routed", "moe_pairs_held")
     assert mod.COUNTERS[-1] == "moe_rows_visited"
@@ -231,9 +233,9 @@ def test_models_count_the_rows_their_dispatch_filled(model):
     w_down = 0.3 * jax.random.normal(key[4], (held, I, M))
     bias = jnp.zeros((E,), jnp.float32)
     if model == "mla_moe":
-        _, c = mod._held_experts(x, mask, router, bias, w_gate, w_up, w_down,
-                                 top_k=k, scaling=1.0, renormalise=True,
-                                 first_expert=first)
+        _, c = blocks.sigmoid_held_experts(
+            x, mask, router, bias, w_gate, w_up, w_down, top_k=k,
+            scaling=1.0, renormalise=True, first_expert=first)
     elif model == "nemotron_h":
         _, c = mod._held_experts(x, x, mask, router, bias, w_up, w_down,
                                  top_k=k, scaling=1.0, renormalise=True,
@@ -241,7 +243,7 @@ def test_models_count_the_rows_their_dispatch_filled(model):
     else:
         _, c = mod._held_experts(x, mask, router, w_gate, w_up, w_down,
                                  top_k=k, first_expert=first)
-    c = np.asarray(c)
     tile = math.gcd(n * k, 256)
-    assert c[0] == int(mask.sum()) * k and 0 < c[1] <= c[0]
-    assert c[-1] == -(-c[1] // tile) * tile
+    assert c.pairs_routed == int(mask.sum()) * k
+    assert 0 < c.pairs_held <= c.pairs_routed
+    assert c.rows_visited == -(-int(c.pairs_held) // tile) * tile

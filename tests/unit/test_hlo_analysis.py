@@ -9,9 +9,13 @@ regex or factor regression cannot silently skew every downstream ratio.
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from deepspeed_tpu.analysis.hlo import collective_bytes, ring_send_bytes
+
+# reads compiled programs: the compiler's normal pipeline (tests/conftest.py)
+pytestmark = pytest.mark.full_compile
 
 SYNTH = """
 HloModule synth

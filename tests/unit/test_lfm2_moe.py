@@ -316,8 +316,8 @@ def test_the_shares_add_up_to_the_uncut_layer(tiny):
                         for b in ("w_gate", "w_up", "w_down")})
         y, counters = lf.HeldExperts(share).apply({"params": ps}, x, mask)
         total = total + np.asarray(y[0])
-        pairs += int(counters[1])
-        assert int(counters[0]) == 24 * 2
+        pairs += int(counters.pairs_held)
+        assert int(counters.pairs_routed) == 24 * 2
     np.testing.assert_allclose(total, want, atol=2e-5)
     assert pairs == 24 * 2          # every pair fell on exactly one share
     w, _ = ref.route(x[0], p, cfg)

@@ -252,14 +252,14 @@ def test_the_shares_add_up_to_the_uncut_layer(tiny):
         y, counters = lh.GroupedExperts(share).apply({"params": ps}, x, mask)
         routed_part = np.asarray(y[0]) - shared
         total = total + routed_part
-        pairs += int(counters[1])
-        tokens_here += int(counters[6])
+        pairs += int(counters.pairs_held)
+        tokens_here += int(counters.tokens_held_group)
         # a token whose kept groups are all elsewhere adds nothing here
         elsewhere = ~np.asarray((kept == first // 4).any(-1))
         assert elsewhere.any()
         assert np.abs(routed_part[elsewhere]).max() < 1e-6
-        assert int(counters[6]) == int((~elsewhere).sum())
-        assert int(counters[5]) == 24
+        assert int(counters.tokens_held_group) == int((~elsewhere).sum())
+        assert int(counters.tokens_routed) == 24
     np.testing.assert_allclose(total + shared, want, atol=2e-5)
     assert pairs == 24 * 3          # every pair fell on exactly one share
     assert tokens_here == 24 * 2    # every token kept two of four groups

@@ -295,7 +295,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
         y, counters = mm.RoutedExperts(share).apply({"params": mine}, x,
                                                     mask)
         total = total + np.asarray(y[0])
-        pairs += int(counters[1])
+        pairs += int(counters.pairs_held)
         one = np.asarray(ref.experts(x[0], mine, ref_cfg(share), first))
         assert np.abs(np.asarray(y[0]) - one).max() <= \
             TOL * np.abs(want).max()

@@ -347,7 +347,7 @@ def test_the_shares_add_up_to_the_uncut_layer(tiny):
             np.asarray(ref.experts(flat, share, ref_cfg(cfg), first)),
             atol=1e-5)
         total = total + np.asarray(y).reshape(want.shape) - shared
-        pairs += int(counters[1])
+        pairs += int(counters.pairs_held)
     np.testing.assert_allclose(total + shared, want, atol=2e-5)
     # every pair fell on exactly one share
     assert pairs == 48 * cfg.num_experts_per_tok

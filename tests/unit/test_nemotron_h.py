@@ -184,7 +184,7 @@ def test_the_shares_add_up_to_the_uncut_layer(tiny):
                   w_down=p["w_down"][first:first + 2])
         y, counters = nh.LatentExperts(share).apply({"params": ps}, x, mask)
         total = total + np.asarray(y[0])
-        pairs += int(counters[1])
+        pairs += int(counters.pairs_held)
     shared = np.asarray(ref.shared(x[0], p))
     np.testing.assert_allclose(total - 3 * shared, want, atol=2e-5)
     assert pairs == 24 * 3          # every pair fell on exactly one share
@@ -358,8 +358,14 @@ LOWERED = {
     # the XLA body this hash pinned
     "granite.prefill": "3f42ee9203bfe3d7447abb9d4db7a66b98cfa048",
     "granite.decode": "1d71efc46d13bf7dc4184849c231b2a5f3239723",
-    "kimi.prefill": "feb256ea11bbac2bad939fb10bd7e2b3df3f7975",
-    "kimi.decode": "bc8536a6fe33c74608adba9b26fbf0827a232987",
+    # PR 59: Kimi's jitted expert layer is `models/blocks.py:
+    # sigmoid_held_experts` (the prefill text moves in that name and
+    # nothing else), and the layers' counters are int32 scalars by name
+    # where they were a stacked vector taken apart by position (the
+    # decode text moves in those int32 ops; every line that holds a
+    # float type is the parent's, in the parent's order: `CHANGES.md`)
+    "kimi.prefill": "4c2688c1d530308dafd7b69f6cffd5f69b18278e",
+    "kimi.decode": "c40fd66a8119ab38538ad1f871d14b71139bc8f1",
     "olmoe.moe": "f8341d876a95c1be49af4102c0b9a7e49aa30274",
 }
 

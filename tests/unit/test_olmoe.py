@@ -64,14 +64,18 @@ def test_logits_loss_terms_and_every_gradient_against_reference(dtype, tol):
         return loss_fn(jax.tree_util.tree_map(
             lambda x: x.astype(dtype), p), {"input_ids": ids})
 
-    (loss, scalars), grads = jax.value_and_grad(cast_loss, has_aux=True)(
-        params)
-    want = olmoe_ref.loss_terms(params, ids, ref_cfg(cfg), LB_COEF, Z_COEF)
-    want_grads = jax.grad(olmoe_ref.loss)(params, ids, ref_cfg(cfg),
-                                          LB_COEF, Z_COEF)
-    logits, _ = model.apply({"params": jax.tree_util.tree_map(
-        lambda x: x.astype(dtype), params)}, ids)
-    want_logits, _, _ = olmoe_ref.forward(params, ids, ref_cfg(cfg))
+    # each side one program: op by op, the two backward passes are some
+    # hundreds of compiles
+    (loss, scalars), grads = jax.jit(jax.value_and_grad(
+        cast_loss, has_aux=True))(params)
+    want = jax.jit(lambda p: olmoe_ref.loss_terms(
+        p, ids, ref_cfg(cfg), LB_COEF, Z_COEF))(params)
+    want_grads = jax.jit(jax.grad(lambda p: olmoe_ref.loss(
+        p, ids, ref_cfg(cfg), LB_COEF, Z_COEF)))(params)
+    logits, _ = jax.jit(lambda p: model.apply({"params": p}, ids))(
+        jax.tree_util.tree_map(lambda x: x.astype(dtype), params))
+    want_logits, _, _ = jax.jit(lambda p: olmoe_ref.forward(
+        p, ids, ref_cfg(cfg)))(params)
 
     scale = float(jnp.abs(want_logits).max())
     assert float(jnp.abs(logits - want_logits).max()) <= tol * scale

@@ -287,6 +287,7 @@ def _hlo_for(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+@pytest.mark.full_compile
 def test_compressed_allreduce_moves_4x_fewer_bytes_than_dense():
     from deepspeed_tpu.analysis.hlo import collective_bytes
 

@@ -20,6 +20,9 @@ import deepspeed_tpu
 from deepspeed_tpu.inference.engine import InferenceEngine
 from deepspeed_tpu.telemetry import programs, scopes, spans
 
+# reads compiled programs: the compiler's normal pipeline (tests/conftest.py)
+pytestmark = pytest.mark.full_compile
+
 # what carries the device's time
 OPCODES = {"fusion", "dot", "convolution", "custom-call", "copy", "sort",
            "gather", "scatter", "dynamic-update-slice"}

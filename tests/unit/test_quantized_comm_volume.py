@@ -32,6 +32,9 @@ from deepspeed_tpu.models.gpt2 import (GPT2Config, GPT2LMHead,
                                        init_gpt2_params, make_gpt2_loss_fn)
 from deepspeed_tpu.analysis.hlo import ring_send_bytes
 
+# reads compiled programs: the compiler's normal pipeline (tests/conftest.py)
+pytestmark = pytest.mark.full_compile
+
 N_DEVICES = 8
 CHUNK = 512
 # The pinned bound: int8 payload + fp32 scales (4/c overhead) + collective

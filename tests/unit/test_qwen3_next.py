@@ -475,7 +475,7 @@ def test_the_shares_add_up_to_the_uncut_layer(tiny):
                         for b in ("w_gate", "w_up", "w_down")})
         y, counters = qn.SparseExperts(share).apply({"params": ps}, x, mask)
         total = total + np.asarray(y[0])
-        pairs += int(counters[1])
+        pairs += int(counters.pairs_held)
     shared = np.asarray(ref.shared(x[0], p))
     np.testing.assert_allclose(total - 3 * shared, want, atol=2e-5)
     assert pairs == 24 * 3          # every pair fell on exactly one share
@@ -527,7 +527,11 @@ def test_expert_layer_with_dead_tokens_against_a_loop():
 # one on purpose takes the new hash from this test's message.
 LOWERED = {
     "qwen3_next.prefill": "331d8944e8342f7c7f6e44ede8df9a1941394c80",
-    "qwen3_next.decode": "2e228a02abc93256503863e20b3473664f23e4af",
+    # PR 59: the decode step's counters are int32 scalars by name
+    # (`models/blocks.py:expert_counters`, `summed_counters`) where they
+    # were a stacked vector taken apart by position; every line that
+    # holds a float type is the parent's, in the parent's order
+    "qwen3_next.decode": "2380fa3d8d0c94bc4833f944962b99d0aaa1e128",
 }
 
 

@@ -407,6 +407,7 @@ class TestRuleSpeculative:
 
 
 class TestAuditSpeculative:
+    @pytest.mark.full_compile
     @pytest.mark.slow
     def test_zero_findings(self):
         """The acceptance criterion: the speculative flavor churns the

@@ -22,6 +22,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+# reads compiled programs: the compiler's normal pipeline (tests/conftest.py)
+pytestmark = pytest.mark.full_compile
+
 B, T, H, D = 8, 1024, 16, 64          # GPT-2 350M: 16 heads x 64
 PAGE = 128                            # chip_smoke.py's page size
 # the serve cell's pool (`benchmarks/suite`): 48 rows of 8 pages + trash

@@ -18,6 +18,9 @@ import pytest
 from tests.unit.test_tpu_compile import (       # noqa: F401 (fixtures)
     PAGE, _compiled_not_interpreted, chip, kernel_grids, topo)
 
+# reads compiled programs: the compiler's normal pipeline (tests/conftest.py)
+pytestmark = pytest.mark.full_compile
+
 # the cell's engine: 64 rows, a bucket of 34,816 (272 pages), a full pool
 # of 5,632 pages and the trash page, 64 rings of five pages
 ROWS, BUCKET, PAGES, CHUNK, RING = 64, 34816, 5633, 1024, 5

@@ -12,6 +12,9 @@ from tests.unit.test_tpu_compile import (       # noqa: F401 (fixtures)
     PAGE, _compiled_not_interpreted, chip, chunk_kernel_calls,
     scores_of_a_bucket, topo)
 
+# reads compiled programs: the compiler's normal pipeline (tests/conftest.py)
+pytestmark = pytest.mark.full_compile
+
 ROWS, BUCKET, PAGES, CHUNK = 192, 9216, 3841, 1024
 
 

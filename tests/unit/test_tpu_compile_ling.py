@@ -11,6 +11,9 @@ import pytest
 from tests.unit.test_tpu_compile import (       # noqa: F401 (fixtures)
     PAGE, _compiled_not_interpreted, chip, kernel_grids, topo)
 
+# reads compiled programs: the compiler's normal pipeline (tests/conftest.py)
+pytestmark = pytest.mark.full_compile
+
 ROWS, BUCKET, PAGES, CHUNK = 64, 34816, 4097, 1024
 H, K, Q = 32, 128, 64
 

@@ -15,6 +15,9 @@ import pytest
 from tests.unit.test_tpu_compile import (       # noqa: F401 (fixtures)
     PAGE, _compiled_not_interpreted, chip, kernel_grids, topo)
 
+# reads compiled programs: the compiler's normal pipeline (tests/conftest.py)
+pytestmark = pytest.mark.full_compile
+
 # the cell's engine: 64 rows, a bucket of 33,792 (264 pages), a full pool
 # of 6,144 pages and the trash page, 64 rings of two pages
 ROWS, BUCKET, PAGES, CHUNK = 64, 33792, 6145, 1024

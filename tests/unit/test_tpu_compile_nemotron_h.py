@@ -12,6 +12,9 @@ from tests.unit.test_tpu_compile import (       # noqa: F401 (fixtures)
     PAGE, _compiled_not_interpreted, chip, chunk_kernel_calls, decode_call,
     held_experts_calls, kernel_grids, scores_of_a_bucket, topo)
 
+# reads compiled programs: the compiler's normal pipeline (tests/conftest.py)
+pytestmark = pytest.mark.full_compile
+
 # the cell's engine: 96 rows, a bucket of 5,120 (40 pages), a pool of
 # 3,840 pages and the trash page, 2 key heads of 128
 ROWS, BUCKET, PAGES, CHUNK = 96, 5120, 3841, 1024

@@ -20,6 +20,9 @@ from tests.unit.test_tpu_compile import (       # noqa: F401 (fixtures)
     MLA_BUCKET, MLA_PAGES, MLA_ROWS, PAGE, _compiled_not_interpreted, chip,
     topo)
 
+# reads compiled programs: the compiler's normal pipeline (tests/conftest.py)
+pytestmark = pytest.mark.full_compile
+
 CHUNK = 1024
 
 

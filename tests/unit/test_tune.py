@@ -32,6 +32,9 @@ from deepspeed_tpu.analysis.tune import (
     write_expected_log,
 )
 
+# reads compiled programs: the compiler's normal pipeline (tests/conftest.py)
+pytestmark = pytest.mark.full_compile
+
 BASE = {
     "train_batch_size": 8,
     "train_micro_batch_size_per_gpu": 1,

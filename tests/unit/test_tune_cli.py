@@ -15,6 +15,9 @@ import sys
 
 import pytest
 
+# reads compiled programs: the compiler's normal pipeline (tests/conftest.py)
+pytestmark = pytest.mark.full_compile
+
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 CLI = os.path.join(REPO, "bin", "ds_tpu_tune")

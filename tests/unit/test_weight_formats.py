@@ -195,6 +195,7 @@ def test_a_turned_answer_is_placed_leaf_by_leaf(monkeypatch):
     assert counters2 == counters
 
 
+@pytest.mark.full_compile
 def test_the_registered_twin_lowers_the_program_that_runs(monkeypatch):
     model, params = toy()
     plain, _, _ = build(model, params)
@@ -226,6 +227,7 @@ def test_the_registered_twin_lowers_the_program_that_runs(monkeypatch):
     assert [i for i, _ in parameter_copies(twin, n)] == copied
 
 
+@pytest.mark.full_compile
 def test_shapes_keeps_a_committed_arrays_format():
     x = jnp.arange(12, dtype=jnp.float32).reshape(3, 4)
     turned = jax.device_put(x, Format(Layout((1, 0), ()), x.sharding))

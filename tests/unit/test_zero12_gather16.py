@@ -96,6 +96,7 @@ def test_eval_and_the_compat_loop_read_the_sharded_masters(stage0, stage):
                                rtol=1e-6, atol=1e-7)
 
 
+@pytest.mark.full_compile
 @pytest.mark.parametrize("stage", [1, 2])
 def test_param_gathers_are_bf16_at_partitioner_level(tmp_path, stage):
     lowered_train_step(stage, compiler_options={

@@ -35,6 +35,7 @@ def test_stage3_losses_match_stage0_exactly():
         assert l0 == pytest.approx(l3, rel=1e-6), (l0, l3)
 
 
+@pytest.mark.full_compile
 def test_stage3_param_gathers_are_bf16_at_partitioner_level(tmp_path):
     # The fixture clears jax's caches between its warm-up step and the
     # dump compile, so XLA really compiles with these options (a

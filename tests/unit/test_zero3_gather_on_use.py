@@ -76,6 +76,7 @@ def test_chunks1_bit_identical_to_legacy_caster():
         assert np.array_equal(a, b_)
 
 
+@pytest.mark.full_compile
 def test_chunked_rings_match_legacy_and_lower_to_permutes():
     b = make_batch()
     legacy = build_engine3(gather_on_use=False)

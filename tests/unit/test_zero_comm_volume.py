@@ -31,6 +31,9 @@ import pytest
 from deepspeed_tpu.analysis.hlo import collective_bytes, ring_send_bytes
 from tests.unit.zero_fixtures import PARAM_BYTES, lowered_train_step
 
+# reads compiled programs: the compiler's normal pipeline (tests/conftest.py)
+pytestmark = pytest.mark.full_compile
+
 N_DEVICES = 8
 
 

@@ -15,6 +15,9 @@ import pytest
 # overheads: 8 layers x 512x512 fp32 ≈ 8.4 MB params (zero_fixtures).
 from tests.unit.zero_fixtures import NLAYERS, HIDDEN, lowered_train_step
 
+# reads compiled programs: the compiler's normal pipeline (tests/conftest.py)
+pytestmark = pytest.mark.full_compile
+
 
 def compiled_stats(stage, accum=4):
     ma = lowered_train_step(stage, accum=accum).memory_analysis()
