@@ -21,27 +21,53 @@ session, feeding ``ds_tpu_metrics summary``'s serve mode, and every
 completion one ``request_done`` event (queue wait, time to first token,
 latency).
 
+**The admission round.** ``step()`` returns whenever it has made a
+token a caller can read. A *round* begins at a ``step()`` that finds a
+free row and a due request at the head of the queue; its members are
+the requests queued at that moment, in order, as far as free rows go.
+Each ``step()`` of the round admits ONE member (pages, the whole
+prefill, the first token) and returns; when no member is left (or the
+pool refuses one, which stays queued) the next ``step()`` is the
+round's decode over the live rows, and ends it. A request submitted
+after a round began waits for the next one. A ``step()`` with nothing
+to admit is a decode step (or an idle tick) and nothing else. The
+engine sees the calls it would see if a round were one call: the same
+prefills in the same order, then the decode; only the returns between
+them are new. ``step_count`` advances at a decode or an idle tick
+only, so ``Request.arrival_step``, ``Completion.steps``, the fault
+seams' step numbers and ``run(max_steps=...)`` count decode steps, not
+returns.
+
 **Stamps and spans** (always on; `telemetry/spans.py` has the clock and
 the ring). Every request is stamped on ``telemetry.spans.clock``:
 ``arrival_t`` (the caller's, else ``submit_t``), ``submit_t``,
 ``admit_t`` (taken off the queue), ``first_token_t`` (``sample_first``
 has returned: the token exists on the host), ``first_return_t`` (the
-``step()`` that made it returns: the first moment a caller of
-``step()`` can read it), ``token_t`` (one per generated token) and
-``finish_t``; they ride on :class:`Completion` and, at finish, on one
-``serve/request`` record in the span ring. Every ``step()`` is one
-``serve/step`` span whose attrs carry that step's counters, read at the
-step's end (``live_rows``, ``batch``, ``max_batch``, ``queue_depth``,
+``step()`` that admitted the request returns, before any other engine
+call is launched: the first moment a caller of ``step()`` can read the
+token, so ``first_return_t - first_token_t`` is the slot's booking and
+the return, not a decode step), ``token_t`` (one per generated token)
+and ``finish_t``; they ride on :class:`Completion` and, at finish, on
+one ``serve/request`` record in the span ring. Every ``step()`` call
+is one ``serve/step`` span whose attrs carry that return's counters,
+read at its end (``step``, the decode step count it began at, shared
+by the returns of one round; ``admitted``, 0 or 1, the requests this
+return admitted; ``round``, the round's ordinal, on the returns of a
+round; on a return that decoded or ticked idle ``round_prefills`` and
+``round_prefill_s``, the prefills of the round it ended and the
+seconds of those that ran with a row waiting in decode, 0 where it
+ended none; ``live_rows``, ``batch``, ``max_batch``, ``queue_depth``,
 ``tokens``, ``pages_live``, ``pages_resident``, ``pages_total``;
-``gc_s``, the collector's seconds over the step; and, on a step that
+``gc_s``, the collector's seconds over the call; and, on a return that
 closes 50 ms or more after the last one that had them, ``cpu_s`` /
 ``cpu_wall_s``, the thread's CPU and wall seconds since then
-(``spans.CpuMark``): by these a stall is read afterwards), with
-children ``expire``, ``admit`` (one per request taken up, attrs
-``rid`` and ``rows_waiting``, the rows that hold a request in decode
-while this one's prompt is prefilled; under it ``pages``, the engine's
-``prefill``, which takes both over, and ``sample``), ``grow``,
-``inputs``, the engine's ``decode`` (under
+(``spans.CpuMark``): by these a stall is read afterwards). Its
+children, on a round's first return ``expire``, on an admitting return
+``admit`` (attrs ``rid`` and ``rows_waiting``, the rows that hold a
+request in decode while this one's prompt is prefilled; under it
+``pages``, the engine's ``prefill``, which takes both over, and
+``sample``), and on a decoding return (``expire`` first where it is no
+round's) ``grow``, ``inputs``, the engine's ``decode`` (under
 it ``upload``, ``dispatch``, ``wait_tokens``: the step's tokens come
 home and its logits stay on the device, which nothing here reads; a
 speculative engine has ``draft`` and ``verify`` instead) and ``book``.
@@ -136,7 +162,7 @@ class Completion:
     submit_t: Optional[float] = None
     admit_t: Optional[float] = None         # taken off the queue
     first_token_t: Optional[float] = None   # exists on the host
-    first_return_t: Optional[float] = None  # its step() returned
+    first_return_t: Optional[float] = None  # its admitting step() returned
     token_t: List[float] = dataclasses.field(default_factory=list)
     finish_t: Optional[float] = None
 
@@ -153,7 +179,8 @@ class Completion:
 
     @property
     def hold_s(self):
-        """How long the finished first token waited inside ``step()``."""
+        """How long the finished first token waited inside ``step()``:
+        the slot's booking and the return."""
         return _minus(self.first_return_t, self.first_token_t)
 
     @property
@@ -176,6 +203,15 @@ class _Slot:
     token_t: List[float] = dataclasses.field(default_factory=list)
 
 
+@dataclasses.dataclass
+class _Round:
+    """An admission round in progress (see the module's head)."""
+    ordinal: int
+    left: int                   # members not yet admitted
+    scan: int = 0               # the row the search for a free one resumes at
+    prefills: int = 0           # members admitted so far
+    prefill_s: float = 0.0      # their prefills' seconds with a row waiting
+
 
 class ContinuousBatchingScheduler:
     def __init__(self, engine, session=None):
@@ -183,10 +219,12 @@ class ContinuousBatchingScheduler:
         self.session = session if session is not None else engine.session
         self.queue = collections.deque()
         self.slots = [None] * engine.max_batch
-        self.step_count = 0
+        self.step_count = 0             # decode steps and idle ticks
+        self.rounds = 0                 # admission rounds begun
+        self._round = None              # the round in progress
         self.completions = []
-        # completions made inside the step that admitted them: their
-        # first token is not out before that step returns
+        # completions made inside the step() that admitted them: their
+        # first token is not out before that call returns
         self._unreturned = []
         self._step_attrs = {}           # the running step's counters
         self._cpu_mark = CpuMark()
@@ -314,15 +352,16 @@ class ContinuousBatchingScheduler:
                               zip(comp.token_t[1:], comp.token_t[2:])])
 
     def _stamp_returned(self):
-        """The last thing ``step()`` does: first tokens made in this
-        step can be read from now on."""
+        """The last thing ``step()`` does: the first token of the
+        request this call admitted can be read from now on, and no
+        engine call has been launched since it was sampled."""
         now = clock()
         for s in self.slots:
             if s is not None and s.first_return_t is None:
                 s.first_return_t = now
         for comp in self._unreturned:
-            # finished inside the step that admitted it: the caller
-            # sees token and completion together, now
+            # finished on its first token: the caller sees token and
+            # completion together, now
             comp.first_return_t = comp.finish_t = now
             self._record_request(comp)
         self._unreturned.clear()
@@ -371,39 +410,65 @@ class ContinuousBatchingScheduler:
                                       rid=s.request.rid, where="decode",
                                       step=self.step_count)
 
-    def _admit(self):
-        for i in range(len(self.slots)):
-            if self.slots[i] is not None:
-                continue
-            if not self.queue or \
-                    self.queue[0].arrival_step > self.step_count:
+    def _begin_round(self):
+        """The admission round this ``step()`` begins, or None where no
+        row is free or no due request heads the queue. Its members are
+        the head of the queue as it stands, as far as free rows go."""
+        free = sum(s is None for s in self.slots)
+        left = 0
+        for req in self.queue:
+            if left == free or req.arrival_step > self.step_count:
                 break
-            req = self.queue[0]
-            attrs = {"rid": req.rid, "rows_waiting": sum(
-                s is not None for s in self.slots)}
-            with Span("admit", self.session, attrs):
-                if not self._admit_one(i, req):
-                    # pool can't back the prompt right now even after
-                    # the eviction ladder — leave the request queued
-                    # and let running rows finish and free pages.
-                    attrs["admitted"] = False
-                    break
+            left += 1
+        if not left:
+            return None
+        self.rounds += 1
+        return _Round(ordinal=self.rounds, left=left)
+
+    def _admit_next(self, rnd):
+        """Admit the round's next member into the next free row. False
+        where the pool cannot back its prompt (it stays queued and the
+        round admits no more)."""
+        # the next free row: the round began with one a member, and a
+        # row freed by a member that finished on its first token is not
+        # taken again in this round
+        i = rnd.scan
+        while self.slots[i] is not None:
+            i += 1
+        rnd.scan = i + 1
+        rnd.left -= 1
+        req = self.queue[0]
+        attrs = {"rid": req.rid, "rows_waiting": sum(
+            s is not None for s in self.slots)}
+        with Span("admit", self.session, attrs):
+            prefill_s = self._admit_one(i, req)
+            if prefill_s is None:
+                # pool can't back the prompt right now even after the
+                # eviction ladder — leave the request queued and let
+                # running rows finish and free pages.
+                attrs["admitted"] = False
+                return False
+        rnd.prefills += 1
+        if attrs["rows_waiting"]:
+            rnd.prefill_s += prefill_s
+        return True
 
     def _admit_one(self, i, req):
         """Take ``req`` off the queue into row ``i``: pages, prefill,
-        first token. False (and the request stays queued) when the pool
-        cannot back its prompt."""
+        first token. Returns the prefill call's seconds; None (and the
+        request stays queued) when the pool cannot back its prompt."""
         session = self.session
         with Span("pages", session):
             row = self.paging.admit(req.prompt, session_id=req.session_id,
                                     slot=i)
         if row is None:
-            return False
+            return None
         self.queue.popleft()
         admit_t = clock()
         last_logits = self.engine.prefill(
             i, req.prompt, page_table=row.table(self.paging.table_width),
             start=row.start)
+        prefill_s = clock() - admit_t
         self.paging.after_prefill(row, req.prompt)
         with Span("sample", session):
             first = self.engine.sample_first(last_logits)
@@ -414,19 +479,23 @@ class ContinuousBatchingScheduler:
             paging=row, admit_t=admit_t, token_t=[clock()])
         self._step_attrs["tokens"] += 1
         self._check_finished(i)
-        return True
+        return prefill_s
 
     # -- the decode loop ----------------------------------------------------
 
     def step(self):
-        """Admit what the queue allows, then run one compiled decode
-        step over the live rows. Returns True while there is (or will
-        be) work left. With a speculative engine the "step" is a whole
-        draft/verify round and rows advance by a VARIABLE number of
-        tokens (their accepted length) — see :meth:`_spec_step`."""
+        """Make the next tokens a caller can read, and return: admit ONE
+        member of the admission round (its prefill and its first token,
+        which ``slot.generated`` or its completion holds when this
+        returns, before any other engine call is launched), or, with no
+        member left to admit, run one compiled decode step over the
+        live rows (see the module's head). Returns True while there is
+        (or will be) work left. With a speculative engine the decode is
+        a whole draft/verify round and rows advance by a VARIABLE number
+        of tokens (their accepted length) — see :meth:`_spec_step`."""
         attrs = self._step_attrs = {
             "step": self.step_count, "max_batch": self.engine.max_batch,
-            "batch": 0, "tokens": 0}
+            "batch": 0, "tokens": 0, "admitted": 0}
         gc0 = collector.seconds
         try:
             with Span("serve/step", self.session, attrs):
@@ -439,7 +508,7 @@ class ContinuousBatchingScheduler:
                     # descheduled thread
                     attrs["gc_s"] = collector.seconds - gc0
                     self._cpu_mark.stamp(attrs)
-                    # the step's counters, read where they are true:
+                    # the call's counters, read where they are true:
                     # at its end, as a caller polling after it would
                     attrs["live_rows"] = sum(
                         s is not None for s in self.slots)
@@ -483,9 +552,21 @@ class ContinuousBatchingScheduler:
 
     def _step(self):
         session = self.session
-        with Span("expire", session):
-            self._expire()
-        self._admit()
+        attrs = self._step_attrs
+        rnd = self._round
+        if rnd is None:
+            with Span("expire", session):
+                self._expire()
+            rnd = self._round = self._begin_round()
+        if rnd is not None:
+            attrs["round"] = rnd.ordinal
+            if rnd.left and self._admit_next(rnd):
+                attrs["admitted"] = 1
+                return True
+            # no member left, or one refused: the round's decode
+            self._round = None
+        attrs["round_prefills"] = rnd.prefills if rnd else 0
+        attrs["round_prefill_s"] = rnd.prefill_s if rnd else 0.0
         if getattr(self.engine, "speculative", None) is not None:
             return self._spec_step(self.engine.speculative)
         # grow each live row's page mapping to cover this step's write
@@ -625,6 +706,9 @@ class ContinuousBatchingScheduler:
     def run(self, requests=None, max_steps=100000):
         """Drain ``requests`` (plus anything already queued) through the
         decode loop; returns the completions in finish order.
+        ``max_steps`` bounds the decode steps (and idle ticks) run
+        here, as ``step_count`` counts them, not the returns of
+        ``step()``: an admission is not one.
 
         Exhausting ``max_steps`` with work still in flight no longer
         returns silently: every live row finishes with the typed
@@ -634,13 +718,12 @@ class ContinuousBatchingScheduler:
         the truncation visible in telemetry."""
         for r in requests or ():
             self.submit(r)
-        steps = 0
-        while steps < max_steps:
-            if not self.step():
-                break
-            steps += 1
+        last = self.step_count + max_steps
+        while self.step_count < last and self.step():
+            pass
         live = [i for i, s in enumerate(self.slots) if s is not None]
         if live or self.queue:
+            self._round = None
             for i in live:
                 self._finish(i, "incomplete")
             queued = len(self.queue)

@@ -243,6 +243,7 @@ def test_prefix_hit_under_a_chunk_of_two_pages_writes_no_shared_page():
     sched = ContinuousBatchingScheduler(eng)
     sched.submit(a)
     sched.step()                                    # "a" prefilled, live
+    sched.step()                                    # its round's decode
     row_a = next(s for s in sched.slots if s is not None)
     pages_a = list(row_a.paging.pages[:3])
     before = jax.tree_util.tree_map(

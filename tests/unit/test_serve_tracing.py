@@ -104,27 +104,35 @@ def test_arrival_t_is_the_callers_when_given():
     assert comps["b"].admit_t >= comps["a"].finish_t
 
 
-def test_first_token_is_held_until_its_step_returns():
-    """``step()`` admits (first token) and then decodes: the first
-    token exists before the decode and can be read only after it."""
+def test_first_token_is_out_before_the_rounds_decode():
+    """The ``step()`` that admits a request returns with its first
+    token, and the round's decode is the next call: the token is not
+    held for a decode step it has no part in."""
 
     class Slow(StubEngine):
         def decode(self, tokens, positions, page_tables):
             time.sleep(0.02)
             return super().decode(tokens, positions, page_tables)
 
-    sched = ContinuousBatchingScheduler(Slow(max_batch=2))
+    eng = Slow(max_batch=2)
+    sched = ContinuousBatchingScheduler(eng)
     sched.submit(Request("a", [1, 2], max_new_tokens=4))
     before = clock()
-    sched.step()
+    assert sched.step()
     after = clock()
     slot = sched.slots[0]
     assert before <= slot.token_t[0] < slot.first_return_t <= after
-    assert slot.first_return_t - slot.token_t[0] >= 0.02
+    assert slot.first_return_t - slot.token_t[0] < 0.02
+    assert len(slot.token_t) == len(slot.generated) == 1
+    assert eng.decodes == 0 and sched.step_count == 0
+    sched.step()                        # the round's decode
     assert len(slot.token_t) == len(slot.generated) == 2
+    assert slot.token_t[1] - slot.first_return_t >= 0.02
+    assert eng.decodes == 1 == sched.step_count
     comp = sched.run()[0]
     assert comp.first_return_t == slot.first_return_t
     assert comp.first_return_t < comp.finish_t
+    assert comp.hold_s < 0.02
 
 
 def test_request_finished_in_its_first_step_is_stamped_at_return():
@@ -180,11 +188,25 @@ def test_every_step_holds_its_children(served):
     records = [r for r in records
                if "/jax/" not in r[0] and not r[0].endswith("/gc")]
     steps = [r for r in records if r[0] == "serve/step"]
-    assert len(steps) == sched.step_count
+    # one span a return: one for every request admitted, and one for
+    # every decode step or idle tick
+    assert sum(r[3]["admitted"] for r in steps) == 6
+    assert len(steps) == 6 + sched.step_count
     seen = set()
+    last_round = 0
     for step in steps:
         kids = _children(records, step)
-        assert kids and kids[0][0] == "serve/step/expire"
+        names = [k[0].rsplit("/", 1)[1] for k in kids]
+        # ``expire`` once a round, before its first admission, and on a
+        # step that is no round's
+        expired = step[3].get("round") != last_round
+        last_round = step[3].get("round", last_round)
+        if step[3]["admitted"]:
+            assert names == ["expire"] * expired + ["admit"]
+        else:
+            assert ("expire" in names) == expired == \
+                (names[0] == "expire")
+            assert "admit" not in names
         assert sum(k[2] - k[1] for k in kids) <= step[2] - step[1]
         for a, b in zip(kids, kids[1:]):    # one thread: no overlap
             assert a[2] <= b[1]
@@ -224,9 +246,31 @@ def test_no_step_copies_the_logits_home(served):
 def test_step_attrs_are_the_steps_counters(served):
     comps, records, sched = served
     steps = [r[3] for r in records if r[0] == "serve/step"]
-    assert [a["step"] for a in steps] == list(range(len(steps)))
+    # ``step`` counts decode steps: a round's admitting returns carry
+    # the number of the decode that ends it
+    decoded = [a for a in steps if not a["admitted"]]
+    assert [a["step"] for a in decoded] == list(range(sched.step_count))
+    assert [a["step"] for a in steps] == sorted(a["step"] for a in steps)
     assert sum(a["tokens"] for a in steps) == \
         sum(len(c.tokens) for c in comps)
+    # a round: its admitting returns, then the decode that ends it and
+    # says how many prefills it held
+    rounds = {}
+    for a in steps:
+        if "round" in a:
+            rounds.setdefault(a["round"], []).append(a)
+    assert sorted(rounds) == list(range(1, sched.rounds + 1))
+    for members in rounds.values():
+        *admits, last = members
+        assert admits and all(a["admitted"] == 1 == a["tokens"]
+                              and a["batch"] == 0 for a in admits)
+        assert last["admitted"] == 0
+        assert last["round_prefills"] == len(admits)
+        assert last["round_prefill_s"] >= 0.0
+        assert {a["step"] for a in members} == {last["step"]}
+    for a in decoded:
+        if "round" not in a:
+            assert a["round_prefills"] == 0 == a["round_prefill_s"]
     for a in steps:
         assert a["max_batch"] == 2
         assert 0 <= a["live_rows"] <= a["batch"] <= 2 or a["batch"] == 0
